@@ -1192,6 +1192,13 @@ class ClusterRuntime(CoreRuntime):
     # ------------------------------------------------------------ tasks
 
     def submit_task(self, remote_function, args, kwargs, options: TaskOptions):
+        resources = options.resource_demand()
+        if resources.get("TPU", 0) > 0:
+            raise exceptions.TpuLeaseError(
+                f"task {remote_function.function_name} asks for TPU="
+                f"{resources['TPU']}: tasks run in pooled workers, which "
+                "hold no chip and never open the TPU backend — lease "
+                "chips with an actor (num_tpus=)")
         fn_key = self.export(remote_function.function, "fn")
         task_id = TaskID.for_normal_task(self.job_id)
         streaming = options.num_returns == "streaming"
@@ -1215,7 +1222,7 @@ class ClusterRuntime(CoreRuntime):
             args_payload=args_payload,
             num_returns=num_returns,
             owner_address=self.address,
-            resources=options.resource_demand(),
+            resources=resources,
             # Streaming tasks never retry: replaying would re-emit items
             # the consumer already observed (ref: generator tasks are
             # non-retriable by default).

@@ -64,11 +64,9 @@ class JobManager:
         log_path = os.path.join(self._session_dir, "logs",
                                 f"job-{job_id}.log")
         os.makedirs(os.path.dirname(log_path), exist_ok=True)
-        from ant_ray_tpu._private import services  # noqa: PLC0415
-
-        # Job drivers are user code — they may run accelerator work, so
-        # restore the TPU-plugin trigger the control-plane env stashed.
-        env = services.accelerator_env(dict(os.environ))
+        # A job driver inherits this process's CPU pin: it holds no
+        # chip — the workers its actors lease own them.
+        env = dict(os.environ)
         env["ART_ADDRESS"] = self._gcs_address
         # Drivers must be able to import the framework even when it is
         # run from a checkout rather than pip-installed.

@@ -923,7 +923,17 @@ class GcsServer:
         self_metrics_every = max(1, int(round(2.0 / period)))
         ticks = 0
         while True:
+            asleep = time.monotonic()
             await asyncio.sleep(period)
+            overslept = time.monotonic() - asleep - period
+            if overslept > period:
+                # This process did not run for that long — a frozen host
+                # (opening a TPU stalls every process of a sandboxed VM
+                # for ~6 s), a paused or starved head.  Beats sent
+                # meanwhile sit unread in the sockets: nobody is judged
+                # by a clock the head itself could not keep.
+                for node_id in self._last_heartbeat:
+                    self._last_heartbeat[node_id] += overslept
             ticks += 1
             if ticks % self_metrics_every == 0:
                 try:  # observability must never stall liveness judging

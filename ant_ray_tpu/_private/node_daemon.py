@@ -23,6 +23,7 @@ import uuid
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 
+from ant_ray_tpu._private import jax_utils
 from ant_ray_tpu._private.config import global_config
 from ant_ray_tpu._private.ids import NodeID, ObjectID, WorkerID
 from ant_ray_tpu._private.object_store import ObjectStore, default_store_capacity
@@ -46,6 +47,12 @@ def _bundle_fits(bundle: dict, demand: dict) -> bool:
     and the grant/infeasible decision — they must never diverge)."""
     return all(bundle["resources"].get(k, 0.0) >= v
                for k, v in demand.items())
+
+
+def _released_while_blocked(resources: dict) -> dict:
+    """What an actor parked in get() gives back: everything but its
+    chips — the process keeps the device open while it waits."""
+    return {k: v for k, v in resources.items() if k != "TPU"}
 
 
 def _enable_subreaper() -> bool:
@@ -107,7 +114,8 @@ class WorkerHandle:
 class NodeManager:
     def __init__(self, gcs_address: str, resources: dict[str, float],
                  session_dir: str, host: str = "127.0.0.1", port: int = 0,
-                 labels: dict[str, str] | None = None):
+                 labels: dict[str, str] | None = None,
+                 chip_platform: str | None = None):
         self.node_id = NodeID.from_random()
         self._gcs_address = gcs_address
         self._server = RpcServer(host, port)
@@ -146,6 +154,11 @@ class NodeManager:
 
         self._total = dict(resources)
         self._available = dict(resources)
+        # One owner per chip: the ledger of chip indices, and through it
+        # the platform every worker is spawned with (jax_utils).
+        self._chips = _tpu.ChipLeases(
+            int(resources.get("TPU", 0)),
+            chip_platform or jax_utils.chip_platform())
         # (pg_id, bundle_index) -> {"resources", "available", "committed"}
         self._bundles: dict[tuple, dict] = {}
         self._workers: dict[WorkerID, WorkerHandle] = {}
@@ -358,6 +371,12 @@ class NodeManager:
                 node_id=self.node_id.hex()).start()
         logger.info("node %s listening on %s (resources=%s)",
                     self.node_id.hex()[:8], self.address, self._total)
+        logger.info(
+            "object store backend: %s; workers that lease TPU start on "
+            "platform %r, all others on 'cpu'",
+            "native arena (art_native)" if self.store.uses_arena
+            else "pure-Python file store (art_native did not build)",
+            self._chips.platform)
         return self.address
 
     async def _prestart_worker(self):
@@ -580,14 +599,17 @@ class NodeManager:
         holds what, which workers are blocked, and each bundle pool."""
         return {
             "available": dict(self._available),
+            "chip_platform": self._chips.platform,
             "bundles": {f"{k[0].hex() if hasattr(k[0], 'hex') else k[0]}"
                         f"#{k[1]}": {"capacity": dict(b["resources"]),
                                      "available": dict(b["available"])}
                         for k, b in self._bundles.items()},
             "workers": [{
                 "worker_id": wid.hex() if hasattr(wid, "hex") else str(wid),
+                "pid": h.proc.pid,
                 "state": h.state,
                 "blocked": h.blocked,
+                "tpu_chips": list(self._chips.held_by(wid)),
                 "lease": dict(h.lease_resources or {}),
                 "actor": (h.actor_spec.class_name
                           if h.actor_spec is not None and
@@ -848,11 +870,7 @@ class NodeManager:
         if actor_spec is not None and runtime_env is None:
             runtime_env = actor_spec.runtime_env
         worker_id = WorkerID.from_random()
-        from ant_ray_tpu._private import services  # noqa: PLC0415
-
-        # Workers run accelerator code: restore the TPU-plugin trigger
-        # the control-plane env stashed (no-op under the CPU pin).
-        env = services.accelerator_env(dict(os.environ))
+        env = dict(os.environ)
         cwd = None
         if runtime_env:
             # packages were prefetched by _ensure_runtime_env (async);
@@ -870,6 +888,12 @@ class NodeManager:
         env["ART_STORE_DIR"] = self.store.directory
         env["ART_WORKER_ID"] = worker_id.hex()
         env["ART_NODE_ID"] = self.node_id.hex()
+        # The platform comes last, so no runtime env overrides it: only
+        # a worker whose actor leased TPU may open the chip; pooled
+        # workers and every other actor are pinned to the CPU backend.
+        env.update(self._chips.grant(
+            worker_id, actor_spec.resources.get("TPU", 0)
+            if actor_spec is not None else 0))
         log_path = os.path.join(self._session_dir, "logs",
                                 f"worker-{worker_id.hex()[:8]}.log")
         os.makedirs(os.path.dirname(log_path), exist_ok=True)
@@ -927,6 +951,7 @@ class NodeManager:
                 if handle.proc.poll() is None:
                     continue
                 del self._workers[worker_id]
+                self._chips.release(worker_id)
                 # A dead worker may itself be a lessee (nested task
                 # submission): reclaim whatever it still leased.
                 self._reclaim_leases_of(handle.address)
@@ -937,8 +962,8 @@ class NodeManager:
                     else:
                         self._release(handle.lease_resources)
                 if handle.state == ACTOR and handle.actor_spec is not None:
-                    if not handle.blocked:  # blocked already released
-                        self._release_actor_resources(handle.actor_spec)
+                    self._release_actor_resources(
+                        handle.actor_spec, self._still_held(handle))
                     # Death reports must survive a GCS restart window —
                     # fire-and-forget here loses the actor forever
                     # (restored as ALIVE on resync with no one to
@@ -1150,11 +1175,9 @@ class NodeManager:
     def _start_agent(self) -> None:
         if not global_config().enable_node_agent:
             return
-        from ant_ray_tpu._private import services  # noqa: PLC0415
-
         os.makedirs(os.path.join(self._session_dir, "logs"),
                     exist_ok=True)
-        agent_env = services.control_plane_env()
+        agent_env = jax_utils.cpu_pinned_env()
         # The agent tags its published device gauges with the node id
         # (per-node series identity + death-time expiry in the GCS).
         agent_env["ART_NODE_ID"] = self.node_id.hex()
@@ -1320,6 +1343,13 @@ class NodeManager:
 
     async def _lease_worker_impl(self, payload):
         demand: dict[str, float] = payload.get("resources", {})
+        if demand.get("TPU", 0) > 0:
+            # Pooled workers are pinned to the CPU backend; running the
+            # task there would be the silent fallback.  (Submitters
+            # raise TpuLeaseError before they get here.)
+            return {"infeasible": True,
+                    "reason": "a task cannot lease TPU: a chip belongs "
+                              "to one process — lease it with an actor"}
         gcs = self._clients.get(self._gcs_address)
         from ant_ray_tpu._private import runtime_env as renv  # noqa: PLC0415
 
@@ -1750,7 +1780,9 @@ class NodeManager:
                 self._release(handle.lease_resources)
         elif handle.state == ACTOR and handle.actor_spec is not None:
             handle.blocked = True
-            self._release_actor_resources(handle.actor_spec)
+            self._release_actor_resources(
+                handle.actor_spec,
+                _released_while_blocked(handle.actor_spec.resources))
         return True
 
     async def _worker_unblocked(self, payload):
@@ -1769,12 +1801,13 @@ class NodeManager:
         elif handle.state == ACTOR and handle.actor_spec is not None:
             handle.blocked = False
             spec = handle.actor_spec
+            released = _released_while_blocked(spec.resources)
             if spec.placement_group_id is not None:
                 self._bundle_allocate(
                     (spec.placement_group_id,
-                     spec.placement_group_bundle_index), spec.resources)
+                     spec.placement_group_bundle_index), released)
             else:
-                self._allocate(spec.resources)
+                self._allocate(released)
         return True
 
     # ------------------------------------------------------------ bundles
@@ -1855,15 +1888,18 @@ class NodeManager:
             if not self._bundle_can_allocate(key, spec.resources):
                 raise RuntimeError("bundle cannot host this actor")
             self._bundle_allocate(key, spec.resources)
+        else:
+            placement = spec.placement_resources or spec.resources
+            if not self._feasible(placement):
+                raise RuntimeError("insufficient node resources for actor")
+            # Only the running demand is held for the actor's lifetime
+            # (placement demand is a scheduling-time constraint).
+            self._allocate(spec.resources)
+        try:
             self._spawn_worker(actor_spec=spec)
-            return True
-        placement = spec.placement_resources or spec.resources
-        if not self._feasible(placement):
-            raise RuntimeError("insufficient node resources for actor")
-        # Only the running demand is held for the actor's lifetime
-        # (placement demand is a scheduling-time constraint).
-        self._allocate(spec.resources)
-        self._spawn_worker(actor_spec=spec)
+        except Exception:   # e.g. TpuLeaseError: no chip to own
+            self._release_actor_resources(spec)
+            raise
         return True
 
     async def _kill_actor_worker(self, payload):
@@ -1874,22 +1910,39 @@ class NodeManager:
                 # Clear the spec first so the monitor loop doesn't report
                 # an (expected) death to the GCS.
                 spec = handle.actor_spec
+                held = self._still_held(handle)
                 handle.actor_spec = None
                 handle.state = STARTING
-                if not handle.blocked:  # blocked already released
-                    self._release_actor_resources(spec)
-                handle.blocked = False
+                # The process goes first: its chips and resources are
+                # free only once nothing holds the device.
                 self._terminate_worker(handle)
+                self._chips.release(handle.worker_id)
+                self._release_actor_resources(spec, held)
+                handle.blocked = False
                 return True
         return False
 
-    def _release_actor_resources(self, spec: ActorSpec):
+    @staticmethod
+    def _still_held(handle: WorkerHandle) -> dict:
+        """What a dying actor worker still holds: everything — or, parked
+        in get() (which gave the rest back already), its chips."""
+        resources = handle.actor_spec.resources
+        if not handle.blocked:
+            return resources
+        return {k: v for k, v in resources.items() if k == "TPU"}
+
+    def _release_actor_resources(self, spec: ActorSpec,
+                                 demand: dict | None = None):
+        """Give ``demand`` (default: all of ``spec.resources``) back to
+        the pool it came from — the actor's bundle or the node."""
+        if demand is None:
+            demand = spec.resources
         if spec.placement_group_id is not None:
             self._bundle_release(
                 (spec.placement_group_id,
-                 spec.placement_group_bundle_index), spec.resources)
+                 spec.placement_group_bundle_index), demand)
         else:
-            self._release(spec.resources)
+            self._release(demand)
 
     # ------------------------------------------------------------ objects
 
@@ -2742,6 +2795,10 @@ def main():  # pragma: no cover — exercised via subprocess in tests
     parser.add_argument("--resources", default="{}")
     parser.add_argument("--session-dir", required=True)
     parser.add_argument("--labels", default="{}")
+    parser.add_argument("--chip-platform", choices=("tpu", "cpu"),
+                        default=None,
+                        help="platform of workers that lease TPU "
+                             "(jax_utils.chip_platform of the launcher)")
     parser.add_argument("--monitor-pid", type=int, default=0,
                         help="exit when this process disappears")
     args = parser.parse_args()
@@ -2755,6 +2812,7 @@ def main():  # pragma: no cover — exercised via subprocess in tests
         session_dir=args.session_dir,
         port=args.port,
         labels=json.loads(args.labels),
+        chip_platform=args.chip_platform,
     )
     manager.start()
     print(f"NODED_READY {manager.address}", flush=True)

@@ -320,8 +320,15 @@ def serialize(value: Any) -> SerializedObject:
 
 
 def _rebuild_jax_array(host):
-    jax = _maybe_jax()
-    if jax is None:  # pragma: no cover
+    """Reader side of a pickled ``jax.Array``.  Reading a value never
+    opens a backend: a process whose jax backend is already up gets a
+    ``jax.Array`` on it (an owner on its chip, a non-owner on the CPU
+    backend it is pinned to); a process that has not touched jax — the
+    driver, the Train controller, a daemon — gets the host ``numpy``
+    array back."""
+    from ant_ray_tpu._private.jax_utils import opened_platforms  # noqa: PLC0415
+
+    if not opened_platforms():
         return host
     import jax.numpy as jnp  # noqa: PLC0415
 

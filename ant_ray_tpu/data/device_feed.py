@@ -238,10 +238,7 @@ class DeviceFeed:
                 return
             dev = self._to_device(tree, sharding)
             if self._jax is not None:
-                try:
-                    self._jax.block_until_ready(dev)
-                except Exception:  # noqa: BLE001 — older jax: tree-less
-                    pass
+                self._jax.block_until_ready(dev)
             self.stats["consumer_starve_s"] += time.perf_counter() - t0
             self.stats["batches"] += 1
             yield dev

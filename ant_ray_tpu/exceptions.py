@@ -134,6 +134,16 @@ class RuntimeEnvSetupError(ArtError):
     pass
 
 
+class TpuLeaseError(ArtError):
+    """A request for ``TPU`` that cannot be given an owner process.
+
+    A chip belongs to the ONE process that leased it.  Raised for a
+    plain task that asks for ``TPU`` (pooled workers hold no chip and
+    never open the TPU backend — lease chips with an actor), for a
+    fractional or odd-shaped chip count, and when a host's chips are
+    all held by live workers."""
+
+
 class NodeDiedError(ArtError):
     pass
 

@@ -20,6 +20,7 @@ sessions to a replica via handle affinity or num_replicas=1.
 
 from __future__ import annotations
 
+import os
 import time
 
 from ant_ray_tpu.llm.engine import EngineLoop, LLMEngine
@@ -37,8 +38,13 @@ class LLMServer:
                  decode_steps_per_chunk: int = 1,
                  kv_idle_evict_s: float | None = None,
                  kv_offload="auto"):
+        from ant_ray_tpu._private.jax_utils import require_tpu  # noqa: PLC0415
         from ant_ray_tpu.llm.tokenizer import get_tokenizer  # noqa: PLC0415
 
+        # No fallback on the chip path: unless this process is pinned
+        # to the CPU backend (it leased no chip, or the whole tree is
+        # pinned from outside), the engine runs on a TPU or not at all.
+        self._device = require_tpu("LLM replica")
         store = self._resolve_store(kv_offload)
         self.engine = LLMEngine(
             model, slots=slots, max_seq=max_seq,
@@ -289,6 +295,14 @@ class LLMServer:
         art_llm_queue_depth, art_llm_resident_sessions."""
         return self._loop.stats()
 
+    def device_info(self) -> dict:
+        """The device this replica's engine runs on, as jax reports it
+        in THIS process."""
+        return {"platform": self._device.platform,
+                "kind": self._device.device_kind,
+                "count": len(self.engine._jax.devices()),
+                "pid": os.getpid()}
+
     def health(self):
         return "ok"
 
@@ -328,12 +342,28 @@ def build_llm_deployment(model="tiny", *, name: str = "llm",
     idle-session offload through ``kv_offload`` ("auto" picks the
     object plane inside a cluster).  ``autoscaling_config`` may target
     the engine's published load signals (see
-    `AutoscalingConfig.target_signal`)."""
+    `AutoscalingConfig.target_signal`).
+
+    Each replica leases ``TPU = tensor_parallel_size`` chips, so the
+    cluster must advertise them (``init(num_tpus=)`` simulates them
+    under the tests' whole-tree CPU pin)."""
     from ant_ray_tpu import serve  # noqa: PLC0415
 
+    # One chip per tensor-parallel shard: the lease makes the replica's
+    # process the owner of those chips.
+    actor_options = {"num_tpus": tensor_parallel_size}
+    if max_ongoing_requests is None:
+        # Requests share engine steps, so they must overlap in the
+        # replica: one thread per KV slot, and headroom so the
+        # controller's ongoing()/health() polls never queue behind a
+        # generation (a first request compiles for a minute at 1B; three
+        # missed polls would eject the replica).  With a request gate
+        # the controller sizes the pool from the gate instead.
+        actor_options["max_concurrency"] = slots + 8
     dep = serve.deployment(
         LLMServer, name=name, num_replicas=num_replicas,
         route_prefix=route_prefix,
+        ray_actor_options=actor_options,
         max_ongoing_requests=max_ongoing_requests,
         max_queued_requests=max_queued_requests,
         request_timeout_s=request_timeout_s,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ant_ray_tpu.ops.attention import attention
+from ant_ray_tpu.ops.attention import attention, kernel_fits
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
 from ant_ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ant_ray_tpu.parallel.sharding import logical_to_spec
@@ -308,7 +308,15 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
             from ant_ray_tpu.parallel.ring import ring_attention  # noqa: PLC0415
 
             return ring_attention(xq, xk, xv, mesh=mesh, causal=True)
-        return attention(xq, xk, xv, causal=True, impl=attn_impl)
+        if mesh is None:
+            return attention(xq, xk, xv, causal=True, impl=attn_impl)
+        rules = llama_rules()
+        return attention(
+            xq, xk, xv, causal=True, impl=attn_impl, mesh=mesh,
+            q_spec=logical_to_spec(
+                ("batch", "seq", "heads", "head_dim"), rules),
+            kv_spec=logical_to_spec(
+                ("batch", "seq", "kv_heads", "head_dim"), rules))
 
     def block(x, layer):
         return apply_block(layer, x, c, cos, sin, positions, attend,
@@ -473,8 +481,14 @@ def prefill_into_cache(params: dict, tokens, cache: dict, slot,
     only — the padded tail writes garbage K/V that decode masks (and
     later overwrites)."""
     last_pos = jnp.maximum(length - 1, 0)
-    logits, ks, vs = forward(params, tokens, config, mesh=mesh,
-                             return_kv=True, logits_at=last_pos)
+    # Prompt buckets start at 16 tokens: below the flash kernel's tile
+    # the blockwise path is named, as the dispatcher demands on a TPU.
+    qkv_shape = (1, tokens.shape[1], config.n_heads, config.head_dim)
+    logits, ks, vs = forward(
+        params, tokens, config, mesh=mesh, return_kv=True,
+        logits_at=last_pos,
+        attn_impl="auto" if kernel_fits(qkv_shape, qkv_shape)
+        else "blockwise")
     cache = dict(cache)
     slot = jnp.asarray(slot, jnp.int32)
     cache["k"] = lax.dynamic_update_slice(

@@ -126,10 +126,10 @@ class StepProfiler:
     profiler cannot see into (a custom data fetch, a manual
     ``all_reduce``).
 
-    ``flops_per_step`` enables MFU: achieved flops / the detected TPU
-    peak (``_private/accelerators/tpu.py`` hardware table × bound
-    chips), or an explicit ``peak_flops`` override (required for a
-    meaningful MFU off-TPU).
+    ``flops_per_step`` enables MFU: achieved flops / the peak of the
+    chips this process owns (their ``device_kind`` keyed into the
+    ``_private/accelerators/tpu.py`` hardware table), or an explicit
+    ``peak_flops`` override (required for an MFU off-TPU).
     """
 
     __slots__ = ("_flops_per_step", "_peak_flops", "records", "_publish",
@@ -143,8 +143,11 @@ class StepProfiler:
         from collections import deque  # noqa: PLC0415
 
         self._flops_per_step = flops_per_step
-        self._peak_flops = (peak_flops if peak_flops is not None
-                            else self._detect_peak_flops())
+        # Only a profiler that computes MFU asks the device what it is
+        # (that opens this process's backend — the loop's own).
+        if peak_flops is None and flops_per_step:
+            peak_flops = self._detect_peak_flops()
+        self._peak_flops = peak_flops
         # raw (step, wall_ts, total_s, phases) tuples — materialized
         # into StepRecords only on read, keeping the step path cheap
         self.records: Any = deque(maxlen=max(1, history))
@@ -207,13 +210,18 @@ class StepProfiler:
 
     @staticmethod
     def _detect_peak_flops() -> float | None:
+        """bf16 peak of the chips this process owns, read from the
+        device: its ``device_kind`` keyed into the hardware table (an
+        unknown kind raises).  Off-TPU there is no peak: MFU needs
+        ``peak_flops=``."""
         from ant_ray_tpu._private.accelerators import tpu as tpu_accel  # noqa: PLC0415
+        from ant_ray_tpu._private.jax_utils import import_jax  # noqa: PLC0415
 
-        gen = tpu_accel.detect_generation()
-        if gen is None:
-            return None             # off-TPU: MFU needs peak_flops=
-        chips = max(1, tpu_accel.num_tpu_chips())
-        return tpu_accel.peak_bf16_tflops(gen) * 1e12 * chips
+        devices = import_jax().local_devices()
+        if devices[0].platform != "tpu":
+            return None
+        gen = tpu_accel.device_generation(devices[0])
+        return tpu_accel.peak_bf16_tflops(gen) * 1e12 * len(devices)
 
     # -------------------------------------------------------- step path
 

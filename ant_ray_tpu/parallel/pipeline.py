@@ -17,12 +17,6 @@ import functools
 from ant_ray_tpu._private.jax_utils import import_jax
 
 
-def _shard_map():
-    from ant_ray_tpu._private.jax_utils import shard_map  # noqa: PLC0415
-
-    return shard_map()
-
-
 def gpipe_kernel(stage_fn, stage_params, microbatches, *, axis_name: str,
                  axis_size: int):
     """Per-device GPipe (call inside shard_map).
@@ -88,6 +82,7 @@ def gpipe(stage_fn, stacked_params, microbatches, *, mesh,
         ``batch_axes``, replicated over pp.
     """
     jax = import_jax()
+    from jax import shard_map  # noqa: PLC0415
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
     axis_size = mesh.shape[axis_name]
@@ -95,13 +90,8 @@ def gpipe(stage_fn, stacked_params, microbatches, *, mesh,
     x_spec = P(None, batch_axes)
     kernel = functools.partial(gpipe_kernel, stage_fn,
                                axis_name=axis_name, axis_size=axis_size)
-    shard_map = _shard_map()
     # The final all_gather+take replicates the output over pp, but the
     # varying-axes checker can't infer that statically — disable it.
-    try:
-        fn = shard_map(kernel, mesh=mesh, in_specs=(param_spec, x_spec),
-                       out_specs=x_spec, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        fn = shard_map(kernel, mesh=mesh, in_specs=(param_spec, x_spec),
-                       out_specs=x_spec, check_rep=False)
+    fn = shard_map(kernel, mesh=mesh, in_specs=(param_spec, x_spec),
+                   out_specs=x_spec, check_vma=False)
     return jax.jit(fn)(stacked_params, microbatches)

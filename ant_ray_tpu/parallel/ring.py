@@ -23,12 +23,6 @@ import functools
 from ant_ray_tpu._private.jax_utils import import_jax
 
 
-def _shard_map():
-    from ant_ray_tpu._private.jax_utils import shard_map  # noqa: PLC0415
-
-    return shard_map()
-
-
 def ring_attention_kernel(q, k, v, *, axis_name: str, axis_size: int,
                           causal: bool = True, scale: float | None = None):
     """Exact ring attention for one device's shard.
@@ -117,6 +111,7 @@ def ring_attention(q, k, v, *, mesh, axis_name: str = "sp",
     ``head_axis``.
     """
     jax = import_jax()
+    from jax import shard_map  # noqa: PLC0415
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
     axis_size = mesh.shape[axis_name]
@@ -124,8 +119,8 @@ def ring_attention(q, k, v, *, mesh, axis_name: str = "sp",
     kernel = functools.partial(
         ring_attention_kernel, axis_name=axis_name, axis_size=axis_size,
         causal=causal, scale=scale)
-    fn = _shard_map()(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)
+    fn = shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec)
     return jax.jit(fn)(q, k, v)
 
 

@@ -18,12 +18,6 @@ from ant_ray_tpu._private.jax_utils import import_jax
 from ant_ray_tpu.parallel.ring import reference_attention
 
 
-def _shard_map():
-    from ant_ray_tpu._private.jax_utils import shard_map  # noqa: PLC0415
-
-    return shard_map()
-
-
 def ulysses_attention_kernel(q, k, v, *, axis_name: str, axis_size: int,
                              causal: bool = True,
                              scale: float | None = None,
@@ -70,6 +64,7 @@ def ulysses_attention(q, k, v, *, mesh, axis_name: str = "sp",
     """Standalone sharded Ulysses attention over global arrays (heads are
     NOT tp-sharded here: the sp axis claims the head dimension)."""
     jax = import_jax()
+    from jax import shard_map  # noqa: PLC0415
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
     axis_size = mesh.shape[axis_name]
@@ -77,6 +72,6 @@ def ulysses_attention(q, k, v, *, mesh, axis_name: str = "sp",
     kernel = functools.partial(
         ulysses_attention_kernel, axis_name=axis_name, axis_size=axis_size,
         causal=causal, scale=scale)
-    fn = _shard_map()(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)
+    fn = shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec)
     return jax.jit(fn)(q, k, v)

@@ -134,7 +134,7 @@ def _record_result(routing, replica, exc: BaseException | None = None):
     loss) counts as a failure — per-replica corruption usually
     manifests as handler errors, and the ejection CAP
     (``max_eject_fraction``) is what protects a healthy fleet from a
-    deterministic bad-input stream, not the error taxonomy."""
+    deterministic bad-input stream, not the error classes."""
     if exc is not None and _typed_cause(exc) is not None:
         return
     routing.record_outcome(replica, exc is None)
@@ -1518,10 +1518,16 @@ class ServeController:
             # behind queued work (+8 headroom for both).
             default_conc = (deployment.max_ongoing_requests
                             + max(deployment.max_queued_requests, 0) + 8)
+        # The replica leases what ``ray_actor_options`` asks for: a
+        # replica that leased TPU is the process that owns those chips
+        # (_private/jax_utils.py); one that leased none is pinned to
+        # the CPU backend.
+        opts = deployment.ray_actor_options
         replica_cls = art.remote(Replica).options(
-            **{"num_cpus": deployment.ray_actor_options.get("num_cpus", 0),
-               "max_concurrency": deployment.ray_actor_options.get(
-                   "max_concurrency", default_conc)})
+            num_cpus=opts.get("num_cpus", 0),
+            num_tpus=opts.get("num_tpus"),
+            resources=opts.get("resources"),
+            max_concurrency=opts.get("max_concurrency", default_conc))
         limits = {"deployment": deployment.name,
                   "max_ongoing_requests": deployment.max_ongoing_requests,
                   "max_queued_requests": deployment.max_queued_requests}

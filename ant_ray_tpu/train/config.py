@@ -33,16 +33,43 @@ class ScalingConfig:
     use_tpu: bool = False
     topology: str = ""                  # e.g. "4x8" (whole-slice reservation)
     accelerator_type: str = "TPU-V5E"   # generation for slice math
-    chips_per_worker: int = 0           # TPU chips each worker binds (0=all)
+    # TPU chips each worker leases; 0 = all of its host's.
+    chips_per_worker: int = 0
     resources_per_worker: dict = dataclasses.field(default_factory=dict)
     placement_strategy: str = "PACK"
 
     def worker_resources(self) -> dict:
+        """What each rank actor leases.  A ``use_tpu`` worker ALWAYS
+        leases ``TPU``: the lease is what makes its process the owner
+        of the chips (``_private/jax_utils.py``) — a worker that leased
+        none is pinned to the CPU backend."""
         res = dict(self.resources_per_worker)
-        if self.use_tpu and self.chips_per_worker:
-            res["TPU"] = float(self.chips_per_worker)
+        if self.use_tpu:
+            res["TPU"] = float(self.chips_per_worker
+                               or self._host_chips())
         res.setdefault("CPU", 1.0)
         return res
+
+    def _host_chips(self) -> int:
+        """Chips of one TPU host: from the slice topology where one is
+        named, else the most ``TPU`` any alive node advertises in the
+        cluster's resource view (one worker per TPU host)."""
+        if self.topology:
+            from ant_ray_tpu._private.accelerators import tpu  # noqa: PLC0415
+
+            return tpu.chips_per_host(self.topology,
+                                      self.accelerator_type)
+        import ant_ray_tpu as art  # noqa: PLC0415
+
+        chips = max((int(n["Resources"].get("TPU", 0))
+                     for n in art.nodes() if n["Alive"]), default=0)
+        if not chips:
+            raise ValueError(
+                "ScalingConfig(use_tpu=True): no alive node of the "
+                "cluster advertises TPU, so there is no chip to lease "
+                "(a host's chips come from its /dev nodes, "
+                "TPU_VISIBLE_CHIPS or init(num_tpus=))")
+        return chips
 
 
 @dataclasses.dataclass

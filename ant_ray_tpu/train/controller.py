@@ -72,22 +72,22 @@ class TrainWorker:
 
     def setup_distributed(self, coordinator: str | None) -> bool:
         """jax.distributed rendezvous for multi-host slices (ref:
-        train/v2/jax/config.py:30,73).  Degrades gracefully where the
-        coordination service is unavailable (single-host)."""
+        train/v2/jax/config.py:30,73).  A gang that cannot rendezvous
+        fails here: ranks that went on single-process would each train
+        alone and report success."""
         if not self._use_tpu or self._world_size == 1 or coordinator is None:
             return False
-        try:
-            from ant_ray_tpu._private.jax_utils import import_jax  # noqa: PLC0415
+        from ant_ray_tpu._private.jax_utils import import_jax  # noqa: PLC0415
 
-            jax = import_jax()
-            jax.distributed.initialize(
-                coordinator, num_processes=self._world_size,
-                process_id=self._rank)
-            return jax.process_count() == self._world_size
-        except Exception as e:  # noqa: BLE001
-            logger.warning("jax.distributed init failed (%s); continuing "
-                           "single-process", e)
-            return False
+        jax = import_jax()
+        jax.distributed.initialize(
+            coordinator, num_processes=self._world_size,
+            process_id=self._rank)
+        if jax.process_count() != self._world_size:
+            raise RuntimeError(
+                f"jax.distributed joined {jax.process_count()} processes, "
+                f"the gang has {self._world_size}")
+        return True
 
     def run(self, loop_fn, loop_config, controller, latest_checkpoint,
             attempt: int = 0, dataset_shards: dict | None = None):
@@ -117,6 +117,12 @@ class TrainWorker:
         )
         _set_context(ctx)
         try:
+            if self._use_tpu:
+                # No fallback on the chip path: this rank leased chips,
+                # so it runs on them or not at all.
+                from ant_ray_tpu._private.jax_utils import require_tpu  # noqa: PLC0415
+
+                require_tpu(f"Train worker rank {self._rank}")
             if loop_config is None:
                 return loop_fn()
             return loop_fn(loop_config)

@@ -42,12 +42,6 @@ def _jax():
     return import_jax()
 
 
-def _shard_map():
-    from ant_ray_tpu._private.jax_utils import shard_map  # noqa: PLC0415
-
-    return shard_map()
-
-
 class XLAGroup(BaseGroup):
     def __init__(self, world_size: int, rank: int, group_name: str,
                  devices=None):
@@ -85,6 +79,7 @@ class XLAGroup(BaseGroup):
     def _compiled(self, verb: str, shape: tuple, dtype: str, n_dev: int,
                   extra):
         jax = _jax()
+        from jax import shard_map  # noqa: PLC0415
         from jax.sharding import Mesh, NamedSharding  # noqa: PLC0415
         from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
@@ -140,7 +135,7 @@ class XLAGroup(BaseGroup):
                     red, index * tile, tile, axis=0)
             raise ValueError(verb)
 
-        fn = _shard_map()(op, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+        fn = shard_map(op, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
         return jax.jit(fn), mesh, NamedSharding(mesh, P(axis))
 
     def _compile_q8(self, verb: str, shape: tuple, n_dev: int, extra,
@@ -154,6 +149,7 @@ class XLAGroup(BaseGroup):
         verbs."""
         jax = _jax()
         import jax.numpy as jnp  # noqa: PLC0415
+        from jax import shard_map  # noqa: PLC0415
         from jax.sharding import Mesh, NamedSharding  # noqa: PLC0415
         from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
@@ -173,7 +169,7 @@ class XLAGroup(BaseGroup):
                 out = out / n_dev
             return out[None]
 
-        fn = _shard_map()(op, mesh=mesh, in_specs=(P(axis), P(axis)),
+        fn = shard_map(op, mesh=mesh, in_specs=(P(axis), P(axis)),
                           out_specs=P(axis))
         return jax.jit(fn), mesh, NamedSharding(mesh, P(axis))
 
@@ -189,6 +185,7 @@ class XLAGroup(BaseGroup):
         contiguous layout matching device order)."""
         jax = _jax()
         import jax.numpy as jnp  # noqa: PLC0415
+        from jax import shard_map  # noqa: PLC0415
         from jax.sharding import Mesh, NamedSharding  # noqa: PLC0415
         from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
@@ -219,7 +216,7 @@ class XLAGroup(BaseGroup):
             return y[None, None]
 
         spec = P("slice", "intra")
-        fn = _shard_map()(op, mesh=mesh, in_specs=spec, out_specs=spec)
+        fn = shard_map(op, mesh=mesh, in_specs=spec, out_specs=spec)
         return jax.jit(fn), mesh, NamedSharding(mesh, spec)
 
     # ------------------------------------------------------------ runners

@@ -19,7 +19,6 @@ import os
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("ART_JAX_PLATFORM", "cpu")
 
 import numpy as np  # noqa: E402
 

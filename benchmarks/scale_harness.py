@@ -37,7 +37,6 @@ import sys
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("ART_JAX_PLATFORM", "cpu")
 # The observatory measures the control plane, not the data plane: no
 # dashboard, no node agents even if a config on this host enables them.
 os.environ.setdefault("ART_INCLUDE_DASHBOARD", "0")

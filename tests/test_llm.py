@@ -99,7 +99,7 @@ def test_prompt_longer_than_bucket(params):
 
 @pytest.mark.slow
 def test_serve_llm_deployment(shutdown_only):
-    art.init(num_cpus=2)
+    art.init(num_cpus=2, num_tpus=1)   # the replica leases a chip
     from ant_ray_tpu import serve
     from ant_ray_tpu.llm.serve_llm import build_llm_deployment
 
@@ -137,7 +137,7 @@ def test_llm_sse_token_streaming(shutdown_only):
     import json
     import urllib.request
 
-    art.init(num_cpus=2)
+    art.init(num_cpus=2, num_tpus=1)   # the replica leases a chip
     from ant_ray_tpu import serve
     from ant_ray_tpu.llm.serve_llm import build_llm_deployment
 

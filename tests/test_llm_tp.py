@@ -62,7 +62,7 @@ def test_render_chat_generic_template():
 
 @pytest.mark.slow
 def test_chat_completions_http_e2e(shutdown_only):
-    art.init(num_cpus=2)
+    art.init(num_cpus=2, num_tpus=1)   # the replica leases a chip
     from ant_ray_tpu import serve
     from ant_ray_tpu.llm.serve_llm import build_llm_deployment
 
@@ -95,7 +95,7 @@ def test_chat_completions_http_e2e(shutdown_only):
 
 @pytest.mark.slow
 def test_chat_sse_streaming(shutdown_only):
-    art.init(num_cpus=2)
+    art.init(num_cpus=2, num_tpus=1)   # the replica leases a chip
     from ant_ray_tpu import serve
     from ant_ray_tpu.llm.serve_llm import build_llm_deployment
 
