@@ -22,6 +22,7 @@ persistent compile cache.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 
@@ -87,6 +88,18 @@ def import_jax():
                               default_cache_dir())
         _configured = True
     return jax
+
+
+def trace_annotation(name: str):
+    """A host event ``name`` in the jax profiler's trace, on the device
+    events' clock, for code that does not otherwise need jax.  Only a
+    process that imported jax can be taking such a trace, so elsewhere
+    this is a null context; with no trace running it costs a flag
+    test."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def require_tpu(who: str):

@@ -36,6 +36,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from ant_ray_tpu._private.jax_utils import trace_annotation
+
 _END = ("end", None)
 
 
@@ -232,13 +234,14 @@ class DeviceFeed:
         gen = self._host_batches()
         while True:
             t0 = time.perf_counter()
-            try:
-                tree = next(gen)
-            except StopIteration:
-                return
-            dev = self._to_device(tree, sharding)
-            if self._jax is not None:
-                self._jax.block_until_ready(dev)
+            with trace_annotation("feed:wait"):
+                try:
+                    tree = next(gen)
+                except StopIteration:
+                    return
+                dev = self._to_device(tree, sharding)
+                if self._jax is not None:
+                    self._jax.block_until_ready(dev)
             self.stats["consumer_starve_s"] += time.perf_counter() - t0
             self.stats["batches"] += 1
             yield dev
@@ -252,8 +255,11 @@ class DeviceFeed:
         self.thread.start()
         try:
             while True:
+                # `feed:wait` in a profiler trace is the interval that
+                # feeds consumer_starve_s.
                 t0 = time.perf_counter()
-                kind, payload = q.get()
+                with trace_annotation("feed:wait"):
+                    kind, payload = q.get()
                 self.stats["consumer_starve_s"] += time.perf_counter() - t0
                 if kind == "end":
                     return

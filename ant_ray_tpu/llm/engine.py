@@ -74,7 +74,12 @@ class _Seq:
     kv_len: int = 0               # slab tokens written for this slot
     last_tok: int | None = None   # device-fed token (resume after restore)
     on_event: Any = None          # callable(dict) | None — streaming sink
-    trace_ctx: Any = None         # TraceContext for llm:restore spans
+    trace_ctx: Any = None         # TraceContext for llm:* spans
+    # Marks for the llm:engine stage span (perf_counter, engine step):
+    submitted: tuple = ()         # (wall, perf_counter, step) at submit
+    first_chunk: tuple | None = None   # first prefill dispatch
+    first_token: tuple | None = None   # first token handed to on_event
+    chunks: int = 0               # prefill dispatches (chunks, or 1)
 
 
 @dataclass(eq=False)
@@ -100,6 +105,97 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
+PHASES = ("drain", "admit", "chunk", "decode", "sample", "fetch", "emit",
+          "housekeeping", "idle_wait")
+
+
+class _PhaseRecorder:
+    """The engine loop's account of its own time; always on.
+
+    One phase is open at a time — ``enter(name)`` closes the one before
+    it — so the phases tile an iteration with no holes.  A phase's wall
+    seconds go to ``stats["phase_<name>_s"]``, and the phase is a
+    ``jax.profiler.TraceAnnotation("engine:<name>", step=n)`` under one
+    ``StepTraceAnnotation("engine", step_num=n)`` per iteration: host
+    events of the profiler's own trace, so on the device events' clock;
+    with no trace running they cost a flag test.  ``n`` is
+    ``stats["steps"]``, the iterations that dispatched a program so far.
+
+    ``to_host`` is the one place the engine blocks on a device value:
+    it counts the read (``d2h_syncs``) and adds its wall time to
+    ``block_s`` and to the open phase's ``block_<name>_s``, so host time
+    proper is ``sum(phase_*_s) - block_s - phase_idle_wait_s``.
+    """
+
+    def __init__(self, jax, stats: dict):
+        self._stats = stats
+        self._annotation = jax.profiler.TraceAnnotation
+        self._step_annotation = jax.profiler.StepTraceAnnotation
+        self._keys = {p: (f"engine:{p}", f"phase_{p}_s", f"block_{p}_s")
+                      for p in PHASES}
+        # Declared here, once: dict(stats) on another thread never sees
+        # the dict change size.
+        for key in ("steps", "decode_steps", "decode_slots", "d2h_syncs"):
+            stats[key] = 0
+        stats["block_s"] = 0.0
+        for _, phase_key, block_key in self._keys.values():
+            stats[phase_key] = stats[block_key] = 0.0
+        self._phase = self._span = self._step_span = None
+        self._t = 0.0
+        self.dispatched = False
+
+    def begin(self) -> bool:
+        """Open an iteration; False when one is open already (the
+        EngineLoop opened it around ``LLMEngine.step``)."""
+        if self._step_span is not None:
+            return False
+        self._close_phase(time.perf_counter())   # an open idle_wait
+        self.dispatched = False
+        self._step_span = self._step_annotation(
+            "engine", step_num=self._stats["steps"])
+        self._step_span.__enter__()
+        return True
+
+    def end(self) -> None:
+        self._close_phase(time.perf_counter())
+        self._step_span.__exit__(None, None, None)
+        self._step_span = None
+        if self.dispatched:
+            self._stats["steps"] += 1
+
+    def enter(self, name: str) -> None:
+        now = time.perf_counter()
+        if name == self._phase:
+            # The stretch goes on (the loop's idle wait woke with no
+            # work): one event, its seconds kept current.
+            self._stats[self._keys[name][1]] += now - self._t
+            self._t = now
+            return
+        self._close_phase(now)
+        self._phase, self._t = name, now
+        self._span = self._annotation(self._keys[name][0],
+                                      step=self._stats["steps"])
+        self._span.__enter__()
+
+    def _close_phase(self, now: float) -> None:
+        if self._phase is not None:
+            self._stats[self._keys[self._phase][1]] += now - self._t
+            self._span.__exit__(None, None, None)
+            self._phase = None
+
+    def to_host(self, value):
+        """Every blocking device→host read of the engine, counted."""
+        t0 = time.perf_counter()
+        out = np.asarray(value)
+        dt = time.perf_counter() - t0
+        stats = self._stats
+        stats["d2h_syncs"] += 1
+        stats["block_s"] += dt
+        if self._phase is not None:
+            stats[self._keys[self._phase][2]] += dt
+        return out
+
+
 class LLMEngine:
     """Synchronous engine core; Serve replicas and batch stages drive it.
 
@@ -117,8 +213,7 @@ class LLMEngine:
                  decode_steps_per_chunk: int = 1,
                  kv_idle_evict_s: float | None = None,
                  kv_offload_store=None,
-                 kv_evict_on_pressure: bool = True,
-                 profiler=None):
+                 kv_evict_on_pressure: bool = True):
         """``tensor_parallel_size > 1`` makes the ENGINE build a tp mesh
         over this process's local devices and shard params + KV slabs
         itself (ref: vllm_models.py:222 tensor_parallel_size — serving
@@ -136,8 +231,7 @@ class LLMEngine:
         governed separately by ``kv_evict_on_pressure``).
         ``kv_offload_store``: a kv_offload.py store (LocalKvStore /
         ObjectPlaneKvStore); defaults to a LocalKvStore built lazily on
-        first eviction.  ``profiler``: optional StepProfiler — each
-        step() records prefill/decode/restore_install phases.
+        first eviction.
         """
         from ant_ray_tpu._private.jax_utils import import_jax
 
@@ -216,12 +310,14 @@ class LLMEngine:
         self._restoring: dict[str, dict] = {}     # sid -> ticket
         self._chunk_rate: float | None = None     # tokens/s EWMA
         self._last_chunk_t: float | None = None
-        self.profiler = profiler
+        # Flat on purpose: readers on other threads take dict(stats),
+        # a shallow copy under which a nested dict would alias.
         self.stats = {"tokens_generated": 0, "chunks": 0,
                       "chunk_tokens": 0, "offloads": 0,
                       "offload_bytes": 0, "restores": 0,
                       "restore_wait_s": 0.0, "restore_failures": 0,
                       "pressure_evictions": 0, "idle_evictions": 0}
+        self._rec = _PhaseRecorder(jax, self.stats)
 
         cfg = self.config
         eng_mesh = self.mesh
@@ -301,7 +397,8 @@ class LLMEngine:
     def add_request(self, prompt, sampling: SamplingParams | None = None,
                     request_id: str | None = None, *,
                     admit: bool = True, session_id: str | None = None,
-                    on_event=None, trace_ctx=None) -> str:
+                    on_event=None, trace_ctx=None,
+                    submitted: tuple | None = None) -> str:
         """prompt: str (tokenized here) or token-id list.
 
         With ``max_waiting`` configured and ``admit=True`` (the serving
@@ -321,7 +418,10 @@ class LLMEngine:
         KV slab survives the request (multi-turn reuse; continuations
         require chunked mode) and may be offloaded/restored.
         ``on_event`` streams per-token dicts to the caller (EngineLoop's
-        sink); ``trace_ctx`` attributes `llm:restore` spans."""
+        sink); ``trace_ctx`` attributes the `llm:engine` and
+        `llm:restore` spans; ``submitted`` is EngineLoop.submit's
+        (wall, perf_counter, engine step), where the request's queue
+        stage starts (default: now)."""
         if (admit and self._max_waiting is not None
                 and not self._free_slots
                 and len(self._waiting) >= self._max_waiting
@@ -347,6 +447,8 @@ class LLMEngine:
         seq = _Seq(rid, token_ids, sampling)
         seq.on_event = on_event
         seq.trace_ctx = trace_ctx
+        seq.submitted = submitted or (time.time(), time.perf_counter(),
+                                      self.stats["steps"])
         if session_id is not None:
             sess = self._sessions.get(session_id)
             if sess is None or sess.state == "failed":
@@ -381,21 +483,26 @@ class LLMEngine:
         run one prefill unit (bucketed prompt or one chunk), decode all
         active slots, sweep idle sessions.  Returns outputs finished
         since the last call."""
-        prof = self.profiler
-        if prof is not None:
-            with prof.step():
-                self._step_inner(prof)
-        else:
-            self._step_inner(None)
+        rec = self._rec
+        mine = rec.begin()       # False under an EngineLoop iteration
+        try:
+            self._step_inner()
+        finally:
+            if mine:
+                rec.end()
         done, self._finished = self._finished, []
         return done
 
-    def _step_inner(self, prof):
-        self._poll_restores(prof)
-        self._admit(prof)
+    def _step_inner(self):
+        rec = self._rec
+        rec.enter("admit")
+        self._poll_restores()
+        self._admit()
         if self._chunk_tokens is not None:
-            self._maybe_prefill_chunk(prof)
-        self._decode(prof)
+            rec.enter("chunk")
+            self._maybe_prefill_chunk()
+        self._decode()
+        rec.enter("housekeeping")
         self._sweep_idle()
 
     def generate(self, prompts, sampling: SamplingParams | None = None,
@@ -520,7 +627,7 @@ class LLMEngine:
 
     # ---------------------------------------------------- step phases
 
-    def _admit(self, prof=None):
+    def _admit(self):
         """Route waiting requests: park session continuations behind
         restores, assign free (or pressure-evicted) slots, and in
         legacy mode run at most one full bucketed prefill per step —
@@ -552,7 +659,7 @@ class LLMEngine:
                 if self._chunk_tokens is None and admitted_prefill:
                     break                     # legacy: ≤1 prefill/step
                 self._waiting.pop(i)          # resident idle: append
-                self._begin_ingest(seq, sess.slot, sess.kv_len, prof)
+                self._begin_ingest(seq, sess.slot, sess.kv_len)
                 admitted_prefill = True
                 continue
             if not self._free_slots and not self._evict_for_pressure():
@@ -565,11 +672,10 @@ class LLMEngine:
             if sess is not None:
                 sess.slot = slot
                 sess.state = "resident"
-            self._begin_ingest(seq, slot, sess.kv_len if sess else 0,
-                               prof)
+            self._begin_ingest(seq, slot, sess.kv_len if sess else 0)
             admitted_prefill = True
 
-    def _begin_ingest(self, seq: _Seq, slot: int, start: int, prof=None):
+    def _begin_ingest(self, seq: _Seq, slot: int, start: int):
         jnp = self._jnp
         sess = seq.session
         if self._chunk_tokens is None and start != 0:
@@ -592,23 +698,26 @@ class LLMEngine:
         if self._chunk_tokens is not None:
             self._prefilling.append(seq)
             return
+        rec = self._rec
+        rec.enter("chunk")            # the bucketed prefill, whole
         bucket = _bucket(len(seq.prompt), self.max_seq)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(seq.prompt)] = seq.prompt
-        timer = prof.phase("prefill") if prof is not None else _NOOP_TIMER
-        with timer:
-            last_logits, self.cache = self._prefill_jit(
-                self.params, self.cache, jnp.asarray(padded), slot,
-                len(seq.prompt))
+        last_logits, self.cache = self._prefill_jit(
+            self.params, self.cache, jnp.asarray(padded), slot,
+            len(seq.prompt))
+        self._note_dispatch(seq)
         seq.kv_len = len(seq.prompt)
-        tok = int(self._sample_one(seq, last_logits))
+        tok = int(rec.to_host(self._sample_one(seq, last_logits)))
+        rec.enter("emit")
         self._after_token(seq, tok)
         if seq.slot >= 0:
             seq.last_tok = tok
             self._last_np[slot] = tok
             self._active[slot] = seq
+        rec.enter("admit")            # back to the caller's phase
 
-    def _maybe_prefill_chunk(self, prof=None):
+    def _maybe_prefill_chunk(self):
         """Run ONE chunk of ONE pending prompt — but only once
         ``decode_steps_per_chunk`` decode steps have run since the last
         chunk (decode for resident sessions stays smooth while a long
@@ -635,11 +744,10 @@ class LLMEngine:
         part = seq.prompt[seq.prefill_done:seq.prefill_done + chunk]
         buf = np.zeros((chunk,), np.int32)
         buf[:len(part)] = part
-        timer = prof.phase("prefill") if prof is not None else _NOOP_TIMER
-        with timer:
-            logits, self.cache = self._prefill_chunk_jit(
-                self.params, self.cache, jnp.asarray(buf), seq.slot,
-                seq.kv_len, len(part))
+        logits, self.cache = self._prefill_chunk_jit(
+            self.params, self.cache, jnp.asarray(buf), seq.slot,
+            seq.kv_len, len(part))
+        self._note_dispatch(seq)
         seq.prefill_done += len(part)
         seq.kv_len += len(part)
         self._note_chunk(len(part))
@@ -647,25 +755,32 @@ class LLMEngine:
         if seq.prefill_done < len(seq.prompt):
             self._prefilling.append(seq)
             return
-        tok = int(self._sample_one(seq, logits))
+        tok = int(self._rec.to_host(self._sample_one(seq, logits)))
+        self._rec.enter("emit")
         self._after_token(seq, tok)
         if seq.slot >= 0:
             seq.last_tok = tok
             self._last_np[seq.slot] = tok
             self._active[seq.slot] = seq
 
-    def _decode(self, prof=None):
+    def _decode(self):
         if not self._active:
             return
-        jnp = self._jnp
+        jnp, rec = self._jnp, self._rec
+        rec.enter("decode")
         mask = np.zeros((self.slots,), bool)
         mask[list(self._active)] = True
-        timer = prof.phase("decode") if prof is not None else _NOOP_TIMER
-        with timer:
-            logits, self.cache = self._decode_jit(
-                self.params, self.cache, jnp.asarray(self._last_np),
-                jnp.asarray(mask))
-            toks = np.asarray(self._sample_all(logits))
+        logits, self.cache = self._decode_jit(
+            self.params, self.cache, jnp.asarray(self._last_np),
+            jnp.asarray(mask))
+        rec.dispatched = True
+        self.stats["decode_steps"] += 1
+        self.stats["decode_slots"] += len(self._active)
+        rec.enter("sample")
+        sampled = self._sample_all(logits)
+        rec.enter("fetch")
+        toks = rec.to_host(sampled)
+        rec.enter("emit")
         self._decode_since_chunk += 1
         for slot, seq in list(self._active.items()):
             # this call wrote seq.last_tok's K/V at position kv_len
@@ -676,6 +791,15 @@ class LLMEngine:
             if seq.slot >= 0:
                 seq.last_tok = tok
                 self._last_np[slot] = tok
+
+    def _note_dispatch(self, seq: _Seq):
+        """A prefill program for ``seq`` was dispatched: the iteration
+        counts as a step, and the first one ends the request's queue
+        stage."""
+        self._rec.dispatched = True
+        seq.chunks += 1
+        if seq.first_chunk is None:
+            seq.first_chunk = (time.perf_counter(), self.stats["steps"])
 
     def _note_chunk(self, n: int):
         self.stats["chunks"] += 1
@@ -731,9 +855,10 @@ class LLMEngine:
         exactly like reused slots always were."""
         slot = sess.slot
         k, v, ln = self._extract_jit(self.cache, slot)
-        slab = (np.asarray(k), np.asarray(v), int(ln))
+        to_host = self._rec.to_host
+        slab = (to_host(k), to_host(v), int(to_host(ln)))
         sess.handle = self._store().put(sess.session_id, slab)
-        sess.kv_len = int(ln)
+        sess.kv_len = slab[2]
         sess.slot = -1
         sess.state = "offloaded"
         self._free_slots.append(slot)
@@ -760,7 +885,7 @@ class LLMEngine:
         threading.Thread(target=fetch, daemon=True,
                          name=f"kv-restore-{sess.session_id}").start()
 
-    def _poll_restores(self, prof=None):
+    def _poll_restores(self):
         """Land finished restore fetches: install the slab into a free
         (or pressure-evicted) slot and resume the session's work.  Never
         blocks — unfinished fetches stay in flight while decode
@@ -785,12 +910,9 @@ class LLMEngine:
             slot = self._free_slots.pop()
             del self._restoring[sid]
             k, v, ln = ticket["result"]
-            timer = (prof.phase("restore_install") if prof is not None
-                     else _NOOP_TIMER)
-            with timer:
-                self.cache = self._install_jit(
-                    self.cache, jnp.asarray(k), jnp.asarray(v),
-                    jnp.int32(ln), slot)
+            self.cache = self._install_jit(
+                self.cache, jnp.asarray(k), jnp.asarray(v),
+                jnp.int32(ln), slot)
             dur = time.monotonic() - ticket["t0"]
             self.stats["restores"] += 1
             self.stats["restore_wait_s"] += dur
@@ -809,7 +931,7 @@ class LLMEngine:
                 self._active[slot] = seq
             elif sess.pending:
                 self._begin_ingest(sess.pending.pop(0), slot,
-                                   sess.kv_len, prof)
+                                   sess.kv_len)
 
     def _record_restore_span(self, sess: _Session, ticket: dict,
                              dur: float, nbytes: int):
@@ -867,13 +989,49 @@ class LLMEngine:
             text=self.tokenizer.decode(seq.generated),
             finished=True, finish_reason="error", error=str(err))
         self._finished.append(out)
+        self._record_engine_span(seq, error=True)
         if seq.on_event is not None:
             seq.on_event({"type": "error", "error": err, "output": out})
+
+    def _record_engine_span(self, seq: _Seq, error: bool = False):
+        """The request's one stage span, ``llm:engine``, as it leaves
+        the engine: ``queue`` (submit → its first prefill program
+        dispatched), ``prefill`` (→ its first token handed to
+        ``on_event``), ``decode`` (→ now); the step numbers join it to
+        the ``engine`` steps of a device trace.  An unsampled context
+        records nothing unless ``error``."""
+        ctx = seq.trace_ctx
+        if ctx is None:
+            return
+        now, step = time.perf_counter(), self.stats["steps"]
+        wall, t_submit, submit_step = seq.submitted
+        t_chunk, chunk_step = seq.first_chunk or (now, step)
+        t_token, token_step = seq.first_token or (now, step)
+        try:
+            from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
+
+            tracing_plane.record_span(
+                ctx, "llm:engine", ts=wall, dur_s=now - t_submit,
+                stages={"queue": t_chunk - t_submit,
+                        "prefill": t_token - t_chunk,
+                        "decode": now - t_token},
+                attrs={"prompt_tokens": len(seq.prompt),
+                       "chunks": seq.chunks,
+                       "output_tokens": len(seq.generated),
+                       "slot": seq.slot, "submit_step": submit_step,
+                       "first_chunk_step": chunk_step,
+                       "first_token_step": token_step,
+                       "last_step": step},
+                error=error)
+        except Exception:  # noqa: BLE001 — tracing is best-effort
+            pass
 
     # ----------------------------------------------------------- private
 
     def _after_token(self, seq: _Seq, tok: int):
         seq.generated.append(tok)
+        if seq.first_token is None:
+            seq.first_token = (time.perf_counter(), self.stats["steps"])
         s = seq.sampling
         eos = getattr(self.tokenizer, "eos_id",
                       getattr(self.tokenizer, "eos_token_id", None))
@@ -904,6 +1062,7 @@ class LLMEngine:
             finish_reason=reason,
         )
         self._finished.append(out)
+        self._record_engine_span(seq)
         sess = seq.session
         if seq.slot >= 0:
             self._active.pop(seq.slot, None)
@@ -949,7 +1108,7 @@ class LLMEngine:
             top_ks[slot] = s.top_k
             top_ps[slot] = s.top_p
             seq.rng_key, sub = self._jax.random.split(seq.rng_key)
-            keys[slot] = np.asarray(sub)
+            keys[slot] = self._rec.to_host(sub)
         return self._sample_jit(
             logits, jnp.asarray(keys), jnp.asarray(temps),
             jnp.asarray(top_ks), jnp.asarray(top_ps))
@@ -981,27 +1140,18 @@ class LLMEngine:
         return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
 
-class _NoopTimer:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_TIMER = _NoopTimer()
-
-
 class _LoopHandle:
     """Per-request handle returned by :meth:`EngineLoop.submit`: an
     event queue for streaming plus a wait() for the final output."""
 
-    def __init__(self, request_id: str):
+    def __init__(self, request_id: str, step: int = 0):
         import queue as _q  # noqa: PLC0415
 
         self.request_id = request_id
         self.events = _q.Queue()
         self.submit_ts = time.monotonic()
+        # where the llm:engine span's queue stage starts
+        self.submitted = (time.time(), time.perf_counter(), step)
         self.first_token_ts: float | None = None
         self._final: RequestOutput | None = None
         self._error: BaseException | None = None
@@ -1122,7 +1272,7 @@ class EngineLoop:
                     f"{self._max_waiting})",
                     retry_after_s=eng.retry_after_hint())
         rid = request_id or f"req-{next(eng._req_counter)}"
-        handle = _LoopHandle(rid)
+        handle = _LoopHandle(rid, eng.stats["steps"])
         with self._lock:
             self._inbox.append((prompt, sampling, rid, session_id,
                                 trace_ctx, handle))
@@ -1207,30 +1357,46 @@ class EngineLoop:
                 eng.add_request(prompt, sampling, rid, admit=False,
                                 session_id=session_id,
                                 on_event=handle._on_event,
-                                trace_ctx=trace_ctx)
+                                trace_ctx=trace_ctx,
+                                submitted=handle.submitted)
             except BaseException as exc:  # noqa: BLE001 — typed to caller
                 handle._fail(exc)
 
     def _run(self):
+        """Each iteration with work is one ``engine`` step of the
+        recorder — drain, the engine's own phases, housekeeping — and a
+        stretch without work is one ``idle_wait`` phase, however many
+        times the wait wakes."""
         eng = self._engine
+        rec = eng._rec
         while not self._stop:
-            self._drain_inbox(eng)
-            if eng.has_unfinished():
-                try:
-                    eng.step()
-                except Exception:  # noqa: BLE001 — keep the loop alive
-                    import logging  # noqa: PLC0415
+            if self._inbox or eng.has_unfinished():
+                rec.begin()
+                rec.enter("drain")
+                self._drain_inbox(eng)
+                if eng.has_unfinished():
+                    try:
+                        eng.step()
+                    except Exception:  # noqa: BLE001 — keep the loop alive
+                        import logging  # noqa: PLC0415
 
-                    logging.getLogger(__name__).exception(
-                        "llm engine step failed")
-                    time.sleep(0.05)
+                        logging.getLogger(__name__).exception(
+                            "llm engine step failed")
+                        time.sleep(0.05)
+                rec.enter("housekeeping")
+                self._housekeep(eng)
+                rec.end()
             else:
+                rec.enter("idle_wait")
                 self._wake.wait(self._idle_sleep)
                 self._wake.clear()
-            self._snapshot = self._loop_snapshot(eng)
-            now = time.monotonic()
-            if now - self._last_tick >= self._metrics_interval:
-                self._tick_metrics(eng, now)
+                self._housekeep(eng)
+
+    def _housekeep(self, eng):
+        self._snapshot = self._loop_snapshot(eng)
+        now = time.monotonic()
+        if now - self._last_tick >= self._metrics_interval:
+            self._tick_metrics(eng, now)
 
     def _tick_metrics(self, eng, now: float):
         tokens = eng.stats["tokens_generated"]

@@ -15,7 +15,11 @@ substrate:
   against the detected TPU peak, absorbing the device-feed and
   collective-fusion stats streams as phases instead of parallel
   idioms.  Near-zero overhead (< 2 µs/step, benchmarked) and a cheap
-  no-op outside a cluster — safe to leave in production loops.
+  no-op outside a cluster — safe to leave in production train loops.
+  (The LLM engine loop accounts for its own time instead: phase seconds
+  and step / sync counters in ``LLMEngine.stats``, ``engine:<phase>``
+  events in the jax profiler's trace, one ``llm:engine`` stage span per
+  request — see ``llm/engine.py``.)
 * ``device_stats.py`` — per-device HBM occupancy from
   ``jax.Device.memory_stats()`` (graceful ``None`` on CPU), published
   through the node agent and the GCS metrics table.

@@ -1,4 +1,10 @@
-"""Per-step phase profiler for training/inference loops.
+"""Per-step phase profiler for training loops.
+
+The LLM engine loop does not use it: that loop accounts for its own time
+(``llm/engine.py`` ``_PhaseRecorder``: phase seconds in ``LLMEngine.stats``
+and ``engine:<phase>`` events in the jax profiler's trace), because a
+``compute`` derived as a remainder is unsound where the host overlaps the
+device.
 
 One instrument, three consumers (the task-events pattern):
 

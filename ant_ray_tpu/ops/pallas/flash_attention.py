@@ -175,6 +175,7 @@ def flash_attention_fwd_lse(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd_lse",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -290,6 +291,7 @@ def flash_attention_backward(q, k, v, out, lse, do, *, causal: bool,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, dot, lse4, delta)
 
     # ---- dk/dv sweep: grid (b, kv_heads, kv_blocks, groups·q_blocks);
@@ -384,6 +386,7 @@ def flash_attention_backward(q, k, v, out, lse, do, *, causal: bool,
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qt, kt, vt, dot, lse4, delta)
 
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),

@@ -2062,6 +2062,31 @@ class ServeController:
         return True
 
 
+def _stream_ingress_span(name: str, service: str, attrs: dict):
+    """Tracing ingress of a STREAMED request: mints the trace root and
+    returns it with ``finish(error, first_chunk, chunks, **more)``,
+    which records the request's one ingress span, from receipt to the
+    end of the stream (``first_chunk``: ``perf_counter`` at the first
+    frame written).  The proxy calls it however the stream ends, so
+    every hop of a streamed request shares one trace id, from the proxy
+    down to ``llm:engine``."""
+    ctx = tracing_plane.mint()
+    t_wall = time.time()
+    t0 = time.perf_counter()
+
+    def finish(error: bool, first_chunk: float | None = None,
+               chunks: int = 0, **more):
+        span_attrs = {**attrs, "stream": True, "chunks": chunks, **more}
+        if first_chunk is not None:
+            span_attrs["first_chunk_s"] = first_chunk - t0
+        tracing_plane.record_span(
+            ctx, name, ts=t_wall, dur_s=time.perf_counter() - t0,
+            attrs=span_attrs, error=error, span_id=ctx.span_id,
+            parent_id="", service=service)
+
+    return ctx, finish
+
+
 class HttpProxy:
     """aiohttp ingress routing requests to deployments by route prefix
     (ref: serve/_private/proxy.py)."""
@@ -2167,14 +2192,14 @@ class HttpProxy:
                     error=status >= 400, span_id=ctx.span_id,
                     parent_id="", service="http-proxy")
 
-        def stream_start(path: str, body, timeout_s: float | None):
-            """Start a streaming call; returns (handle, replica,
-            ObjectRefGenerator) — the replica so the caller can feed
-            the stream's outcome into its breaker (convention:
-            ``{"stream": true}`` requests dispatch to the deployment's
-            ``stream`` method as a generator).  The end-to-end deadline
-            (explicit header or deployment default) is stamped on the
-            dispatch like the unary path."""
+        def stream_start(path: str, body, timeout_s: float | None, ctx):
+            """Start a streaming call under the trace root ``ctx``;
+            returns (handle, replica, ObjectRefGenerator) — the replica
+            so the caller can feed the stream's outcome into its breaker
+            (convention: ``{"stream": true}`` requests dispatch to the
+            deployment's ``stream`` method as a generator).  The
+            end-to-end deadline (explicit header or deployment default)
+            is stamped on the dispatch like the unary path."""
             handle = resolve_handle(path)
             if handle is None:
                 return None
@@ -2182,27 +2207,11 @@ class HttpProxy:
                 body.setdefault("__route_path__", path)
             h = handle.options(method_name="stream", stream=True)
             h._maybe_refresh()
-            # Streaming ingress mints the trace root too; the span is
-            # recorded when dispatch fails (shed) — mid-stream life is
-            # covered by the replica-side stream span.
-            ctx = tracing_plane.mint()
-            t_wall = time.time()
-            t0 = time.perf_counter()
-            try:
-                with tracing_plane.use(ctx):
-                    replica = h._pick()  # may raise typed BackPressure
-                    gen = h._dispatch(replica, (body,), {},
-                                      h._mux_model_id,
-                                      h._request_meta(timeout_s,
-                                                      trace=ctx))
-            except BaseException:
-                tracing_plane.record_span(
-                    ctx, f"http:{path}", ts=t_wall,
-                    dur_s=time.perf_counter() - t0,
-                    attrs={"path": path, "stream": True}, error=True,
-                    span_id=ctx.span_id, parent_id="",
-                    service="http-proxy")
-                raise
+            with tracing_plane.use(ctx):
+                replica = h._pick()  # may raise typed BackPressure
+                gen = h._dispatch(replica, (body,), {},
+                                  h._mux_model_id,
+                                  h._request_meta(timeout_s, trace=ctx))
             return (h, replica, gen)
 
         def next_chunk(gen):
@@ -2238,21 +2247,33 @@ class HttpProxy:
                 # Server-sent events: one `data:` frame per produced
                 # chunk, flowing while the model still generates
                 # (ref: serve streaming HTTP responses).
-                try:
-                    started = await loop_.run_in_executor(
-                        None, stream_start, request.path, body,
-                        timeout_s)
-                except Exception as e:  # noqa: BLE001 — classified below
-                    # _pick with every replica ejected raises typed
-                    # BackPressureError: same shed contract as unary.
+                ctx, span_done = _stream_ingress_span(
+                    f"http:{request.path}", "http-proxy",
+                    {"path": request.path})
+
+                def finish(status, first_chunk=None, chunks=0):
+                    span_done(status >= 400, first_chunk, chunks,
+                              status=status)
+
+                def failed(e):
                     # NB: explicit None check — an unprepared
                     # web.Response is FALSY (it has __len__), so `or`
                     # would silently discard the 429.
                     resp_t = shed_response(e)
-                    if resp_t is not None:
-                        return resp_t
-                    return web.json_response({"error": repr(e)},
-                                             status=500)
+                    if resp_t is None:
+                        resp_t = web.json_response({"error": repr(e)},
+                                                   status=500)
+                    finish(resp_t.status)
+                    return resp_t
+
+                try:
+                    started = await loop_.run_in_executor(
+                        None, stream_start, request.path, body,
+                        timeout_s, ctx)
+                except Exception as e:  # noqa: BLE001 — classified below
+                    # _pick with every replica ejected raises typed
+                    # BackPressureError: same shed contract as unary.
+                    return failed(e)
                 if started is None:
                     return web.json_response(
                         {"error": f"no route for {request.path}"},
@@ -2268,18 +2289,18 @@ class HttpProxy:
                         None, next_chunk, gen)
                 except Exception as e:  # noqa: BLE001 — classified below
                     _record_result(sh._routing, replica, e)
-                    resp_t = shed_response(e)
-                    if resp_t is not None:
-                        return resp_t
-                    return web.json_response({"error": repr(e)},
-                                             status=500)
+                    return failed(e)
                 resp = web.StreamResponse(
                     headers={"Content-Type": "text/event-stream",
                              "Cache-Control": "no-cache"})
                 await resp.prepare(request)
+                first_chunk_s, chunks = None, 0
                 while chunk is not None:
                     await resp.write(
                         b"data: " + _json.dumps(chunk).encode() + b"\n\n")
+                    chunks += 1
+                    if first_chunk_s is None:
+                        first_chunk_s = time.perf_counter()
                     try:
                         chunk = await loop_.run_in_executor(
                             None, next_chunk, gen)
@@ -2289,10 +2310,12 @@ class HttpProxy:
                         # missing [DONE]).  resp.write failures (client
                         # gone) are NOT replica outcomes and propagate.
                         _record_result(sh._routing, replica, e)
+                        finish(500, first_chunk_s, chunks)
                         await resp.write_eof()
                         return resp
                 _record_result(sh._routing, replica)
                 await resp.write(b"data: [DONE]\n\n")
+                finish(200, first_chunk_s, chunks)
                 await resp.write_eof()
                 return resp
             return await loop_.run_in_executor(
@@ -2456,16 +2479,16 @@ class GrpcProxy:
         # admission gate fires on generator start, i.e. the first get).
         h = handle.options(method_name="stream", stream=True)
         h._maybe_refresh()
-        ctx = tracing_plane.mint()
+        # Tracing ingress (gRPC stream): one `grpc:{route}` span for the
+        # whole stream, however it ends, as the HTTP proxy records its.
+        ctx, finish = _stream_ingress_span(
+            f"grpc:{route}", "grpc-proxy", {"route": route})
+        first_chunk_s, chunks = None, 0
         with tracing_plane.use(ctx):
             try:
                 replica = h._pick()
             except BackPressureError as e:
-                tracing_plane.record_span(
-                    ctx, f"grpc:{route}", ts=time.time(), dur_s=0.0,
-                    attrs={"route": route, "stream": True}, error=True,
-                    span_id=ctx.span_id, parent_id="",
-                    service="grpc-proxy")
+                finish(True)
                 # Every replica ejected: same shed contract as unary.
                 self._abort_overload(context, e)
             gen = h._dispatch(replica, (body,), {}, h._mux_model_id,
@@ -2474,11 +2497,16 @@ class GrpcProxy:
         try:
             for ref in gen:
                 yield json.dumps(art.get(ref)).encode("utf-8")
+                chunks += 1
+                if first_chunk_s is None:
+                    first_chunk_s = time.perf_counter()
         except Exception as e:  # noqa: BLE001 — classified below
             _record_result(h._routing, replica, e)
+            finish(True, first_chunk_s, chunks)
             self._abort_overload(context, e)
             raise
         _record_result(h._routing, replica)
+        finish(False, first_chunk_s, chunks)
 
     def start(self, port: int) -> int:
         from concurrent import futures  # noqa: PLC0415

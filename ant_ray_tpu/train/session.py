@@ -79,6 +79,7 @@ def report(metrics: dict, checkpoint=None) -> None:
     rank-skew gauges, and the profiler's publish buffer is flushed so
     the timeline's device rows stay current."""
     import ant_ray_tpu as art  # noqa: PLC0415
+    from ant_ray_tpu._private.jax_utils import trace_annotation  # noqa: PLC0415
 
     ctx = get_context()
     metrics = dict(metrics)
@@ -88,7 +89,9 @@ def report(metrics: dict, checkpoint=None) -> None:
         if last is not None:
             metrics["_step_record"] = last.as_dict()
         prof.flush()
-    with ctx._report_lock:
+    # Named in a profiler trace: the gap between two steps that this
+    # round trip leaves on the device is labelled `train:report`.
+    with trace_annotation("train:report"), ctx._report_lock:
         reply = art.get(ctx.controller.report_from_worker.remote(
             ctx.world_rank, metrics, checkpoint))
     # The ack doubles as the drain channel: when the controller has a

@@ -1,0 +1,307 @@
+"""The engine loop accounts for its own time (llm/engine.py
+``_PhaseRecorder``): phase seconds that tile the loop, step and sync
+counters, ``engine:<phase>`` events in the jax profiler's trace, one
+``llm:engine`` stage span per request, and the proxy's ``http:`` span
+for a stream that succeeds — what ``chipbench/layer_metrics`` reads.
+
+The counter readings asserted here are the DOCUMENTED ones: a PR that
+changes how often the engine synchronises with the device edits them
+knowingly."""
+
+import json
+import os
+import time
+import urllib.request
+
+import pytest
+
+import jax
+
+import ant_ray_tpu as art
+from ant_ray_tpu.llm import LLMEngine, SamplingParams
+from ant_ray_tpu.llm.engine import PHASES, EngineLoop
+from ant_ray_tpu.llm.kv_offload import LocalKvStore
+from ant_ray_tpu.models import llama
+from ant_ray_tpu.observability import tracing_plane
+
+CFG = llama.CONFIGS["tiny"]
+PROMPTS = ([5, 9, 17, 3, 88, 41, 12, 13, 14, 15, 16],   # two chunks of 8
+           [44, 55, 66],
+           [7, 8, 9, 10, 11])
+
+
+class _NoEos:
+    """Token ids through, no end-of-sequence: lengths are the asked."""
+
+    def encode(self, text):
+        return [ord(c) % CFG.vocab_size for c in text]
+
+    def decode(self, ids):
+        return " ".join(str(i) for i in ids)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return llama.init_params(CFG, jax.random.PRNGKey(7))
+
+
+def _engine(params, **kw):
+    kw.setdefault("slots", 4)
+    kw.setdefault("max_seq", 96)
+    kw.setdefault("prefill_chunk_tokens", 8)
+    kw.setdefault("tokenizer", _NoEos())
+    return LLMEngine(CFG, params, **kw)
+
+
+def _spans(trace_id, name=None):
+    return [s for s in tracing_plane.recorder().snapshot()
+            if s["trace_id"] == trace_id
+            and (name is None or s["name"] == name)]
+
+
+def test_counters_are_deterministic_for_a_fixed_batch(params):
+    """Today's reading, documented: every decode step reads one key per
+    active slot and then the tokens (active + 1), every prompt's end
+    reads its first token (1)."""
+    readings = []
+    for _ in range(2):
+        eng = _engine(params)
+        outs = eng.generate(list(PROMPTS), SamplingParams(max_tokens=6))
+        assert [len(o.token_ids) for o in outs] == [6, 6, 6]
+        readings.append({k: v for k, v in eng.stats.items()
+                         if isinstance(v, int)})
+    first = readings[0]
+    assert first == readings[1]
+    assert first["chunks"] == 4 and first["chunk_tokens"] == 19
+    # the first token of a prompt comes from its last chunk
+    assert first["decode_slots"] == first["tokens_generated"] == 3 * 5
+    assert first["d2h_syncs"] == (first["decode_slots"]
+                                  + first["decode_steps"] + len(PROMPTS))
+    assert 0 < first["decode_steps"] <= first["steps"]
+    assert first["steps"] <= first["decode_steps"] + first["chunks"]
+
+
+def test_blocked_time_is_counted_once_and_by_phase(params):
+    store = LocalKvStore()
+    eng = _engine(params, slots=2, kv_offload_store=store)
+    eng.add_request(list(PROMPTS[1]), SamplingParams(max_tokens=4),
+                    admit=False, session_id="s")
+    while eng.has_unfinished():
+        eng.step()
+    before = eng.stats["d2h_syncs"]
+    assert eng.evict_session("s")           # slab k, v and its length
+    assert eng.stats["d2h_syncs"] == before + 3
+    stats = eng.stats
+    by_phase = sum(stats[f"block_{p}_s"] for p in PHASES)
+    assert 0 < by_phase <= stats["block_s"]  # the eviction ran in no phase
+    for phase in PHASES:
+        assert stats[f"block_{phase}_s"] <= stats[f"phase_{phase}_s"] + 1e-9
+    assert stats["block_fetch_s"] > 0 and stats["block_sample_s"] > 0
+
+
+def test_phases_tile_the_loop_and_stats_keep_their_keys(params):
+    eng = _engine(params)
+    keys = set(eng.stats)
+    assert {f"phase_{p}_s" for p in PHASES} <= keys
+    loop = EngineLoop(eng)
+    try:
+        loop.submit(list(PROMPTS[1]), SamplingParams(max_tokens=2)).wait(120)
+        s0, t0 = dict(eng.stats), time.perf_counter()
+        handles = [loop.submit(list(p), SamplingParams(max_tokens=24))
+                   for p in PROMPTS]
+        for h in handles:
+            h.wait(120)
+        time.sleep(0.3)                      # an idle stretch counts too
+        s1, t1 = dict(eng.stats), time.perf_counter()
+    finally:
+        loop.shutdown()
+    assert set(eng.stats) == keys == set(s1)
+    phases = sum(s1[k] - s0[k] for k in keys if k.startswith("phase_"))
+    assert phases == pytest.approx(t1 - t0, rel=0.05)
+    assert s1["phase_idle_wait_s"] - s0["phase_idle_wait_s"] >= 0.2
+    for phase in ("drain", "admit", "chunk", "decode", "sample", "fetch",
+                  "emit", "housekeeping"):
+        assert s1[f"phase_{phase}_s"] > s0[f"phase_{phase}_s"], phase
+    assert s1["steps"] - s0["steps"] >= s1["decode_steps"] - s0["decode_steps"]
+
+
+def test_one_engine_span_per_sampled_request(params):
+    eng = _engine(params)
+    loop = EngineLoop(eng)
+    ctxs = [tracing_plane.mint(sampled=True) for _ in PROMPTS]
+    try:
+        handles = [loop.submit(list(p), SamplingParams(max_tokens=5),
+                               trace_ctx=c)
+                   for p, c in zip(PROMPTS, ctxs)]
+        outs = [h.wait(120) for h in handles]
+    finally:
+        loop.shutdown()
+    for prompt, ctx, out in zip(PROMPTS, ctxs, outs):
+        (span,) = _spans(ctx.trace_id, "llm:engine")
+        stages, attrs = span["stages"], span["attrs"]
+        assert set(stages) == {"queue", "prefill", "decode"}
+        assert all(v >= 0 for v in stages.values())
+        assert sum(stages.values()) == pytest.approx(span["dur_s"],
+                                                     abs=1e-6)
+        assert stages["prefill"] > 0 and stages["decode"] > 0
+        assert attrs["prompt_tokens"] == len(prompt)
+        assert attrs["chunks"] == -(-len(prompt) // 8)
+        assert attrs["output_tokens"] == len(out.token_ids) == 5
+        assert 0 <= attrs["slot"] < 4
+        steps = [attrs[k] for k in ("submit_step", "first_chunk_step",
+                                    "first_token_step", "last_step")]
+        assert steps == sorted(steps)
+        # the step that ends the prompt decodes too: tokens 1 and 2
+        assert attrs["last_step"] - attrs["first_token_step"] == 3
+        assert "error" not in span
+
+
+def test_unsampled_context_records_nothing(params):
+    eng = _engine(params)
+    ctx = tracing_plane.mint(sampled=False)
+    eng.add_request(list(PROMPTS[1]), SamplingParams(max_tokens=3),
+                    admit=False, trace_ctx=ctx)
+    while eng.has_unfinished():
+        eng.step()
+    assert _spans(ctx.trace_id) == []
+
+
+def test_failed_request_records_a_forced_error_span(params):
+    store = LocalKvStore()
+    eng = _engine(params, slots=2, kv_offload_store=store)
+    eng.add_request([5, 9, 17], SamplingParams(max_tokens=3), admit=False,
+                    session_id="s")
+    while eng.has_unfinished():
+        eng.step()
+    assert eng.evict_session("s")
+    store.delete("s")                        # the restore will fail
+    ctx = tracing_plane.mint(sampled=False)
+    eng.add_request([21, 22], SamplingParams(max_tokens=3), admit=False,
+                    session_id="s", trace_ctx=ctx)
+    outs = []
+    deadline = time.monotonic() + 120
+    while eng.has_unfinished():
+        outs.extend(eng.step())
+        assert time.monotonic() < deadline
+    assert [o.finish_reason for o in outs] == ["error"]
+    (span,) = _spans(ctx.trace_id, "llm:engine")
+    assert span["error"] is True and span["forced"] is True
+    # it never reached a slot: all of its life was queue
+    assert span["stages"]["queue"] == pytest.approx(span["dur_s"], abs=1e-6)
+    assert span["attrs"]["chunks"] == 0
+
+
+def test_profiler_trace_holds_engine_phases_by_step(params, tmp_path):
+    """The trace as the benchmark takes it (host TraceMe events, no
+    Python tracer): bare event names, the step number as metadata."""
+    from jax.profiler import ProfileData
+
+    eng = _engine(params)
+    eng.generate([list(PROMPTS[1])], SamplingParams(max_tokens=3))  # warm
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    first = eng.stats["steps"]
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.generate([list(PROMPTS[2])], SamplingParams(max_tokens=6))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = [e for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    names = {e.name for e in events}
+    assert {"engine", "engine:admit", "engine:chunk", "engine:decode",
+            "engine:sample", "engine:fetch", "engine:emit",
+            "engine:housekeeping"} <= names, names
+    decode = sorted((e.start_ns, dict(e.stats)["step"]) for e in events
+                    if e.name == "engine:decode")
+    # six tokens: one from the chunk, whose step decodes too, then four
+    assert [s for _, s in decode] == list(range(first, first + 5))
+    steps = sorted((e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)["step_num"]) for e in events
+                   if e.name == "engine")
+    assert [n for _, _, n in steps] == list(range(first, first + 5))
+    # every phase event lies inside the step event that carries its number
+    for e in events:
+        if e.name.startswith("engine:"):
+            lo, hi, _ = steps[dict(e.stats)["step"] - first]
+            assert lo <= e.start_ns and e.start_ns + e.duration_ns <= hi
+
+
+def test_feed_wait_is_named_in_a_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    from ant_ray_tpu._private.jax_utils import trace_annotation
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace_annotation("feed:wait"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    assert any(e.name == "feed:wait"
+               for plane in ProfileData.from_file(str(path)).planes
+               for line in plane.lines for e in line.events)
+
+
+def test_streamed_request_is_one_trace_from_proxy_to_engine(shutdown_only):
+    """http: (with first_chunk_s) -> llm:admission -> llm:engine under
+    one trace id, for a stream that SUCCEEDS."""
+    from ant_ray_tpu._private import config as config_mod
+    from ant_ray_tpu.util.timeline import fetch_span_events
+
+    os.environ["ART_TRACE_SAMPLE_RATE"] = "1.0"
+    config_mod._global_config = None
+    try:
+        art.init(num_cpus=2, num_tpus=1)     # the replica leases a chip
+        from ant_ray_tpu import serve
+        from ant_ray_tpu.llm.serve_llm import build_llm_deployment
+
+        serve.run(build_llm_deployment("tiny", slots=2, max_seq=64),
+                  port=0)
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{serve.run.last_http_port}/v1/completions",
+            data=json.dumps({"prompt": "hello", "max_tokens": 5,
+                             "stream": True}).encode(),
+            headers={"Content-Type": "application/json"})
+        frames = 0
+        with urllib.request.urlopen(req, timeout=180) as resp:
+            for raw in resp:
+                line = raw.decode().strip()
+                if line == "data: [DONE]":
+                    break
+                frames += line.startswith("data: ")
+        assert frames >= 2
+
+        def whole(spans):
+            by_trace = {}
+            for s in spans:
+                by_trace.setdefault(s["trace_id"], {})[
+                    s["name"].split(":/")[0]] = s
+            return [t for t in by_trace.values()
+                    if {"http", "llm:admission", "llm:engine"} <= set(t)]
+
+        deadline = time.monotonic() + 30
+        while not (found := whole(fetch_span_events())):
+            assert time.monotonic() < deadline, "spans never landed"
+            time.sleep(0.2)
+        (trace,) = found
+        http, engine = trace["http"], trace["llm:engine"]
+        assert http["name"] == "http:/v1/completions"
+        assert http["attrs"]["stream"] is True
+        assert http["attrs"]["status"] == 200
+        assert http["attrs"]["chunks"] == frames
+        assert 0 < http["attrs"]["first_chunk_s"] <= http["dur_s"]
+        assert "llm:stream" in trace
+        assert engine["attrs"]["output_tokens"] == frames - 1
+        # the engine's part lies inside the proxy's
+        assert http["ts"] <= engine["ts"]
+        assert engine["ts"] + engine["dur_s"] <= http["ts"] + http["dur_s"] \
+            + 0.05
+        serve.shutdown()
+    finally:
+        os.environ.pop("ART_TRACE_SAMPLE_RATE", None)
+        config_mod._global_config = None
