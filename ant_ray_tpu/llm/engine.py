@@ -68,6 +68,9 @@ class _Seq:
     sampling: SamplingParams
     slot: int = -1
     generated: list = field(default_factory=list)
+    # uint32 (2,) device array while the seq is waiting, prefilling or
+    # paused; None while it decodes — its key is then row ``slot`` of
+    # the engine's key table and advances inside the jitted sampler.
     rng_key: Any = None
     session: Any = None           # _Session | None
     prefill_done: int = 0         # prompt tokens ingested (chunked mode)
@@ -124,7 +127,9 @@ class _PhaseRecorder:
     ``to_host`` is the one place the engine blocks on a device value:
     it counts the read (``d2h_syncs``) and adds its wall time to
     ``block_s`` and to the open phase's ``block_<name>_s``, so host time
-    proper is ``sum(phase_*_s) - block_s - phase_idle_wait_s``.
+    proper is ``sum(phase_*_s) - block_s - phase_idle_wait_s``.  A
+    decode step reads once, its tokens in ``fetch``; a prompt's end
+    reads its first token in ``chunk``; ``sample`` only dispatches.
     """
 
     def __init__(self, jax, stats: dict):
@@ -279,6 +284,12 @@ class LLMEngine:
                 tp=tensor_parallel_size)
         self.params = params
         self.cache = llama.init_kv_cache(self.config, slots, self.max_seq)
+        # Per-slot sampling keys, resident on the device: a key enters
+        # its row when its sequence joins the decode batch, the jitted
+        # sampler splits every active row each step, and the row leaves
+        # as a device slice if the sequence is evicted unfinished.  The
+        # host never reads a key.
+        self._keys = jnp.zeros((slots, 2), jnp.uint32)
         if self.mesh is not None:
             self._shard_state()
         # Host-side mirror of each slot's most recent token: mutated in
@@ -286,6 +297,11 @@ class LLMEngine:
         # loop costs one host→device transfer per step instead of one
         # tiny device op per slot.
         self._last_np = np.zeros((slots,), np.int32)
+        # Each slot's (temperature, top_k, top_p), kept current when a
+        # sequence joins the decode batch; uploaded again only after a
+        # change.
+        self._sampling_rows = [(0.0, 0, 1.0)] * slots
+        self._sampling_dev = None     # (temps, top_ks, top_ps) on device
         # Admission bound: with every KV slot busy, at most this many
         # requests may wait for one (None = unbounded, legacy).  Serving
         # paths set it so a traffic spike sheds typed BackPressureError
@@ -355,16 +371,28 @@ class LLMEngine:
                 "length": cache["length"].at[slot].set(length),
             }
 
+        def _put_key(keys, key, slot):
+            return keys.at[slot].set(key)
+
+        def _take_key(keys, slot):
+            from jax import lax  # noqa: PLC0415
+
+            return lax.dynamic_index_in_dim(keys, slot, axis=0,
+                                            keepdims=False)
+
         # one compile per prompt bucket (slot/length traced); ONE chunk
         # variant (slot/start/length traced); one decode; one extract /
-        # install each (slot traced).
+        # install / key write / key read each (slot traced).
         self._prefill_jit = jax.jit(_prefill, donate_argnums=(1,))
         self._prefill_chunk_jit = jax.jit(_prefill_chunk,
                                           donate_argnums=(1,))
         self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
         self._extract_jit = jax.jit(_extract)
         self._install_jit = jax.jit(_install, donate_argnums=(0,))
+        self._put_key_jit = jax.jit(_put_key)
+        self._take_key_jit = jax.jit(_take_key)
         self._sample_jit = jax.jit(self._sample_batch)
+        self._one_active = jnp.ones((1,), bool)   # _sample_one's mask
 
     def _shard_state(self):
         """Distribute params and KV slabs over the engine's mesh: params
@@ -391,6 +419,7 @@ class LLMEngine:
             "v": jax.device_put(self.cache["v"], kv),
             "length": jax.device_put(self.cache["length"], rep),
         }
+        self._keys = jax.device_put(self._keys, rep)
 
     # ------------------------------------------------------------ public
 
@@ -595,6 +624,8 @@ class LLMEngine:
             if not force or cur in self._prefilling:
                 return False
             self._active.pop(cur.slot, None)
+            # the key rides the sequence, not the slot: a device slice
+            cur.rng_key = self._take_key_jit(self._keys, cur.slot)
             cur.slot = -1
             sess.paused = cur
             sess.current = None
@@ -708,13 +739,12 @@ class LLMEngine:
             len(seq.prompt))
         self._note_dispatch(seq)
         seq.kv_len = len(seq.prompt)
-        tok = int(rec.to_host(self._sample_one(seq, last_logits)))
+        tok = int(rec.to_host(self._sample_one(seq, last_logits))[0])
         rec.enter("emit")
         self._after_token(seq, tok)
         if seq.slot >= 0:
             seq.last_tok = tok
-            self._last_np[slot] = tok
-            self._active[slot] = seq
+            self._join_decode(seq)
         rec.enter("admit")            # back to the caller's phase
 
     def _maybe_prefill_chunk(self):
@@ -755,13 +785,27 @@ class LLMEngine:
         if seq.prefill_done < len(seq.prompt):
             self._prefilling.append(seq)
             return
-        tok = int(self._rec.to_host(self._sample_one(seq, logits)))
+        tok = int(self._rec.to_host(self._sample_one(seq, logits))[0])
         self._rec.enter("emit")
         self._after_token(seq, tok)
         if seq.slot >= 0:
             seq.last_tok = tok
-            self._last_np[seq.slot] = tok
-            self._active[seq.slot] = seq
+            self._join_decode(seq)
+
+    def _join_decode(self, seq: _Seq):
+        """``seq`` (slot and ``last_tok`` set) decodes from the next
+        step on: its token, its sampling parameters and its key move
+        into the slot's rows — the key through one small program, no
+        read."""
+        slot, s = seq.slot, seq.sampling
+        self._last_np[slot] = seq.last_tok
+        row = (s.temperature, s.top_k, s.top_p)
+        if row != self._sampling_rows[slot]:
+            self._sampling_rows[slot] = row
+            self._sampling_dev = None
+        self._keys = self._put_key_jit(self._keys, seq.rng_key, slot)
+        seq.rng_key = None
+        self._active[slot] = seq
 
     def _decode(self):
         if not self._active:
@@ -770,14 +814,14 @@ class LLMEngine:
         rec.enter("decode")
         mask = np.zeros((self.slots,), bool)
         mask[list(self._active)] = True
+        active = jnp.asarray(mask)
         logits, self.cache = self._decode_jit(
-            self.params, self.cache, jnp.asarray(self._last_np),
-            jnp.asarray(mask))
+            self.params, self.cache, jnp.asarray(self._last_np), active)
         rec.dispatched = True
         self.stats["decode_steps"] += 1
         self.stats["decode_slots"] += len(self._active)
         rec.enter("sample")
-        sampled = self._sample_all(logits)
+        sampled = self._sample_all(logits, active)
         rec.enter("fetch")
         toks = rec.to_host(sampled)
         rec.enter("emit")
@@ -927,8 +971,7 @@ class LLMEngine:
                 sess.paused = None
                 sess.current = seq
                 seq.slot = slot
-                self._last_np[slot] = seq.last_tok
-                self._active[slot] = seq
+                self._join_decode(seq)
             elif sess.pending:
                 self._begin_ingest(sess.pending.pop(0), slot,
                                    sess.kv_len)
@@ -1088,37 +1131,44 @@ class LLMEngine:
             seq.on_event({"type": "final", "output": out})
 
     def _sample_one(self, seq: _Seq, logits):
-        seq.rng_key, sub = self._jax.random.split(seq.rng_key)
-        s = seq.sampling
-        return self._sample_jit(
-            logits[None], sub[None],
-            self._jnp.asarray([s.temperature], self._jnp.float32),
-            self._jnp.asarray([s.top_k], self._jnp.int32),
-            self._jnp.asarray([s.top_p], self._jnp.float32))[0]
+        """The first token at a prompt's end, as a (1,) device array —
+        the caller's one read.  The key chain is the decode batch's:
+        split once, sample with the second half, the first half stays
+        on ``seq.rng_key`` for the table."""
+        jnp, s = self._jnp, seq.sampling
+        toks, rest = self._sample_jit(
+            logits[None], seq.rng_key[None], self._one_active,
+            jnp.asarray([s.temperature], jnp.float32),
+            jnp.asarray([s.top_k], jnp.int32),
+            jnp.asarray([s.top_p], jnp.float32))
+        seq.rng_key = rest[0]
+        return toks
 
-    def _sample_all(self, logits):
-        jnp = self._jnp
-        temps = np.zeros((self.slots,), np.float32)
-        top_ks = np.zeros((self.slots,), np.int32)
-        top_ps = np.ones((self.slots,), np.float32)
-        keys = np.zeros((self.slots, 2), np.uint32)
-        for slot, seq in self._active.items():
-            s = seq.sampling
-            temps[slot] = s.temperature
-            top_ks[slot] = s.top_k
-            top_ps[slot] = s.top_p
-            seq.rng_key, sub = self._jax.random.split(seq.rng_key)
-            keys[slot] = self._rec.to_host(sub)
-        return self._sample_jit(
-            logits, jnp.asarray(keys), jnp.asarray(temps),
-            jnp.asarray(top_ks), jnp.asarray(top_ps))
+    def _sample_all(self, logits, active):
+        """Dispatch the batch sampler on the decode step's logits; the
+        key table advances on the device.  No read, no eager op."""
+        if self._sampling_dev is None:
+            jnp = self._jnp
+            temps, top_ks, top_ps = zip(*self._sampling_rows)
+            self._sampling_dev = (jnp.asarray(temps, jnp.float32),
+                                  jnp.asarray(top_ks, jnp.int32),
+                                  jnp.asarray(top_ps, jnp.float32))
+        sampled, self._keys = self._sample_jit(
+            logits, self._keys, active, *self._sampling_dev)
+        return sampled
 
-    def _sample_batch(self, logits, keys, temps, top_ks, top_ps):
+    def _sample_batch(self, logits, keys, active, temps, top_ks, top_ps):
         """Vectorized per-slot sampling: greedy when temperature == 0,
         else temperature softmax with optional top-k / top-p (nucleus)
-        filtering — all branch-free for XLA."""
+        filtering — all branch-free for XLA.  Every row of ``keys``
+        (uint32 (n, 2)) is split as the eager ``jax.random.split``
+        would: the second half samples, the first half is the row's
+        next key where ``active``, and an inactive row keeps its key.
+        Returns ``(tokens, keys)``."""
         jax, jnp = self._jax, self._jnp
         vocab = logits.shape[-1]
+        split = jax.vmap(jax.random.split)(keys)          # (n, 2, 2)
+        next_keys = jnp.where(active[:, None], split[:, 0], keys)
         greedy = jnp.argmax(logits, axis=-1)
 
         scaled = logits / jnp.maximum(temps[:, None], 1e-6)
@@ -1136,8 +1186,10 @@ class LLMEngine:
         keep_p = ranks <= cutoff_rank[:, None]
         masked = jnp.where(keep_k & keep_p, scaled, -jnp.inf)
         sampled = jax.vmap(
-            lambda k, lg: jax.random.categorical(k, lg))(keys, masked)
-        return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+            lambda k, lg: jax.random.categorical(k, lg))(split[:, 1],
+                                                         masked)
+        tokens = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        return tokens, next_keys
 
 
 class _LoopHandle:

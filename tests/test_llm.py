@@ -89,6 +89,77 @@ def test_sampling_determinism_and_greedy_equivalence(params):
     assert topk1 == greedy  # top_k=1 collapses to argmax
 
 
+# Seeded streams of the tiny config as the engine gave them BEFORE the
+# sampling keys moved onto the device (PR 25; taken from commit 2381737
+# with the bucketed and the chunked prefill, which agree): one request,
+# temperature 0.8 / top_k 40 / top_p 0.95, by seed; and a mixed batch —
+# one greedy, two sampled, three lengths.
+PINNED = {
+    123: [145, 251, 152, 167, 51, 175, 90, 24, 167, 191, 165, 155],
+    7: [152, 178, 135, 15, 186, 19, 52, 57, 110, 138, 115, 15],
+}
+MIXED = [
+    ([5, 9, 17, 3, 88, 41, 12, 13, 14, 15, 16], None, 7,
+     [202, 150, 114, 117, 243, 232, 194]),
+    ([44, 55, 66], 11, 12,
+     [48, 97, 64, 165, 2, 172, 253, 42, 172, 0, 22, 32]),
+    ([7, 8, 9, 10, 11], 99, 9,
+     [206, 100, 29, 158, 23, 41, 12, 34, 222]),
+]
+
+
+def _pinned_engine(params, chunk, slots=4):
+    return LLMEngine(CFG, params, slots=slots, max_seq=96,
+                     prefill_chunk_tokens=chunk)
+
+
+def _sampling(seed, n):
+    """Greedy for ``seed=None``, else the pinned streams' parameters."""
+    if seed is None:
+        return SamplingParams(max_tokens=n)
+    return SamplingParams(max_tokens=n, temperature=0.8, top_k=40,
+                          top_p=0.95, seed=seed)
+
+
+def _run_all(eng, requests):
+    rids = [eng.add_request(list(p), sp, admit=False) for p, sp in requests]
+    outs = {}
+    while eng.has_unfinished():
+        for out in eng.step():
+            outs[out.request_id] = out
+    return [outs[r].token_ids for r in rids]
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("seed", sorted(PINNED))
+def test_seeded_stream_is_the_pinned_one(params, seed, chunk):
+    got = _pinned_engine(params, chunk).generate([[10, 20, 30]],
+                                                 _sampling(seed, 12))
+    assert got[0].token_ids == PINNED[seed]
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+def test_mixed_batch_streams_are_the_pinned_ones(params, chunk):
+    got = _run_all(_pinned_engine(params, chunk),
+                   [(p, _sampling(seed, n)) for p, seed, n, _ in MIXED])
+    assert got == [want for *_, want in MIXED]
+
+
+@pytest.mark.parametrize("which", range(len(MIXED)))
+def test_stream_depends_on_neither_slot_nor_neighbours(params, which):
+    """Alone in a one-slot engine, and last into a full batch of four
+    behind three sampled neighbours (so in another slot): the same ids
+    as in the mixed batch."""
+    prompt, seed, n, want = MIXED[which]
+    alone = _pinned_engine(params, 8, slots=1)
+    assert _run_all(alone, [(prompt, _sampling(seed, n))]) == [want]
+    neighbours = [([60 + i, 61, 62], _sampling(1000 + i, 16))
+                  for i in range(3)]
+    full = _run_all(_pinned_engine(params, 8),
+                    neighbours + [(prompt, _sampling(seed, n))])
+    assert full[-1] == want
+
+
 def test_prompt_longer_than_bucket(params):
     engine = LLMEngine(CFG, params, slots=1, max_seq=128)
     prompt = list(np.random.RandomState(0).randint(1, 200, 50))

@@ -60,9 +60,9 @@ def _spans(trace_id, name=None):
 
 
 def test_counters_are_deterministic_for_a_fixed_batch(params):
-    """Today's reading, documented: every decode step reads one key per
-    active slot and then the tokens (active + 1), every prompt's end
-    reads its first token (1)."""
+    """The reading, documented: every decode step reads its tokens
+    (1) — the sampling keys stay on the device (PR 25) — and every
+    prompt's end reads its first token (1)."""
     readings = []
     for _ in range(2):
         eng = _engine(params)
@@ -75,8 +75,7 @@ def test_counters_are_deterministic_for_a_fixed_batch(params):
     assert first["chunks"] == 4 and first["chunk_tokens"] == 19
     # the first token of a prompt comes from its last chunk
     assert first["decode_slots"] == first["tokens_generated"] == 3 * 5
-    assert first["d2h_syncs"] == (first["decode_slots"]
-                                  + first["decode_steps"] + len(PROMPTS))
+    assert first["d2h_syncs"] == first["decode_steps"] + len(PROMPTS)
     assert 0 < first["decode_steps"] <= first["steps"]
     assert first["steps"] <= first["decode_steps"] + first["chunks"]
 
@@ -96,7 +95,39 @@ def test_blocked_time_is_counted_once_and_by_phase(params):
     assert 0 < by_phase <= stats["block_s"]  # the eviction ran in no phase
     for phase in PHASES:
         assert stats[f"block_{phase}_s"] <= stats[f"phase_{phase}_s"] + 1e-9
-    assert stats["block_fetch_s"] > 0 and stats["block_sample_s"] > 0
+    # a decode step blocks for its tokens, a prompt's end for its first
+    # token; sampling only dispatches
+    assert stats["block_fetch_s"] > 0 and stats["block_chunk_s"] > 0
+    assert stats["block_sample_s"] == 0
+
+
+def test_full_batch_decode_reads_once_a_step_and_splits_no_key_eagerly(
+        params, monkeypatch):
+    """N decode steps of a full batch, half of it sampled: ``d2h_syncs``
+    grows by exactly N, all of it through ``_PhaseRecorder.to_host`` in
+    ``fetch``, and no eager ``jax.random.split`` runs (the keys advance
+    inside the jitted sampler, traced long before)."""
+    eng = _engine(params)
+    for i in range(eng.slots):
+        eng.add_request([3 + i, 9, 17 + i], SamplingParams(
+            max_tokens=40, temperature=0.8 * (i % 2), seed=i), admit=False)
+    while len(eng._active) < eng.slots:
+        eng.step()
+    eng.step()                               # one step with the batch full
+    splits, reads = [], []
+    real_split, real_read = jax.random.split, eng._rec.to_host
+    monkeypatch.setattr(jax.random, "split",
+                        lambda *a, **k: splits.append(1) or real_split(*a, **k))
+    monkeypatch.setattr(eng._rec, "to_host",
+                        lambda v: reads.append(eng._rec._phase)
+                        or real_read(v))
+    before = dict(eng.stats)
+    for _ in range(8):
+        eng.step()
+    assert eng.stats["decode_steps"] - before["decode_steps"] == 8
+    assert eng.stats["decode_slots"] - before["decode_slots"] == 8 * eng.slots
+    assert eng.stats["d2h_syncs"] - before["d2h_syncs"] == 8
+    assert reads == ["fetch"] * 8 and splits == []
 
 
 def test_phases_tile_the_loop_and_stats_keep_their_keys(params):
