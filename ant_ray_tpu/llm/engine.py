@@ -334,6 +334,14 @@ class LLMEngine:
                       "restore_wait_s": 0.0, "restore_failures": 0,
                       "pressure_evictions": 0, "idle_evictions": 0}
         self._rec = _PhaseRecorder(jax, self.stats)
+        # A routed model's routing counters (llama.ROUTING_COUNTERS):
+        # the step programs add to them on the device, in the cache,
+        # and a decode step's one read brings them along with its
+        # tokens.  ``_routing_seen`` is that array as last read.
+        if self.config.num_experts:
+            self.stats.update(dict.fromkeys(llama.ROUTING_COUNTERS, 0))
+            self._routing_seen = np.zeros(
+                (len(llama.ROUTING_COUNTERS),), np.uint32)
 
         cfg = self.config
         eng_mesh = self.mesh
@@ -364,6 +372,7 @@ class LLMEngine:
 
             slot = jnp.asarray(slot, jnp.int32)
             return {
+                **cache,
                 "k": lax.dynamic_update_slice(
                     cache["k"], k[:, None], (0, slot, 0, 0, 0)),
                 "v": lax.dynamic_update_slice(
@@ -415,10 +424,8 @@ class LLMEngine:
         kv = NamedSharding(mesh, P(None, None, None, "tp", None))
         rep = NamedSharding(mesh, P())
         self.cache = {
-            "k": jax.device_put(self.cache["k"], kv),
-            "v": jax.device_put(self.cache["v"], kv),
-            "length": jax.device_put(self.cache["length"], rep),
-        }
+            name: jax.device_put(x, kv if name in ("k", "v") else rep)
+            for name, x in self.cache.items()}
         self._keys = jax.device_put(self._keys, rep)
 
     # ------------------------------------------------------------ public
@@ -825,6 +832,8 @@ class LLMEngine:
         rec.enter("fetch")
         toks = rec.to_host(sampled)
         rec.enter("emit")
+        if self.config.num_experts:
+            self._note_routing(toks[self.slots:])
         self._decode_since_chunk += 1
         for slot, seq in list(self._active.items()):
             # this call wrote seq.last_tok's K/V at position kv_len
@@ -835,6 +844,16 @@ class LLMEngine:
             if seq.slot >= 0:
                 seq.last_tok = tok
                 self._last_np[slot] = tok
+
+    def _note_routing(self, counters):
+        """``counters``: the device's running routing counters as they
+        came with a step's tokens (uint32 bits in int32; they wrap) —
+        what was added since the last reading goes to ``stats``."""
+        now = counters.view(np.uint32)
+        for name, more in zip(self._llama.ROUTING_COUNTERS,
+                              (now - self._routing_seen).tolist()):
+            self.stats[name] += more
+        self._routing_seen = now
 
     def _note_dispatch(self, seq: _Seq):
         """A prefill program for ``seq`` was dispatched: the iteration
@@ -1154,16 +1173,20 @@ class LLMEngine:
                                   jnp.asarray(top_ks, jnp.int32),
                                   jnp.asarray(top_ps, jnp.float32))
         sampled, self._keys = self._sample_jit(
-            logits, self._keys, active, *self._sampling_dev)
+            logits, self._keys, active, *self._sampling_dev,
+            self.cache.get("routing"))
         return sampled
 
-    def _sample_batch(self, logits, keys, active, temps, top_ks, top_ps):
+    def _sample_batch(self, logits, keys, active, temps, top_ks, top_ps,
+                      routing=None):
         """Vectorized per-slot sampling: greedy when temperature == 0,
         else temperature softmax with optional top-k / top-p (nucleus)
         filtering — all branch-free for XLA.  Every row of ``keys``
         (uint32 (n, 2)) is split as the eager ``jax.random.split``
         would: the second half samples, the first half is the row's
         next key where ``active``, and an inactive row keeps its key.
+        ``routing`` (a routed model's counters, uint32) is appended to
+        the tokens bit for bit, so that the step's one read brings both.
         Returns ``(tokens, keys)``."""
         jax, jnp = self._jax, self._jnp
         vocab = logits.shape[-1]
@@ -1189,6 +1212,9 @@ class LLMEngine:
             lambda k, lg: jax.random.categorical(k, lg))(split[:, 1],
                                                          masked)
         tokens = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        if routing is not None:
+            tokens = jnp.concatenate(
+                [tokens, jax.lax.bitcast_convert_type(routing, jnp.int32)])
         return tokens, next_keys
 
 

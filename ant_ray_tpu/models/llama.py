@@ -41,12 +41,17 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     tie_embeddings: bool = False
-    # Mixture-of-experts MLP (0 = dense).  Experts shard over the mesh's
-    # ``ep`` axis; routing is dense top-k dispatch (static shapes — the
-    # XLA-friendly formulation; expert weights never leave their shard,
-    # the combine einsum's contraction inserts the psum over ep).
+    # Mixture-of-experts MLP (0 = dense): ``_routed_mlp`` sorts the
+    # (token, expert) assignments by expert and runs one grouped product
+    # over them, so the work grows with ``experts_per_token``, not with
+    # ``num_experts``.  Experts shard over the mesh's ``ep`` axis.
     num_experts: int = 0
     experts_per_token: int = 2
+    # Gates are the top-k of a softmax over ALL experts; True divides
+    # them by their sum (Mixtral), False leaves them as they are (OLMoE).
+    norm_topk_prob: bool = True
+    # RMSNorm over the whole q and k projections, before RoPE (OLMoE).
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -65,6 +70,8 @@ class LlamaConfig:
             + self.n_heads * self.head_dim * self.dim        # wo
             + mlp
             + 2 * self.dim                                   # norms
+            + ((self.n_heads + self.n_kv_heads) * self.head_dim
+               if self.qk_norm else 0)                       # q, k norms
         )
         p += self.n_layers * per_layer + self.dim            # final norm
         if not self.tie_embeddings:
@@ -93,6 +100,13 @@ CONFIGS: dict[str, LlamaConfig] = {
         vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
         mlp_dim=128, max_seq=512, dtype=jnp.float32,
         num_experts=4, experts_per_token=2),
+    # OLMoE's block at test size: 8 experts, 2 a token, gates left as
+    # the softmax gave them, RMSNorm over the whole q and k projections
+    "olmoe-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        mlp_dim=32, max_seq=512, dtype=jnp.float32,
+        num_experts=8, experts_per_token=2, norm_topk_prob=False,
+        qk_norm=True),
 }
 
 
@@ -124,6 +138,9 @@ def param_shapes(config: LlamaConfig) -> dict:
             "wo": (c.n_layers, c.n_heads * hd, c.dim),
             "ln_mlp": (c.n_layers, c.dim),
             **mlp_shapes,
+            **({"q_norm": (c.n_layers, c.n_heads * hd),
+                "k_norm": (c.n_layers, c.n_kv_heads * hd)}
+               if c.qk_norm else {}),
         },
         "norm_f": (c.dim,),
         **({} if config.tie_embeddings else
@@ -156,6 +173,8 @@ def param_logical_dims(config: LlamaConfig) -> dict:
             "wo": (None, "heads_flat", "embed_param"),
             "ln_mlp": (None, "norm"),
             **mlp_dims,
+            **({"q_norm": (None, "norm"), "k_norm": (None, "norm")}
+               if config.qk_norm else {}),
         },
         "norm_f": ("norm",),
     }
@@ -177,22 +196,21 @@ def llama_rules() -> dict:
 
 
 def init_params(config: LlamaConfig, key) -> dict:
-    shapes = param_shapes(config)
-    flat, treedef = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(
-        x, tuple))
+    def is_leaf(x):
+        return isinstance(x, tuple)
+
+    flat, treedef = jax.tree.flatten(param_shapes(config), is_leaf=is_leaf)
+    dims = jax.tree.leaves(param_logical_dims(config), is_leaf=is_leaf)
     keys = jax.random.split(key, len(flat))
 
-    def _init(shape, k):
-        if len(shape) <= 2 and shape[-1] == config.dim and len(shape) == 1:
-            return jnp.ones(shape, config.dtype)             # final norm
-        if shape[-1] == config.dim and len(shape) == 2 and \
-                shape[0] == config.n_layers:
-            return jnp.ones(shape, config.dtype)             # layer norms
+    def _init(shape, logical, k):
+        if logical[-1] == "norm":
+            return jnp.ones(shape, config.dtype)
         scale = 0.02
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(
             config.dtype)
 
-    leaves = [_init(s, k) for s, k in zip(flat, keys)]
+    leaves = [_init(s, d, k) for s, d, k in zip(flat, dims, keys)]
     return jax.tree.unflatten(treedef, leaves)
 
 
@@ -221,8 +239,9 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     GPipe stage path (loss_fn_pp), and decode variants."""
     batch, seq, _ = x.shape
     h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-    xq = (h @ layer["wq"]).reshape(batch, seq, c.n_heads, c.head_dim)
-    xk = (h @ layer["wk"]).reshape(batch, seq, c.n_kv_heads, c.head_dim)
+    xq, xk = _qk_proj(layer, h, c)
+    xq = xq.reshape(batch, seq, c.n_heads, c.head_dim)
+    xk = xk.reshape(batch, seq, c.n_kv_heads, c.head_dim)
     xv = (h @ layer["wv"]).reshape(batch, seq, c.n_kv_heads, c.head_dim)
     xq = apply_rope(xq, cos, sin, positions)
     xk = apply_rope(xk, cos, sin, positions)
@@ -234,38 +253,91 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     x = constrain_act(x, ("batch", "seq", "embed"))
 
     h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-    if c.num_experts:
-        x = x + _moe_mlp(layer, h, c, constrain_act).astype(x.dtype)
-    else:
-        gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-        x = x + (gated @ layer["w_down"]).astype(x.dtype)
+    x = x + _mlp(layer, h, c)[0].astype(x.dtype)
     x = constrain_act(x, ("batch", "seq", "embed"))
     kv = (xk.astype(c.dtype), xv.astype(c.dtype)) if return_kv else None
     return x, kv
 
 
-def _moe_mlp(layer: dict, h, c: LlamaConfig, constrain_act):
-    """Top-k mixture-of-experts MLP with dense dispatch.
+def _qk_proj(layer: dict, h, c: LlamaConfig):
+    """The q and k projections of ``h`` (..., dim), still flat
+    (..., heads * head_dim): with ``qk_norm`` each is RMS-normalised
+    over its WHOLE width — all heads together, as OLMoE publishes it,
+    not head by head — before the caller splits heads and applies RoPE.
+    The one place q/k are made, for training, chunks and decode."""
+    xq, xk = h @ layer["wq"], h @ layer["wk"]
+    if c.qk_norm:
+        xq = rmsnorm(xq, layer["q_norm"], c.norm_eps)
+        xk = rmsnorm(xk, layer["k_norm"], c.norm_eps)
+    return xq, xk
 
-    Every expert runs on every token with static shapes (XLA-friendly; no
-    ragged gather), weighted by the router's top-k gates.  The experts
-    dimension shards over the mesh's ``ep`` axis — expert weights stay on
-    their shard and the final combine einsum (contraction over e) is
-    where XLA inserts the psum across ep.
+
+def _mlp(layer: dict, h, c: LlamaConfig, index=None):
+    """The block's feed-forward on ``h`` (..., dim): dense SwiGLU, or
+    the routed experts.  Returns ``(out, load)``; ``load`` is the
+    (num_experts,) int32 count of rows each expert was given, None for
+    a dense model.  The one MLP of training, chunks and decode."""
+    if c.num_experts:
+        return _routed_mlp(layer, h, c, index)
+    gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
+    return gated @ layer["w_down"], None
+
+
+def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
+    """Top-k mixture of experts; every token is computed by its k
+    experts only, and none is dropped.
+
+    The router's probabilities are a float32 softmax over ALL experts;
+    the gates are its k largest (divided by their sum only when
+    ``norm_topk_prob``).  The tokens * k (token, expert) assignments are
+    sorted by expert, so each expert's rows lie together, and
+    ``lax.ragged_dot`` multiplies each run of rows with its expert's
+    matrix: a grouped product whose operations are those of k experts a
+    token, and which reads an expert's weights only if it has a row.
+    On the TPU XLA compiles it to a grouped-matmul kernel; elsewhere to
+    masked dense products (the same values).  The rows then go back to
+    token order and are summed under their float32 gates.  Shapes are
+    static (tokens * k rows whatever the routing), so one formulation
+    serves the training step, a prefill chunk and a decode step, and
+    differentiates as written.  With experts sharded over ``ep`` the
+    partitioner splits the grouped product by expert.
+
+    ``layer``'s expert matrices are one layer's (experts, in, out), as
+    a scan over the stacked layers slices them — or, with ``index``,
+    the whole stack's (layers, experts, in, out), of which layer
+    ``index`` (traced) is meant: the stack is then read as layers *
+    experts groups, all empty but that layer's.  The serving bodies do
+    so, because a slice of the stack cannot be fused into the grouped
+    kernel's operand: sliced, every step would first copy every
+    expert's weights, hit or not.
     """
-    router_logits = h @ layer["router"]                    # (b, s, E)
-    top_vals, top_idx = lax.top_k(router_logits, c.experts_per_token)
-    gates = jax.nn.softmax(top_vals, axis=-1)              # (b, s, k)
-    # Scatter the top-k gates back to a dense (b, s, E) weight map.
-    weights = jnp.sum(
-        jax.nn.one_hot(top_idx, c.num_experts, dtype=h.dtype)
-        * gates[..., None].astype(h.dtype), axis=-2)
-    ge = jnp.einsum("bsd,edm->ebsm", h, layer["w_gate"])   # (E, b, s, m)
-    ue = jnp.einsum("bsd,edm->ebsm", h, layer["w_up"])
-    oe = jnp.einsum("ebsm,emd->ebsd", jax.nn.silu(ge) * ue,
-                    layer["w_down"])
-    oe = constrain_act(oe, ("experts", "batch", "seq", "embed"))
-    return jnp.einsum("ebsd,bse->bsd", oe, weights)
+    lead, dim = h.shape[:-1], h.shape[-1]
+    k, n_exp = c.experts_per_token, c.num_experts
+    with jax.named_scope("moe"):
+        x = h.reshape(-1, dim)
+        probs = jax.nn.softmax(jnp.dot(
+            x, layer["router"], preferred_element_type=jnp.float32), axis=-1)
+        gates, experts = lax.top_k(probs, k)               # (tokens, k)
+        if c.norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        experts = experts.reshape(-1)                      # (tokens * k,)
+        order = jnp.argsort(experts)                       # stable
+        load = jnp.zeros((n_exp,), jnp.int32).at[experts].add(1)
+        rows = x[order // k]                               # sorted by expert
+        sizes = load if index is None else lax.dynamic_update_slice(
+            jnp.zeros((layer["w_down"].shape[0] * n_exp,), jnp.int32),
+            load, (index * n_exp,))
+
+        def grouped(a, w):
+            return lax.ragged_dot(a, w.reshape(-1, *w.shape[-2:]), sizes,
+                                  preferred_element_type=jnp.float32)
+
+        gated = jax.nn.silu(grouped(rows, layer["w_gate"])) * grouped(
+            rows, layer["w_up"])
+        out = grouped(gated.astype(h.dtype), layer["w_down"])
+        out = out[jnp.argsort(order)].reshape(-1, k, dim)  # token order
+        out = jnp.sum(out * gates[..., None], axis=1)
+    return out.astype(h.dtype).reshape(*lead, dim), load
 
 
 def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
@@ -444,9 +516,12 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
 
 
 def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
-    """Training FLOPs/token (6·N matmul + attention quadratic term)."""
+    """Training FLOPs/token (6·N matmul + attention quadratic term); of
+    a routed model's experts N holds the k a token multiplies with."""
     c = config
-    matmul = 6 * c.num_params()
+    idle = max(c.num_experts - c.experts_per_token, 0)
+    matmul = 6 * (c.num_params()
+                  - c.n_layers * idle * 3 * c.dim * c.mlp_dim)
     attn = 12 * c.n_layers * c.head_dim * c.n_heads * seq_len
     return matmul + attn
 
@@ -459,16 +534,57 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
 
 def init_kv_cache(config: LlamaConfig, slots: int,
                   max_seq: int | None = None) -> dict:
-    """Per-slot dense KV slabs: (layers, slots, max_seq, kv_heads, hd)."""
+    """Per-slot dense KV slabs: (layers, slots, max_seq, kv_heads, hd).
+    A routed model's cache also carries ``routing``, the step programs'
+    running counters (``ROUTING_COUNTERS``)."""
     c = config
     ms = max_seq or c.max_seq
     shape = (c.n_layers, slots, ms, c.n_kv_heads, c.head_dim)
-    return {
+    cache = {
         "k": jnp.zeros(shape, c.dtype),
         "v": jnp.zeros(shape, c.dtype),
         # tokens already written per slot (== next write position)
         "length": jnp.zeros((slots,), jnp.int32),
     }
+    if c.num_experts:
+        cache["routing"] = jnp.zeros((len(ROUTING_COUNTERS),), jnp.uint32)
+    return cache
+
+
+# What ``prefill_chunk_into_cache`` and ``decode_step`` count of a routed
+# model's routing, summed over layers and executions in
+# ``cache["routing"]`` (uint32, wraps; a reader takes differences).  They
+# count the rows the program computed, padded and idle ones included:
+# that is what decides which expert weights a step reads.
+ROUTING_COUNTERS = (
+    "moe_assignments",    # (row, expert) pairs computed
+    "moe_experts_hit",    # experts given at least one row
+    "moe_expert_slots",   # experts there were: num_experts a layer
+    "moe_load_max",       # rows of each layer's busiest expert, summed
+)
+
+
+def _hoist_experts(layers: dict, c: LlamaConfig):
+    """The stacked layers as a serving body's scan takes them: ``(the
+    leaves it slices layer by layer, the expert matrices it closes over
+    whole, the layer indices it scans beside them)`` — see
+    ``_routed_mlp`` on why; a dense model's layers are all sliced."""
+    if not c.num_experts:
+        return layers, {}, None
+    whole = {name: layers[name] for name in ("w_gate", "w_up", "w_down")}
+    sliced = {name: leaf for name, leaf in layers.items()
+              if name not in whole}
+    return sliced, whole, jnp.arange(c.n_layers)
+
+
+def _count_routing(cache: dict, loads) -> dict:
+    """``loads``: (layers, num_experts) rows per expert of one
+    execution, None for a dense model -> the cache entries to carry."""
+    if loads is None:
+        return {}
+    seen = jnp.stack([jnp.sum(loads), jnp.sum(loads > 0), loads.size,
+                      jnp.sum(jnp.max(loads, axis=-1))])
+    return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
 
 
 def prefill_into_cache(params: dict, tokens, cache: dict, slot,
@@ -540,11 +656,14 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     pc = cos[rope_pos][:, None, :]               # (chunk, 1, hd/2)
     ps = sin[rope_pos][:, None, :]
 
+    layers, experts, index = _hoist_experts(params["layers"], c)
+
     def block(x, scanned):
-        layer, ck_all, cv_all = scanned          # (slots, ms, kvh, hd)
+        layer, ck_all, cv_all, i = scanned       # (slots, ms, kvh, hd)
         h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-        xq = (h @ layer["wq"]).reshape(chunk, c.n_heads, c.head_dim)
-        xk = (h @ layer["wk"]).reshape(chunk, c.n_kv_heads, c.head_dim)
+        xq, xk = _qk_proj(layer, h, c)
+        xq = xq.reshape(chunk, c.n_heads, c.head_dim)
+        xk = xk.reshape(chunk, c.n_kv_heads, c.head_dim)
         xv = (h @ layer["wv"]).reshape(chunk, c.n_kv_heads, c.head_dim)
         xq = _rope_one(xq, pc, ps)
         xk = _rope_one(xk, pc, ps)
@@ -566,22 +685,22 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
         out = out.reshape(chunk, c.n_heads * c.head_dim).astype(x.dtype)
         x = x + (out @ layer["wo"]).astype(x.dtype)
         h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-        gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-        x = x + (gated @ layer["w_down"]).astype(x.dtype)
+        out, load = _mlp({**layer, **experts}, h, c, i)
+        x = x + out.astype(x.dtype)
         ck_all = lax.dynamic_update_slice(ck_all, ck[None],
                                           (slot, 0, 0, 0))
         cv_all = lax.dynamic_update_slice(cv_all, cv[None],
                                           (slot, 0, 0, 0))
-        return x, (ck_all, cv_all)
+        return x, (ck_all, cv_all, load)
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
-    x, (new_k, new_v) = lax.scan(
-        block, x, (params["layers"], cache["k"], cache["v"]))
+    x, (new_k, new_v, loads) = lax.scan(
+        block, x, (layers, cache["k"], cache["v"], index))
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x_last @ head.astype(c.dtype)).astype(jnp.float32)
-    cache = {"k": new_k, "v": new_v,
+    cache = {**_count_routing(cache, loads), "k": new_k, "v": new_v,
              "length": cache["length"].at[slot].set(start + chunk_len)}
     return logits, cache
 
@@ -612,11 +731,14 @@ def decode_step(params: dict, last_tokens, cache: dict,
                                 jnp.float32)
     group = c.n_heads // c.n_kv_heads
 
+    layers, experts, index = _hoist_experts(params["layers"], c)
+
     def block(x, scanned):
-        layer, ck, cv = scanned                 # ck/cv: (slots, ms, kvh, hd)
+        layer, ck, cv, i = scanned              # ck/cv: (slots, ms, kvh, hd)
         h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-        xq = (h @ layer["wq"]).reshape(slots, c.n_heads, c.head_dim)
-        xk = (h @ layer["wk"]).reshape(slots, c.n_kv_heads, c.head_dim)
+        xq, xk = _qk_proj(layer, h, c)
+        xq = xq.reshape(slots, c.n_heads, c.head_dim)
+        xk = xk.reshape(slots, c.n_kv_heads, c.head_dim)
         xv = (h @ layer["wv"]).reshape(slots, c.n_kv_heads, c.head_dim)
         # rope at each slot's own position
         pc = cos[pos][:, None, :]               # (slots, 1, hd/2)
@@ -640,13 +762,13 @@ def decode_step(params: dict, last_tokens, cache: dict,
         out = out.reshape(slots, c.n_heads * c.head_dim).astype(x.dtype)
         x = x + (out @ layer["wo"]).astype(x.dtype)
         h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-        gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-        x = x + (gated @ layer["w_down"]).astype(x.dtype)
-        return x, (ck, cv)
+        out, load = _mlp({**layer, **experts}, h, c, i)
+        x = x + out.astype(x.dtype)
+        return x, (ck, cv, load)
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
-    x, (new_k, new_v) = lax.scan(
-        block, x, (params["layers"], cache["k"], cache["v"]))
+    x, (new_k, new_v, loads) = lax.scan(
+        block, x, (layers, cache["k"], cache["v"], index))
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
@@ -657,7 +779,8 @@ def decode_step(params: dict, last_tokens, cache: dict,
     new_len = jnp.minimum(cache["length"] + 1, jnp.int32(max_seq))
     if active is not None:
         new_len = jnp.where(active, new_len, cache["length"])
-    cache = {"k": new_k, "v": new_v, "length": new_len}
+    cache = {**_count_routing(cache, loads), "k": new_k, "v": new_v,
+             "length": new_len}
     return logits, cache
 
 

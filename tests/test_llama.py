@@ -117,22 +117,26 @@ def test_greedy_generate(tiny_params):
     assert out.shape == (1, 12)
 
 
-MOE_CFG = llama.CONFIGS["moe-tiny"]
+# The routed models: Mixtral-style (gates renormalised) and OLMoE-style
+# (gates as the softmax gave them, QK-norm).  One routed MLP serves both.
+ROUTED = ["moe-tiny", "olmoe-tiny"]
 
 
-@pytest.fixture(scope="module")
-def moe_params():
-    return llama.init_params(MOE_CFG, jax.random.PRNGKey(1))
+@pytest.fixture(scope="module", params=ROUTED)
+def routed(request):
+    cfg = llama.CONFIGS[request.param]
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(1))
 
 
-def test_moe_forward_and_grad(moe_params):
+def test_moe_forward_and_grad(routed):
+    cfg, moe_params = routed
     tokens = _tokens()
-    logits = llama.forward(moe_params, tokens, MOE_CFG)
-    assert logits.shape == (2, 64, MOE_CFG.vocab_size)
+    logits = llama.forward(moe_params, tokens, cfg)
+    assert logits.shape == (2, 64, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits)).all()
     batch = {"tokens": _tokens(2, 65)}
     loss, grads = jax.value_and_grad(llama.loss_fn)(
-        moe_params, batch, MOE_CFG)
+        moe_params, batch, cfg)
     assert np.isfinite(float(loss))
     flat = jax.tree.leaves(grads)
     assert all(np.isfinite(np.asarray(g)).all() for g in flat)
@@ -140,19 +144,97 @@ def test_moe_forward_and_grad(moe_params):
     assert float(jnp.abs(grads["layers"]["router"]).sum()) > 0
 
 
-def test_moe_expert_sharded_matches_unsharded(moe_params):
+def test_moe_expert_sharded_matches_unsharded(routed):
     """Expert-parallel (ep) sharded forward equals the base — the ep
     axis is real, not decorative."""
+    cfg, moe_params = routed
     mesh = build_mesh(MeshConfig(ep=2, tp=2, dp=-1))
     sharded_params = jax.device_put(
-        moe_params, llama.param_shardings(MOE_CFG, mesh))
+        moe_params, llama.param_shardings(cfg, mesh))
     tokens = _tokens()
-    base = llama.forward(moe_params, tokens, MOE_CFG)
+    base = llama.forward(moe_params, tokens, cfg)
     sharded = jax.jit(
-        lambda p, t: llama.forward(p, t, MOE_CFG, mesh=mesh))(
+        lambda p, t: llama.forward(p, t, cfg, mesh=mesh))(
             sharded_params, tokens)
     np.testing.assert_allclose(np.asarray(base), np.asarray(sharded),
                                atol=2e-4, rtol=2e-4)
+
+
+def _per_token_loop(layer, h, cfg):
+    """The routed MLP written the slow way: for each token, its k most
+    probable experts one by one (numpy, float64)."""
+    h = np.asarray(h, np.float64)
+    w = {name: np.asarray(layer[name], np.float64)
+         for name in ("router", "w_gate", "w_up", "w_down")}
+    out = np.zeros_like(h)
+    load = np.zeros((cfg.num_experts,), np.int64)
+    for t, x in enumerate(h):
+        logits = x @ w["router"]
+        probs = np.exp(logits - logits.max())
+        probs /= probs.sum()
+        chosen = np.argsort(-probs, kind="stable")[:cfg.experts_per_token]
+        gates = probs[chosen]
+        if cfg.norm_topk_prob:
+            gates = gates / gates.sum()
+        for e, g in zip(chosen, gates):
+            a = x @ w["w_gate"][e]
+            out[t] += g * ((a / (1 + np.exp(-a)) * (x @ w["w_up"][e]))
+                           @ w["w_down"][e])
+            load[e] += 1
+    return out, load
+
+
+def _routed_case(name):
+    """(config, one layer's weights, activations) of a routing case."""
+    import dataclasses
+
+    base, steer = {
+        "mixtral-style": ("moe-tiny", None),
+        "olmoe-style": ("olmoe-tiny", None),
+        "one-expert-takes-all": ("olmoe-tiny", +1),
+        "one-expert-gets-none": ("olmoe-tiny", -1),
+        "three-of-eight": ("olmoe-tiny", None),
+    }[name]
+    cfg = llama.CONFIGS[base]
+    if name == "one-expert-takes-all":
+        cfg = dataclasses.replace(cfg, experts_per_token=1)
+    if name == "three-of-eight":
+        cfg = dataclasses.replace(cfg, experts_per_token=3)
+    params = llama.init_params(cfg, jax.random.PRNGKey(3))
+    layer = {k: v[0] * 8 for k, v in params["layers"].items()}
+    h = jax.random.normal(jax.random.PRNGKey(4), (3, 7, cfg.dim))
+    if steer:
+        # feature 0 is +4 on every token and the router reads it into
+        # expert 5 alone: every token's first choice, or nobody's.
+        h = h.at[..., 0].set(4.0)
+        layer["router"] = layer["router"].at[0].set(0.0).at[0, 5].set(
+            steer * 8.0)
+    return cfg, layer, h
+
+
+@pytest.mark.parametrize("name", [
+    "mixtral-style", "olmoe-style", "one-expert-takes-all",
+    "one-expert-gets-none", "three-of-eight"])
+def test_routed_mlp_equals_the_per_token_loop(name):
+    """Sort, grouped product, unsort and combine against the loop over
+    each token's experts, values and gradients' reach; float32 against
+    float64, so 1e-5 relative is rounding and any routing or gate slip
+    is orders above it."""
+    cfg, layer, h = _routed_case(name)
+    got, load = jax.jit(lambda l, x: llama._routed_mlp(l, x, cfg))(layer, h)
+    want, want_load = _per_token_loop(layer, h.reshape(-1, cfg.dim), cfg)
+    np.testing.assert_array_equal(np.asarray(load), want_load)
+    assert int(load.sum()) == 21 * cfg.experts_per_token
+    if name == "one-expert-takes-all":
+        assert int(load[5]) == 21
+    if name == "one-expert-gets-none":
+        assert int(load[5]) == 0
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, cfg.dim), want,
+                               rtol=1e-5, atol=1e-6)
+    grads = jax.grad(lambda l: jnp.sum(
+        llama._routed_mlp(l, h, cfg)[0] ** 2))(layer)
+    hit = np.asarray(jnp.abs(grads["w_down"]).sum(axis=(1, 2)) > 0)
+    np.testing.assert_array_equal(hit, want_load > 0)
 
 
 def test_pp_loss_matches_dense_loss(tiny_params):

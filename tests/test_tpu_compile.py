@@ -134,6 +134,50 @@ def test_engine_prefill_chunk_compiles_for_v5e(v5e, engine_state):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
 
 
+# OLMoE-1B-7B's block at its published widths (two layers of it): 64
+# experts of 2048 x 1024, 8 a token, QK-norm.
+ROUTED = dataclasses.replace(
+    CFG, vocab_size=50304, dim=2048, n_layers=2, n_heads=16, n_kv_heads=16,
+    mlp_dim=1024, max_seq=4096, rope_theta=10000.0, num_experts=64,
+    experts_per_token=8, norm_topk_prob=False, qk_norm=True)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_routed_step_reads_the_expert_stack_in_place(v5e, program):
+    """The routed serving programs on the chip: ``lax.ragged_dot``
+    becomes the compiler's grouped-matmul kernel, and it is handed the
+    whole stack of expert matrices (layers x experts groups) — no
+    per-layer slice of 64 experts is ever materialised in front of it,
+    which would copy every expert's weights on every step."""
+    params = jax.eval_shape(
+        lambda: llama.init_params(ROUTED, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: llama.init_kv_cache(ROUTED, 16, 512))
+    params, cache = _on(v5e.devices[0], (params, cache))
+    tokens, scalar, active = _on(v5e.devices[0], (
+        jax.ShapeDtypeStruct((16 if program == "decode" else 64,),
+                             jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((16,), jnp.bool_)))
+    if program == "decode":
+        lowered = jax.jit(
+            lambda p, c, last, act: llama.decode_step(
+                p, last, c, ROUTED, active=act),
+            donate_argnums=(1,)).lower(params, cache, tokens, active)
+    else:
+        lowered = jax.jit(
+            lambda p, c, t, slot, start, n: llama.prefill_chunk_into_cache(
+                p, t, c, slot, start, n, ROUTED),
+            donate_argnums=(1,)).lower(params, cache, tokens, scalar,
+                                       scalar, scalar)
+    text = lowered.compile().as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "bf16[128,2048,1024]" in text          # the stack, as groups
+    for one_layers_experts in ("bf16[64,2048,1024]", "bf16[64,1024,2048]",
+                               "bf16[1,64,2048,1024]",
+                               "bf16[1,64,1024,2048]"):
+        assert one_layers_experts not in text
+
+
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
     """loss + grad under the fsdp=4 rule table on the described 2x2
     mesh (Llama-3.2-1B widths, 2 layers): the flash kernel runs per
