@@ -17,7 +17,12 @@ vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
 * **Dense per-slot KV slabs** (models/llama.py `init_kv_cache`) instead
   of paged KV: XLA cannot tile dynamic gather-heavy paging the way a
   CUDA kernel can, while dense slabs keep decode attention a plain
-  masked matmul on the MXU.  Slot reuse gives the same
+  masked matmul on the MXU.  That holds only while nothing copies the
+  slabs: a step reads every reserved position of them whatever is
+  valid, so reserved slots cost time as well as memory, and the step
+  programs update the donated cache in place (they carry it through
+  the layer loop; scanned over, it was copied about three times a
+  call).  Slot reuse gives the same
   admit-new-work-each-step behavior as paged attention's block reuse.
 * **Continuous batching**: each `step()` admits queued prompts, runs at
   most one prefill unit (a full bucketed prompt, or one chunk), then

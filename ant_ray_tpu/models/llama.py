@@ -530,13 +530,23 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
 # Serving-path primitives (ref capability: llm/_internal/serve/engines/
 # vllm — re-designed TPU-first: dense per-slot KV slabs with static
 # shapes instead of paged indirection, because XLA wants static shapes
-# and HBM slabs keep the decode matmuls MXU-friendly).
+# and HBM slabs keep the decode matmuls MXU-friendly).  The slabs are
+# cheap only while nothing copies them: scanned over as a layer loop's
+# inputs and outputs they were moved about three times a call (59 % of
+# the step programs' device time on a v5e); the step programs now carry
+# them through the loop and write the new rows in place.
 
 def init_kv_cache(config: LlamaConfig, slots: int,
                   max_seq: int | None = None) -> dict:
     """Per-slot dense KV slabs: (layers, slots, max_seq, kv_heads, hd).
     A routed model's cache also carries ``routing``, the step programs'
-    running counters (``ROUTING_COUNTERS``)."""
+    running counters (``ROUTING_COUNTERS``).
+
+    Whoever jits a step program owns these buffers and DONATES them
+    (``llm/engine.py``: ``donate_argnums=(1,)``): every leaf of the
+    cache a program returns is then the buffer it was given, rows
+    written where they lie, and the dict passed in is dead.  Without
+    the donation a call allocates and fills a second whole cache."""
     c = config
     ms = max_seq or c.max_seq
     shape = (c.n_layers, slots, ms, c.n_kv_heads, c.head_dim)
@@ -568,13 +578,15 @@ def _hoist_experts(layers: dict, c: LlamaConfig):
     """The stacked layers as a serving body's scan takes them: ``(the
     leaves it slices layer by layer, the expert matrices it closes over
     whole, the layer indices it scans beside them)`` — see
-    ``_routed_mlp`` on why; a dense model's layers are all sliced."""
+    ``_routed_mlp`` on why; a dense model's layers are all sliced.  The
+    index is also where a layer finds its part of the carried cache."""
+    index = jnp.arange(c.n_layers)
     if not c.num_experts:
-        return layers, {}, None
+        return layers, {}, index
     whole = {name: layers[name] for name in ("w_gate", "w_up", "w_down")}
     sliced = {name: leaf for name, leaf in layers.items()
               if name not in whole}
-    return sliced, whole, jnp.arange(c.n_layers)
+    return sliced, whole, index
 
 
 def _count_routing(cache: dict, loads) -> dict:
@@ -638,7 +650,8 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     """
     c = config
     chunk = tokens.shape[0]
-    max_seq = cache["k"].shape[2]
+    slab = cache["k"].shape[2:]                  # (max_seq, kvh, hd)
+    max_seq = slab[0]
     cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
                                 jnp.float32)
     group = c.n_heads // c.n_kv_heads
@@ -658,8 +671,9 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
 
     layers, experts, index = _hoist_experts(params["layers"], c)
 
-    def block(x, scanned):
-        layer, ck_all, cv_all, i = scanned       # (slots, ms, kvh, hd)
+    def block(carry, scanned):
+        x, ks, vs = carry                        # ks/vs: the whole cache
+        layer, i = scanned
         h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
         xq, xk = _qk_proj(layer, h, c)
         xq = xq.reshape(chunk, c.n_heads, c.head_dim)
@@ -667,12 +681,13 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
         xv = (h @ layer["wv"]).reshape(chunk, c.n_kv_heads, c.head_dim)
         xq = _rope_one(xq, pc, ps)
         xk = _rope_one(xk, pc, ps)
-        ck = lax.dynamic_index_in_dim(ck_all, slot, axis=0,
-                                      keepdims=False)  # (ms, kvh, hd)
-        cv = lax.dynamic_index_in_dim(cv_all, slot, axis=0,
-                                      keepdims=False)
-        ck = ck.at[write_pos].set(xk.astype(ck.dtype))
-        cv = cv.at[write_pos].set(xv.astype(cv.dtype))
+        # Write the chunk's rows where they lie, THEN read the slot's
+        # slab out of the carried cache (see ``decode_step``).
+        ks = ks.at[i, slot, write_pos].set(xk.astype(ks.dtype))
+        vs = vs.at[i, slot, write_pos].set(xv.astype(vs.dtype))
+        ck = lax.dynamic_slice(ks, (i, slot, 0, 0, 0),
+                               (1, 1) + slab)[0, 0]   # (ms, kvh, hd)
+        cv = lax.dynamic_slice(vs, (i, slot, 0, 0, 0), (1, 1) + slab)[0, 0]
         q = xq.reshape(chunk, c.n_kv_heads, group, c.head_dim)
         scores = jnp.einsum("ckgd,tkd->ckgt", q, ck,
                             preferred_element_type=jnp.float32)
@@ -687,15 +702,11 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
         h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
         out, load = _mlp({**layer, **experts}, h, c, i)
         x = x + out.astype(x.dtype)
-        ck_all = lax.dynamic_update_slice(ck_all, ck[None],
-                                          (slot, 0, 0, 0))
-        cv_all = lax.dynamic_update_slice(cv_all, cv[None],
-                                          (slot, 0, 0, 0))
-        return x, (ck_all, cv_all, load)
+        return (x, ks, vs), load
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
-    x, (new_k, new_v, loads) = lax.scan(
-        block, x, (layers, cache["k"], cache["v"], index))
+    (x, new_k, new_v), loads = lax.scan(
+        block, (x, cache["k"], cache["v"]), (layers, index))
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
@@ -733,8 +744,11 @@ def decode_step(params: dict, last_tokens, cache: dict,
 
     layers, experts, index = _hoist_experts(params["layers"], c)
 
-    def block(x, scanned):
-        layer, ck, cv, i = scanned              # ck/cv: (slots, ms, kvh, hd)
+    rows = jnp.arange(slots)
+
+    def block(carry, scanned):
+        x, ks, vs = carry                       # ks/vs: the whole cache
+        layer, i = scanned
         h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
         xq, xk = _qk_proj(layer, h, c)
         xq = xq.reshape(slots, c.n_heads, c.head_dim)
@@ -745,8 +759,19 @@ def decode_step(params: dict, last_tokens, cache: dict,
         ps = sin[pos][:, None, :]
         xq = _rope_one(xq, pc, ps)
         xk = _rope_one(xk, pc, ps)
-        ck = ck.at[jnp.arange(slots), write_pos].set(xk.astype(ck.dtype))
-        cv = cv.at[jnp.arange(slots), write_pos].set(xv.astype(cv.dtype))
+        # The cache travels as the loop's CARRY, which the compiler
+        # aliases to the donated input: one row per slot is written
+        # where it lies, and only THEN is the layer's slab sliced out
+        # of the carried array to feed the products.  As a scanned
+        # input and output of the loop the slabs are copied about
+        # three times a call; attending over the old slab with the new
+        # row beside it compiles to more temporaries and reorders the
+        # float32 sums.
+        ks = ks.at[i, rows, write_pos].set(xk.astype(ks.dtype))
+        vs = vs.at[i, rows, write_pos].set(xv.astype(vs.dtype))
+        ck = lax.dynamic_index_in_dim(ks, i, axis=0,
+                                      keepdims=False)  # (slots, ms, kvh, hd)
+        cv = lax.dynamic_index_in_dim(vs, i, axis=0, keepdims=False)
         # GQA attention against the slab, masked beyond each length.
         # bf16 inputs with fp32 accumulation keep the matmuls at full
         # MXU rate without an fp32 copy of the slab (see ops/attention).
@@ -764,11 +789,11 @@ def decode_step(params: dict, last_tokens, cache: dict,
         h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
         out, load = _mlp({**layer, **experts}, h, c, i)
         x = x + out.astype(x.dtype)
-        return x, (ck, cv, load)
+        return (x, ks, vs), load
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
-    x, (new_k, new_v, loads) = lax.scan(
-        block, x, (layers, cache["k"], cache["v"], index))
+    (x, new_k, new_v), loads = lax.scan(
+        block, (x, cache["k"], cache["v"]), (layers, index))
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)

@@ -14,6 +14,7 @@ read back).
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,46 +93,62 @@ def test_flash_backward_compiles_for_v5e(v5e, shape):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.fixture(scope="module")
-def engine_state(v5e):
-    """llama3-1b parameters and the serving cache (8 slots x 2048) as
-    shapes on one described chip."""
+def _tree_bytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def _compile_step(device, program, config, slots, max_seq):
+    """``decode`` or ``prefill_chunk`` as the engine jits it (the cache
+    donated) for one described chip -> (compiled, the shapes of the
+    parameters, of the cache)."""
     params = jax.eval_shape(
-        lambda: llama.init_params(CFG, jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(lambda: llama.init_kv_cache(CFG, 8, 2048))
-    return _on(v5e.devices[0], (params, cache))
+        lambda: llama.init_params(config, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(
+        lambda: llama.init_kv_cache(config, slots, max_seq))
+    params, cache = _on(device, (params, cache))
+    tokens, scalar, active = _on(device, (
+        jax.ShapeDtypeStruct((slots if program == "decode" else 64,),
+                             jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    if program == "decode":
+        lowered = jax.jit(
+            lambda p, c, last, act: llama.decode_step(
+                p, last, c, config, active=act),
+            donate_argnums=(1,)).lower(params, cache, tokens, active)
+    else:
+        lowered = jax.jit(
+            lambda p, c, t, slot, start, n: llama.prefill_chunk_into_cache(
+                p, t, c, slot, start, n, config),
+            donate_argnums=(1,)).lower(params, cache, tokens, scalar,
+                                       scalar, scalar)
+    return lowered.compile(), params, cache
 
 
-def test_engine_decode_step_compiles_for_v5e(v5e, engine_state):
-    params, cache = engine_state
-    last, active = _on(v5e.devices[0], (
-        jax.ShapeDtypeStruct((8,), jnp.int32),
-        jax.ShapeDtypeStruct((8,), jnp.bool_)))
-
-    def decode(params, cache, last_tokens, active):
-        return llama.decode_step(params, last_tokens, cache, CFG,
-                                 active=active)
-
-    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, cache, last, active).compile()
+def _fits_beside_one_cache(compiled, params, cache):
+    """An engine program needs the weights and ONE set of slabs.  The
+    stated margin is llama3-1b's: its heads are 64 wide, the chip keeps
+    such a cache with ``max_seq`` innermost, and the layer loop wants
+    ``head_dim`` innermost, padded to the 128 lanes — so the compiler
+    re-lays the whole cache on entry and exit (2 x the slabs' bytes of
+    temporaries, with the cache scanned over as with the cache
+    carried).  128-wide heads need no margin:
+    ``test_step_updates_the_cache_in_place``."""
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
+    slabs = _tree_bytes((cache["k"], cache["v"]))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < (
+        _tree_bytes(params) + slabs + 2 * slabs + (64 << 20))
 
 
-def test_engine_prefill_chunk_compiles_for_v5e(v5e, engine_state):
-    params, cache = engine_state
-    tokens, scalar = _on(v5e.devices[0], (
-        jax.ShapeDtypeStruct((64,), jnp.int32),
-        jax.ShapeDtypeStruct((), jnp.int32)))
+def test_engine_decode_step_compiles_for_v5e(v5e):
+    """llama3-1b, the serving cache at 8 slots x 2048."""
+    _fits_beside_one_cache(
+        *_compile_step(v5e.devices[0], "decode", CFG, 8, 2048))
 
-    def prefill_chunk(params, cache, tokens, slot, start, length):
-        return llama.prefill_chunk_into_cache(
-            params, tokens, cache, slot, start, length, CFG)
 
-    compiled = jax.jit(prefill_chunk, donate_argnums=(1,)).lower(
-        params, cache, tokens, scalar, scalar, scalar).compile()
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
+def test_engine_prefill_chunk_compiles_for_v5e(v5e):
+    _fits_beside_one_cache(
+        *_compile_step(v5e.devices[0], "prefill_chunk", CFG, 8, 2048))
 
 
 # OLMoE-1B-7B's block at its published widths (two layers of it): 64
@@ -149,33 +166,47 @@ def test_routed_step_reads_the_expert_stack_in_place(v5e, program):
     whole stack of expert matrices (layers x experts groups) — no
     per-layer slice of 64 experts is ever materialised in front of it,
     which would copy every expert's weights on every step."""
-    params = jax.eval_shape(
-        lambda: llama.init_params(ROUTED, jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(lambda: llama.init_kv_cache(ROUTED, 16, 512))
-    params, cache = _on(v5e.devices[0], (params, cache))
-    tokens, scalar, active = _on(v5e.devices[0], (
-        jax.ShapeDtypeStruct((16 if program == "decode" else 64,),
-                             jnp.int32),
-        jax.ShapeDtypeStruct((), jnp.int32),
-        jax.ShapeDtypeStruct((16,), jnp.bool_)))
-    if program == "decode":
-        lowered = jax.jit(
-            lambda p, c, last, act: llama.decode_step(
-                p, last, c, ROUTED, active=act),
-            donate_argnums=(1,)).lower(params, cache, tokens, active)
-    else:
-        lowered = jax.jit(
-            lambda p, c, t, slot, start, n: llama.prefill_chunk_into_cache(
-                p, t, c, slot, start, n, ROUTED),
-            donate_argnums=(1,)).lower(params, cache, tokens, scalar,
-                                       scalar, scalar)
-    text = lowered.compile().as_text()
+    text = _compile_step(v5e.devices[0], program, ROUTED, 16,
+                         512)[0].as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert "bf16[128,2048,1024]" in text          # the stack, as groups
     for one_layers_experts in ("bf16[64,2048,1024]", "bf16[64,1024,2048]",
                                "bf16[1,64,2048,1024]",
                                "bf16[1,64,1024,2048]"):
         assert one_layers_experts not in text
+
+
+# llama3-1b with its 2048 columns of attention as 16 heads of 128 (8 of
+# them KV heads: InternLM2-1.8B's attention), the head width of every
+# configuration the benchmark serves.
+DENSE_128 = dataclasses.replace(CFG, n_heads=16)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("config,slots,max_seq", [
+    pytest.param(DENSE_128, 8, 2048, id="dense"),
+    pytest.param(ROUTED, 16, 512, id="routed")])
+def test_step_updates_the_cache_in_place(v5e, program, config, slots,
+                                         max_seq):
+    """The step programs write their rows into the donated cache and
+    move nothing slab-sized: every leaf of the cache is aliased to its
+    output, the temporaries stay under ONE layer's K + V slabs (the
+    scanned-over form needed a second whole cache), and no ``copy`` or
+    ``dynamic-update-slice`` anywhere in the program produces an array
+    of the whole ``k`` / ``v`` shape — what has that shape is the row
+    scatter, in place.  (A layer's slab read by a ``dynamic-slice`` and
+    transposed inside a fusion is the attention's one read of it.)"""
+    compiled, _, cache = _compile_step(v5e.devices[0], program, config,
+                                       slots, max_seq)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    one_layer = _tree_bytes((cache["k"], cache["v"])) // config.n_layers
+    assert mem.temp_size_in_bytes < one_layer
+    whole = "bf16[" + ",".join(map(str, cache["k"].shape)) + "]"
+    moved = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if re.match(r"\s*(ROOT )?%?[\w.\-]+ = " + re.escape(whole)
+                         + r"\S* (copy|dynamic-update-slice)\(", line)]
+    assert not moved, moved
 
 
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
