@@ -234,29 +234,46 @@ def param_shardings(config: LlamaConfig, mesh) -> dict:
 # ---------------------------------------------------------------- forward
 
 def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
-                attend, constrain_act, *, return_kv: bool = False):
-    """One transformer block — shared by the scan path (forward), the
-    GPipe stage path (loss_fn_pp), and decode variants."""
-    batch, seq, _ = x.shape
+                attend, constrain_act, index=None):
+    """One transformer block on ``x`` (..., dim), the only place its
+    equations are written: training hands it (batch, seq, dim), a
+    prefill chunk and a decode step their rows, (chunk, dim) and
+    (slots, dim).  The three differ in how they attend:
+    ``attend(xq, xk, xv) -> (out, state)`` takes rotated queries and
+    keys and the values, heads split, and returns the attention output,
+    shaped as the queries, beside what the caller keeps of the layer —
+    nothing, its (xk, xv), or the KV cache with its rows written
+    (``_scan_layers``).  ``positions``: int32 of ``x``'s leading shape,
+    None for arange over the sequence.  ``index``: the layer's number
+    where ``layer`` holds the whole stack's expert matrices
+    (``_routed_mlp``).  Returns ``(x, state, load)``, ``load`` as
+    ``_mlp`` gives it."""
+    lead = x.shape[:-1]
     h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
     xq, xk = _qk_proj(layer, h, c)
-    xq = xq.reshape(batch, seq, c.n_heads, c.head_dim)
-    xk = xk.reshape(batch, seq, c.n_kv_heads, c.head_dim)
-    xv = (h @ layer["wv"]).reshape(batch, seq, c.n_kv_heads, c.head_dim)
+    xq = xq.reshape(*lead, c.n_heads, c.head_dim)
+    xk = xk.reshape(*lead, c.n_kv_heads, c.head_dim)
+    xv = (h @ layer["wv"]).reshape(*lead, c.n_kv_heads, c.head_dim)
     xq = apply_rope(xq, cos, sin, positions)
     xk = apply_rope(xk, cos, sin, positions)
     xq = constrain_act(xq, ("batch", "seq", "heads", "head_dim"))
     xk = constrain_act(xk, ("batch", "seq", "kv_heads", "head_dim"))
-    attn = attend(xq, xk, xv)
-    attn = attn.reshape(batch, seq, c.n_heads * c.head_dim)
+    attn, state = attend(xq, xk, xv)
+    attn = attn.reshape(*lead, c.n_heads * c.head_dim)
     x = x + (attn @ layer["wo"]).astype(x.dtype)
     x = constrain_act(x, ("batch", "seq", "embed"))
 
     h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-    x = x + _mlp(layer, h, c)[0].astype(x.dtype)
+    out, load = _mlp(layer, h, c, index)
+    x = x + out.astype(x.dtype)
     x = constrain_act(x, ("batch", "seq", "embed"))
-    kv = (xk.astype(c.dtype), xv.astype(c.dtype)) if return_kv else None
-    return x, kv
+    return x, state, load
+
+
+def _unconstrained(x, _dims):
+    """``apply_block``'s ``constrain_act`` where no mesh lays the
+    activations out."""
+    return x
 
 
 def _qk_proj(layer: dict, h, c: LlamaConfig):
@@ -306,10 +323,10 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
     a scan over the stacked layers slices them — or, with ``index``,
     the whole stack's (layers, experts, in, out), of which layer
     ``index`` (traced) is meant: the stack is then read as layers *
-    experts groups, all empty but that layer's.  The serving bodies do
-    so, because a slice of the stack cannot be fused into the grouped
-    kernel's operand: sliced, every step would first copy every
-    expert's weights, hit or not.
+    experts groups, all empty but that layer's.  The step programs do
+    so (``_scan_layers``), because a slice of the stack cannot be fused
+    into the grouped kernel's operand: sliced, every step would first
+    copy every expert's weights, hit or not.
     """
     lead, dim = h.shape[:-1], h.shape[-1]
     k, n_exp = c.experts_per_token, c.num_experts
@@ -351,7 +368,9 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     meshes use ring attention.
 
     ``return_kv=True`` additionally returns the per-layer K/V
-    (layers, b, s, kv_heads, hd) for cache insertion (serving prefill);
+    (layers, b, s, kv_heads, hd), which the block's attention hands back
+    as its state, for the bucketed prefill (``prefill_into_cache``) to
+    put into a slot;
     ``logits_at`` (traced scalar position) computes logits for that one
     position only — (b, vocab) — skipping the full-sequence lm-head
     matmul.
@@ -376,23 +395,28 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
     def attend(xq, xk, xv):
+        # no cache: the whole sequence attends over itself
         if use_ring:
             from ant_ray_tpu.parallel.ring import ring_attention  # noqa: PLC0415
 
-            return ring_attention(xq, xk, xv, mesh=mesh, causal=True)
-        if mesh is None:
-            return attention(xq, xk, xv, causal=True, impl=attn_impl)
-        rules = llama_rules()
-        return attention(
-            xq, xk, xv, causal=True, impl=attn_impl, mesh=mesh,
-            q_spec=logical_to_spec(
-                ("batch", "seq", "heads", "head_dim"), rules),
-            kv_spec=logical_to_spec(
-                ("batch", "seq", "kv_heads", "head_dim"), rules))
+            out = ring_attention(xq, xk, xv, mesh=mesh, causal=True)
+        elif mesh is None:
+            out = attention(xq, xk, xv, causal=True, impl=attn_impl)
+        else:
+            rules = llama_rules()
+            out = attention(
+                xq, xk, xv, causal=True, impl=attn_impl, mesh=mesh,
+                q_spec=logical_to_spec(
+                    ("batch", "seq", "heads", "head_dim"), rules),
+                kv_spec=logical_to_spec(
+                    ("batch", "seq", "kv_heads", "head_dim"), rules))
+        kv = (xk.astype(c.dtype), xv.astype(c.dtype)) if return_kv else None
+        return out, kv
 
     def block(x, layer):
-        return apply_block(layer, x, c, cos, sin, positions, attend,
-                           constrain_act, return_kv=return_kv)
+        x, kv, _ = apply_block(layer, x, c, cos, sin, positions, attend,
+                               constrain_act)
+        return x, kv
 
     if remat == "full":
         block = jax.checkpoint(block)
@@ -480,15 +504,12 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
                                 jnp.float32)
 
     def attend(xq, xk, xv):
-        return attention(xq, xk, xv, causal=True, impl=attn_impl)
-
-    def no_constrain(x, _dims):
-        return x
+        return attention(xq, xk, xv, causal=True, impl=attn_impl), None
 
     def stage_fn(stage_layers, mx):
         def body(h, layer):
-            h, _ = apply_block(layer, h, c, cos, sin, None, attend,
-                               no_constrain)
+            h, _, _ = apply_block(layer, h, c, cos, sin, None, attend,
+                                  _unconstrained)
             return h, None
 
         out, _ = lax.scan(body, mx, stage_layers)
@@ -531,10 +552,11 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
 # vllm — re-designed TPU-first: dense per-slot KV slabs with static
 # shapes instead of paged indirection, because XLA wants static shapes
 # and HBM slabs keep the decode matmuls MXU-friendly).  The slabs are
-# cheap only while nothing copies them: scanned over as a layer loop's
-# inputs and outputs they were moved about three times a call (59 % of
-# the step programs' device time on a v5e); the step programs now carry
-# them through the loop and write the new rows in place.
+# cheap only while nothing copies them (moved about, they were 59 % of
+# the step programs' device time on a v5e): the step programs carry them
+# through the layer loop and write the new rows in place
+# (``_scan_layers``).  Both run ``apply_block``; what is theirs is which
+# rows they write and which slab they attend over.
 
 def init_kv_cache(config: LlamaConfig, slots: int,
                   max_seq: int | None = None) -> dict:
@@ -575,7 +597,7 @@ ROUTING_COUNTERS = (
 
 
 def _hoist_experts(layers: dict, c: LlamaConfig):
-    """The stacked layers as a serving body's scan takes them: ``(the
+    """The stacked layers as ``_scan_layers``' scan takes them: ``(the
     leaves it slices layer by layer, the expert matrices it closes over
     whole, the layer indices it scans beside them)`` — see
     ``_routed_mlp`` on why; a dense model's layers are all sliced.  The
@@ -627,6 +649,60 @@ def prefill_into_cache(params: dict, tokens, cache: dict, slot,
     return logits[0], cache
 
 
+def _attend_slab(xq, ck, cv, pos, c: LlamaConfig):
+    """Grouped-query attention of rows ``xq`` (rows, heads, hd), row
+    ``r`` over cached positions 0..``pos[r]``: against ONE slab
+    (max_seq, kv_heads, hd) that the rows share (a chunk's slot), or a
+    slab a row (rows, max_seq, kv_heads, hd) (a decode step's slots).
+    bf16 inputs with fp32 accumulation keep the products at full MXU
+    rate without an fp32 copy of the slab (see ops/attention)."""
+    slab = "rtkd" if ck.ndim == 4 else "tkd"
+    q = xq.reshape(xq.shape[0], c.n_kv_heads, c.n_heads // c.n_kv_heads,
+                   c.head_dim)
+    scores = jnp.einsum(f"rkgd,{slab}->rkgt", q, ck,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(c.head_dim))
+    valid = jnp.arange(ck.shape[-3])[None, :] <= pos[:, None]  # (rows, ms)
+    scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum(f"rkgt,{slab}->rkgd", probs.astype(ck.dtype), cv,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(xq.shape).astype(xq.dtype)
+
+
+def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
+                 write_attend):
+    """A step program's layers over rows ``x`` (rows, dim): one
+    ``lax.scan`` of ``apply_block`` whose carry is the rows and the
+    whole cache.  Returns (x, new k, new v, loads).
+
+    ``write_attend(ks, vs, i, xq, xk, xv) -> (out, (ks, vs))`` is the
+    block's attention over the carried slabs, and has one order: the
+    cache travels as the loop's CARRY, which the compiler aliases to the
+    donated input, so layer ``i``'s new rows are written where they lie
+    (a row whose position is max_seq is dropped by the scatter), and
+    only THEN is the slab sliced out of the carried array to feed
+    ``_attend_slab``.  As a scanned input and output of the loop the
+    slabs are copied about three times a call; attending over the old
+    slab with the new rows beside it compiles to more temporaries and
+    reorders the float32 sums."""
+    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
+                                jnp.float32)
+    layers, experts, index = _hoist_experts(params["layers"], c)
+
+    def block(carry, scanned):
+        x, ks, vs = carry                        # ks/vs: the whole cache
+        layer, i = scanned
+        x, (ks, vs), load = apply_block(
+            {**layer, **experts}, x, c, cos, sin, positions,
+            functools.partial(write_attend, ks, vs, i), _unconstrained, i)
+        return (x, ks, vs), load
+
+    (x, ks, vs), loads = lax.scan(
+        block, (x, cache["k"], cache["v"]), (layers, index))
+    return x, ks, vs, loads
+
+
 def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
                              start, chunk_len, config: LlamaConfig):
     """Ingest ONE fixed-size chunk of a prompt into ``slot``.
@@ -652,61 +728,29 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     chunk = tokens.shape[0]
     slab = cache["k"].shape[2:]                  # (max_seq, kvh, hd)
     max_seq = slab[0]
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
-                                jnp.float32)
-    group = c.n_heads // c.n_kv_heads
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
     offs = jnp.arange(chunk, dtype=jnp.int32)
     pos = start + offs                           # (chunk,) absolute
-    real = offs < chunk_len                      # pad mask
     # Pad tokens' writes land at max_seq → dropped by the scatter; rope
     # positions are clamped only to keep the gather in range (their
     # values never reach the slab or the masked attention).
-    write_pos = jnp.where(real, pos, jnp.int32(max_seq))
+    write_pos = jnp.where(offs < chunk_len, pos, jnp.int32(max_seq))
     rope_pos = jnp.minimum(pos, jnp.int32(c.max_seq - 1))
-    pc = cos[rope_pos][:, None, :]               # (chunk, 1, hd/2)
-    ps = sin[rope_pos][:, None, :]
 
-    layers, experts, index = _hoist_experts(params["layers"], c)
-
-    def block(carry, scanned):
-        x, ks, vs = carry                        # ks/vs: the whole cache
-        layer, i = scanned
-        h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-        xq, xk = _qk_proj(layer, h, c)
-        xq = xq.reshape(chunk, c.n_heads, c.head_dim)
-        xk = xk.reshape(chunk, c.n_kv_heads, c.head_dim)
-        xv = (h @ layer["wv"]).reshape(chunk, c.n_kv_heads, c.head_dim)
-        xq = _rope_one(xq, pc, ps)
-        xk = _rope_one(xk, pc, ps)
-        # Write the chunk's rows where they lie, THEN read the slot's
-        # slab out of the carried cache (see ``decode_step``).
+    def write_chunk(ks, vs, i, xq, xk, xv):
+        """The chunk's real rows into (layer i, slot); attend over that
+        slot's slab, causally by absolute position."""
         ks = ks.at[i, slot, write_pos].set(xk.astype(ks.dtype))
         vs = vs.at[i, slot, write_pos].set(xv.astype(vs.dtype))
-        ck = lax.dynamic_slice(ks, (i, slot, 0, 0, 0),
-                               (1, 1) + slab)[0, 0]   # (ms, kvh, hd)
+        ck = lax.dynamic_slice(ks, (i, slot, 0, 0, 0), (1, 1) + slab)[0, 0]
         cv = lax.dynamic_slice(vs, (i, slot, 0, 0, 0), (1, 1) + slab)[0, 0]
-        q = xq.reshape(chunk, c.n_kv_heads, group, c.head_dim)
-        scores = jnp.einsum("ckgd,tkd->ckgt", q, ck,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(c.head_dim))
-        valid = jnp.arange(max_seq)[None, :] <= pos[:, None]  # (chunk, ms)
-        scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("ckgt,tkd->ckgd", probs.astype(ck.dtype), cv,
-                         preferred_element_type=jnp.float32)
-        out = out.reshape(chunk, c.n_heads * c.head_dim).astype(x.dtype)
-        x = x + (out @ layer["wo"]).astype(x.dtype)
-        h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-        out, load = _mlp({**layer, **experts}, h, c, i)
-        x = x + out.astype(x.dtype)
-        return (x, ks, vs), load
+        return _attend_slab(xq, ck, cv, pos, c), (ks, vs)
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
-    (x, new_k, new_v), loads = lax.scan(
-        block, (x, cache["k"], cache["v"]), (layers, index))
+    x, new_k, new_v, loads = _scan_layers(params, x, cache, c, rope_pos,
+                                          write_chunk)
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
@@ -717,108 +761,47 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
 
 
 def decode_step(params: dict, last_tokens, cache: dict,
-                config: LlamaConfig, active=None):
+                config: LlamaConfig, active):
     """One token for every slot, attending against the KV cache.
 
     last_tokens: (slots,) int32 — the most recent token per slot.
-    ``active`` ((slots,) bool, optional): slots marked False neither
-    write K/V nor advance their length — required once idle slots can
-    hold a RESIDENT session's slab (session KV must stay bit-exact
-    while the slot sits out decode steps).  ``active=None`` keeps the
-    legacy everything-steps behavior.
+    ``active`` ((slots,) bool): slots marked False neither write K/V
+    nor advance their length — an idle slot can hold a RESIDENT
+    session's slab, which must stay bit-exact while the slot sits out
+    decode steps.
     Returns (logits (slots, vocab) fp32, new cache with +1 lengths).
     """
     c = config
-    slots = last_tokens.shape[0]
     max_seq = cache["k"].shape[2]
     pos = cache["length"]                       # (slots,) write position
-    if active is not None:
-        # Inactive slots' scatter writes are pushed out of bounds (and
-        # dropped); their lengths hold still below.
-        write_pos = jnp.where(active, pos, jnp.int32(max_seq))
-    else:
-        write_pos = pos
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
-                                jnp.float32)
-    group = c.n_heads // c.n_kv_heads
+    # Inactive slots' scatter writes are pushed out of bounds (and
+    # dropped), as a full slot's are; their lengths hold still below.
+    write_pos = jnp.where(active, pos, jnp.int32(max_seq))
 
-    layers, experts, index = _hoist_experts(params["layers"], c)
+    slots = jnp.arange(last_tokens.shape[0])
 
-    rows = jnp.arange(slots)
-
-    def block(carry, scanned):
-        x, ks, vs = carry                       # ks/vs: the whole cache
-        layer, i = scanned
-        h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-        xq, xk = _qk_proj(layer, h, c)
-        xq = xq.reshape(slots, c.n_heads, c.head_dim)
-        xk = xk.reshape(slots, c.n_kv_heads, c.head_dim)
-        xv = (h @ layer["wv"]).reshape(slots, c.n_kv_heads, c.head_dim)
-        # rope at each slot's own position
-        pc = cos[pos][:, None, :]               # (slots, 1, hd/2)
-        ps = sin[pos][:, None, :]
-        xq = _rope_one(xq, pc, ps)
-        xk = _rope_one(xk, pc, ps)
-        # The cache travels as the loop's CARRY, which the compiler
-        # aliases to the donated input: one row per slot is written
-        # where it lies, and only THEN is the layer's slab sliced out
-        # of the carried array to feed the products.  As a scanned
-        # input and output of the loop the slabs are copied about
-        # three times a call; attending over the old slab with the new
-        # row beside it compiles to more temporaries and reorders the
-        # float32 sums.
-        ks = ks.at[i, rows, write_pos].set(xk.astype(ks.dtype))
-        vs = vs.at[i, rows, write_pos].set(xv.astype(vs.dtype))
+    def write_one(ks, vs, i, xq, xk, xv):
+        """One row a slot into layer i; attend over the layer's slabs,
+        each slot up to its own position."""
+        ks = ks.at[i, slots, write_pos].set(xk.astype(ks.dtype))
+        vs = vs.at[i, slots, write_pos].set(xv.astype(vs.dtype))
         ck = lax.dynamic_index_in_dim(ks, i, axis=0,
                                       keepdims=False)  # (slots, ms, kvh, hd)
         cv = lax.dynamic_index_in_dim(vs, i, axis=0, keepdims=False)
-        # GQA attention against the slab, masked beyond each length.
-        # bf16 inputs with fp32 accumulation keep the matmuls at full
-        # MXU rate without an fp32 copy of the slab (see ops/attention).
-        q = xq.reshape(slots, c.n_kv_heads, group, c.head_dim)
-        scores = jnp.einsum("skgd,stkd->skgt", q, ck,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(c.head_dim))
-        valid = jnp.arange(max_seq)[None, :] <= pos[:, None]  # (slots, ms)
-        scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("skgt,stkd->skgd", probs.astype(ck.dtype), cv,
-                         preferred_element_type=jnp.float32)
-        out = out.reshape(slots, c.n_heads * c.head_dim).astype(x.dtype)
-        x = x + (out @ layer["wo"]).astype(x.dtype)
-        h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-        out, load = _mlp({**layer, **experts}, h, c, i)
-        x = x + out.astype(x.dtype)
-        return (x, ks, vs), load
+        return _attend_slab(xq, ck, cv, pos, c), (ks, vs)
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
-    (x, new_k, new_v), loads = lax.scan(
-        block, (x, cache["k"], cache["v"]), (layers, index))
+    x, new_k, new_v, loads = _scan_layers(params, x, cache, c, pos,
+                                          write_one)
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
-    # Clamp so idle slots (which keep stepping) never index past the
-    # slab; their scatter writes drop out of bounds harmlessly.  With an
-    # ``active`` mask, inactive slots' lengths hold perfectly still so a
-    # resident session's slab stays byte-stable across steps.
-    new_len = jnp.minimum(cache["length"] + 1, jnp.int32(max_seq))
-    if active is not None:
-        new_len = jnp.where(active, new_len, cache["length"])
+    # Clamped so a full slot never indexes past its slab.
+    new_len = jnp.where(active,
+                        jnp.minimum(pos + 1, jnp.int32(max_seq)), pos)
     cache = {**_count_routing(cache, loads), "k": new_k, "v": new_v,
              "length": new_len}
     return logits, cache
-
-
-def _rope_one(x, cos, sin):
-    """Rotate (slots, heads, hd) at per-slot positions (cos/sin already
-    gathered: (slots, 1, hd/2))."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1 = x1.astype(jnp.float32)
-    xf2 = x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
-        axis=-1).astype(x.dtype)
 
 
 # ---------------------------------------------------------------- generate

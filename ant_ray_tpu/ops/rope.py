@@ -16,15 +16,13 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 500000.0,
 
 
 def apply_rope(x, cos, sin, positions=None):
-    """x: (batch, seq, heads, head_dim); cos/sin: (max_seq, head_dim//2);
-    positions: (batch, seq) int32 (defaults to arange)."""
-    seq = x.shape[1]
+    """x: (..., seq, heads, head_dim); cos/sin: (max_seq, head_dim//2);
+    positions: int32 of x's leading shape (..., seq) — (batch, seq) in
+    training, (rows,) for a serving step's rows — defaults to arange."""
     if positions is None:
-        cos_sel = cos[:seq][None, :, None, :]     # (1, s, 1, d/2)
-        sin_sel = sin[:seq][None, :, None, :]
-    else:
-        cos_sel = cos[positions][:, :, None, :]   # (b, s, 1, d/2)
-        sin_sel = sin[positions][:, :, None, :]
+        positions = slice(x.shape[-3])
+    cos_sel = cos[positions][..., None, :]        # (..., s, 1, d/2)
+    sin_sel = sin[positions][..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate(
         [x1 * cos_sel - x2 * sin_sel, x2 * cos_sel + x1 * sin_sel], axis=-1)

@@ -739,8 +739,16 @@ class _RoutingState:
         self._listener.start()
 
     def _listen_loop(self) -> None:
+        from ant_ray_tpu._private.worker import global_worker  # noqa: PLC0415
+
         art = _art()
-        while True:
+        # The loop belongs to the runtime it was started under.  Once
+        # that is shut down (with the deployment still up: a driver that
+        # failed before ``serve.shutdown()``), one more call from this
+        # thread would auto-init a cluster of its own inside whatever
+        # the process does next.
+        runtime = global_worker.runtime
+        while global_worker.runtime is runtime:
             try:
                 changed = art.get(
                     self.controller.listen_for_change.remote(

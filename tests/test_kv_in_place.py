@@ -31,6 +31,18 @@ SLOTS, MAX_SEQ, CHUNK = 4, 96, 16
 LOGIT_TOL = 2.0 ** -8                       # one step of bf16
 
 
+def _rope_one(x, cos, sin):
+    """Rotate (rows, heads, hd) by cos/sin already gathered at the rows'
+    positions (rows, 1, hd/2) — the reference's own, as ``llama.py`` had
+    it beside the two bodies below."""
+    half = x.shape[-1] // 2
+    xf1 = x[..., :half].astype(jnp.float32)
+    xf2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
+        axis=-1).astype(x.dtype)
+
+
 def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
                           config):
     c = config
@@ -59,8 +71,8 @@ def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
         xq = xq.reshape(chunk, c.n_heads, c.head_dim)
         xk = xk.reshape(chunk, c.n_kv_heads, c.head_dim)
         xv = (h @ layer["wv"]).reshape(chunk, c.n_kv_heads, c.head_dim)
-        xq = llama._rope_one(xq, pc, ps)
-        xk = llama._rope_one(xk, pc, ps)
+        xq = _rope_one(xq, pc, ps)
+        xk = _rope_one(xk, pc, ps)
         ck = lax.dynamic_index_in_dim(ck_all, slot, axis=0,
                                       keepdims=False)
         cv = lax.dynamic_index_in_dim(cv_all, slot, axis=0,
@@ -123,8 +135,8 @@ def scanned_decode_step(params, last_tokens, cache, config, active=None):
         xv = (h @ layer["wv"]).reshape(slots, c.n_kv_heads, c.head_dim)
         pc = cos[pos][:, None, :]
         ps = sin[pos][:, None, :]
-        xq = llama._rope_one(xq, pc, ps)
-        xk = llama._rope_one(xk, pc, ps)
+        xq = _rope_one(xq, pc, ps)
+        xk = _rope_one(xk, pc, ps)
         ck = ck.at[jnp.arange(slots), write_pos].set(xk.astype(ck.dtype))
         cv = cv.at[jnp.arange(slots), write_pos].set(xv.astype(cv.dtype))
         q = xq.reshape(slots, c.n_kv_heads, group, c.head_dim)
@@ -201,13 +213,13 @@ def assert_same_step(got, want):
                                       err_msg=name)
 
 
-@pytest.mark.parametrize("active", [None, (True, False, True, True),
+@pytest.mark.parametrize("active", [(True, True, True, True),
+                                    (True, False, True, True),
                                     (False, True, False, False)], ids=str)
 def test_decode_step_equals_the_scanned_form(model, active):
     c, params, cache = model
     last = jnp.asarray([3, 250, 77, 9], jnp.int32)
-    act = None if active is None else jnp.asarray(active)
-    on = np.ones((SLOTS,), bool) if active is None else np.asarray(active)
+    act, on = jnp.asarray(active), np.asarray(active)
     pos = cache["length"]
 
     _, new = got = jax.jit(

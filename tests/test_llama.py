@@ -265,3 +265,44 @@ def test_pp_grads_flow(tiny_params):
         g = np.asarray(grads["layers"][name])
         # Both layers (= both pipeline stages) receive gradient signal.
         assert np.abs(g[0]).sum() > 0 and np.abs(g[1]).sum() > 0, name
+
+
+def _trace_forward(params):
+    return llama.forward(params, _tokens(), CFG)
+
+
+def _trace_loss_fn_pp(params):
+    mesh = build_mesh(MeshConfig(pp=2, tp=2, dp=-1))
+    return llama.loss_fn_pp(params, {"tokens": _tokens(4, 65)}, CFG,
+                            mesh=mesh, num_microbatches=2)
+
+
+def _trace_decode_step(params):
+    cache = llama.init_kv_cache(CFG, 4, 64)
+    return llama.decode_step(params, jnp.zeros((4,), jnp.int32), cache, CFG,
+                             active=jnp.ones((4,), bool))
+
+
+def _trace_prefill_chunk_into_cache(params):
+    cache = llama.init_kv_cache(CFG, 4, 64)
+    return llama.prefill_chunk_into_cache(
+        params, jnp.zeros((16,), jnp.int32), cache, 1, 0, 5, CFG)
+
+
+@pytest.mark.parametrize("program", [
+    _trace_forward, _trace_loss_fn_pp, _trace_decode_step,
+    _trace_prefill_chunk_into_cache], ids=lambda f: f.__name__[7:])
+def test_every_program_runs_the_one_block(tiny_params, monkeypatch, program):
+    """Training, the pipeline stages, a prefill chunk and a decode step
+    are traced through ``llama.apply_block``: the block's equations are
+    written there and nowhere else."""
+    calls = []
+    apply_block = llama.apply_block
+
+    def counted(*args, **kwargs):
+        calls.append(None)
+        return apply_block(*args, **kwargs)
+
+    monkeypatch.setattr(llama, "apply_block", counted)
+    jax.eval_shape(program, tiny_params)
+    assert calls

@@ -24,7 +24,8 @@ import pytest
 from ant_ray_tpu.llm import LLMEngine, SamplingParams
 from ant_ray_tpu.models import llama
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
-from chipbench.models import olmoe
+from chipbench.models import dense_llama, olmoe
+from chipbench.reference import dense_decoder as dense_ref
 from chipbench.reference import olmoe_decoder as ref
 
 CFG = llama.CONFIGS["olmoe-tiny"]
@@ -34,14 +35,16 @@ SPEC = {"num_attention_heads": CFG.n_heads,
         "num_experts_per_tok": CFG.experts_per_token,
         "norm_topk_prob": CFG.norm_topk_prob}
 TOL = 2e-5
+# Every head its own KV head, dense: the layout whose step programs
+# compile to another shape than grouped queries' (PERF.md §6, PR 28).
+DENSE_UNGROUPED = dataclasses.replace(llama.CONFIGS["tiny"], n_kv_heads=4)
 
 
-@pytest.fixture(scope="module")
-def params():
+def seeded_params(cfg):
     """Seeded weights, made less bland than the initialiser's: matrices
     large enough that the router decides and attention attends, norm
     weights that are not all ones (a swapped or missing norm shows)."""
-    p = llama.init_params(CFG, jax.random.PRNGKey(0))
+    p = llama.init_params(cfg, jax.random.PRNGKey(0))
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
     layers = dict(p["layers"])
     for name, leaf in layers.items():
@@ -51,6 +54,11 @@ def params():
         else:
             layers[name] = leaf * 6.0
     return {**p, "layers": layers, "norm_f": p["norm_f"] * 0.7}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return seeded_params(CFG)
 
 
 def as_reference(p):
@@ -130,12 +138,29 @@ def through_the_cache(p, cfg, tokens, prompt, chunk=16, slots=4, slot=2):
     return jnp.stack(got), cache
 
 
-def test_prefill_in_chunks_then_decode_equals_the_full_forward(params):
+def reference_logits(p, cfg, tokens):
+    """The plain reference's full forward: OLMoE's, or for a dense
+    model the dense decoder's."""
+    if cfg.num_experts:
+        return ref.forward(*as_reference(p), tokens, **ref.dims_of(SPEC))
+    embed, layer, n, norm_f, head = dense_llama.reference_layers(p)
+    return dense_ref.forward(
+        embed, [layer(i) for i in range(n)], norm_f, head, tokens,
+        n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+        rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
+
+
+@pytest.mark.parametrize("cfg", [CFG, DENSE_UNGROUPED],
+                         ids=["olmoe-tiny", "dense-ungrouped"])
+def test_prefill_in_chunks_then_decode_equals_the_full_forward(params, cfg):
+    p = params if cfg is CFG else seeded_params(cfg)
     tokens, prompt = tokens_of(2, 60), 50
-    got, cache = through_the_cache(params, CFG, tokens, prompt)
-    want = ref.forward(*as_reference(params), jnp.asarray(tokens),
-                       **ref.dims_of(SPEC))[prompt - 1:]
+    got, cache = through_the_cache(p, cfg, tokens, prompt)
+    want = reference_logits(p, cfg, jnp.asarray(tokens))[prompt - 1:]
     assert rel_l2(got[:-1], want[:-1]).max() < TOL
+    if not cfg.num_experts:
+        assert "routing" not in cache
+        return
     # The step programs counted what they computed: 4 chunks of 16 rows
     # and 10 steps of 4 slots, k experts a row, in each layer.
     executions, rows = 4 + 10, 4 * 16 + 10 * 4

@@ -340,3 +340,32 @@ def test_scale_up_pushed_to_handle_without_ttl(cluster):
     # Well inside the 30s fallback TTL -> it was pushed, not polled.
     assert observed_at < DeploymentHandle._REFRESH_TTL_S / 2, \
         f"scale-up took {observed_at:.1f}s to reach the handle"
+
+
+def test_a_handles_listener_ends_with_its_runtime(cluster, monkeypatch):
+    """The long-poll thread of a handle whose deployment was never shut
+    down stops once the runtime it was started under is gone: were it
+    to ask the controller again, that call would auto-init a cluster
+    of its own in this process, inside whatever it runs next."""
+    import threading
+
+    from ant_ray_tpu._private.worker import global_worker
+    from ant_ray_tpu.serve.api import _RoutingState
+
+    asked = threading.Event()
+
+    class GoneController:
+        class listen_for_change:
+            @staticmethod
+            def remote(_versions):
+                asked.set()
+                raise ConnectionError("the controller went with its cluster")
+
+    state = _RoutingState("orphan", [], GoneController())
+    state.ensure_listener()
+    assert asked.wait(10)
+    # another runtime than the listener's own, as after shutdown + init
+    # (not None: nothing may auto-init under the module's cluster)
+    monkeypatch.setattr(global_worker, "runtime", object())
+    state._listener.join(10)
+    assert not state._listener.is_alive()

@@ -243,13 +243,18 @@ def test_two_node_cross_node_trace():
             scheduling_strategy=strategy).remote(blob_ref))
         assert value == 400_000
 
+        def _runs(spans):
+            return [s for s in spans if s["name"].startswith("run:")
+                    and "consume" in s["name"]]
+
         def _landed(spans):
-            return any(s["name"] == "daemon:object_pull"
-                       for s in spans)
+            # both: the worker's span and the daemon's arrive on
+            # flushes of their own
+            return _runs(spans) and any(
+                s["name"] == "daemon:object_pull" for s in spans)
 
         spans = _gcs_spans(_landed)
-        runs = [s for s in spans if s["name"].startswith("run:")
-                and "consume" in s["name"]]
+        runs = _runs(spans)
         assert runs, [s["name"] for s in spans]
         trace_id = runs[-1]["trace_id"]
         ours = [s for s in spans if s["trace_id"] == trace_id]
