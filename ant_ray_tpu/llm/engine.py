@@ -27,6 +27,13 @@ vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
 * **Continuous batching**: each `step()` admits queued prompts, runs at
   most one prefill unit (a full bucketed prompt, or one chunk), then
   decodes every active slot in one batched call.
+* **One decode step ahead.**  The newest token of every slot stays on
+  the device and the jitted sampler feeds it to the next step, so an
+  iteration dispatches decode step N+1 and only then reads and emits
+  step N: the host's work between two steps runs under the device's.
+  A finish by count (``max_tokens``, ``max_seq``) is known at dispatch
+  and leaves the next step's mask; a stop token is read one step late,
+  and the row that step computed for the ended sequence is dropped.
 * **Session KV offload** (``session_id=`` + kv_offload.py stores): a
   finished request's slab stays RESIDENT in its slot for multi-turn
   reuse; idle sessions are evicted — LRU past ``kv_idle_evict_s`` or on
@@ -80,7 +87,10 @@ class _Seq:
     session: Any = None           # _Session | None
     prefill_done: int = 0         # prompt tokens ingested (chunked mode)
     kv_len: int = 0               # slab tokens written for this slot
-    last_tok: int | None = None   # device-fed token (resume after restore)
+    last_tok: int | None = None   # newest token read (resume after restore)
+    # Decode steps still to dispatch before max_tokens or max_seq ends
+    # the sequence: a finish by count is known before its token is read.
+    steps_left: int = 0
     on_event: Any = None          # callable(dict) | None — streaming sink
     trace_ctx: Any = None         # TraceContext for llm:* spans
     # Marks for the llm:engine stage span (perf_counter, engine step):
@@ -135,6 +145,15 @@ class _PhaseRecorder:
     proper is ``sum(phase_*_s) - block_s - phase_idle_wait_s``.  A
     decode step reads once, its tokens in ``fetch``; a prompt's end
     reads its first token in ``chunk``; ``sample`` only dispatches.
+
+    The decode step is pipelined one deep, so an iteration's phases run
+    ``decode`` and ``sample`` (dispatch step N+1), then ``fetch`` (read
+    step N) and ``emit`` (step N's tokens to their callers).  The read
+    waits only for what is left of step N after the host's own work of
+    the iteration: ``block_fetch_s`` is the remainder of the device
+    step that the host did not cover, not the step.
+    ``decode_ahead_steps`` counts the decode steps dispatched while the
+    step before them was still unread.
     """
 
     def __init__(self, jax, stats: dict):
@@ -145,7 +164,8 @@ class _PhaseRecorder:
                       for p in PHASES}
         # Declared here, once: dict(stats) on another thread never sees
         # the dict change size.
-        for key in ("steps", "decode_steps", "decode_slots", "d2h_syncs"):
+        for key in ("steps", "decode_steps", "decode_slots",
+                    "decode_ahead_steps", "d2h_syncs"):
             stats[key] = 0
         stats["block_s"] = 0.0
         for _, phase_key, block_key in self._keys.values():
@@ -192,6 +212,13 @@ class _PhaseRecorder:
             self._stats[self._keys[self._phase][1]] += now - self._t
             self._span.__exit__(None, None, None)
             self._phase = None
+
+    def back_to(self, name: str | None) -> None:
+        """Reopen the phase a detour interrupted (None: none was open)."""
+        if name is None:
+            self._close_phase(time.perf_counter())
+        else:
+            self.enter(name)
 
     def to_host(self, value):
         """Every blocking device→host read of the engine, counted."""
@@ -295,13 +322,14 @@ class LLMEngine:
         # as a device slice if the sequence is evicted unfinished.  The
         # host never reads a key.
         self._keys = jnp.zeros((slots, 2), jnp.uint32)
+        # Per-slot newest tokens, resident on the device beside the
+        # keys: a prompt's first token enters its row when the sequence
+        # joins the decode batch, and the jitted sampler writes every
+        # active row's token back, so step N+1 is fed from step N on
+        # the chip and can be dispatched before the host has read N.
+        self._last = jnp.zeros((slots,), jnp.int32)
         if self.mesh is not None:
             self._shard_state()
-        # Host-side mirror of each slot's most recent token: mutated in
-        # numpy and uploaded once per decode call, so the scheduling
-        # loop costs one host→device transfer per step instead of one
-        # tiny device op per slot.
-        self._last_np = np.zeros((slots,), np.int32)
         # Each slot's (temperature, top_k, top_p), kept current when a
         # sequence joins the decode batch; uploaded again only after a
         # change.
@@ -313,7 +341,19 @@ class LLMEngine:
         # at admission instead of queueing prompts toward OOM.
         self._max_waiting = max_waiting
         self._free_slots = list(range(slots))
-        self._active: dict[int, _Seq] = {}        # slot -> seq
+        # slot -> seq: the rows of the NEXT decode step.  A sequence
+        # leaves at the dispatch of its last step by count, or when a
+        # stop token is read; its slot is freed when its last token is.
+        self._active: dict[int, _Seq] = {}
+        # The mask of ``_active`` on the device and its rows as a tuple,
+        # made again only after ``_active`` changed (None).
+        self._active_dev = None
+        self._rows: tuple = ()
+        # The decode step dispatched and not yet read: (the sampler's
+        # output on the device, the (slot, seq) rows it was dispatched
+        # for).  Tokens are attributed by those rows, never by
+        # ``_active`` as it is when they are read.
+        self._flight: tuple | None = None
         self._waiting: list[_Seq] = []
         self._finished: list[RequestOutput] = []
         self._req_counter = itertools.count()
@@ -370,7 +410,7 @@ class LLMEngine:
                                          keepdims=False)
             v = lax.dynamic_index_in_dim(cache["v"], slot, axis=1,
                                          keepdims=False)
-            return k, v, cache["length"][slot]
+            return k, v
 
         def _install(cache, k, v, length, slot):
             from jax import lax  # noqa: PLC0415
@@ -385,8 +425,8 @@ class LLMEngine:
                 "length": cache["length"].at[slot].set(length),
             }
 
-        def _put_key(keys, key, slot):
-            return keys.at[slot].set(key)
+        def _put_row(keys, last, key, token, slot):
+            return keys.at[slot].set(key), last.at[slot].set(token[0])
 
         def _take_key(keys, slot):
             from jax import lax  # noqa: PLC0415
@@ -396,14 +436,14 @@ class LLMEngine:
 
         # one compile per prompt bucket (slot/length traced); ONE chunk
         # variant (slot/start/length traced); one decode; one extract /
-        # install / key write / key read each (slot traced).
+        # install / row write / key read each (slot traced).
         self._prefill_jit = jax.jit(_prefill, donate_argnums=(1,))
         self._prefill_chunk_jit = jax.jit(_prefill_chunk,
                                           donate_argnums=(1,))
         self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
         self._extract_jit = jax.jit(_extract)
         self._install_jit = jax.jit(_install, donate_argnums=(0,))
-        self._put_key_jit = jax.jit(_put_key)
+        self._put_row_jit = jax.jit(_put_row)
         self._take_key_jit = jax.jit(_take_key)
         self._sample_jit = jax.jit(self._sample_batch)
         self._one_active = jnp.ones((1,), bool)   # _sample_one's mask
@@ -432,6 +472,7 @@ class LLMEngine:
             name: jax.device_put(x, kv if name in ("k", "v") else rep)
             for name, x in self.cache.items()}
         self._keys = jax.device_put(self._keys, rep)
+        self._last = jax.device_put(self._last, rep)
 
     # ------------------------------------------------------------ public
 
@@ -515,15 +556,21 @@ class LLMEngine:
 
     def has_unfinished(self) -> bool:
         return bool(self._waiting or self._active or self._prefilling
-                    or self._restoring
+                    or self._restoring or self._flight
                     or any(s.paused or s.pending
                            for s in self._sessions.values()))
 
     def step(self) -> list[RequestOutput]:
         """One engine iteration: land finished restores, admit prompts,
-        run one prefill unit (bucketed prompt or one chunk), decode all
-        active slots, sweep idle sessions.  Returns outputs finished
-        since the last call."""
+        run one prefill unit (bucketed prompt or one chunk), dispatch
+        decode step N+1 for all active slots, read step N's tokens and
+        emit them, sweep idle sessions.  Returns outputs finished since
+        the last call.
+
+        The decode step runs one ahead of the host: a token comes back
+        from the ``step()`` after the one that dispatched it, and while
+        a step is in flight ``has_unfinished()`` stays true, so a
+        caller that loops on it drains the last one without knowing."""
         rec = self._rec
         mine = rec.begin()       # False under an EngineLoop iteration
         try:
@@ -628,6 +675,7 @@ class LLMEngine:
         session (its request resumes after an automatic restore —
         bit-identically, since the slab round trip is exact).  Sessions
         mid-prefill are never evictable.  Returns True if evicted."""
+        self._land_flight()      # its tokens first: they may end a turn
         sess = self._sessions.get(session_id)
         if sess is None or sess.state != "resident" or sess.slot < 0:
             return False
@@ -635,10 +683,12 @@ class LLMEngine:
         if cur is not None:
             if not force or cur in self._prefilling:
                 return False
-            self._active.pop(cur.slot, None)
+            if self._active.pop(cur.slot, None) is not None:
+                self._active_dev = None
             # the key rides the sequence, not the slot: a device slice
             cur.rng_key = self._take_key_jit(self._keys, cur.slot)
             cur.slot = -1
+            sess.kv_len = cur.kv_len
             sess.paused = cur
             sess.current = None
         self._offload(sess)
@@ -648,6 +698,7 @@ class LLMEngine:
         """Drop a session: frees its slot (if resident) and deletes its
         offloaded slab (if any).  In-flight work is not interrupted —
         call only for idle sessions."""
+        self._land_flight()
         sess = self._sessions.pop(session_id, None)
         if sess is None:
             return False
@@ -711,7 +762,10 @@ class LLMEngine:
             if self._chunk_tokens is None and admitted_prefill:
                 break                         # legacy: ≤1 prefill/step
             slot = self._free_slots.pop()
-            self._waiting.pop(i)
+            # not pop(i): the eviction above lands the step in flight,
+            # and a turn that ends there puts its session's next one
+            # at the head of the line
+            self._waiting.remove(seq)
             if sess is not None:
                 sess.slot = slot
                 sess.state = "resident"
@@ -751,12 +805,7 @@ class LLMEngine:
             len(seq.prompt))
         self._note_dispatch(seq)
         seq.kv_len = len(seq.prompt)
-        tok = int(rec.to_host(self._sample_one(seq, last_logits))[0])
-        rec.enter("emit")
-        self._after_token(seq, tok)
-        if seq.slot >= 0:
-            seq.last_tok = tok
-            self._join_decode(seq)
+        self._first_token(seq, last_logits)
         rec.enter("admit")            # back to the caller's phase
 
     def _maybe_prefill_chunk(self):
@@ -794,61 +843,117 @@ class LLMEngine:
         seq.kv_len += len(part)
         self._note_chunk(len(part))
         self._decode_since_chunk = 0
-        if seq.prefill_done < len(seq.prompt):
+        if seq.prefill_done == len(seq.prompt):
+            self._first_token(seq, logits)
+        else:
             self._prefilling.append(seq)
-            return
-        tok = int(self._rec.to_host(self._sample_one(seq, logits))[0])
+
+    def _first_token(self, seq: _Seq, logits):
+        """A prompt's end: sample, read and emit its first token.  The
+        read stays where it was, in ``chunk``, and waits out the decode
+        step in flight before the prefill program, as the unpipelined
+        loop's did; the token enters the slot's row from the device."""
+        token = self._sample_one(seq, logits)
+        tok = int(self._rec.to_host(token)[0])
         self._rec.enter("emit")
         self._after_token(seq, tok)
         if seq.slot >= 0:
             seq.last_tok = tok
-            self._join_decode(seq)
+            self._join_decode(seq, token)
 
-    def _join_decode(self, seq: _Seq):
-        """``seq`` (slot and ``last_tok`` set) decodes from the next
-        step on: its token, its sampling parameters and its key move
-        into the slot's rows — the key through one small program, no
-        read."""
+    def _join_decode(self, seq: _Seq, token=None):
+        """``seq`` (slot set) decodes from the next step on: its newest
+        token (``token``, (1,) on the device, else ``last_tok``), its
+        key and its sampling parameters move into the slot's rows —
+        token and key through one small program, no read."""
         slot, s = seq.slot, seq.sampling
-        self._last_np[slot] = seq.last_tok
         row = (s.temperature, s.top_k, s.top_p)
         if row != self._sampling_rows[slot]:
             self._sampling_rows[slot] = row
             self._sampling_dev = None
-        self._keys = self._put_key_jit(self._keys, seq.rng_key, slot)
+        if token is None:
+            token = np.asarray([seq.last_tok], np.int32)
+        self._keys, self._last = self._put_row_jit(
+            self._keys, self._last, seq.rng_key, token, slot)
         seq.rng_key = None
+        seq.steps_left = min(s.max_tokens - len(seq.generated),
+                             self.max_seq - 1 - seq.kv_len)
         self._active[slot] = seq
+        self._active_dev = None
 
     def _decode(self):
-        if not self._active:
-            return
-        jnp, rec = self._jnp, self._rec
+        """Dispatch step N+1, then read and emit step N: the host's
+        turn-around and the transfer run under the device's step.  A
+        dispatch that fails still lands the step before it."""
+        flight, self._flight = self._flight, None
+        try:
+            if self._active:
+                self._flight = self._dispatch_decode(flight is not None)
+        finally:
+            if flight is not None:
+                self._land(flight)
+
+    def _dispatch_decode(self, ahead: bool) -> tuple:
+        """One decode step and its sampler for the rows of ``_active``,
+        fed from the device's token table; no read.  Returns the flight
+        record."""
+        rec, stats = self._rec, self.stats
         rec.enter("decode")
-        mask = np.zeros((self.slots,), bool)
-        mask[list(self._active)] = True
-        active = jnp.asarray(mask)
+        if self._active_dev is None:
+            mask = np.zeros((self.slots,), bool)
+            mask[list(self._active)] = True
+            self._active_dev = self._jnp.asarray(mask)
+            self._rows = tuple(self._active.items())
+        rows = self._rows
         logits, self.cache = self._decode_jit(
-            self.params, self.cache, jnp.asarray(self._last_np), active)
+            self.params, self.cache, self._last, self._active_dev)
         rec.dispatched = True
-        self.stats["decode_steps"] += 1
-        self.stats["decode_slots"] += len(self._active)
+        stats["decode_steps"] += 1
+        stats["decode_slots"] += len(rows)
+        stats["decode_ahead_steps"] += ahead
+        self._decode_since_chunk += 1
         rec.enter("sample")
-        sampled = self._sample_all(logits, active)
+        sampled = self._sample_all(logits)
+        for slot, seq in rows:
+            seq.steps_left -= 1
+            if not seq.steps_left:
+                # max_tokens or max_seq ends it with this step's token:
+                # known now, so the next step's mask leaves the row out
+                del self._active[slot]
+                self._active_dev = None
+        return sampled, rows
+
+    def _land(self, flight: tuple):
+        """The one read of a dispatched decode step, and its tokens to
+        their sequences as the step's own rows name them.  A sequence
+        that a stop token ended at the step before has left its slot:
+        its row is dropped — the host learns of a stop one step late,
+        and the K/V row that step wrote lies where the session's next
+        turn writes its carry, or behind the length of a freed slot."""
+        sampled, rows = flight
+        rec = self._rec
         rec.enter("fetch")
         toks = rec.to_host(sampled)
         rec.enter("emit")
         if self.config.num_experts:
             self._note_routing(toks[self.slots:])
-        self._decode_since_chunk += 1
-        for slot, seq in list(self._active.items()):
-            # this call wrote seq.last_tok's K/V at position kv_len
+        for slot, seq in rows:
+            if seq.slot != slot:
+                continue
+            # the step wrote the K/V of the token it was fed at kv_len
             seq.kv_len = min(seq.kv_len + 1, self.max_seq)
-            tok = int(toks[slot])
+            seq.last_tok = tok = int(toks[slot])
             self.stats["tokens_generated"] += 1
             self._after_token(seq, tok)
-            if seq.slot >= 0:
-                seq.last_tok = tok
-                self._last_np[slot] = tok
+
+    def _land_flight(self):
+        """Land the step in flight now, out of turn: before anything
+        reads or moves a slot's state on the host."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            phase = self._rec._phase
+            self._land(flight)
+            self._rec.back_to(phase)
 
     def _note_routing(self, counters):
         """``counters``: the device's running routing counters as they
@@ -921,12 +1026,14 @@ class LLMEngine:
         store; the slot returns to the free pool.  The slab is NOT
         zeroed — stale bytes past a future occupant's length are masked
         exactly like reused slots always were."""
+        self._land_flight()
         slot = sess.slot
-        k, v, ln = self._extract_jit(self.cache, slot)
+        k, v = self._extract_jit(self.cache, slot)
         to_host = self._rec.to_host
-        slab = (to_host(k), to_host(v), int(to_host(ln)))
+        # The length is the host's: the device's counts the row of a
+        # step dispatched before a stop token was read.
+        slab = (to_host(k), to_host(v), sess.kv_len)
         sess.handle = self._store().put(sess.session_id, slab)
-        sess.kv_len = slab[2]
         sess.slot = -1
         sess.state = "offloaded"
         self._free_slots.append(slot)
@@ -1132,7 +1239,8 @@ class LLMEngine:
         self._record_engine_span(seq)
         sess = seq.session
         if seq.slot >= 0:
-            self._active.pop(seq.slot, None)
+            if self._active.pop(seq.slot, None) is not None:
+                self._active_dev = None
             if sess is None:
                 self._free_slots.append(seq.slot)
             else:
@@ -1160,7 +1268,7 @@ class LLMEngine:
         split once, sample with the second half, the first half stays
         on ``seq.rng_key`` for the table."""
         jnp, s = self._jnp, seq.sampling
-        toks, rest = self._sample_jit(
+        toks, rest, _ = self._sample_jit(
             logits[None], seq.rng_key[None], self._one_active,
             jnp.asarray([s.temperature], jnp.float32),
             jnp.asarray([s.top_k], jnp.int32),
@@ -1168,22 +1276,23 @@ class LLMEngine:
         seq.rng_key = rest[0]
         return toks
 
-    def _sample_all(self, logits, active):
+    def _sample_all(self, logits):
         """Dispatch the batch sampler on the decode step's logits; the
-        key table advances on the device.  No read, no eager op."""
+        key table advances and the token table takes the active rows'
+        tokens on the device.  No read, no eager op."""
         if self._sampling_dev is None:
             jnp = self._jnp
             temps, top_ks, top_ps = zip(*self._sampling_rows)
             self._sampling_dev = (jnp.asarray(temps, jnp.float32),
                                   jnp.asarray(top_ks, jnp.int32),
                                   jnp.asarray(top_ps, jnp.float32))
-        sampled, self._keys = self._sample_jit(
-            logits, self._keys, active, *self._sampling_dev,
-            self.cache.get("routing"))
+        sampled, self._keys, self._last = self._sample_jit(
+            logits, self._keys, self._active_dev, *self._sampling_dev,
+            self.cache.get("routing"), self._last)
         return sampled
 
     def _sample_batch(self, logits, keys, active, temps, top_ks, top_ps,
-                      routing=None):
+                      routing=None, last=None):
         """Vectorized per-slot sampling: greedy when temperature == 0,
         else temperature softmax with optional top-k / top-p (nucleus)
         filtering — all branch-free for XLA.  Every row of ``keys``
@@ -1192,7 +1301,9 @@ class LLMEngine:
         next key where ``active``, and an inactive row keeps its key.
         ``routing`` (a routed model's counters, uint32) is appended to
         the tokens bit for bit, so that the step's one read brings both.
-        Returns ``(tokens, keys)``."""
+        ``last`` (the per-slot token table) takes the active rows'
+        tokens: the next decode step is fed from it on the device.
+        Returns ``(tokens, keys, last)``."""
         jax, jnp = self._jax, self._jnp
         vocab = logits.shape[-1]
         split = jax.vmap(jax.random.split)(keys)          # (n, 2, 2)
@@ -1217,10 +1328,12 @@ class LLMEngine:
             lambda k, lg: jax.random.categorical(k, lg))(split[:, 1],
                                                          masked)
         tokens = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        if last is not None:
+            last = jnp.where(active, tokens, last)
         if routing is not None:
             tokens = jnp.concatenate(
                 [tokens, jax.lax.bitcast_convert_type(routing, jnp.int32)])
-        return tokens, next_keys
+        return tokens, next_keys, last
 
 
 class _LoopHandle:
@@ -1447,9 +1560,12 @@ class EngineLoop:
 
     def _run(self):
         """Each iteration with work is one ``engine`` step of the
-        recorder — drain, the engine's own phases, housekeeping — and a
-        stretch without work is one ``idle_wait`` phase, however many
-        times the wait wakes."""
+        recorder — drain, the engine's own phases (dispatch decode step
+        N+1, read step N, emit step N), housekeeping — and a stretch
+        without work is one ``idle_wait`` phase, however many times the
+        wait wakes.  A decode step in flight is work: the iteration
+        after a batch's last dispatch reads and emits its tokens, and a
+        shutdown lands it before the thread ends."""
         eng = self._engine
         rec = eng._rec
         while not self._stop:
@@ -1474,6 +1590,7 @@ class EngineLoop:
                 self._wake.wait(self._idle_sleep)
                 self._wake.clear()
                 self._housekeep(eng)
+        eng._land_flight()
 
     def _housekeep(self, eng):
         self._snapshot = self._loop_snapshot(eng)

@@ -2,6 +2,8 @@
 path, continuous batching, sampling, serve + batch integration
 (capability mirror of the reference's llm/ test tiers)."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -158,6 +160,96 @@ def test_stream_depends_on_neither_slot_nor_neighbours(params, which):
     full = _run_all(_pinned_engine(params, 8),
                     neighbours + [(prompt, _sampling(seed, n))])
     assert full[-1] == want
+
+
+# Six requests through three slots as the UNPIPELINED engine answered
+# them (commit b1d9bd2, bucketed and chunked prefill agree): A, C and F
+# end at a stop token, at different steps, B, D and E at ``max_tokens``;
+# D, E and F arrive later and wait for the slots A and C leave.
+# (request, prompt, seed, max_tokens, stop ids, added before step,
+#  token ids, finish reason)
+STAGGERED = [
+    ("A", [5, 9, 17, 3, 88, 41, 12, 13, 14, 15, 16], None, 7, (117,), 0,
+     [202, 150, 114], "stop"),
+    ("B", [44, 55, 66], 11, 12, (), 0,
+     [48, 97, 64, 165, 2, 172, 253, 42, 172, 0, 22, 32], "length"),
+    ("C", [7, 8, 9, 10, 11], 99, 9, (23,), 0, [206, 100, 29, 158], "stop"),
+    ("D", [60, 61, 62], 1000, 6, (), 2,
+     [111, 37, 57, 80, 107, 229], "length"),
+    ("E", [1, 2, 3], None, 5, (), 4, [21, 29, 21, 29, 21], "length"),
+    ("F", [10, 20, 30], 123, 12, (167,), 6, [145, 251, 152], "stop"),
+]
+_STAGGERED_RUNS = {}
+
+
+def _run_staggered(params, chunk):
+    """The staggered batch, once a prefill mode: the outputs by request,
+    the engine, and what the spy on ``_land`` saw — for every row the
+    engine dropped, who held the row's slot when it was."""
+    if chunk in _STAGGERED_RUNS:
+        return _STAGGERED_RUNS[chunk]
+    eng = _pinned_engine(params, chunk, slots=3)
+    dropped, land = [], eng._land
+
+    def spy(flight):
+        holders = {seq.slot: seq for seq in
+                   list(eng._active.values()) + eng._prefilling}
+        for slot, seq in flight[1]:
+            if seq.slot != slot:
+                dropped.append((seq.request_id, holders.get(slot)))
+        return land(flight)
+
+    eng._land = spy
+    pending, outs, step = list(STAGGERED), {}, 0
+    while pending or eng.has_unfinished():
+        while pending and pending[0][5] <= step:
+            rid, prompt, seed, n, stop, *_ = pending.pop(0)
+            eng.add_request(list(prompt), dataclasses.replace(
+                _sampling(seed, n), stop_token_ids=stop),
+                request_id=rid, admit=False)
+        for out in eng.step():
+            outs[out.request_id] = out
+        step += 1
+    _STAGGERED_RUNS[chunk] = outs, eng, dropped
+    return _STAGGERED_RUNS[chunk]
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("rid", [r[0] for r in STAGGERED])
+def test_pipelined_batch_answers_as_the_unpipelined_engine_did(
+        params, rid, chunk):
+    """Joins, finishes by ``max_tokens`` and by a stop token at
+    different steps: every request's ids and finish reason are the
+    ones recorded from the engine that read each step before it
+    dispatched the next."""
+    outs, _, _ = _run_staggered(params, chunk)
+    (want,) = [r for r in STAGGERED if r[0] == rid]
+    assert outs[rid].token_ids == want[6]
+    assert outs[rid].finish_reason == want[7]
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+def test_a_stop_costs_one_dropped_row_and_it_reaches_nobody(params, chunk):
+    """A stop token is read one step late: the step in flight computed
+    a row for the sequence that ended.  The row is counted as decoded
+    (``decode_slots``) and as nothing else, and where the freed slot
+    already holds a new prompt when the stale row is read, the new
+    sequence's stream is untouched (its ids are the recorded ones)."""
+    outs, eng, dropped = _run_staggered(params, chunk)
+    assert sorted(rid for rid, _ in dropped) == ["A", "C", "F"]
+    assert eng.stats["tokens_generated"] == 30       # as unpipelined
+    assert eng.stats["decode_slots"] == 30 + len(dropped)
+    assert sum(len(o.token_ids) + (o.finish_reason == "stop") - 1
+               for o in outs.values()) == 30
+    if chunk is not None:
+        # a waiting prompt took A's slot in the iteration after the stop
+        # was read: its prefill and the stale row's read crossed
+        readmitted = [seq.request_id for _, seq in dropped if seq]
+        assert readmitted, dropped
+        for rid in readmitted:
+            (want,) = [r for r in STAGGERED if r[0] == rid]
+            assert outs[rid].token_ids == want[6]
+    assert eng._flight is None and not eng._active
 
 
 def test_prompt_longer_than_bucket(params):

@@ -62,7 +62,9 @@ def _spans(trace_id, name=None):
 def test_counters_are_deterministic_for_a_fixed_batch(params):
     """The reading, documented: every decode step reads its tokens
     (1) — the sampling keys stay on the device (PR 25) — and every
-    prompt's end reads its first token (1)."""
+    prompt's end reads its first token (1).  Every decode step but the
+    batch's first is dispatched with the one before it unread (PR 31):
+    a prompt's end does not drain the pipeline."""
     readings = []
     for _ in range(2):
         eng = _engine(params)
@@ -76,6 +78,7 @@ def test_counters_are_deterministic_for_a_fixed_batch(params):
     # the first token of a prompt comes from its last chunk
     assert first["decode_slots"] == first["tokens_generated"] == 3 * 5
     assert first["d2h_syncs"] == first["decode_steps"] + len(PROMPTS)
+    assert first["decode_ahead_steps"] == first["decode_steps"] - 1
     assert 0 < first["decode_steps"] <= first["steps"]
     assert first["steps"] <= first["decode_steps"] + first["chunks"]
 
@@ -88,8 +91,8 @@ def test_blocked_time_is_counted_once_and_by_phase(params):
     while eng.has_unfinished():
         eng.step()
     before = eng.stats["d2h_syncs"]
-    assert eng.evict_session("s")           # slab k, v and its length
-    assert eng.stats["d2h_syncs"] == before + 3
+    assert eng.evict_session("s")           # slab k and v; the length
+    assert eng.stats["d2h_syncs"] == before + 2   # is the host's (PR 31)
     stats = eng.stats
     by_phase = sum(stats[f"block_{p}_s"] for p in PHASES)
     assert 0 < by_phase <= stats["block_s"]  # the eviction ran in no phase
@@ -127,7 +130,96 @@ def test_full_batch_decode_reads_once_a_step_and_splits_no_key_eagerly(
     assert eng.stats["decode_steps"] - before["decode_steps"] == 8
     assert eng.stats["decode_slots"] - before["decode_slots"] == 8 * eng.slots
     assert eng.stats["d2h_syncs"] - before["d2h_syncs"] == 8
+    assert eng.stats["decode_ahead_steps"] \
+        - before["decode_ahead_steps"] == 8
     assert reads == ["fetch"] * 8 and splits == []
+
+
+@pytest.mark.parametrize("slots,lengths", [
+    (1, [5]),                     # a lone sequence: nothing else to overlap
+    (4, [9, 4, 6, 12]),           # a batch whose rows end at different steps
+    (2, [3, 7, 5, 4, 6]),         # more requests than slots: rows rejoin
+])
+def test_a_step_is_dispatched_before_the_step_before_it_is_read(
+        params, slots, lengths):
+    """The order of an iteration is dispatch N+1, read N, emit N, one
+    deep: at most one step is unread at a dispatch, every step is read
+    exactly once, ``decode_ahead_steps`` counts the dispatches that
+    found one unread, and the phases keep their order."""
+    eng = _engine(params, slots=slots)
+    log, phases, flights = [], [], []
+    dispatch, land, enter = eng._dispatch_decode, eng._land, eng._rec.enter
+
+    def spy_dispatch(ahead):
+        flights.append(dispatch(ahead))
+        log.append(("D", len(flights) - 1, ahead))
+        return flights[-1]
+
+    def spy_land(flight):
+        (key,) = [i for i, f in enumerate(flights) if f is flight]
+        log.append(("L", key, None))
+        return land(flight)
+
+    eng._dispatch_decode, eng._land = spy_dispatch, spy_land
+    eng._rec.enter = lambda name: phases.append(name) or enter(name)
+    for i, n in enumerate(lengths):
+        eng.add_request([3 + i, 9, 17 + i], SamplingParams(max_tokens=n),
+                        admit=False)
+    outs, per_step = [], []
+    while eng.has_unfinished():
+        del phases[:]
+        outs.extend(eng.step())
+        per_step.append(list(phases))
+    assert sorted(len(o.token_ids) for o in outs) == sorted(lengths)
+    order = {key: i for i, (kind, key, _) in enumerate(log) if kind == "D"}
+    landed = [key for kind, key, _ in log if kind == "L"]
+    assert sorted(landed) == sorted(order) and len(set(landed)) == len(landed)
+    dispatched = [key for kind, key, _ in log if kind == "D"]
+    assert landed == dispatched              # read in dispatch order
+    ahead = 0
+    for k, key in enumerate(dispatched):
+        at = log.index(("L", key, None))
+        assert order[key] < at               # read after its dispatch ...
+        if k + 1 < len(dispatched):
+            nxt = order[dispatched[k + 1]]
+            ahead += nxt < at                # ... and mostly after the next
+        if k + 2 < len(dispatched):
+            assert at < order[dispatched[k + 2]]   # one deep, never two
+    stats = eng.stats
+    assert ahead == stats["decode_ahead_steps"] == sum(
+        1 for kind, _, was_ahead in log if kind == "D" and was_ahead)
+    assert 0 < ahead + 1 <= stats["decode_steps"] == len(dispatched)
+    if len(lengths) <= slots:
+        assert ahead == stats["decode_steps"] - 1   # it never drained
+    assert stats["d2h_syncs"] == stats["decode_steps"] + len(lengths)
+    assert stats["decode_slots"] == stats["tokens_generated"] \
+        == sum(lengths) - len(lengths)       # no stop token: no row dropped
+    tail = ["decode", "sample", "fetch", "emit", "housekeeping"]
+    assert any(p[-5:] == tail for p in per_step)
+    for p in per_step:                       # from the dispatch on
+        seen = p[p.index("decode"):] if "decode" in p else p[-3:]
+        assert seen == [name for name in tail if name in seen], p
+
+
+def test_decode_ahead_pct_reads_the_counter_and_nothing_without_it(params):
+    """``chipbench/layer_metrics/decode_ahead_pct.py`` over a window of
+    the engine's own stats; a program without the counter (the parent
+    of PR 31) gives None, which leaves the metric out of the line."""
+    from chipbench.layer_metrics import d2h_syncs_per_step, decode_ahead_pct
+
+    eng = _engine(params)
+    before = dict(eng.stats)
+    eng.generate(list(PROMPTS), SamplingParams(max_tokens=6))
+    obs = {"traced": {"engine": dict(eng.stats), "engine_before": before}}
+    steps = eng.stats["decode_steps"]
+    assert decode_ahead_pct.read(obs) == pytest.approx(
+        100.0 * (steps - 1) / steps)
+    assert d2h_syncs_per_step.read(obs) == pytest.approx(
+        (steps + len(PROMPTS)) / steps)
+    for side in obs["traced"].values():
+        del side["decode_ahead_steps"]
+    assert decode_ahead_pct.read(obs) is None
+    assert decode_ahead_pct.read({"traced": None}) is None
 
 
 def test_phases_tile_the_loop_and_stats_keep_their_keys(params):
@@ -182,8 +274,10 @@ def test_one_engine_span_per_sampled_request(params):
         steps = [attrs[k] for k in ("submit_step", "first_chunk_step",
                                     "first_token_step", "last_step")]
         assert steps == sorted(steps)
-        # the step that ends the prompt decodes too: tokens 1 and 2
-        assert attrs["last_step"] - attrs["first_token_step"] == 3
+        # the step that ends the prompt dispatches a decode step too,
+        # and a step's token is read in the iteration after it: tokens
+        # 2 to 5 come one iteration each after the first
+        assert attrs["last_step"] - attrs["first_token_step"] == 4
         assert "error" not in span
 
 
@@ -253,7 +347,11 @@ def test_profiler_trace_holds_engine_phases_by_step(params, tmp_path):
     steps = sorted((e.start_ns, e.start_ns + e.duration_ns,
                     dict(e.stats)["step_num"]) for e in events
                    if e.name == "engine")
-    assert [n for _, _, n in steps] == list(range(first, first + 5))
+    # and one iteration more, which only reads the last step's token
+    assert [n for _, _, n in steps] == list(range(first, first + 6))
+    fetch = sorted((e.start_ns, dict(e.stats)["step"]) for e in events
+                   if e.name == "engine:fetch")
+    assert [s for _, s in fetch] == list(range(first + 1, first + 6))
     # every phase event lies inside the step event that carries its number
     for e in events:
         if e.name.startswith("engine:"):

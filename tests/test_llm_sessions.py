@@ -141,6 +141,146 @@ def test_forced_mid_generation_evict_bit_parity(params):
     assert eng.stats["offloads"] == 1 and eng.stats["restores"] == 1
 
 
+# A session's three turns beside a plain request, as the UNPIPELINED
+# engine left them (commit b1d9bd2; 2 slots, max_seq 64, chunks of 8,
+# with and without an eviction after turn 1): turn 1 ends at a stop
+# token (its greedy stream's fourth, 42), turn 2 at ``max_tokens``, turn
+# 3 at ``max_seq`` (45 of the 60 tokens asked).  Per turn: token ids,
+# finish reason, the session's ``kv_len`` and ``carry`` after it.
+TURN_1 = ([227, 234, 128], "stop", 6, [42])
+TURN_2 = ([150, 114, 186, 12, 114, 186], "length", 16, [186])
+TURN_3 = ([243, 199, 238, 79, 66, 203, 219, 60, 147, 251, 129, 129, 18, 43,
+           110, 200, 151, 177, 190, 131, 103, 165, 133, 190, 182, 102, 99,
+           128, 53, 211, 114, 161, 62, 114, 139, 184, 82, 167, 179, 230,
+           106, 104, 157, 220, 211], "length", 63, [211])
+BESIDE = [130, 21, 114, 47, 199, 220, 199, 220, 199, 220, 199, 220, 225, 29]
+
+
+def _drain(eng, outs, until=None):
+    deadline = time.monotonic() + 120
+    while eng.has_unfinished() and until not in outs:
+        for out in eng.step():
+            outs[out.request_id] = out
+        assert time.monotonic() < deadline, "engine never drained"
+
+
+def _turn(eng, outs, rid):
+    sess = eng._sessions["s"]
+    return (outs[rid].token_ids, outs[rid].finish_reason, sess.kv_len,
+            list(sess.carry))
+
+
+@pytest.mark.parametrize("evict", [None, "idle", "pressure"])
+def test_stop_token_drops_the_row_of_the_step_in_flight(params, evict):
+    """A stop token at step N is read after step N+1 was dispatched:
+    the row N+1 computed for the ended turn is dropped — not emitted,
+    not in ``generated``, not in ``tokens_generated`` — the session's
+    ``kv_len`` and ``carry`` are the unpipelined engine's, and so are
+    the next turns' answers, whether the slab stays (the stale K/V row
+    lies where turn 2 writes the carry), is evicted by hand while the
+    plain request's step is in flight, or by admission pressure."""
+    eng = _engine(params, slots=2, max_seq=64)
+    outs = {}
+    eng.add_request([7, 8, 9], SamplingParams(max_tokens=14),
+                    request_id="bg", admit=False)
+    eng.add_request([5, 9, 17], SamplingParams(
+        max_tokens=8, stop_token_ids=(42,)), request_id="t1",
+        admit=False, session_id="s")
+    _drain(eng, outs, until="t1")
+    assert _turn(eng, outs, "t1") == TURN_1
+    sess = eng._sessions["s"]
+    assert sess.current is None and sess.state == "resident"
+    if evict == "idle":
+        assert eng._flight is not None       # bg's step, and t1's stale row
+        assert eng.evict_session("s")
+        assert eng._flight is None and sess.state == "offloaded"
+    elif evict == "pressure":
+        assert eng._flight is not None
+        eng.add_request([9, 9, 9], SamplingParams(max_tokens=2),
+                        request_id="x", admit=False)
+        eng.step()                           # no free slot: s is spilled
+        assert sess.state == "offloaded"
+        assert eng.stats["pressure_evictions"] == 1
+    eng.add_request([3, 88, 41, 2], SamplingParams(max_tokens=6),
+                    request_id="t2", admit=False, session_id="s")
+    _drain(eng, outs)
+    assert outs["bg"].token_ids == BESIDE
+    assert _turn(eng, outs, "t2") == TURN_2
+    eng.add_request([11, 12], SamplingParams(
+        max_tokens=60, temperature=0.8, top_k=40, top_p=0.95, seed=77),
+        request_id="t3", admit=False, session_id="s")
+    _drain(eng, outs)
+    assert _turn(eng, outs, "t3") == TURN_3  # ended by max_seq
+    extra = len(outs["x"].token_ids) - 1 if evict == "pressure" else 0
+    assert eng.stats["tokens_generated"] == 65 + extra
+    # one row was computed and dropped: t1's, after its stop token
+    assert eng.stats["decode_slots"] == 65 + extra + 1
+    assert eng.stats["offloads"] == eng.stats["restores"] == (evict
+                                                              is not None)
+
+
+@pytest.mark.parametrize("how", ["evict_session", "offload", "end_session",
+                                 "shutdown"])
+def test_nothing_moves_a_slot_under_a_step_in_flight(params, how):
+    """``evict_session`` (forced, mid-generation), ``_offload`` (an idle
+    session, while another request's step is in flight),
+    ``end_session`` and the loop's ``shutdown`` first land the step in
+    flight: its tokens reach their sequences, and the streams are the
+    uninterrupted ones."""
+    from ant_ray_tpu.llm.engine import EngineLoop
+
+    want = [_engine(params).generate([p], SamplingParams(max_tokens=n))[0]
+            for p, n in (([5, 9, 17, 3], 12), ([7, 8, 9], 16))]
+    eng = _engine(params)
+    if how == "shutdown":
+        loop = EngineLoop(eng)
+        handle = loop.submit([5, 9, 17, 3], SamplingParams(max_tokens=40))
+        first = handle.events.get(timeout=120)
+        assert first["type"] == "token"
+        loop.shutdown(timeout=60)
+        assert not loop._thread.is_alive() and eng._flight is None
+        streamed = [first["token_id"]]
+        while not handle.events.empty():
+            event = handle.events.get()
+            if event["type"] == "token":
+                streamed.append(event["token_id"])
+        # every token read was emitted, and the step in flight was read
+        # (a loop that outran the shutdown has finished the request)
+        for seq in eng._active.values():
+            assert streamed == seq.generated
+            assert seq.kv_len == 4 + len(streamed) - 1
+        assert eng._active or streamed == handle.wait(0).token_ids
+        return
+    outs = {}
+    eng.add_request([5, 9, 17, 3], SamplingParams(max_tokens=12),
+                    request_id="a", admit=False, session_id="s")
+    eng.add_request([7, 8, 9], SamplingParams(max_tokens=16),
+                    request_id="b", admit=False)
+    for _ in range(5):
+        eng.step()
+    assert eng._flight is not None and len(eng._flight[1]) == 2
+    seq_a, sess = eng._sessions["s"].current, eng._sessions["s"]
+    before = len(seq_a.generated)
+    if how == "evict_session":
+        assert eng.evict_session("s", force=True)
+        assert eng._flight is None and len(seq_a.generated) == before + 1
+        assert sess.kv_len == seq_a.kv_len == 4 + before
+    elif how == "offload":
+        _drain(eng, outs, until="a")         # s idle; b's step in flight
+        assert eng._flight is not None and "b" not in outs
+        seq_b = eng._flight[1][-1][1]
+        before = len(seq_b.generated)
+        eng._offload(sess)
+        assert eng._flight is None and len(seq_b.generated) == before + 1
+        assert sess.state == "offloaded" and sess.kv_len == 4 + 11
+    else:
+        assert eng.end_session("s")          # mid-turn: the turn goes on
+        assert eng._flight is None and len(seq_a.generated) == before + 1
+    _drain(eng, outs)
+    assert [outs["a"].token_ids, outs["b"].token_ids] == \
+        [o.token_ids for o in want]
+
+
 def test_sessions_beyond_slots_all_complete(params):
     """Acceptance: resident sessions exceed the KV slot count at fixed
     HBM — sessions beyond `slots` complete via offload, and their

@@ -215,6 +215,46 @@ def test_engine_batch_of_two_lengths_equals_the_reference(params):
     assert stats["moe_load_max"] > 0
 
 
+@pytest.mark.parametrize("stop", [False, True])
+def test_routing_counters_arrive_with_their_step_and_are_added_once(
+        params, stop):
+    """The decode step runs one ahead of its read: a step's counters
+    still come with that step's tokens — each reading covers exactly
+    the programs dispatched up to its sampler — and ``stats`` holds
+    what the device counted, once, when the last step has landed; a row
+    dropped after a stop token was computed, so it counts."""
+    engine = LLMEngine(CFG, params=params, slots=4, max_seq=64,
+                       prefill_chunk_tokens=8)
+    prompts = [tokens_of(5, 19).tolist(), tokens_of(6, 5).tolist()]
+    first = engine.generate(prompts[:1], SamplingParams(max_tokens=6))[0]
+    engine = LLMEngine(CFG, params=params, slots=4, max_seq=64,
+                       prefill_chunk_tokens=8)
+    readings, note = [], engine._note_routing
+    per_program = CFG.num_experts * CFG.n_layers
+
+    def spy(counters):
+        before = engine.stats["moe_expert_slots"]
+        note(counters)
+        readings.append((engine.stats["moe_expert_slots"] - before)
+                        // per_program)
+
+    engine._note_routing = spy
+    cut = first.token_ids.index(first.token_ids[3]) if stop else 6
+    assert cut > 0                   # a decode step's token, not the chunk's
+    outs = engine.generate(prompts, SamplingParams(
+        max_tokens=6, stop_token_ids=tuple(first.token_ids[cut:cut + 1])))
+    assert [len(o.token_ids) for o in outs][0] == cut
+    stats = engine.stats
+    assert len(readings) == stats["decode_steps"]
+    # every reading brings its own decode step, and the chunks between
+    # the sampler before it and its own
+    assert min(readings) == 1 and sum(readings) == \
+        stats["decode_steps"] + stats["chunks"]
+    assert [stats[name] for name in llama.ROUTING_COUNTERS] == \
+        np.asarray(engine.cache["routing"]).tolist()
+    assert stats["decode_slots"] - stats["tokens_generated"] == int(stop)
+
+
 def test_a_dense_engine_has_no_routing_counters():
     engine = LLMEngine("tiny", slots=2, max_seq=32)
     assert "routing" not in engine.cache
