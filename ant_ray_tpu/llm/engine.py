@@ -304,9 +304,14 @@ class LLMEngine:
         self.slots = slots
         self.tokenizer = tokenizer or get_tokenizer(None)
         if params is None:
+            # Random weights as ONE program: each leaf is drawn, scaled
+            # and cast in a fusion that writes the leaf alone (leaf by
+            # leaf, eagerly, the largest leaf's float32 draw lies beside
+            # the weights — 5 GiB of a 16 GiB chip for a 4 GB leaf — and
+            # every shape is three small compilations).  Same values.
             params = (loaded if loaded is not None
-                      else llama.init_params(self.config,
-                                             jax.random.PRNGKey(seed)))
+                      else jax.jit(llama.init_params, static_argnums=0)(
+                          self.config, jax.random.PRNGKey(seed)))
         self.mesh = mesh
         if tensor_parallel_size > 1 and mesh is None:
             from ant_ray_tpu.parallel.mesh import build_mesh  # noqa: PLC0415
@@ -403,25 +408,25 @@ class LLMEngine:
             return llama.decode_step(params, last_tokens, cache, cfg,
                                      active=active)
 
+        slab_names = tuple(llama.kv_slabs(cfg))  # k, v — or c_kv, k_rope
+
         def _extract(cache, slot):
             from jax import lax  # noqa: PLC0415
 
-            k = lax.dynamic_index_in_dim(cache["k"], slot, axis=1,
-                                         keepdims=False)
-            v = lax.dynamic_index_in_dim(cache["v"], slot, axis=1,
-                                         keepdims=False)
-            return k, v
+            return tuple(lax.dynamic_index_in_dim(
+                cache[name], slot, axis=1, keepdims=False)
+                for name in slab_names)
 
-        def _install(cache, k, v, length, slot):
+        def _install(cache, slabs, length, slot):
             from jax import lax  # noqa: PLC0415
 
             slot = jnp.asarray(slot, jnp.int32)
             return {
                 **cache,
-                "k": lax.dynamic_update_slice(
-                    cache["k"], k[:, None], (0, slot, 0, 0, 0)),
-                "v": lax.dynamic_update_slice(
-                    cache["v"], v[:, None], (0, slot, 0, 0, 0)),
+                **{name: lax.dynamic_update_slice(
+                    cache[name], slab[:, None],
+                    (0, slot) + (0,) * (slab.ndim - 1))
+                   for name, slab in zip(slab_names, slabs)},
                 "length": cache["length"].at[slot].set(length),
             }
 
@@ -459,6 +464,11 @@ class LLMEngine:
 
         mesh = self.mesh
         tp = mesh.shape.get("tp", 1)
+        if self.config.kv_lora_rank:
+            raise ValueError(
+                "a latent (MLA) cache has no heads axis to shard: the "
+                "engine runs latent attention on one device only "
+                "(tensor_parallel_size=1, no mesh)")
         if self.config.n_kv_heads % tp or self.config.n_heads % tp:
             raise ValueError(
                 f"tensor_parallel_size={tp} must divide n_heads="
@@ -1028,17 +1038,19 @@ class LLMEngine:
         exactly like reused slots always were."""
         self._land_flight()
         slot = sess.slot
-        k, v = self._extract_jit(self.cache, slot)
         to_host = self._rec.to_host
+        # What the layers keep of the slot (llama.kv_slabs: keys and
+        # values, or the latent and its rotary key), then its length.
         # The length is the host's: the device's counts the row of a
         # step dispatched before a stop token was read.
-        slab = (to_host(k), to_host(v), sess.kv_len)
+        slab = (*map(to_host, self._extract_jit(self.cache, slot)),
+                sess.kv_len)
         sess.handle = self._store().put(sess.session_id, slab)
         sess.slot = -1
         sess.state = "offloaded"
         self._free_slots.append(slot)
         self.stats["offloads"] += 1
-        self.stats["offload_bytes"] += (slab[0].nbytes + slab[1].nbytes)
+        self.stats["offload_bytes"] += sum(a.nbytes for a in slab[:-1])
 
     def _start_restore(self, sess: _Session):
         if sess.state != "offloaded":
@@ -1084,15 +1096,15 @@ class LLMEngine:
                 continue                     # retry next step
             slot = self._free_slots.pop()
             del self._restoring[sid]
-            k, v, ln = ticket["result"]
+            *slabs, ln = ticket["result"]
             self.cache = self._install_jit(
-                self.cache, jnp.asarray(k), jnp.asarray(v),
+                self.cache, tuple(map(jnp.asarray, slabs)),
                 jnp.int32(ln), slot)
             dur = time.monotonic() - ticket["t0"]
             self.stats["restores"] += 1
             self.stats["restore_wait_s"] += dur
             self._record_restore_span(sess, ticket, dur,
-                                      k.nbytes + v.nbytes)
+                                      sum(a.nbytes for a in slabs))
             sess.slot = slot
             sess.state = "resident"
             sess.kv_len = int(ln)

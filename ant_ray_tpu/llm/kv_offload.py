@@ -1,8 +1,10 @@
 """Tiered KV-session offload stores for the LLM engine.
 
 When the engine evicts an idle session (`kv_idle_evict_s` LRU sweep or
-KV-full admission pressure), it device-gets the session's per-slot KV
-slab as host numpy and hands it to one of these stores; on the
+KV-full admission pressure), it device-gets what the layers keep of the
+session's slot as host numpy — ``(*slabs, length)``: keys and values, or
+a latent model's latent and rotary key (``llama.kv_slabs``) — and hands
+it to one of these stores, which never look inside; on the
 session's next token the slab is fetched back (on a background thread —
 the engine step loop never blocks on a restore) and re-installed into a
 free slot.  The round trip is bitwise exact, so restored sessions'

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -24,7 +25,12 @@ from jax import lax
 
 from ant_ray_tpu.ops.attention import attention, kernel_fits
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
-from ant_ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ant_ray_tpu.ops.rope import (
+    YarnScaling,
+    apply_rope,
+    rope_frequencies,
+    yarn_mscale,
+)
 from ant_ray_tpu.parallel.sharding import logical_to_spec
 
 
@@ -52,31 +58,96 @@ class LlamaConfig:
     norm_topk_prob: bool = True
     # RMSNorm over the whole q and k projections, before RoPE (OLMoE).
     qk_norm: bool = False
+    # The router as DeepSeek-V3's family publishes it: ``scoring_func``
+    # "sigmoid" scores every expert on its own instead of a softmax over
+    # all, and the (normalised) gates are multiplied by
+    # ``routed_scaling_factor``.
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # The router's width where this program holds a SHARE of a layer's
+    # experts (0 = it holds all): the router scores ``router_width``,
+    # the ``num_experts`` held are ``first_expert`` and the ones after
+    # it, and what the others would add is left out (the other ranks of
+    # an expert-parallel deployment hold them).
+    router_width: int = 0
+    first_expert: int = 0
+    # Experts every token goes through, beside the routed ones: one
+    # SwiGLU of width ``n_shared_experts * mlp_dim``.
+    n_shared_experts: int = 0
+    # ``first_k_dense_replace``: the first layers of a routed model that
+    # have a dense SwiGLU of width ``dense_mlp_dim`` instead; they are
+    # counted in ``n_layers`` and are a stack of their own in the tree.
+    n_dense_layers: int = 0
+    dense_mlp_dim: int = 0
+    # Latent attention (MLA; 0 = grouped-query): q and k/v come through
+    # low-rank projections with an RMSNorm inside, only
+    # ``qk_rope_head_dim`` of a head's ``qk_nope_head_dim +
+    # qk_rope_head_dim`` are rotated, one rotary key serves all heads,
+    # and the cache holds ``kv_lora_rank + qk_rope_head_dim`` values a
+    # position instead of keys and values per head (``n_kv_heads`` is
+    # not read).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (latent attention only: its ``mscale_all_dim`` temperature
+    # is applied where the latent scores are made).
+    rope_scaling: YarnScaling | None = None
+
+    def __post_init__(self):
+        if self.rope_scaling is not None and not self.kv_lora_rank:
+            raise ValueError("rope_scaling is computed by the latent "
+                             "attention only")
+        if self.kv_lora_rank and not self.q_lora_rank:
+            raise ValueError("latent attention without q_lora_rank is "
+                             "not computed")
+        if self.n_dense_layers and not self.num_experts:
+            raise ValueError("n_dense_layers are the leading dense "
+                             "layers of a routed model")
 
     @property
     def head_dim(self) -> int:
+        """Width of a head's query and key."""
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.dim // self.n_heads
 
+    @property
+    def rope_dim(self) -> int:
+        """How much of a head is rotated."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """The softmax scale of the latent scores: head_dim^-1/2, times
+        the square of YaRN's ``mscale_all_dim`` temperature."""
+        scale = self.head_dim ** -0.5
+        s = self.rope_scaling
+        if s is not None and s.mscale_all_dim:
+            scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+        return scale
+
+    def stacks(self) -> dict:
+        """The model's layers in order, as stacks of LIKE layers: the
+        name of each stack in the parameter tree -> the config that
+        reads it (``n_layers`` its own).  A routed model's leading dense
+        layers are ``dense_layers``; everything else is ``layers``."""
+        rest = dataclasses.replace(
+            self, n_layers=self.n_layers - self.n_dense_layers,
+            n_dense_layers=0)
+        if not self.n_dense_layers:
+            return {"layers": rest}
+        dense = dataclasses.replace(
+            rest, n_layers=self.n_dense_layers, num_experts=0,
+            router_width=0, n_shared_experts=0,
+            mlp_dim=self.dense_mlp_dim)
+        return {"dense_layers": dense, "layers": rest}
+
     def num_params(self) -> int:
-        p = self.vocab_size * self.dim                       # embed
-        if self.num_experts:
-            mlp = (self.dim * self.num_experts               # router
-                   + 3 * self.num_experts * self.dim * self.mlp_dim)
-        else:
-            mlp = 3 * self.dim * self.mlp_dim                # gate, up, down
-        per_layer = (
-            self.dim * self.n_heads * self.head_dim          # wq
-            + 2 * self.dim * self.n_kv_heads * self.head_dim  # wk, wv
-            + self.n_heads * self.head_dim * self.dim        # wo
-            + mlp
-            + 2 * self.dim                                   # norms
-            + ((self.n_heads + self.n_kv_heads) * self.head_dim
-               if self.qk_norm else 0)                       # q, k norms
-        )
-        p += self.n_layers * per_layer + self.dim            # final norm
-        if not self.tie_embeddings:
-            p += self.dim * self.vocab_size                  # lm head
-        return p
+        """Every parameter this program holds (of a share, the share)."""
+        return sum(math.prod(shape) for shape in jax.tree.leaves(
+            param_shapes(self), is_leaf=lambda x: isinstance(x, tuple)))
 
 
 CONFIGS: dict[str, LlamaConfig] = {
@@ -107,80 +178,107 @@ CONFIGS: dict[str, LlamaConfig] = {
         mlp_dim=32, max_seq=512, dtype=jnp.float32,
         num_experts=8, experts_per_token=2, norm_topk_prob=False,
         qk_norm=True),
+    # A.X-K1's block at test size (DeepSeek-V3's family): latent
+    # attention (4 heads of 16 + 8 / 16, ranks 24 / 16, YaRN 4 x 32), one
+    # dense layer, then two whose sigmoid router scores 8 experts, 2 a
+    # token, of which this share holds 4 (experts 0-3), beside a shared one
+    "axk1-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        mlp_dim=32, max_seq=512, rope_theta=10000.0, norm_eps=1e-6,
+        dtype=jnp.float32, num_experts=4, experts_per_token=2,
+        router_scoring="sigmoid", routed_scaling_factor=2.5,
+        router_width=8, n_shared_experts=1, n_dense_layers=1,
+        dense_mlp_dim=96, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_scaling=YarnScaling(
+            factor=4.0, original_max_position_embeddings=32,
+            mscale=1.0, mscale_all_dim=1.0)),
 }
 
 
 # ---------------------------------------------------------------- params
 
-def param_shapes(config: LlamaConfig) -> dict:
-    c = config
-    hd = c.head_dim
-    if c.num_experts:
-        mlp_shapes = {
-            "router": (c.n_layers, c.dim, c.num_experts),
-            "w_gate": (c.n_layers, c.num_experts, c.dim, c.mlp_dim),
-            "w_up": (c.n_layers, c.num_experts, c.dim, c.mlp_dim),
-            "w_down": (c.n_layers, c.num_experts, c.mlp_dim, c.dim),
+def _layer_leaves(c: LlamaConfig) -> dict:
+    """One stack of like layers: leaf name -> (its shape after the
+    leading layers axis, its logical dims after it)."""
+    hd, e, p, m = c.head_dim, "embed_param", "heads_flat", "mlp"
+    if c.kv_lora_rank:
+        attn = {
+            "w_qa": ((c.dim, c.q_lora_rank), (e, None)),
+            "q_a_norm": ((c.q_lora_rank,), ("norm",)),
+            "w_qb": ((c.q_lora_rank, c.n_heads * hd), (None, p)),
+            "w_kva": ((c.dim, c.kv_lora_rank + c.qk_rope_head_dim),
+                      (e, None)),
+            "kv_a_norm": ((c.kv_lora_rank,), ("norm",)),
+            "w_kvb": ((c.kv_lora_rank, c.n_heads * (
+                c.qk_nope_head_dim + c.v_head_dim)), (None, p)),
+            "wo": ((c.n_heads * c.v_head_dim, c.dim), (p, e)),
         }
     else:
-        mlp_shapes = {
-            "w_gate": (c.n_layers, c.dim, c.mlp_dim),
-            "w_up": (c.n_layers, c.dim, c.mlp_dim),
-            "w_down": (c.n_layers, c.mlp_dim, c.dim),
+        attn = {
+            "wq": ((c.dim, c.n_heads * hd), (e, p)),
+            "wk": ((c.dim, c.n_kv_heads * hd), (e, p)),
+            "wv": ((c.dim, c.n_kv_heads * hd), (e, p)),
+            "wo": ((c.n_heads * hd, c.dim), (p, e)),
+        }
+    if c.num_experts:
+        n = c.num_experts
+        mlp = {
+            "router": ((c.dim, c.router_width or n), (None, "experts")),
+            "w_gate": ((n, c.dim, c.mlp_dim), ("experts", e, m)),
+            "w_up": ((n, c.dim, c.mlp_dim), ("experts", e, m)),
+            "w_down": ((n, c.mlp_dim, c.dim), ("experts", m, e)),
+        }
+        if c.n_shared_experts:
+            width = c.n_shared_experts * c.mlp_dim
+            mlp.update({
+                "shared_gate": ((c.dim, width), (e, m)),
+                "shared_up": ((c.dim, width), (e, m)),
+                "shared_down": ((width, c.dim), (m, e)),
+            })
+    else:
+        mlp = {
+            "w_gate": ((c.dim, c.mlp_dim), (e, m)),
+            "w_up": ((c.dim, c.mlp_dim), (e, m)),
+            "w_down": ((c.mlp_dim, c.dim), (m, e)),
         }
     return {
-        "embed": (c.vocab_size, c.dim),
-        "layers": {
-            "ln_attn": (c.n_layers, c.dim),
-            "wq": (c.n_layers, c.dim, c.n_heads * hd),
-            "wk": (c.n_layers, c.dim, c.n_kv_heads * hd),
-            "wv": (c.n_layers, c.dim, c.n_kv_heads * hd),
-            "wo": (c.n_layers, c.n_heads * hd, c.dim),
-            "ln_mlp": (c.n_layers, c.dim),
-            **mlp_shapes,
-            **({"q_norm": (c.n_layers, c.n_heads * hd),
-                "k_norm": (c.n_layers, c.n_kv_heads * hd)}
-               if c.qk_norm else {}),
-        },
-        "norm_f": (c.dim,),
-        **({} if config.tie_embeddings else
-           {"lm_head": (c.dim, c.vocab_size)}),
+        "ln_attn": ((c.dim,), ("norm",)),
+        **attn,
+        "ln_mlp": ((c.dim,), ("norm",)),
+        **mlp,
+        **({"q_norm": ((c.n_heads * hd,), ("norm",)),
+            "k_norm": ((c.n_kv_heads * hd,), ("norm",))}
+           if c.qk_norm else {}),
     }
+
+
+def _param_tree(config: LlamaConfig, pick) -> dict:
+    """The parameter tree with ``pick(layers, shape, dims)`` at every
+    leaf (``layers`` None outside the stacks).  A routed model's leading
+    dense layers are a stack of their own, ``dense_layers``, beside
+    ``layers``, which then holds the routed ones only."""
+    c = config
+    return {
+        "embed": pick(None, (c.vocab_size, c.dim), ("vocab", "embed_param")),
+        **{name: {leaf: pick(stack.n_layers, *both)
+                  for leaf, both in _layer_leaves(stack).items()}
+           for name, stack in c.stacks().items()},
+        "norm_f": pick(None, (c.dim,), ("norm",)),
+        **({} if c.tie_embeddings else {"lm_head": pick(
+            None, (c.dim, c.vocab_size), ("embed_param", "vocab"))}),
+    }
+
+
+def param_shapes(config: LlamaConfig) -> dict:
+    return _param_tree(config, lambda n, shape, _dims: (
+        shape if n is None else (n, *shape)))
 
 
 def param_logical_dims(config: LlamaConfig) -> dict:
     """Logical dim names per param (see parallel/sharding.py rules)."""
-    if config.num_experts:
-        mlp_dims = {
-            "router": (None, None, "experts"),
-            "w_gate": (None, "experts", "embed_param", "mlp"),
-            "w_up": (None, "experts", "embed_param", "mlp"),
-            "w_down": (None, "experts", "mlp", "embed_param"),
-        }
-    else:
-        mlp_dims = {
-            "w_gate": (None, "embed_param", "mlp"),
-            "w_up": (None, "embed_param", "mlp"),
-            "w_down": (None, "mlp", "embed_param"),
-        }
-    tree = {
-        "embed": ("vocab", "embed_param"),
-        "layers": {
-            "ln_attn": (None, "norm"),
-            "wq": (None, "embed_param", "heads_flat"),
-            "wk": (None, "embed_param", "heads_flat"),
-            "wv": (None, "embed_param", "heads_flat"),
-            "wo": (None, "heads_flat", "embed_param"),
-            "ln_mlp": (None, "norm"),
-            **mlp_dims,
-            **({"q_norm": (None, "norm"), "k_norm": (None, "norm")}
-               if config.qk_norm else {}),
-        },
-        "norm_f": ("norm",),
-    }
-    if not config.tie_embeddings:
-        tree["lm_head"] = ("embed_param", "vocab")
-    return tree
+    return _param_tree(config, lambda n, _shape, dims: (
+        dims if n is None else (None, *dims)))
 
 
 # extra rule: flattened (heads*head_dim) dims shard over tp
@@ -243,23 +341,31 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     keys and the values, heads split, and returns the attention output,
     shaped as the queries, beside what the caller keeps of the layer —
     nothing, its (xk, xv), or the KV cache with its rows written
-    (``_scan_layers``).  ``positions``: int32 of ``x``'s leading shape,
+    (``_scan_layers``).  With latent attention the block hands it a
+    position's latent and its one rotary key in the keys' and values'
+    places, and the layer's up-projection as a fourth argument
+    (``_latent_qkv``).  ``positions``: int32 of ``x``'s leading shape,
     None for arange over the sequence.  ``index``: the layer's number
     where ``layer`` holds the whole stack's expert matrices
     (``_routed_mlp``).  Returns ``(x, state, load)``, ``load`` as
     ``_mlp`` gives it."""
     lead = x.shape[:-1]
     h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-    xq, xk = _qk_proj(layer, h, c)
-    xq = xq.reshape(*lead, c.n_heads, c.head_dim)
-    xk = xk.reshape(*lead, c.n_kv_heads, c.head_dim)
-    xv = (h @ layer["wv"]).reshape(*lead, c.n_kv_heads, c.head_dim)
-    xq = apply_rope(xq, cos, sin, positions)
-    xk = apply_rope(xk, cos, sin, positions)
-    xq = constrain_act(xq, ("batch", "seq", "heads", "head_dim"))
-    xk = constrain_act(xk, ("batch", "seq", "kv_heads", "head_dim"))
-    attn, state = attend(xq, xk, xv)
-    attn = attn.reshape(*lead, c.n_heads * c.head_dim)
+    if c.kv_lora_rank:
+        with jax.named_scope("mla"):
+            xq, c_kv, k_rope = _latent_qkv(layer, h, c, cos, sin, positions)
+            attn, state = attend(xq, c_kv, k_rope, layer["w_kvb"])
+    else:
+        xq, xk = _qk_proj(layer, h, c)
+        xq = xq.reshape(*lead, c.n_heads, c.head_dim)
+        xk = xk.reshape(*lead, c.n_kv_heads, c.head_dim)
+        xv = (h @ layer["wv"]).reshape(*lead, c.n_kv_heads, c.head_dim)
+        xq = apply_rope(xq, cos, sin, positions)
+        xk = apply_rope(xk, cos, sin, positions)
+        xq = constrain_act(xq, ("batch", "seq", "heads", "head_dim"))
+        xk = constrain_act(xk, ("batch", "seq", "kv_heads", "head_dim"))
+        attn, state = attend(xq, xk, xv)
+    attn = attn.reshape(*lead, -1)               # heads * value width
     x = x + (attn @ layer["wo"]).astype(x.dtype)
     x = constrain_act(x, ("batch", "seq", "embed"))
 
@@ -289,28 +395,94 @@ def _qk_proj(layer: dict, h, c: LlamaConfig):
     return xq, xk
 
 
+def _latent_qkv(layer: dict, h, c: LlamaConfig, cos, sin, positions):
+    """Latent attention's three products of ``h`` (..., dim), as
+    DeepSeek-V2 (arXiv 2405.04434, section 2.1) publishes them: the
+    queries ``xq`` (..., heads, nope + rope) through a low-rank
+    projection with an RMSNorm inside, their last ``qk_rope_head_dim``
+    rotated; the position's latent ``c_kv`` (..., kv_lora_rank), after
+    its RMSNorm; and its rotary key ``k_rope`` (..., rope), rotated,
+    ONE for all heads.  ``c_kv`` and ``k_rope`` are all a cache keeps of
+    the position; per-head keys and values are ``c_kv @ w_kvb``, made
+    (``_attend_latent_rows``) or absorbed (``_attend_latent_slab``)
+    where the scores are.  The one place they are made, for training,
+    chunks and decode."""
+    nope, rank = c.qk_nope_head_dim, c.kv_lora_rank
+    xq = rmsnorm(h @ layer["w_qa"], layer["q_a_norm"], c.norm_eps)
+    xq = (xq @ layer["w_qb"]).reshape(*h.shape[:-1], c.n_heads, c.head_dim)
+    xq = jnp.concatenate(
+        [xq[..., :nope], apply_rope(xq[..., nope:], cos, sin, positions)],
+        axis=-1)
+    kv = h @ layer["w_kva"]
+    c_kv = rmsnorm(kv[..., :rank], layer["kv_a_norm"], c.norm_eps)
+    k_rope = apply_rope(kv[..., None, rank:], cos, sin, positions)[..., 0, :]
+    return xq, c_kv, k_rope
+
+
+def _kvb_by_head(w_kvb, c: LlamaConfig):
+    """``w_kvb`` (rank, heads * (nope + v)) -> its keys' part (rank,
+    heads, nope) and its values' part (rank, heads, v)."""
+    w = w_kvb.reshape(c.kv_lora_rank, c.n_heads, -1)
+    return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+
+
+def _attend_latent_rows(xq, c_kv, k_rope, w_kvb, c: LlamaConfig):
+    """Causal latent attention of whole sequences over themselves, no
+    cache, in the published per-head form: every position's keys and
+    values are made from its latent.  xq (b, s, heads, nope + rope),
+    c_kv (b, s, rank), k_rope (b, s, rope) -> (b, s, heads, v).  Plain
+    products with a float32 softmax (the flash kernel takes one width
+    for q, k and v)."""
+    wk, wv = _kvb_by_head(w_kvb, c)
+    k = jnp.einsum("bsc,chd->bshd", c_kv, wk)
+    v = jnp.einsum("bsc,chd->bshd", c_kv, wv)
+    k = jnp.concatenate([k, jnp.broadcast_to(
+        k_rope[:, :, None, :], (*k.shape[:-1], k_rope.shape[-1]))], axis=-1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", xq, k,
+                        preferred_element_type=jnp.float32) * c.attn_scale
+    seq = xq.shape[1]
+    scores = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), scores,
+                       -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(xq.dtype)
+
+
+def _swiglu(h, w_gate, w_up, w_down):
+    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+
+
 def _mlp(layer: dict, h, c: LlamaConfig, index=None):
     """The block's feed-forward on ``h`` (..., dim): dense SwiGLU, or
-    the routed experts.  Returns ``(out, load)``; ``load`` is the
-    (num_experts,) int32 count of rows each expert was given, None for
-    a dense model.  The one MLP of training, chunks and decode."""
-    if c.num_experts:
-        return _routed_mlp(layer, h, c, index)
-    gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-    return gated @ layer["w_down"], None
+    the routed experts, with the shared expert beside them where the
+    model has one.  Returns ``(out, load)``; ``load`` is the
+    (num_experts,) int32 count of rows each expert held was given, None
+    for a dense layer.  The one MLP of training, chunks and decode."""
+    if not c.num_experts:
+        return _swiglu(h, layer["w_gate"], layer["w_up"],
+                       layer["w_down"]), None
+    out, load = _routed_mlp(layer, h, c, index)
+    if c.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            out = out + _swiglu(h, layer["shared_gate"], layer["shared_up"],
+                                layer["shared_down"])
+    return out, load
 
 
 def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
-    """Top-k mixture of experts; every token is computed by its k
-    experts only, and none is dropped.
+    """Top-k mixture of experts; every token is computed by those of its
+    k experts that are held here, and none is dropped.
 
-    The router's probabilities are a float32 softmax over ALL experts;
-    the gates are its k largest (divided by their sum only when
-    ``norm_topk_prob``).  The tokens * k (token, expert) assignments are
-    sorted by expert, so each expert's rows lie together, and
-    ``lax.ragged_dot`` multiplies each run of rows with its expert's
-    matrix: a grouped product whose operations are those of k experts a
-    token, and which reads an expert's weights only if it has a row.
+    The router's scores are float32, a softmax over ALL experts or
+    (``router_scoring`` "sigmoid") each expert's own sigmoid; the gates
+    are the k largest (divided by their sum only when
+    ``norm_topk_prob``), times ``routed_scaling_factor``.  The tokens *
+    k (token, expert) assignments are sorted by expert, so each expert's
+    rows lie together, and ``lax.ragged_dot`` multiplies each run of
+    rows with its expert's matrix: a grouped product whose operations
+    are those of k experts a token, and which reads an expert's weights
+    only if it has a row.
     On the TPU XLA compiles it to a grouped-matmul kernel; elsewhere to
     masked dense products (the same values).  The rows then go back to
     token order and are summed under their float32 gates.  Shapes are
@@ -318,6 +490,14 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
     serves the training step, a prefill chunk and a decode step, and
     differentiates as written.  With experts sharded over ``ep`` the
     partitioner splits the grouped product by expert.
+
+    Where the router is wider than the experts held (``router_width``
+    against ``num_experts`` from ``first_expert`` on), the routing is
+    over all of them and the products over the held ones: an assignment
+    to an absent expert sorts behind the last group, belongs to no
+    group — the grouped product's sizes add up to the assignments held,
+    so it neither reads a weight nor multiplies for it — and counts
+    zero in the sum.  Nothing stands in for the absent experts.
 
     ``layer``'s expert matrices are one layer's (experts, in, out), as
     a scan over the stacked layers slices them — or, with ``index``,
@@ -330,14 +510,25 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
     """
     lead, dim = h.shape[:-1], h.shape[-1]
     k, n_exp = c.experts_per_token, c.num_experts
+    share = bool(c.router_width) and c.router_width != n_exp
     with jax.named_scope("moe"):
         x = h.reshape(-1, dim)
-        probs = jax.nn.softmax(jnp.dot(
-            x, layer["router"], preferred_element_type=jnp.float32), axis=-1)
-        gates, experts = lax.top_k(probs, k)               # (tokens, k)
+        logits = jnp.dot(x, layer["router"],
+                         preferred_element_type=jnp.float32)
+        scores = (jax.nn.sigmoid(logits) if c.router_scoring == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        gates, experts = lax.top_k(scores, k)              # (tokens, k)
         if c.norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        if c.routed_scaling_factor != 1.0:
+            gates = gates * c.routed_scaling_factor
         experts = experts.reshape(-1)                      # (tokens * k,)
+        if share:
+            # held experts 0..n_exp-1; every absent one n_exp, which the
+            # sort puts last and the count below drops (out of bounds)
+            experts = experts - c.first_expert
+            experts = jnp.where((experts >= 0) & (experts < n_exp),
+                                experts, n_exp)
         order = jnp.argsort(experts)                       # stable
         load = jnp.zeros((n_exp,), jnp.int32).at[experts].add(1)
         rows = x[order // k]                               # sorted by expert
@@ -352,9 +543,50 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
         gated = jax.nn.silu(grouped(rows, layer["w_gate"])) * grouped(
             rows, layer["w_up"])
         out = grouped(gated.astype(h.dtype), layer["w_down"])
+        if share:
+            # a row of no group is whatever the product left there
+            out = jnp.where(
+                (jnp.arange(out.shape[0]) < jnp.sum(load))[:, None], out, 0.0)
         out = out[jnp.argsort(order)].reshape(-1, k, dim)  # token order
         out = jnp.sum(out * gates[..., None], axis=1)
     return out.astype(h.dtype).reshape(*lead, dim), load
+
+
+def _rope_tables(c: LlamaConfig):
+    """cos and sin (max_seq, rope_dim / 2) float32 of what a head
+    rotates."""
+    return rope_frequencies(c.rope_dim, c.max_seq, c.rope_theta,
+                            jnp.float32, c.rope_scaling)
+
+
+def _stacks(params: dict, c: LlamaConfig) -> list:
+    """``[(a stack's stacked leaves, the config that reads them)]``, in
+    the layers' order (``LlamaConfig.stacks``)."""
+    return [(params[name], cfg) for name, cfg in c.stacks().items()]
+
+
+def _checkpointed(block, remat: str):
+    """``block`` under ``forward``'s ``remat`` policy."""
+    if remat == "full":
+        return jax.checkpoint(block)
+    if remat == "dots":
+        return jax.checkpoint(
+            block,
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    if remat == "matmuls":
+        # Saves every matmul output (batch dims included) plus the flash
+        # kernel's named residuals (attention output + logsumexp) — in a
+        # transformer block that is all the expensive ops, so backward
+        # recomputes only the elementwise tail and never re-runs the
+        # attention forward.  ~3× the activation HBM of "full",
+        # near-"none" step time; the single-chip bench sweet spot when
+        # "none" OOMs.
+        from ant_ray_tpu.ops.attention import saveable_attention_policy  # noqa: PLC0415
+
+        return jax.checkpoint(block, policy=saveable_attention_policy())
+    if remat != "none":
+        raise ValueError(f"unknown remat policy {remat!r}")
+    return block
 
 
 def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
@@ -367,10 +599,11 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     (batch over dp/fsdp, seq over sp, heads over tp) and sequence-sharded
     meshes use ring attention.
 
-    ``return_kv=True`` additionally returns the per-layer K/V
-    (layers, b, s, kv_heads, hd), which the block's attention hands back
-    as its state, for the bucketed prefill (``prefill_into_cache``) to
-    put into a slot;
+    ``return_kv=True`` additionally returns what the layers keep of
+    every position (``kv_slabs``: the per-layer K and V, (layers, b, s,
+    kv_heads, hd) each — or a latent model's latents and rotary keys),
+    which the block's attention hands back as its state, for the
+    bucketed prefill (``prefill_into_cache``) to put into a slot;
     ``logits_at`` (traced scalar position) computes logits for that one
     position only — (b, vocab) — skipping the full-sequence lm-head
     matmul.
@@ -382,8 +615,7 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     bench).
     """
     c = config
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
-                                jnp.float32)
+    cos, sin = _rope_tables(c)
     use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
 
     def constrain_act(x, dims):
@@ -394,9 +626,11 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         spec = logical_to_spec(dims, llama_rules())
         return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
-    def attend(xq, xk, xv):
+    def attend(xq, xk, xv, w_kvb=None):
         # no cache: the whole sequence attends over itself
-        if use_ring:
+        if w_kvb is not None:       # latent: xk, xv are c_kv and k_rope
+            out = _attend_latent_rows(xq, xk, xv, w_kvb, c)
+        elif use_ring:
             from ant_ray_tpu.parallel.ring import ring_attention  # noqa: PLC0415
 
             out = ring_attention(xq, xk, xv, mesh=mesh, causal=True)
@@ -413,30 +647,13 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         kv = (xk.astype(c.dtype), xv.astype(c.dtype)) if return_kv else None
         return out, kv
 
-    def block(x, layer):
-        x, kv, _ = apply_block(layer, x, c, cos, sin, positions, attend,
-                               constrain_act)
-        return x, kv
+    def scan_stack(x, stack, cfg):
+        def block(x, layer):
+            x, kv, _ = apply_block(layer, x, cfg, cos, sin, positions,
+                                   attend, constrain_act)
+            return x, kv
 
-    if remat == "full":
-        block = jax.checkpoint(block)
-    elif remat == "dots":
-        block = jax.checkpoint(
-            block,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    elif remat == "matmuls":
-        # Saves every matmul output (batch dims included) plus the flash
-        # kernel's named residuals (attention output + logsumexp) — in a
-        # transformer block that is all the expensive ops, so backward
-        # recomputes only the elementwise tail and never re-runs the
-        # attention forward.  ~3× the activation HBM of "full",
-        # near-"none" step time; the single-chip bench sweet spot when
-        # "none" OOMs.
-        from ant_ray_tpu.ops.attention import saveable_attention_policy  # noqa: PLC0415
-
-        block = jax.checkpoint(block, policy=saveable_attention_policy())
-    elif remat != "none":
-        raise ValueError(f"unknown remat policy {remat!r}")
+        return lax.scan(_checkpointed(block, remat), x, stack)
 
     x = params["embed"][tokens].astype(c.dtype)
     # Staged reshard: first acknowledge the gather's TABLE-natural
@@ -454,7 +671,12 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         x = lax.with_sharding_constraint(
             x, NamedSharding(mesh, PartitionSpec("dp", "sp", "fsdp")))
     x = constrain_act(x, ("batch", "seq", "embed"))
-    x, kv = lax.scan(block, x, params["layers"])
+    kvs = []
+    for stack, cfg in _stacks(params, c):
+        x, kv = scan_stack(x, stack, cfg)
+        kvs.append(kv)
+    kv = kvs[0] if len(kvs) == 1 else jax.tree.map(
+        lambda *parts: jnp.concatenate(parts), *kvs)
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     if logits_at is not None:
@@ -500,8 +722,11 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
     pp = mesh.shape["pp"]
     if c.n_layers % pp != 0:
         raise ValueError(f"n_layers {c.n_layers} % pp {pp} != 0")
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
-                                jnp.float32)
+    if c.n_dense_layers or c.kv_lora_rank:
+        raise ValueError("the pipeline schedule runs one stack of "
+                         "grouped-query layers: no leading dense layers, "
+                         "no latent attention")
+    cos, sin = _rope_tables(c)
 
     def attend(xq, xk, xv):
         return attention(xq, xk, xv, causal=True, impl=attn_impl), None
@@ -538,12 +763,17 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
 
 def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
     """Training FLOPs/token (6·N matmul + attention quadratic term); of
-    a routed model's experts N holds the k a token multiplies with."""
+    a routed model's experts N holds the k a token multiplies with — of
+    a share of them (``router_width``), the part of k that falls on
+    it when the router is even."""
     c = config
-    idle = max(c.num_experts - c.experts_per_token, 0)
-    matmul = 6 * (c.num_params()
-                  - c.n_layers * idle * 3 * c.dim * c.mlp_dim)
-    attn = 12 * c.n_layers * c.head_dim * c.n_heads * seq_len
+    active = c.experts_per_token * c.num_experts / (
+        c.router_width or c.num_experts or 1)
+    idle = max(c.num_experts - active, 0)
+    matmul = 6 * (c.num_params() - (c.n_layers - c.n_dense_layers)
+                  * idle * 3 * c.dim * c.mlp_dim)
+    attn = 6 * c.n_layers * c.n_heads * seq_len * (
+        c.head_dim + (c.v_head_dim or c.head_dim))
     return matmul + attn
 
 
@@ -558,11 +788,25 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
 # (``_scan_layers``).  Both run ``apply_block``; what is theirs is which
 # rows they write and which slab they attend over.
 
+def kv_slabs(config: LlamaConfig) -> dict:
+    """What a layer keeps of a position: the cache's two slab leaves by
+    name, each with the shape of one position.  Keys and values per KV
+    head — or, with latent attention, the position's latent ``c_kv``
+    (after its norm) and its one rotary key ``k_rope`` (rotated),
+    ``kv_lora_rank + qk_rope_head_dim`` values and no heads axis."""
+    c = config
+    if c.kv_lora_rank:
+        return {"c_kv": (c.kv_lora_rank,), "k_rope": (c.qk_rope_head_dim,)}
+    return {"k": (c.n_kv_heads, c.head_dim), "v": (c.n_kv_heads, c.head_dim)}
+
+
 def init_kv_cache(config: LlamaConfig, slots: int,
                   max_seq: int | None = None) -> dict:
-    """Per-slot dense KV slabs: (layers, slots, max_seq, kv_heads, hd).
-    A routed model's cache also carries ``routing``, the step programs'
-    running counters (``ROUTING_COUNTERS``).
+    """Per-slot dense slabs (layers, slots, max_seq, *position), one for
+    each of ``kv_slabs``; all ``n_layers`` of a model lie in one slab,
+    its leading dense layers first.  A routed model's cache also carries
+    ``routing``, the step programs' running counters
+    (``ROUTING_COUNTERS``).
 
     Whoever jits a step program owns these buffers and DONATES them
     (``llm/engine.py``: ``donate_argnums=(1,)``): every leaf of the
@@ -571,13 +815,10 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     the donation a call allocates and fills a second whole cache."""
     c = config
     ms = max_seq or c.max_seq
-    shape = (c.n_layers, slots, ms, c.n_kv_heads, c.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, c.dtype),
-        "v": jnp.zeros(shape, c.dtype),
-        # tokens already written per slot (== next write position)
-        "length": jnp.zeros((slots,), jnp.int32),
-    }
+    cache = {name: jnp.zeros((c.n_layers, slots, ms, *position), c.dtype)
+             for name, position in kv_slabs(c).items()}
+    # tokens already written per slot (== next write position)
+    cache["length"] = jnp.zeros((slots,), jnp.int32)
     if c.num_experts:
         cache["routing"] = jnp.zeros((len(ROUTING_COUNTERS),), jnp.uint32)
     return cache
@@ -587,22 +828,28 @@ def init_kv_cache(config: LlamaConfig, slots: int,
 # model's routing, summed over layers and executions in
 # ``cache["routing"]`` (uint32, wraps; a reader takes differences).  They
 # count the rows the program computed, padded and idle ones included:
-# that is what decides which expert weights a step reads.
+# that is what decides which expert weights a step reads.  The first four
+# are over the experts HELD (all of them, unless the model is a share).
 ROUTING_COUNTERS = (
     "moe_assignments",    # (row, expert) pairs computed
     "moe_experts_hit",    # experts given at least one row
     "moe_expert_slots",   # experts there were: num_experts a layer
     "moe_load_max",       # rows of each layer's busiest expert, summed
+    "moe_rows_routed",    # (row, expert) pairs routed: rows * k, held or not
+    # The same of the decode steps alone: a chunk's rows are ONE
+    # sequence's and route alike, so what a decode step reads cannot be
+    # told from counters that a window's share of chunks moves.
+    "moe_decode_assignments", "moe_decode_experts_hit",
+    "moe_decode_expert_slots", "moe_decode_rows_routed",
 )
 
 
 def _hoist_experts(layers: dict, c: LlamaConfig):
-    """The stacked layers as ``_scan_layers``' scan takes them: ``(the
+    """A stack of layers as ``_scan_layers``' scan takes it: ``(the
     leaves it slices layer by layer, the expert matrices it closes over
     whole, the layer indices it scans beside them)`` — see
-    ``_routed_mlp`` on why; a dense model's layers are all sliced.  The
-    index is also where a layer finds its part of the carried cache."""
-    index = jnp.arange(c.n_layers)
+    ``_routed_mlp`` on why; a dense stack's layers are all sliced."""
+    index = jnp.arange(layers["ln_attn"].shape[0])
     if not c.num_experts:
         return layers, {}, index
     whole = {name: layers[name] for name in ("w_gate", "w_up", "w_down")}
@@ -611,51 +858,58 @@ def _hoist_experts(layers: dict, c: LlamaConfig):
     return sliced, whole, index
 
 
-def _count_routing(cache: dict, loads) -> dict:
-    """``loads``: (layers, num_experts) rows per expert of one
-    execution, None for a dense model -> the cache entries to carry."""
+def _count_routing(cache: dict, loads, routed, decode: bool) -> dict:
+    """``loads``: (layers, num_experts) rows per expert held of one
+    execution, None for a dense model; ``routed``: the (row, expert)
+    pairs its routers made, held or not; ``decode``: the execution is a
+    decode step -> the cache entries to carry."""
     if loads is None:
         return {}
     seen = jnp.stack([jnp.sum(loads), jnp.sum(loads > 0), loads.size,
-                      jnp.sum(jnp.max(loads, axis=-1))])
+                      jnp.sum(jnp.max(loads, axis=-1)), routed])
+    apart = seen[jnp.array([0, 1, 2, 4])]
+    seen = jnp.concatenate([seen, apart if decode else apart * 0])
     return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
 
 
 def prefill_into_cache(params: dict, tokens, cache: dict, slot,
                        length, config: LlamaConfig, *, mesh=None):
-    """Run prefill on one padded prompt (1, s) and write its K/V into
-    ``slot``; returns (last-token logits (vocab,), new cache).
+    """Run prefill on one padded prompt (1, s) and write what its
+    layers keep of it into ``slot``; returns (last-token logits
+    (vocab,), new cache).
 
     ``slot`` and ``length`` may be traced (one compile per prompt
     bucket, none per slot); logits are computed for the last real token
-    only — the padded tail writes garbage K/V that decode masks (and
+    only — the padded tail writes garbage rows that decode masks (and
     later overwrites)."""
     last_pos = jnp.maximum(length - 1, 0)
     # Prompt buckets start at 16 tokens: below the flash kernel's tile
     # the blockwise path is named, as the dispatcher demands on a TPU.
     qkv_shape = (1, tokens.shape[1], config.n_heads, config.head_dim)
-    logits, ks, vs = forward(
+    logits, *kept = forward(
         params, tokens, config, mesh=mesh, return_kv=True,
         logits_at=last_pos,
         attn_impl="auto" if kernel_fits(qkv_shape, qkv_shape)
         else "blockwise")
     cache = dict(cache)
     slot = jnp.asarray(slot, jnp.int32)
-    cache["k"] = lax.dynamic_update_slice(
-        cache["k"], ks, (0, slot, 0, 0, 0))
-    cache["v"] = lax.dynamic_update_slice(
-        cache["v"], vs, (0, slot, 0, 0, 0))
+    for name, rows in zip(kv_slabs(config), kept):
+        cache[name] = lax.dynamic_update_slice(
+            cache[name], rows, (0, slot) + (0,) * (rows.ndim - 2))
     cache["length"] = cache["length"].at[slot].set(length)
     return logits[0], cache
 
 
-def _attend_slab(xq, ck, cv, pos, c: LlamaConfig):
+def _attend_slab(xq, ck, cv, pos, c: LlamaConfig, w_kvb=None):
     """Grouped-query attention of rows ``xq`` (rows, heads, hd), row
     ``r`` over cached positions 0..``pos[r]``: against ONE slab
     (max_seq, kv_heads, hd) that the rows share (a chunk's slot), or a
     slab a row (rows, max_seq, kv_heads, hd) (a decode step's slots).
     bf16 inputs with fp32 accumulation keep the products at full MXU
-    rate without an fp32 copy of the slab (see ops/attention)."""
+    rate without an fp32 copy of the slab (see ops/attention).  With
+    ``w_kvb`` the slabs are latent ones: ``_attend_latent_slab``."""
+    if w_kvb is not None:
+        return _attend_latent_slab(xq, ck, cv, pos, c, w_kvb)
     slab = "rtkd" if ck.ndim == 4 else "tkd"
     q = xq.reshape(xq.shape[0], c.n_kv_heads, c.n_heads // c.n_kv_heads,
                    c.head_dim)
@@ -670,37 +924,103 @@ def _attend_slab(xq, ck, cv, pos, c: LlamaConfig):
     return out.reshape(xq.shape).astype(xq.dtype)
 
 
-def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
-                 write_attend):
-    """A step program's layers over rows ``x`` (rows, dim): one
-    ``lax.scan`` of ``apply_block`` whose carry is the rows and the
-    whole cache.  Returns (x, new k, new v, loads).
+def _attend_latent_slab(xq, c_kv, k_rope, pos, c: LlamaConfig, w_kvb):
+    """Latent attention of rows ``xq`` (rows, heads, nope + rope), row
+    ``r`` over cached positions 0..``pos[r]`` of the latent slabs
+    ``c_kv`` (max_seq, rank) and ``k_rope`` (max_seq, rope) — one pair
+    the rows share, or a pair a row (rows, max_seq, ·) — in the
+    ABSORBED form: ``w_kvb``'s keys' part goes into the query and its
+    values' part onto the output,
 
-    ``write_attend(ks, vs, i, xq, xk, xv) -> (out, (ks, vs))`` is the
-    block's attention over the carried slabs, and has one order: the
-    cache travels as the loop's CARRY, which the compiler aliases to the
-    donated input, so layer ``i``'s new rows are written where they lie
-    (a row whose position is max_seq is dropped by the scatter), and
+        score_h(s) = (q_nope_h W_K,h^T) · c_kv(s) + q_rope_h · k_rope(s)
+        out_h      = (sum_s p_h(s) c_kv(s)) W_V,h
+
+    the same values as making every cached position's keys and values
+    (c_kv(s) W_K,h, c_kv(s) W_V,h) first, without ever holding them:
+    per position a head then multiplies rank + rope and rank values
+    against the slab's one row instead of reading nope + rope and v of
+    its own.  A chunk runs it too: against a slab of max_seq rows, making
+    the keys and values costs max_seq * rank * heads * (nope + v)
+    multiply-adds, which the narrower per-head products win back only
+    beyond some 170 rows a call."""
+    nope = c.qk_nope_head_dim
+    wk, wv = _kvb_by_head(w_kvb, c)
+    slab = "rtc" if c_kv.ndim == 3 else "tc"
+    q_lat = jnp.einsum("rhd,chd->rhc", xq[..., :nope], wk,
+                       preferred_element_type=jnp.float32).astype(xq.dtype)
+    scores = jnp.einsum(f"rhc,{slab}->rht", q_lat, c_kv,
+                        preferred_element_type=jnp.float32)
+    scores = scores + jnp.einsum(f"rhc,{slab}->rht", xq[..., nope:], k_rope,
+                                 preferred_element_type=jnp.float32)
+    scores = scores * c.attn_scale
+    valid = jnp.arange(c_kv.shape[-2])[None, :] <= pos[:, None]  # (rows, ms)
+    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum(f"rht,{slab}->rhc", probs.astype(c_kv.dtype), c_kv,
+                     preferred_element_type=jnp.float32).astype(xq.dtype)
+    out = jnp.einsum("rhc,chd->rhd", ctx, wv,
+                     preferred_element_type=jnp.float32)
+    return out.astype(xq.dtype)
+
+
+def _slab_positions(cache: dict, c: LlamaConfig) -> int:
+    """A slot's ``max_seq``, as the cache was made."""
+    return cache[next(iter(kv_slabs(c)))].shape[2]
+
+
+def _slab_at(slabs, i, slot):
+    """(layer ``i``, ``slot``)'s slab (max_seq, *position) out of the
+    carried array."""
+    return lax.dynamic_slice(
+        slabs, (i, slot) + (0,) * (slabs.ndim - 2),
+        (1, 1) + slabs.shape[2:])[0, 0]
+
+
+def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
+                 write_attend, *, decode: bool):
+    """A step program's layers over rows ``x`` (rows, dim): a
+    ``lax.scan`` of ``apply_block`` over each stack of like layers
+    (``_stacks``), whose carry is the rows and the whole cache.  Returns
+    (x, the cache's new entries: its two slabs, its counters — a
+    ``decode`` step's counted apart as well, ``ROUTING_COUNTERS``).
+
+    ``write_attend(ks, vs, i, xq, xk, xv[, w_kvb]) -> (out, (ks, vs))``
+    is the block's attention over the carried slabs (``kv_slabs``: keys
+    and values, or the latent and the rotary key), and has one order:
+    the cache travels as the loop's CARRY, which the compiler aliases to
+    the donated input, so layer ``i``'s new rows are written where they
+    lie (a row whose position is max_seq is dropped by the scatter), and
     only THEN is the slab sliced out of the carried array to feed
     ``_attend_slab``.  As a scanned input and output of the loop the
     slabs are copied about three times a call; attending over the old
     slab with the new rows beside it compiles to more temporaries and
     reorders the float32 sums."""
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
-                                jnp.float32)
-    layers, experts, index = _hoist_experts(params["layers"], c)
+    cos, sin = _rope_tables(c)
+    names = tuple(kv_slabs(c))
 
-    def block(carry, scanned):
-        x, ks, vs = carry                        # ks/vs: the whole cache
-        layer, i = scanned
-        x, (ks, vs), load = apply_block(
-            {**layer, **experts}, x, c, cos, sin, positions,
-            functools.partial(write_attend, ks, vs, i), _unconstrained, i)
-        return (x, ks, vs), load
+    def scan_stack(carry, stack, cfg, first):
+        """``first``: the stack's first layer's place in the cache."""
+        layers, experts, index = _hoist_experts(stack, cfg)
 
-    (x, ks, vs), loads = lax.scan(
-        block, (x, cache["k"], cache["v"]), (layers, index))
-    return x, ks, vs, loads
+        def block(carry, scanned):
+            x, ks, vs = carry                    # ks/vs: the whole cache
+            layer, i = scanned                   # i: the layer's number
+            x, (ks, vs), load = apply_block(     # in its stack
+                {**layer, **experts}, x, cfg, cos, sin, positions,
+                functools.partial(write_attend, ks, vs, first + i),
+                _unconstrained, i)
+            return (x, ks, vs), load
+
+        return lax.scan(block, carry, (layers, index))
+
+    carry, first, loads = (x, cache[names[0]], cache[names[1]]), 0, None
+    for stack, cfg in _stacks(params, c):
+        carry, loads = scan_stack(carry, stack, cfg, first)
+        first += cfg.n_layers
+    routed = None if loads is None else (
+        loads.shape[0] * x.shape[0] * c.experts_per_token)
+    return carry[0], {names[0]: carry[1], names[1]: carry[2],
+                      **_count_routing(cache, loads, routed, decode)}
 
 
 def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
@@ -715,7 +1035,7 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     bucketed `prefill_into_cache` variants.
 
     Chunk queries attend against the slot's FULL slab (earlier chunks'
-    K/V plus this chunk's own, causally masked), mirroring
+    rows plus this chunk's own, causally masked), mirroring
     `decode_step`'s masked-slab attention so the dense-slab static-shape
     discipline holds.  Pad positions write nothing: their scatter
     indices are pushed out of bounds and dropped, and the returned
@@ -726,8 +1046,7 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     """
     c = config
     chunk = tokens.shape[0]
-    slab = cache["k"].shape[2:]                  # (max_seq, kvh, hd)
-    max_seq = slab[0]
+    max_seq = _slab_positions(cache, c)
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
@@ -739,40 +1058,39 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     write_pos = jnp.where(offs < chunk_len, pos, jnp.int32(max_seq))
     rope_pos = jnp.minimum(pos, jnp.int32(c.max_seq - 1))
 
-    def write_chunk(ks, vs, i, xq, xk, xv):
+    def write_chunk(ks, vs, i, xq, xk, xv, w_kvb=None):
         """The chunk's real rows into (layer i, slot); attend over that
         slot's slab, causally by absolute position."""
         ks = ks.at[i, slot, write_pos].set(xk.astype(ks.dtype))
         vs = vs.at[i, slot, write_pos].set(xv.astype(vs.dtype))
-        ck = lax.dynamic_slice(ks, (i, slot, 0, 0, 0), (1, 1) + slab)[0, 0]
-        cv = lax.dynamic_slice(vs, (i, slot, 0, 0, 0), (1, 1) + slab)[0, 0]
-        return _attend_slab(xq, ck, cv, pos, c), (ks, vs)
+        return _attend_slab(xq, _slab_at(ks, i, slot), _slab_at(vs, i, slot),
+                            pos, c, w_kvb), (ks, vs)
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
-    x, new_k, new_v, loads = _scan_layers(params, x, cache, c, rope_pos,
-                                          write_chunk)
+    x, written = _scan_layers(params, x, cache, c, rope_pos, write_chunk,
+                              decode=False)
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x_last @ head.astype(c.dtype)).astype(jnp.float32)
-    cache = {**_count_routing(cache, loads), "k": new_k, "v": new_v,
+    cache = {**written,
              "length": cache["length"].at[slot].set(start + chunk_len)}
     return logits, cache
 
 
 def decode_step(params: dict, last_tokens, cache: dict,
                 config: LlamaConfig, active):
-    """One token for every slot, attending against the KV cache.
+    """One token for every slot, attending against the cache.
 
     last_tokens: (slots,) int32 — the most recent token per slot.
-    ``active`` ((slots,) bool): slots marked False neither write K/V
-    nor advance their length — an idle slot can hold a RESIDENT
+    ``active`` ((slots,) bool): slots marked False neither write their
+    row nor advance their length — an idle slot can hold a RESIDENT
     session's slab, which must stay bit-exact while the slot sits out
     decode steps.
     Returns (logits (slots, vocab) fp32, new cache with +1 lengths).
     """
     c = config
-    max_seq = cache["k"].shape[2]
+    max_seq = _slab_positions(cache, c)
     pos = cache["length"]                       # (slots,) write position
     # Inactive slots' scatter writes are pushed out of bounds (and
     # dropped), as a full slot's are; their lengths hold still below.
@@ -780,28 +1098,26 @@ def decode_step(params: dict, last_tokens, cache: dict,
 
     slots = jnp.arange(last_tokens.shape[0])
 
-    def write_one(ks, vs, i, xq, xk, xv):
+    def write_one(ks, vs, i, xq, xk, xv, w_kvb=None):
         """One row a slot into layer i; attend over the layer's slabs,
         each slot up to its own position."""
         ks = ks.at[i, slots, write_pos].set(xk.astype(ks.dtype))
         vs = vs.at[i, slots, write_pos].set(xv.astype(vs.dtype))
         ck = lax.dynamic_index_in_dim(ks, i, axis=0,
-                                      keepdims=False)  # (slots, ms, kvh, hd)
+                                      keepdims=False)  # (slots, ms, ...)
         cv = lax.dynamic_index_in_dim(vs, i, axis=0, keepdims=False)
-        return _attend_slab(xq, ck, cv, pos, c), (ks, vs)
+        return _attend_slab(xq, ck, cv, pos, c, w_kvb), (ks, vs)
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
-    x, new_k, new_v, loads = _scan_layers(params, x, cache, c, pos,
-                                          write_one)
+    x, written = _scan_layers(params, x, cache, c, pos, write_one,
+                              decode=True)
     x = rmsnorm(x, params["norm_f"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
     # Clamped so a full slot never indexes past its slab.
     new_len = jnp.where(active,
                         jnp.minimum(pos + 1, jnp.int32(max_seq)), pos)
-    cache = {**_count_routing(cache, loads), "k": new_k, "v": new_v,
-             "length": new_len}
-    return logits, cache
+    return logits, {**written, "length": new_len}
 
 
 # ---------------------------------------------------------------- generate

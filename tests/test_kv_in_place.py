@@ -43,6 +43,13 @@ def _rope_one(x, cos, sin):
         axis=-1).astype(x.dtype)
 
 
+def _counted(cache, loads, decode):
+    """The program's own counters, of a model that holds every expert
+    (what it routes is what it computes)."""
+    routed = None if loads is None else jnp.sum(loads)
+    return llama._count_routing(cache, loads, routed, decode)
+
+
 def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
                           config):
     c = config
@@ -106,7 +113,7 @@ def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x_last @ head.astype(c.dtype)).astype(jnp.float32)
-    cache = {**llama._count_routing(cache, loads), "k": new_k, "v": new_v,
+    cache = {**_counted(cache, loads, decode=False), "k": new_k, "v": new_v,
              "length": cache["length"].at[slot].set(start + chunk_len)}
     return logits, cache
 
@@ -164,7 +171,7 @@ def scanned_decode_step(params, last_tokens, cache, config, active=None):
     new_len = jnp.minimum(cache["length"] + 1, jnp.int32(max_seq))
     if active is not None:
         new_len = jnp.where(active, new_len, cache["length"])
-    cache = {**llama._count_routing(cache, loads), "k": new_k, "v": new_v,
+    cache = {**_counted(cache, loads, decode=True), "k": new_k, "v": new_v,
              "length": new_len}
     return logits, cache
 

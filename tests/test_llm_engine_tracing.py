@@ -222,6 +222,48 @@ def test_decode_ahead_pct_reads_the_counter_and_nothing_without_it(params):
     assert decode_ahead_pct.read({"traced": None}) is None
 
 
+@pytest.mark.parametrize("name,scopes", [
+    ("olmoe-tiny", {"moe"}), ("axk1-tiny", {"mla", "moe", "moe_shared"})])
+def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
+    """What a routed model's step programs record, always on: the
+    routing counters (``llama.ROUTING_COUNTERS``, PR 27; ``moe_rows_routed``
+    and the decode steps' own PR 32) in ``LLMEngine.stats`` from the start, riding the step's one
+    read, and the named scopes a reducer can file operations under —
+    ``moe`` around the routed experts, ``moe_shared`` around the shared
+    one, ``mla`` around latent attention."""
+    cfg = llama.CONFIGS[name]
+    assert llama.ROUTING_COUNTERS == (
+        "moe_assignments", "moe_experts_hit", "moe_expert_slots",
+        "moe_load_max", "moe_rows_routed", "moe_decode_assignments",
+        "moe_decode_experts_hit", "moe_decode_expert_slots",
+        "moe_decode_rows_routed")
+    eng = LLMEngine(cfg, slots=2, max_seq=64, prefill_chunk_tokens=8,
+                    tokenizer=_NoEos())
+    assert set(llama.ROUTING_COUNTERS) <= set(eng.stats)
+    eng.generate([[5, 9, 17]], SamplingParams(max_tokens=3))
+    stats = eng.stats
+    assert stats["d2h_syncs"] == stats["decode_steps"] + 1
+    held, routed = stats["moe_assignments"], stats["moe_rows_routed"]
+    assert (held == routed) == (not cfg.router_width)
+    routed_layers = cfg.n_layers - cfg.n_dense_layers
+    assert stats["moe_expert_slots"] == cfg.num_experts * routed_layers * (
+        stats["decode_steps"] + stats["chunks"])
+    assert routed == cfg.experts_per_token * routed_layers * (
+        2 * stats["decode_steps"] + 8 * stats["chunks"])
+    # the decode steps' own, apart from the chunks'
+    assert stats["moe_decode_expert_slots"] == (
+        cfg.num_experts * routed_layers * stats["decode_steps"])
+    assert stats["moe_decode_rows_routed"] == (
+        cfg.experts_per_token * routed_layers * 2 * stats["decode_steps"])
+    assert 0 < stats["moe_decode_assignments"] < held
+    assert 0 < stats["moe_decode_experts_hit"] < stats["moe_experts_hit"]
+    lowered = eng._decode_jit.lower(
+        eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool))
+    text = lowered.as_text(debug_info=True)
+    for scope in ("mla", "moe", "moe_shared"):
+        assert (f'"{scope}/' in text) == (scope in scopes), scope
+
+
 def test_phases_tile_the_loop_and_stats_keep_their_keys(params):
     eng = _engine(params)
     keys = set(eng.stats)
