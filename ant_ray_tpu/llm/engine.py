@@ -153,7 +153,10 @@ class _PhaseRecorder:
     the iteration: ``block_fetch_s`` is the remainder of the device
     step that the host did not cover, not the step.
     ``decode_ahead_steps`` counts the decode steps dispatched while the
-    step before them was still unread.
+    step before them was still unread.  ``decode_span_positions`` adds
+    up, per decode step, the positions of a slab its attention walks
+    (whole blocks up to the longest active row, from the host's own
+    ``kv_len``: no read), ``decode_slab_positions`` the slab's.
     """
 
     def __init__(self, jax, stats: dict):
@@ -165,7 +168,8 @@ class _PhaseRecorder:
         # Declared here, once: dict(stats) on another thread never sees
         # the dict change size.
         for key in ("steps", "decode_steps", "decode_slots",
-                    "decode_ahead_steps", "d2h_syncs"):
+                    "decode_ahead_steps", "decode_span_positions",
+                    "decode_slab_positions", "d2h_syncs"):
             stats[key] = 0
         stats["block_s"] = 0.0
         for _, phase_key, block_key in self._keys.values():
@@ -898,14 +902,15 @@ class LLMEngine:
         flight, self._flight = self._flight, None
         try:
             if self._active:
-                self._flight = self._dispatch_decode(flight is not None)
+                self._flight = self._dispatch_decode(flight)
         finally:
             if flight is not None:
                 self._land(flight)
 
-    def _dispatch_decode(self, ahead: bool) -> tuple:
+    def _dispatch_decode(self, flight: tuple | None) -> tuple:
         """One decode step and its sampler for the rows of ``_active``,
-        fed from the device's token table; no read.  Returns the flight
+        fed from the device's token table; no read.  ``flight`` is the
+        step before it where that is still unread.  Returns the flight
         record."""
         rec, stats = self._rec, self.stats
         rec.enter("decode")
@@ -920,7 +925,14 @@ class LLMEngine:
         rec.dispatched = True
         stats["decode_steps"] += 1
         stats["decode_slots"] += len(rows)
-        stats["decode_ahead_steps"] += ahead
+        stats["decode_ahead_steps"] += flight is not None
+        # A row of the unread step is one position further on the device
+        # than the host's kv_len, which moves when its token lands.
+        unread = {id(seq) for _, seq in flight[1]} if flight else ()
+        stats["decode_span_positions"] += self._llama.span_positions(
+            1 + max(seq.kv_len + (id(seq) in unread) for _, seq in rows),
+            self.max_seq)
+        stats["decode_slab_positions"] += self.max_seq
         self._decode_since_chunk += 1
         rec.enter("sample")
         sampled = self._sample_all(logits)

@@ -404,7 +404,7 @@ def _latent_qkv(layer: dict, h, c: LlamaConfig, cos, sin, positions):
     its RMSNorm; and its rotary key ``k_rope`` (..., rope), rotated,
     ONE for all heads.  ``c_kv`` and ``k_rope`` are all a cache keeps of
     the position; per-head keys and values are ``c_kv @ w_kvb``, made
-    (``_attend_latent_rows``) or absorbed (``_attend_latent_slab``)
+    (``_attend_latent_rows``) or absorbed (``_attend_slab``)
     where the scores are.  The one place they are made, for training,
     chunks and decode."""
     nope, rank = c.qk_nope_head_dim, c.kv_lora_rank
@@ -900,37 +900,58 @@ def prefill_into_cache(params: dict, tokens, cache: dict, slot,
     return logits[0], cache
 
 
-def _attend_slab(xq, ck, cv, pos, c: LlamaConfig, w_kvb=None):
-    """Grouped-query attention of rows ``xq`` (rows, heads, hd), row
-    ``r`` over cached positions 0..``pos[r]``: against ONE slab
-    (max_seq, kv_heads, hd) that the rows share (a chunk's slot), or a
-    slab a row (rows, max_seq, kv_heads, hd) (a decode step's slots).
-    bf16 inputs with fp32 accumulation keep the products at full MXU
-    rate without an fp32 copy of the slab (see ops/attention).  With
-    ``w_kvb`` the slabs are latent ones: ``_attend_latent_slab``."""
-    if w_kvb is not None:
-        return _attend_latent_slab(xq, ck, cv, pos, c, w_kvb)
-    slab = "rtkd" if ck.ndim == 4 else "tkd"
-    q = xq.reshape(xq.shape[0], c.n_kv_heads, c.n_heads // c.n_kv_heads,
-                   c.head_dim)
-    scores = jnp.einsum(f"rkgd,{slab}->rkgt", q, ck,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(c.head_dim))
-    valid = jnp.arange(ck.shape[-3])[None, :] <= pos[:, None]  # (rows, ms)
-    scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(f"rkgt,{slab}->rkgd", probs.astype(ck.dtype), cv,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(xq.shape).astype(xq.dtype)
+# Positions a step program attends over at a time.  A module constant,
+# not a parameter: it decides how a row's float32 sums are grouped, and
+# that grouping has to be the same in every program and under every
+# bound (``_attend_slab``).  Settled on a v5e: 256 gave 1-3 % more
+# tokens a second than 512 in every serving cell (the walk's bound
+# follows the longest row more closely), PERF.md section 6, PR 33.
+ATTEND_BLOCK = 256
 
 
-def _attend_latent_slab(xq, c_kv, k_rope, pos, c: LlamaConfig, w_kvb):
-    """Latent attention of rows ``xq`` (rows, heads, nope + rope), row
-    ``r`` over cached positions 0..``pos[r]`` of the latent slabs
-    ``c_kv`` (max_seq, rank) and ``k_rope`` (max_seq, rope) — one pair
-    the rows share, or a pair a row (rows, max_seq, ·) — in the
-    ABSORBED form: ``w_kvb``'s keys' part goes into the query and its
-    values' part onto the output,
+def _span_blocks(longest, max_seq: int):
+    """Blocks of ``ATTEND_BLOCK`` positions that hold positions
+    0..``longest`` - 1 (a traced scalar) of a ``max_seq``-position slab:
+    at least one, at most the slab's own."""
+    size = min(ATTEND_BLOCK, max_seq)
+    return jnp.clip((longest + size - 1) // size, 1, -(-max_seq // size))
+
+
+def span_positions(longest: int, max_seq: int) -> int:
+    """The positions ``_span_blocks`` makes a step walk, on the host."""
+    size = min(ATTEND_BLOCK, max_seq)
+    return min(max(-(-longest // size), 1) * size, max_seq)
+
+
+def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
+                 w_kvb=None):
+    """Attention of rows ``xq`` (rows, heads, hd), row ``r`` over cached
+    positions 0..``pos[r]`` of layer ``i`` of the CARRIED slabs ``ks``,
+    ``vs`` (layers, slots, max_seq, *position): of ONE slot's slab that
+    the rows share (``slot`` a scalar: a chunk's), or row ``r`` of slot
+    ``r``'s (``slot`` None: a decode step's).
+
+    The slab is walked in blocks of ``ATTEND_BLOCK`` positions, each
+    sliced out of the carried array where it lies, the first ``blocks``
+    of them (a traced scalar, ``_span_blocks``: the reserve behind the
+    longest live position is never read), with the online softmax's
+    running maximum, denominator and output in float32.  A row's sums
+    run over blocks 0, 1, 2, … in that order whatever ``blocks`` is, and
+    a block wholly behind ``pos[r]`` adds exact zeros under a rescale of
+    exactly 1: a row's output does not depend, to the bit, on how far
+    the OTHER rows made the walk go.  A row whose position lies behind
+    the walk (an inactive slot's, a chunk's padding) attends over what
+    was walked; nobody reads it.  Block 0 holds position 0, which every
+    row may see, so no denominator is zero.  A slab no longer than a
+    block is one block; the last block of one that is not a multiple
+    starts early and masks what the block before it covered.
+
+    Grouped-query slabs hold keys and values per KV head: bf16 inputs
+    with fp32 accumulation keep the products at full MXU rate without an
+    fp32 copy of a block (see ops/attention).  With ``w_kvb`` the slabs
+    are latent ones, ``c_kv`` (rank) and ``k_rope`` (rope) a position
+    and no heads axis, attended in the ABSORBED form: ``w_kvb``'s keys'
+    part goes into the query and its values' part onto the output,
 
         score_h(s) = (q_nope_h W_K,h^T) · c_kv(s) + q_rope_h · k_rope(s)
         out_h      = (sum_s p_h(s) c_kv(s)) W_V,h
@@ -939,41 +960,77 @@ def _attend_latent_slab(xq, c_kv, k_rope, pos, c: LlamaConfig, w_kvb):
     (c_kv(s) W_K,h, c_kv(s) W_V,h) first, without ever holding them:
     per position a head then multiplies rank + rope and rank values
     against the slab's one row instead of reading nope + rope and v of
-    its own.  A chunk runs it too: against a slab of max_seq rows, making
-    the keys and values costs max_seq * rank * heads * (nope + v)
-    multiply-adds, which the narrower per-head products win back only
+    its own.  A chunk runs it too: making the keys and values of the
+    positions it attends over costs positions * rank * heads * (nope +
+    v) multiply-adds, which the narrower per-head products win back only
     beyond some 170 rows a call."""
-    nope = c.qk_nope_head_dim
-    wk, wv = _kvb_by_head(w_kvb, c)
-    slab = "rtc" if c_kv.ndim == 3 else "tc"
-    q_lat = jnp.einsum("rhd,chd->rhc", xq[..., :nope], wk,
-                       preferred_element_type=jnp.float32).astype(xq.dtype)
-    scores = jnp.einsum(f"rhc,{slab}->rht", q_lat, c_kv,
-                        preferred_element_type=jnp.float32)
-    scores = scores + jnp.einsum(f"rhc,{slab}->rht", xq[..., nope:], k_rope,
-                                 preferred_element_type=jnp.float32)
-    scores = scores * c.attn_scale
-    valid = jnp.arange(c_kv.shape[-2])[None, :] <= pos[:, None]  # (rows, ms)
-    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum(f"rht,{slab}->rhc", probs.astype(c_kv.dtype), c_kv,
-                     preferred_element_type=jnp.float32).astype(xq.dtype)
-    out = jnp.einsum("rhc,chd->rhd", ctx, wv,
-                     preferred_element_type=jnp.float32)
+    rows, max_seq = xq.shape[0], ks.shape[2]
+    size = min(ATTEND_BLOCK, max_seq)
+    every = slot is None                         # row r reads slot r
+    t = "rt" if every else "t"                   # a block's leading axes
+    f32 = {"preferred_element_type": jnp.float32}
+    if w_kvb is None:
+        q = xq.reshape(rows, c.n_kv_heads, c.n_heads // c.n_kv_heads,
+                       c.head_dim)
+        scale = 1 / jnp.sqrt(jnp.float32(c.head_dim))
+        heads, width = q.shape[1:3], c.head_dim
+
+        def scores(bk, bv):
+            return jnp.einsum(f"rkgd,{t}kd->rkgt", q, bk, **f32)
+
+        def values(probs, bk, bv):
+            return jnp.einsum(f"rkgt,{t}kd->rkgd", probs, bv, **f32)
+    else:
+        nope = c.qk_nope_head_dim
+        wk, wv = _kvb_by_head(w_kvb, c)
+        q_lat = jnp.einsum("rhd,chd->rhc", xq[..., :nope], wk,
+                           **f32).astype(xq.dtype)
+        q_rope, scale = xq[..., nope:], c.attn_scale
+        heads, width = (c.n_heads,), c.kv_lora_rank
+
+        def scores(bk, bv):                      # bk: c_kv, bv: k_rope
+            return (jnp.einsum(f"rhc,{t}c->rht", q_lat, bk, **f32)
+                    + jnp.einsum(f"rhc,{t}c->rht", q_rope, bv, **f32))
+
+        def values(probs, bk, bv):
+            return jnp.einsum(f"rht,{t}c->rhc", probs, bk, **f32)
+
+    def block(slabs, start):
+        at = (i, 0 if every else slot, start) + (0,) * (slabs.ndim - 3)
+        shape = (1, slabs.shape[1] if every else 1, size) + slabs.shape[3:]
+        taken = lax.dynamic_slice(slabs, at, shape)
+        return taken[0] if every else taken[0, 0]
+
+    def walk(b, state):
+        high, denom, out = state
+        first = b * size
+        start = jnp.minimum(first, max_seq - size)
+        bk, bv = block(ks, start), block(vs, start)
+        at = start + jnp.arange(size)
+        valid = (at >= first) & (at <= pos[:, None])          # (rows, size)
+        s = jnp.where(valid.reshape(rows, *(1,) * len(heads), size),
+                      scores(bk, bv) * scale, -jnp.inf)
+        new_high = jnp.maximum(high, jnp.max(s, axis=-1))
+        keep = jnp.exp(high - new_high)
+        probs = jnp.exp(s - new_high[..., None])
+        denom = keep * denom + jnp.sum(probs, axis=-1)
+        out = keep[..., None] * out + values(probs.astype(ks.dtype), bk, bv)
+        return new_high, denom, out
+
+    _, denom, out = lax.fori_loop(0, blocks, walk, (
+        jnp.full((rows, *heads), -jnp.inf, jnp.float32),
+        jnp.zeros((rows, *heads), jnp.float32),
+        jnp.zeros((rows, *heads, width), jnp.float32)))
+    out = out / denom[..., None]
+    if w_kvb is None:
+        return out.reshape(xq.shape).astype(xq.dtype)
+    out = jnp.einsum("rhc,chd->rhd", out.astype(xq.dtype), wv, **f32)
     return out.astype(xq.dtype)
 
 
 def _slab_positions(cache: dict, c: LlamaConfig) -> int:
     """A slot's ``max_seq``, as the cache was made."""
     return cache[next(iter(kv_slabs(c)))].shape[2]
-
-
-def _slab_at(slabs, i, slot):
-    """(layer ``i``, ``slot``)'s slab (max_seq, *position) out of the
-    carried array."""
-    return lax.dynamic_slice(
-        slabs, (i, slot) + (0,) * (slabs.ndim - 2),
-        (1, 1) + slabs.shape[2:])[0, 0]
 
 
 def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
@@ -990,11 +1047,12 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     the cache travels as the loop's CARRY, which the compiler aliases to
     the donated input, so layer ``i``'s new rows are written where they
     lie (a row whose position is max_seq is dropped by the scatter), and
-    only THEN is the slab sliced out of the carried array to feed
-    ``_attend_slab``.  As a scanned input and output of the loop the
-    slabs are copied about three times a call; attending over the old
-    slab with the new rows beside it compiles to more temporaries and
-    reorders the float32 sums."""
+    only THEN does ``_attend_slab`` read the carried array, block by
+    block and no further than the step's longest live position — no
+    layer's slab is ever sliced out whole.  As a scanned input and
+    output of the loop the slabs are copied about three times a call;
+    attending over the old slab with the new rows beside it compiles to
+    more temporaries and reorders the float32 sums."""
     cos, sin = _rope_tables(c)
     names = tuple(kv_slabs(c))
 
@@ -1034,10 +1092,12 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     prompt — the chunked-prefill replacement for the O(log max_seq)
     bucketed `prefill_into_cache` variants.
 
-    Chunk queries attend against the slot's FULL slab (earlier chunks'
-    rows plus this chunk's own, causally masked), mirroring
-    `decode_step`'s masked-slab attention so the dense-slab static-shape
-    discipline holds.  Pad positions write nothing: their scatter
+    Chunk queries attend against the slot's slab as far as the chunk's
+    own end, ``start + chunk_len`` rounded up to a block
+    (``_attend_slab``: earlier chunks' rows plus this chunk's own,
+    causally masked) — the same walk as `decode_step`'s, so the
+    dense-slab static-shape discipline holds and the reserve behind the
+    prompt is not read.  Pad positions write nothing: their scatter
     indices are pushed out of bounds and dropped, and the returned
     logits are taken at the chunk's last REAL token.
 
@@ -1058,13 +1118,15 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     write_pos = jnp.where(offs < chunk_len, pos, jnp.int32(max_seq))
     rope_pos = jnp.minimum(pos, jnp.int32(c.max_seq - 1))
 
+    blocks = _span_blocks(start + chunk_len, max_seq)
+
     def write_chunk(ks, vs, i, xq, xk, xv, w_kvb=None):
         """The chunk's real rows into (layer i, slot); attend over that
         slot's slab, causally by absolute position."""
         ks = ks.at[i, slot, write_pos].set(xk.astype(ks.dtype))
         vs = vs.at[i, slot, write_pos].set(xv.astype(vs.dtype))
-        return _attend_slab(xq, _slab_at(ks, i, slot), _slab_at(vs, i, slot),
-                            pos, c, w_kvb), (ks, vs)
+        return _attend_slab(xq, ks, vs, i, slot, pos, blocks, c,
+                            w_kvb), (ks, vs)
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
     x, written = _scan_layers(params, x, cache, c, rope_pos, write_chunk,
@@ -1096,6 +1158,9 @@ def decode_step(params: dict, last_tokens, cache: dict,
     # dropped), as a full slot's are; their lengths hold still below.
     write_pos = jnp.where(active, pos, jnp.int32(max_seq))
 
+    # The walk ends behind the longest ACTIVE row: an idle slot that
+    # holds a resident session's long slab does not lengthen it.
+    blocks = _span_blocks(jnp.max(jnp.where(active, pos, 0)) + 1, max_seq)
     slots = jnp.arange(last_tokens.shape[0])
 
     def write_one(ks, vs, i, xq, xk, xv, w_kvb=None):
@@ -1103,10 +1168,8 @@ def decode_step(params: dict, last_tokens, cache: dict,
         each slot up to its own position."""
         ks = ks.at[i, slots, write_pos].set(xk.astype(ks.dtype))
         vs = vs.at[i, slots, write_pos].set(xv.astype(vs.dtype))
-        ck = lax.dynamic_index_in_dim(ks, i, axis=0,
-                                      keepdims=False)  # (slots, ms, ...)
-        cv = lax.dynamic_index_in_dim(vs, i, axis=0, keepdims=False)
-        return _attend_slab(xq, ck, cv, pos, c, w_kvb), (ks, vs)
+        return _attend_slab(xq, ks, vs, i, None, pos, blocks, c,
+                            w_kvb), (ks, vs)
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
     x, written = _scan_layers(params, x, cache, c, pos, write_one,

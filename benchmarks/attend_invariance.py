@@ -1,0 +1,96 @@
+"""On the chip: a row's attention output, and a whole decode step's
+logits for that row, are bit-equal whatever bound the OTHER rows'
+lengths set for the walk over the cache (``llama._attend_slab``) — what
+``tests/test_llama.py`` holds on the CPU at test size, here at Mistral's,
+OLMoE's and A.X-K1's widths and slabs (two layers each, random weights
+and cache).  One JSON line a shape; through the chip tool, from the root:
+
+    python -m benchmarks.attend_invariance
+"""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ant_ray_tpu.models import llama
+from ant_ray_tpu.ops.rope import YarnScaling
+
+MISTRAL = llama.LlamaConfig(
+    vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+    mlp_dim=14336, max_seq=4096, rope_theta=1e6, norm_eps=1e-5)
+OLMOE = llama.LlamaConfig(
+    vocab_size=50304, dim=2048, n_layers=2, n_heads=16, n_kv_heads=16,
+    mlp_dim=1024, max_seq=4096, rope_theta=10000.0, num_experts=64,
+    experts_per_token=8, norm_topk_prob=False, qk_norm=True)
+AXK1 = llama.LlamaConfig(
+    vocab_size=20480, dim=7168, n_layers=2, n_heads=64, n_kv_heads=64,
+    mlp_dim=2048, max_seq=4096, rope_theta=10000.0, norm_eps=1e-6,
+    num_experts=12, experts_per_token=8, router_scoring="sigmoid",
+    routed_scaling_factor=2.5, router_width=192, n_shared_experts=1,
+    n_dense_layers=1, dense_mlp_dim=18432, q_lora_rank=1536,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, rope_scaling=YarnScaling(
+        32.0, 4096, mscale=1.0, mscale_all_dim=1.0))
+
+
+def bits(x):
+    return np.asarray(x.astype(jnp.float32)).view(np.uint32)
+
+
+def check(name, c, slots, max_seq):
+    key = jax.random.PRNGKey(11)
+    params = jax.jit(llama.init_params, static_argnums=0)(c, key)
+    cache = llama.init_kv_cache(c, slots, max_seq)
+    names = tuple(llama.kv_slabs(c))
+    for slab, k in zip(names, jax.random.split(key, 2)):
+        cache[slab] = jax.random.normal(
+            k, cache[slab].shape, jnp.float32).astype(c.dtype)
+    size = min(llama.ATTEND_BLOCK, max_seq)
+    total, own = -(-max_seq // size), 437 // size + 1   # row 0 holds 438
+
+    # the attention alone: one compiled program, the bound an argument
+    w_kvb = params["layers"]["w_kvb"][0] if c.kv_lora_rank else None
+    xq = jax.random.normal(jax.random.PRNGKey(3), (
+        slots, c.n_heads, c.head_dim), jnp.float32).astype(c.dtype)
+    pos = jnp.full((slots,), 300, jnp.int32).at[0].set(437)
+    attend = jax.jit(lambda xq, ks, vs, w_kvb, blocks: llama._attend_slab(
+        xq, ks, vs, 1, None, pos, blocks, c, w_kvb))
+    outs = [attend(xq, cache[names[0]], cache[names[1]], w_kvb,
+                   jnp.int32(blocks)) for blocks in (own, own + 1, total)]
+    same_attention = all((bits(out[0]) == bits(outs[0][0])).all()
+                         for out in outs)
+
+    # the whole step: the other rows short, one of them near its slab's
+    # end, and that one inactive
+    step = jax.jit(lambda p, cache, last, active: llama.decode_step(
+        p, last, cache, c, active)[0])
+    last = jnp.arange(slots, dtype=jnp.int32) + 5
+
+    def logits(lengths, active):
+        return step(params, {**cache, "length": jnp.asarray(
+            lengths, jnp.int32)}, last, jnp.asarray(active))
+
+    short = [437] + [300] * (slots - 1)
+    long_ = short[:-1] + [max_seq - 2]
+    on = [True] * slots
+    a, b, d = (logits(short, on), logits(long_, on),
+               logits(long_, on[:-1] + [False]))
+    print(json.dumps({
+        "shape": name, "slots": slots, "max_seq": max_seq, "blocks": total,
+        "attention_row0_bit_equal_at_its_own_bound_one_more_and_all":
+            bool(same_attention),
+        "decode_step_row0_bit_equal_short_vs_long_active":
+            bool((bits(a[0]) == bits(b[0])).all()),
+        "decode_step_row0_bit_equal_short_vs_long_inactive":
+            bool((bits(a[0]) == bits(d[0])).all()),
+        "max_abs_diff_logits": float(jnp.max(jnp.abs(a[0] - b[0])))}),
+        flush=True)
+
+
+if __name__ == "__main__":
+    print(jax.devices())
+    check("mistral-7b", MISTRAL, 16, 3072)
+    check("olmoe-1b-7b", OLMOE, 16, 3072)
+    check("ax-k1", AXK1, 48, 4096)

@@ -119,12 +119,18 @@ def test_forward_logits_equal_the_reference(params):
     assert rel_l2(got, reference_logits(params, tokens)).max() < TOL
 
 
+@pytest.mark.parametrize("block", [llama.ATTEND_BLOCK, 20], ids=[
+    "slab-under-a-block", "blocks-with-a-tail"])
 @pytest.mark.parametrize("prompt", [40, 16, 7])
 def test_chunks_then_decode_through_the_latent_cache_equal_the_reference(
-        params, prompt):
+        params, prompt, block, monkeypatch):
     """Prefill in the engine's chunks (a whole one, several, a partial
     one) and decode through the latent cache, absorbed, against the
-    reference's full forward in the published per-head form."""
+    reference's full forward in the published per-head form — the slab
+    attended over as one block, and in blocks of 20 positions with a
+    tail (chunks and steps end on and across their edges)."""
+    assert MAX_SEQ % 20
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", block)
     tokens = tokens_of(prompt, prompt + 8)
     got, _ = through_the_cache(CFG, params, tokens, prompt)
     want = reference_logits(params, tokens)[prompt - 1:-1]
@@ -161,9 +167,9 @@ def test_bucketed_prefill_writes_the_same_latent_rows(params):
 @pytest.mark.parametrize("a_slab_a_row", [False, True])
 def test_absorbed_attention_equals_the_per_head_form_on_the_same_cache(
         a_slab_a_row):
-    """``_attend_latent_slab`` never makes a key or a value; made from
-    the same cached latents, head by head as published, they give the
-    same output."""
+    """The absorbed form of ``_attend_slab`` never makes a key or a
+    value; made from the same cached latents, head by head as
+    published, they give the same output."""
     c, rows, seq = CFG, 5, 24
     keys = jax.random.split(jax.random.PRNGKey(4), 4)
     lead = (rows,) if a_slab_a_row else ()
@@ -173,7 +179,11 @@ def test_absorbed_attention_equals_the_per_head_form_on_the_same_cache(
     w_kvb = jax.random.normal(keys[3], (c.kv_lora_rank, c.n_heads * (
         c.qk_nope_head_dim + c.v_head_dim))) * 0.3
     pos = jnp.asarray([0, 3, 23, 11, 7])
-    got = llama._attend_latent_slab(xq, c_kv, k_rope, pos, c, w_kvb)
+    # as the step programs hold them: (layers, slots, max_seq, ·)
+    carried = [slab[None] if a_slab_a_row else slab[None, None]
+               for slab in (c_kv, k_rope)]
+    got = llama._attend_slab(xq, *carried, 0, None if a_slab_a_row else 0,
+                             pos, llama._span_blocks(seq, seq), c, w_kvb)
 
     w = np.asarray(w_kvb).reshape(c.kv_lora_rank, c.n_heads, -1)
     want = np.zeros((rows, c.n_heads, c.v_head_dim))

@@ -28,7 +28,13 @@ CONFIGS = {
                                            dtype=jnp.bfloat16),
 }
 SLOTS, MAX_SEQ, CHUNK = 4, 96, 16
-LOGIT_TOL = 2.0 ** -8                       # one step of bf16
+# Logits, relative L2 a row, by the cache's itemsize.  float32: the two
+# forms differ by the order of their sums.  bf16: they round the
+# probabilities at different places (see ROW_ATOL), and through two
+# layers whose weights are scaled x 4 each form reads 0.6-1.3 % from the
+# same step in float32 and 0.5-1.3 % from the other (PR 33, measured
+# here): four steps of bf16.
+LOGIT_TOL = {4: 2.0 ** -8, 2: 2.0 ** -5}
 
 
 def _rope_one(x, cos, sin):
@@ -205,19 +211,49 @@ def bits(x):
     return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
+# What the first layer writes does not pass through attention and is the
+# scanned form's bit for bit.  A deeper layer's rows do: since PR 33 the
+# step programs walk the slab block by block with an online softmax
+# (probabilities rounded to the cache's dtype BEFORE the division, sums
+# in another order), so those rows agree to the dtype's rounding — two
+# units in the last place of a bf16 value in [2, 4), the largest here;
+# float32 sums of 96 terms reordered.
+ROW_ATOL = {2: 2.0 ** -5, 4: 1e-5}
+
+
 def assert_same_step(got, want):
     """(logits, cache) of the two forms: the logits within bf16
-    rounding by the benchmark's measure (relative L2 a row; on the CPU
-    they come out equal), every leaf of the cache bit for bit."""
+    rounding by the benchmark's measure (relative L2 a row), the first
+    layer's slabs, the lengths and (in float32) the counters bit for
+    bit, the deeper layers' slabs to the dtype's rounding."""
     got_logits, want_logits = (np.atleast_2d(np.asarray(x[0], np.float64))
                                for x in (got, want))
     rel_l2 = np.sqrt(((got_logits - want_logits) ** 2).sum(-1)
                      / (want_logits ** 2).sum(-1))
-    assert rel_l2.max() < LOGIT_TOL
+    assert rel_l2.max() < LOGIT_TOL[want[1]["k"].dtype.itemsize]
     assert sorted(got[1]) == sorted(want[1])
     for name, leaf in want[1].items():
-        np.testing.assert_array_equal(bits(got[1][name]), bits(leaf),
+        new = got[1][name]
+        if name == "routing" and want[1]["k"].dtype.itemsize == 2:
+            # in bf16 a near-tie of two router scores may fall the other
+            # way in a deeper layer: the counts of what was computed are
+            # equal, the experts hit and the busiest one's rows nearly
+            np.testing.assert_allclose(np.asarray(new, np.int64),
+                                       np.asarray(leaf, np.int64), atol=2)
+            counted = [llama.ROUTING_COUNTERS.index(n) for n in (
+                "moe_assignments", "moe_expert_slots", "moe_rows_routed")]
+            np.testing.assert_array_equal(new[np.array(counted)],
+                                          leaf[np.array(counted)])
+            continue
+        if name not in ("k", "v"):
+            np.testing.assert_array_equal(bits(new), bits(leaf),
+                                          err_msg=name)
+            continue
+        np.testing.assert_array_equal(bits(new[0]), bits(leaf[0]),
                                       err_msg=name)
+        np.testing.assert_allclose(
+            np.asarray(new, np.float32), np.asarray(leaf, np.float32),
+            rtol=0, atol=ROW_ATOL[leaf.dtype.itemsize], err_msg=name)
 
 
 @pytest.mark.parametrize("active", [(True, True, True, True),

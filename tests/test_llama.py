@@ -1,6 +1,8 @@
 """Llama model tests: shapes, loss/grad sanity, sharded == unsharded, and
 a short training run that actually learns."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -306,3 +308,229 @@ def test_every_program_runs_the_one_block(tiny_params, monkeypatch, program):
     monkeypatch.setattr(llama, "apply_block", counted)
     jax.eval_shape(program, tiny_params)
     assert calls
+
+
+# ------------------------------------------ the block walk over the cache
+# ``_attend_slab`` walks a slot's slab in blocks of ``ATTEND_BLOCK``
+# positions and stops behind the longest live one.  The tests' slabs are
+# shorter than the serving block, so the walk is cut to 16 positions a
+# block here: 40 positions are two blocks and a tail of 8, 48 three
+# whole ones, 12 are less than one.
+
+BLOCK = 16
+LAYOUTS = {
+    "grouped-query": CFG,                                       # 4 / 2
+    "16-of-16": dataclasses.replace(CFG, n_kv_heads=4),         # 4 / 4
+    "latent": llama.CONFIGS["axk1-tiny"],
+}
+
+
+@pytest.fixture
+def short_blocks(monkeypatch):
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+
+
+def _one_shot(xq, ck, cv, pos, c, w_kvb=None):
+    """What ``_attend_slab`` was before the walk, kept as the reference:
+    ONE slab (max_seq, ...) the rows share, or a slab a row, scored over
+    every reserved position at once and normalised before the cast."""
+    f32 = {"preferred_element_type": jnp.float32}
+    valid = jnp.arange(ck.shape[-2 if w_kvb is not None else -3]) \
+        <= pos[:, None]
+    if w_kvb is not None:
+        nope = c.qk_nope_head_dim
+        wk, wv = llama._kvb_by_head(w_kvb, c)
+        t = "rtc" if ck.ndim == 3 else "tc"
+        q_lat = jnp.einsum("rhd,chd->rhc", xq[..., :nope], wk,
+                           **f32).astype(xq.dtype)
+        scores = (jnp.einsum(f"rhc,{t}->rht", q_lat, ck, **f32)
+                  + jnp.einsum(f"rhc,{t}->rht", xq[..., nope:], cv, **f32))
+        scores = jnp.where(valid[:, None, :], scores * c.attn_scale,
+                           -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        ctx = jnp.einsum(f"rht,{t}->rhc", probs.astype(ck.dtype), ck,
+                         **f32).astype(xq.dtype)
+        return jnp.einsum("rhc,chd->rhd", ctx, wv, **f32).astype(xq.dtype)
+    t = "rtkd" if ck.ndim == 4 else "tkd"
+    q = xq.reshape(xq.shape[0], c.n_kv_heads, c.n_heads // c.n_kv_heads,
+                   c.head_dim)
+    scores = jnp.einsum(f"rkgd,{t}->rkgt", q, ck, **f32)
+    scores = scores / jnp.sqrt(jnp.float32(c.head_dim))
+    scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum(f"rkgt,{t}->rkgd", probs.astype(ck.dtype), cv, **f32)
+    return out.reshape(xq.shape).astype(xq.dtype)
+
+
+def _slabs(layout, dtype, slots, max_seq, rows=None, seed=0):
+    """Random queries for ``rows`` rows (a row a slot unless given) and
+    a random two-layer cache as the step programs carry it."""
+    c = dataclasses.replace(LAYOUTS[layout], dtype=dtype)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    xq = jax.random.normal(keys[0], (rows or slots, c.n_heads, c.head_dim),
+                           jnp.float32).astype(dtype)
+    ks, vs = (jax.random.normal(key, (2, slots, max_seq, *position),
+                                jnp.float32).astype(dtype)
+              for key, position in zip(keys[1:], llama.kv_slabs(c).values()))
+    w_kvb = None
+    if c.kv_lora_rank:
+        w_kvb = (jax.random.normal(keys[3], (c.kv_lora_rank, c.n_heads * (
+            c.qk_nope_head_dim + c.v_head_dim))) * 0.3).astype(dtype)
+    return c, xq, ks, vs, w_kvb
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+# The one-shot form rounds the probabilities to the cache's dtype after
+# the division, the walk before it: in bf16 that is 2**-9 relative a
+# probability, and both outputs are then rounded to bf16 themselves (one
+# unit in the last place of a value of 1-2 is 2**-7); in float32 only
+# the order of the sums differs.
+WALK_ATOL = {jnp.float32: 5e-6, jnp.bfloat16: 2.0 ** -6}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("max_seq", [40, 48, 12],
+                         ids=["tail-of-8", "whole-blocks", "under-a-block"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_walk_equals_the_one_shot_attention_a_slab_a_row(
+        short_blocks, layout, max_seq, dtype):
+    """A decode step's attention, rows at position 0, a block's edge and
+    either side of it, the slab's last position and a FULL slot
+    (``pos == max_seq``: its write was dropped, it sees every row)."""
+    pos = [0, BLOCK - 2, BLOCK - 1, BLOCK, max_seq - 1, max_seq, 5]
+    pos = jnp.asarray([min(p, max_seq) for p in pos], jnp.int32)
+    c, xq, ks, vs, w_kvb = _slabs(layout, dtype, len(pos), max_seq)
+    blocks = llama._span_blocks(jnp.max(pos) + 1, max_seq)
+    assert int(blocks) == -(-max_seq // BLOCK)
+    got = llama._attend_slab(xq, ks, vs, 1, None, pos, blocks, c, w_kvb)
+    want = _one_shot(xq, ks[1], vs[1], pos, c, w_kvb)
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=WALK_ATOL[dtype], rtol=0)
+
+
+@pytest.mark.parametrize("start,chunk_len", [(10, 9), (0, 16), (30, 10),
+                                             (16, 1)],
+                         ids=["crosses-an-edge", "ends-on-an-edge",
+                              "ends-at-the-slab", "starts-a-block"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_walk_equals_the_one_shot_attention_of_a_padded_chunk(
+        short_blocks, layout, start, chunk_len):
+    """A chunk's attention over its ONE slot: 16 rows of which
+    ``chunk_len`` are real, walked as far as ``start + chunk_len``
+    rounded up to a block; the real rows see what the one-shot form
+    sees.  (A padded row's position may lie behind the walk: nobody
+    reads it.)"""
+    max_seq, slot = 40, 2
+    c, xq, ks, vs, w_kvb = _slabs(layout, jnp.float32, 3, max_seq, rows=16)
+    pos = start + jnp.arange(16, dtype=jnp.int32)
+    blocks = llama._span_blocks(start + chunk_len, max_seq)
+    assert int(blocks) == min(-(-(start + chunk_len) // BLOCK), 3)
+    got = llama._attend_slab(xq, ks, vs, 1, slot, pos, blocks, c, w_kvb)
+    want = _one_shot(xq, ks[1, slot], vs[1, slot], pos, c, w_kvb)
+    np.testing.assert_allclose(got[:chunk_len], want[:chunk_len], atol=5e-6,
+                               rtol=0)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_a_rows_attention_is_bit_equal_whatever_bound_the_others_set(
+        short_blocks, layout, dtype):
+    """Row 0 (position 9: one block) under a walk of one block, of two
+    and of the whole slab — as the OTHER rows' lengths would set it —
+    comes out bit for bit the same: the blocks behind its position add
+    exact zeros under a rescale of exactly 1."""
+    max_seq = 40
+    c, xq, ks, vs, w_kvb = _slabs(layout, dtype, 4, max_seq)
+    pos = jnp.asarray([9, 3, 12, 7], jnp.int32)
+    outs = [llama._attend_slab(xq, ks, vs, 0, None, pos, jnp.int32(blocks),
+                               c, w_kvb)
+            for blocks in (1, 2, 3)]
+    for out in outs[1:]:
+        assert (_bits(out) == _bits(outs[0])).all()
+    # and a walk that stops short of a row's position changes THAT row
+    far = pos.at[1].set(max_seq - 1)
+    short, whole = (llama._attend_slab(xq, ks, vs, 0, None, far,
+                                       jnp.int32(blocks), c, w_kvb)
+                    for blocks in (1, 3))
+    assert (_bits(short[0]) == _bits(whole[0])).all()
+    assert (_bits(short[1]) != _bits(whole[1])).any()
+
+
+@pytest.mark.parametrize("longest,max_seq,blocks", [
+    (0, 40, 1), (1, 40, 1), (16, 40, 1), (17, 40, 2), (32, 40, 2),
+    (33, 40, 3), (41, 40, 3), (49, 48, 3), (5, 12, 1), (13, 12, 1)])
+def test_span_on_the_host_is_the_span_on_the_device(
+        short_blocks, longest, max_seq, blocks):
+    """``span_positions`` (the engine's counter) and ``_span_blocks``
+    (the step programs' bound) are one rule: whole blocks that hold
+    ``longest`` positions, at least one, at most the slab."""
+    assert int(llama._span_blocks(jnp.int32(longest), max_seq)) == blocks
+    assert llama.span_positions(longest, max_seq) == min(
+        blocks * min(BLOCK, max_seq), max_seq)
+
+
+def _step_logits(params, c, lengths, active, max_seq=40, seed=1):
+    """One ``decode_step`` over a random cache whose slots hold
+    ``lengths`` positions."""
+    cache = llama.init_kv_cache(c, len(lengths), max_seq)
+    for name, key in zip(llama.kv_slabs(c),
+                         jax.random.split(jax.random.PRNGKey(seed), 2)):
+        cache[name] = jax.random.normal(key, cache[name].shape, c.dtype)
+    cache["length"] = jnp.asarray(lengths, jnp.int32)
+    last = jnp.arange(len(lengths), dtype=jnp.int32) + 3
+    return llama.decode_step(params, last, cache, c,
+                             active=jnp.asarray(active))
+
+
+@pytest.mark.parametrize("config", [
+    *LAYOUTS.values(), llama.CONFIGS["olmoe-tiny"]],
+    ids=[*LAYOUTS, "routed"])
+def test_decode_step_row_is_bit_equal_whatever_the_other_rows_hold(
+        short_blocks, config):
+    """A whole decode step's logits for row 0: the other rows short (the
+    walk is one block), one other row near its slab's end (every
+    block), and that long row INACTIVE (one block again: a resident
+    session's slab does not lengthen the walk) — bit for bit the same,
+    through routed experts too, where the other rows' tokens change
+    which rows each expert is given; and an inactive row's slab and
+    length stay as they were."""
+    params = llama.init_params(config, jax.random.PRNGKey(0))
+    short, long_ = [9, 3, 12, 7], [9, 3, 38, 7]
+    on, off = [True] * 4, [True, True, False, True]
+    a, _ = _step_logits(params, config, short, on)
+    b, _ = _step_logits(params, config, long_, on)
+    d, after = _step_logits(params, config, long_, off)
+    assert (_bits(a[0]) == _bits(b[0])).all()
+    assert (_bits(a[0]) == _bits(d[0])).all()
+    assert (_bits(a[2]) != _bits(b[2])).any()
+    assert after["length"].tolist() == [10, 4, 38, 8]
+    _, before = _step_logits(params, config, long_, [False] * 4)
+    for name in llama.kv_slabs(config):
+        assert (_bits(after[name][:, 2]) == _bits(before[name][:, 2])).all()
+
+
+def test_decode_step_with_no_active_row_and_with_a_full_slot(
+        short_blocks, tiny_params, monkeypatch):
+    """No active row at all: the walk is its one least block, every
+    logit finite, nothing written.  A full slot (length == max_seq)
+    among the active rows: every block, its write dropped, its logits
+    the one-shot form's — the step equals the same step with the walk
+    switched off (one block as long as the slab)."""
+    logits, cache = _step_logits(tiny_params, CFG, [9, 40, 12, 7],
+                                 [False] * 4)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert cache["length"].tolist() == [9, 40, 12, 7]
+    walked, cache = _step_logits(tiny_params, CFG, [9, 40, 12, 7],
+                                 [True] * 4)
+    assert cache["length"].tolist() == [10, 40, 13, 8]
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", 40)
+    whole, _ = _step_logits(tiny_params, CFG, [9, 40, 12, 7], [True] * 4)
+    np.testing.assert_allclose(walked, whole, atol=2e-5, rtol=0)

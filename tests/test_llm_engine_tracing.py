@@ -222,6 +222,92 @@ def test_decode_ahead_pct_reads_the_counter_and_nothing_without_it(params):
     assert decode_ahead_pct.read({"traced": None}) is None
 
 
+def _span_by_step(eng, monkeypatch):
+    """Drive ``eng`` to the end; per decode step ``(what the DEVICE's
+    rule makes of the step's own inputs, what the host added to
+    decode_span_positions, the longest active length on the device)``."""
+    import numpy as np
+
+    seen, decode_jit = [], eng._decode_jit
+
+    def watched(params, cache, last, active):
+        longest = int(np.max(np.where(np.asarray(active),
+                                      np.asarray(cache["length"]), 0)))
+        seen.append((llama.span_positions(longest + 1, eng.max_seq),
+                     longest))
+        return decode_jit(params, cache, last, active)
+
+    monkeypatch.setattr(eng, "_decode_jit", watched)
+    rows = []
+    while eng.has_unfinished():
+        before = eng.stats["decode_span_positions"]
+        eng.step()
+        if len(seen) > len(rows):
+            rows.append((seen[-1][0],
+                         eng.stats["decode_span_positions"] - before,
+                         seen[-1][1]))
+    return rows
+
+
+def test_decode_span_counters_follow_the_longest_active_row(
+        params, monkeypatch):
+    """``decode_span_positions`` / ``decode_slab_positions`` (PR 33), per
+    decode step: the positions the step's attention walks — whole blocks
+    (16 here) up to the longest ACTIVE row, from the host's own
+    ``kv_len`` plus the step still unread, exactly what the device makes
+    of its ``cache["length"]`` — and the slab's 96.  The first never
+    passes the second, rises block by block with the longest row, and a
+    resident session's long slab that sits the steps out does not raise
+    it."""
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
+    eng = _engine(params, slots=3)
+    assert eng.stats["decode_span_positions"] == \
+        eng.stats["decode_slab_positions"] == 0
+    # a resident session whose slab holds 70 positions, then idle
+    eng.add_request(list(range(3, 71)), SamplingParams(max_tokens=3),
+                    admit=False, session_id="resident")
+    first = _span_by_step(eng, monkeypatch)
+    assert {device for device, _, _ in first} == {80}      # 69-71 -> 5 blocks
+    assert eng.stats["decode_slab_positions"] == 96 * len(first)
+    # rows of 5 and 3 prompt tokens, 40 and 9 more: 16 -> 32 -> 48
+    eng.add_request([5, 9, 17, 3, 88], SamplingParams(max_tokens=41),
+                    admit=False)
+    eng.add_request([44, 55, 66], SamplingParams(max_tokens=10), admit=False)
+    then = _span_by_step(eng, monkeypatch)
+    assert [host for _, host, _ in then] == [device for device, _, _ in then]
+    spans = [device for device, _, _ in then]
+    assert spans == sorted(spans) and set(spans) == {16, 32, 48}
+    for device, _, longest in then:
+        assert longest < device <= longest + 16 <= 96
+    stats = eng.stats
+    assert stats["decode_span_positions"] == sum(
+        host for _, host, _ in first + then)
+    assert stats["decode_slab_positions"] == 96 * stats["decode_steps"]
+    assert stats["decode_span_positions"] < stats["decode_slab_positions"]
+
+
+def test_decode_span_pct_reads_the_counters_and_nothing_without_them(params):
+    """``chipbench/layer_metrics/decode_span_pct.py`` over a window of
+    the engine's own stats: slabs of 96 are ONE serving block, so every
+    step walks all of them; a program without the counters (the parent
+    of PR 33) gives None, which leaves the metric out of the line."""
+    from chipbench.layer_metrics import decode_span_pct
+
+    eng = _engine(params)
+    before = dict(eng.stats)
+    eng.generate(list(PROMPTS), SamplingParams(max_tokens=6))
+    obs = {"traced": {"engine": dict(eng.stats), "engine_before": before}}
+    assert eng.stats["decode_slab_positions"] == \
+        96 * eng.stats["decode_steps"] > 0
+    assert decode_span_pct.read(obs) == 100.0
+    obs["traced"]["engine"]["decode_span_positions"] //= 4
+    assert decode_span_pct.read(obs) == 25.0
+    for side in obs["traced"].values():
+        del side["decode_span_positions"]
+    assert decode_span_pct.read(obs) is None
+    assert decode_span_pct.read({"traced": None}) is None
+
+
 @pytest.mark.parametrize("name,scopes", [
     ("olmoe-tiny", {"moe"}), ("axk1-tiny", {"mla", "moe", "moe_shared"})])
 def test_routed_models_name_their_counters_and_their_scopes(name, scopes):

@@ -150,9 +150,16 @@ def reference_logits(p, cfg, tokens):
         rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
 
 
+@pytest.mark.parametrize("block", [llama.ATTEND_BLOCK, 48, 16], ids=[
+    "slab-under-a-block", "blocks-with-a-tail", "whole-blocks"])
 @pytest.mark.parametrize("cfg", [CFG, DENSE_UNGROUPED],
                          ids=["olmoe-tiny", "dense-ungrouped"])
-def test_prefill_in_chunks_then_decode_equals_the_full_forward(params, cfg):
+def test_prefill_in_chunks_then_decode_equals_the_full_forward(
+        params, cfg, block, monkeypatch):
+    """``block``: the positions the step programs attend over at a time
+    — the 128-position slab as one block, as 48 + 48 + a tail of 32
+    (chunks end on and across the edges), and as eight of 16."""
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", block)
     p = params if cfg is CFG else seeded_params(cfg)
     tokens, prompt = tokens_of(2, 60), 50
     got, cache = through_the_cache(p, cfg, tokens, prompt)

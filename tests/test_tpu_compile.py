@@ -208,7 +208,7 @@ DENSE_128 = dataclasses.replace(CFG, n_heads=16)
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
 @pytest.mark.parametrize("config,slots,max_seq", [
     pytest.param(DENSE_128, 8, 2048, id="dense"),
-    pytest.param(ROUTED, 16, 512, id="routed"),
+    pytest.param(ROUTED, 16, 1536, id="routed"),
     pytest.param(LATENT, 48, 4096, id="latent")])
 def test_step_updates_the_cache_in_place(v5e, program, config, slots,
                                          max_seq):
@@ -218,22 +218,32 @@ def test_step_updates_the_cache_in_place(v5e, program, config, slots,
     latent and its rotary key; the scanned-over form needed a second
     whole cache), and no ``copy`` or ``dynamic-update-slice`` anywhere
     in the program produces an array of a whole slab's shape — what has
-    that shape is the row scatter, in place.  (A layer's slab read by a
-    ``dynamic-slice`` and transposed inside a fusion is the attention's
-    one read of it.)"""
+    that shape is the row scatter, in place.  Nor does anything produce
+    one LAYER's slab (``bf16[1, slots, max_seq, …]``, a decode step's
+    full-span read) or one slot's (``bf16[1, 1, max_seq, …]``, a
+    chunk's): the attention reads the carried cache a block of
+    ``ATTEND_BLOCK`` positions at a time (slabs of several blocks
+    here)."""
+    assert max_seq > llama.ATTEND_BLOCK
     compiled, _, cache = _compile_step(v5e.devices[0], program, config,
                                        slots, max_seq)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _tree_bytes(cache)
     slabs = [cache[name] for name in llama.kv_slabs(config)]
     assert mem.temp_size_in_bytes < _tree_bytes(slabs) // config.n_layers
+    produces = r"\s*(ROOT )?%?[\w.\-]+ = "
+    lines = compiled.as_text().splitlines()
     for slab in slabs:
         whole = "bf16[" + ",".join(map(str, slab.shape)) + "]"
-        moved = [line.strip()[:160]
-                 for line in compiled.as_text().splitlines()
-                 if re.match(r"\s*(ROOT )?%?[\w.\-]+ = " + re.escape(whole)
+        moved = [line.strip()[:160] for line in lines
+                 if re.match(produces + re.escape(whole)
                              + r"\S* (copy|dynamic-update-slice)\(", line)]
         assert not moved, moved
+        read = slots if program == "decode" else 1
+        full_span = "bf16[" + ",".join(map(str, (1, read) + slab.shape[2:]))
+        sliced = [line.strip()[:160] for line in lines
+                  if re.match(produces + re.escape(full_span + "]"), line)]
+        assert not sliced, sliced
 
 
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
