@@ -156,7 +156,15 @@ class _PhaseRecorder:
     step before them was still unread.  ``decode_span_positions`` adds
     up, per decode step, the positions of a slab its attention walks
     (whole blocks up to the longest active row, from the host's own
-    ``kv_len``: no read), ``decode_slab_positions`` the slab's.
+    ``kv_len``: no read), ``decode_slab_positions`` the slab's.  Of a
+    model with window layers (``LlamaConfig.window``; every other
+    leaves these three at zero) ``full_span_positions`` and
+    ``window_span_positions`` add up, per decode step AND per chunk,
+    the positions walked on its full layers and on its window layers'
+    rings (each a layer's walk times the layers of its kind; the same
+    rule, no read), and ``decode_rows_past_window`` the active rows of
+    a decode step whose context exceeds the window; the two
+    ``decode_*_positions`` count the full layers' walk.
     """
 
     def __init__(self, jax, stats: dict):
@@ -169,7 +177,9 @@ class _PhaseRecorder:
         # the dict change size.
         for key in ("steps", "decode_steps", "decode_slots",
                     "decode_ahead_steps", "decode_span_positions",
-                    "decode_slab_positions", "d2h_syncs"):
+                    "decode_slab_positions", "window_span_positions",
+                    "full_span_positions", "decode_rows_past_window",
+                    "d2h_syncs"):
             stats[key] = 0
         stats["block_s"] = 0.0
         for _, phase_key, block_key in self._keys.values():
@@ -324,7 +334,12 @@ class LLMEngine:
                 devices=jax.local_devices()[:tensor_parallel_size],
                 tp=tensor_parallel_size)
         self.params = params
-        self.cache = llama.init_kv_cache(self.config, slots, self.max_seq)
+        self.cache = llama.init_kv_cache(self.config, slots, self.max_seq,
+                                         prefill_chunk_tokens or 0)
+        # A window layer's ring rows (0: the model has none), as the
+        # cache was made.
+        self._ring = llama.ring_positions(self.config, self.max_seq,
+                                          prefill_chunk_tokens or 0)
         # Per-slot sampling keys, resident on the device: a key enters
         # its row when its sequence joins the decode batch, the jitted
         # sampler splits every active row each step, and the row leaves
@@ -412,7 +427,8 @@ class LLMEngine:
             return llama.decode_step(params, last_tokens, cache, cfg,
                                      active=active)
 
-        slab_names = tuple(llama.kv_slabs(cfg))  # k, v — or c_kv, k_rope
+        # k, v — or c_kv, k_rope; a window model's rings beside them
+        slab_names = tuple(llama.kv_slabs(cfg))
 
         def _extract(cache, slot):
             from jax import lax  # noqa: PLC0415
@@ -459,8 +475,9 @@ class LLMEngine:
 
     def _shard_state(self):
         """Distribute params and KV slabs over the engine's mesh: params
-        by the model's logical-axis rules (heads/mlp over tp), slabs by
-        kv-head over tp — decode attention then runs fully sharded with
+        by the model's logical-axis rules (heads/mlp over tp), slabs
+        (a window model's rings too) by kv-head over tp — decode
+        attention then runs fully sharded with
         XLA inserting the one all-reduce per block (ref capability:
         vLLM tensor_parallel_size, engine-owned sharding)."""
         jax = self._jax
@@ -482,8 +499,9 @@ class LLMEngine:
         self.params = jax.device_put(self.params, shardings)
         kv = NamedSharding(mesh, P(None, None, None, "tp", None))
         rep = NamedSharding(mesh, P())
+        slabs = self._llama.kv_slabs(self.config)
         self.cache = {
-            name: jax.device_put(x, kv if name in ("k", "v") else rep)
+            name: jax.device_put(x, kv if name in slabs else rep)
             for name, x in self.cache.items()}
         self._keys = jax.device_put(self._keys, rep)
         self._last = jax.device_put(self._last, rep)
@@ -855,6 +873,7 @@ class LLMEngine:
         self._note_dispatch(seq)
         seq.prefill_done += len(part)
         seq.kv_len += len(part)
+        self._note_walk(seq.kv_len)
         self._note_chunk(len(part))
         self._decode_since_chunk = 0
         if seq.prefill_done == len(seq.prompt):
@@ -929,10 +948,11 @@ class LLMEngine:
         # A row of the unread step is one position further on the device
         # than the host's kv_len, which moves when its token lands.
         unread = {id(seq) for _, seq in flight[1]} if flight else ()
+        contexts = [1 + seq.kv_len + (id(seq) in unread) for _, seq in rows]
         stats["decode_span_positions"] += self._llama.span_positions(
-            1 + max(seq.kv_len + (id(seq) in unread) for _, seq in rows),
-            self.max_seq)
+            max(contexts), self.max_seq)
         stats["decode_slab_positions"] += self.max_seq
+        self._note_walk(max(contexts), contexts)
         self._decode_since_chunk += 1
         rec.enter("sample")
         sampled = self._sample_all(logits)
@@ -986,6 +1006,21 @@ class LLMEngine:
                               (now - self._routing_seen).tolist()):
             self.stats[name] += more
         self._routing_seen = now
+
+    def _note_walk(self, longest: int, decoding=()):
+        """A step program was dispatched whose longest live row holds
+        ``longest`` positions (``decoding``: a decode step's rows'
+        contexts): of a model with window layers, what its attention
+        walks, by the device's own rule."""
+        if not self._ring:
+            return
+        span, stats = self._llama.span_positions, self.stats
+        n_window, n_full = self.config.layer_counts()
+        stats["full_span_positions"] += n_full * span(longest, self.max_seq)
+        stats["window_span_positions"] += n_window * span(longest,
+                                                          self._ring)
+        stats["decode_rows_past_window"] += sum(
+            n > self.config.window for n in decoding)
 
     def _note_dispatch(self, seq: _Seq):
         """A prefill program for ``seq`` was dispatched: the iteration

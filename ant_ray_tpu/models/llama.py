@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ant_ray_tpu.ops.attention import attention, kernel_fits
+from ant_ray_tpu.ops.layernorm import layernorm
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
 from ant_ray_tpu.ops.rope import (
     YarnScaling,
@@ -94,6 +95,26 @@ class LlamaConfig:
     # YaRN (latent attention only: its ``mscale_all_dim`` temperature
     # is applied where the latent scores are made).
     rope_scaling: YarnScaling | None = None
+    # A head's width where the config STATES it (0 = dim // n_heads).
+    head_width: int = 0
+    # Window and full layers in one model: ``window_pattern`` says, for
+    # each place of the layer pattern's period, whether the layer there
+    # attends over a sliding window — query ``t`` sees key ``s`` iff
+    # 0 <= t - s < ``window`` — or over the whole context; () = every
+    # layer is full.  The period repeats over ``n_layers``.  With
+    # ``full_rope`` False the full layers of such a model rotate
+    # nothing (no positional embedding at all); window layers always do.
+    window: int = 0
+    window_pattern: tuple = ()
+    full_rope: bool = True
+    # "rms" (RMSNorm) or "layer": a LayerNorm without bias, over the
+    # block's input and before the head (``ops/layernorm.py``).
+    norm: str = "rms"
+    # Attention and feed-forward from the SAME normed input, one
+    # residual sum: x + attn(h) + ffn(h), h = norm(x); no second norm.
+    parallel_block: bool = False
+    # The shared experts' outputs are averaged, not summed.
+    shared_experts_average: bool = False
 
     def __post_init__(self):
         if self.rope_scaling is not None and not self.kv_lora_rank:
@@ -105,13 +126,35 @@ class LlamaConfig:
         if self.n_dense_layers and not self.num_experts:
             raise ValueError("n_dense_layers are the leading dense "
                              "layers of a routed model")
+        if bool(self.window) != any(self.window_pattern):
+            raise ValueError("window and window_pattern go together")
+        if self.window_pattern and (
+                self.kv_lora_rank or self.n_dense_layers
+                or self.n_layers % len(self.window_pattern)):
+            raise ValueError("a window pattern repeats whole over "
+                             "n_layers of grouped-query layers, none of "
+                             "them a leading dense one")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm {self.norm!r}")
 
     @property
     def head_dim(self) -> int:
         """Width of a head's query and key."""
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.dim // self.n_heads
+        return self.head_width or self.dim // self.n_heads
+
+    @property
+    def period(self) -> tuple:
+        """The layer pattern's period: for each place, whether the layer
+        there is a window layer.  (False,) where all layers are alike."""
+        return tuple(map(bool, self.window_pattern)) or (False,)
+
+    def layer_counts(self) -> tuple:
+        """(window layers, full layers) of the ``n_layers``."""
+        periods = self.n_layers // len(self.period)
+        n_window = periods * sum(self.period)
+        return n_window, self.n_layers - n_window
 
     @property
     def rope_dim(self) -> int:
@@ -193,6 +236,20 @@ CONFIGS: dict[str, LlamaConfig] = {
         rope_scaling=YarnScaling(
             factor=4.0, original_max_position_embeddings=32,
             mscale=1.0, mscale_all_dim=1.0)),
+    # Command A+'s block at test size (Cohere2-MoE): two periods of three
+    # window layers (16 positions, rotated) and a full one (no positional
+    # embedding), 8 heads of 16 on a hidden size of 64, ONE LayerNorm a
+    # block with attention and feed-forward in parallel under it, a
+    # sigmoid router over 8 experts, 2 a token, of which this share
+    # holds 4, four shared experts averaged, the embedding tied
+    "cmdaplus-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=8, n_heads=8, n_kv_heads=2,
+        head_width=16, mlp_dim=32, max_seq=512, rope_theta=50000.0,
+        norm_eps=1e-5, dtype=jnp.float32, tie_embeddings=True,
+        num_experts=4, experts_per_token=2, router_scoring="sigmoid",
+        router_width=8, n_shared_experts=4, shared_experts_average=True,
+        window=16, window_pattern=(True, True, True, False),
+        full_rope=False, norm="layer", parallel_block=True),
 }
 
 
@@ -245,7 +302,7 @@ def _layer_leaves(c: LlamaConfig) -> dict:
     return {
         "ln_attn": ((c.dim,), ("norm",)),
         **attn,
-        "ln_mlp": ((c.dim,), ("norm",)),
+        **({} if c.parallel_block else {"ln_mlp": ((c.dim,), ("norm",))}),
         **mlp,
         **({"q_norm": ((c.n_heads * hd,), ("norm",)),
             "k_norm": ((c.n_kv_heads * hd,), ("norm",))}
@@ -332,7 +389,7 @@ def param_shardings(config: LlamaConfig, mesh) -> dict:
 # ---------------------------------------------------------------- forward
 
 def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
-                attend, constrain_act, index=None):
+                attend, constrain_act, index=None, windowed: bool = False):
     """One transformer block on ``x`` (..., dim), the only place its
     equations are written: training hands it (batch, seq, dim), a
     prefill chunk and a decode step their rows, (chunk, dim) and
@@ -347,10 +404,15 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     (``_latent_qkv``).  ``positions``: int32 of ``x``'s leading shape,
     None for arange over the sequence.  ``index``: the layer's number
     where ``layer`` holds the whole stack's expert matrices
-    (``_routed_mlp``).  Returns ``(x, state, load)``, ``load`` as
-    ``_mlp`` gives it."""
+    (``_routed_mlp``).  ``windowed``: the layer is one of the model's
+    window layers (``LlamaConfig.period``) — the caller's ``attend``
+    masks accordingly; here it decides whether the heads are rotated (a
+    full layer of a ``full_rope=False`` model rotates nothing).  With
+    ``parallel_block`` attention and feed-forward read the same normed
+    input and join in one residual sum.  Returns ``(x, state, load)``,
+    ``load`` as ``_mlp`` gives it."""
     lead = x.shape[:-1]
-    h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
+    h = _norm(x, layer["ln_attn"], c)
     if c.kv_lora_rank:
         with jax.named_scope("mla"):
             xq, c_kv, k_rope = _latent_qkv(layer, h, c, cos, sin, positions)
@@ -360,20 +422,34 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         xq = xq.reshape(*lead, c.n_heads, c.head_dim)
         xk = xk.reshape(*lead, c.n_kv_heads, c.head_dim)
         xv = (h @ layer["wv"]).reshape(*lead, c.n_kv_heads, c.head_dim)
-        xq = apply_rope(xq, cos, sin, positions)
-        xk = apply_rope(xk, cos, sin, positions)
+        if windowed or c.full_rope:
+            xq = apply_rope(xq, cos, sin, positions)
+            xk = apply_rope(xk, cos, sin, positions)
         xq = constrain_act(xq, ("batch", "seq", "heads", "head_dim"))
         xk = constrain_act(xk, ("batch", "seq", "kv_heads", "head_dim"))
-        attn, state = attend(xq, xk, xv)
+        with jax.named_scope("attn_window" if windowed else "attn_full"):
+            attn, state = attend(xq, xk, xv)
     attn = attn.reshape(*lead, -1)               # heads * value width
-    x = x + (attn @ layer["wo"]).astype(x.dtype)
+    attn = (attn @ layer["wo"]).astype(x.dtype)
+    if c.parallel_block:
+        out, load = _mlp(layer, h, c, index)
+        x = x + attn + out.astype(x.dtype)
+        return constrain_act(x, ("batch", "seq", "embed")), state, load
+    x = x + attn
     x = constrain_act(x, ("batch", "seq", "embed"))
 
-    h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
+    h = _norm(x, layer["ln_mlp"], c)
     out, load = _mlp(layer, h, c, index)
     x = x + out.astype(x.dtype)
     x = constrain_act(x, ("batch", "seq", "embed"))
     return x, state, load
+
+
+def _norm(x, weight, c: LlamaConfig):
+    """The block's and the head's norm, of the kind the config names."""
+    if c.norm == "layer":
+        return layernorm(x, weight, c.norm_eps)
+    return rmsnorm(x, weight, c.norm_eps)
 
 
 def _unconstrained(x, _dims):
@@ -465,8 +541,13 @@ def _mlp(layer: dict, h, c: LlamaConfig, index=None):
     out, load = _routed_mlp(layer, h, c, index)
     if c.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            out = out + _swiglu(h, layer["shared_gate"], layer["shared_up"],
-                                layer["shared_down"])
+            # one SwiGLU as wide as all the shared experts together is
+            # the sum of theirs; averaged, that sum over their number
+            shared = _swiglu(h, layer["shared_gate"], layer["shared_up"],
+                             layer["shared_down"])
+            if c.shared_experts_average:
+                shared = shared / c.n_shared_experts
+            out = out + shared
     return out, load
 
 
@@ -552,17 +633,50 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
     return out.astype(h.dtype).reshape(*lead, dim), load
 
 
-def _rope_tables(c: LlamaConfig):
-    """cos and sin (max_seq, rope_dim / 2) float32 of what a head
-    rotates."""
-    return rope_frequencies(c.rope_dim, c.max_seq, c.rope_theta,
-                            jnp.float32, c.rope_scaling)
+def _rope_tables(c: LlamaConfig, positions: int | None = None):
+    """cos and sin (positions, rope_dim / 2) float32 of what a head
+    rotates; ``positions``: as many as the caller can reach (a step
+    program: its cache's), by default the model's ``max_seq``."""
+    return rope_frequencies(c.rope_dim, positions or c.max_seq,
+                            c.rope_theta, jnp.float32, c.rope_scaling)
 
 
 def _stacks(params: dict, c: LlamaConfig) -> list:
     """``[(a stack's stacked leaves, the config that reads them)]``, in
     the layers' order (``LlamaConfig.stacks``)."""
     return [(params[name], cfg) for name, cfg in c.stacks().items()]
+
+
+def _by_period(stack: dict, c: LlamaConfig):
+    """A stack's leaves (layers, ...) as a scan over the layer pattern's
+    periods takes them: ``(what it scans, what it closes over)``.  A
+    model whose layers are all alike (a period of one) is scanned layer
+    by layer, as it lies.  Unlike layers are not sliced by the scan at
+    all: the body takes each place's layer out of the whole stack where
+    it lies (``_place``) — a period's slice, sliced again by place, is a
+    copy of every weight of the period on every call."""
+    if len(c.period) == 1:
+        return stack, None
+    periods = next(iter(jax.tree.leaves(stack))).shape[0] // len(c.period)
+    return jnp.arange(periods), stack
+
+
+def _place(scanned, whole, j: int, c: LlamaConfig) -> dict:
+    """The layer at place ``j`` of the period a scan over ``_by_period``
+    is at."""
+    if whole is None:
+        return scanned
+    return jax.tree.map(lambda leaf: lax.dynamic_index_in_dim(
+        leaf, scanned * len(c.period) + j, keepdims=False), whole)
+
+
+def _unperiod(scanned, c: LlamaConfig):
+    """What a scan over periods stacked per place, (periods, places,
+    ...), back in the layers' order (layers, ...)."""
+    if len(c.period) == 1:
+        return scanned
+    return jax.tree.map(
+        lambda leaf: leaf.reshape(-1, *leaf.shape[2:]), scanned)
 
 
 def _checkpointed(block, remat: str):
@@ -626,10 +740,18 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         spec = logical_to_spec(dims, llama_rules())
         return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
-    def attend(xq, xk, xv, w_kvb=None):
+    def attend(window, xq, xk, xv, w_kvb=None):
         # no cache: the whole sequence attends over itself
         if w_kvb is not None:       # latent: xk, xv are c_kv and k_rope
             out = _attend_latent_rows(xq, xk, xv, w_kvb, c)
+        elif window:
+            # the flash kernel takes no window mask: the blockwise path
+            # is named for a window layer, never swapped in silently
+            if use_ring:
+                raise ValueError("ring (sequence-parallel) attention "
+                                 "computes no sliding window")
+            out = attention(xq, xk, xv, causal=True, impl="blockwise",
+                            window=window)
         elif use_ring:
             from ant_ray_tpu.parallel.ring import ring_attention  # noqa: PLC0415
 
@@ -648,12 +770,25 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         return out, kv
 
     def scan_stack(x, stack, cfg):
-        def block(x, layer):
-            x, kv, _ = apply_block(layer, x, cfg, cos, sin, positions,
-                                   attend, constrain_act)
-            return x, kv
+        scanned, whole = _by_period(stack, cfg)
 
-        return lax.scan(_checkpointed(block, remat), x, stack)
+        def period(x, layers):
+            # the period's unlike layers, each with its own way to attend
+            kvs = []
+            for j, windowed in enumerate(cfg.period):
+                x, kv, _ = apply_block(
+                    _place(layers, whole, j, cfg), x, cfg, cos, sin,
+                    positions,
+                    functools.partial(attend,
+                                      cfg.window if windowed else 0),
+                    constrain_act, windowed=windowed)
+                kvs.append(kv)
+            if len(kvs) == 1:
+                return x, kvs[0]
+            return x, jax.tree.map(lambda *parts: jnp.stack(parts), *kvs)
+
+        x, kv = lax.scan(_checkpointed(period, remat), x, scanned)
+        return x, _unperiod(kv, cfg)
 
     x = params["embed"][tokens].astype(c.dtype)
     # Staged reshard: first acknowledge the gather's TABLE-natural
@@ -677,7 +812,7 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         kvs.append(kv)
     kv = kvs[0] if len(kvs) == 1 else jax.tree.map(
         lambda *parts: jnp.concatenate(parts), *kvs)
-    x = rmsnorm(x, params["norm_f"], c.norm_eps)
+    x = _norm(x, params["norm_f"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     if logits_at is not None:
         x = jnp.take(x, logits_at, axis=1)          # (b, dim)
@@ -722,10 +857,10 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
     pp = mesh.shape["pp"]
     if c.n_layers % pp != 0:
         raise ValueError(f"n_layers {c.n_layers} % pp {pp} != 0")
-    if c.n_dense_layers or c.kv_lora_rank:
-        raise ValueError("the pipeline schedule runs one stack of "
+    if c.n_dense_layers or c.kv_lora_rank or c.window:
+        raise ValueError("the pipeline schedule runs one stack of like "
                          "grouped-query layers: no leading dense layers, "
-                         "no latent attention")
+                         "no latent attention, no window layers")
     cos, sin = _rope_tables(c)
 
     def attend(xq, xk, xv):
@@ -752,7 +887,7 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
         params["layers"])
     y = gpipe(stage_fn, stacked, micro, mesh=mesh)
     x = y.reshape(b, *y.shape[2:])
-    x = rmsnorm(x, params["norm_f"], c.norm_eps)
+    x = _norm(x, params["norm_f"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
     import optax  # noqa: PLC0415
@@ -789,24 +924,46 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
 # rows they write and which slab they attend over.
 
 def kv_slabs(config: LlamaConfig) -> dict:
-    """What a layer keeps of a position: the cache's two slab leaves by
+    """What a layer keeps of a position: the cache's slab leaves by
     name, each with the shape of one position.  Keys and values per KV
     head — or, with latent attention, the position's latent ``c_kv``
     (after its norm) and its one rotary key ``k_rope`` (rotated),
-    ``kv_lora_rank + qk_rope_head_dim`` values and no heads axis."""
+    ``kv_lora_rank + qk_rope_head_dim`` values and no heads axis.  A
+    model with window layers has a second group, ``k_ring`` / ``v_ring``:
+    its full layers keep every position in ``k`` / ``v``, its window
+    layers the newest ``ring_positions`` in a ring."""
     c = config
     if c.kv_lora_rank:
         return {"c_kv": (c.kv_lora_rank,), "k_rope": (c.qk_rope_head_dim,)}
-    return {"k": (c.n_kv_heads, c.head_dim), "v": (c.n_kv_heads, c.head_dim)}
+    position = (c.n_kv_heads, c.head_dim)
+    return {"k": position, "v": position,
+            **({"k_ring": position, "v_ring": position} if c.window else {})}
+
+
+def ring_positions(config: LlamaConfig, max_seq: int, chunk: int = 0) -> int:
+    """Rows of a window layer's ring in a ``max_seq``-position cache
+    whose prompts arrive in chunks of ``chunk`` tokens (0: whole, or
+    never): position ``p`` lies at row ``p mod ring``.  The step
+    programs write a call's rows and only THEN attend
+    (``_scan_layers``), so the ring holds the window AND the chunk: in
+    one of exactly ``window`` rows a chunk's later tokens would
+    overwrite keys its first queries still see.  0 without window
+    layers."""
+    if not config.window:
+        return 0
+    return min(config.window + chunk, max_seq)
 
 
 def init_kv_cache(config: LlamaConfig, slots: int,
-                  max_seq: int | None = None) -> dict:
+                  max_seq: int | None = None, chunk: int = 0) -> dict:
     """Per-slot dense slabs (layers, slots, max_seq, *position), one for
     each of ``kv_slabs``; all ``n_layers`` of a model lie in one slab,
-    its leading dense layers first.  A routed model's cache also carries
-    ``routing``, the step programs' running counters
-    (``ROUTING_COUNTERS``).
+    its leading dense layers first — or, of a model with window layers,
+    the full layers in (full layers, slots, max_seq, ...) and the window
+    layers in rings (window layers, slots, ``ring_positions``, ...),
+    both under ONE ``length``; ``chunk`` is the width its prompts are
+    ingested in.  A routed model's cache also carries ``routing``, the
+    step programs' running counters (``ROUTING_COUNTERS``).
 
     Whoever jits a step program owns these buffers and DONATES them
     (``llm/engine.py``: ``donate_argnums=(1,)``): every leaf of the
@@ -815,8 +972,12 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     the donation a call allocates and fills a second whole cache."""
     c = config
     ms = max_seq or c.max_seq
-    cache = {name: jnp.zeros((c.n_layers, slots, ms, *position), c.dtype)
-             for name, position in kv_slabs(c).items()}
+    n_window, n_full = c.layer_counts()
+    ring = ring_positions(c, ms, chunk)
+    cache = {name: jnp.zeros(
+        ((n_window, slots, ring) if name.endswith("_ring")
+         else (n_full, slots, ms)) + position, c.dtype)
+        for name, position in kv_slabs(c).items()}
     # tokens already written per slot (== next write position)
     cache["length"] = jnp.zeros((slots,), jnp.int32)
     if c.num_experts:
@@ -846,10 +1007,11 @@ ROUTING_COUNTERS = (
 
 def _hoist_experts(layers: dict, c: LlamaConfig):
     """A stack of layers as ``_scan_layers``' scan takes it: ``(the
-    leaves it slices layer by layer, the expert matrices it closes over
-    whole, the layer indices it scans beside them)`` — see
-    ``_routed_mlp`` on why; a dense stack's layers are all sliced."""
-    index = jnp.arange(layers["ln_attn"].shape[0])
+    leaves it slices layer by layer (``_by_period``), the expert
+    matrices it closes over whole, the period indices it scans beside
+    them)`` — see ``_routed_mlp`` on why; a dense stack's layers are all
+    sliced."""
+    index = jnp.arange(layers["ln_attn"].shape[0] // len(c.period))
     if not c.num_experts:
         return layers, {}, index
     whole = {name: layers[name] for name in ("w_gate", "w_up", "w_down")}
@@ -893,9 +1055,28 @@ def prefill_into_cache(params: dict, tokens, cache: dict, slot,
         else "blockwise")
     cache = dict(cache)
     slot = jnp.asarray(slot, jnp.int32)
-    for name, rows in zip(kv_slabs(config), kept):
+
+    def put(name, rows):
         cache[name] = lax.dynamic_update_slice(
             cache[name], rows, (0, slot) + (0,) * (rows.ndim - 2))
+
+    if config.window:
+        # full layers keep the prompt as it lies; a window layer's ring
+        # row r gets the newest position p <= length - 1 with p = r
+        # (mod ring) — rows that hold none yet are masked by the walk
+        kinds = config.period * (config.n_layers // len(config.period))
+        full, windowed = (jnp.array(
+            [i for i, w in enumerate(kinds) if w == kind], jnp.int32)
+            for kind in (False, True))
+        ring = cache["k_ring"].shape[2]
+        held = jnp.clip(_ring_holds(last_pos, jnp.arange(ring), ring),
+                        0, tokens.shape[1] - 1)
+        for name, rows in zip(("k", "v"), kept):
+            put(name, rows[full])
+            put(name + "_ring", rows[windowed][:, :, held])
+    else:
+        for name, rows in zip(kv_slabs(config), kept):
+            put(name, rows)
     cache["length"] = cache["length"].at[slot].set(length)
     return logits[0], cache
 
@@ -923,8 +1104,25 @@ def span_positions(longest: int, max_seq: int) -> int:
     return min(max(-(-longest // size), 1) * size, max_seq)
 
 
+def _ring_holds(top, rows, ring: int):
+    """The position each of a ring's ``rows`` holds once position
+    ``top`` is written: the newest p <= top with p = row (mod ring);
+    negative where the row holds none yet."""
+    return top - (top - rows) % ring
+
+
+def _rows_of(pos, live, length: int, max_seq: int):
+    """Where positions ``pos`` are written in a slab of ``length`` rows
+    of a ``max_seq``-position cache — where they lie, or in a ring at
+    ``pos mod length``; a row not ``live`` (padding, an inactive or a
+    full slot) out of bounds, so that the scatter drops it."""
+    if length != max_seq:
+        pos, live = pos % length, live & (pos < max_seq)
+    return jnp.where(live, pos, jnp.int32(length))
+
+
 def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
-                 w_kvb=None):
+                 w_kvb=None, window: int = 0, top=None):
     """Attention of rows ``xq`` (rows, heads, hd), row ``r`` over cached
     positions 0..``pos[r]`` of layer ``i`` of the CARRIED slabs ``ks``,
     ``vs`` (layers, slots, max_seq, *position): of ONE slot's slab that
@@ -941,10 +1139,23 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
     exactly 1: a row's output does not depend, to the bit, on how far
     the OTHER rows made the walk go.  A row whose position lies behind
     the walk (an inactive slot's, a chunk's padding) attends over what
-    was walked; nobody reads it.  Block 0 holds position 0, which every
-    row may see, so no denominator is zero.  A slab no longer than a
+    was walked; nobody reads it.  Every live row sees its own position,
+    so no denominator is zero.  A slab no longer than a
     block is one block; the last block of one that is not a multiple
     starts early and masks what the block before it covered.
+
+    With ``window`` the slabs are a window layer's RINGS
+    (``ring_positions``) and ``top`` is the newest position written —
+    the slot's (a scalar) or each row's own slot's (rows,).  The walk is
+    the same, over the ring's rows in the order they lie; a row's mask
+    is made from the position each ring row holds NOW (``_ring_holds``):
+    row ``r`` at position ``t`` sees a ring row iff it holds a position
+    ``s`` with 0 <= t - s < window.  A block may then be masked whole
+    before a row has seen anything, so the running maximum starts at
+    float32's lowest finite value, not at -inf: such a block adds exact
+    zeros under a rescale of exactly 1 there too, and the first score
+    seen takes the maximum over with a rescale of exactly 0 — the order
+    of a row's sums still depends on its own position alone.
 
     Grouped-query slabs hold keys and values per KV head: bf16 inputs
     with fp32 accumulation keep the products at full MXU rate without an
@@ -1007,7 +1218,12 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
         start = jnp.minimum(first, max_seq - size)
         bk, bv = block(ks, start), block(vs, start)
         at = start + jnp.arange(size)
-        valid = (at >= first) & (at <= pos[:, None])          # (rows, size)
+        if window:
+            held = _ring_holds(jnp.reshape(top, (-1, 1)), at, max_seq)
+            valid = (at >= first) & (held >= 0) & (held <= pos[:, None]) & (
+                pos[:, None] - held < window)
+        else:
+            valid = (at >= first) & (at <= pos[:, None])      # (rows, size)
         s = jnp.where(valid.reshape(rows, *(1,) * len(heads), size),
                       scores(bk, bv) * scale, -jnp.inf)
         new_high = jnp.maximum(high, jnp.max(s, axis=-1))
@@ -1018,7 +1234,7 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
         return new_high, denom, out
 
     _, denom, out = lax.fori_loop(0, blocks, walk, (
-        jnp.full((rows, *heads), -jnp.inf, jnp.float32),
+        jnp.full((rows, *heads), jnp.finfo(jnp.float32).min, jnp.float32),
         jnp.zeros((rows, *heads), jnp.float32),
         jnp.zeros((rows, *heads, width), jnp.float32)))
     out = out / denom[..., None]
@@ -1036,48 +1252,69 @@ def _slab_positions(cache: dict, c: LlamaConfig) -> int:
 def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                  write_attend, *, decode: bool):
     """A step program's layers over rows ``x`` (rows, dim): a
-    ``lax.scan`` of ``apply_block`` over each stack of like layers
-    (``_stacks``), whose carry is the rows and the whole cache.  Returns
-    (x, the cache's new entries: its two slabs, its counters — a
-    ``decode`` step's counted apart as well, ``ROUTING_COUNTERS``).
+    ``lax.scan`` over each stack's PERIODS of the layer pattern
+    (``_stacks``, ``LlamaConfig.period``), whose body is the period's
+    layers — unlike ones, each ``apply_block`` with its own way to
+    attend; a period of one where all layers are alike — and whose
+    carry is the rows and the whole cache.  Returns (x, the cache's new
+    entries: its slabs, its counters — a ``decode`` step's counted
+    apart as well, ``ROUTING_COUNTERS``).
 
-    ``write_attend(ks, vs, i, xq, xk, xv[, w_kvb]) -> (out, (ks, vs))``
-    is the block's attention over the carried slabs (``kv_slabs``: keys
-    and values, or the latent and the rotary key), and has one order:
-    the cache travels as the loop's CARRY, which the compiler aliases to
-    the donated input, so layer ``i``'s new rows are written where they
-    lie (a row whose position is max_seq is dropped by the scatter), and
-    only THEN does ``_attend_slab`` read the carried array, block by
-    block and no further than the step's longest live position — no
-    layer's slab is ever sliced out whole.  As a scanned input and
-    output of the loop the slabs are copied about three times a call;
-    attending over the old slab with the new rows beside it compiles to
-    more temporaries and reorders the float32 sums."""
-    cos, sin = _rope_tables(c)
+    ``write_attend(ks, vs, i, window, xq, xk, xv[, w_kvb]) -> (out, (ks,
+    vs))`` is the block's attention over the carried slabs of the
+    layer's kind (``kv_slabs``: keys and values, or the latent and the
+    rotary key; a window layer's the rings, ``window`` then its width,
+    else 0) and has one order: the cache travels as the loop's CARRY,
+    which the compiler aliases to the donated input, so layer ``i``'s
+    new rows are written where they lie (a row whose position is
+    max_seq is dropped by the scatter), and only THEN does
+    ``_attend_slab`` read the carried array, block by block and no
+    further than the step's longest live position — no layer's slab is
+    ever sliced out whole.  As a scanned input and output of the loop
+    the slabs are copied about three times a call; attending over the
+    old slab with the new rows beside it compiles to more temporaries
+    and reorders the float32 sums."""
+    # as far as a row's position goes: a full slot's is max_seq itself,
+    # a chunk's last padded row's max_seq + chunk - 2
+    cos, sin = _rope_tables(c, _slab_positions(cache, c) + x.shape[0])
     names = tuple(kv_slabs(c))
 
     def scan_stack(carry, stack, cfg, first):
         """``first``: the stack's first layer's place in the cache."""
         layers, experts, index = _hoist_experts(stack, cfg)
+        layers, whole = _by_period(layers, cfg)
+        kinds = cfg.period
 
-        def block(carry, scanned):
-            x, ks, vs = carry                    # ks/vs: the whole cache
-            layer, i = scanned                   # i: the layer's number
-            x, (ks, vs), load = apply_block(     # in its stack
-                {**layer, **experts}, x, cfg, cos, sin, positions,
-                functools.partial(write_attend, ks, vs, first + i),
-                _unconstrained, i)
-            return (x, ks, vs), load
+        def period(carry, scanned):
+            x, slabs = carry                     # slabs: the whole cache
+            layers, p = scanned                  # p: the period's number
+            loads = []                           # in its stack
+            for j, windowed in enumerate(kinds):
+                # the layer's place among the slabs of its kind
+                i = (p * kinds.count(windowed) + kinds[:j].count(windowed)
+                     + (0 if windowed else first))
+                k, v = names[2:] if windowed else names[:2]
+                x, (ks, vs), load = apply_block(
+                    {**_place(layers, whole, j, cfg), **experts}, x, cfg,
+                    cos, sin, positions, functools.partial(
+                        write_attend, slabs[k], slabs[v], i,
+                        cfg.window if windowed else 0),
+                    _unconstrained, p * len(kinds) + j, windowed)
+                slabs = {**slabs, k: ks, v: vs}
+                loads.append(load)
+            return (x, slabs), (loads[0] if len(loads) == 1
+                                or loads[0] is None else jnp.stack(loads))
 
-        return lax.scan(block, carry, (layers, index))
+        carry, loads = lax.scan(period, carry, (layers, index))
+        return carry, _unperiod(loads, cfg)
 
-    carry, first, loads = (x, cache[names[0]], cache[names[1]]), 0, None
+    carry, first, loads = (x, {n: cache[n] for n in names}), 0, None
     for stack, cfg in _stacks(params, c):
         carry, loads = scan_stack(carry, stack, cfg, first)
         first += cfg.n_layers
     routed = None if loads is None else (
         loads.shape[0] * x.shape[0] * c.experts_per_token)
-    return carry[0], {names[0]: carry[1], names[1]: carry[2],
+    return carry[0], {**carry[1],
                       **_count_routing(cache, loads, routed, decode)}
 
 
@@ -1112,26 +1349,30 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
     offs = jnp.arange(chunk, dtype=jnp.int32)
     pos = start + offs                           # (chunk,) absolute
-    # Pad tokens' writes land at max_seq → dropped by the scatter; rope
-    # positions are clamped only to keep the gather in range (their
-    # values never reach the slab or the masked attention).
-    write_pos = jnp.where(offs < chunk_len, pos, jnp.int32(max_seq))
-    rope_pos = jnp.minimum(pos, jnp.int32(c.max_seq - 1))
+    # Pad tokens' writes land out of bounds (``_rows_of``) → dropped by
+    # the scatter; their values never reach the slab or the masked
+    # attention.
+    top = start + chunk_len - 1                  # the newest position
+    # by the rows of a slab: max_seq, and a window layer's ring
+    lengths = {cache[name].shape[2] for name in kv_slabs(c)}
+    write_pos = {n: _rows_of(pos, offs < chunk_len, n, max_seq)
+                 for n in lengths}
+    blocks = {n: _span_blocks(top + 1, n) for n in lengths}
 
-    blocks = _span_blocks(start + chunk_len, max_seq)
-
-    def write_chunk(ks, vs, i, xq, xk, xv, w_kvb=None):
+    def write_chunk(ks, vs, i, window, xq, xk, xv, w_kvb=None):
         """The chunk's real rows into (layer i, slot); attend over that
-        slot's slab, causally by absolute position."""
-        ks = ks.at[i, slot, write_pos].set(xk.astype(ks.dtype))
-        vs = vs.at[i, slot, write_pos].set(xv.astype(vs.dtype))
-        return _attend_slab(xq, ks, vs, i, slot, pos, blocks, c,
-                            w_kvb), (ks, vs)
+        slot's slab — a window layer's ring — causally by absolute
+        position, as far as the chunk's own end."""
+        n = ks.shape[2]
+        ks = ks.at[i, slot, write_pos[n]].set(xk.astype(ks.dtype))
+        vs = vs.at[i, slot, write_pos[n]].set(xv.astype(vs.dtype))
+        return _attend_slab(xq, ks, vs, i, slot, pos, blocks[n], c, w_kvb,
+                            window, top), (ks, vs)
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
-    x, written = _scan_layers(params, x, cache, c, rope_pos, write_chunk,
+    x, written = _scan_layers(params, x, cache, c, pos, write_chunk,
                               decode=False)
-    x = rmsnorm(x, params["norm_f"], c.norm_eps)
+    x = _norm(x, params["norm_f"], c)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x_last @ head.astype(c.dtype)).astype(jnp.float32)
@@ -1154,27 +1395,30 @@ def decode_step(params: dict, last_tokens, cache: dict,
     c = config
     max_seq = _slab_positions(cache, c)
     pos = cache["length"]                       # (slots,) write position
-    # Inactive slots' scatter writes are pushed out of bounds (and
-    # dropped), as a full slot's are; their lengths hold still below.
-    write_pos = jnp.where(active, pos, jnp.int32(max_seq))
-
     # The walk ends behind the longest ACTIVE row: an idle slot that
     # holds a resident session's long slab does not lengthen it.
-    blocks = _span_blocks(jnp.max(jnp.where(active, pos, 0)) + 1, max_seq)
+    longest = jnp.max(jnp.where(active, pos, 0)) + 1
     slots = jnp.arange(last_tokens.shape[0])
+    # by the rows of a slab (max_seq, and a window layer's ring).
+    # Inactive slots' scatter writes are pushed out of bounds (and
+    # dropped), as a full slot's are; their lengths hold still below.
+    lengths = {cache[name].shape[2] for name in kv_slabs(c)}
+    write_pos = {n: _rows_of(pos, active, n, max_seq) for n in lengths}
+    blocks = {n: _span_blocks(longest, n) for n in lengths}
 
-    def write_one(ks, vs, i, xq, xk, xv, w_kvb=None):
-        """One row a slot into layer i; attend over the layer's slabs,
-        each slot up to its own position."""
-        ks = ks.at[i, slots, write_pos].set(xk.astype(ks.dtype))
-        vs = vs.at[i, slots, write_pos].set(xv.astype(vs.dtype))
-        return _attend_slab(xq, ks, vs, i, None, pos, blocks, c,
-                            w_kvb), (ks, vs)
+    def write_one(ks, vs, i, window, xq, xk, xv, w_kvb=None):
+        """One row a slot into layer i; attend over the layer's slabs
+        (a window layer's rings), each slot up to its own position."""
+        n = ks.shape[2]
+        ks = ks.at[i, slots, write_pos[n]].set(xk.astype(ks.dtype))
+        vs = vs.at[i, slots, write_pos[n]].set(xv.astype(vs.dtype))
+        return _attend_slab(xq, ks, vs, i, None, pos, blocks[n], c, w_kvb,
+                            window, pos), (ks, vs)
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
     x, written = _scan_layers(params, x, cache, c, pos, write_one,
                               decode=True)
-    x = rmsnorm(x, params["norm_f"], c.norm_eps)
+    x = _norm(x, params["norm_f"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
     # Clamped so a full slot never indexes past its slab.

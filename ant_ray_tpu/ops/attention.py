@@ -31,13 +31,17 @@ def _repeat_kv(k, groups: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "block_k"))
+    jax.jit, static_argnames=("causal", "scale", "block_k", "window"))
 def blockwise_attention(q, k, v, *, causal: bool = True,
-                        scale: float | None = None, block_k: int = 512):
+                        scale: float | None = None, block_k: int = 512,
+                        window: int = 0):
     """Flash-style attention in pure JAX.
 
     q: (batch, q_len, heads, dim); k/v: (batch, kv_len, kv_heads, dim).
     Memory is O(q_len · block_k) per head instead of O(q_len · kv_len).
+    ``window`` > 0 (causal only): query ``t`` sees key ``s`` iff
+    0 <= t - s < window; every block is still computed (skipping the
+    blocks behind the window is the kernels' to do).
     """
     batch, q_len, num_heads, head_dim = q.shape
     kv_len, num_kv_heads = k.shape[1], k.shape[2]
@@ -67,6 +71,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
         if causal:
             kv_pos = blk_idx * block_k + jnp.arange(block_k)
             mask = kv_pos[None, :] > q_pos[:, None]
+            if window:
+                mask |= q_pos[:, None] - kv_pos[None, :] >= window
             scores = jnp.where(mask[None, None], NEG_INF, scores)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
         p = jnp.exp(scores - m_new[..., None])
@@ -141,14 +147,21 @@ def kernel_fits(q_shape, k_shape) -> bool:
 
 
 def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
-              impl: str = "auto", mesh=None, q_spec=None, kv_spec=None):
+              impl: str = "auto", mesh=None, q_spec=None, kv_spec=None,
+              window: int = 0):
     """Dispatch: 'pallas' | 'blockwise' | 'reference' | 'auto'.
+    A sliding ``window`` is computed by 'blockwise' alone, which the
+    caller has to name.
 
     Under a ``mesh`` (q/k/v sharded by ``q_spec``/``kv_spec`` over batch
     and heads, sequence whole) the pallas kernel runs per shard inside a
     ``shard_map``: a Mosaic kernel is not partitioned automatically, and
     attention is independent per batch row and head group."""
     on_tpu = jax.default_backend() == "tpu"
+    if window and not (impl == "blockwise" and causal):
+        raise ValueError(
+            f"attention: a sliding window is computed by the causal "
+            f"blockwise path only, not by impl={impl!r}")
     if impl == "auto":
         if on_tpu and not kernel_fits(q.shape, k.shape):
             raise ValueError(
@@ -169,7 +182,8 @@ def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                 out_specs=q_spec, check_vma=False)
         return kernel(q, k, v)
     if impl == "blockwise":
-        return blockwise_attention(q, k, v, causal=causal, scale=scale)
+        return blockwise_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
     if impl == "reference":
         from ant_ray_tpu.parallel.ring import reference_attention  # noqa: PLC0415
 

@@ -308,15 +308,90 @@ def test_decode_span_pct_reads_the_counters_and_nothing_without_them(params):
     assert decode_span_pct.read({"traced": None}) is None
 
 
+def test_window_and_full_walks_are_counted_by_the_devices_rule(monkeypatch):
+    """``full_span_positions`` / ``window_span_positions`` (PR 34), per
+    decode step AND per chunk of a model with window layers: the
+    positions walked on its full layers' slabs and on its window layers'
+    rings — whole blocks (16 here) up to the longest live row, capped by
+    the ring's 24 rows, times the layers of each kind — from the host's
+    own ``kv_len``, exactly what the device makes of the program's own
+    inputs; ``decode_rows_past_window`` the active rows whose context
+    exceeds the window of 16.  ``decode_span_positions`` counts the FULL
+    layers' walk against ``max_seq``, as in every other model."""
+    import numpy as np
+
+    from chipbench.layer_metrics import window_walk_pct
+
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
+    cfg = llama.CONFIGS["cmdaplus-tiny"]
+    eng = LLMEngine(cfg, slots=3, max_seq=96, prefill_chunk_tokens=8,
+                    tokenizer=_NoEos())
+    assert eng._ring == 24 and cfg.layer_counts() == (6, 2)
+    device = {"full": 0, "window": 0, "past": 0, "span": 0}
+    decode_jit, chunk_jit = eng._decode_jit, eng._prefill_chunk_jit
+
+    def walked(longest):
+        device["full"] += 2 * llama.span_positions(longest, 96)
+        device["window"] += 6 * llama.span_positions(longest, 24)
+
+    def decode(params, cache, last, active):
+        lengths = np.where(np.asarray(active), np.asarray(cache["length"]), 0)
+        walked(int(lengths.max()) + 1)
+        device["span"] += llama.span_positions(int(lengths.max()) + 1, 96)
+        device["past"] += int((lengths + 1 > 16).sum())
+        return decode_jit(params, cache, last, active)
+
+    def chunk(params, cache, tokens, slot, start, length):
+        walked(int(start) + int(length))
+        return chunk_jit(params, cache, tokens, slot, start, length)
+
+    monkeypatch.setattr(eng, "_decode_jit", decode)
+    monkeypatch.setattr(eng, "_prefill_chunk_jit", chunk)
+    before = dict(eng.stats)
+    assert before["full_span_positions"] == before[
+        "window_span_positions"] == before["decode_rows_past_window"] == 0
+    eng.generate([list(range(3, 40)), [5, 9, 17], list(range(7, 20))],
+                 SamplingParams(max_tokens=12))
+    stats = eng.stats
+    assert stats["full_span_positions"] == device["full"] > 0
+    assert stats["window_span_positions"] == device["window"] > 0
+    assert stats["decode_rows_past_window"] == device["past"] > 0
+    assert stats["decode_span_positions"] == device["span"]
+    assert stats["decode_slab_positions"] == 96 * stats["decode_steps"]
+    # the rings stop at 24 rows where the slabs go on: under 100
+    obs = {"traced": {"engine": dict(stats), "engine_before": before},
+           "config": {"layer_types": ["sliding_attention"] * 3
+                      + ["full_attention"], "num_hidden_layers": 4}}
+    assert window_walk_pct.read(obs) == pytest.approx(
+        100.0 * device["window"] / (device["full"] * 3))
+    assert 30 < window_walk_pct.read(obs) < 100
+    for side in obs["traced"].values():
+        del side["window_span_positions"]
+    assert window_walk_pct.read(obs) is None          # the parent's program
+
+
+def test_a_model_without_window_layers_leaves_the_new_counters_at_zero(
+        params):
+    eng = _engine(params)
+    eng.generate(list(PROMPTS), SamplingParams(max_tokens=6))
+    assert eng._ring == 0 and eng.stats["decode_span_positions"] > 0
+    assert eng.stats["full_span_positions"] == eng.stats[
+        "window_span_positions"] == eng.stats["decode_rows_past_window"] == 0
+
+
 @pytest.mark.parametrize("name,scopes", [
-    ("olmoe-tiny", {"moe"}), ("axk1-tiny", {"mla", "moe", "moe_shared"})])
+    ("olmoe-tiny", {"moe", "attn_full"}),
+    ("axk1-tiny", {"mla", "moe", "moe_shared"}),
+    ("cmdaplus-tiny", {"moe", "moe_shared", "attn_window", "attn_full"})])
 def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     """What a routed model's step programs record, always on: the
     routing counters (``llama.ROUTING_COUNTERS``, PR 27; ``moe_rows_routed``
     and the decode steps' own PR 32) in ``LLMEngine.stats`` from the start, riding the step's one
     read, and the named scopes a reducer can file operations under —
     ``moe`` around the routed experts, ``moe_shared`` around the shared
-    one, ``mla`` around latent attention."""
+    one, ``mla`` around latent attention, ``attn_window`` and
+    ``attn_full`` around the two ways a grouped-query layer attends
+    (PR 34)."""
     cfg = llama.CONFIGS[name]
     assert llama.ROUTING_COUNTERS == (
         "moe_assignments", "moe_experts_hit", "moe_expert_slots",
@@ -346,7 +421,7 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     lowered = eng._decode_jit.lower(
         eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool))
     text = lowered.as_text(debug_info=True)
-    for scope in ("mla", "moe", "moe_shared"):
+    for scope in ("mla", "moe", "moe_shared", "attn_window", "attn_full"):
         assert (f'"{scope}/' in text) == (scope in scopes), scope
 
 
