@@ -123,6 +123,21 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
+def sampler_work(asked) -> int:
+    """What ``LLMEngine._sample_batch`` does for active rows that ask
+    ``(temperature, top_k, top_p)``: 0 the arg-max alone, 1 a plain
+    draw, 2 a sort of the vocabulary — the dearest that any row asks
+    for.  The host's copy of the rule the program applies on the device
+    to the same rows."""
+    work = 0
+    for temperature, top_k, top_p in asked:
+        if temperature > 0:
+            if top_k > 0 or top_p < 1.0:
+                return 2
+            work = 1
+    return work
+
+
 PHASES = ("drain", "admit", "chunk", "decode", "sample", "fetch", "emit",
           "housekeeping", "idle_wait")
 
@@ -165,6 +180,10 @@ class _PhaseRecorder:
     rule, no read), and ``decode_rows_past_window`` the active rows of
     a decode step whose context exceeds the window; the two
     ``decode_*_positions`` count the full layers' walk.
+    ``sample_plain_steps`` and ``sample_sorted_steps`` count the decode
+    steps whose sampler drew without a filter and with a sort
+    (``sampler_work`` of the step's rows: no read); the rest took the
+    arg-max alone.
     """
 
     def __init__(self, jax, stats: dict):
@@ -179,6 +198,7 @@ class _PhaseRecorder:
                     "decode_ahead_steps", "decode_span_positions",
                     "decode_slab_positions", "window_span_positions",
                     "full_span_positions", "decode_rows_past_window",
+                    "sample_plain_steps", "sample_sorted_steps",
                     "d2h_syncs"):
             stats[key] = 0
         stats["block_s"] = 0.0
@@ -900,7 +920,10 @@ class LLMEngine:
         key and its sampling parameters move into the slot's rows —
         token and key through one small program, no read."""
         slot, s = seq.slot, seq.sampling
-        row = (s.temperature, s.top_k, s.top_p)
+        # as float32 holds them: what sampler_work tests on the host
+        # is what the sampler tests on the device
+        row = (float(np.float32(s.temperature)), s.top_k,
+               float(np.float32(s.top_p)))
         if row != self._sampling_rows[slot]:
             self._sampling_rows[slot] = row
             self._sampling_dev = None
@@ -953,6 +976,9 @@ class LLMEngine:
             max(contexts), self.max_seq)
         stats["decode_slab_positions"] += self.max_seq
         self._note_walk(max(contexts), contexts)
+        work = sampler_work(self._sampling_rows[slot] for slot, _ in rows)
+        stats["sample_plain_steps"] += work == 1
+        stats["sample_sorted_steps"] += work == 2
         self._decode_since_chunk += 1
         rec.enter("sample")
         sampled = self._sample_all(logits)
@@ -1350,42 +1376,82 @@ class LLMEngine:
             self.cache.get("routing"), self._last)
         return sampled
 
-    def _sample_batch(self, logits, keys, active, temps, top_ks, top_ps,
-                      routing=None, last=None):
-        """Vectorized per-slot sampling: greedy when temperature == 0,
-        else temperature softmax with optional top-k / top-p (nucleus)
-        filtering — all branch-free for XLA.  Every row of ``keys``
-        (uint32 (n, 2)) is split as the eager ``jax.random.split``
-        would: the second half samples, the first half is the row's
-        next key where ``active``, and an inactive row keeps its key.
-        ``routing`` (a routed model's counters, uint32) is appended to
-        the tokens bit for bit, so that the step's one read brings both.
-        ``last`` (the per-slot token table) takes the active rows'
-        tokens: the next decode step is fed from it on the device.
-        Returns ``(tokens, keys, last)``."""
+    def _kept(self, scaled, top_ks, top_ps):
+        """The columns of ``scaled`` (rows × vocabulary) that a row's
+        top-k and top-p leave to draw from, from ONE value sort."""
         jax, jnp = self._jax, self._jnp
-        vocab = logits.shape[-1]
-        split = jax.vmap(jax.random.split)(keys)          # (n, 2, 2)
-        next_keys = jnp.where(active[:, None], split[:, 0], keys)
-        greedy = jnp.argmax(logits, axis=-1)
-
-        scaled = logits / jnp.maximum(temps[:, None], 1e-6)
+        vocab = scaled.shape[-1]
+        # values alone: a stable sort would carry an index along
+        sorted_desc = jnp.sort(scaled, axis=-1, stable=False)[:, ::-1]
         # top-k: mask everything below the k-th largest (k==0 → keep all)
-        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
         k_idx = jnp.clip(top_ks - 1, 0, vocab - 1)
         kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
         keep_k = (top_ks[:, None] <= 0) | (scaled >= kth)
         # top-p: smallest prefix of the sorted distribution with
-        # cumulative prob >= p
+        # cumulative prob >= p; cutoff_rank is its last rank
         probs_sorted = jax.nn.softmax(sorted_desc, axis=-1)
         cum = jnp.cumsum(probs_sorted, axis=-1)
-        cutoff_rank = jnp.sum(cum < top_ps[:, None], axis=-1)  # inclusive
-        ranks = jnp.argsort(jnp.argsort(-scaled, axis=-1), axis=-1)
-        keep_p = ranks <= cutoff_rank[:, None]
-        masked = jnp.where(keep_k & keep_p, scaled, -jnp.inf)
-        sampled = jax.vmap(
-            lambda k, lg: jax.random.categorical(k, lg))(split[:, 1],
-                                                         masked)
+        cutoff_rank = jnp.sum(cum < top_ps[:, None], axis=-1, keepdims=True)
+        v_cut = jnp.take_along_axis(
+            sorted_desc, jnp.minimum(cutoff_rank, vocab - 1), axis=-1)
+        above, tied = scaled > v_cut, scaled == v_cut
+        # a stable sort ranks equal logits by index: of those equal to
+        # the cutoff's, the ranks up to cutoff_rank go to the first
+        room = cutoff_rank + 1 - jnp.sum(above, axis=-1, keepdims=True)
+        before = jnp.cumsum(tied, axis=-1, dtype=jnp.int32) - tied
+        keep_p = (top_ps[:, None] >= 1.0) | above | (tied & (before < room))
+        return keep_k & keep_p
+
+    def _sample_batch(self, logits, keys, active, temps, top_ks, top_ps,
+                      routing=None, last=None):
+        """Vectorized per-slot sampling: greedy when temperature == 0,
+        else temperature softmax with optional top-k / top-p (nucleus)
+        filtering.  ONE program that does only what the ACTIVE rows ask
+        for — a ``lax.switch`` on the device over its own operands
+        (:func:`sampler_work` is the host's copy of the rule): no
+        active row samples → the arg-max; some sample and none filters
+        → a plain ``categorical`` draw; some filter → one value sort.
+        A row's token is the same whichever branch its neighbours
+        choose, and an inactive row decides nothing.
+
+        A row filters when it samples with ``top_k > 0`` or
+        ``top_p < 1``; ``top_p == 1`` is no filter (the float32 running
+        sum of the old formula could reach 1.0 a few columns early and
+        cut a tail under float32's resolution).  top-k keeps every
+        logit tied with the k-th largest; top-p keeps the smallest
+        prefix of the descending order whose mass reaches ``top_p``,
+        equal logits in ascending index order, so of a tie group that
+        straddles the cutoff the first by index stay.
+
+        Every row of ``keys`` (uint32 (n, 2)) is split as the eager
+        ``jax.random.split`` would: the second half samples, the first
+        half is the row's next key where ``active``, and an inactive
+        row keeps its key.  ``routing`` (a routed model's counters,
+        uint32) is appended to the tokens bit for bit, so that the
+        step's one read brings both.  ``last`` (the per-slot token
+        table) takes the active rows' tokens: the next decode step is
+        fed from it on the device.  Returns ``(tokens, keys, last)``."""
+        jax, jnp = self._jax, self._jnp
+        split = jax.vmap(jax.random.split)(keys)          # (n, 2, 2)
+        next_keys = jnp.where(active[:, None], split[:, 0], keys)
+        greedy = jnp.argmax(logits, axis=-1)
+        draw = jax.vmap(jax.random.categorical)
+
+        def scale():
+            return logits / jnp.maximum(temps[:, None], 1e-6)
+
+        def plain():
+            return draw(split[:, 1], scale())
+
+        def filtered():
+            scaled = scale()
+            return draw(split[:, 1], jnp.where(
+                self._kept(scaled, top_ks, top_ps), scaled, -jnp.inf))
+
+        samples = active & (temps > 0)
+        filters = samples & ((top_ks > 0) | (top_ps < 1.0))
+        work = jnp.where(filters.any(), 2, samples.any().astype(jnp.int32))
+        sampled = jax.lax.switch(work, (lambda: greedy, plain, filtered))
         tokens = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
         if last is not None:
             last = jnp.where(active, tokens, last)

@@ -10,8 +10,12 @@ from dataclasses import dataclass, field
 class SamplingParams:
     max_tokens: int = 64
     temperature: float = 0.0        # 0 → greedy
-    top_k: int = 0                  # 0 → disabled
-    top_p: float = 1.0              # 1 → disabled
+    # 0 → disabled; else every logit tied with the k-th largest stays
+    top_k: int = 0
+    # 1 → disabled (no filter at all); else the smallest prefix of the
+    # descending order whose mass reaches top_p, equal logits in index
+    # order: of a tie that straddles the cutoff the first by index stay
+    top_p: float = 1.0
     stop_token_ids: tuple = field(default_factory=tuple)
     seed: int | None = None
 

@@ -10,6 +10,7 @@ import pytest
 import jax
 
 from ant_ray_tpu.llm import LLMEngine, SamplingParams
+from ant_ray_tpu.llm import engine as engine_mod
 from ant_ray_tpu.models import llama
 
 import ant_ray_tpu as art
@@ -160,6 +161,185 @@ def test_stream_depends_on_neither_slot_nor_neighbours(params, which):
     full = _run_all(_pinned_engine(params, 8),
                     neighbours + [(prompt, _sampling(seed, n))])
     assert full[-1] == want
+
+
+# The sampler does what the active rows ask (PR 35): one program, three
+# amounts of work.  The reference is the sampler as it stood before —
+# three sorts of the vocabulary on every step, whatever was asked.
+VOCAB = 2048
+
+
+def _three_sorts(logits, keys, active, temps, top_ks, top_ps):
+    """``LLMEngine._sample_batch`` before PR 35, and the mask it drew
+    from: ranks by a double ``argsort`` (stable: ties by index)."""
+    jnp = jax.numpy
+    vocab = logits.shape[-1]
+    split = jax.vmap(jax.random.split)(keys)
+    next_keys = jnp.where(active[:, None], split[:, 0], keys)
+    greedy = jnp.argmax(logits, axis=-1)
+    scaled = logits / jnp.maximum(temps[:, None], 1e-6)
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k_idx = jnp.clip(top_ks - 1, 0, vocab - 1)
+    kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
+    keep_k = (top_ks[:, None] <= 0) | (scaled >= kth)
+    probs_sorted = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs_sorted, axis=-1)
+    cutoff_rank = jnp.sum(cum < top_ps[:, None], axis=-1)
+    ranks = jnp.argsort(jnp.argsort(-scaled, axis=-1), axis=-1)
+    keep_p = ranks <= cutoff_rank[:, None]
+    masked = jnp.where(keep_k & keep_p, scaled, -jnp.inf)
+    sampled = jax.vmap(jax.random.categorical)(split[:, 1], masked)
+    tokens = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+    return tokens, next_keys, keep_k & keep_p, scaled
+
+
+def _tied_logits(seed, rows, coarse=False):
+    """Logits as the model's head gives them: bf16 values cast to
+    float32, so equal values are the rule; ``coarse`` leaves some 60
+    distinct values, and every cutoff falls inside a group of ties."""
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(seed), (rows, VOCAB))
+    if coarse:
+        x = jax.numpy.round(4.0 * x) / 4.0
+    return x.astype(jax.numpy.bfloat16).astype(jax.numpy.float32)
+
+
+# (temperature, top_k, top_p, active) a row; the work the rows ask for
+T, F = True, False
+SAMPLER_CASES = {
+    "all-greedy": ([(0.0, 0, 1.0, T)] * 4 + [(1.0, 0, 1.0, F),
+                                             (0.8, 5, 0.9, F)], 0),
+    "plain": ([(1.0, 0, 1.0, T), (0.7, 0, 1.0, T), (0.0, 0, 1.0, T),
+               (1.3, 0, 1.0, T), (1.0, 0, 1.0, F), (0.7, 0, 1.0, T)], 1),
+    "top-p": ([(1.0, 0, 0.9, T), (0.7, 0, 0.5, T), (1.0, 0, 0.95, T),
+               (0.7, 0, 0.3, T), (1.5, 0, 0.99, T), (0.7, 0, 0.0, T)], 2),
+    "top-k": ([(1.0, 1, 1.0, T), (0.7, 5, 1.0, T), (1.0, 40, 1.0, T),
+               (0.7, 300, 1.0, T), (1.5, 2, 1.0, T),
+               (1.0, VOCAB + 5, 1.0, T)], 2),
+    "both": ([(0.8, 40, 0.95, T), (0.7, 5, 0.5, T), (1.0, 300, 0.9, T),
+              (1.0, 2, 0.99, T), (1.5, 40, 0.3, T), (0.8, 40, 0.95, F)], 2),
+    "mixed": ([(0.0, 0, 1.0, T), (0.7, 0, 0.9, T), (0.0, 0, 1.0, T),
+               (1.0, 0, 1.0, T), (0.8, 40, 0.95, T), (0.0, 7, 0.5, F)], 2),
+    "greedy-rows-filter-nothing": ([(0.0, 40, 0.5, T), (1.0, 0, 1.0, T),
+                                    (0.0, 1, 0.9, T)], 1),
+    "inactive-filter": ([(1.0, 0, 1.0, T), (0.7, 0, 1.0, T),
+                         (5.0, 1, 1.0, F), (0.0, 0, 1.0, T)], 1),
+    "one-greedy": ([(0.0, 0, 1.0, T)], 0),
+    "one-plain": ([(1.0, 0, 1.0, T)], 1),
+    "one-filtered": ([(0.8, 40, 0.95, T)], 2),
+    "top-k-1": ([(0.7, 1, 1.0, T), (1.0, 1, 0.9, T), (3.0, 1, 1.0, T)], 2),
+    "straddle": ([(1.0, 0, 0.9, T), (0.7, 0, 0.5, T), (1.0, 0, 0.95, T),
+                  (1.3, 0, 0.6, T)], 2),
+}
+
+
+@pytest.fixture(scope="module")
+def sampler(params):
+    return LLMEngine(CFG, params, slots=2, max_seq=32)
+
+
+@pytest.mark.parametrize("case", sorted(SAMPLER_CASES))
+def test_sampler_draws_what_three_sorts_drew(sampler, case):
+    """Tokens of the active rows, every row's next key and the set a
+    filtering row draws from (tie by tie) are the old formula's; the
+    host's rule names the work, and where the work shows it was done."""
+    jnp = jax.numpy
+    rows, work = SAMPLER_CASES[case]
+    temps, top_ks, top_ps, active = (np.asarray(c) for c in zip(*rows))
+    seed = sorted(SAMPLER_CASES).index(case)
+    logits = _tied_logits(seed, len(rows), coarse=case == "straddle")
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(len(rows)) + 100 * seed)
+    args = (logits, keys, jnp.asarray(active),
+            jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
+            jnp.asarray(top_ps, jnp.float32))
+    want, want_keys, want_kept, scaled = _three_sorts(*args)
+    got, got_keys, _ = sampler._sample_jit(*args)
+    assert engine_mod.sampler_work(r[:3] for r in rows if r[3]) == work
+    np.testing.assert_array_equal(np.asarray(got)[active],
+                                  np.asarray(want)[active])
+    np.testing.assert_array_equal(np.asarray(got_keys),
+                                  np.asarray(want_keys))
+    # the kept set, on the rows that filter (a row with top_p == 1 and
+    # no top-k is not filtered at all: the next test)
+    filters = (top_ks > 0) | (top_ps < 1.0)
+    kept = np.asarray(sampler._kept(scaled, args[4], args[5]))
+    np.testing.assert_array_equal(kept[filters],
+                                  np.asarray(want_kept)[filters])
+    assert kept[~filters].all()
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    if case == "all-greedy":
+        # the inactive rows sample and filter, and decide nothing: no
+        # draw was made for them
+        assert (np.asarray(got) == greedy).all()
+    if case == "inactive-filter":
+        # a sort would have left the inactive row its arg-max alone
+        # (top_k 1): the draw was plain
+        assert np.asarray(want)[2] == greedy[2] != np.asarray(got)[2]
+    if case == "top-k-1":
+        assert (np.asarray(got) == greedy).all()
+    if case == "straddle":
+        # equal logits on both sides of the cutoff, in every row: the
+        # first by index are in, a bare ``scaled >= v_cut`` keeps all
+        lowest = np.where(kept, np.asarray(scaled), np.inf).min(
+            axis=-1, keepdims=True)
+        tied_out = (np.asarray(scaled) == lowest) & ~kept
+        assert tied_out.any(axis=-1).all()
+        for row, out in zip(np.asarray(scaled) == lowest, tied_out):
+            assert np.flatnonzero(out).min() > np.flatnonzero(
+                row & ~out).max()
+
+
+def test_top_p_one_is_no_filter(sampler):
+    """The one stream the old formula gave another answer to: its
+    float32 running sum reaches 1.0 at the first column here (the tail's
+    whole mass, 2,047 × e^-40, is far under float32's resolution), so
+    ``cum < 1.0`` counted no column and top_p == 1 cut the tail off.
+    ``SamplingParams`` documents 1 as disabled: nothing is cut now, alone
+    (a plain draw) or beside a row that filters (the sort's mask)."""
+    jnp = jax.numpy
+    logits = jnp.full((2, VOCAB), -40.0).at[:, 3].set(0.0)
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(2))
+    temps = jnp.ones((2,), jnp.float32)
+    top_ks = jnp.zeros((2,), jnp.int32)
+    top_ps = jnp.asarray([1.0, 0.9], jnp.float32)
+    *_, old_kept, scaled = _three_sorts(
+        logits, keys, jnp.ones((2,), bool), temps, top_ks, top_ps)
+    assert np.asarray(old_kept).sum(axis=-1).tolist() == [1, 1]
+    kept = np.asarray(sampler._kept(scaled, top_ks, top_ps))
+    assert kept.sum(axis=-1).tolist() == [VOCAB, 1]
+    assert engine_mod.sampler_work([(1.0, 0, 1.0)]) == 1
+
+
+@pytest.mark.parametrize("asked, plain, sorted_steps", [
+    (dict(), False, False),
+    (dict(temperature=1.0, seed=3), True, False),
+    (dict(temperature=0.7, top_p=0.9, seed=3), False, True),
+    (dict(temperature=0.7, top_k=40, seed=3), False, True),
+], ids=["greedy", "plain", "top-p", "top-k"])
+def test_sampler_counters_count_what_the_rows_ask(params, asked, plain,
+                                                  sorted_steps):
+    eng = _pinned_engine(params, 8)
+    eng.generate([[10, 20, 30], [7, 8, 9, 10]],
+                 SamplingParams(max_tokens=6, **asked))
+    steps = eng.stats["decode_steps"]
+    assert steps >= 5
+    assert eng.stats["sample_plain_steps"] == steps * plain
+    assert eng.stats["sample_sorted_steps"] == steps * sorted_steps
+
+
+def test_sampler_counters_follow_the_rows_of_each_step(params):
+    """A greedy stream of 12 beside a top-p stream of 4 and a plain one
+    of 8, admitted a step apart: the sort is counted while the top-p
+    row decodes (3 steps), the plain draw on the plain row's 7 steps
+    less the 2 it shares with the sort, neither once both have left."""
+    eng = _pinned_engine(params, 8)
+    _run_all(eng, [
+        ([10, 20, 30], SamplingParams(max_tokens=12)),
+        ([7, 8, 9], SamplingParams(max_tokens=4, temperature=0.7,
+                                   top_p=0.9, seed=1)),
+        ([4, 5, 6], SamplingParams(max_tokens=8, temperature=1.0, seed=2))])
+    assert eng.stats["decode_steps"] == 11
+    assert eng.stats["sample_sorted_steps"] == 3
+    assert eng.stats["sample_plain_steps"] == 5
 
 
 # Six requests through three slots as the UNPIPELINED engine answered

@@ -152,6 +152,29 @@ def test_engine_prefill_chunk_compiles_for_v5e(v5e):
         *_compile_step(v5e.devices[0], "prefill_chunk", CFG, 8, 2048))
 
 
+@pytest.mark.parametrize("slots,vocab", [
+    (12, 92544), (48, 20480), (1, 92544)], ids=str)
+def test_sampler_is_one_program_with_one_sort(v5e, slots, vocab):
+    """The engine's sampler at the serving cells' shapes (and a
+    prompt's end, batch 1): ONE conditional over the work the active
+    rows ask for, and in it ONE sort of the vocabulary — values alone,
+    no index rides along."""
+    from ant_ray_tpu.llm import LLMEngine
+
+    tiny = llama.CONFIGS["tiny"]
+    engine = LLMEngine(tiny, llama.init_params(tiny, jax.random.PRNGKey(0)),
+                       slots=2, max_seq=32)
+    rows = functools.partial(jax.ShapeDtypeStruct, (slots,))
+    text = engine._sample_jit.lower(*_on(v5e.devices[0], (
+        jax.ShapeDtypeStruct((slots, vocab), jnp.float32),
+        jax.ShapeDtypeStruct((slots, 2), jnp.uint32), rows(jnp.bool_),
+        rows(jnp.float32), rows(jnp.int32), rows(jnp.float32))),
+        None, _on(v5e.devices[0], rows(jnp.int32))).compile().as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    (sort,) = re.findall(r"= (\S+) sort\(", text)
+    assert sort.startswith("f32[")      # one operand: no pair sort
+
+
 # OLMoE-1B-7B's block at its published widths (two layers of it): 64
 # experts of 2048 x 1024, 8 a token, QK-norm.
 ROUTED = dataclasses.replace(
