@@ -50,6 +50,7 @@ vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
 from __future__ import annotations
 
 import itertools
+import logging
 import os
 import threading
 import time
@@ -60,6 +61,8 @@ import numpy as np
 
 from ant_ray_tpu.llm.sampling import SamplingParams
 from ant_ray_tpu.llm.tokenizer import get_tokenizer
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -98,6 +101,10 @@ class _Seq:
     first_chunk: tuple | None = None   # first prefill dispatch
     first_token: tuple | None = None   # first token handed to on_event
     chunks: int = 0               # prefill dispatches (chunks, or 1)
+    # Under a sampled trace_ctx only: (perf_counter, the engine's
+    # prefill dispatches so far) a token handed over, the first token's
+    # too -> the span's emit_ms and chunk_gaps.
+    emits: list | None = None
 
 
 @dataclass(eq=False)
@@ -140,6 +147,10 @@ def sampler_work(asked) -> int:
 
 PHASES = ("drain", "admit", "chunk", "decode", "sample", "fetch", "emit",
           "housekeeping", "idle_wait")
+# An iteration that landed a decode step and took longer than this kept
+# its rows standing still: four times the longest step any benchmark
+# cell runs (62.6 ms).
+STALL_S = 0.25
 
 
 class _PhaseRecorder:
@@ -184,6 +195,20 @@ class _PhaseRecorder:
     steps whose sampler drew without a filter and with a sort
     (``sampler_work`` of the step's rows: no read); the rest took the
     arg-max alone.
+
+    An iteration that landed a decode step — rows were waiting for its
+    tokens — and took longer than ``STALL_S`` leaves a record of its
+    own, whatever is sampled: one warning line in the worker's log and
+    a forced ``llm:stall`` span under a trace id minted for it, with
+    the ``engine`` event's ``step``, the ``phase`` whose one stretch
+    was the longest and its ``phase_s``, the iteration's ``blocked_s``
+    (near the whole: the device or the runtime stood still, or another
+    thread kept the GIL from the read's return; near zero: the host
+    did) and the ``rows`` it kept waiting.  With no row decoding the
+    host dispatches a lone prompt's chunks ahead of the device and the
+    read at its end waits them all out: long, and nothing stands still.
+    A steady iteration pays one comparison for it, and one a phase for
+    the longest stretch.
     """
 
     def __init__(self, jax, stats: dict):
@@ -207,25 +232,64 @@ class _PhaseRecorder:
         self._phase = self._span = self._step_span = None
         self._t = 0.0
         self.dispatched = False
+        self.landed = 0       # rows of the decode step(s) it read
+        # For a stall's record: the iteration's start (perf_counter,
+        # block_s) and its longest stretch (phase, seconds).
+        self._began = (0.0, 0.0)
+        self._longest = (None, 0.0)
 
     def begin(self) -> bool:
         """Open an iteration; False when one is open already (the
         EngineLoop opened it around ``LLMEngine.step``)."""
         if self._step_span is not None:
             return False
-        self._close_phase(time.perf_counter())   # an open idle_wait
+        now = time.perf_counter()
+        self._close_phase(now)                   # an open idle_wait
         self.dispatched = False
+        self.landed = 0
+        self._began = (now, self._stats["block_s"])
+        self._longest = (None, 0.0)
         self._step_span = self._step_annotation(
             "engine", step_num=self._stats["steps"])
         self._step_span.__enter__()
         return True
 
     def end(self) -> None:
-        self._close_phase(time.perf_counter())
+        now = time.perf_counter()
+        self._close_phase(now)
         self._step_span.__exit__(None, None, None)
         self._step_span = None
+        if self.landed and now - self._began[0] > STALL_S:
+            self._record_stall(now)
         if self.dispatched:
             self._stats["steps"] += 1
+
+    def _record_stall(self, now: float) -> None:
+        began, blocked = self._began
+        stats, (phase, phase_s) = self._stats, self._longest
+        dur = now - began
+        attrs = {"step": stats["steps"], "phase": phase,
+                 "phase_s": round(phase_s, 4),
+                 "blocked_s": round(stats["block_s"] - blocked, 4),
+                 "rows": self.landed}
+        logger.warning(
+            "llm engine step %d stood still: %.3f s, %.3f of them in "
+            "%s, %.3f blocked on the device, %d decode rows waiting",
+            attrs["step"], dur, phase_s, phase, attrs["blocked_s"],
+            attrs["rows"])
+        try:
+            from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
+
+            # forced, as a shed is kept: no sampled request needed, and
+            # a trace id of its own
+            # artlint: disable=banned-apis — span `ts` is a cross-
+            # process wall-clock wire field, the iteration's start
+            ts = time.time() - dur
+            tracing_plane.record_span(
+                tracing_plane.mint(sampled=False), "llm:stall",
+                ts=ts, dur_s=dur, attrs=attrs, error=True)
+        except Exception:  # noqa: BLE001 — tracing is best-effort
+            pass
 
     def enter(self, name: str) -> None:
         now = time.perf_counter()
@@ -243,7 +307,10 @@ class _PhaseRecorder:
 
     def _close_phase(self, now: float) -> None:
         if self._phase is not None:
-            self._stats[self._keys[self._phase][1]] += now - self._t
+            held = now - self._t
+            self._stats[self._keys[self._phase][1]] += held
+            if held > self._longest[1]:
+                self._longest = (self._phase, held)
             self._span.__exit__(None, None, None)
             self._phase = None
 
@@ -414,6 +481,7 @@ class LLMEngine:
         self._kv_store = kv_offload_store
         self._restoring: dict[str, dict] = {}     # sid -> ticket
         self._chunk_rate: float | None = None     # tokens/s EWMA
+        self._prefills = 0    # prefill programs dispatched: chunks, prompts
         self._last_chunk_t: float | None = None
         # Flat on purpose: readers on other threads take dict(stats),
         # a shallow copy under which a nested dict would alias.
@@ -581,6 +649,8 @@ class LLMEngine:
         seq = _Seq(rid, token_ids, sampling)
         seq.on_event = on_event
         seq.trace_ctx = trace_ctx
+        if getattr(trace_ctx, "sampled", False):
+            seq.emits = []
         seq.submitted = submitted or (time.time(), time.perf_counter(),
                                       self.stats["steps"])
         if session_id is not None:
@@ -909,7 +979,7 @@ class LLMEngine:
         token = self._sample_one(seq, logits)
         tok = int(self._rec.to_host(token)[0])
         self._rec.enter("emit")
-        self._after_token(seq, tok)
+        self._after_token(seq, tok, time.perf_counter())
         if seq.slot >= 0:
             seq.last_tok = tok
             self._join_decode(seq, token)
@@ -1000,9 +1070,11 @@ class LLMEngine:
         turn writes its carry, or behind the length of a freed slot."""
         sampled, rows = flight
         rec = self._rec
+        rec.landed += len(rows)
         rec.enter("fetch")
         toks = rec.to_host(sampled)
         rec.enter("emit")
+        now = time.perf_counter()     # a step's tokens leave together
         if self.config.num_experts:
             self._note_routing(toks[self.slots:])
         for slot, seq in rows:
@@ -1012,7 +1084,7 @@ class LLMEngine:
             seq.kv_len = min(seq.kv_len + 1, self.max_seq)
             seq.last_tok = tok = int(toks[slot])
             self.stats["tokens_generated"] += 1
-            self._after_token(seq, tok)
+            self._after_token(seq, tok, now)
 
     def _land_flight(self):
         """Land the step in flight now, out of turn: before anything
@@ -1053,6 +1125,7 @@ class LLMEngine:
         counts as a step, and the first one ends the request's queue
         stage."""
         self._rec.dispatched = True
+        self._prefills += 1
         seq.chunks += 1
         if seq.first_chunk is None:
             seq.first_chunk = (time.perf_counter(), self.stats["steps"])
@@ -1257,8 +1330,13 @@ class LLMEngine:
         the engine: ``queue`` (submit → its first prefill program
         dispatched), ``prefill`` (→ its first token handed to
         ``on_event``), ``decode`` (→ now); the step numbers join it to
-        the ``engine`` steps of a device trace.  An unsampled context
-        records nothing unless ``error``."""
+        the ``engine`` steps of a device trace.  Of a sampled context
+        also every token's hand-over: ``emit_ms``, one entry a token
+        handed to its caller (a stop token is not), milliseconds after
+        the first one's, which is the end of ``prefill``; and
+        ``chunk_gaps``, the indexes ``i`` of those whose gap from token
+        ``i - 1`` saw a prefill program of ANY request dispatched.  An
+        unsampled context records nothing unless ``error``."""
         ctx = seq.trace_ctx
         if ctx is None:
             return
@@ -1266,6 +1344,14 @@ class LLMEngine:
         wall, t_submit, submit_step = seq.submitted
         t_chunk, chunk_step = seq.first_chunk or (now, step)
         t_token, token_step = seq.first_token or (now, step)
+        attrs = {}
+        if seq.emits is not None:
+            emits = seq.emits
+            attrs["emit_ms"] = [round(1000.0 * (t - t_token), 2)
+                                for t, _ in emits]
+            attrs["chunk_gaps"] = [
+                i for i in range(1, len(emits))
+                if emits[i][1] != emits[i - 1][1]]
         try:
             from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
 
@@ -1280,17 +1366,20 @@ class LLMEngine:
                        "slot": seq.slot, "submit_step": submit_step,
                        "first_chunk_step": chunk_step,
                        "first_token_step": token_step,
-                       "last_step": step},
+                       "last_step": step, **attrs},
                 error=error)
         except Exception:  # noqa: BLE001 — tracing is best-effort
             pass
 
     # ----------------------------------------------------------- private
 
-    def _after_token(self, seq: _Seq, tok: int):
+    def _after_token(self, seq: _Seq, tok: int, now: float):
+        """``tok`` of ``seq`` has been read: to its caller, and the end
+        of the sequence where it is one.  ``now``: the landed step's
+        one clock read, where its tokens are handed over."""
         seq.generated.append(tok)
         if seq.first_token is None:
-            seq.first_token = (time.perf_counter(), self.stats["steps"])
+            seq.first_token = (now, self.stats["steps"])
         s = seq.sampling
         eos = getattr(self.tokenizer, "eos_id",
                       getattr(self.tokenizer, "eos_token_id", None))
@@ -1304,8 +1393,11 @@ class LLMEngine:
             reason = "length"
         elif seq.kv_len + 1 >= self.max_seq:
             reason = "length"
-        if seq.on_event is not None and reason != "stop":
-            seq.on_event({"type": "token", "token_id": tok})
+        if reason != "stop":
+            if seq.emits is not None:
+                seq.emits.append((now, self._prefills))
+            if seq.on_event is not None:
+                seq.on_event({"type": "token", "token_id": tok})
         if reason is not None:
             self._release(seq, reason)
 
@@ -1470,18 +1562,14 @@ class _LoopHandle:
 
         self.request_id = request_id
         self.events = _q.Queue()
-        self.submit_ts = time.monotonic()
         # where the llm:engine span's queue stage starts
         self.submitted = (time.time(), time.perf_counter(), step)
-        self.first_token_ts: float | None = None
         self._final: RequestOutput | None = None
         self._error: BaseException | None = None
         self._done = threading.Event()
 
     # engine-loop side ------------------------------------------------
     def _on_event(self, ev: dict):
-        if ev["type"] == "token" and self.first_token_ts is None:
-            self.first_token_ts = time.monotonic()
         if ev["type"] == "final":
             self._final = ev["output"]
         elif ev["type"] == "error":
@@ -1502,11 +1590,6 @@ class _LoopHandle:
         if self._error is not None:
             raise self._error
         return self._final
-
-    def ttft_s(self) -> float | None:
-        if self.first_token_ts is None:
-            return None
-        return self.first_token_ts - self.submit_ts
 
     def __iter__(self):
         """Yield events until (and including) the final/error event."""
@@ -1702,10 +1785,7 @@ class EngineLoop:
                     try:
                         eng.step()
                     except Exception:  # noqa: BLE001 — keep the loop alive
-                        import logging  # noqa: PLC0415
-
-                        logging.getLogger(__name__).exception(
-                            "llm engine step failed")
+                        logger.exception("llm engine step failed")
                         time.sleep(0.05)
                 rec.enter("housekeeping")
                 self._housekeep(eng)

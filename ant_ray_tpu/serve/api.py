@@ -2072,19 +2072,32 @@ class ServeController:
 
 def _stream_ingress_span(name: str, service: str, attrs: dict):
     """Tracing ingress of a STREAMED request: mints the trace root and
-    returns it with ``finish(error, first_chunk, chunks, **more)``,
-    which records the request's one ingress span, from receipt to the
-    end of the stream (``first_chunk``: ``perf_counter`` at the first
-    frame written).  The proxy calls it however the stream ends, so
-    every hop of a streamed request shares one trace id, from the proxy
-    down to ``llm:engine``."""
+    returns it with ``finish(error, first_chunk, chunks, frames,
+    **more)``, which records the request's one ingress span, from
+    receipt to the end of the stream (``first_chunk``: ``perf_counter``
+    at the first frame written).  The proxy calls it however the stream
+    ends, so every hop of a streamed request shares one trace id, from
+    the proxy down to ``llm:engine``.
+
+    ``frames``, which the HTTP proxy keeps for a sampled root only:
+    one ``(perf_counter when written, seconds its pull waited for a
+    pool thread)`` a frame written.  They become ``frame_ms``,
+    milliseconds after the span's ``ts`` (the first is
+    ``first_chunk_s``), and ``pull_wait_ms``.  The gRPC proxy keeps
+    none: the call's own thread pulls its stream."""
     ctx = tracing_plane.mint()
     t_wall = time.time()
     t0 = time.perf_counter()
 
     def finish(error: bool, first_chunk: float | None = None,
-               chunks: int = 0, **more):
+               chunks: int = 0, frames: list | None = None, **more):
         span_attrs = {**attrs, "stream": True, "chunks": chunks, **more}
+        if frames:
+            first_chunk = frames[0][0]
+            span_attrs["frame_ms"] = [round(1000.0 * (t - t0), 2)
+                                      for t, _ in frames]
+            span_attrs["pull_wait_ms"] = [round(1000.0 * w, 2)
+                                          for _, w in frames]
         if first_chunk is not None:
             span_attrs["first_chunk_s"] = first_chunk - t0
         tracing_plane.record_span(
@@ -2229,6 +2242,11 @@ class HttpProxy:
                 return None
             return art.get(ref)
 
+        def timed_next_chunk(gen):
+            """``next_chunk`` of a sampled stream, behind the time the
+            pull reached its pool thread."""
+            return time.perf_counter(), next_chunk(gen)
+
         async def handler(request: "web.Request"):
             import json as _json  # noqa: PLC0415
 
@@ -2259,9 +2277,25 @@ class HttpProxy:
                     f"http:{request.path}", "http-proxy",
                     {"path": request.path})
 
+                # sampled: (written, its pull's wait for a pool thread)
+                # a data: frame
+                frames = [] if ctx.sampled else None
+
                 def finish(status, first_chunk=None, chunks=0):
-                    span_done(status >= 400, first_chunk, chunks,
+                    span_done(status >= 400, first_chunk, chunks, frames,
                               status=status)
+
+                async def pull():
+                    """The stream's next chunk off the default pool,
+                    and of a sampled stream how long the pull waited
+                    for one of its threads."""
+                    if frames is None:
+                        return await loop_.run_in_executor(
+                            None, next_chunk, gen), None
+                    asked = time.perf_counter()
+                    began, chunk = await loop_.run_in_executor(
+                        None, timed_next_chunk, gen)
+                    return chunk, began - asked
 
                 def failed(e):
                     # NB: explicit None check — an unprepared
@@ -2293,8 +2327,7 @@ class HttpProxy:
                 # documented typed status — not a 200 that dies
                 # mid-stream with no Retry-After.
                 try:
-                    chunk = await loop_.run_in_executor(
-                        None, next_chunk, gen)
+                    chunk, waited = await pull()
                 except Exception as e:  # noqa: BLE001 — classified below
                     _record_result(sh._routing, replica, e)
                     return failed(e)
@@ -2307,11 +2340,12 @@ class HttpProxy:
                     await resp.write(
                         b"data: " + _json.dumps(chunk).encode() + b"\n\n")
                     chunks += 1
-                    if first_chunk_s is None:
+                    if frames is not None:
+                        frames.append((time.perf_counter(), waited))
+                    elif first_chunk_s is None:
                         first_chunk_s = time.perf_counter()
                     try:
-                        chunk = await loop_.run_in_executor(
-                            None, next_chunk, gen)
+                        chunk, waited = await pull()
                     except Exception as e:  # noqa: BLE001 — mid-stream
                         # Headers already went out: feed the breaker
                         # and end the stream (the client sees the

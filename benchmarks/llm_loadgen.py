@@ -102,6 +102,7 @@ class LoadGen:
                 prompt = self._prompt(spec, idx, turn)
                 sampling = SamplingParams(temperature=0.0,
                                           max_tokens=spec.max_tokens)
+                sent = time.monotonic()
                 try:
                     handle = self._loop.submit(prompt, sampling,
                                                session_id=sid)
@@ -113,13 +114,16 @@ class LoadGen:
                 with lock:
                     report.started += 1
                 try:
+                    # the caller's clock, to the stream's first event
+                    first = handle.events.get(timeout=wait_timeout_s)
+                    ttft = (time.monotonic() - sent
+                            if first["type"] == "token" else None)
                     out = handle.wait(timeout=wait_timeout_s)
                 except BaseException as exc:  # noqa: BLE001 — tallied
                     with lock:
                         report.failed += 1
                         report.errors.append(repr(exc))
                     continue
-                ttft = handle.ttft_s()
                 with lock:
                     report.finished += 1
                     report.tokens += len(out.token_ids)

@@ -8,18 +8,21 @@ The counter readings asserted here are the DOCUMENTED ones: a PR that
 changes how often the engine synchronises with the device edits them
 knowingly."""
 
+import functools
 import json
+import logging
 import os
 import time
 import urllib.request
 
+import numpy as np
 import pytest
 
 import jax
 
 import ant_ray_tpu as art
 from ant_ray_tpu.llm import LLMEngine, SamplingParams
-from ant_ray_tpu.llm.engine import PHASES, EngineLoop
+from ant_ray_tpu.llm.engine import PHASES, STALL_S, EngineLoop
 from ant_ray_tpu.llm.kv_offload import LocalKvStore
 from ant_ray_tpu.models import llama
 from ant_ray_tpu.observability import tracing_plane
@@ -494,6 +497,159 @@ def test_unsampled_context_records_nothing(params):
     assert _spans(ctx.trace_id) == []
 
 
+def _run_out(eng):
+    while eng.has_unfinished():
+        eng.step()
+
+
+@pytest.mark.parametrize("ended_by", ["length", "stop"])
+def test_emit_ms_is_one_entry_a_token_handed_over(params, ended_by):
+    """A stop token is read but not handed over: one entry fewer."""
+    eng = _engine(params)
+    alone = eng.generate([list(PROMPTS[0])], SamplingParams(max_tokens=9))
+    sampling = SamplingParams(
+        max_tokens=9, stop_token_ids=(alone[0].token_ids[5],)
+        if ended_by == "stop" else ())
+    ctx, events = tracing_plane.mint(sampled=True), []
+    eng.add_request(list(PROMPTS[0]), sampling, admit=False, trace_ctx=ctx,
+                    on_event=events.append)
+    _run_out(eng)
+    assert events[-1]["output"].finish_reason == ended_by
+    (span,) = _spans(ctx.trace_id, "llm:engine")
+    emit_ms, attrs = span["attrs"]["emit_ms"], span["attrs"]
+    handed = sum(ev["type"] == "token" for ev in events)
+    assert len(emit_ms) == handed == \
+        attrs["output_tokens"] - (ended_by == "stop")
+    assert 1 < handed <= 9
+    assert emit_ms[0] == 0 and emit_ms == sorted(emit_ms)
+    # the last hand-over lies inside the decode stage, at 0.01 ms
+    assert emit_ms[-1] <= 1000 * span["stages"]["decode"] + 0.01
+    assert attrs["chunk_gaps"] == []          # no other request
+
+
+@pytest.mark.parametrize("chunk_tokens,dispatches", [(8, 2), (None, 1)],
+                         ids=["chunked", "bucketed"])
+def test_chunk_gaps_name_the_gaps_that_saw_a_prefill_dispatched(
+        params, chunk_tokens, dispatches):
+    """Two requests, the second submitted in mid-decode: its prefill
+    programs (two chunks, or the whole prompt as one) are dispatched
+    across exactly as many of the first one's gaps."""
+    eng = _engine(params, prefill_chunk_tokens=chunk_tokens)
+    first, second = (tracing_plane.mint(sampled=True) for _ in range(2))
+    chunks_at = []        # stats["chunks"] at each of the first's tokens
+
+    def on_event(ev):
+        if ev["type"] == "token":
+            chunks_at.append(eng.stats["chunks"])
+
+    eng.add_request(list(PROMPTS[1]), SamplingParams(max_tokens=12),
+                    admit=False, trace_ctx=first, on_event=on_event)
+    while len(chunks_at) < 4:
+        eng.step()
+    had = len(chunks_at)
+    eng.add_request(list(PROMPTS[0]), SamplingParams(max_tokens=3),
+                    admit=False, trace_ctx=second)
+    _run_out(eng)
+    (span,) = _spans(first.trace_id, "llm:engine")
+    gaps = span["attrs"]["chunk_gaps"]
+    assert len(span["attrs"]["emit_ms"]) == len(chunks_at) == 12
+    # the iteration that admits the second dispatches its first
+    # prefill program and then lands the first one's next token
+    assert gaps == list(range(had, had + dispatches))
+    if chunk_tokens:
+        assert gaps == [i for i in range(1, 12)
+                        if chunks_at[i] != chunks_at[i - 1]]
+    # nothing was prefilled after the second one's own first token
+    (span,) = _spans(second.trace_id, "llm:engine")
+    assert span["attrs"]["chunk_gaps"] == []
+    assert len(span["attrs"]["emit_ms"]) == 3
+
+
+@pytest.mark.parametrize("ctx", [None, tracing_plane.mint(sampled=False),
+                                 tracing_plane.mint(sampled=True)],
+                         ids=["no-context", "unsampled", "sampled"])
+def test_only_a_sampled_request_keeps_its_hand_over_times(params, ctx):
+    """Unsampled, a token costs the one ``is None`` test: no list."""
+    eng = _engine(params)
+    eng.add_request(list(PROMPTS[1]), SamplingParams(max_tokens=3),
+                    admit=False, trace_ctx=ctx)
+    seq = eng._waiting[-1]
+    _run_out(eng)
+    if ctx is not None and ctx.sampled:
+        assert len(seq.emits) == 3
+    else:
+        assert seq.emits is None
+        assert ctx is None or _spans(ctx.trace_id) == []
+
+
+def _stalls():
+    return [s for s in tracing_plane.recorder().snapshot()
+            if s["name"] == "llm:stall"]
+
+
+@pytest.mark.parametrize("where", ["fetch", "emit", "chunk"])
+def test_a_step_that_keeps_rows_standing_still_leaves_one_forced_span(
+        params, where, monkeypatch, caplog):
+    """Blocked on the device (a read that sleeps) the iteration's
+    ``blocked_s`` is its whole; asleep on the host (in a caller's
+    ``on_event``) it is nothing.  A lone prompt's end (the read in
+    ``chunk``, no row decoding yet) keeps no row waiting however long
+    it takes, like the steps that compile: no record."""
+    eng = _engine(params)
+    eng.generate([list(PROMPTS[1])], SamplingParams(max_tokens=3))  # compile
+    before, sleep_s, slept = _stalls(), STALL_S + 0.1, []
+
+    def sleep_once():
+        if not slept:
+            slept.append(eng.stats["steps"])
+            time.sleep(sleep_s)
+
+    to_host = eng._rec.to_host
+
+    class Slow:
+        def __init__(self, value):
+            self.value = value
+
+        def __array__(self, *args, **kw):
+            if eng._rec._phase == where:
+                sleep_once()
+            return np.asarray(self.value)
+
+    monkeypatch.setattr(eng._rec, "to_host",
+                        lambda value: to_host(Slow(value)))
+
+    def on_event(ev):
+        if where == "emit" and ev["type"] == "token" and len(seen) == 2:
+            sleep_once()
+        seen.append(ev)
+
+    seen = []
+    eng.add_request(list(PROMPTS[1]), SamplingParams(max_tokens=6),
+                    admit=False, on_event=on_event)   # no trace context
+    caplog.clear()
+    with caplog.at_level(logging.WARNING, logger="ant_ray_tpu.llm.engine"):
+        _run_out(eng)
+    assert slept
+    lines = [r.getMessage() for r in caplog.records
+             if "stood still" in r.getMessage()]
+    if where == "chunk":
+        assert _stalls() == before and not lines
+        return
+    (stall,) = [s for s in _stalls() if s not in before]
+    attrs = stall["attrs"]
+    assert stall["forced"] is True and stall["error"] is True
+    assert attrs["phase"] == where and attrs["step"] == slept[0]
+    assert sleep_s <= attrs["phase_s"] <= stall["dur_s"] < sleep_s + 0.2
+    assert attrs["rows"] == 1          # the one row the read kept waiting
+    if where == "fetch":
+        assert attrs["blocked_s"] == pytest.approx(stall["dur_s"], abs=0.05)
+    else:
+        assert attrs["blocked_s"] < 0.05
+    assert stall["ts"] == pytest.approx(time.time(), abs=60)
+    (line,) = lines
+    assert f"step {attrs['step']} " in line and where in line
+
+
 def test_failed_request_records_a_forced_error_span(params):
     store = LocalKvStore()
     eng = _engine(params, slots=2, kv_offload_store=store)
@@ -579,6 +735,35 @@ def test_feed_wait_is_named_in_a_trace(tmp_path):
                for line in plane.lines for e in line.events)
 
 
+@pytest.mark.parametrize("sampled", [True, False],
+                         ids=["sampled", "unsampled"])
+def test_ingress_span_of_a_stream_carries_a_sampled_roots_frames(
+        monkeypatch, sampled):
+    """``frame_ms`` on the clock of ``first_chunk_s``, ``pull_wait_ms``
+    beside it; an unsampled root keeps no frames and records nothing."""
+    from ant_ray_tpu.serve import api
+
+    monkeypatch.setattr(tracing_plane, "mint", functools.partial(
+        tracing_plane.mint, sampled=sampled))
+    ctx, finish = api._stream_ingress_span("http:/x", "http-proxy",
+                                           {"path": "/x"})
+    at = time.perf_counter()
+    if not sampled:
+        finish(False, at + 0.010, 2, None, status=200)
+        assert _spans(ctx.trace_id) == []
+        return
+    finish(False, None, 2, [(at + 0.010, 0.0012), (at + 0.030, 0.25)],
+           status=200)
+    (span,) = _spans(ctx.trace_id)
+    attrs = span["attrs"]
+    assert attrs["chunks"] == len(attrs["frame_ms"]) == 2
+    assert attrs["frame_ms"][0] == pytest.approx(
+        1000 * attrs["first_chunk_s"], abs=0.006)
+    assert attrs["frame_ms"][1] - attrs["frame_ms"][0] == pytest.approx(
+        20.0, abs=0.011)
+    assert attrs["pull_wait_ms"] == [1.2, 250.0]
+
+
 def test_streamed_request_is_one_trace_from_proxy_to_engine(shutdown_only):
     """http: (with first_chunk_s) -> llm:admission -> llm:engine under
     one trace id, for a stream that SUCCEEDS."""
@@ -628,6 +813,17 @@ def test_streamed_request_is_one_trace_from_proxy_to_engine(shutdown_only):
         assert http["attrs"]["chunks"] == frames
         assert 0 < http["attrs"]["first_chunk_s"] <= http["dur_s"]
         assert engine["attrs"]["output_tokens"] == frames - 1
+        # every frame's write time and pool wait, every token's
+        # hand-over: frame k is token k's, the last the finish chunk
+        frame_ms = http["attrs"]["frame_ms"]
+        waits = http["attrs"]["pull_wait_ms"]
+        assert len(frame_ms) == len(waits) == http["attrs"]["chunks"]
+        assert len(frame_ms) == len(engine["attrs"]["emit_ms"]) + 1
+        assert frame_ms[0] == pytest.approx(
+            1000 * http["attrs"]["first_chunk_s"], abs=0.006)
+        assert frame_ms == sorted(frame_ms)
+        assert frame_ms[-1] <= 1000 * http["dur_s"] + 0.01
+        assert all(0 <= w < 5000 for w in waits)
         # the engine's part lies inside the proxy's
         assert http["ts"] <= engine["ts"]
         assert engine["ts"] + engine["dur_s"] <= http["ts"] + http["dur_s"] \

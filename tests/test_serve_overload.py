@@ -683,10 +683,8 @@ def test_llm_server_max_waiting_bounds_loop_queue():
     # Pin the slot: a long generation submitted straight to the loop.
     pin = srv._loop.submit([1, 2, 3], SamplingParams(temperature=0.0,
                                                      max_tokens=40))
-    deadline = time.monotonic() + 60
-    while pin.first_token_ts is None and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert pin.first_token_ts is not None, "pin request never started"
+    assert pin.events.get(timeout=60)["type"] == "token", \
+        "pin request never started"
     with pytest.raises(BackPressureError) as err:
         srv({"prompt": "hi", "max_tokens": 1})
     assert err.value.retry_after_s > 0
