@@ -509,11 +509,12 @@ class LLMEngine:
 
         def _prefill_chunk(params, cache, tokens, slot, start, length):
             return llama.prefill_chunk_into_cache(
-                params, tokens, cache, slot, start, length, cfg)
+                params, tokens, cache, slot, start, length, cfg,
+                mesh=eng_mesh)
 
         def _decode(params, cache, last_tokens, active):
             return llama.decode_step(params, last_tokens, cache, cfg,
-                                     active=active)
+                                     active=active, mesh=eng_mesh)
 
         # k, v — or c_kv, k_rope; a window model's rings beside them
         slab_names = tuple(llama.kv_slabs(cfg))

@@ -25,6 +25,7 @@ from jax import lax
 
 from ant_ray_tpu.ops.attention import attention, kernel_fits
 from ant_ray_tpu.ops.layernorm import layernorm
+from ant_ray_tpu.ops.pallas import grouped_matmul
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
 from ant_ray_tpu.ops.rope import (
     YarnScaling,
@@ -389,7 +390,8 @@ def param_shardings(config: LlamaConfig, mesh) -> dict:
 # ---------------------------------------------------------------- forward
 
 def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
-                attend, constrain_act, index=None, windowed: bool = False):
+                attend, constrain_act, index=None, windowed: bool = False,
+                tile: int = 0):
     """One transformer block on ``x`` (..., dim), the only place its
     equations are written: training hands it (batch, seq, dim), a
     prefill chunk and a decode step their rows, (chunk, dim) and
@@ -403,8 +405,9 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     places, and the layer's up-projection as a fourth argument
     (``_latent_qkv``).  ``positions``: int32 of ``x``'s leading shape,
     None for arange over the sequence.  ``index``: the layer's number
-    where ``layer`` holds the whole stack's expert matrices
-    (``_routed_mlp``).  ``windowed``: the layer is one of the model's
+    where ``layer`` holds the whole stack's expert matrices, ``tile``
+    the grouped kernel's row tile there (``_routed_mlp``).
+    ``windowed``: the layer is one of the model's
     window layers (``LlamaConfig.period``) — the caller's ``attend``
     masks accordingly; here it decides whether the heads are rotated (a
     full layer of a ``full_rope=False`` model rotates nothing).  With
@@ -432,14 +435,14 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     attn = attn.reshape(*lead, -1)               # heads * value width
     attn = (attn @ layer["wo"]).astype(x.dtype)
     if c.parallel_block:
-        out, load = _mlp(layer, h, c, index)
+        out, load = _mlp(layer, h, c, index, tile)
         x = x + attn + out.astype(x.dtype)
         return constrain_act(x, ("batch", "seq", "embed")), state, load
     x = x + attn
     x = constrain_act(x, ("batch", "seq", "embed"))
 
     h = _norm(x, layer["ln_mlp"], c)
-    out, load = _mlp(layer, h, c, index)
+    out, load = _mlp(layer, h, c, index, tile)
     x = x + out.astype(x.dtype)
     x = constrain_act(x, ("batch", "seq", "embed"))
     return x, state, load
@@ -529,7 +532,7 @@ def _swiglu(h, w_gate, w_up, w_down):
     return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
 
 
-def _mlp(layer: dict, h, c: LlamaConfig, index=None):
+def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
     """The block's feed-forward on ``h`` (..., dim): dense SwiGLU, or
     the routed experts, with the shared expert beside them where the
     model has one.  Returns ``(out, load)``; ``load`` is the
@@ -538,7 +541,7 @@ def _mlp(layer: dict, h, c: LlamaConfig, index=None):
     if not c.num_experts:
         return _swiglu(h, layer["w_gate"], layer["w_up"],
                        layer["w_down"]), None
-    out, load = _routed_mlp(layer, h, c, index)
+    out, load = _routed_mlp(layer, h, c, index, tile)
     if c.n_shared_experts:
         with jax.named_scope("moe_shared"):
             # one SwiGLU as wide as all the shared experts together is
@@ -551,7 +554,7 @@ def _mlp(layer: dict, h, c: LlamaConfig, index=None):
     return out, load
 
 
-def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
+def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
     """Top-k mixture of experts; every token is computed by those of its
     k experts that are held here, and none is dropped.
 
@@ -560,17 +563,30 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
     are the k largest (divided by their sum only when
     ``norm_topk_prob``), times ``routed_scaling_factor``.  The tokens *
     k (token, expert) assignments are sorted by expert, so each expert's
-    rows lie together, and ``lax.ragged_dot`` multiplies each run of
-    rows with its expert's matrix: a grouped product whose operations
-    are those of k experts a token, and which reads an expert's weights
-    only if it has a row.
-    On the TPU XLA compiles it to a grouped-matmul kernel; elsewhere to
-    masked dense products (the same values).  The rows then go back to
-    token order and are summed under their float32 gates.  Shapes are
-    static (tokens * k rows whatever the routing), so one formulation
-    serves the training step, a prefill chunk and a decode step, and
-    differentiates as written.  With experts sharded over ``ep`` the
-    partitioner splits the grouped product by expert.
+    rows lie together, and a GROUPED PRODUCT multiplies each run of rows
+    with its expert's matrix: its operations are those of k experts a
+    token, and it reads an expert's weights only if it has a row.  The
+    rows then go back to token order and are summed under their float32
+    gates.  Shapes are static (tokens * k rows whatever the routing), so
+    one formulation serves the training step, a prefill chunk and a
+    decode step.
+
+    Which grouped product runs where (bf16 operands, float32 sums and
+    float32 out in both; the same mathematics):
+
+    * ``lax.ragged_dot`` — ``forward`` / ``loss_fn`` (it differentiates
+      as written), every backend but the TPU (masked dense products, the
+      same values) and a stack sharded over a mesh (the partitioner
+      splits it by expert over ``ep``): ``_grouped_tile`` says which.
+      On the TPU XLA compiles it to a
+      grouped-matmul kernel whose row tile is the OPERAND's row count:
+      every expert hit pays for all tokens * k rows, its own or not.
+    * ``ops/pallas/grouped_matmul.py`` (``tile`` > 0: its row tile;
+      with ``index``) — the step programs on one TPU device.  Its work list visits (row
+      tile, expert) pairs that hold a row of a held expert, in tiles of
+      about the rows an expert really gets, so a chunk or a decode step
+      of a share stops multiplying tiles of masked rows; a row's sum is
+      its own whatever rows share its tile.
 
     Where the router is wider than the experts held (``router_width``
     against ``num_experts`` from ``first_expert`` on), the routing is
@@ -583,10 +599,11 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
     ``layer``'s expert matrices are one layer's (experts, in, out), as
     a scan over the stacked layers slices them — or, with ``index``,
     the whole stack's (layers, experts, in, out), of which layer
-    ``index`` (traced) is meant: the stack is then read as layers *
-    experts groups, all empty but that layer's.  The step programs do
-    so (``_scan_layers``), because a slice of the stack cannot be fused
-    into the grouped kernel's operand: sliced, every step would first
+    ``index`` (traced) is meant: XLA's kernel then reads the stack as
+    layers * experts groups, all empty but that layer's, the Pallas
+    kernel takes the layer by scalar prefetch.  The step programs do so
+    (``_scan_layers``), because a slice of the stack cannot be fused
+    into a grouped kernel's operand: sliced, every step would first
     copy every expert's weights, hit or not.
     """
     lead, dim = h.shape[:-1], h.shape[-1]
@@ -613,13 +630,20 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None):
         order = jnp.argsort(experts)                       # stable
         load = jnp.zeros((n_exp,), jnp.int32).at[experts].add(1)
         rows = x[order // k]                               # sorted by expert
-        sizes = load if index is None else lax.dynamic_update_slice(
-            jnp.zeros((layer["w_down"].shape[0] * n_exp,), jnp.int32),
-            load, (index * n_exp,))
+        if tile:
+            def grouped(a, w):
+                return grouped_matmul.grouped_matmul(
+                    a, w, load, index, tiling=(tile,) + grouped_matmul.panel(
+                        *w.shape[-2:], w.dtype.itemsize),
+                    interpret=jax.default_backend() != "tpu")
+        else:
+            sizes = load if index is None else lax.dynamic_update_slice(
+                jnp.zeros((layer["w_down"].shape[0] * n_exp,), jnp.int32),
+                load, (index * n_exp,))
 
-        def grouped(a, w):
-            return lax.ragged_dot(a, w.reshape(-1, *w.shape[-2:]), sizes,
-                                  preferred_element_type=jnp.float32)
+            def grouped(a, w):
+                return lax.ragged_dot(a, w.reshape(-1, *w.shape[-2:]), sizes,
+                                      preferred_element_type=jnp.float32)
 
         gated = jax.nn.silu(grouped(rows, layer["w_gate"])) * grouped(
             rows, layer["w_up"])
@@ -1002,7 +1026,26 @@ ROUTING_COUNTERS = (
     # told from counters that a window's share of chunks moves.
     "moe_decode_assignments", "moe_decode_experts_hit",
     "moe_decode_expert_slots", "moe_decode_rows_routed",
+    # rows of the row tiles the grouped kernel visited (its work list's
+    # length times its tile, a layer): what it multiplied, where
+    # ``moe_assignments`` is what it was asked; 0 under XLA's kernel
+    "moe_tile_rows",
 )
+
+
+def _grouped_tile(c: LlamaConfig, rows: int, mesh) -> int:
+    """The row tile with which a step program of ``rows`` rows runs its
+    routed experts through ``ops/pallas/grouped_matmul.py``; 0: it keeps
+    ``lax.ragged_dot`` — a dense model, any backend but the TPU, and a
+    ``mesh`` (a Mosaic kernel is not partitioned automatically, and the
+    partitioner splits XLA's product by expert).  The tile follows the
+    rows an expert gets under an even router: rows * k over the
+    router's width."""
+    if not c.num_experts or mesh is not None \
+            or jax.default_backend() != "tpu":
+        return 0
+    return grouped_matmul.row_tile(
+        rows * c.experts_per_token / (c.router_width or c.num_experts))
 
 
 def _hoist_experts(layers: dict, c: LlamaConfig):
@@ -1020,17 +1063,23 @@ def _hoist_experts(layers: dict, c: LlamaConfig):
     return sliced, whole, index
 
 
-def _count_routing(cache: dict, loads, routed, decode: bool) -> dict:
+def _count_routing(cache: dict, loads, routed, decode: bool,
+                   tile: int = 0) -> dict:
     """``loads``: (layers, num_experts) rows per expert held of one
     execution, None for a dense model; ``routed``: the (row, expert)
     pairs its routers made, held or not; ``decode``: the execution is a
-    decode step -> the cache entries to carry."""
+    decode step; ``tile``: the grouped kernel's row tile, 0 under XLA's
+    kernel -> the cache entries to carry."""
     if loads is None:
         return {}
     seen = jnp.stack([jnp.sum(loads), jnp.sum(loads > 0), loads.size,
                       jnp.sum(jnp.max(loads, axis=-1)), routed])
     apart = seen[jnp.array([0, 1, 2, 4])]
-    seen = jnp.concatenate([seen, apart if decode else apart * 0])
+    # the kernel's own rule, layer by layer
+    visited = tile * jnp.sum(jax.vmap(grouped_matmul.visits, (0, None))(
+        loads, tile)) if tile else 0
+    seen = jnp.concatenate([seen, apart if decode else apart * 0,
+                            jnp.stack([visited])])
     return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
 
 
@@ -1250,7 +1299,7 @@ def _slab_positions(cache: dict, c: LlamaConfig) -> int:
 
 
 def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
-                 write_attend, *, decode: bool):
+                 write_attend, *, decode: bool, mesh=None):
     """A step program's layers over rows ``x`` (rows, dim): a
     ``lax.scan`` over each stack's PERIODS of the layer pattern
     (``_stacks``, ``LlamaConfig.period``), whose body is the period's
@@ -1258,7 +1307,8 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     attend; a period of one where all layers are alike — and whose
     carry is the rows and the whole cache.  Returns (x, the cache's new
     entries: its slabs, its counters — a ``decode`` step's counted
-    apart as well, ``ROUTING_COUNTERS``).
+    apart as well, ``ROUTING_COUNTERS``).  ``mesh``: the one the
+    parameters are sharded over, if any (``_grouped_tile``).
 
     ``write_attend(ks, vs, i, window, xq, xk, xv[, w_kvb]) -> (out, (ks,
     vs))`` is the block's attention over the carried slabs of the
@@ -1278,6 +1328,7 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     # a chunk's last padded row's max_seq + chunk - 2
     cos, sin = _rope_tables(c, _slab_positions(cache, c) + x.shape[0])
     names = tuple(kv_slabs(c))
+    tile = _grouped_tile(c, x.shape[0], mesh)
 
     def scan_stack(carry, stack, cfg, first):
         """``first``: the stack's first layer's place in the cache."""
@@ -1299,7 +1350,7 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                     cos, sin, positions, functools.partial(
                         write_attend, slabs[k], slabs[v], i,
                         cfg.window if windowed else 0),
-                    _unconstrained, p * len(kinds) + j, windowed)
+                    _unconstrained, p * len(kinds) + j, windowed, tile)
                 slabs = {**slabs, k: ks, v: vs}
                 loads.append(load)
             return (x, slabs), (loads[0] if len(loads) == 1
@@ -1315,11 +1366,12 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     routed = None if loads is None else (
         loads.shape[0] * x.shape[0] * c.experts_per_token)
     return carry[0], {**carry[1],
-                      **_count_routing(cache, loads, routed, decode)}
+                      **_count_routing(cache, loads, routed, decode, tile)}
 
 
 def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
-                             start, chunk_len, config: LlamaConfig):
+                             start, chunk_len, config: LlamaConfig, *,
+                             mesh=None):
     """Ingest ONE fixed-size chunk of a prompt into ``slot``.
 
     tokens: (chunk,) int32 — ``chunk_len`` real tokens, zero-padded to
@@ -1339,7 +1391,8 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     logits are taken at the chunk's last REAL token.
 
     Returns (logits (vocab,) fp32, new cache with slot length set to
-    ``start + chunk_len``).
+    ``start + chunk_len``).  ``mesh``: the one the parameters are
+    sharded over, if any (``_grouped_tile``).
     """
     c = config
     chunk = tokens.shape[0]
@@ -1371,7 +1424,7 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
 
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
     x, written = _scan_layers(params, x, cache, c, pos, write_chunk,
-                              decode=False)
+                              decode=False, mesh=mesh)
     x = _norm(x, params["norm_f"], c)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
@@ -1382,7 +1435,7 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
 
 
 def decode_step(params: dict, last_tokens, cache: dict,
-                config: LlamaConfig, active):
+                config: LlamaConfig, active, *, mesh=None):
     """One token for every slot, attending against the cache.
 
     last_tokens: (slots,) int32 — the most recent token per slot.
@@ -1391,6 +1444,8 @@ def decode_step(params: dict, last_tokens, cache: dict,
     session's slab, which must stay bit-exact while the slot sits out
     decode steps.
     Returns (logits (slots, vocab) fp32, new cache with +1 lengths).
+    ``mesh``: the one the parameters are sharded over, if any
+    (``_grouped_tile``).
     """
     c = config
     max_seq = _slab_positions(cache, c)
@@ -1417,7 +1472,7 @@ def decode_step(params: dict, last_tokens, cache: dict,
 
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
     x, written = _scan_layers(params, x, cache, c, pos, write_one,
-                              decode=True)
+                              decode=True, mesh=mesh)
     x = _norm(x, params["norm_f"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)

@@ -1,0 +1,203 @@
+"""Pallas TPU grouped matrix product for a step program's routed experts:
+``(rows sorted by expert, m x k) x (the WHOLE expert stack, layers x
+experts x k x n) -> m x n float32``, the layer and the group sizes by
+scalar prefetch, so the stack is read where it lies and an expert's
+weights only if it has a row.
+
+The form is that of ``jax.experimental.pallas.ops.tpu.megablox.gmm``: a
+work list of (row tile, group) VISITS built from the group sizes, a
+dynamic grid over the visits only, and a store mask where a tile holds
+rows of more than one group.  A visit multiplies one ``tm``-row tile by
+one expert, so what a product costs follows the rows the experts really
+got, not the operand's row count: XLA's ``ragged-dot`` kernel takes its
+row tile from the operand (a 64-token chunk of 8 experts a token is ONE
+512-row tile per expert hit, whatever rows the expert has).  Rows behind
+the last group (a share's assignments to experts held elsewhere) are
+never visited and hold whatever was there: the caller masks them.
+
+Grid ``(n tiles, visits, k tiles)``, k innermost: a visit's float32
+accumulator sums its k tiles in their order, and a row's sum is its own
+— the same bits whatever rows share its tile or its batch.  Consecutive
+visits of one group (a group that straddles row tiles) name the same
+weight block, which is then not fetched again where k is one tile.
+
+``tiling`` is the rule for (tm, tk, tn), a function of shapes alone,
+read once from ``benchmarks/grouped_product``'s sweep on a v5e
+(``PERF.md`` section 6, PR 37): a visit costs its expert's bytes —
+680-740 GB/s over the experts hit from 32 to 128 rows a tile — so what
+the tile decides is how often a run of rows crosses a tile boundary and
+its expert is read a second time.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# The bytes of one weight panel (tk x tn) a visit step fetches: two of
+# them are in flight (the pipeline's double buffer).
+PANEL_BYTES = 4 << 20
+# bf16 rows come in tiles of 16 sublanes: what a row tile is a multiple of
+MIN_ROWS = 16
+# the rule's row tiles
+LEAST_TILE, MOST_TILE, TILE_OVER_MEAN = 32, 128, 8
+
+
+def row_tile(rows_an_expert: float) -> int:
+    """The row tile for experts that get ``rows_an_expert`` rows under
+    an even router: the power of two at or above ``TILE_OVER_MEAN``
+    times that, from 32 to 128.  A visit costs its expert's bytes, not
+    its rows (``tm`` operations a byte against the chip's 240), and a
+    run of rows that crosses a tile is a second visit: a tile of
+    several times the mean keeps crossings few where routing is uneven;
+    beyond 128 rows a visit's arithmetic no longer hides under its
+    expert's DMA."""
+    tm = LEAST_TILE
+    while tm < min(TILE_OVER_MEAN * rows_an_expert, MOST_TILE):
+        tm *= 2
+    return tm
+
+
+def panel(k: int, n: int, itemsize: int = 2,
+          budget: int = PANEL_BYTES) -> tuple[int, int]:
+    """(tk, tn): the widest panel of whole rows of an expert's (k, n)
+    matrix within ``budget`` bytes — ``tn`` = n, so a panel is one
+    contiguous stretch of the stack, and ``tk`` k halved while it is
+    too large and stays a multiple of 128; a matrix too wide for 128
+    whole rows is cut in columns too."""
+    tn = n
+    while 128 * tn * itemsize > budget and tn % 256 == 0:
+        tn //= 2
+    tk = k
+    while tk * tn * itemsize > budget and tk % 256 == 0:
+        tk //= 2
+    return tk, tn
+
+
+def tiling(rows_an_expert: float, k: int, n: int,
+           itemsize: int = 2) -> tuple[int, int, int]:
+    """(tm, tk, tn) for experts of (k, n) that get ``rows_an_expert``
+    rows under an even router."""
+    return (row_tile(rows_an_expert),) + panel(k, n, itemsize)
+
+
+def visits_by_group(sizes, tm: int):
+    """(groups,) int32: the row tiles of ``tm`` rows each group's run of
+    rows touches, the groups' rows lying one behind the other from row
+    0 — none for an empty group.  Their sum is the work list's length;
+    times ``tm``, the rows the grouped product multiplies."""
+    ends = jnp.cumsum(sizes)
+    first = (ends - sizes) // tm
+    last = (ends - 1) // tm
+    return jnp.where(sizes > 0, last - first + 1, 0).astype(jnp.int32)
+
+
+def visits(sizes, tm: int):
+    """The work list's length for ``sizes``: the groups' visits, and
+    with no row in any group still ONE, of an empty group (it stores
+    nothing) — a grid of no step at all is not asked of the chip."""
+    return jnp.maximum(jnp.sum(visits_by_group(sizes, tm)), 1)
+
+
+def work_list(sizes, m: int, tm: int):
+    """The visits of ``sizes`` (groups,) over ``m`` rows in tiles of
+    ``tm``: ``(offsets (groups + 1,), group of visit i, row tile of
+    visit i, number of visits)``; the two lists are as long as there can
+    be visits (tiles + groups - 1) and mean nothing behind the number.
+    Visits are ordered by group, so a row tile's visits are consecutive
+    and an output tile is complete when the grid leaves it."""
+    groups = sizes.shape[0]
+    tiles = pl.cdiv(m, tm)
+    most = tiles + groups - 1
+    ends = jnp.cumsum(sizes).astype(jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    per_group = visits_by_group(sizes, tm)
+    before = jnp.cumsum(per_group) - per_group       # visits before a group
+    group_ids = jnp.repeat(jnp.arange(groups, dtype=jnp.int32), per_group,
+                           total_repeat_length=most)
+    tile_ids = (offsets[group_ids] // tm
+                + jnp.arange(most, dtype=jnp.int32) - before[group_ids])
+    tile_ids = jnp.clip(tile_ids, 0, tiles - 1).astype(jnp.int32)
+    return offsets, group_ids, tile_ids, visits(sizes, tm)
+
+
+def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, layer_ref, lhs_ref,
+            rhs_ref, out_ref, acc_ref, *, tm: int, k_tiles: int):
+    del layer_ref                                   # the index maps' alone
+    visit, k_i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k_i == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(k_i == k_tiles - 1)
+    def _store():
+        # the rows of this tile that are this visit's group's: the
+        # others are another visit's, before or after it
+        group = group_ids_ref[visit]
+        row = tile_ids_ref[visit] * tm + jax.lax.broadcasted_iota(
+            jnp.int32, acc_ref.shape, 0)
+        mine = (row >= offsets_ref[group]) & (row < offsets_ref[group + 1])
+        out_ref[...] = jnp.where(mine, acc_ref[...], out_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
+def grouped_matmul(lhs, rhs, sizes, layer=0, *,
+                   tiling: tuple[int, int, int], interpret: bool = False):
+    """``lhs`` (m, k) rows sorted by group, ``rhs`` (layers, groups, k,
+    n) of which layer ``layer`` (traced) is meant, ``sizes`` (groups,)
+    int32 rows of each group, from row 0 on -> (m, n) float32: row r of
+    group g is ``lhs[r] @ rhs[layer, g]``.  Rows behind the groups are
+    not computed: they hold whatever was there.  ``tiling`` = (tm, tk,
+    tn), tm a multiple of 16, tk and tn multiples of 128 that divide k
+    and n (or k and n themselves)."""
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    tm, tk, tn = tiling
+    if k % tk or n % tn or tm % MIN_ROWS:
+        raise ValueError(
+            f"grouped_matmul: tiling {tiling} does not tile ({m}, {k}) x "
+            f"({k}, {n}): tm must be a multiple of {MIN_ROWS}, tk and tn "
+            "must divide k and n")
+    padded = pl.cdiv(m, tm) * tm
+    if padded != m:                 # whole row tiles: rows of no group
+        lhs = jnp.pad(lhs, ((0, padded - m), (0, 0)))
+    offsets, group_ids, tile_ids, visits = work_list(sizes, padded, tm)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    k_tiles = k // tk
+    blocks = 2 * (tm * tk * lhs.dtype.itemsize + tk * tn * rhs.dtype.itemsize
+                  + tm * tn * 4) + tm * tn * 4
+    out = pl.pallas_call(
+        functools.partial(_kernel, tm=tm, k_tiles=k_tiles),
+        out_shape=jax.ShapeDtypeStruct((padded, n), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda n_i, v, k_i, offsets, groups,
+                             tiles, layer: (tiles[v], k_i)),
+                pl.BlockSpec((None, None, tk, tn),
+                             lambda n_i, v, k_i, offsets, groups, tiles,
+                             layer: (layer[0], groups[v], k_i, n_i)),
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda n_i, v, k_i, offsets, groups, tiles,
+                layer: (tiles[v], n_i)),
+            grid=(n // tn, visits, k_tiles),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=blocks + (8 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(m * k * lhs.dtype.itemsize + m * n * 4
+                            + sizes.shape[0] * k * n * rhs.dtype.itemsize)),
+        name="grouped_matmul", interpret=interpret,
+    )(offsets, group_ids, tile_ids, layer, lhs, rhs)
+    return out[:m]

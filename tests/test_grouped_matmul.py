@@ -1,0 +1,267 @@
+"""``ops/pallas/grouped_matmul.py`` in the Pallas interpreter against
+``lax.ragged_dot``, which the step programs ran before it and which
+``forward`` / ``loss_fn`` keep: the same rows by the same experts, the
+whole stack with a traced layer, a share's rows of no group, empty
+groups, ragged row counts, tiles that hold many groups — and that a
+row's sum is bit for bit its own whatever rows share its tile or its
+batch.  Then the step programs through it: the same logits as through
+XLA's kernel, and the counter that says what the work list visited.
+(That the kernel compiles for the chip, at the cells' shapes and with
+the stack read in place: ``tests/test_tpu_compile.py``.)
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from ant_ray_tpu.models import llama
+from ant_ray_tpu.ops.pallas import grouped_matmul as gm
+
+LAYERS, K, N = 3, 256, 384
+
+# name: (rows, sizes of the groups from row 0 on, (tm, tk, tn))
+CASES = {
+    "all-held": (64, [16, 16, 16, 16], (16, 256, 384)),
+    "all-held-ragged-groups": (64, [5, 30, 2, 27], (16, 256, 384)),
+    "a-share-rows-of-no-group-behind": (96, [7, 11, 3, 9], (16, 256, 384)),
+    "empty-groups": (64, [0, 20, 0, 0, 9, 0], (16, 256, 384)),
+    "first-and-last-groups-empty": (48, [0, 0, 13, 21, 0], (32, 256, 384)),
+    "no-row-in-any-group": (32, [0, 0, 0, 0], (16, 256, 384)),
+    "rows-not-a-multiple-of-the-tile": (40, [9, 14, 10], (16, 256, 384)),
+    "rows-under-one-tile": (8, [3, 0, 4], (16, 256, 384)),
+    "a-tile-straddles-three-groups": (64, [3, 4, 5, 20, 1, 1, 1], (
+        16, 256, 384)),
+    "one-group-over-many-tiles": (128, [2, 97, 5], (16, 256, 384)),
+    "k-in-tiles": (64, [5, 30, 2, 20], (16, 128, 384)),
+    "k-and-n-in-tiles": (64, [5, 30, 2, 20], (32, 128, 128)),
+    "a-tile-as-tall-as-the-rows": (64, [5, 30, 2, 20], (64, 256, 384)),
+}
+
+
+def _operands(rows, groups, dtype=jnp.bfloat16):
+    lhs = jax.random.normal(jax.random.PRNGKey(1), (rows, K), dtype)
+    rhs = jax.random.normal(jax.random.PRNGKey(2), (LAYERS, groups, K, N),
+                            dtype) * K ** -0.5
+    return lhs, rhs
+
+
+def _kernel(tiling):
+    return jax.jit(lambda lhs, rhs, sizes, layer: gm.grouped_matmul(
+        lhs, rhs, sizes, layer, tiling=tiling, interpret=True))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("name", CASES)
+def test_kernel_equals_ragged_dot_on_the_rows_of_a_group(name, layer):
+    """Layer ``layer`` of the whole stack, the index traced: every row
+    of a group is what ``lax.ragged_dot`` gives against that layer's
+    experts alone (float32 sums of bf16 products; the k tiles' partial
+    sums round apart by a few units in the last place)."""
+    rows, sizes, tiling = CASES[name]
+    sizes = jnp.asarray(sizes, jnp.int32)
+    lhs, rhs = _operands(rows, sizes.shape[0])
+    got = _kernel(tiling)(lhs, rhs, sizes, jnp.int32(layer))
+    want = lax.ragged_dot(lhs, rhs[layer], sizes,
+                          preferred_element_type=jnp.float32)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    real = int(sizes.sum())
+    np.testing.assert_allclose(np.asarray(got)[:real],
+                               np.asarray(want)[:real], rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_work_list_visits_every_tile_a_group_touches_once(name):
+    """The work list against the pairs counted row by row: (row tile,
+    group) for every row of a group, in the groups' order; tiles of a
+    run are consecutive, so an output tile is left complete."""
+    rows, sizes, (tm, _, _) = CASES[name]
+    padded = -(-rows // tm) * tm
+    offsets, group_ids, tile_ids, visits = gm.work_list(
+        jnp.asarray(sizes, jnp.int32), padded, tm)
+    group_of_row = np.repeat(np.arange(len(sizes)), sizes)
+    want = sorted({(int(g), r // tm) for r, g in enumerate(group_of_row)})
+    got = list(zip(np.asarray(group_ids).tolist(),
+                   np.asarray(tile_ids).tolist()))[:int(visits)]
+    if want:
+        assert got == want
+        assert int(gm.visits_by_group(jnp.asarray(sizes), tm).sum()) == len(
+            want)
+    else:                       # one visit that stores nothing
+        assert len(got) == 1 and sizes[got[0][0]] == 0
+    assert group_ids.shape == tile_ids.shape == (
+        padded // tm + len(sizes) - 1,)
+    np.testing.assert_array_equal(np.asarray(offsets),
+                                  np.concatenate([[0], np.cumsum(sizes)]))
+    assert np.all(np.diff(np.asarray(tile_ids)[:int(visits)]) >= 0)
+
+
+@pytest.mark.parametrize("tiling", [(16, 256, 384), (32, 128, 384),
+                                    (64, 128, 128)], ids=str)
+def test_a_rows_sum_is_its_own_whatever_shares_its_batch(tiling):
+    """The same 12 rows of expert 2 — alone in the batch, in the middle
+    of a full one (another place in another tile, other neighbours), and
+    behind an expert that now has rows — give the same bits."""
+    mine = jax.random.normal(jax.random.PRNGKey(5), (12, K), jnp.bfloat16)
+    other = jax.random.normal(jax.random.PRNGKey(6), (200, K), jnp.bfloat16)
+    _, rhs = _operands(1, 4)
+    kernel = _kernel(tiling)
+
+    def rows_of_expert_2(before, behind, total):
+        """``before`` rows of experts 0 and 1 in front, ``behind`` rows
+        of expert 3 after, padded to ``total`` rows."""
+        lhs = jnp.concatenate([other[:before], mine,
+                               other[before:before + behind]])
+        lhs = jnp.pad(lhs, ((0, total - lhs.shape[0]), (0, 0)))
+        sizes = jnp.asarray([before // 2, before - before // 2, 12, behind],
+                            jnp.int32)
+        return np.asarray(kernel(lhs, rhs, sizes, jnp.int32(1)))[
+            before:before + 12]
+
+    alone = rows_of_expert_2(0, 0, 64)
+    np.testing.assert_array_equal(alone, rows_of_expert_2(37, 50, 128))
+    np.testing.assert_array_equal(alone, rows_of_expert_2(8, 0, 32))
+    np.testing.assert_array_equal(alone, rows_of_expert_2(100, 88, 200))
+
+
+# the rule, at the six shapes of the benchmark's routed cells: (tokens
+# of the step, k a token, router width, an expert's (in, out)) -> tiling
+@pytest.mark.parametrize("tokens,k,width,expert,want", [
+    (16, 8, 64, (2048, 1024), (32, 2048, 1024)),
+    (64, 8, 64, (1024, 2048), (64, 1024, 2048)),
+    (48, 8, 192, (7168, 2048), (32, 896, 2048)),
+    (64, 8, 192, (2048, 7168), (32, 256, 7168)),
+    (16, 8, 128, (4096, 4096), (32, 512, 4096)),
+    (512, 8, 128, (4096, 4096), (128, 512, 4096)),
+], ids=str)
+def test_tiling_follows_the_rows_an_expert_gets(tokens, k, width, expert,
+                                                want):
+    """``tm`` from eight times rows * k over the router's width, from 32
+    to 128, a panel of whole rows within ``PANEL_BYTES``: a function of
+    shapes alone (each the best or within 1 % of it in
+    ``benchmarks/grouped_product``'s sweep on the chip, the last within
+    8 %: ``PERF.md`` section 6, PR 37)."""
+    got = gm.tiling(tokens * k / width, *expert)
+    assert got == want
+    tm, tk, tn = got
+    assert tk * tn * 2 <= gm.PANEL_BYTES and expert[0] % tk == 0
+    assert tm % gm.MIN_ROWS == 0 and tk % 128 == 0 and tn % 128 == 0
+
+
+def test_a_tiling_that_does_not_tile_is_refused():
+    lhs, rhs = _operands(32, 2)
+    with pytest.raises(ValueError, match="does not tile"):
+        gm.grouped_matmul(lhs, rhs, jnp.asarray([16, 16], jnp.int32), 0,
+                          tiling=(16, 96, 384), interpret=True)
+    with pytest.raises(ValueError, match="does not tile"):
+        gm.grouped_matmul(lhs, rhs, jnp.asarray([16, 16], jnp.int32), 0,
+                          tiling=(8, 256, 384), interpret=True)
+
+
+# ------------------------------------------------ through ``_routed_mlp``
+
+@pytest.mark.parametrize("name", ["moe-tiny", "olmoe-tiny", "axk1-tiny",
+                                  "cmdaplus-tiny"])
+@pytest.mark.parametrize("rows", [5, 48])
+def test_routed_mlp_through_the_kernel_is_what_ragged_dot_gives(name, rows):
+    """The whole routed feed-forward of the step programs — all experts
+    held, and a share whose absent experts' rows sort behind — with the
+    kernel (tile 16) against XLA's product, the stack whole and the
+    layer traced; and the same ``load``."""
+    cfg = llama.CONFIGS[name].stacks()["layers"]
+    stack = llama.init_params(llama.CONFIGS[name],
+                              jax.random.PRNGKey(3))["layers"]
+    layer = {**{k: v[1] * 8 for k, v in stack.items()},
+             **{k: stack[k] * 8 for k in ("w_gate", "w_up", "w_down")}}
+    h = jax.random.normal(jax.random.PRNGKey(4), (rows, cfg.dim))
+
+    def run(tile):
+        return jax.jit(lambda i: llama._routed_mlp(layer, h, cfg, i, tile))(
+            jnp.int32(1))
+
+    got, want = run(16), run(0)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-6)
+    assert np.isfinite(np.asarray(got[0])).all()
+
+
+@pytest.mark.parametrize("name", ["olmoe-tiny", "axk1-tiny",
+                                  "cmdaplus-tiny"])
+def test_step_programs_through_the_kernel(name, monkeypatch):
+    """A chunk and three decode steps with the kernel's tile forced (on
+    the CPU ``_grouped_tile`` keeps XLA's product) give the logits of
+    the same programs without it, and ``moe_tile_rows`` counts tile x
+    visits by the work list's own rule where it stays 0 without."""
+    cfg = llama.CONFIGS[name]
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (16,), 0, 256)
+    counted = llama.ROUTING_COUNTERS.index
+
+    def run(tile):
+        monkeypatch.setattr(llama, "_grouped_tile",
+                            lambda c, rows, mesh: tile if c.num_experts
+                            else 0)
+        cache = llama.init_kv_cache(cfg, 4, 64, chunk=16)
+        chunk = jax.jit(lambda p, c, t: llama.prefill_chunk_into_cache(
+            p, t, c, 1, 0, 11, cfg), donate_argnums=(1,))
+        decode = jax.jit(lambda p, c, last, act: llama.decode_step(
+            p, last, c, cfg, act), donate_argnums=(1,))
+        logits, cache = chunk(params, cache, tokens)
+        out = [logits]
+        active = jnp.asarray([False, True, False, False])
+        for _ in range(3):
+            logits, cache = decode(
+                params, cache, jnp.argmax(out[-1], axis=-1).reshape(
+                    -1)[:1].repeat(4).astype(jnp.int32), active)
+            out.append(logits[1])
+        return np.stack([np.asarray(o).reshape(-1) for o in out]), \
+            np.asarray(cache["routing"])
+
+    got, got_routing = run(16)
+    want, want_routing = run(0)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(got_routing[:-1], want_routing[:-1])
+    assert want_routing[counted("moe_tile_rows")] == 0
+    tile_rows = int(got_routing[counted("moe_tile_rows")])
+    assert tile_rows % 16 == 0
+    routed_layers = cfg.stacks()["layers"].n_layers
+    assert tile_rows >= 16 * routed_layers * 4      # a visit a layer at least
+    assert tile_rows >= int(got_routing[counted("moe_assignments")])
+
+
+def test_tile_rows_are_the_work_lists_visits():
+    """``_count_routing`` with a tile: tile x the visits of each layer's
+    loads, one at least a layer (the kernel's grid is never empty)."""
+    loads = jnp.asarray([[3, 0, 20, 1], [0, 0, 0, 0], [16, 16, 0, 1]],
+                        jnp.int32)
+    cache = {"routing": jnp.zeros((len(llama.ROUTING_COUNTERS),),
+                                  jnp.uint32)}
+    seen = np.asarray(llama._count_routing(cache, loads, 99, True, 16)[
+        "routing"])
+    # layer 0: rows 0-2 | 3-22 | 23 -> tiles 0 | 0, 1 | 1 = 4 visits;
+    # layer 1: none -> 1; layer 2: 0 | 1 | 2 = 3
+    assert seen[llama.ROUTING_COUNTERS.index("moe_tile_rows")] == 16 * 8
+    assert seen[0] == 57
+    none = np.asarray(llama._count_routing(cache, loads, 99, True, 0)[
+        "routing"])
+    assert none[-1] == 0 and (none[:-1] == seen[:-1]).all()
+
+
+def test_grouped_tile_keeps_ragged_dot_off_the_tpu_and_under_a_mesh(
+        monkeypatch):
+    cfg = llama.CONFIGS["olmoe-tiny"]
+    assert llama._grouped_tile(cfg, 16, None) == 0          # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert llama._grouped_tile(cfg, 16, None) == 32        # 8 * 16 * 2 / 8
+    assert llama._grouped_tile(cfg, 16, object()) == 0      # a mesh
+    assert llama._grouped_tile(llama.CONFIGS["tiny"], 16, None) == 0
+    wide = dataclasses.replace(cfg, num_experts=16, experts_per_token=8,
+                               router_width=128)
+    assert llama._grouped_tile(wide, 16, None) == 32        # 8 * 1: the least
+    assert llama._grouped_tile(wide, 128, None) == 64       # 8 * 128 * 8 / 128
+    assert llama._grouped_tile(wide, 512, None) == 128      # the most

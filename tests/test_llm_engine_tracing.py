@@ -389,7 +389,8 @@ def test_a_model_without_window_layers_leaves_the_new_counters_at_zero(
 def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     """What a routed model's step programs record, always on: the
     routing counters (``llama.ROUTING_COUNTERS``, PR 27; ``moe_rows_routed``
-    and the decode steps' own PR 32) in ``LLMEngine.stats`` from the start, riding the step's one
+    and the decode steps' own PR 32, ``moe_tile_rows`` PR 37: 0 where XLA's
+    kernel multiplies, as here) in ``LLMEngine.stats`` from the start, riding the step's one
     read, and the named scopes a reducer can file operations under —
     ``moe`` around the routed experts, ``moe_shared`` around the shared
     one, ``mla`` around latent attention, ``attn_window`` and
@@ -400,7 +401,7 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
         "moe_assignments", "moe_experts_hit", "moe_expert_slots",
         "moe_load_max", "moe_rows_routed", "moe_decode_assignments",
         "moe_decode_experts_hit", "moe_decode_expert_slots",
-        "moe_decode_rows_routed")
+        "moe_decode_rows_routed", "moe_tile_rows")
     eng = LLMEngine(cfg, slots=2, max_seq=64, prefill_chunk_tokens=8,
                     tokenizer=_NoEos())
     assert set(llama.ROUTING_COUNTERS) <= set(eng.stats)
@@ -409,6 +410,7 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     assert stats["d2h_syncs"] == stats["decode_steps"] + 1
     held, routed = stats["moe_assignments"], stats["moe_rows_routed"]
     assert (held == routed) == (not cfg.router_width)
+    assert stats["moe_tile_rows"] == 0
     routed_layers = cfg.n_layers - cfg.n_dense_layers
     assert stats["moe_expert_slots"] == cfg.num_experts * routed_layers * (
         stats["decode_steps"] + stats["chunks"])

@@ -199,29 +199,6 @@ LATENT = llama.LlamaConfig(
         32.0, 4096, mscale=1.0, mscale_all_dim=1.0))
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
-@pytest.mark.parametrize("config,slots,stack,one_layers_experts", [
-    pytest.param(ROUTED, 16, "bf16[128,2048,1024]", (
-        "bf16[64,2048,1024]", "bf16[64,1024,2048]", "bf16[1,64,2048,1024]",
-        "bf16[1,64,1024,2048]"), id="all-held"),
-    pytest.param(LATENT, 48, "bf16[72,7168,2048]", (
-        "bf16[12,7168,2048]", "bf16[12,2048,7168]", "bf16[1,12,7168,2048]",
-        "bf16[1,12,2048,7168]"), id="a-share-held")])
-def test_routed_step_reads_the_expert_stack_in_place(
-        v5e, program, config, slots, stack, one_layers_experts):
-    """The routed serving programs on the chip: ``lax.ragged_dot``
-    becomes the compiler's grouped-matmul kernel, and it is handed the
-    whole stack of expert matrices (layers x experts HELD groups) — no
-    per-layer slice of a layer's experts is ever materialised in front
-    of it, which would copy every expert's weights on every step."""
-    text = _compile_step(v5e.devices[0], program, config, slots,
-                         512)[0].as_text()
-    assert "ragged-dot" in text and "tpu_custom_call" in text
-    assert stack in text                          # the stack, as groups
-    for gathered in one_layers_experts:
-        assert gathered not in text
-
-
 # Command A+'s blocks at their published widths: window layers (4,096,
 # rotated) and full ones (no positional embedding) three to one, 128
 # heads of 128 on a hidden size of 4,096, one LayerNorm over a parallel
@@ -239,6 +216,46 @@ MIXED = llama.LlamaConfig(
     window_pattern=(True, True, True, False), full_rope=False,
     norm="layer", parallel_block=True)
 MIXED_TWICE = dataclasses.replace(MIXED, n_layers=8, num_experts=4)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize(
+    "config,slots,max_seq,chunk,stacks,one_layers_experts", [
+        pytest.param(ROUTED, 16, 512, 64, (
+            "bf16[2,64,2048,1024]", "bf16[2,64,1024,2048]"), (
+            "bf16[64,2048,1024]", "bf16[64,1024,2048]",
+            "bf16[1,64,2048,1024]", "bf16[1,64,1024,2048]"), id="all-held"),
+        pytest.param(LATENT, 48, 512, 64, (
+            "bf16[6,12,7168,2048]", "bf16[6,12,2048,7168]"), (
+            "bf16[12,7168,2048]", "bf16[12,2048,7168]",
+            "bf16[1,12,7168,2048]", "bf16[1,12,2048,7168]"),
+            id="a-share-held"),
+        pytest.param(MIXED, 16, 8192, 512, ("bf16[4,16,4096,4096]",), (
+            "bf16[16,4096,4096]", "bf16[1,16,4096,4096]"),
+            id="a-share-held-512-token-chunks")])
+def test_routed_step_reads_the_expert_stack_in_place(
+        v5e, monkeypatch, program, config, slots, max_seq, chunk, stacks,
+        one_layers_experts):
+    """The routed serving programs on the chip: the grouped product is
+    ``ops/pallas/grouped_matmul.py``'s kernel (three calls a routed
+    layer's scan body: gate, up, down), and it is handed the whole stack
+    of expert matrices (layers x experts HELD) with the layer by scalar
+    prefetch — no per-layer slice of a layer's experts is ever
+    materialised in front of it, which would copy every expert's
+    weights on every step."""
+    # ``_grouped_tile`` asks the process's own backend, which is the CPU
+    # here; the program is compiled for the described chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile_step(v5e.devices[0], program, config, slots, max_seq,
+                         chunk)[0].as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "grouped_matmul" in line]
+    assert len(calls) >= 3 and "ragged-dot" not in text
+    for stack in stacks:                          # the stack, whole
+        assert any(stack in line for line in calls)
+    assert all(any(stack in line for stack in stacks) for line in calls)
+    for gathered in one_layers_experts:
+        assert gathered not in text
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
