@@ -190,7 +190,18 @@ class _PhaseRecorder:
     rings (each a layer's walk times the layers of its kind; the same
     rule, no read), and ``decode_rows_past_window`` the active rows of
     a decode step whose context exceeds the window; the two
-    ``decode_*_positions`` count the full layers' walk.
+    ``decode_*_positions`` count the full layers' walk.  Of a model
+    with linear layers (``LlamaConfig.n_linear``; every other leaves
+    these five at zero), whose state is a slot's and not a position's:
+    ``recurrent_decode_rows`` adds up, per decode step, the active rows
+    times the linear layers — the states the step advanced — and
+    ``recurrent_slot_rows`` the slots times the linear layers, the
+    states its program read and wrote; ``recurrent_chunk_tokens`` and
+    ``recurrent_chunk_rows`` a chunk's real tokens and its width, times
+    the linear layers; ``recurrent_resets`` the chunks that began a
+    prompt and so began from an empty state (the host's own ``start``:
+    no read).  A linear layer walks nothing: the ``decode_*_positions``
+    of such a model are its softmax layers' walk.
     ``sample_plain_steps`` and ``sample_sorted_steps`` count the decode
     steps whose sampler drew without a filter and with a sort
     (``sampler_work`` of the step's rows: no read); the rest took the
@@ -223,7 +234,9 @@ class _PhaseRecorder:
                     "decode_ahead_steps", "decode_span_positions",
                     "decode_slab_positions", "window_span_positions",
                     "full_span_positions", "decode_rows_past_window",
-                    "sample_plain_steps", "sample_sorted_steps",
+                    "recurrent_decode_rows", "recurrent_slot_rows",
+                    "recurrent_chunk_tokens", "recurrent_chunk_rows",
+                    "recurrent_resets", "sample_plain_steps", "sample_sorted_steps",
                     "d2h_syncs"):
             stats[key] = 0
         stats["block_s"] = 0.0
@@ -403,6 +416,11 @@ class LLMEngine:
         self.max_seq = min(max_seq or self.config.max_seq,
                            self.config.max_seq)
         self.slots = slots
+        if self.config.n_linear and prefill_chunk_tokens is None:
+            raise ValueError(
+                "bucketed prefill keeps no recurrent state: a model with "
+                "linear-attention layers is ingested in chunks "
+                "(prefill_chunk_tokens=)")
         self.tokenizer = tokenizer or get_tokenizer(None)
         if params is None:
             # Random weights as ONE program: each leaf is drawn, scaled
@@ -516,8 +534,9 @@ class LLMEngine:
             return llama.decode_step(params, last_tokens, cache, cfg,
                                      active=active, mesh=eng_mesh)
 
-        # k, v — or c_kv, k_rope; a window model's rings beside them
-        slab_names = tuple(llama.kv_slabs(cfg))
+        # k, v — or c_kv, k_rope; a window model's rings beside them;
+        # behind them what linear layers keep of a slot's sequence
+        slab_names = (*llama.kv_slabs(cfg), *llama.state_slabs(cfg))
 
         def _extract(cache, slot):
             from jax import lax  # noqa: PLC0415
@@ -574,6 +593,11 @@ class LLMEngine:
 
         mesh = self.mesh
         tp = mesh.shape.get("tp", 1)
+        if self.config.n_linear:
+            raise ValueError(
+                "a recurrent state (linear-attention layers) is not "
+                "sharded: the engine runs such a model on one device "
+                "only (tensor_parallel_size=1, no mesh)")
         if self.config.kv_lora_rank:
             raise ValueError(
                 "a latent (MLA) cache has no heads axis to shard: the "
@@ -654,6 +678,15 @@ class LLMEngine:
             seq.emits = []
         seq.submitted = submitted or (time.time(), time.perf_counter(),
                                       self.stats["steps"])
+        if session_id is not None and self.config.n_linear:
+            # The decode step runs one ahead: a turn that a stop token
+            # ends has had its state advanced by that token, and the
+            # next turn would ingest it a second time (slabs only
+            # overwrite the row; a state cannot take a token back).
+            raise ValueError(
+                "sessions are not kept over a recurrent state: a model "
+                "with linear-attention layers serves each request from "
+                "an empty state (no session_id)")
         if session_id is not None:
             sess = self._sessions.get(session_id)
             if sess is None or sess.state == "failed":
@@ -962,6 +995,7 @@ class LLMEngine:
             self.params, self.cache, jnp.asarray(buf), seq.slot,
             seq.kv_len, len(part))
         self._note_dispatch(seq)
+        self._note_recurrent(chunk, len(part), seq.kv_len == 0)
         seq.prefill_done += len(part)
         seq.kv_len += len(part)
         self._note_walk(seq.kv_len)
@@ -1047,6 +1081,7 @@ class LLMEngine:
             max(contexts), self.max_seq)
         stats["decode_slab_positions"] += self.max_seq
         self._note_walk(max(contexts), contexts)
+        self._note_recurrent(self.slots, len(rows))
         work = sampler_work(self._sampling_rows[slot] for slot, _ in rows)
         stats["sample_plain_steps"] += work == 1
         stats["sample_sorted_steps"] += work == 2
@@ -1120,6 +1155,23 @@ class LLMEngine:
                                                           self._ring)
         stats["decode_rows_past_window"] += sum(
             n > self.config.window for n in decoding)
+
+    def _note_recurrent(self, rows: int, live: int, fresh=None):
+        """A step program of ``rows`` rows was dispatched, ``live`` of
+        them real (a decode step's slots and its active rows; a chunk's
+        width and its tokens, ``fresh``: it began its prompt): of a
+        model with linear layers, the states it touched and advanced."""
+        n = self.config.n_linear
+        if not n:
+            return
+        stats = self.stats
+        if fresh is None:                        # a decode step
+            stats["recurrent_slot_rows"] += n * rows
+            stats["recurrent_decode_rows"] += n * live
+        else:
+            stats["recurrent_chunk_rows"] += n * rows
+            stats["recurrent_chunk_tokens"] += n * live
+            stats["recurrent_resets"] += fresh
 
     def _note_dispatch(self, seq: _Seq):
         """A prefill program for ``seq`` was dispatched: the iteration

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ant_ray_tpu.ops import delta_rule
 from ant_ray_tpu.ops.attention import attention, kernel_fits
 from ant_ray_tpu.ops.layernorm import layernorm
 from ant_ray_tpu.ops.pallas import grouped_matmul
@@ -98,16 +99,34 @@ class LlamaConfig:
     rope_scaling: YarnScaling | None = None
     # A head's width where the config STATES it (0 = dim // n_heads).
     head_width: int = 0
-    # Window and full layers in one model: ``window_pattern`` says, for
-    # each place of the layer pattern's period, whether the layer there
-    # attends over a sliding window — query ``t`` sees key ``s`` iff
-    # 0 <= t - s < ``window`` — or over the whole context; () = every
-    # layer is full.  The period repeats over ``n_layers``.  With
-    # ``full_rope`` False the full layers of such a model rotate
-    # nothing (no positional embedding at all); window layers always do.
+    # Unlike layers in one model: ``layer_kinds`` names, for each place
+    # of the layer pattern's period, the KIND of the layer there; () =
+    # every layer is "full".  The period repeats over ``n_layers``.
+    # "full": softmax attention over the whole context.  "window": over
+    # a sliding window — query ``t`` sees key ``s`` iff 0 <= t - s <
+    # ``window``.  "linear": the gated delta rule (``_linear_inputs``,
+    # ``ops/delta_rule.py``), which keeps a state of the SEQUENCE and
+    # nothing of a position; its leaves are a stack of their own
+    # (``LINEAR``).  ``window_pattern`` is the same period written as
+    # booleans (window or full), taken at construction only.  With
+    # ``full_rope`` False the full layers rotate nothing (no positional
+    # embedding at all); window layers always do, linear layers never.
     window: int = 0
-    window_pattern: tuple = ()
+    layer_kinds: tuple = ()
+    window_pattern: dataclasses.InitVar[tuple] = ()
     full_rope: bool = True
+    # A linear layer: ``linear_heads`` heads whose keys and values are
+    # both ``linear_head_dim`` wide (the state a head is that squared,
+    # float32), a depth-wise causal convolution of ``linear_conv`` taps
+    # over q, k and v, and low-rank projections of width
+    # ``linear_rank`` for the decay and the output gate.
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv: int = 4
+    linear_rank: int = 0
+    # The softmax layers' output under an element-wise sigmoid gate, a
+    # projection of the block's normed input, before ``wo``.
+    attn_gate: bool = False
     # "rms" (RMSNorm) or "layer": a LayerNorm without bias, over the
     # block's input and before the head (``ops/layernorm.py``).
     norm: str = "rms"
@@ -117,7 +136,13 @@ class LlamaConfig:
     # The shared experts' outputs are averaged, not summed.
     shared_experts_average: bool = False
 
-    def __post_init__(self):
+    def __post_init__(self, window_pattern):
+        if window_pattern:
+            if self.layer_kinds:
+                raise ValueError("window_pattern is layer_kinds written "
+                                 "as booleans: give one of them")
+            object.__setattr__(self, "layer_kinds", tuple(
+                "window" if w else "full" for w in window_pattern))
         if self.rope_scaling is not None and not self.kv_lora_rank:
             raise ValueError("rope_scaling is computed by the latent "
                              "attention only")
@@ -127,14 +152,25 @@ class LlamaConfig:
         if self.n_dense_layers and not self.num_experts:
             raise ValueError("n_dense_layers are the leading dense "
                              "layers of a routed model")
-        if bool(self.window) != any(self.window_pattern):
+        if set(self.layer_kinds) - {"full", "window", "linear"}:
+            raise ValueError(f"unknown layer kinds {self.layer_kinds!r}")
+        if bool(self.window) != any(self.period):
             raise ValueError("window and window_pattern go together")
-        if self.window_pattern and (
+        if self.layer_kinds and (
                 self.kv_lora_rank or self.n_dense_layers
-                or self.n_layers % len(self.window_pattern)):
+                or self.n_layers % len(self.layer_kinds)):
             raise ValueError("a window pattern repeats whole over "
                              "n_layers of grouped-query layers, none of "
                              "them a leading dense one")
+        if self.n_linear and (
+                self.window or self.parallel_block
+                or "full" not in self.layer_kinds or not (
+                    self.linear_heads and self.linear_head_dim
+                    and self.linear_rank and self.linear_conv > 1)):
+            raise ValueError(
+                "linear layers state their heads, a head's width, the "
+                "convolution's taps and the low-rank width, and stand "
+                "in a sequential block beside full layers only")
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
 
@@ -146,16 +182,35 @@ class LlamaConfig:
         return self.head_width or self.dim // self.n_heads
 
     @property
+    def kinds(self) -> tuple:
+        """The layer pattern's period: the kind of the layer at each
+        place.  ("full",) where all layers are alike."""
+        return self.layer_kinds or ("full",)
+
+    @property
     def period(self) -> tuple:
-        """The layer pattern's period: for each place, whether the layer
-        there is a window layer.  (False,) where all layers are alike."""
-        return tuple(map(bool, self.window_pattern)) or (False,)
+        """``kinds`` as booleans: whether a place's layer is a window
+        layer."""
+        return tuple(kind == "window" for kind in self.kinds)
+
+    @property
+    def n_linear(self) -> int:
+        """Linear layers of the ``n_layers``."""
+        return self.n_layers // len(self.kinds) * self.kinds.count("linear")
 
     def layer_counts(self) -> tuple:
         """(window layers, full layers) of the ``n_layers``."""
-        periods = self.n_layers // len(self.period)
-        n_window = periods * sum(self.period)
-        return n_window, self.n_layers - n_window
+        n_window = self.n_layers // len(self.kinds) * sum(self.period)
+        return n_window, self.n_layers - n_window - self.n_linear
+
+    def place(self, j: int) -> tuple:
+        """Where the layer at place ``j`` of the period lies: (its
+        stack's name in a ``stacks`` entry's parameters, that stack's
+        layers a period, ``j``'s rank among them)."""
+        linear = self.kinds[j] == "linear"
+        alike = [i for i, kind in enumerate(self.kinds)
+                 if (kind == "linear") == linear]
+        return LINEAR if linear else "layers", len(alike), alike.index(j)
 
     @property
     def rope_dim(self) -> int:
@@ -193,6 +248,11 @@ class LlamaConfig:
         return sum(math.prod(shape) for shape in jax.tree.leaves(
             param_shapes(self), is_leaf=lambda x: isinstance(x, tuple)))
 
+
+# The stack of a model's linear layers in the parameter tree, beside
+# ``layers`` (its softmax layers): a linear layer's leaves are not a
+# softmax layer's, so the two kinds cannot lie in one stacked array.
+LINEAR = "linear_layers"
 
 CONFIGS: dict[str, LlamaConfig] = {
     # ref parity: the Llama-3-8B benchmark model (BASELINE.md north star)
@@ -251,16 +311,51 @@ CONFIGS: dict[str, LlamaConfig] = {
         router_width=8, n_shared_experts=4, shared_experts_average=True,
         window=16, window_pattern=(True, True, True, False),
         full_rope=False, norm="layer", parallel_block=True),
+    # Solar Open 2's block at test size: two periods of a gated softmax
+    # layer without positional embedding (4 query / 2 KV heads of 16)
+    # and three gated delta-rule layers (4 heads of 16 x 16, conv of 4
+    # taps, low-rank width 8), a sigmoid router over 16 experts, 2 a
+    # token, of which this share holds 8, beside one shared expert
+    "solar2-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        mlp_dim=32, max_seq=512, norm_eps=1e-5, dtype=jnp.float32,
+        num_experts=8, experts_per_token=2, router_scoring="sigmoid",
+        router_width=16, n_shared_experts=1, full_rope=False,
+        layer_kinds=("full", "linear", "linear", "linear"),
+        linear_heads=4, linear_head_dim=16, linear_rank=8, attn_gate=True),
 }
 
 
 # ---------------------------------------------------------------- params
 
-def _layer_leaves(c: LlamaConfig) -> dict:
+def _layer_leaves(c: LlamaConfig, linear: bool = False) -> dict:
     """One stack of like layers: leaf name -> (its shape after the
-    leading layers axis, its logical dims after it)."""
+    leading layers axis, its logical dims after it); ``linear``: the
+    model's linear layers (``LINEAR``), else its softmax layers.  A
+    leaf's last logical dim also says how ``init_params`` draws it."""
     hd, e, p, m = c.head_dim, "embed_param", "heads_flat", "mlp"
-    if c.kv_lora_rank:
+    if linear:
+        heads, rank = c.linear_heads, c.linear_rank
+        width = heads * c.linear_head_dim
+        attn = {
+            "wq": ((c.dim, width), (e, p)),
+            "wk": ((c.dim, width), (e, p)),
+            "wv": ((c.dim, width), (e, p)),
+            # q, k and v side by side, as the cache keeps their tails
+            "conv_w": ((c.linear_conv, 3 * width), (None, "conv")),
+            # the decay: a low-rank pair, a rate a head, a bias a channel
+            "w_fa": ((c.dim, rank), (e, None)),
+            "w_fb": ((rank, width), (None, p)),
+            "a_log": ((heads,), ("decay_rate",)),
+            "dt_bias": ((width,), ("decay_bias",)),
+            "w_beta": ((c.dim, heads), (e, None)),
+            # the output gate's pair, and the norm a head before it
+            "w_ga": ((c.dim, rank), (e, None)),
+            "w_gb": ((rank, width), (None, p)),
+            "o_norm": ((c.linear_head_dim,), ("norm",)),
+            "wo": ((width, c.dim), (p, e)),
+        }
+    elif c.kv_lora_rank:
         attn = {
             "w_qa": ((c.dim, c.q_lora_rank), (e, None)),
             "q_a_norm": ((c.q_lora_rank,), ("norm",)),
@@ -278,6 +373,8 @@ def _layer_leaves(c: LlamaConfig) -> dict:
             "wk": ((c.dim, c.n_kv_heads * hd), (e, p)),
             "wv": ((c.dim, c.n_kv_heads * hd), (e, p)),
             "wo": ((c.n_heads * hd, c.dim), (p, e)),
+            **({"w_attn_gate": ((c.dim, c.n_heads * hd), (e, p))}
+               if c.attn_gate else {}),
         }
     if c.num_experts:
         n = c.num_experts
@@ -307,7 +404,7 @@ def _layer_leaves(c: LlamaConfig) -> dict:
         **mlp,
         **({"q_norm": ((c.n_heads * hd,), ("norm",)),
             "k_norm": ((c.n_kv_heads * hd,), ("norm",))}
-           if c.qk_norm else {}),
+           if c.qk_norm and not linear else {}),
     }
 
 
@@ -317,11 +414,14 @@ def _param_tree(config: LlamaConfig, pick) -> dict:
     dense layers are a stack of their own, ``dense_layers``, beside
     ``layers``, which then holds the routed ones only."""
     c = config
+    stacks = {name: (stack.n_layers - stack.n_linear, _layer_leaves(stack))
+              for name, stack in c.stacks().items()}
+    if c.n_linear:
+        stacks[LINEAR] = (c.n_linear, _layer_leaves(c, linear=True))
     return {
         "embed": pick(None, (c.vocab_size, c.dim), ("vocab", "embed_param")),
-        **{name: {leaf: pick(stack.n_layers, *both)
-                  for leaf, both in _layer_leaves(stack).items()}
-           for name, stack in c.stacks().items()},
+        **{name: {leaf: pick(n, *both) for leaf, both in leaves.items()}
+           for name, (n, leaves) in stacks.items()},
         "norm_f": pick(None, (c.dim,), ("norm",)),
         **({} if c.tie_embeddings else {"lm_head": pick(
             None, (c.dim, c.vocab_size), ("embed_param", "vocab"))}),
@@ -339,8 +439,10 @@ def param_logical_dims(config: LlamaConfig) -> dict:
         dims if n is None else (None, *dims)))
 
 
-# extra rule: flattened (heads*head_dim) dims shard over tp
-LLAMA_RULES_EXTRA = {"heads_flat": "tp"}
+# extra rules: flattened (heads*head_dim) dims shard over tp; a linear
+# layer's small leaves, named for how they are drawn, are replicated
+LLAMA_RULES_EXTRA = {"heads_flat": "tp", "conv": None,
+                     "decay_rate": None, "decay_bias": None}
 
 
 def llama_rules() -> dict:
@@ -362,9 +464,20 @@ def init_params(config: LlamaConfig, key) -> dict:
     def _init(shape, logical, k):
         if logical[-1] == "norm":
             return jnp.ones(shape, config.dtype)
-        scale = 0.02
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(
-            config.dtype)
+        if logical[-1] == "decay_rate":
+            # a_log: a head forgets at a rate drawn from 1 to 16 ...
+            drawn = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1, 16))
+        elif logical[-1] == "decay_bias":
+            # ... times a channel's step of 0.001 to 0.1, log-uniform
+            # (dt_bias is its inverse softplus): the family's
+            # convention, half-lives from under one token to hundreds
+            dt = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+            drawn = dt + jnp.log(-jnp.expm1(-dt))
+        else:
+            # every matrix, a convolution's taps among them
+            drawn = jax.random.normal(k, shape, jnp.float32) * 0.02
+        return drawn.astype(config.dtype)
 
     leaves = [_init(s, d, k) for s, d, k in zip(flat, dims, keys)]
     return jax.tree.unflatten(treedef, leaves)
@@ -390,7 +503,7 @@ def param_shardings(config: LlamaConfig, mesh) -> dict:
 # ---------------------------------------------------------------- forward
 
 def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
-                attend, constrain_act, index=None, windowed: bool = False,
+                attend, constrain_act, index=None, kind: str = "full",
                 tile: int = 0):
     """One transformer block on ``x`` (..., dim), the only place its
     equations are written: training hands it (batch, seq, dim), a
@@ -407,16 +520,31 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     None for arange over the sequence.  ``index``: the layer's number
     where ``layer`` holds the whole stack's expert matrices, ``tile``
     the grouped kernel's row tile there (``_routed_mlp``).
-    ``windowed``: the layer is one of the model's
-    window layers (``LlamaConfig.period``) — the caller's ``attend``
-    masks accordingly; here it decides whether the heads are rotated (a
-    full layer of a ``full_rope=False`` model rotates nothing).  With
+    ``kind``: the layer's, of ``LlamaConfig.kinds``.  A "window"
+    layer's ``attend`` masks accordingly; here the kind decides whether
+    the heads are rotated (a full layer of a ``full_rope=False`` model
+    rotates nothing).  A "linear" layer hands ``attend(u, g, beta,
+    conv_w)`` what ``_linear_inputs`` makes of the normed input — q, k
+    and v BEFORE their convolution, whose last inputs the caller may
+    hold, the log-decays and the write strengths — and gets back the
+    heads' outputs (..., heads, d_v) float32, which it norms a head,
+    gates and projects.  With
     ``parallel_block`` attention and feed-forward read the same normed
     input and join in one residual sum.  Returns ``(x, state, load)``,
     ``load`` as ``_mlp`` gives it."""
     lead = x.shape[:-1]
     h = _norm(x, layer["ln_attn"], c)
-    if c.kv_lora_rank:
+    if kind == "linear":
+        with jax.named_scope("attn_linear"):
+            attn, state = attend(*_linear_inputs(layer, h, c),
+                                 layer["conv_w"])
+            gate = jax.nn.sigmoid(jnp.dot(
+                h @ layer["w_ga"], layer["w_gb"],
+                preferred_element_type=jnp.float32))
+            attn = rmsnorm(attn, layer["o_norm"].astype(jnp.float32),
+                           c.norm_eps).reshape(*lead, -1) * gate
+            attn = attn.astype(x.dtype)
+    elif c.kv_lora_rank:
         with jax.named_scope("mla"):
             xq, c_kv, k_rope = _latent_qkv(layer, h, c, cos, sin, positions)
             attn, state = attend(xq, c_kv, k_rope, layer["w_kvb"])
@@ -425,14 +553,18 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         xq = xq.reshape(*lead, c.n_heads, c.head_dim)
         xk = xk.reshape(*lead, c.n_kv_heads, c.head_dim)
         xv = (h @ layer["wv"]).reshape(*lead, c.n_kv_heads, c.head_dim)
-        if windowed or c.full_rope:
+        if kind == "window" or c.full_rope:
             xq = apply_rope(xq, cos, sin, positions)
             xk = apply_rope(xk, cos, sin, positions)
         xq = constrain_act(xq, ("batch", "seq", "heads", "head_dim"))
         xk = constrain_act(xk, ("batch", "seq", "kv_heads", "head_dim"))
-        with jax.named_scope("attn_window" if windowed else "attn_full"):
+        with jax.named_scope("attn_" + kind):
             attn, state = attend(xq, xk, xv)
     attn = attn.reshape(*lead, -1)               # heads * value width
+    if c.attn_gate and kind != "linear":
+        attn = (attn * jax.nn.sigmoid(jnp.dot(
+            h, layer["w_attn_gate"],
+            preferred_element_type=jnp.float32))).astype(x.dtype)
     attn = (attn @ layer["wo"]).astype(x.dtype)
     if c.parallel_block:
         out, load = _mlp(layer, h, c, index, tile)
@@ -459,6 +591,52 @@ def _unconstrained(x, _dims):
     """``apply_block``'s ``constrain_act`` where no mesh lays the
     activations out."""
     return x
+
+
+def _linear_inputs(layer: dict, h, c: LlamaConfig):
+    """What a linear layer makes of ``h`` (..., dim) before anything
+    runs along the sequence: ``u`` (..., 3 * heads * d_k), the q, k and
+    v projections side by side, not yet convolved; ``g`` (..., heads,
+    d_k) float32, every key channel's log-decay ``-exp(a_log) *
+    softplus(w_fb (w_fa h) + dt_bias)``, a rate a head and a bias a
+    channel around a low-rank projection; ``beta`` (..., heads)
+    float32, the write strength ``2 * sigmoid(w_beta h)`` — up to 2, so
+    that a write may turn a direction of the state over.  The one place
+    they are made, for training, chunks and decode."""
+    f32 = {"preferred_element_type": jnp.float32}
+    heads = c.linear_heads
+    u = jnp.concatenate([h @ layer[w] for w in ("wq", "wk", "wv")], axis=-1)
+    step = jax.nn.softplus(
+        jnp.dot(h @ layer["w_fa"], layer["w_fb"], **f32)
+        + layer["dt_bias"].astype(jnp.float32))
+    g = -jnp.exp(layer["a_log"].astype(jnp.float32))[:, None] * step.reshape(
+        *h.shape[:-1], heads, -1)
+    beta = 2.0 * jax.nn.sigmoid(jnp.dot(h, layer["w_beta"], **f32))
+    return u, g, beta
+
+
+def _linear_qkv(y, c: LlamaConfig):
+    """The convolution's output ``y`` (..., 3 * heads * d_k) float32 ->
+    q, k, v (..., heads, d_k) float32: SiLU, then a head at a time q
+    and k to unit length, q times d_k^-1/2 besides."""
+    y = jax.nn.silu(y).reshape(*y.shape[:-1], 3, c.linear_heads, -1)
+    q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
+
+    def unit(x):
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * c.linear_head_dim ** -0.5, unit(k), v
+
+
+def _attend_linear_rows(u, g, beta, conv_w, c: LlamaConfig):
+    """A linear layer over ONE whole sequence from an empty state, no
+    cache: u (seq, 3 * heads * d_k), g (seq, heads, d_k), beta (seq,
+    heads) -> (seq, heads, d_v) float32.  The chunk form."""
+    y, _ = delta_rule.causal_conv(
+        u, jnp.zeros((c.linear_conv - 1, u.shape[-1]), u.dtype), conv_w)
+    out, _ = delta_rule.chunk_delta_rule(
+        *_linear_qkv(y, c), g, beta, jnp.zeros(state_slabs(c)["s"][0]))
+    return out
 
 
 def _qk_proj(layer: dict, h, c: LlamaConfig):
@@ -666,38 +844,44 @@ def _rope_tables(c: LlamaConfig, positions: int | None = None):
 
 
 def _stacks(params: dict, c: LlamaConfig) -> list:
-    """``[(a stack's stacked leaves, the config that reads them)]``, in
-    the layers' order (``LlamaConfig.stacks``)."""
-    return [(params[name], cfg) for name, cfg in c.stacks().items()]
+    """``[(a run of layers' stacked leaves BY KIND, the config that
+    reads them)]``, in the layers' order (``LlamaConfig.stacks``): the
+    softmax layers' stack under "layers", and beside it the linear
+    layers' (``LINEAR``) where the run has any (``LlamaConfig.place``
+    says which a place of the period reads)."""
+    return [({"layers": params[name],
+              **({LINEAR: params[LINEAR]} if cfg.n_linear else {})}, cfg)
+            for name, cfg in c.stacks().items()]
 
 
-def _by_period(stack: dict, c: LlamaConfig):
-    """A stack's leaves (layers, ...) as a scan over the layer pattern's
-    periods takes them: ``(what it scans, what it closes over)``.  A
+def _by_period(stacks: dict, c: LlamaConfig):
+    """A run's stacks (``_stacks``), leaves (layers, ...), as a scan
+    over the layer pattern's periods takes them: ``(what it scans, what
+    it closes over)``.  A
     model whose layers are all alike (a period of one) is scanned layer
     by layer, as it lies.  Unlike layers are not sliced by the scan at
     all: the body takes each place's layer out of the whole stack where
     it lies (``_place``) — a period's slice, sliced again by place, is a
     copy of every weight of the period on every call."""
-    if len(c.period) == 1:
-        return stack, None
-    periods = next(iter(jax.tree.leaves(stack))).shape[0] // len(c.period)
-    return jnp.arange(periods), stack
+    if len(c.kinds) == 1:
+        return stacks["layers"], None
+    return jnp.arange(c.n_layers // len(c.kinds)), stacks
 
 
 def _place(scanned, whole, j: int, c: LlamaConfig) -> dict:
     """The layer at place ``j`` of the period a scan over ``_by_period``
-    is at."""
+    is at, out of the stack of its kind."""
     if whole is None:
         return scanned
+    name, a_period, rank = c.place(j)
     return jax.tree.map(lambda leaf: lax.dynamic_index_in_dim(
-        leaf, scanned * len(c.period) + j, keepdims=False), whole)
+        leaf, scanned * a_period + rank, keepdims=False), whole[name])
 
 
 def _unperiod(scanned, c: LlamaConfig):
     """What a scan over periods stacked per place, (periods, places,
     ...), back in the layers' order (layers, ...)."""
-    if len(c.period) == 1:
+    if len(c.kinds) == 1:
         return scanned
     return jax.tree.map(
         lambda leaf: leaf.reshape(-1, *leaf.shape[2:]), scanned)
@@ -753,6 +937,11 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     bench).
     """
     c = config
+    if return_kv and c.n_linear:
+        raise ValueError(
+            "forward(return_kv=True) returns what layers keep of every "
+            "position; a linear layer keeps a state of the sequence: "
+            "ingest it in chunks (prefill_chunk_into_cache)")
     cos, sin = _rope_tables(c)
     use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
 
@@ -763,6 +952,11 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
 
         spec = logical_to_spec(dims, llama_rules())
         return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+    def attend_linear(u, g, beta, conv_w):
+        # no cache: every sequence from an empty state
+        return jax.vmap(functools.partial(_attend_linear_rows, c=c),
+                        (0, 0, 0, None))(u, g, beta, conv_w), None
 
     def attend(window, xq, xk, xv, w_kvb=None):
         # no cache: the whole sequence attends over itself
@@ -799,13 +993,13 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         def period(x, layers):
             # the period's unlike layers, each with its own way to attend
             kvs = []
-            for j, windowed in enumerate(cfg.period):
+            for j, kind in enumerate(cfg.kinds):
                 x, kv, _ = apply_block(
                     _place(layers, whole, j, cfg), x, cfg, cos, sin,
                     positions,
-                    functools.partial(attend,
-                                      cfg.window if windowed else 0),
-                    constrain_act, windowed=windowed)
+                    attend_linear if kind == "linear" else functools.partial(
+                        attend, cfg.window if kind == "window" else 0),
+                    constrain_act, kind=kind)
                 kvs.append(kv)
             if len(kvs) == 1:
                 return x, kvs[0]
@@ -881,10 +1075,11 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
     pp = mesh.shape["pp"]
     if c.n_layers % pp != 0:
         raise ValueError(f"n_layers {c.n_layers} % pp {pp} != 0")
-    if c.n_dense_layers or c.kv_lora_rank or c.window:
+    if c.n_dense_layers or c.kv_lora_rank or c.window or c.n_linear:
         raise ValueError("the pipeline schedule runs one stack of like "
                          "grouped-query layers: no leading dense layers, "
-                         "no latent attention, no window layers")
+                         "no latent attention, no window layers, no "
+                         "linear layers")
     cos, sin = _rope_tables(c)
 
     def attend(xq, xk, xv):
@@ -964,6 +1159,22 @@ def kv_slabs(config: LlamaConfig) -> dict:
             **({"k_ring": position, "v_ring": position} if c.window else {})}
 
 
+def state_slabs(config: LlamaConfig) -> dict:
+    """What a LINEAR layer keeps of a sequence — a slot's, whatever its
+    length: the cache's state leaves by name, each (its shape a slot,
+    its dtype).  ``s``: the delta rule's state, (d_k, d_v) a head,
+    float32 (it is summed into over the whole sequence); ``conv``: the
+    last ``linear_conv - 1`` inputs of the convolution over q, k and v,
+    as the block made them.  Empty without linear layers.  Not among
+    ``kv_slabs``, whose third axis is positions: these have none."""
+    c = config
+    if not c.n_linear:
+        return {}
+    hd = c.linear_head_dim
+    return {"s": ((c.linear_heads, hd, hd), jnp.float32),
+            "conv": ((c.linear_conv - 1, 3 * c.linear_heads * hd), c.dtype)}
+
+
 def ring_positions(config: LlamaConfig, max_seq: int, chunk: int = 0) -> int:
     """Rows of a window layer's ring in a ``max_seq``-position cache
     whose prompts arrive in chunks of ``chunk`` tokens (0: whole, or
@@ -986,7 +1197,10 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     the full layers in (full layers, slots, max_seq, ...) and the window
     layers in rings (window layers, slots, ``ring_positions``, ...),
     both under ONE ``length``; ``chunk`` is the width its prompts are
-    ingested in.  A routed model's cache also carries ``routing``, the
+    ingested in.  A model's linear layers keep no positions but
+    ``state_slabs`` (linear layers, slots, ...), under the same
+    ``length``; its full layers alone have slabs.  A routed model's
+    cache also carries ``routing``, the
     step programs' running counters (``ROUTING_COUNTERS``).
 
     Whoever jits a step program owns these buffers and DONATES them
@@ -1002,6 +1216,8 @@ def init_kv_cache(config: LlamaConfig, slots: int,
         ((n_window, slots, ring) if name.endswith("_ring")
          else (n_full, slots, ms)) + position, c.dtype)
         for name, position in kv_slabs(c).items()}
+    for name, (shape, dtype) in state_slabs(c).items():
+        cache[name] = jnp.zeros((c.n_linear, slots) + shape, dtype)
     # tokens already written per slot (== next write position)
     cache["length"] = jnp.zeros((slots,), jnp.int32)
     if c.num_experts:
@@ -1048,13 +1264,15 @@ def _grouped_tile(c: LlamaConfig, rows: int, mesh) -> int:
         rows * c.experts_per_token / (c.router_width or c.num_experts))
 
 
-def _hoist_experts(layers: dict, c: LlamaConfig):
-    """A stack of layers as ``_scan_layers``' scan takes it: ``(the
+def _hoist_experts(layers: dict, c: LlamaConfig, linear: bool = False):
+    """A stack of layers (``linear``: the model's linear layers') as
+    ``_scan_layers``' scan takes it: ``(the
     leaves it slices layer by layer (``_by_period``), the expert
     matrices it closes over whole, the period indices it scans beside
     them)`` — see ``_routed_mlp`` on why; a dense stack's layers are all
     sliced."""
-    index = jnp.arange(layers["ln_attn"].shape[0] // len(c.period))
+    a_period = sum((kind == "linear") == linear for kind in c.kinds)
+    index = jnp.arange(layers["ln_attn"].shape[0] // a_period)
     if not c.num_experts:
         return layers, {}, index
     whole = {name: layers[name] for name in ("w_gate", "w_up", "w_down")}
@@ -1299,7 +1517,8 @@ def _slab_positions(cache: dict, c: LlamaConfig) -> int:
 
 
 def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
-                 write_attend, *, decode: bool, mesh=None):
+                 write_attend, write_state=None, *, decode: bool,
+                 mesh=None):
     """A step program's layers over rows ``x`` (rows, dim): a
     ``lax.scan`` over each stack's PERIODS of the layer pattern
     (``_stacks``, ``LlamaConfig.period``), whose body is the period's
@@ -1323,45 +1542,62 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     ever sliced out whole.  As a scanned input and output of the loop
     the slabs are copied about three times a call; attending over the
     old slab with the new rows beside it compiles to more temporaries
-    and reorders the float32 sums."""
+    and reorders the float32 sums.
+
+    ``write_state(s, conv, i, u, g, beta, conv_w) -> (out, (s, conv))``
+    is a LINEAR layer's: it runs the call's rows from the carried
+    states of linear layer ``i`` (``state_slabs``) and leaves the new
+    ones where they lay; a model without linear layers never calls it."""
     # as far as a row's position goes: a full slot's is max_seq itself,
     # a chunk's last padded row's max_seq + chunk - 2
     cos, sin = _rope_tables(c, _slab_positions(cache, c) + x.shape[0])
     names = tuple(kv_slabs(c))
+    states = tuple(state_slabs(c))
     tile = _grouped_tile(c, x.shape[0], mesh)
 
-    def scan_stack(carry, stack, cfg, first):
-        """``first``: the stack's first layer's place in the cache."""
-        layers, experts, index = _hoist_experts(stack, cfg)
-        layers, whole = _by_period(layers, cfg)
-        kinds = cfg.period
+    def scan_stack(carry, stacks, cfg, first):
+        """``first``: the run's first layer's place in the cache."""
+        hoisted = {name: _hoist_experts(stack, cfg, name == LINEAR)
+                   for name, stack in stacks.items()}
+        experts = {name: parts[1] for name, parts in hoisted.items()}
+        layers, whole = _by_period(
+            {name: parts[0] for name, parts in hoisted.items()}, cfg)
+        kinds = cfg.kinds
 
         def period(carry, scanned):
             x, slabs = carry                     # slabs: the whole cache
             layers, p = scanned                  # p: the period's number
-            loads = []                           # in its stack
-            for j, windowed in enumerate(kinds):
+            loads = []                           # in its run
+            for j, kind in enumerate(kinds):
+                stack, a_period, rank = cfg.place(j)
                 # the layer's place among the slabs of its kind
-                i = (p * kinds.count(windowed) + kinds[:j].count(windowed)
-                     + (0 if windowed else first))
-                k, v = names[2:] if windowed else names[:2]
-                x, (ks, vs), load = apply_block(
-                    {**_place(layers, whole, j, cfg), **experts}, x, cfg,
-                    cos, sin, positions, functools.partial(
-                        write_attend, slabs[k], slabs[v], i,
-                        cfg.window if windowed else 0),
-                    _unconstrained, p * len(kinds) + j, windowed, tile)
-                slabs = {**slabs, k: ks, v: vs}
+                i = (p * kinds.count(kind) + kinds[:j].count(kind)
+                     + (first if kind == "full" else 0))
+                if kind == "linear":
+                    held = states
+                    attend = functools.partial(
+                        write_state, *(slabs[name] for name in held), i)
+                else:
+                    held = names[2:] if kind == "window" else names[:2]
+                    attend = functools.partial(
+                        write_attend, *(slabs[name] for name in held), i,
+                        cfg.window if kind == "window" else 0)
+                x, kept, load = apply_block(
+                    {**_place(layers, whole, j, cfg), **experts[stack]}, x,
+                    cfg, cos, sin, positions, attend, _unconstrained,
+                    p * a_period + rank, kind, tile)
+                slabs = {**slabs, **dict(zip(held, kept))}
                 loads.append(load)
             return (x, slabs), (loads[0] if len(loads) == 1
                                 or loads[0] is None else jnp.stack(loads))
 
-        carry, loads = lax.scan(period, carry, (layers, index))
+        carry, loads = lax.scan(period, carry,
+                                (layers, hoisted["layers"][2]))
         return carry, _unperiod(loads, cfg)
 
-    carry, first, loads = (x, {n: cache[n] for n in names}), 0, None
-    for stack, cfg in _stacks(params, c):
-        carry, loads = scan_stack(carry, stack, cfg, first)
+    carry, first, loads = (x, {n: cache[n] for n in names + states}), 0, None
+    for stacks, cfg in _stacks(params, c):
+        carry, loads = scan_stack(carry, stacks, cfg, first)
         first += cfg.n_layers
     routed = None if loads is None else (
         loads.shape[0] * x.shape[0] * c.experts_per_token)
@@ -1422,9 +1658,26 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
         return _attend_slab(xq, ks, vs, i, slot, pos, blocks[n], c, w_kvb,
                             window, top), (ks, vs)
 
+    def chunk_state(s, conv, i, u, g, beta, conv_w):
+        """A linear layer: the chunk from (layer i, slot)'s state — from
+        an EMPTY one where the prompt begins there (``start`` 0: what
+        the slot's last occupant left is never read) — and back goes the
+        state behind the chunk's last REAL token: padding neither
+        decays nor writes, and the convolution's tail is the last
+        real inputs."""
+        real = offs < chunk_len
+        s0 = jnp.where(start == 0, 0.0, s[i, slot])
+        tail = jnp.where(start == 0, 0, conv[i, slot]).astype(conv.dtype)
+        y, ext = delta_rule.causal_conv(u.astype(conv.dtype), tail, conv_w)
+        out, s1 = delta_rule.chunk_delta_rule(
+            *_linear_qkv(y, c), jnp.where(real[:, None, None], g, 0.0),
+            jnp.where(real[:, None], beta, 0.0), s0)
+        tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
+        return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
+
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
     x, written = _scan_layers(params, x, cache, c, pos, write_chunk,
-                              decode=False, mesh=mesh)
+                              chunk_state, decode=False, mesh=mesh)
     x = _norm(x, params["norm_f"], c)
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
@@ -1470,9 +1723,20 @@ def decode_step(params: dict, last_tokens, cache: dict,
         return _attend_slab(xq, ks, vs, i, None, pos, blocks[n], c, w_kvb,
                             window, pos), (ks, vs)
 
+    def step_state(s, conv, i, u, g, beta, conv_w):
+        """A linear layer: one token a slot from layer i's states; a
+        slot that is not ``active`` — free, or between two chunks of
+        its own prompt — keeps its state and its tail bit for bit."""
+        y, tail = delta_rule.causal_conv_step(u, conv[i], conv_w)
+        out, new = delta_rule.delta_rule_step(
+            *_linear_qkv(y, c), g, beta, s[i], active)
+        tail = jnp.where(active[:, None, None], tail, conv[i])
+        return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),
+                     lax.dynamic_update_index_in_dim(conv, tail, i, 0))
+
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
     x, written = _scan_layers(params, x, cache, c, pos, write_one,
-                              decode=True, mesh=mesh)
+                              step_state, decode=True, mesh=mesh)
     x = _norm(x, params["norm_f"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)

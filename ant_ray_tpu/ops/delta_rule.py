@@ -1,0 +1,180 @@
+"""The gated delta rule with a decay a CHANNEL its own (linear
+attention whose state is a matrix a head), in its three forms, and the
+short causal convolution in front of it.  Plain jnp: no kernel here
+computes it yet.
+
+A head keeps a state ``S`` (d_k, d_v), float32, and reads one token as
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t                       alpha_t = exp(g_t), g_t <= 0
+
+``g`` the log-decay of every key channel, ``beta`` the write strength
+(up to 2: the state's eigenvalues may turn negative).  With ``u_t =
+beta_t (v_t - (alpha_t * S_{t-1})^T k_t)`` that is ``S_t = alpha_t *
+S_{t-1} + k_t u_t^T``: a decay, then a rank-one write.
+
+* ``delta_rule_scan`` — the recurrence token by token (``lax.scan``):
+  what the other two are held to.
+* ``chunk_delta_rule`` — blocks of ``BLOCK`` tokens.  Inside a block,
+  with ``G`` the cumulative log-decay from the block's start, the
+  ``u`` of all its tokens solve ONE unit-triangular system
+
+      (I + Diag(beta) A) U = Diag(beta) (V - (K * exp(G)) S_0)
+      A[t, i] = sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c]),   i < t
+
+  whose inverse is matrix products too (``_unit_lower_inverse``), in
+  float32 at the highest matmul precision; outputs and the next block's state are
+  matrix products with ``U``.  A decay enters only as a DIFFERENCE of
+  cumulative log-decays, ``exp(G_t - G_i)`` with ``t >= i``, so at most
+  1: ``exp(-G_i)`` alone overflows float32 within one block when a
+  channel forgets fast (g = -1.6 a token is exp(102) after 64).  The
+  state passes from block to block in the scan's carry.
+* ``delta_rule_step`` — one token a row, elementwise in float32; a row
+  that is not ``active`` keeps its state bit for bit.
+
+A token with ``beta = 0`` and ``g = 0`` changes nothing: that is how a
+caller pads.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+BLOCK = 64
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def causal_conv(u, tail, w):
+    """The depth-wise causal convolution over a sequence: ``y_t = sum_j
+    w_j * ext[t + j]`` with ``ext`` the ``taps - 1`` inputs before the
+    sequence (``tail``, zeros at a sequence's start) and then ``u``
+    (tokens, channels); ``w`` (taps, channels), a channel its own taps.
+    Returns (y float32, ext)."""
+    taps, tokens = w.shape[0], u.shape[0]
+    ext = jnp.concatenate([tail.astype(u.dtype), u])
+    y = sum(ext[j:j + tokens].astype(jnp.float32)
+            * w[j].astype(jnp.float32) for j in range(taps))
+    return y, ext
+
+
+def causal_conv_step(u, tail, w):
+    """``causal_conv`` of one token a row: u (rows, channels), tail
+    (rows, taps - 1, channels) -> (y float32, the new tail)."""
+    ext = jnp.concatenate([tail, u[:, None].astype(tail.dtype)], axis=1)
+    y = jnp.sum(ext.astype(jnp.float32) * w.astype(jnp.float32), axis=1)
+    return y, ext[:, 1:]
+
+
+def delta_rule_scan(q, k, v, g, beta, s0):
+    """Token by token.  q, k, g (tokens, heads, d_k), v (tokens, heads,
+    d_v), beta (tokens, heads), s0 (heads, d_k, d_v), all float32 ->
+    (o (tokens, heads, d_v), the last state)."""
+
+    def token(s, x):
+        q, k, v, g, beta = x
+        s = jnp.exp(g)[..., None] * s
+        u = beta[:, None] * (v - jnp.einsum("hkv,hk->hv", s, k,
+                                            precision=_HIGHEST))
+        s = s + k[..., None] * u[:, None, :]
+        return s, jnp.einsum("hkv,hk->hv", s, q, precision=_HIGHEST)
+
+    s, o = lax.scan(token, s0, (q, k, v, g, beta))
+    return o, s
+
+
+def _diagonal_blocks(x, m: int):
+    """(..., n, n) -> (..., n / m, m, m): the blocks on the diagonal."""
+    n = x.shape[-1]
+    x = x.reshape(*x.shape[:-2], n // m, m, n // m, m)
+    return jnp.moveaxis(jnp.diagonal(x, axis1=-4, axis2=-2), -1, -3)
+
+
+def _unit_lower_inverse(lower, leaf: int = 16):
+    """(I + lower)^-1 of strictly lower triangular (..., n, n), by
+    matrix products: the diagonal blocks of ``leaf`` rows by the series
+    ``(I - M)^-1 = (I + M)(I + M^2)(I + M^4)...`` (M nilpotent: it ends),
+    then pairs of neighbours merged, ``[[A, 0], [C, B]]^-1 = [[A^-1,
+    0], [-B^-1 C A^-1, B^-1]]``, until one block is left.  The series
+    over all n rows at once loses digits where a block's keys lie close
+    together (its powers grow before they vanish); merged from small
+    blocks it is as good as substitution row by row."""
+    n = lower.shape[-1]
+    m = min(leaf, n)
+    power = -_diagonal_blocks(lower, m)
+    inverse, reach = jnp.eye(m, dtype=lower.dtype) + power, 2
+    while reach < m:
+        power = jnp.matmul(power, power, precision=_HIGHEST)
+        inverse = inverse + jnp.matmul(inverse, power, precision=_HIGHEST)
+        reach *= 2
+    while m < n:
+        below = _diagonal_blocks(lower, 2 * m)[..., m:, :m]
+        pairs = inverse.reshape(*inverse.shape[:-3], -1, 2, m, m)
+        first, second = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        corner = -jnp.matmul(jnp.matmul(second, below, precision=_HIGHEST),
+                             first, precision=_HIGHEST)
+        inverse = jnp.concatenate([
+            jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
+            jnp.concatenate([corner, second], axis=-1)], axis=-2)
+        m *= 2
+    return inverse[..., 0, :, :]
+
+
+def chunk_delta_rule(q, k, v, g, beta, s0, block: int = BLOCK):
+    """``delta_rule_scan``'s values in blocks of ``block`` tokens, the
+    last one filled up with tokens that change nothing."""
+    with jax.named_scope("kda_chunk"):
+        given, heads = q.shape[:2]
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, -given % block),) + ((0, 0),) * (x.ndim - 1))
+            for x in (q, k, v, g, beta))
+        tokens = q.shape[0]
+        n = tokens // block
+
+        def blocks(x):                        # -> (n, heads, block, ...)
+            x = x.reshape(n, block, *x.shape[1:])
+            return jnp.moveaxis(x, 2, 1)
+
+        lower = jnp.tril(jnp.ones((block, block), bool))
+        strict = jnp.tril(jnp.ones((block, block), bool), -1)
+
+        def one(s, x):
+            q, k, v, g, beta = x              # (heads, block, ...)
+            gc = jnp.cumsum(g, axis=1)
+            # exp(G_t - G_i) for i <= t, 0 above the diagonal
+            decay = jnp.exp(jnp.where(
+                lower[..., None], gc[:, :, None] - gc[:, None], -jnp.inf))
+            kk = jnp.sum(k[:, :, None] * k[:, None] * decay, axis=-1)
+            qk = jnp.sum(q[:, :, None] * k[:, None] * decay, axis=-1)
+            solve = _unit_lower_inverse(
+                beta[..., None] * jnp.where(strict, kk, 0.0))
+            into = jnp.exp(gc)                # from the block's start
+            u = jnp.matmul(solve, beta[..., None] * (
+                v - jnp.matmul(k * into, s)),
+                precision=_HIGHEST)
+            o = jnp.matmul(q * into, s) + jnp.matmul(qk, u)
+            out_of = jnp.exp(gc[:, -1:] - gc)   # to the block's end
+            s = into[:, -1, :, None] * s + jnp.einsum(
+                "hck,hcv->hkv", k * out_of, u)
+            return s, o
+
+        s, o = lax.scan(one, s0, tuple(map(blocks, (q, k, v, g, beta))))
+        return jnp.moveaxis(o, 1, 2).reshape(tokens, heads, -1)[:given], s
+
+
+def delta_rule_step(q, k, v, g, beta, s, active):
+    """One token a row: q, k, g (rows, heads, d_k), v (rows, heads,
+    d_v), beta (rows, heads), s (rows, heads, d_k, d_v), active (rows,)
+    bool -> (o (rows, heads, d_v), the new states).  Elementwise
+    products and sums in float32, two passes over the states: the first
+    reads ``(alpha * S)^T k`` and ``(alpha * S)^T q`` together, the
+    second writes ``S``; ``o = S_new^T q = (alpha * S)^T q + u (k . q)``."""
+    with jax.named_scope("kda_step"):
+        decayed = jnp.exp(g)[..., None] * s
+        sk = jnp.sum(decayed * k[..., None], axis=-2)
+        sq = jnp.sum(decayed * q[..., None], axis=-2)
+        u = beta[..., None] * (v - sk)
+        o = sq + u * jnp.sum(k * q, axis=-1, keepdims=True)
+        new = decayed + k[..., None] * u[..., None, :]
+        return o, jnp.where(active[:, None, None, None], new, s)

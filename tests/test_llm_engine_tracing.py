@@ -380,12 +380,77 @@ def test_a_model_without_window_layers_leaves_the_new_counters_at_zero(
     assert eng._ring == 0 and eng.stats["decode_span_positions"] > 0
     assert eng.stats["full_span_positions"] == eng.stats[
         "window_span_positions"] == eng.stats["decode_rows_past_window"] == 0
+    assert [eng.stats[name] for name in RECURRENT_COUNTERS] == [0] * 5
+
+
+RECURRENT_COUNTERS = ("recurrent_decode_rows", "recurrent_slot_rows",
+                      "recurrent_chunk_tokens", "recurrent_chunk_rows",
+                      "recurrent_resets")
+
+
+def test_recurrent_state_traffic_is_counted_by_the_devices_rule(monkeypatch):
+    """The five ``recurrent_*`` counters (PR 38) of a model with linear
+    layers (six here): per decode step the rows it decoded and the
+    slots its program touched, per chunk its real tokens and its width,
+    each times the linear layers, and the chunks that began a prompt —
+    from the host's own bookkeeping, exactly what the device makes of
+    the programs' own inputs (the ``active`` mask, a chunk's ``start``
+    and length).  ``decode_span_positions`` counts the softmax layers'
+    walk alone: a linear layer walks nothing."""
+    import numpy as np
+
+    from chipbench.layer_metrics import recurrent_state_live_pct
+
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
+    cfg = llama.CONFIGS["solar2-tiny"]
+    assert cfg.n_linear == 6 and cfg.layer_counts() == (0, 2)
+    eng = LLMEngine(cfg, slots=3, max_seq=96, prefill_chunk_tokens=8,
+                    tokenizer=_NoEos())
+    assert eng._ring == 0
+    device = dict.fromkeys(RECURRENT_COUNTERS, 0)
+    device["span"] = 0
+    decode_jit, chunk_jit = eng._decode_jit, eng._prefill_chunk_jit
+
+    def decode(params, cache, last, active):
+        active = np.asarray(active)
+        device["recurrent_decode_rows"] += 6 * int(active.sum())
+        device["recurrent_slot_rows"] += 6 * active.size
+        lengths = np.where(active, np.asarray(cache["length"]), 0)
+        device["span"] += llama.span_positions(int(lengths.max()) + 1, 96)
+        return decode_jit(params, cache, last, active)
+
+    def chunk(params, cache, tokens, slot, start, length):
+        device["recurrent_chunk_tokens"] += 6 * int(length)
+        device["recurrent_chunk_rows"] += 6 * len(tokens)
+        device["recurrent_resets"] += int(start) == 0
+        return chunk_jit(params, cache, tokens, slot, start, length)
+
+    monkeypatch.setattr(eng, "_decode_jit", decode)
+    monkeypatch.setattr(eng, "_prefill_chunk_jit", chunk)
+    before = dict(eng.stats)
+    assert [before[name] for name in RECURRENT_COUNTERS] == [0] * 5
+    eng.generate([list(range(3, 40)), [5, 9, 17], list(range(7, 20)),
+                  [44, 55]], SamplingParams(max_tokens=12))
+    stats = eng.stats
+    for name in RECURRENT_COUNTERS:
+        assert stats[name] == device[name] > 0, name
+    assert stats["recurrent_resets"] == 4               # one a prompt
+    assert stats["recurrent_chunk_tokens"] == 6 * stats["chunk_tokens"]
+    assert stats["recurrent_decode_rows"] == 6 * stats["decode_slots"]
+    assert stats["decode_span_positions"] == device["span"]
+    assert stats["full_span_positions"] == stats["window_span_positions"] == 0
+    obs = {"traced": {"engine": dict(stats), "engine_before": before}}
+    assert recurrent_state_live_pct.read(obs) == pytest.approx(
+        100.0 * stats["decode_slots"] / (3 * stats["decode_steps"]))
+    assert 30 < recurrent_state_live_pct.read(obs) < 100
 
 
 @pytest.mark.parametrize("name,scopes", [
     ("olmoe-tiny", {"moe", "attn_full"}),
     ("axk1-tiny", {"mla", "moe", "moe_shared"}),
-    ("cmdaplus-tiny", {"moe", "moe_shared", "attn_window", "attn_full"})])
+    ("cmdaplus-tiny", {"moe", "moe_shared", "attn_window", "attn_full"}),
+    ("solar2-tiny", {"moe", "moe_shared", "attn_full", "attn_linear",
+                     "kda_step"})])
 def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     """What a routed model's step programs record, always on: the
     routing counters (``llama.ROUTING_COUNTERS``, PR 27; ``moe_rows_routed``
@@ -395,7 +460,9 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     ``moe`` around the routed experts, ``moe_shared`` around the shared
     one, ``mla`` around latent attention, ``attn_window`` and
     ``attn_full`` around the two ways a grouped-query layer attends
-    (PR 34)."""
+    (PR 34), ``attn_linear`` around a linear layer's mix and inside it
+    ``kda_step`` around the delta rule's step (PR 38; ``kda_chunk``
+    around its block form, in the chunk program)."""
     cfg = llama.CONFIGS[name]
     assert llama.ROUTING_COUNTERS == (
         "moe_assignments", "moe_experts_hit", "moe_expert_slots",
@@ -426,8 +493,14 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     lowered = eng._decode_jit.lower(
         eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool))
     text = lowered.as_text(debug_info=True)
-    for scope in ("mla", "moe", "moe_shared", "attn_window", "attn_full"):
-        assert (f'"{scope}/' in text) == (scope in scopes), scope
+    for scope in ("mla", "moe", "moe_shared", "attn_window", "attn_full",
+                  "attn_linear", "kda_step"):
+        assert (f'{scope}/' in text) == (scope in scopes), scope
+    chunk = eng._prefill_chunk_jit.lower(
+        eng.params, eng.cache, eng._jnp.zeros((8,), "int32"), 0, 0, 3)
+    assert ("attn_linear/kda_chunk/" in chunk.as_text(debug_info=True)) == (
+        "attn_linear" in scopes)
+    assert "kda_chunk/" not in text
 
 
 def test_phases_tile_the_loop_and_stats_keep_their_keys(params):

@@ -288,6 +288,54 @@ def test_mixed_cache_fits_the_chip_at_the_cells_size(v5e, program):
         rf"= bf16\[1,{read},4608,8,128\]", text)
 
 
+# Solar Open 2's blocks at their published widths, the benchmark's cut:
+# one period of a gated softmax layer without positional embedding (64
+# query / 8 KV heads of 128) and three gated delta-rule layers (64 heads
+# of 128 x 128, a convolution of 4 taps, low-rank pairs of 128), a
+# sigmoid router over 320 experts of 4096 x 1280, 8 a token, 40 held,
+# one shared expert, 1/8 of the vocabulary, the head not tied.
+RECURRENT = llama.LlamaConfig(
+    vocab_size=24576, dim=4096, n_layers=4, n_heads=64, n_kv_heads=8,
+    head_width=128, mlp_dim=1280, max_seq=1048576, rope_theta=10000.0,
+    norm_eps=1e-5, num_experts=40, experts_per_token=8,
+    router_scoring="sigmoid", router_width=320, n_shared_experts=1,
+    full_rope=False, layer_kinds=("full", "linear", "linear", "linear"),
+    linear_heads=64, linear_head_dim=128, linear_rank=128, attn_gate=True)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_recurrent_state_fits_the_chip_at_the_cells_size(
+        v5e, monkeypatch, program):
+    """`solar-open2.digest` as it is served: 24 slots x 32,768, ONE
+    layer's slabs beside three layers' states (a slot 64 heads of 128 x
+    128 float32 and the convolution's 3 x 24,576 tail), prompts in
+    chunks of 512, the grouped kernel over the 40 experts held.  PR
+    28's sizing rule: the weights, ONE cache — every leaf, the states
+    among them, aliased to its output — and under one layer's slabs of
+    temporaries, inside the chip with room for the sampler."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, cache = _compile_step(
+        v5e.devices[0], program, RECURRENT, 24, 32768, chunk=512)
+    kept = {name: cache[name] for name in (
+        *llama.kv_slabs(RECURRENT), *llama.state_slabs(RECURRENT))}
+    assert {name: (leaf.shape, leaf.dtype.name)
+            for name, leaf in kept.items()} == {
+        "k": ((1, 24, 32768, 8, 128), "bfloat16"),
+        "v": ((1, 24, 32768, 8, 128), "bfloat16"),
+        "s": ((3, 24, 64, 128, 128), "float32"),
+        "conv": ((3, 24, 3, 24576), "bfloat16")}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    a_layer = _tree_bytes((cache["k"], cache["v"]))
+    assert mem.temp_size_in_bytes < a_layer
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < _tree_bytes(params) + _tree_bytes(kept) + a_layer
+    assert need < 13.0 * 2 ** 30
+    text = compiled.as_text()
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+
+
 # llama3-1b with its 2048 columns of attention as 16 heads of 128 (8 of
 # them KV heads: InternLM2-1.8B's attention), the head width of every
 # configuration the benchmark serves.
