@@ -25,8 +25,18 @@ vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
   call).  Slot reuse gives the same
   admit-new-work-each-step behavior as paged attention's block reuse.
 * **Continuous batching**: each `step()` admits queued prompts, runs at
-  most one prefill unit (a full bucketed prompt, or one chunk), then
+  most one prefill unit (a full bucketed prompt, or one chunk), and
   decodes every active slot in one batched call.
+* **Three step programs.**  When a chunk is due AND rows decode, the
+  iteration dispatches ONE program for both (``_mixed_step``,
+  models/llama.py `mixed_step`): the chunk's rows ride the decode step
+  through the layers, and every weight is read once where the chunk
+  program followed by the decode program read it twice.  With nothing
+  decoding the chunk runs alone (``_prefill_chunk``), with no chunk
+  due the decode step does (``_decode``).  What decides is what the
+  engine observes at that iteration, not an option — and the rows:
+  where slots + chunk pass ``RIDE_ROWS`` (a 512-token chunk) the chunk
+  program and the decode program run one after the other as before.
 * **One decode step ahead.**  The newest token of every slot stays on
   the device and the jitted sampler feeds it to the next step, so an
   iteration dispatches decode step N+1 and only then reads and emits
@@ -151,6 +161,21 @@ PHASES = ("drain", "admit", "chunk", "decode", "sample", "fetch", "emit",
 # its rows standing still: four times the longest step any benchmark
 # cell runs (62.6 ms).
 STALL_S = 0.25
+# A chunk rides a decode step (one program for both) while the step's
+# rows, slots + chunk, stay at or under this.  Two reasons, the second
+# the one that binds.  Under the chip's ridge (v5e: 197 T operations a
+# second over 819 GB/s = 240 a byte, so ~240 rows on 2-byte weights) a
+# step is bound by its weights' bytes and the chunk's rows come almost
+# free; above it the chunk is bound by its arithmetic and riding saves
+# only the decode step's read, the smaller part.  And a reply must not
+# depend on the company its prompt had: a row has to come out of the
+# mixed program with the bits its own program gives it.  On the chip
+# that holds at 76–112 rows in every configuration measured and NOT at
+# 528 / 536 — the compiler's products give the rows of a 512-token
+# chunk other float32 sums among 528 rows than among 512, a recurrent
+# state other roundings — which a greedy probe reads as another reply
+# (``benchmarks/mixed_step_bits.py``, PERF.md section 6, PR 39).
+RIDE_ROWS = 256
 
 
 class _PhaseRecorder:
@@ -490,6 +515,8 @@ class LLMEngine:
 
         # ---- chunked prefill + session state
         self._chunk_tokens = prefill_chunk_tokens
+        self._chunk_rides = (prefill_chunk_tokens is not None and
+                             slots + prefill_chunk_tokens <= RIDE_ROWS)
         self._decode_per_chunk = max(1, int(decode_steps_per_chunk))
         self._decode_since_chunk = self._decode_per_chunk  # 1st chunk runs now
         self._prefilling: list[_Seq] = []         # chunked-mode ingest queue
@@ -504,7 +531,7 @@ class LLMEngine:
         # Flat on purpose: readers on other threads take dict(stats),
         # a shallow copy under which a nested dict would alias.
         self.stats = {"tokens_generated": 0, "chunks": 0,
-                      "chunk_tokens": 0, "offloads": 0,
+                      "chunks_fused": 0, "chunk_tokens": 0, "offloads": 0,
                       "offload_bytes": 0, "restores": 0,
                       "restore_wait_s": 0.0, "restore_failures": 0,
                       "pressure_evictions": 0, "idle_evictions": 0}
@@ -533,6 +560,12 @@ class LLMEngine:
         def _decode(params, cache, last_tokens, active):
             return llama.decode_step(params, last_tokens, cache, cfg,
                                      active=active, mesh=eng_mesh)
+
+        def _mixed_step(params, cache, last_tokens, active, tokens, slot,
+                        start, length):
+            return llama.mixed_step(params, last_tokens, tokens, cache, cfg,
+                                    active, slot, start, length,
+                                    mesh=eng_mesh)
 
         # k, v — or c_kv, k_rope; a window model's rings beside them;
         # behind them what linear layers keep of a slot's sequence
@@ -568,12 +601,18 @@ class LLMEngine:
                                             keepdims=False)
 
         # one compile per prompt bucket (slot/length traced); ONE chunk
-        # variant (slot/start/length traced); one decode; one extract /
-        # install / row write / key read each (slot traced).
+        # variant (slot/start/length traced); one decode; one of a chunk
+        # and a decode step together; one extract / install / row write
+        # / key read each (slot traced).
         self._prefill_jit = jax.jit(_prefill, donate_argnums=(1,))
         self._prefill_chunk_jit = jax.jit(_prefill_chunk,
                                           donate_argnums=(1,))
         self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
+        self._mixed_step_jit = jax.jit(_mixed_step, donate_argnums=(1,))
+        # The mixed program has run (so: is compiled).  Set-up answers
+        # its requests one at a time and would never run it; the first
+        # chunk that runs alone runs it once, empty (``_chunk_alone``).
+        self._mixed_ran = False
         self._extract_jit = jax.jit(_extract)
         self._install_jit = jax.jit(_install, donate_argnums=(0,))
         self._put_row_jit = jax.jit(_put_row)
@@ -723,6 +762,15 @@ class LLMEngine:
         emit them, sweep idle sessions.  Returns outputs finished since
         the last call.
 
+        One of three step programs does the device's part: the mixed
+        step where a chunk is due and rows decode (the chunk rides
+        decode step N+1; a prompt that ends there reads its first token
+        behind step N's landing), the chunk program alone where nothing
+        decodes (a prompt that ends there joins the decode step of the
+        same iteration), the decode program alone where no chunk is
+        due.  A chunk too wide to ride (``RIDE_ROWS``) runs alone and
+        the decode step behind it, two programs an iteration.
+
         The decode step runs one ahead of the host: a token comes back
         from the ``step()`` after the one that dispatched it, and while
         a step is in flight ``has_unfinished()`` stays true, so a
@@ -742,10 +790,15 @@ class LLMEngine:
         rec.enter("admit")
         self._poll_restores()
         self._admit()
+        chunk = None
         if self._chunk_tokens is not None:
             rec.enter("chunk")
-            self._maybe_prefill_chunk()
-        self._decode()
+            chunk = self._next_chunk()
+            if chunk is not None and not (self._active and self._chunk_rides):
+                # nothing decodes beside it, or it is too wide to ride
+                self._chunk_alone(*chunk)
+                chunk = None
+        self._decode(chunk)
         rec.enter("housekeeping")
         self._sweep_idle()
 
@@ -964,11 +1017,13 @@ class LLMEngine:
         self._first_token(seq, last_logits)
         rec.enter("admit")            # back to the caller's phase
 
-    def _maybe_prefill_chunk(self):
-        """Run ONE chunk of ONE pending prompt — but only once
-        ``decode_steps_per_chunk`` decode steps have run since the last
-        chunk (decode for resident sessions stays smooth while a long
-        prompt trickles in).
+    def _next_chunk(self):
+        """The next chunk of ONE pending prompt: ``(seq, its tokens
+        padded to the chunk width, how many are real)``; the prompt
+        stays in the queue until ``_chunk_end`` — or None: nothing
+        waits, or fewer than ``decode_steps_per_chunk`` decode steps
+        have run since the last chunk (decode for resident sessions
+        stays smooth while a long prompt trickles in).
 
         Selection is shortest-remaining-prompt-first (FIFO tiebreak):
         a short interactive prompt's single chunk jumps ahead of a
@@ -978,29 +1033,55 @@ class LLMEngine:
         a sustained flood of short prompts will stall them; that is
         the intended bias for an interactive serving tier."""
         if not self._prefilling:
-            return
+            return None
         if self._active and \
                 self._decode_since_chunk < self._decode_per_chunk:
-            return
-        jnp = self._jnp
-        idx = min(range(len(self._prefilling)),
-                  key=lambda i: (len(self._prefilling[i].prompt)
-                                 - self._prefilling[i].prefill_done, i))
-        seq = self._prefilling.pop(idx)
+            return None
+        seq = min(self._prefilling,
+                  key=lambda s: len(s.prompt) - s.prefill_done)
         chunk = self._chunk_tokens
         part = seq.prompt[seq.prefill_done:seq.prefill_done + chunk]
         buf = np.zeros((chunk,), np.int32)
         buf[:len(part)] = part
+        return seq, self._jnp.asarray(buf), len(part)
+
+    def _chunk_alone(self, seq: _Seq, tokens, n: int):
+        """The chunk program by itself: no row decodes beside it.  The
+        first time, the mixed program runs once before it, EMPTY — no
+        row active, no token of the chunk real, at the slot's own
+        length on the device: slabs, states and lengths stay as they
+        are — so that it is compiled while requests still come one at a
+        time (a server's warm-up), not under the first prompt that
+        arrives while rows decode.  Its arguments are of the kinds a
+        real mixed step passes (the length read to the host: a Python
+        number like ``kv_len``, not the device's scalar), or the first
+        real one would miss the program's cache and compile again."""
+        if self._chunk_rides and not self._mixed_ran:
+            _, _, self.cache = self._mixed_step_jit(
+                self.params, self.cache, self._last,
+                self._jnp.asarray(np.zeros((self.slots,), bool)), tokens,
+                seq.slot, int(self.cache["length"][seq.slot]), 0)
+            self._mixed_ran = True
         logits, self.cache = self._prefill_chunk_jit(
-            self.params, self.cache, jnp.asarray(buf), seq.slot,
-            seq.kv_len, len(part))
+            self.params, self.cache, tokens, seq.slot, seq.kv_len, n)
+        self._chunk_dispatched(seq, n)
+        self._chunk_end(seq, logits)
+
+    def _chunk_dispatched(self, seq: _Seq, n: int):
+        """A program that ingests ``n`` tokens of ``seq`` — the chunk
+        program, or the mixed one — was dispatched: the host's books."""
         self._note_dispatch(seq)
-        self._note_recurrent(chunk, len(part), seq.kv_len == 0)
-        seq.prefill_done += len(part)
-        seq.kv_len += len(part)
+        self._note_recurrent(self._chunk_tokens, n, seq.kv_len == 0)
+        seq.prefill_done += n
+        seq.kv_len += n
         self._note_walk(seq.kv_len)
-        self._note_chunk(len(part))
+        self._note_chunk(n)
         self._decode_since_chunk = 0
+
+    def _chunk_end(self, seq: _Seq, logits):
+        """Behind a chunk's dispatch: a prompt's end leaves the queue
+        and reads its first token, any other goes to the queue's end."""
+        self._prefilling.remove(seq)
         if seq.prefill_done == len(seq.prompt):
             self._first_token(seq, logits)
         else:
@@ -1008,9 +1089,12 @@ class LLMEngine:
 
     def _first_token(self, seq: _Seq, logits):
         """A prompt's end: sample, read and emit its first token.  The
-        read stays where it was, in ``chunk``, and waits out the decode
-        step in flight before the prefill program, as the unpipelined
-        loop's did; the token enters the slot's row from the device."""
+        read stays where it was, in ``chunk``, and waits out the
+        program that made ``logits`` — the prefill program behind the
+        decode step in flight, or the mixed step, whose own tokens are
+        read an iteration later like any step's; the token enters the
+        slot's row from the device."""
+        self._rec.enter("chunk")
         token = self._sample_one(seq, logits)
         tok = int(self._rec.to_host(token)[0])
         self._rec.enter("emit")
@@ -1042,23 +1126,33 @@ class LLMEngine:
         self._active[slot] = seq
         self._active_dev = None
 
-    def _decode(self):
+    def _decode(self, chunk: tuple | None = None):
         """Dispatch step N+1, then read and emit step N: the host's
         turn-around and the transfer run under the device's step.  A
-        dispatch that fails still lands the step before it."""
+        dispatch that fails still lands the step before it.  ``chunk``
+        (``_next_chunk``'s, rows are active) rides step N+1, one
+        program; its prompt's end is read behind step N's landing, so
+        that step N's emit work runs under the mixed step too."""
         flight, self._flight = self._flight, None
+        logits = None
         try:
             if self._active:
-                self._flight = self._dispatch_decode(flight)
+                self._flight, logits = self._dispatch_decode(flight, chunk)
         finally:
             if flight is not None:
                 self._land(flight)
+        if chunk is not None:
+            self._chunk_end(chunk[0], logits)
 
-    def _dispatch_decode(self, flight: tuple | None) -> tuple:
+    def _dispatch_decode(self, flight: tuple | None,
+                         chunk: tuple | None = None) -> tuple:
         """One decode step and its sampler for the rows of ``_active``,
         fed from the device's token table; no read.  ``flight`` is the
-        step before it where that is still unread.  Returns the flight
-        record."""
+        step before it where that is still unread.  With ``chunk``
+        (``_next_chunk``'s) the step is the mixed program: the chunk's
+        rows behind the decode rows, the weights read once for both.
+        Returns the flight record and the chunk's logits (None without
+        one)."""
         rec, stats = self._rec, self.stats
         rec.enter("decode")
         if self._active_dev is None:
@@ -1067,8 +1161,18 @@ class LLMEngine:
             self._active_dev = self._jnp.asarray(mask)
             self._rows = tuple(self._active.items())
         rows = self._rows
-        logits, self.cache = self._decode_jit(
-            self.params, self.cache, self._last, self._active_dev)
+        chunk_logits = None
+        if chunk is None:
+            logits, self.cache = self._decode_jit(
+                self.params, self.cache, self._last, self._active_dev)
+        else:
+            seq, tokens, n = chunk
+            logits, chunk_logits, self.cache = self._mixed_step_jit(
+                self.params, self.cache, self._last, self._active_dev,
+                tokens, seq.slot, seq.kv_len, n)
+            self._mixed_ran = True
+            self._chunk_dispatched(seq, n)
+            stats["chunks_fused"] += 1
         rec.dispatched = True
         stats["decode_steps"] += 1
         stats["decode_slots"] += len(rows)
@@ -1095,7 +1199,7 @@ class LLMEngine:
                 # known now, so the next step's mask leaves the row out
                 del self._active[slot]
                 self._active_dev = None
-        return sampled, rows
+        return (sampled, rows), chunk_logits
 
     def _land(self, flight: tuple):
         """The one read of a dispatched decode step, and its tokens to
@@ -1822,7 +1926,8 @@ class EngineLoop:
     def _run(self):
         """Each iteration with work is one ``engine`` step of the
         recorder — drain, the engine's own phases (dispatch decode step
-        N+1, read step N, emit step N), housekeeping — and a stretch
+        N+1, with the chunk that is due among its rows; read step N,
+        emit step N), housekeeping — and a stretch
         without work is one ``idle_wait`` phase, however many times the
         wait wakes.  A decode step in flight is work: the iteration
         after a batch's last dispatch reads and emits its tokens, and a

@@ -706,8 +706,28 @@ def _attend_latent_rows(xq, c_kv, k_rope, w_kvb, c: LlamaConfig):
     return out.astype(xq.dtype)
 
 
-def _swiglu(h, w_gate, w_up, w_down):
-    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+def _rounded(x, dtype):
+    """float32 ``x`` rounded to ``dtype`` by an operation of its own,
+    which no compiler setting takes out (a bare ``astype`` it may)."""
+    info = jnp.finfo(dtype)
+    return lax.reduce_precision(x, info.nexp, info.nmant).astype(dtype)
+
+
+def _swiglu(h, w_gate, w_up, w_down, step: bool = False):
+    """``step``: in a step program the gate's and the up product's
+    rounding to the activations' dtype is spelt out.  The TPU compiler
+    fuses ONE of the two products with the elementwise tail and then
+    keeps that one's float32 sums unrounded (its "excess precision");
+    which one depends on the rows — the gate's for 12 decode rows, the
+    up product's for 76 — so a row's bits depended on the program it
+    went through (InternLM2: decode logits 2.6e-2 off, PERF.md section
+    6, PR 39).  Rounded by name both are what the equations say, in
+    every program.  Training keeps the bare form."""
+    if not step:
+        return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+    gate, up = (_rounded(jnp.dot(h, w, preferred_element_type=jnp.float32),
+                         h.dtype) for w in (w_gate, w_up))
+    return (jax.nn.silu(gate) * up) @ w_down
 
 
 def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
@@ -715,17 +735,19 @@ def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
     the routed experts, with the shared expert beside them where the
     model has one.  Returns ``(out, load)``; ``load`` is the
     (num_experts,) int32 count of rows each expert held was given, None
-    for a dense layer.  The one MLP of training, chunks and decode."""
+    for a dense layer.  The one MLP of training, chunks and decode; a
+    step program's (it alone passes ``index``) spells its roundings out
+    (``_swiglu``)."""
     if not c.num_experts:
         return _swiglu(h, layer["w_gate"], layer["w_up"],
-                       layer["w_down"]), None
+                       layer["w_down"], index is not None), None
     out, load = _routed_mlp(layer, h, c, index, tile)
     if c.n_shared_experts:
         with jax.named_scope("moe_shared"):
             # one SwiGLU as wide as all the shared experts together is
             # the sum of theirs; averaged, that sum over their number
             shared = _swiglu(h, layer["shared_gate"], layer["shared_up"],
-                             layer["shared_down"])
+                             layer["shared_down"], index is not None)
             if c.shared_experts_average:
                 shared = shared / c.n_shared_experts
             out = out + shared
@@ -1139,8 +1161,9 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
 # cheap only while nothing copies them (moved about, they were 59 % of
 # the step programs' device time on a v5e): the step programs carry them
 # through the layer loop and write the new rows in place
-# (``_scan_layers``).  Both run ``apply_block``; what is theirs is which
-# rows they write and which slab they attend over.
+# (``_scan_layers``).  All three — a chunk, a decode step, and the two
+# as one (``mixed_step``) — run ``apply_block``; what is theirs is which
+# rows they write and which slab they attend over (``_row_groups``).
 
 def kv_slabs(config: LlamaConfig) -> dict:
     """What a layer keeps of a position: the cache's slab leaves by
@@ -1225,7 +1248,7 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     return cache
 
 
-# What ``prefill_chunk_into_cache`` and ``decode_step`` count of a routed
+# What the step programs count of a routed
 # model's routing, summed over layers and executions in
 # ``cache["routing"]`` (uint32, wraps; a reader takes differences).  They
 # count the rows the program computed, padded and idle ones included:
@@ -1239,7 +1262,8 @@ ROUTING_COUNTERS = (
     "moe_rows_routed",    # (row, expert) pairs routed: rows * k, held or not
     # The same of the decode steps alone: a chunk's rows are ONE
     # sequence's and route alike, so what a decode step reads cannot be
-    # told from counters that a window's share of chunks moves.
+    # told from counters that a window's share of chunks moves.  A
+    # mixed step carries a chunk: it counts above, not here.
     "moe_decode_assignments", "moe_decode_experts_hit",
     "moe_decode_expert_slots", "moe_decode_rows_routed",
     # rows of the row tiles the grouped kernel visited (its work list's
@@ -1605,6 +1629,171 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                       **_count_routing(cache, loads, routed, decode, tile)}
 
 
+def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
+                chunk_len):
+    """What the ``chunk`` rows of ONE prompt in ``slot`` — ``chunk_len``
+    real tokens from absolute position ``start`` on, the rest padding —
+    do with a layer, for ``_row_groups``: ``(rows, their positions,
+    write, attend, state)``."""
+    max_seq = _slab_positions(cache, c)
+    offs = jnp.arange(chunk, dtype=jnp.int32)
+    pos = start + offs                           # (chunk,) absolute
+    # Pad tokens' writes land out of bounds (``_rows_of``) → dropped by
+    # the scatter; their values never reach the slab or the masked
+    # attention.
+    top = start + chunk_len - 1                  # the newest position
+    # by the rows of a slab: max_seq, and a window layer's ring
+    lengths = {cache[name].shape[2] for name in kv_slabs(c)}
+    write_pos = {n: _rows_of(pos, offs < chunk_len, n, max_seq)
+                 for n in lengths}
+    blocks = {n: _span_blocks(top + 1, n) for n in lengths}
+
+    def write(ks, vs, i, xk, xv):
+        """The chunk's real rows into (layer i, slot)."""
+        n = ks.shape[2]
+        return (ks.at[i, slot, write_pos[n]].set(xk.astype(ks.dtype)),
+                vs.at[i, slot, write_pos[n]].set(xv.astype(vs.dtype)))
+
+    def attend(ks, vs, i, window, xq, w_kvb=None):
+        """Over that slot's slab — a window layer's ring — causally by
+        absolute position, as far as the chunk's own end."""
+        return _attend_slab(xq, ks, vs, i, slot, pos, blocks[ks.shape[2]],
+                            c, w_kvb, window, top)
+
+    def state(s, conv, i, u, g, beta, conv_w):
+        """A linear layer: the chunk from (layer i, slot)'s state — from
+        an EMPTY one where the prompt begins there (``start`` 0: what
+        the slot's last occupant left is never read) — and back goes the
+        state behind the chunk's last REAL token: padding neither
+        decays nor writes, and the convolution's tail is the last
+        real inputs."""
+        real = offs < chunk_len
+        s0 = jnp.where(start == 0, 0.0, s[i, slot])
+        tail = jnp.where(start == 0, 0, conv[i, slot]).astype(conv.dtype)
+        y, ext = delta_rule.causal_conv(u.astype(conv.dtype), tail, conv_w)
+        out, s1 = delta_rule.chunk_delta_rule(
+            *_linear_qkv(y, c), jnp.where(real[:, None, None], g, 0.0),
+            jnp.where(real[:, None], beta, 0.0), s0)
+        tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
+        return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
+
+    return chunk, pos, write, attend, state
+
+
+def _decode_rows(cache: dict, c: LlamaConfig, active):
+    """What a decode step's rows — one a slot, those ``active`` live —
+    do with a layer, for ``_row_groups``: ``(rows, their positions,
+    write, attend, state)``."""
+    max_seq = _slab_positions(cache, c)
+    pos = cache["length"]                       # (slots,) write position
+    # The walk ends behind the longest ACTIVE row: an idle slot that
+    # holds a resident session's long slab does not lengthen it.
+    longest = jnp.max(jnp.where(active, pos, 0)) + 1
+    slots = jnp.arange(pos.shape[0])
+    # by the rows of a slab (max_seq, and a window layer's ring).
+    # Inactive slots' scatter writes are pushed out of bounds (and
+    # dropped), as a full slot's are; their lengths hold still.
+    lengths = {cache[name].shape[2] for name in kv_slabs(c)}
+    write_pos = {n: _rows_of(pos, active, n, max_seq) for n in lengths}
+    blocks = {n: _span_blocks(longest, n) for n in lengths}
+
+    def write(ks, vs, i, xk, xv):
+        """One row a slot into layer i."""
+        n = ks.shape[2]
+        return (ks.at[i, slots, write_pos[n]].set(xk.astype(ks.dtype)),
+                vs.at[i, slots, write_pos[n]].set(xv.astype(vs.dtype)))
+
+    def attend(ks, vs, i, window, xq, w_kvb=None):
+        """Over the layer's slabs (a window layer's rings), each slot up
+        to its own position."""
+        return _attend_slab(xq, ks, vs, i, None, pos, blocks[ks.shape[2]],
+                            c, w_kvb, window, pos)
+
+    def state(s, conv, i, u, g, beta, conv_w):
+        """A linear layer: one token a slot from layer i's states; a
+        slot that is not ``active`` — free, or between two chunks of
+        its own prompt — keeps its state and its tail bit for bit."""
+        y, tail = delta_rule.causal_conv_step(u, conv[i], conv_w)
+        out, new = delta_rule.delta_rule_step(
+            *_linear_qkv(y, c), g, beta, s[i], active)
+        tail = jnp.where(active[:, None, None], tail, conv[i])
+        return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),
+                     lax.dynamic_update_index_in_dim(conv, tail, i, 0))
+
+    return pos.shape[0], pos, write, attend, state
+
+
+def _row_groups(*groups):
+    """``_scan_layers``' ``(positions, write_attend, write_state)`` for
+    a step program whose rows are ``groups`` laid end to end
+    (``_decode_rows``, ``_chunk_rows``): a layer's rows are split where
+    the groups meet, EVERY group writes its rows into the carried slabs
+    first, then each attends over them as it does alone, one group
+    after the other, and the outputs are joined again — the groups'
+    slots are disjoint, so the writes do not meet and a group reads
+    what it would read alone.  A linear layer's states go through the
+    groups in turn.  One group is the group itself: nothing is split or
+    joined."""
+    edges = [0]
+    for rows, *_ in groups:
+        edges.append(edges[-1] + rows)
+
+    def split(x):
+        if len(groups) == 1:
+            return [x]
+        return [x[a:b] for a, b in zip(edges, edges[1:])]
+
+    def join(parts):
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    def write_attend(ks, vs, i, window, xq, xk, xv, w_kvb=None):
+        for (_, _, write, _, _), k, v in zip(groups, split(xk), split(xv)):
+            ks, vs = write(ks, vs, i, k, v)
+        outs = []
+        for (_, _, _, attend, _), q in zip(groups, split(xq)):
+            if outs:
+                # One walk hands the slabs to the next: as two readers
+                # of one array the TPU compiler gave both walks a copy
+                # of the WHOLE slabs in the products' layout, a layer —
+                # 1 GiB where a block at a time is re-laid
+                # (``tests/test_tpu_compile.py`` holds that no program
+                # moves a slab).  The barrier computes nothing.
+                outs[-1], ks, vs = lax.optimization_barrier(
+                    (outs[-1], ks, vs))
+            outs.append(attend(ks, vs, i, window, q, w_kvb))
+        return join(outs), (ks, vs)
+
+    def write_state(s, conv, i, u, g, beta, conv_w):
+        outs = []
+        for (*_, state), *mine in zip(groups, split(u), split(g),
+                                      split(beta)):
+            out, (s, conv) = state(s, conv, i, *mine, conv_w)
+            outs.append(out)
+        return join(outs), (s, conv)
+
+    return (join([pos for _, pos, *_ in groups]), write_attend,
+            write_state)
+
+
+def _head(params: dict, x, c: LlamaConfig):
+    """Normed rows -> their logits, float32."""
+    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
+    return (x @ head.astype(c.dtype)).astype(jnp.float32)
+
+
+def _logits(params: dict, x, c: LlamaConfig):
+    """Rows behind the layers -> their logits."""
+    return _head(params, _norm(x, params["norm_f"], c), c)
+
+
+def _chunk_logits(params: dict, x, chunk_len, c: LlamaConfig):
+    """A chunk's rows behind the layers -> the logits (vocab,) at its
+    last REAL token: the rows normed, that one's multiplied with the
+    head as one vector."""
+    return _head(params, jnp.take(_norm(x, params["norm_f"], c),
+                                  jnp.maximum(chunk_len - 1, 0), axis=0), c)
+
+
 def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
                              start, chunk_len, config: LlamaConfig, *,
                              mesh=None):
@@ -1631,60 +1820,24 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     sharded over, if any (``_grouped_tile``).
     """
     c = config
-    chunk = tokens.shape[0]
-    max_seq = _slab_positions(cache, c)
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
-    offs = jnp.arange(chunk, dtype=jnp.int32)
-    pos = start + offs                           # (chunk,) absolute
-    # Pad tokens' writes land out of bounds (``_rows_of``) → dropped by
-    # the scatter; their values never reach the slab or the masked
-    # attention.
-    top = start + chunk_len - 1                  # the newest position
-    # by the rows of a slab: max_seq, and a window layer's ring
-    lengths = {cache[name].shape[2] for name in kv_slabs(c)}
-    write_pos = {n: _rows_of(pos, offs < chunk_len, n, max_seq)
-                 for n in lengths}
-    blocks = {n: _span_blocks(top + 1, n) for n in lengths}
-
-    def write_chunk(ks, vs, i, window, xq, xk, xv, w_kvb=None):
-        """The chunk's real rows into (layer i, slot); attend over that
-        slot's slab — a window layer's ring — causally by absolute
-        position, as far as the chunk's own end."""
-        n = ks.shape[2]
-        ks = ks.at[i, slot, write_pos[n]].set(xk.astype(ks.dtype))
-        vs = vs.at[i, slot, write_pos[n]].set(xv.astype(vs.dtype))
-        return _attend_slab(xq, ks, vs, i, slot, pos, blocks[n], c, w_kvb,
-                            window, top), (ks, vs)
-
-    def chunk_state(s, conv, i, u, g, beta, conv_w):
-        """A linear layer: the chunk from (layer i, slot)'s state — from
-        an EMPTY one where the prompt begins there (``start`` 0: what
-        the slot's last occupant left is never read) — and back goes the
-        state behind the chunk's last REAL token: padding neither
-        decays nor writes, and the convolution's tail is the last
-        real inputs."""
-        real = offs < chunk_len
-        s0 = jnp.where(start == 0, 0.0, s[i, slot])
-        tail = jnp.where(start == 0, 0, conv[i, slot]).astype(conv.dtype)
-        y, ext = delta_rule.causal_conv(u.astype(conv.dtype), tail, conv_w)
-        out, s1 = delta_rule.chunk_delta_rule(
-            *_linear_qkv(y, c), jnp.where(real[:, None, None], g, 0.0),
-            jnp.where(real[:, None], beta, 0.0), s0)
-        tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
-        return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
-
     x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
-    x, written = _scan_layers(params, x, cache, c, pos, write_chunk,
-                              chunk_state, decode=False, mesh=mesh)
-    x = _norm(x, params["norm_f"], c)
-    x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = (x_last @ head.astype(c.dtype)).astype(jnp.float32)
+    x, written = _scan_layers(
+        params, x, cache, c, *_row_groups(_chunk_rows(
+            cache, c, tokens.shape[0], slot, start, chunk_len)),
+        decode=False, mesh=mesh)
     cache = {**written,
              "length": cache["length"].at[slot].set(start + chunk_len)}
-    return logits, cache
+    return _chunk_logits(params, x, chunk_len, c), cache
+
+
+def _stepped(length, active, max_seq: int):
+    """A decode step's new lengths: + 1 where ``active``, clamped so a
+    full slot never indexes past its slab."""
+    return jnp.where(active, jnp.minimum(length + 1, jnp.int32(max_seq)),
+                     length)
 
 
 def decode_step(params: dict, last_tokens, cache: dict,
@@ -1701,49 +1854,63 @@ def decode_step(params: dict, last_tokens, cache: dict,
     (``_grouped_tile``).
     """
     c = config
-    max_seq = _slab_positions(cache, c)
-    pos = cache["length"]                       # (slots,) write position
-    # The walk ends behind the longest ACTIVE row: an idle slot that
-    # holds a resident session's long slab does not lengthen it.
-    longest = jnp.max(jnp.where(active, pos, 0)) + 1
-    slots = jnp.arange(last_tokens.shape[0])
-    # by the rows of a slab (max_seq, and a window layer's ring).
-    # Inactive slots' scatter writes are pushed out of bounds (and
-    # dropped), as a full slot's are; their lengths hold still below.
-    lengths = {cache[name].shape[2] for name in kv_slabs(c)}
-    write_pos = {n: _rows_of(pos, active, n, max_seq) for n in lengths}
-    blocks = {n: _span_blocks(longest, n) for n in lengths}
-
-    def write_one(ks, vs, i, window, xq, xk, xv, w_kvb=None):
-        """One row a slot into layer i; attend over the layer's slabs
-        (a window layer's rings), each slot up to its own position."""
-        n = ks.shape[2]
-        ks = ks.at[i, slots, write_pos[n]].set(xk.astype(ks.dtype))
-        vs = vs.at[i, slots, write_pos[n]].set(xv.astype(vs.dtype))
-        return _attend_slab(xq, ks, vs, i, None, pos, blocks[n], c, w_kvb,
-                            window, pos), (ks, vs)
-
-    def step_state(s, conv, i, u, g, beta, conv_w):
-        """A linear layer: one token a slot from layer i's states; a
-        slot that is not ``active`` — free, or between two chunks of
-        its own prompt — keeps its state and its tail bit for bit."""
-        y, tail = delta_rule.causal_conv_step(u, conv[i], conv_w)
-        out, new = delta_rule.delta_rule_step(
-            *_linear_qkv(y, c), g, beta, s[i], active)
-        tail = jnp.where(active[:, None, None], tail, conv[i])
-        return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),
-                     lax.dynamic_update_index_in_dim(conv, tail, i, 0))
-
     x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
-    x, written = _scan_layers(params, x, cache, c, pos, write_one,
-                              step_state, decode=True, mesh=mesh)
-    x = _norm(x, params["norm_f"], c)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
-    # Clamped so a full slot never indexes past its slab.
-    new_len = jnp.where(active,
-                        jnp.minimum(pos + 1, jnp.int32(max_seq)), pos)
-    return logits, {**written, "length": new_len}
+    x, written = _scan_layers(
+        params, x, cache, c, *_row_groups(_decode_rows(cache, c, active)),
+        decode=True, mesh=mesh)
+    return _logits(params, x, c), {**written, "length": _stepped(
+        cache["length"], active, _slab_positions(cache, c))}
+
+
+def mixed_step(params: dict, last_tokens, tokens, cache: dict,
+               config: LlamaConfig, active, slot, start, chunk_len, *,
+               mesh=None):
+    """A decode step AND one prompt's chunk as one program: the
+    ``slots`` decode rows (``decode_step``'s ``last_tokens`` and
+    ``active``) followed by the chunk's rows
+    (``prefill_chunk_into_cache``'s ``tokens``, ``slot``, ``start`` and
+    ``chunk_len``) go through the layers together, ONE ``_scan_layers``
+    pass over ``slots + chunk`` rows, so that norms, projections, the
+    dense or the routed feed-forward (one top-k, one sort, one grouped
+    product over the experts either hit, its row tile from the summed
+    rows) and the shared experts read their weights once (the head
+    twice: see below).
+    Only the attention — and a linear layer's state — knows which rows
+    it has (``_row_groups``): each part writes and attends exactly as
+    its own program does.  The chunk's slot is not an ``active`` row
+    (the engine's: a prompt decodes once it is ingested).
+
+    Returns (the decode rows' logits (slots, vocab) fp32, the chunk's
+    logits at its last real token (vocab,) fp32, the cache with
+    ``length`` advanced for the active rows and set for the chunk's
+    slot).  A routed model's counters count it as one execution among
+    all, not among the decode steps (``ROUTING_COUNTERS``).  With no
+    row active and ``chunk_len`` 0 at the slot's own length it leaves
+    slabs, states and lengths as they are.
+    """
+    c = config
+    slots = last_tokens.shape[0]
+    slot = jnp.asarray(slot, jnp.int32)
+    start = jnp.asarray(start, jnp.int32)
+    chunk_len = jnp.asarray(chunk_len, jnp.int32)
+    x = params["embed"][jnp.concatenate([last_tokens, tokens])].astype(
+        c.dtype)                                 # (slots + chunk, dim)
+    x, written = _scan_layers(
+        params, x, cache, c, *_row_groups(
+            _decode_rows(cache, c, active),
+            _chunk_rows(cache, c, tokens.shape[0], slot, start, chunk_len)),
+        decode=False, mesh=mesh)
+    # Behind the layers each part goes on as in its own program — the
+    # decode rows normed and multiplied with the head together, the
+    # chunk's rows normed and its last real token's multiplied as one
+    # vector — and not the rows as one operand: on the chip the one-row
+    # product differs from a row of a many-row product in every logit
+    # (1.6e-3, PERF.md section 6, PR 39), and a token must not depend
+    # on whether its prompt ended in company.
+    length = _stepped(cache["length"], active, _slab_positions(cache, c))
+    return (_logits(params, x[:slots], c),
+            _chunk_logits(params, x[slots:], chunk_len, c),
+            {**written, "length": length.at[slot].set(start + chunk_len)})
 
 
 # ---------------------------------------------------------------- generate

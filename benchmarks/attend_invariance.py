@@ -3,11 +3,15 @@ logits for that row, are bit-equal whatever bound the OTHER rows'
 lengths set for the walk over the cache (``llama._attend_slab``) — what
 ``tests/test_llama.py`` holds on the CPU at test size, here at Mistral's,
 OLMoE's and A.X-K1's widths and slabs (two layers each, random weights
-and cache).  One JSON line a shape; through the chip tool, from the root:
+and cache); and the mixed step's two walks (PR 39: ``_row_groups`` of a
+decode step's rows and a chunk's) each give their rows what the same
+walk gives them alone, to the bit, whatever the other part's lengths.
+One JSON line a shape; through the chip tool, from the root:
 
     python -m benchmarks.attend_invariance
 """
 
+import functools
 import json
 
 import jax
@@ -77,6 +81,45 @@ def check(name, c, slots, max_seq):
     on = [True] * slots
     a, b, d = (logits(short, on), logits(long_, on),
                logits(long_, on[:-1] + [False]))
+
+    # the mixed step's two walks (PR 39): a chunk of 64 rows in the last
+    # slot behind the decode rows — each part's output against the same
+    # part alone, whatever the OTHER part's lengths make its walk
+    chunk, ride = 64, slots - 1
+    fresh = [jax.random.normal(k, (slots + chunk, *position),
+                               jnp.float32).astype(c.dtype)
+             for k, position in zip(jax.random.split(key, 2),
+                                    llama.kv_slabs(c).values())]
+    xq2 = jax.random.normal(jax.random.PRNGKey(5), (
+        slots + chunk, c.n_heads, c.head_dim), jnp.float32).astype(c.dtype)
+    resting = jnp.asarray(on[:-1] + [False])
+
+    def walks(slabs, lengths, start, n, parts):
+        held = {**cache, **dict(zip(names, slabs)), "length": lengths}
+        groups = (llama._decode_rows(held, c, resting),
+                  llama._chunk_rows(held, c, chunk, ride, start, n))
+        lo, hi = {"both": (0, slots + chunk), "decode": (0, slots),
+                  "chunk": (slots, slots + chunk)}[parts]
+        _, write_attend, _ = llama._row_groups(*(
+            groups if parts == "both" else groups[parts == "chunk":][:1]))
+        return write_attend(held[names[0]], held[names[1]], 1, 0, xq2[lo:hi],
+                            fresh[0][lo:hi], fresh[1][lo:hi], w_kvb)[0]
+
+    walks = functools.partial(jax.jit(walks, static_argnums=4), tuple(
+        cache[name] for name in names))   # arguments, not constants
+    rows = jnp.asarray(short, jnp.int32)
+    far = jnp.asarray(long_[:-2] + [max_seq - 2, 300], jnp.int32)
+    decode_alone = walks(rows, 0, chunk, "decode")
+    chunk_alone = {(at, n): walks(rows, at, n, "chunk")
+                   for at, n in ((0, chunk), (max_seq - 200, 40))}
+    same_decode_rows = all(
+        (bits(walks(rows, at, n, "both")[0]) == bits(decode_alone[0])).all()
+        for at, n in chunk_alone)
+    same_chunk_rows = all(
+        (bits(walks(lengths, at, n, "both")[slots:slots + n])
+         == bits(alone[:n])).all()
+        for (at, n), alone in chunk_alone.items()
+        for lengths in (rows, far))
     print(json.dumps({
         "shape": name, "slots": slots, "max_seq": max_seq, "blocks": total,
         "attention_row0_bit_equal_at_its_own_bound_one_more_and_all":
@@ -85,7 +128,11 @@ def check(name, c, slots, max_seq):
             bool((bits(a[0]) == bits(b[0])).all()),
         "decode_step_row0_bit_equal_short_vs_long_inactive":
             bool((bits(a[0]) == bits(d[0])).all()),
-        "max_abs_diff_logits": float(jnp.max(jnp.abs(a[0] - b[0])))}),
+        "max_abs_diff_logits": float(jnp.max(jnp.abs(a[0] - b[0]))),
+        "mixed_walks_decode_row0_bit_equal_to_the_decode_walk_alone":
+            bool(same_decode_rows),
+        "mixed_walks_chunk_rows_bit_equal_to_the_chunk_walk_alone":
+            bool(same_chunk_rows)}),
         flush=True)
 
 

@@ -432,6 +432,161 @@ def test_a_stop_costs_one_dropped_row_and_it_reaches_nobody(params, chunk):
     assert eng._flight is None and not eng._active
 
 
+def _watch_programs(eng):
+    """Every step program ``eng`` dispatches from now on, by name — a
+    mixed step with no real token "empty" — in a list."""
+    seen = []
+
+    def watch(name, program):
+        def watched(*args):
+            seen.append("empty" if name == "mixed" and not int(args[-1])
+                        else name)
+            return program(*args)
+        return watched
+
+    for name, attr in (("chunk", "_prefill_chunk_jit"),
+                       ("decode", "_decode_jit"),
+                       ("mixed", "_mixed_step_jit")):
+        setattr(eng, attr, watch(name, getattr(eng, attr)))
+    return seen
+
+
+LONG = list(range(40, 59))                   # 19 tokens: chunks of 8, 8, 3
+
+
+def test_a_chunk_rides_the_decode_step_it_shares(params):
+    """Prompts that arrive while rows decode (PR 39): an iteration
+    dispatches ONE step program, the mixed one — the chunk's rows behind
+    the decode rows — where it dispatched the chunk program and then the
+    decode program.  The counters as documented: a mixed step is a
+    decode step (``decode_steps``, ``decode_slots``,
+    ``decode_ahead_steps``) and a chunk (``chunks``, ``_prefills``), and
+    ``chunks_fused`` counts it; reads are one a decode step and one a
+    prompt's end, as before; the streams are those of each request
+    alone."""
+    want = [_pinned_engine(params, 8, slots=1).generate(
+        [p], _sampling(seed, n))[0].token_ids
+        for p, seed, n in (([10, 20, 30], 11, 14), (LONG, 99, 5),
+                           ([7, 8, 9], None, 4))]
+    eng = _pinned_engine(params, 8, slots=3)
+    seen = _watch_programs(eng)
+    outs, per_step = {}, []
+
+    def step():
+        del seen[:]
+        for out in eng.step():
+            outs[out.request_id] = out
+        per_step.append(list(seen))
+
+    eng.add_request([10, 20, 30], _sampling(11, 14), request_id="a",
+                    admit=False)
+    step()
+    # alone: the chunk program — before it, once, the mixed one, empty,
+    # so that it is compiled — and the prompt's end joins a decode step
+    assert per_step == [["empty", "chunk", "decode"]]
+    step()
+    assert per_step[-1] == ["decode"] and eng.stats["chunks_fused"] == 0
+    eng.add_request(LONG, _sampling(99, 5), request_id="b", admit=False)
+    eng.add_request([7, 8, 9], _sampling(None, 4), request_id="c",
+                    admit=False)
+    before = dict(eng.stats)
+    prefills = eng._prefills
+    while eng.has_unfinished():
+        step()
+    # shortest first: c's one chunk, then b's three, each on a step
+    assert per_step[2:6] == [["mixed"]] * 4
+    assert all(programs in (["decode"], []) for programs in per_step[6:])
+    stats = eng.stats
+    assert stats["chunks_fused"] == 4 == stats["chunks"] - before["chunks"]
+    assert eng._prefills - prefills == 4
+    assert stats["chunk_tokens"] == 3 + 19 + 3
+    assert stats["decode_steps"] == sum(
+        programs[-1:] in (["decode"], ["mixed"]) for programs in per_step)
+    assert stats["decode_ahead_steps"] == stats["decode_steps"] - 1
+    assert stats["d2h_syncs"] == stats["decode_steps"] + 3
+    assert stats["decode_slots"] == stats["tokens_generated"] \
+        == 13 + 4 + 3
+    assert stats["steps"] == sum(bool(programs) for programs in per_step)
+    assert [outs[rid].token_ids for rid in "abc"] == want
+    assert "empty" not in sum(per_step[1:], [])
+
+
+def test_decode_steps_per_chunk_counts_a_mixed_step_as_a_step(params):
+    """``decode_steps_per_chunk=2``: a chunk rides every second step —
+    the mixed step is the first decode step after its own chunk."""
+    eng = LLMEngine(CFG, params, slots=2, max_seq=96,
+                    prefill_chunk_tokens=8, decode_steps_per_chunk=2)
+    seen = _watch_programs(eng)
+    eng.add_request([10, 20, 30], SamplingParams(max_tokens=12),
+                    admit=False)
+    eng.step()
+    eng.add_request(LONG, SamplingParams(max_tokens=2), admit=False)
+    order = []
+    while eng.has_unfinished():
+        del seen[:]
+        eng.step()
+        order.extend(seen)
+    assert order[:6] == ["decode", "mixed", "decode", "mixed", "decode",
+                         "mixed"]
+    assert eng.stats["chunks_fused"] == 3
+
+
+def test_the_empty_mixed_step_is_the_compilation_of_the_real_ones(params):
+    """The mixed program that the first lone chunk runs empty is the
+    one every later mixed step finds compiled: one entry in its cache,
+    whatever rode (a server must not compile under its first prompt
+    that meets decoding rows — the benchmark counts it as a
+    compilation inside the window)."""
+    eng = _pinned_engine(params, 8, slots=3)
+    eng.add_request([10, 20, 30], _sampling(11, 14), admit=False)
+    eng.step()
+    assert eng._mixed_step_jit._cache_size() == 1
+    assert eng.stats["chunks_fused"] == 0
+    eng.add_request(LONG, _sampling(99, 5), admit=False)
+    eng.add_request([7, 8, 9], _sampling(None, 4), admit=False)
+    while eng.has_unfinished():
+        eng.step()
+    assert eng.stats["chunks_fused"] == 4
+    assert eng._mixed_step_jit._cache_size() == 1
+    assert eng._prefill_chunk_jit._cache_size() == 1
+    assert eng._decode_jit._cache_size() == 1
+
+
+@pytest.mark.parametrize("rows, rides", [(10, True), (9, False)])
+def test_a_chunk_too_wide_to_ride_runs_alone(params, monkeypatch, rows,
+                                             rides):
+    """``RIDE_ROWS``: where slots + chunk pass it the iteration keeps
+    the two programs, the chunk and then the decode step, and the mixed
+    one is never run — not even empty; at it the chunk rides.  The
+    streams are the same either way."""
+    monkeypatch.setattr(engine_mod, "RIDE_ROWS", rows)
+    eng = _pinned_engine(params, 8, slots=2)
+    seen = _watch_programs(eng)
+    eng.add_request([10, 20, 30], _sampling(11, 14), request_id="a",
+                    admit=False)
+    eng.step()
+    assert seen == (["empty"] if rides else []) + ["chunk", "decode"]
+    eng.add_request(LONG, _sampling(99, 5), request_id="b", admit=False)
+    outs, order = {}, []
+    while eng.has_unfinished():
+        del seen[:]
+        for out in eng.step():
+            outs[out.request_id] = out
+        order.append(list(seen))
+    if rides:
+        assert order[:3] == [["mixed"]] * 3
+        assert eng.stats["chunks_fused"] == 3
+    else:
+        assert order[:3] == [["chunk", "decode"]] * 3
+        assert eng.stats["chunks_fused"] == 0
+        assert "mixed" not in sum(order, [])
+    assert eng.stats["chunks"] == 4
+    want = [_pinned_engine(params, 8, slots=1).generate(
+        [p], _sampling(seed, n))[0].token_ids
+        for p, seed, n in (([10, 20, 30], 11, 14), (LONG, 99, 5))]
+    assert [outs[rid].token_ids for rid in "ab"] == want
+
+
 def test_prompt_longer_than_bucket(params):
     engine = LLMEngine(CFG, params, slots=1, max_seq=128)
     prompt = list(np.random.RandomState(0).randint(1, 200, 50))

@@ -153,10 +153,11 @@ def test_a_step_is_dispatched_before_the_step_before_it_is_read(
     log, phases, flights = [], [], []
     dispatch, land, enter = eng._dispatch_decode, eng._land, eng._rec.enter
 
-    def spy_dispatch(ahead):
-        flights.append(dispatch(ahead))
+    def spy_dispatch(ahead, chunk=None):
+        flight, chunk_logits = dispatch(ahead, chunk)
+        flights.append(flight)
         log.append(("D", len(flights) - 1, ahead))
-        return flights[-1]
+        return flight, chunk_logits
 
     def spy_land(flight):
         (key,) = [i for i, f in enumerate(flights) if f is flight]
@@ -199,9 +200,20 @@ def test_a_step_is_dispatched_before_the_step_before_it_is_read(
         == sum(lengths) - len(lengths)       # no stop token: no row dropped
     tail = ["decode", "sample", "fetch", "emit", "housekeeping"]
     assert any(p[-5:] == tail for p in per_step)
+    rode = 0
     for p in per_step:                       # from the dispatch on
         seen = p[p.index("decode"):] if "decode" in p else p[-3:]
+        if "chunk" in seen:
+            # a prompt whose last chunk rode the step (PR 39): its first
+            # token is read behind the landing of the step before
+            at = seen.index("chunk")
+            assert seen[at - 1:at + 2] == ["emit", "chunk", "emit"], p
+            del seen[at:at + 2]
+            rode += 1
         assert seen == [name for name in tail if name in seen], p
+    # every prompt here is one chunk: all but the batch's first rode
+    assert rode == stats["chunks_fused"]
+    assert (rode > 0) == (len(lengths) > 1)
 
 
 def test_decode_ahead_pct_reads_the_counter_and_nothing_without_it(params):
@@ -231,16 +243,21 @@ def _span_by_step(eng, monkeypatch):
     decode_span_positions, the longest active length on the device)``."""
     import numpy as np
 
-    seen, decode_jit = [], eng._decode_jit
+    seen = []
 
-    def watched(params, cache, last, active):
-        longest = int(np.max(np.where(np.asarray(active),
-                                      np.asarray(cache["length"]), 0)))
-        seen.append((llama.span_positions(longest + 1, eng.max_seq),
-                     longest))
-        return decode_jit(params, cache, last, active)
+    def watch(program):
+        """``_decode_jit``, or a real mixed step (``*chunk``)."""
+        def watched(params, cache, last, active, *chunk):
+            if not chunk or int(chunk[-1]):
+                longest = int(np.max(np.where(
+                    np.asarray(active), np.asarray(cache["length"]), 0)))
+                seen.append((llama.span_positions(longest + 1, eng.max_seq),
+                             longest))
+            return program(params, cache, last, active, *chunk)
+        return watched
 
-    monkeypatch.setattr(eng, "_decode_jit", watched)
+    monkeypatch.setattr(eng, "_decode_jit", watch(eng._decode_jit))
+    monkeypatch.setattr(eng, "_mixed_step_jit", watch(eng._mixed_step_jit))
     rows = []
     while eng.has_unfinished():
         before = eng.stats["decode_span_positions"]
@@ -348,14 +365,29 @@ def test_window_and_full_walks_are_counted_by_the_devices_rule(monkeypatch):
         walked(int(start) + int(length))
         return chunk_jit(params, cache, tokens, slot, start, length)
 
+    def mixed(params, cache, last, active, tokens, slot, start, length):
+        if int(length):                       # not the empty first run
+            lengths = np.where(np.asarray(active),
+                               np.asarray(cache["length"]), 0)
+            walked(int(lengths.max()) + 1)
+            device["span"] += llama.span_positions(int(lengths.max()) + 1, 96)
+            device["past"] += int((lengths + 1 > 16).sum())
+            walked(int(start) + int(length))
+            device["mixed"] = device.get("mixed", 0) + 1
+        return mixed_jit(params, cache, last, active, tokens, slot, start,
+                         length)
+
+    mixed_jit = eng._mixed_step_jit
     monkeypatch.setattr(eng, "_decode_jit", decode)
     monkeypatch.setattr(eng, "_prefill_chunk_jit", chunk)
+    monkeypatch.setattr(eng, "_mixed_step_jit", mixed)
     before = dict(eng.stats)
     assert before["full_span_positions"] == before[
         "window_span_positions"] == before["decode_rows_past_window"] == 0
     eng.generate([list(range(3, 40)), [5, 9, 17], list(range(7, 20))],
                  SamplingParams(max_tokens=12))
     stats = eng.stats
+    assert stats["chunks_fused"] == device["mixed"] > 0
     assert stats["full_span_positions"] == device["full"] > 0
     assert stats["window_span_positions"] == device["window"] > 0
     assert stats["decode_rows_past_window"] == device["past"] > 0
@@ -410,28 +442,45 @@ def test_recurrent_state_traffic_is_counted_by_the_devices_rule(monkeypatch):
     device = dict.fromkeys(RECURRENT_COUNTERS, 0)
     device["span"] = 0
     decode_jit, chunk_jit = eng._decode_jit, eng._prefill_chunk_jit
+    mixed_jit = eng._mixed_step_jit
 
-    def decode(params, cache, last, active):
+    def decoded(cache, active):
         active = np.asarray(active)
         device["recurrent_decode_rows"] += 6 * int(active.sum())
         device["recurrent_slot_rows"] += 6 * active.size
         lengths = np.where(active, np.asarray(cache["length"]), 0)
         device["span"] += llama.span_positions(int(lengths.max()) + 1, 96)
-        return decode_jit(params, cache, last, active)
 
-    def chunk(params, cache, tokens, slot, start, length):
+    def ingested(tokens, start, length):
         device["recurrent_chunk_tokens"] += 6 * int(length)
         device["recurrent_chunk_rows"] += 6 * len(tokens)
         device["recurrent_resets"] += int(start) == 0
+
+    def decode(params, cache, last, active):
+        decoded(cache, active)
+        return decode_jit(params, cache, last, active)
+
+    def chunk(params, cache, tokens, slot, start, length):
+        ingested(tokens, start, length)
         return chunk_jit(params, cache, tokens, slot, start, length)
+
+    def mixed(params, cache, last, active, tokens, slot, start, length):
+        if int(length):                       # not the empty first run
+            decoded(cache, active)
+            ingested(tokens, start, length)
+            device["mixed"] = device.get("mixed", 0) + 1
+        return mixed_jit(params, cache, last, active, tokens, slot, start,
+                         length)
 
     monkeypatch.setattr(eng, "_decode_jit", decode)
     monkeypatch.setattr(eng, "_prefill_chunk_jit", chunk)
+    monkeypatch.setattr(eng, "_mixed_step_jit", mixed)
     before = dict(eng.stats)
     assert [before[name] for name in RECURRENT_COUNTERS] == [0] * 5
     eng.generate([list(range(3, 40)), [5, 9, 17], list(range(7, 20)),
                   [44, 55]], SamplingParams(max_tokens=12))
     stats = eng.stats
+    assert stats["chunks_fused"] == device["mixed"] > 0
     for name in RECURRENT_COUNTERS:
         assert stats[name] == device[name] > 0, name
     assert stats["recurrent_resets"] == 4               # one a prompt
@@ -479,10 +528,13 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     assert (held == routed) == (not cfg.router_width)
     assert stats["moe_tile_rows"] == 0
     routed_layers = cfg.n_layers - cfg.n_dense_layers
+    # a lone prompt: its chunk ran alone, and before it, once, the
+    # mixed program empty (PR 39) — an execution of 2 + 8 rows
+    assert stats["chunks"] == 1 and stats["chunks_fused"] == 0
     assert stats["moe_expert_slots"] == cfg.num_experts * routed_layers * (
-        stats["decode_steps"] + stats["chunks"])
+        stats["decode_steps"] + stats["chunks"] + 1)
     assert routed == cfg.experts_per_token * routed_layers * (
-        2 * stats["decode_steps"] + 8 * stats["chunks"])
+        2 * stats["decode_steps"] + 8 * stats["chunks"] + (2 + 8))
     # the decode steps' own, apart from the chunks'
     assert stats["moe_decode_expert_slots"] == (
         cfg.num_experts * routed_layers * stats["decode_steps"])
@@ -501,6 +553,12 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     assert ("attn_linear/kda_chunk/" in chunk.as_text(debug_info=True)) == (
         "attn_linear" in scopes)
     assert "kda_chunk/" not in text
+    mixed = eng._mixed_step_jit.lower(
+        eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool),
+        eng._jnp.zeros((8,), "int32"), 0, 0, 3).as_text(debug_info=True)
+    for scope in (*scopes, "attn_linear/kda_chunk"):
+        assert (f'{scope}/' in mixed) == (
+            scope.split("/")[0] in scopes), scope
 
 
 def test_phases_tile_the_loop_and_stats_keep_their_keys(params):
@@ -557,8 +615,10 @@ def test_one_engine_span_per_sampled_request(params):
         assert steps == sorted(steps)
         # the step that ends the prompt dispatches a decode step too,
         # and a step's token is read in the iteration after it: tokens
-        # 2 to 5 come one iteration each after the first
-        assert attrs["last_step"] - attrs["first_token_step"] == 4
+        # 2 to 5 come one iteration each after the first — or, where
+        # the prompt's last chunk rode a decode step (PR 39), the row
+        # joins the step after that one: one iteration more
+        assert attrs["last_step"] - attrs["first_token_step"] in (4, 5)
         assert "error" not in span
 
 
@@ -634,6 +694,9 @@ def test_chunk_gaps_name_the_gaps_that_saw_a_prefill_dispatched(
     if chunk_tokens:
         assert gaps == [i for i in range(1, 12)
                         if chunks_at[i] != chunks_at[i - 1]]
+        # both chunks rode the first one's decode steps (PR 39): a gap
+        # is named where a prefill was dispatched, alone or not
+        assert eng.stats["chunks_fused"] == dispatches
     # nothing was prefilled after the second one's own first token
     (span,) = _spans(second.trace_id, "llm:engine")
     assert span["attrs"]["chunk_gaps"] == []

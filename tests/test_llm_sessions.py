@@ -281,6 +281,60 @@ def test_nothing_moves_a_slot_under_a_step_in_flight(params, how):
         [o.token_ids for o in want]
 
 
+@pytest.mark.parametrize("how", ["evict_session", "end_session",
+                                 "shutdown"])
+def test_a_mixed_step_in_flight_is_landed_like_any_other(params, how):
+    """The step in flight carries another prompt's chunk (PR 39: one
+    program for both): a forced eviction, ``end_session`` and the
+    loop's ``shutdown`` land it first all the same — its decode rows'
+    tokens reach their sequences, the chunk's prompt goes on where it
+    was, and the streams are the uninterrupted ones."""
+    from ant_ray_tpu.llm.engine import EngineLoop
+
+    long_prompt = list(range(3, 30))         # four chunks of 8
+    want = [_engine(params).generate([p], SamplingParams(max_tokens=n))[0]
+            for p, n in (([5, 9, 17, 3], 12), (long_prompt, 6))]
+    eng = _engine(params, slots=3)
+    if how == "shutdown":
+        loop = EngineLoop(eng)
+        first = loop.submit([5, 9, 17, 3], SamplingParams(max_tokens=60))
+        assert first.events.get(timeout=120)["type"] == "token"
+        second = loop.submit(long_prompt, SamplingParams(max_tokens=6))
+        assert second.events.get(timeout=120)["type"] == "token"
+        loop.shutdown(timeout=60)
+        assert not loop._thread.is_alive() and eng._flight is None
+        assert eng.stats["chunks_fused"] == 4          # all of them rode
+        for seq in eng._active.values():
+            assert seq.kv_len == len(seq.prompt) + len(seq.generated) - 1
+        return
+    outs = {}
+    eng.add_request([5, 9, 17, 3], SamplingParams(max_tokens=12),
+                    request_id="a", admit=False, session_id="s")
+    for _ in range(3):
+        eng.step()
+    eng.add_request(long_prompt, SamplingParams(max_tokens=6),
+                    request_id="c", admit=False)
+    eng.step()
+    eng.step()
+    sampled, rows = eng._flight
+    assert eng.stats["chunks_fused"] == 2    # the step in flight: mixed
+    assert [seq.request_id for _, seq in rows] == ["a"]
+    (seq_c,) = eng._prefilling
+    assert seq_c.prefill_done == seq_c.kv_len == 16
+    seq_a, sess = eng._sessions["s"].current, eng._sessions["s"]
+    before = len(seq_a.generated)
+    if how == "evict_session":
+        assert eng.evict_session("s", force=True)
+        assert sess.kv_len == seq_a.kv_len == 4 + before
+    else:
+        assert eng.end_session("s")          # mid-turn: the turn goes on
+    assert eng._flight is None and len(seq_a.generated) == before + 1
+    assert eng._prefilling == [seq_c] and seq_c.kv_len == 16
+    _drain(eng, outs)
+    assert [outs["a"].token_ids, outs["c"].token_ids] == \
+        [o.token_ids for o in want]
+
+
 def test_sessions_beyond_slots_all_complete(params):
     """Acceptance: resident sessions exceed the KV slot count at fixed
     HBM — sessions beyond `slots` complete via offload, and their

@@ -213,11 +213,15 @@ def test_engine_batch_of_two_lengths_equals_the_reference(params):
         assert out.token_ids == want
     stats = engine.stats
     assert stats["d2h_syncs"] == stats["decode_steps"] + len(prompts)
+    # the programs run: a chunk that rode a decode step is one with it
+    # (PR 39), and the first lone chunk ran the mixed program once
+    # before it, empty — 4 + 8 rows that route like any
+    assert stats["chunks_fused"] == 3       # the longer prompt's
     assert stats["moe_expert_slots"] == CFG.num_experts * CFG.n_layers * (
-        stats["decode_steps"] + stats["chunks"])
+        stats["decode_steps"] + stats["chunks"] - stats["chunks_fused"] + 1)
     assert stats["moe_assignments"] == \
         CFG.experts_per_token * CFG.n_layers * (
-            4 * stats["decode_steps"] + 8 * stats["chunks"])
+            4 * stats["decode_steps"] + 8 * stats["chunks"] + (4 + 8))
     assert 0 < stats["moe_experts_hit"] <= stats["moe_expert_slots"]
     assert stats["moe_load_max"] > 0
 
@@ -254,9 +258,11 @@ def test_routing_counters_arrive_with_their_step_and_are_added_once(
     stats = engine.stats
     assert len(readings) == stats["decode_steps"]
     # every reading brings its own decode step, and the chunks between
-    # the sampler before it and its own
+    # the sampler before it and its own; a chunk that rode a step is
+    # that step's program, and the first reading brings the empty run
+    assert stats["chunks_fused"] > 0
     assert min(readings) == 1 and sum(readings) == \
-        stats["decode_steps"] + stats["chunks"]
+        stats["decode_steps"] + stats["chunks"] - stats["chunks_fused"] + 1
     assert [stats[name] for name in llama.ROUTING_COUNTERS] == \
         np.asarray(engine.cache["routing"]).tolist()
     assert stats["decode_slots"] - stats["tokens_generated"] == int(stop)

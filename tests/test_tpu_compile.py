@@ -94,29 +94,45 @@ def test_flash_backward_compiles_for_v5e(v5e, shape):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# The engine's three step programs, and the slots whose slabs each
+# attends over at a time: every slot's (a decode step's rows), one (a
+# chunk's), or both in turn (the mixed step's two walks).
+PROGRAMS = ["decode", "prefill_chunk", "mixed_step"]
+_SLOTS_READ = {"decode": lambda slots: (slots,),
+               "prefill_chunk": lambda slots: (1,),
+               "mixed_step": lambda slots: (slots, 1)}
+
+
 def _tree_bytes(tree):
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
 def _compile_step(device, program, config, slots, max_seq, chunk=64):
-    """``decode`` or ``prefill_chunk`` as the engine jits it (the cache
-    donated, prompts in chunks of ``chunk`` tokens) for one described
-    chip -> (compiled, the shapes of the parameters, of the cache)."""
+    """``decode``, ``prefill_chunk`` or ``mixed_step`` (both together,
+    PR 39) as the engine jits it (the cache donated, prompts in chunks
+    of ``chunk`` tokens) for one described chip -> (compiled, the
+    shapes of the parameters, of the cache)."""
     params = jax.eval_shape(
         lambda: llama.init_params(config, jax.random.PRNGKey(0)))
     cache = jax.eval_shape(
         lambda: llama.init_kv_cache(config, slots, max_seq, chunk))
     params, cache = _on(device, (params, cache))
-    tokens, scalar, active = _on(device, (
-        jax.ShapeDtypeStruct((slots if program == "decode" else chunk,),
-                             jnp.int32),
+    last, tokens, scalar, active = _on(device, (
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((chunk,), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.bool_)))
     if program == "decode":
         lowered = jax.jit(
             lambda p, c, last, act: llama.decode_step(
                 p, last, c, config, active=act),
-            donate_argnums=(1,)).lower(params, cache, tokens, active)
+            donate_argnums=(1,)).lower(params, cache, last, active)
+    elif program == "mixed_step":
+        lowered = jax.jit(
+            lambda p, c, last, act, t, slot, start, n: llama.mixed_step(
+                p, last, t, c, config, act, slot, start, n),
+            donate_argnums=(1,)).lower(params, cache, last, active, tokens,
+                                       scalar, scalar, scalar)
     else:
         lowered = jax.jit(
             lambda p, c, t, slot, start, n: llama.prefill_chunk_into_cache(
@@ -150,6 +166,13 @@ def test_engine_decode_step_compiles_for_v5e(v5e):
 def test_engine_prefill_chunk_compiles_for_v5e(v5e):
     _fits_beside_one_cache(
         *_compile_step(v5e.devices[0], "prefill_chunk", CFG, 8, 2048))
+
+
+def test_engine_mixed_step_compiles_for_v5e(v5e):
+    """The third step program (PR 39): a chunk's 64 rows behind the 8
+    decode rows, one pass over the layers."""
+    _fits_beside_one_cache(
+        *_compile_step(v5e.devices[0], "mixed_step", CFG, 8, 2048))
 
 
 @pytest.mark.parametrize("slots,vocab", [
@@ -258,7 +281,7 @@ def test_routed_step_reads_the_expert_stack_in_place(
         assert gathered not in text
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_mixed_cache_fits_the_chip_at_the_cells_size(v5e, program):
     """`command-a-plus.docqa` as it is served: 16 slots x 32,768, the
     full layer's slabs beside three rings of 4,096 + 512 rows, prompts
@@ -283,9 +306,8 @@ def test_mixed_cache_fits_the_chip_at_the_cells_size(v5e, program):
     assert need < 13.0 * 2 ** 30
     # a ring is never read whole, nor a slot's ring: blocks of 256
     text = compiled.as_text()
-    read = 16 if program == "decode" else 1
-    assert not re.search(
-        rf"= bf16\[1,{read},4608,8,128\]", text)
+    for read in _SLOTS_READ[program](16):
+        assert not re.search(rf"= bf16\[1,{read},4608,8,128\]", text)
 
 
 # Solar Open 2's blocks at their published widths, the benchmark's cut:
@@ -303,7 +325,7 @@ RECURRENT = llama.LlamaConfig(
     linear_heads=64, linear_head_dim=128, linear_rank=128, attn_gate=True)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_recurrent_state_fits_the_chip_at_the_cells_size(
         v5e, monkeypatch, program):
     """`solar-open2.digest` as it is served: 24 slots x 32,768, ONE
@@ -342,7 +364,7 @@ def test_recurrent_state_fits_the_chip_at_the_cells_size(
 DENSE_128 = dataclasses.replace(CFG, n_heads=16)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("config,slots,max_seq", [
     pytest.param(DENSE_128, 8, 2048, id="dense"),
     pytest.param(ROUTED, 16, 1536, id="routed"),
@@ -380,11 +402,12 @@ def test_step_updates_the_cache_in_place(v5e, program, config, slots,
                  if re.match(produces + re.escape(whole)
                              + r"\S* (copy|dynamic-update-slice)\(", line)]
         assert not moved, moved
-        read = slots if program == "decode" else 1
-        full_span = "bf16[" + ",".join(map(str, (1, read) + slab.shape[2:]))
-        sliced = [line.strip()[:160] for line in lines
-                  if re.match(produces + re.escape(full_span + "]"), line)]
-        assert not sliced, sliced
+        for read in _SLOTS_READ[program](slots):
+            full_span = "bf16[" + ",".join(
+                map(str, (1, read) + slab.shape[2:]))
+            sliced = [line.strip()[:160] for line in lines if re.match(
+                produces + re.escape(full_span + "]"), line)]
+            assert not sliced, sliced
 
 
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
