@@ -216,17 +216,19 @@ class _PhaseRecorder:
     rule, no read), and ``decode_rows_past_window`` the active rows of
     a decode step whose context exceeds the window; the two
     ``decode_*_positions`` count the full layers' walk.  Of a model
-    with linear layers (``LlamaConfig.n_linear``; every other leaves
-    these five at zero), whose state is a slot's and not a position's:
+    with recurrent layers — linear or state-space ones
+    (``LlamaConfig.n_recurrent``; every other leaves these five at
+    zero), whose state is a slot's and not a position's:
     ``recurrent_decode_rows`` adds up, per decode step, the active rows
-    times the linear layers — the states the step advanced — and
-    ``recurrent_slot_rows`` the slots times the linear layers, the
+    times the recurrent layers — the states the step advanced — and
+    ``recurrent_slot_rows`` the slots times the recurrent layers, the
     states its program read and wrote; ``recurrent_chunk_tokens`` and
     ``recurrent_chunk_rows`` a chunk's real tokens and its width, times
-    the linear layers; ``recurrent_resets`` the chunks that began a
+    the recurrent layers; ``recurrent_resets`` the chunks that began a
     prompt and so began from an empty state (the host's own ``start``:
-    no read).  A linear layer walks nothing: the ``decode_*_positions``
-    of such a model are its softmax layers' walk.
+    no read).  A recurrent layer walks nothing: the
+    ``decode_*_positions`` of such a model are its softmax layers'
+    walk.
     ``sample_plain_steps`` and ``sample_sorted_steps`` count the decode
     steps whose sampler drew without a filter and with a sort
     (``sampler_work`` of the step's rows: no read); the rest took the
@@ -441,11 +443,11 @@ class LLMEngine:
         self.max_seq = min(max_seq or self.config.max_seq,
                            self.config.max_seq)
         self.slots = slots
-        if self.config.n_linear and prefill_chunk_tokens is None:
+        if self.config.n_recurrent and prefill_chunk_tokens is None:
             raise ValueError(
                 "bucketed prefill keeps no recurrent state: a model with "
-                "linear-attention layers is ingested in chunks "
-                "(prefill_chunk_tokens=)")
+                "linear-attention or state-space layers is ingested in "
+                "chunks (prefill_chunk_tokens=)")
         self.tokenizer = tokenizer or get_tokenizer(None)
         if params is None:
             # Random weights as ONE program: each leaf is drawn, scaled
@@ -568,7 +570,7 @@ class LLMEngine:
                                     mesh=eng_mesh)
 
         # k, v — or c_kv, k_rope; a window model's rings beside them;
-        # behind them what linear layers keep of a slot's sequence
+        # behind them what recurrent layers keep of a slot's sequence
         slab_names = (*llama.kv_slabs(cfg), *llama.state_slabs(cfg))
 
         def _extract(cache, slot):
@@ -632,11 +634,11 @@ class LLMEngine:
 
         mesh = self.mesh
         tp = mesh.shape.get("tp", 1)
-        if self.config.n_linear:
+        if self.config.n_recurrent:
             raise ValueError(
-                "a recurrent state (linear-attention layers) is not "
-                "sharded: the engine runs such a model on one device "
-                "only (tensor_parallel_size=1, no mesh)")
+                "a recurrent state (linear-attention or state-space "
+                "layers) is not sharded: the engine runs such a model on "
+                "one device only (tensor_parallel_size=1, no mesh)")
         if self.config.kv_lora_rank:
             raise ValueError(
                 "a latent (MLA) cache has no heads axis to shard: the "
@@ -717,15 +719,15 @@ class LLMEngine:
             seq.emits = []
         seq.submitted = submitted or (time.time(), time.perf_counter(),
                                       self.stats["steps"])
-        if session_id is not None and self.config.n_linear:
+        if session_id is not None and self.config.n_recurrent:
             # The decode step runs one ahead: a turn that a stop token
             # ends has had its state advanced by that token, and the
             # next turn would ingest it a second time (slabs only
             # overwrite the row; a state cannot take a token back).
             raise ValueError(
                 "sessions are not kept over a recurrent state: a model "
-                "with linear-attention layers serves each request from "
-                "an empty state (no session_id)")
+                "with linear-attention or state-space layers serves each "
+                "request from an empty state (no session_id)")
         if session_id is not None:
             sess = self._sessions.get(session_id)
             if sess is None or sess.state == "failed":
@@ -1264,8 +1266,9 @@ class LLMEngine:
         """A step program of ``rows`` rows was dispatched, ``live`` of
         them real (a decode step's slots and its active rows; a chunk's
         width and its tokens, ``fresh``: it began its prompt): of a
-        model with linear layers, the states it touched and advanced."""
-        n = self.config.n_linear
+        model with recurrent layers, the states it touched and
+        advanced."""
+        n = self.config.n_recurrent
         if not n:
             return
         stats = self.stats

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ant_ray_tpu.ops import delta_rule
+from ant_ray_tpu.ops import delta_rule, ssd
 from ant_ray_tpu.ops.attention import attention, kernel_fits
 from ant_ray_tpu.ops.layernorm import layernorm
 from ant_ray_tpu.ops.pallas import grouped_matmul
@@ -107,10 +107,14 @@ class LlamaConfig:
     # ``window``.  "linear": the gated delta rule (``_linear_inputs``,
     # ``ops/delta_rule.py``), which keeps a state of the SEQUENCE and
     # nothing of a position; its leaves are a stack of their own
-    # (``LINEAR``).  ``window_pattern`` is the same period written as
+    # (``LINEAR``).  "ssm": Mamba-2's selective state-space recurrence
+    # (``_ssm_inputs``, ``ops/ssd.py``), the other kind that keeps a
+    # state of the sequence, of another shape; its leaves are the stack
+    # ``SSM``.  A model has one RECURRENT kind or none.
+    # ``window_pattern`` is the same period written as
     # booleans (window or full), taken at construction only.  With
     # ``full_rope`` False the full layers rotate nothing (no positional
-    # embedding at all); window layers always do, linear layers never.
+    # embedding at all); window layers always do, recurrent layers never.
     window: int = 0
     layer_kinds: tuple = ()
     window_pattern: dataclasses.InitVar[tuple] = ()
@@ -124,6 +128,29 @@ class LlamaConfig:
     linear_head_dim: int = 0
     linear_conv: int = 4
     linear_rank: int = 0
+    # A state-space layer: ``ssm_heads`` heads of width
+    # ``ssm_head_dim``, each with a state ``ssm_head_dim`` x
+    # ``ssm_state`` (float32); the write and the read direction
+    # (``ssm_state`` wide each) are shared by the heads of a group, of
+    # which there is ONE (``ssm_groups``: the published key, no other
+    # count is computed); a depth-wise causal convolution of
+    # ``ssm_conv`` taps WITH bias over the heads' inputs and the two
+    # directions.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    # Granite's four scalars, each absent at its default: the embedding
+    # is multiplied by ``embedding_multiplier``, what a mix and a
+    # feed-forward add to the residual by ``residual_multiplier``, the
+    # softmax layers' scores by ``attention_multiplier`` in place of
+    # head_dim^-1/2 (0: absent), and the logits are divided by
+    # ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # The softmax layers' output under an element-wise sigmoid gate, a
     # projection of the block's normed input, before ``wo``.
     attn_gate: bool = False
@@ -152,8 +179,13 @@ class LlamaConfig:
         if self.n_dense_layers and not self.num_experts:
             raise ValueError("n_dense_layers are the leading dense "
                              "layers of a routed model")
-        if set(self.layer_kinds) - {"full", "window", "linear"}:
+        if set(self.layer_kinds) - {"full", "window", *RECURRENT}:
             raise ValueError(f"unknown layer kinds {self.layer_kinds!r}")
+        if len(set(self.layer_kinds) & set(RECURRENT)) > 1:
+            raise ValueError(
+                "linear (delta-rule) layers and ssm (state-space) layers "
+                "in ONE model are not computed: the cache keeps one kind "
+                "of recurrent state")
         if bool(self.window) != any(self.period):
             raise ValueError("window and window_pattern go together")
         if self.layer_kinds and (
@@ -171,8 +203,23 @@ class LlamaConfig:
                 "linear layers state their heads, a head's width, the "
                 "convolution's taps and the low-rank width, and stand "
                 "in a sequential block beside full layers only")
+        if "ssm" in self.layer_kinds and (
+                self.window or self.parallel_block or self.kv_lora_rank
+                or "full" not in self.layer_kinds or self.ssm_groups != 1
+                or not (self.ssm_heads and self.ssm_head_dim
+                        and self.ssm_state and self.ssm_conv > 1)):
+            raise ValueError(
+                "ssm layers state their heads, a head's width, the "
+                "state's width and the convolution's taps, share the "
+                "write and read directions among ALL heads (ssm_groups "
+                "1), and stand in a sequential block beside full layers "
+                "only")
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
+        if self.parallel_block and self.residual_multiplier != 1.0:
+            raise ValueError("residual_multiplier scales what a "
+                             "sequential block adds: a parallel block's "
+                             "one sum is not scaled")
 
     @property
     def head_dim(self) -> int:
@@ -198,19 +245,32 @@ class LlamaConfig:
         """Linear layers of the ``n_layers``."""
         return self.n_layers // len(self.kinds) * self.kinds.count("linear")
 
+    @property
+    def recurrent(self) -> str:
+        """The model's RECURRENT kind — "linear", "ssm" — or "": the
+        kind of layer that keeps a state a slot (``state_slabs``) and
+        nothing of a position."""
+        return next((kind for kind in RECURRENT if kind in self.kinds), "")
+
+    @property
+    def n_recurrent(self) -> int:
+        """Layers of the ``n_layers`` that keep a state a slot."""
+        return self.n_layers // len(self.kinds) * self.kinds.count(
+            self.recurrent)
+
     def layer_counts(self) -> tuple:
         """(window layers, full layers) of the ``n_layers``."""
         n_window = self.n_layers // len(self.kinds) * sum(self.period)
-        return n_window, self.n_layers - n_window - self.n_linear
+        return n_window, self.n_layers - n_window - self.n_recurrent
 
     def place(self, j: int) -> tuple:
         """Where the layer at place ``j`` of the period lies: (its
         stack's name in a ``stacks`` entry's parameters, that stack's
         layers a period, ``j``'s rank among them)."""
-        linear = self.kinds[j] == "linear"
+        stack = RECURRENT.get(self.kinds[j], "layers")
         alike = [i for i, kind in enumerate(self.kinds)
-                 if (kind == "linear") == linear]
-        return LINEAR if linear else "layers", len(alike), alike.index(j)
+                 if RECURRENT.get(kind, "layers") == stack]
+        return stack, len(alike), alike.index(j)
 
     @property
     def rope_dim(self) -> int:
@@ -219,9 +279,12 @@ class LlamaConfig:
 
     @property
     def attn_scale(self) -> float:
-        """The softmax scale of the latent scores: head_dim^-1/2, times
-        the square of YaRN's ``mscale_all_dim`` temperature."""
-        scale = self.head_dim ** -0.5
+        """The softmax scale where it is NOT plain head_dim^-1/2 (the
+        grouped-query paths spell that one out themselves): the
+        ``attention_multiplier`` a config states, or the latent scores'
+        head_dim^-1/2 times the square of YaRN's ``mscale_all_dim``
+        temperature."""
+        scale = self.attention_multiplier or self.head_dim ** -0.5
         s = self.rope_scaling
         if s is not None and s.mscale_all_dim:
             scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
@@ -252,7 +315,11 @@ class LlamaConfig:
 # The stack of a model's linear layers in the parameter tree, beside
 # ``layers`` (its softmax layers): a linear layer's leaves are not a
 # softmax layer's, so the two kinds cannot lie in one stacked array.
+# ``SSM``: the same for its state-space layers.  ``RECURRENT``: the
+# kinds that keep a state a slot -> their stack's name.
 LINEAR = "linear_layers"
+SSM = "ssm_layers"
+RECURRENT = {"linear": LINEAR, "ssm": SSM}
 
 CONFIGS: dict[str, LlamaConfig] = {
     # ref parity: the Llama-3-8B benchmark model (BASELINE.md north star)
@@ -323,18 +390,49 @@ CONFIGS: dict[str, LlamaConfig] = {
         router_width=16, n_shared_experts=1, full_rope=False,
         layer_kinds=("full", "linear", "linear", "linear"),
         linear_heads=4, linear_head_dim=16, linear_rank=8, attn_gate=True),
+    # Granite 4.0-H's block at test size: two periods of two Mamba-2
+    # layers (4 heads of 16 with a state of 16, conv of 4 taps with
+    # bias), a softmax layer without positional embedding (4 query / 2
+    # KV heads of 16, scale 1/16 stated) and a third Mamba-2 layer; a
+    # softmax router over 8 experts, 3 a token, of which this share
+    # holds 4, beside a shared SwiGLU twice an expert's width; the four
+    # multipliers set, the embedding tied
+    "granite-h-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        mlp_dim=32, max_seq=512, norm_eps=1e-5, dtype=jnp.float32,
+        tie_embeddings=True, num_experts=4, experts_per_token=3,
+        router_width=8, n_shared_experts=2, full_rope=False,
+        layer_kinds=("ssm", "ssm", "full", "ssm"),
+        ssm_heads=4, ssm_head_dim=16, ssm_state=16,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=1 / 16, logits_scaling=16.0),
 }
 
 
 # ---------------------------------------------------------------- params
 
-def _layer_leaves(c: LlamaConfig, linear: bool = False) -> dict:
+def _layer_leaves(c: LlamaConfig, stack: str = "layers") -> dict:
     """One stack of like layers: leaf name -> (its shape after the
-    leading layers axis, its logical dims after it); ``linear``: the
-    model's linear layers (``LINEAR``), else its softmax layers.  A
-    leaf's last logical dim also says how ``init_params`` draws it."""
+    leading layers axis, its logical dims after it); ``stack``: the
+    model's linear layers (``LINEAR``), its state-space layers
+    (``SSM``), else its softmax layers.  A leaf's last logical dim also
+    says how ``init_params`` draws it."""
     hd, e, p, m = c.head_dim, "embed_param", "heads_flat", "mlp"
-    if linear:
+    if stack == SSM:
+        heads, inner, channels = c.ssm_heads, *ssm_widths(c)
+        attn = {
+            # the gate z, the convolution's channels, a step a head
+            "in_proj": ((c.dim, inner + channels + heads), (e, p)),
+            "conv_w": ((c.ssm_conv, channels), (None, "taps")),
+            "conv_b": ((channels,), ("bias",)),
+            "dt_bias": ((heads,), ("decay_bias",)),
+            "a_log": ((heads,), ("decay_rate",)),
+            "d_skip": ((heads,), ("norm",)),
+            # one RMS over all the heads' channels, behind the gate
+            "ssm_norm": ((inner,), ("norm",)),
+            "wo": ((inner, c.dim), (p, e)),
+        }
+    elif stack == LINEAR:
         heads, rank = c.linear_heads, c.linear_rank
         width = heads * c.linear_head_dim
         attn = {
@@ -404,8 +502,15 @@ def _layer_leaves(c: LlamaConfig, linear: bool = False) -> dict:
         **mlp,
         **({"q_norm": ((c.n_heads * hd,), ("norm",)),
             "k_norm": ((c.n_kv_heads * hd,), ("norm",))}
-           if c.qk_norm and not linear else {}),
+           if c.qk_norm and stack == "layers" else {}),
     }
+
+
+def ssm_widths(c: LlamaConfig) -> tuple:
+    """(the heads' channels together, the convolution's channels: those
+    and the write and the read direction) of a state-space layer."""
+    inner = c.ssm_heads * c.ssm_head_dim
+    return inner, inner + 2 * c.ssm_groups * c.ssm_state
 
 
 def _param_tree(config: LlamaConfig, pick) -> dict:
@@ -414,10 +519,12 @@ def _param_tree(config: LlamaConfig, pick) -> dict:
     dense layers are a stack of their own, ``dense_layers``, beside
     ``layers``, which then holds the routed ones only."""
     c = config
-    stacks = {name: (stack.n_layers - stack.n_linear, _layer_leaves(stack))
+    stacks = {name: (stack.n_layers - stack.n_recurrent,
+                     _layer_leaves(stack))
               for name, stack in c.stacks().items()}
-    if c.n_linear:
-        stacks[LINEAR] = (c.n_linear, _layer_leaves(c, linear=True))
+    if c.recurrent:
+        name = RECURRENT[c.recurrent]
+        stacks[name] = (c.n_recurrent, _layer_leaves(c, name))
     return {
         "embed": pick(None, (c.vocab_size, c.dim), ("vocab", "embed_param")),
         **{name: {leaf: pick(n, *both) for leaf, both in leaves.items()}
@@ -439,10 +546,11 @@ def param_logical_dims(config: LlamaConfig) -> dict:
         dims if n is None else (None, *dims)))
 
 
-# extra rules: flattened (heads*head_dim) dims shard over tp; a linear
-# layer's small leaves, named for how they are drawn, are replicated
-LLAMA_RULES_EXTRA = {"heads_flat": "tp", "conv": None,
-                     "decay_rate": None, "decay_bias": None}
+# extra rules: flattened (heads*head_dim) dims shard over tp; a
+# recurrent layer's small leaves, named for how they are drawn, are
+# replicated
+LLAMA_RULES_EXTRA = {"heads_flat": "tp", "conv": None, "taps": None,
+                     "bias": None, "decay_rate": None, "decay_bias": None}
 
 
 def llama_rules() -> dict:
@@ -464,6 +572,8 @@ def init_params(config: LlamaConfig, key) -> dict:
     def _init(shape, logical, k):
         if logical[-1] == "norm":
             return jnp.ones(shape, config.dtype)
+        if logical[-1] == "bias":
+            return jnp.zeros(shape, config.dtype)
         if logical[-1] == "decay_rate":
             # a_log: a head forgets at a rate drawn from 1 to 16 ...
             drawn = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1, 16))
@@ -474,8 +584,17 @@ def init_params(config: LlamaConfig, key) -> dict:
             dt = jnp.exp(jax.random.uniform(
                 k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
             drawn = dt + jnp.log(-jnp.expm1(-dt))
+        elif logical[-1] == "taps":
+            # a state-space layer's convolution, as that family draws
+            # it: uniform within +-taps^-1/2.  What it writes and reads
+            # the state along is then of the size of its input, and the
+            # state is a large part of the layer's output; taps at 0.02
+            # leave the state a thousandth of the skip beside it
+            # (a linear layer sets q and k to unit length instead)
+            drawn = jax.random.uniform(
+                k, shape, jnp.float32, -1.0, 1.0) * shape[-2] ** -0.5
         else:
-            # every matrix, a convolution's taps among them
+            # every matrix, a linear layer's taps among them
             drawn = jax.random.normal(k, shape, jnp.float32) * 0.02
         return drawn.astype(config.dtype)
 
@@ -528,13 +647,27 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     and v BEFORE their convolution, whose last inputs the caller may
     hold, the log-decays and the write strengths — and gets back the
     heads' outputs (..., heads, d_v) float32, which it norms a head,
-    gates and projects.  With
+    gates and projects.  An "ssm" layer hands ``attend(u, dt,
+    weights)`` what ``_ssm_inputs`` makes of it — the convolution's
+    channels BEFORE it and the steps, beside the layer's small leaves —
+    and gets back the heads' outputs (..., heads, P) float32, which it
+    gates FIRST and then norms over all channels at once.  With
     ``parallel_block`` attention and feed-forward read the same normed
-    input and join in one residual sum.  Returns ``(x, state, load)``,
+    input and join in one residual sum; otherwise what either adds to
+    the residual is multiplied by ``residual_multiplier`` where the
+    config states one.  Returns ``(x, state, load)``,
     ``load`` as ``_mlp`` gives it."""
     lead = x.shape[:-1]
     h = _norm(x, layer["ln_attn"], c)
-    if kind == "linear":
+    if kind == "ssm":
+        with jax.named_scope("attn_ssm"):
+            z, u, dt = _ssm_inputs(layer, h, c)
+            attn, state = attend(u, dt, {name: layer[name] for name in (
+                "conv_w", "conv_b", "a_log", "d_skip")})
+            attn = rmsnorm(attn.reshape(*lead, -1) * jax.nn.silu(z),
+                           layer["ssm_norm"].astype(jnp.float32),
+                           c.norm_eps).astype(x.dtype)
+    elif kind == "linear":
         with jax.named_scope("attn_linear"):
             attn, state = attend(*_linear_inputs(layer, h, c),
                                  layer["conv_w"])
@@ -561,7 +694,7 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         with jax.named_scope("attn_" + kind):
             attn, state = attend(xq, xk, xv)
     attn = attn.reshape(*lead, -1)               # heads * value width
-    if c.attn_gate and kind != "linear":
+    if c.attn_gate and kind not in RECURRENT:
         attn = (attn * jax.nn.sigmoid(jnp.dot(
             h, layer["w_attn_gate"],
             preferred_element_type=jnp.float32))).astype(x.dtype)
@@ -570,14 +703,24 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         out, load = _mlp(layer, h, c, index, tile)
         x = x + attn + out.astype(x.dtype)
         return constrain_act(x, ("batch", "seq", "embed")), state, load
-    x = x + attn
+    x = _residual(x, attn, c)
     x = constrain_act(x, ("batch", "seq", "embed"))
 
     h = _norm(x, layer["ln_mlp"], c)
     out, load = _mlp(layer, h, c, index, tile)
-    x = x + out.astype(x.dtype)
+    x = _residual(x, out.astype(x.dtype), c)
     x = constrain_act(x, ("batch", "seq", "embed"))
     return x, state, load
+
+
+def _residual(x, out, c: LlamaConfig):
+    """``x`` + what a mix or a feed-forward adds to it, under the
+    config's ``residual_multiplier`` (the product in float32, rounded
+    once)."""
+    if c.residual_multiplier == 1.0:
+        return x + out
+    return x + (out.astype(jnp.float32)
+                * c.residual_multiplier).astype(x.dtype)
 
 
 def _norm(x, weight, c: LlamaConfig):
@@ -613,6 +756,48 @@ def _linear_inputs(layer: dict, h, c: LlamaConfig):
         *h.shape[:-1], heads, -1)
     beta = 2.0 * jax.nn.sigmoid(jnp.dot(h, layer["w_beta"], **f32))
     return u, g, beta
+
+
+def _ssm_inputs(layer: dict, h, c: LlamaConfig):
+    """What a state-space layer makes of ``h`` (..., dim) before
+    anything runs along the sequence, ONE product ``h @ in_proj`` with
+    float32 sums, split: the gate ``z`` (..., heads * P) float32; ``u``
+    (..., heads * P + 2 * N), the heads' inputs and the write and read
+    directions side by side, not yet convolved, in the activations'
+    dtype (as the cache keeps their tail); ``dt`` (..., heads) float32,
+    the steps ``softplus(delta + dt_bias)``, not clamped.  The one place
+    they are made, for training, chunks and decode."""
+    inner, channels = ssm_widths(c)
+    zxd = jnp.dot(h, layer["in_proj"], preferred_element_type=jnp.float32)
+    dt = jax.nn.softplus(zxd[..., inner + channels:]
+                         + layer["dt_bias"].astype(jnp.float32))
+    return zxd[..., :inner], zxd[..., inner:inner + channels].astype(
+        h.dtype), dt
+
+
+def _ssm_run(y, dt, weights: dict, c: LlamaConfig):
+    """The convolution's output ``y`` (..., heads * P + 2 * N) float32
+    -> ``ops/ssd.py``'s arguments up to the state: SiLU, then the heads'
+    inputs x (..., heads, P), the steps, the heads' rates exp(a_log),
+    the directions b and c (..., N), the skips."""
+    y, inner = jax.nn.silu(y), ssm_widths(c)[0]
+    return (y[..., :inner].reshape(*y.shape[:-1], c.ssm_heads, -1), dt,
+            jnp.exp(weights["a_log"].astype(jnp.float32)),
+            y[..., inner:inner + c.ssm_state], y[..., inner + c.ssm_state:],
+            weights["d_skip"].astype(jnp.float32))
+
+
+def _attend_ssm_rows(u, dt, weights: dict, c: LlamaConfig):
+    """A state-space layer over ONE whole sequence from an empty state,
+    no cache: u (seq, channels), dt (seq, heads) -> (seq, heads, P)
+    float32.  The block form."""
+    y, _ = delta_rule.causal_conv(
+        u, jnp.zeros((c.ssm_conv - 1, u.shape[-1]), u.dtype),
+        weights["conv_w"], weights["conv_b"])
+    out, _ = ssd.chunk_ssd(*_ssm_run(y, dt, weights, c),
+                           jnp.zeros(state_slabs(c)["s"][0]),
+                           operand=c.dtype)
+    return out
 
 
 def _linear_qkv(y, c: LlamaConfig):
@@ -868,11 +1053,12 @@ def _rope_tables(c: LlamaConfig, positions: int | None = None):
 def _stacks(params: dict, c: LlamaConfig) -> list:
     """``[(a run of layers' stacked leaves BY KIND, the config that
     reads them)]``, in the layers' order (``LlamaConfig.stacks``): the
-    softmax layers' stack under "layers", and beside it the linear
-    layers' (``LINEAR``) where the run has any (``LlamaConfig.place``
-    says which a place of the period reads)."""
+    softmax layers' stack under "layers", and beside it the recurrent
+    layers' (``LINEAR`` or ``SSM``) where the run has any
+    (``LlamaConfig.place`` says which a place of the period reads)."""
+    beside = [RECURRENT[c.recurrent]] if c.recurrent else []
     return [({"layers": params[name],
-              **({LINEAR: params[LINEAR]} if cfg.n_linear else {})}, cfg)
+              **{stack: params[stack] for stack in beside}}, cfg)
             for name, cfg in c.stacks().items()]
 
 
@@ -959,11 +1145,13 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     bench).
     """
     c = config
-    if return_kv and c.n_linear:
+    if return_kv and c.recurrent:
         raise ValueError(
             "forward(return_kv=True) returns what layers keep of every "
-            "position; a linear layer keeps a state of the sequence: "
-            "ingest it in chunks (prefill_chunk_into_cache)")
+            "position; a linear layer keeps a state of the sequence, "
+            "and so does an ssm layer: ingest it in chunks "
+            "(prefill_chunk_into_cache)")
+    scale = c.attention_multiplier or None       # None: head_dim^-1/2
     cos, sin = _rope_tables(c)
     use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
 
@@ -975,10 +1163,11 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         spec = logical_to_spec(dims, llama_rules())
         return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
-    def attend_linear(u, g, beta, conv_w):
-        # no cache: every sequence from an empty state
-        return jax.vmap(functools.partial(_attend_linear_rows, c=c),
-                        (0, 0, 0, None))(u, g, beta, conv_w), None
+    def attend_state(rows, *inputs):
+        # no cache: every sequence from an empty state; the last input
+        # is the layer's own leaves, the others are a sequence's
+        return jax.vmap(functools.partial(rows, c=c), (
+            *(0,) * (len(inputs) - 1), None))(*inputs), None
 
     def attend(window, xq, xk, xv, w_kvb=None):
         # no cache: the whole sequence attends over itself
@@ -991,23 +1180,28 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
                 raise ValueError("ring (sequence-parallel) attention "
                                  "computes no sliding window")
             out = attention(xq, xk, xv, causal=True, impl="blockwise",
-                            window=window)
+                            window=window, scale=scale)
         elif use_ring:
             from ant_ray_tpu.parallel.ring import ring_attention  # noqa: PLC0415
 
-            out = ring_attention(xq, xk, xv, mesh=mesh, causal=True)
+            out = ring_attention(xq, xk, xv, mesh=mesh, causal=True,
+                                 scale=scale)
         elif mesh is None:
-            out = attention(xq, xk, xv, causal=True, impl=attn_impl)
+            out = attention(xq, xk, xv, causal=True, impl=attn_impl,
+                            scale=scale)
         else:
             rules = llama_rules()
             out = attention(
                 xq, xk, xv, causal=True, impl=attn_impl, mesh=mesh,
+                scale=scale,
                 q_spec=logical_to_spec(
                     ("batch", "seq", "heads", "head_dim"), rules),
                 kv_spec=logical_to_spec(
                     ("batch", "seq", "kv_heads", "head_dim"), rules))
         kv = (xk.astype(c.dtype), xv.astype(c.dtype)) if return_kv else None
         return out, kv
+
+    state_rows = {"linear": _attend_linear_rows, "ssm": _attend_ssm_rows}
 
     def scan_stack(x, stack, cfg):
         scanned, whole = _by_period(stack, cfg)
@@ -1019,7 +1213,8 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
                 x, kv, _ = apply_block(
                     _place(layers, whole, j, cfg), x, cfg, cos, sin,
                     positions,
-                    attend_linear if kind == "linear" else functools.partial(
+                    functools.partial(attend_state, state_rows[kind])
+                    if kind in RECURRENT else functools.partial(
                         attend, cfg.window if kind == "window" else 0),
                     constrain_act, kind=kind)
                 kvs.append(kv)
@@ -1030,7 +1225,7 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         x, kv = lax.scan(_checkpointed(period, remat), x, scanned)
         return x, _unperiod(kv, cfg)
 
-    x = params["embed"][tokens].astype(c.dtype)
+    x = _embed(params, tokens, c)
     # Staged reshard: first acknowledge the gather's TABLE-natural
     # output sharding (embed dim carries the table's fsdp shards; batch
     # keeps its dp shard — fsdp moves from batch to embed for one hop),
@@ -1053,13 +1248,10 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     kv = kvs[0] if len(kvs) == 1 else jax.tree.map(
         lambda *parts: jnp.concatenate(parts), *kvs)
     x = _norm(x, params["norm_f"], c)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     if logits_at is not None:
-        x = jnp.take(x, logits_at, axis=1)          # (b, dim)
-        logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
+        logits = _head(params, jnp.take(x, logits_at, axis=1), c)
     else:
-        logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
-        logits = constrain_act(logits, ("batch", "seq", None))
+        logits = constrain_act(_head(params, x, c), ("batch", "seq", None))
     if return_kv:
         return logits, kv[0], kv[1]
     return logits
@@ -1097,15 +1289,16 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
     pp = mesh.shape["pp"]
     if c.n_layers % pp != 0:
         raise ValueError(f"n_layers {c.n_layers} % pp {pp} != 0")
-    if c.n_dense_layers or c.kv_lora_rank or c.window or c.n_linear:
+    if c.n_dense_layers or c.kv_lora_rank or c.window or c.recurrent:
         raise ValueError("the pipeline schedule runs one stack of like "
                          "grouped-query layers: no leading dense layers, "
                          "no latent attention, no window layers, no "
-                         "linear layers")
+                         "linear layers, no ssm layers")
     cos, sin = _rope_tables(c)
 
     def attend(xq, xk, xv):
-        return attention(xq, xk, xv, causal=True, impl=attn_impl), None
+        return attention(xq, xk, xv, causal=True, impl=attn_impl,
+                         scale=c.attention_multiplier or None), None
 
     def stage_fn(stage_layers, mx):
         def body(h, layer):
@@ -1116,7 +1309,7 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
         out, _ = lax.scan(body, mx, stage_layers)
         return out
 
-    x = params["embed"][inputs].astype(c.dtype)          # (b, s, d)
+    x = _embed(params, inputs, c)                        # (b, s, d)
     b = x.shape[0]
     if b % num_microbatches != 0:
         raise ValueError(
@@ -1128,9 +1321,7 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
         params["layers"])
     y = gpipe(stage_fn, stacked, micro, mesh=mesh)
     x = y.reshape(b, *y.shape[2:])
-    x = _norm(x, params["norm_f"], c)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
+    logits = _logits(params, x, c)
     import optax  # noqa: PLC0415
 
     return jnp.mean(
@@ -1183,14 +1374,21 @@ def kv_slabs(config: LlamaConfig) -> dict:
 
 
 def state_slabs(config: LlamaConfig) -> dict:
-    """What a LINEAR layer keeps of a sequence — a slot's, whatever its
-    length: the cache's state leaves by name, each (its shape a slot,
-    its dtype).  ``s``: the delta rule's state, (d_k, d_v) a head,
-    float32 (it is summed into over the whole sequence); ``conv``: the
-    last ``linear_conv - 1`` inputs of the convolution over q, k and v,
-    as the block made them.  Empty without linear layers.  Not among
-    ``kv_slabs``, whose third axis is positions: these have none."""
+    """What a RECURRENT layer keeps of a sequence — a slot's, whatever
+    its length: the cache's state leaves by name, each (its shape a
+    slot, its dtype), under the same two names for either kind.  ``s``:
+    the state, float32 (it is summed into over the whole sequence) — a
+    linear layer's (d_k, d_v) a head, a state-space layer's (P, N) a
+    head; ``conv``: the last ``taps - 1`` inputs of the layer's
+    convolution — over q, k and v, or over the heads' inputs and the
+    two directions — as the block made them.  Empty without such
+    layers.  Not among ``kv_slabs``, whose third axis is positions:
+    these have none."""
     c = config
+    if c.recurrent == "ssm":
+        return {"s": ((c.ssm_heads, c.ssm_head_dim, c.ssm_state),
+                      jnp.float32),
+                "conv": ((c.ssm_conv - 1, ssm_widths(c)[1]), c.dtype)}
     if not c.n_linear:
         return {}
     hd = c.linear_head_dim
@@ -1220,8 +1418,8 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     the full layers in (full layers, slots, max_seq, ...) and the window
     layers in rings (window layers, slots, ``ring_positions``, ...),
     both under ONE ``length``; ``chunk`` is the width its prompts are
-    ingested in.  A model's linear layers keep no positions but
-    ``state_slabs`` (linear layers, slots, ...), under the same
+    ingested in.  A model's recurrent layers keep no positions but
+    ``state_slabs`` (recurrent layers, slots, ...), under the same
     ``length``; its full layers alone have slabs.  A routed model's
     cache also carries ``routing``, the
     step programs' running counters (``ROUTING_COUNTERS``).
@@ -1240,7 +1438,7 @@ def init_kv_cache(config: LlamaConfig, slots: int,
          else (n_full, slots, ms)) + position, c.dtype)
         for name, position in kv_slabs(c).items()}
     for name, (shape, dtype) in state_slabs(c).items():
-        cache[name] = jnp.zeros((c.n_linear, slots) + shape, dtype)
+        cache[name] = jnp.zeros((c.n_recurrent, slots) + shape, dtype)
     # tokens already written per slot (== next write position)
     cache["length"] = jnp.zeros((slots,), jnp.int32)
     if c.num_experts:
@@ -1288,14 +1486,15 @@ def _grouped_tile(c: LlamaConfig, rows: int, mesh) -> int:
         rows * c.experts_per_token / (c.router_width or c.num_experts))
 
 
-def _hoist_experts(layers: dict, c: LlamaConfig, linear: bool = False):
-    """A stack of layers (``linear``: the model's linear layers') as
+def _hoist_experts(layers: dict, c: LlamaConfig, stack: str = "layers"):
+    """A stack of layers (``stack``: its name, as ``place`` gives it) as
     ``_scan_layers``' scan takes it: ``(the
     leaves it slices layer by layer (``_by_period``), the expert
     matrices it closes over whole, the period indices it scans beside
     them)`` — see ``_routed_mlp`` on why; a dense stack's layers are all
     sliced."""
-    a_period = sum((kind == "linear") == linear for kind in c.kinds)
+    a_period = sum(RECURRENT.get(kind, "layers") == stack
+                   for kind in c.kinds)
     index = jnp.arange(layers["ln_attn"].shape[0] // a_period)
     if not c.num_experts:
         return layers, {}, index
@@ -1474,7 +1673,8 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
     if w_kvb is None:
         q = xq.reshape(rows, c.n_kv_heads, c.n_heads // c.n_kv_heads,
                        c.head_dim)
-        scale = 1 / jnp.sqrt(jnp.float32(c.head_dim))
+        scale = c.attention_multiplier or 1 / jnp.sqrt(
+            jnp.float32(c.head_dim))
         heads, width = q.shape[1:3], c.head_dim
 
         def scores(bk, bv):
@@ -1568,10 +1768,11 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     old slab with the new rows beside it compiles to more temporaries
     and reorders the float32 sums.
 
-    ``write_state(s, conv, i, u, g, beta, conv_w) -> (out, (s, conv))``
-    is a LINEAR layer's: it runs the call's rows from the carried
-    states of linear layer ``i`` (``state_slabs``) and leaves the new
-    ones where they lay; a model without linear layers never calls it."""
+    ``write_state(s, conv, i, *inputs) -> (out, (s, conv))`` is a
+    RECURRENT layer's, ``inputs`` what ``apply_block`` hands the kind's
+    ``attend``: it runs the call's rows from the carried states of
+    recurrent layer ``i`` (``state_slabs``) and leaves the new ones
+    where they lay; a model without such layers never calls it."""
     # as far as a row's position goes: a full slot's is max_seq itself,
     # a chunk's last padded row's max_seq + chunk - 2
     cos, sin = _rope_tables(c, _slab_positions(cache, c) + x.shape[0])
@@ -1581,7 +1782,7 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
 
     def scan_stack(carry, stacks, cfg, first):
         """``first``: the run's first layer's place in the cache."""
-        hoisted = {name: _hoist_experts(stack, cfg, name == LINEAR)
+        hoisted = {name: _hoist_experts(stack, cfg, name)
                    for name, stack in stacks.items()}
         experts = {name: parts[1] for name, parts in hoisted.items()}
         layers, whole = _by_period(
@@ -1597,7 +1798,7 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                 # the layer's place among the slabs of its kind
                 i = (p * kinds.count(kind) + kinds[:j].count(kind)
                      + (first if kind == "full" else 0))
-                if kind == "linear":
+                if kind in RECURRENT:
                     held = states
                     attend = functools.partial(
                         write_state, *(slabs[name] for name in held), i)
@@ -1660,20 +1861,30 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
         return _attend_slab(xq, ks, vs, i, slot, pos, blocks[ks.shape[2]],
                             c, w_kvb, window, top)
 
-    def state(s, conv, i, u, g, beta, conv_w):
-        """A linear layer: the chunk from (layer i, slot)'s state — from
-        an EMPTY one where the prompt begins there (``start`` 0: what
-        the slot's last occupant left is never read) — and back goes the
-        state behind the chunk's last REAL token: padding neither
-        decays nor writes, and the convolution's tail is the last
-        real inputs."""
+    def state(s, conv, i, u, *inputs):
+        """A recurrent layer: the chunk from (layer i, slot)'s state —
+        from an EMPTY one where the prompt begins there (``start`` 0:
+        what the slot's last occupant left is never read) — and back
+        goes the state behind the chunk's last REAL token: padding
+        neither decays nor writes, and the convolution's tail is the
+        last real inputs."""
         real = offs < chunk_len
         s0 = jnp.where(start == 0, 0.0, s[i, slot])
         tail = jnp.where(start == 0, 0, conv[i, slot]).astype(conv.dtype)
-        y, ext = delta_rule.causal_conv(u.astype(conv.dtype), tail, conv_w)
-        out, s1 = delta_rule.chunk_delta_rule(
-            *_linear_qkv(y, c), jnp.where(real[:, None, None], g, 0.0),
-            jnp.where(real[:, None], beta, 0.0), s0)
+        u = u.astype(conv.dtype)
+        if c.recurrent == "ssm":
+            dt, weights = inputs
+            y, ext = delta_rule.causal_conv(
+                u, tail, weights["conv_w"], weights["conv_b"])
+            out, s1 = ssd.chunk_ssd(
+                *_ssm_run(y, jnp.where(real[:, None], dt, 0.0), weights, c),
+                s0, operand=c.dtype)
+        else:
+            g, beta, conv_w = inputs
+            y, ext = delta_rule.causal_conv(u, tail, conv_w)
+            out, s1 = delta_rule.chunk_delta_rule(
+                *_linear_qkv(y, c), jnp.where(real[:, None, None], g, 0.0),
+                jnp.where(real[:, None], beta, 0.0), s0)
         tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
         return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
 
@@ -1709,13 +1920,21 @@ def _decode_rows(cache: dict, c: LlamaConfig, active):
         return _attend_slab(xq, ks, vs, i, None, pos, blocks[ks.shape[2]],
                             c, w_kvb, window, pos)
 
-    def state(s, conv, i, u, g, beta, conv_w):
-        """A linear layer: one token a slot from layer i's states; a
+    def state(s, conv, i, u, *inputs):
+        """A recurrent layer: one token a slot from layer i's states; a
         slot that is not ``active`` — free, or between two chunks of
         its own prompt — keeps its state and its tail bit for bit."""
-        y, tail = delta_rule.causal_conv_step(u, conv[i], conv_w)
-        out, new = delta_rule.delta_rule_step(
-            *_linear_qkv(y, c), g, beta, s[i], active)
+        if c.recurrent == "ssm":
+            dt, weights = inputs
+            y, tail = delta_rule.causal_conv_step(
+                u, conv[i], weights["conv_w"], weights["conv_b"])
+            out, new = ssd.ssd_step(*_ssm_run(y, dt, weights, c), s[i],
+                                    active)
+        else:
+            g, beta, conv_w = inputs
+            y, tail = delta_rule.causal_conv_step(u, conv[i], conv_w)
+            out, new = delta_rule.delta_rule_step(
+                *_linear_qkv(y, c), g, beta, s[i], active)
         tail = jnp.where(active[:, None, None], tail, conv[i])
         return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),
                      lax.dynamic_update_index_in_dim(conv, tail, i, 0))
@@ -1731,8 +1950,8 @@ def _row_groups(*groups):
     first, then each attends over them as it does alone, one group
     after the other, and the outputs are joined again — the groups'
     slots are disjoint, so the writes do not meet and a group reads
-    what it would read alone.  A linear layer's states go through the
-    groups in turn.  One group is the group itself: nothing is split or
+    what it would read alone.  A recurrent layer's states go through
+    the groups in turn.  One group is the group itself: nothing is split or
     joined."""
     edges = [0]
     for rows, *_ in groups:
@@ -1763,11 +1982,11 @@ def _row_groups(*groups):
             outs.append(attend(ks, vs, i, window, q, w_kvb))
         return join(outs), (ks, vs)
 
-    def write_state(s, conv, i, u, g, beta, conv_w):
+    def write_state(s, conv, i, *inputs):
+        # the last input is the layer's own leaves, the others are rows
         outs = []
-        for (*_, state), *mine in zip(groups, split(u), split(g),
-                                      split(beta)):
-            out, (s, conv) = state(s, conv, i, *mine, conv_w)
+        for (*_, state), *mine in zip(groups, *map(split, inputs[:-1])):
+            out, (s, conv) = state(s, conv, i, *mine, inputs[-1])
             outs.append(out)
         return join(outs), (s, conv)
 
@@ -1775,10 +1994,21 @@ def _row_groups(*groups):
             write_state)
 
 
+def _embed(params: dict, tokens, c: LlamaConfig):
+    """Token ids -> their rows of the embedding, times the config's
+    ``embedding_multiplier``."""
+    x = params["embed"][tokens].astype(c.dtype)
+    if c.embedding_multiplier != 1.0:
+        x = x * c.embedding_multiplier
+    return x
+
+
 def _head(params: dict, x, c: LlamaConfig):
-    """Normed rows -> their logits, float32."""
+    """Normed rows -> their logits, float32, over the config's
+    ``logits_scaling``."""
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    return (x @ head.astype(c.dtype)).astype(jnp.float32)
+    logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
+    return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling
 
 
 def _logits(params: dict, x, c: LlamaConfig):
@@ -1823,7 +2053,7 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
-    x = params["embed"][tokens].astype(c.dtype)  # (chunk, dim)
+    x = _embed(params, tokens, c)                # (chunk, dim)
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(_chunk_rows(
             cache, c, tokens.shape[0], slot, start, chunk_len)),
@@ -1854,7 +2084,7 @@ def decode_step(params: dict, last_tokens, cache: dict,
     (``_grouped_tile``).
     """
     c = config
-    x = params["embed"][last_tokens].astype(c.dtype)   # (slots, dim)
+    x = _embed(params, last_tokens, c)                 # (slots, dim)
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(_decode_rows(cache, c, active)),
         decode=True, mesh=mesh)
@@ -1893,8 +2123,7 @@ def mixed_step(params: dict, last_tokens, tokens, cache: dict,
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
-    x = params["embed"][jnp.concatenate([last_tokens, tokens])].astype(
-        c.dtype)                                 # (slots + chunk, dim)
+    x = _embed(params, jnp.concatenate([last_tokens, tokens]), c)
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(
             _decode_rows(cache, c, active),

@@ -46,25 +46,26 @@ BLOCK = 64
 _HIGHEST = lax.Precision.HIGHEST
 
 
-def causal_conv(u, tail, w):
+def causal_conv(u, tail, w, bias=None):
     """The depth-wise causal convolution over a sequence: ``y_t = sum_j
     w_j * ext[t + j]`` with ``ext`` the ``taps - 1`` inputs before the
     sequence (``tail``, zeros at a sequence's start) and then ``u``
-    (tokens, channels); ``w`` (taps, channels), a channel its own taps.
+    (tokens, channels); ``w`` (taps, channels), a channel its own taps,
+    and its own ``bias`` (channels,) where the layer has one.
     Returns (y float32, ext)."""
     taps, tokens = w.shape[0], u.shape[0]
     ext = jnp.concatenate([tail.astype(u.dtype), u])
     y = sum(ext[j:j + tokens].astype(jnp.float32)
             * w[j].astype(jnp.float32) for j in range(taps))
-    return y, ext
+    return y if bias is None else y + bias.astype(jnp.float32), ext
 
 
-def causal_conv_step(u, tail, w):
+def causal_conv_step(u, tail, w, bias=None):
     """``causal_conv`` of one token a row: u (rows, channels), tail
     (rows, taps - 1, channels) -> (y float32, the new tail)."""
     ext = jnp.concatenate([tail, u[:, None].astype(tail.dtype)], axis=1)
     y = jnp.sum(ext.astype(jnp.float32) * w.astype(jnp.float32), axis=1)
-    return y, ext[:, 1:]
+    return y if bias is None else y + bias.astype(jnp.float32), ext[:, 1:]
 
 
 def delta_rule_scan(q, k, v, g, beta, s0):

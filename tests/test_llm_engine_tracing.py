@@ -420,22 +420,25 @@ RECURRENT_COUNTERS = ("recurrent_decode_rows", "recurrent_slot_rows",
                       "recurrent_resets")
 
 
-def test_recurrent_state_traffic_is_counted_by_the_devices_rule(monkeypatch):
-    """The five ``recurrent_*`` counters (PR 38) of a model with linear
-    layers (six here): per decode step the rows it decoded and the
+@pytest.mark.parametrize("name", ["solar2-tiny", "granite-h-tiny"])
+def test_recurrent_state_traffic_is_counted_by_the_devices_rule(
+        monkeypatch, name):
+    """The five ``recurrent_*`` counters (PR 38) of a model with
+    recurrent layers — linear or state-space ones (PR 42), six here
+    either way: per decode step the rows it decoded and the
     slots its program touched, per chunk its real tokens and its width,
-    each times the linear layers, and the chunks that began a prompt —
-    from the host's own bookkeeping, exactly what the device makes of
+    each times the recurrent layers, and the chunks that began a prompt
+    — from the host's own bookkeeping, exactly what the device makes of
     the programs' own inputs (the ``active`` mask, a chunk's ``start``
     and length).  ``decode_span_positions`` counts the softmax layers'
-    walk alone: a linear layer walks nothing."""
+    walk alone: a recurrent layer walks nothing."""
     import numpy as np
 
     from chipbench.layer_metrics import recurrent_state_live_pct
 
     monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
-    cfg = llama.CONFIGS["solar2-tiny"]
-    assert cfg.n_linear == 6 and cfg.layer_counts() == (0, 2)
+    cfg = llama.CONFIGS[name]
+    assert cfg.n_recurrent == 6 and cfg.layer_counts() == (0, 2)
     eng = LLMEngine(cfg, slots=3, max_seq=96, prefill_chunk_tokens=8,
                     tokenizer=_NoEos())
     assert eng._ring == 0
@@ -499,7 +502,9 @@ def test_recurrent_state_traffic_is_counted_by_the_devices_rule(monkeypatch):
     ("axk1-tiny", {"mla", "moe", "moe_shared"}),
     ("cmdaplus-tiny", {"moe", "moe_shared", "attn_window", "attn_full"}),
     ("solar2-tiny", {"moe", "moe_shared", "attn_full", "attn_linear",
-                     "kda_step"})])
+                     "kda_step"}),
+    ("granite-h-tiny", {"moe", "moe_shared", "attn_full", "attn_ssm",
+                        "ssd_step"})])
 def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     """What a routed model's step programs record, always on: the
     routing counters (``llama.ROUTING_COUNTERS``, PR 27; ``moe_rows_routed``
@@ -511,7 +516,9 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     ``attn_full`` around the two ways a grouped-query layer attends
     (PR 34), ``attn_linear`` around a linear layer's mix and inside it
     ``kda_step`` around the delta rule's step (PR 38; ``kda_chunk``
-    around its block form, in the chunk program)."""
+    around its block form, in the chunk program), ``attn_ssm`` around a
+    state-space layer's mix and inside it ``ssd_step`` and ``ssd_chunk``
+    likewise (PR 42)."""
     cfg = llama.CONFIGS[name]
     assert llama.ROUTING_COUNTERS == (
         "moe_assignments", "moe_experts_hit", "moe_expert_slots",
@@ -546,17 +553,19 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
         eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool))
     text = lowered.as_text(debug_info=True)
     for scope in ("mla", "moe", "moe_shared", "attn_window", "attn_full",
-                  "attn_linear", "kda_step"):
+                  "attn_linear", "kda_step", "attn_ssm", "ssd_step"):
         assert (f'{scope}/' in text) == (scope in scopes), scope
     chunk = eng._prefill_chunk_jit.lower(
         eng.params, eng.cache, eng._jnp.zeros((8,), "int32"), 0, 0, 3)
-    assert ("attn_linear/kda_chunk/" in chunk.as_text(debug_info=True)) == (
-        "attn_linear" in scopes)
-    assert "kda_chunk/" not in text
+    block_forms = ("attn_linear/kda_chunk", "attn_ssm/ssd_chunk")
+    for scope in block_forms:
+        assert (f"{scope}/" in chunk.as_text(debug_info=True)) == (
+            scope.split("/")[0] in scopes), scope
+    assert "kda_chunk/" not in text and "ssd_chunk/" not in text
     mixed = eng._mixed_step_jit.lower(
         eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool),
         eng._jnp.zeros((8,), "int32"), 0, 0, 3).as_text(debug_info=True)
-    for scope in (*scopes, "attn_linear/kda_chunk"):
+    for scope in (*scopes, *block_forms):
         assert (f'{scope}/' in mixed) == (
             scope.split("/")[0] in scopes), scope
 

@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from ant_ray_tpu.llm import LLMEngine
 from ant_ray_tpu.models import llama
 
-PRESETS = ["tiny", "olmoe-tiny", "cmdaplus-tiny", "axk1-tiny", "solar2-tiny"]
+PRESETS = ["tiny", "olmoe-tiny", "cmdaplus-tiny", "axk1-tiny", "solar2-tiny",
+           "granite-h-tiny"]
 SLOTS, MAX_SEQ, CHUNK = 4, 96, 8
 
 

@@ -607,7 +607,7 @@ def test_a_window_pattern_is_layer_kinds_in_booleans():
     with pytest.raises(ValueError, match="give one of them"):
         dataclasses.replace(cmd, window_pattern=(True, False))
     with pytest.raises(ValueError, match="unknown layer kinds"):
-        dataclasses.replace(llama.CONFIGS["tiny"], layer_kinds=("ssm",))
+        dataclasses.replace(llama.CONFIGS["tiny"], layer_kinds=("hyena",))
     with pytest.raises(ValueError, match="linear layers state"):
         dataclasses.replace(llama.CONFIGS["tiny"],
                             layer_kinds=("full", "linear"))

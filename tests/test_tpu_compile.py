@@ -358,6 +358,60 @@ def test_recurrent_state_fits_the_chip_at_the_cells_size(
     assert "grouped_matmul" in text and "ragged-dot" not in text
 
 
+# Granite 4.0-H Small's blocks at their published widths, the
+# benchmark's cut: one period of five Mamba-2 state-space layers (128
+# heads of 64 with a state of 128, a convolution of 4 taps with bias
+# over 8,448 channels), a softmax layer without positional embedding
+# (32 query / 8 KV heads of 128, scale 1/128) and four more state-space
+# layers; a softmax router over 72 experts of 4096 x 768, 10 a token, 36
+# held, a shared SwiGLU of 1536, the four multipliers, half the
+# vocabulary, the head tied.
+STATE_SPACE = llama.LlamaConfig(
+    vocab_size=50176, dim=4096, n_layers=10, n_heads=32, n_kv_heads=8,
+    mlp_dim=768, max_seq=131072, rope_theta=10000.0, norm_eps=1e-5,
+    tie_embeddings=True, num_experts=36, experts_per_token=10,
+    router_width=72, n_shared_experts=2, full_rope=False,
+    layer_kinds=("ssm",) * 5 + ("full",) + ("ssm",) * 4,
+    ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    attention_multiplier=1 / 128, logits_scaling=16.0)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_state_space_state_fits_the_chip_at_the_cells_size(
+        v5e, monkeypatch, program):
+    """`granite-4.0-h-small.sessions` as it is served: 48 slots x 6,144,
+    ONE layer's slabs (1.125 GiB) beside nine layers' states (a slot
+    128 heads of 64 x 128 float32, 4 MiB, and the convolution's 3 x
+    8,448 tail: 1.69 GiB), prompts in chunks of 512 — 560 rows with the
+    slots, over ``engine.RIDE_ROWS``: the chunk and the decode step stay
+    two programs — the grouped kernel over the 36 experts held.  PR
+    28's sizing rule as above; the block form's decays of one block
+    (128 heads x 256 x 256 float32, 32 MiB) are among the
+    temporaries."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, cache = _compile_step(
+        v5e.devices[0], program, STATE_SPACE, 48, 6144, chunk=512)
+    kept = {name: cache[name] for name in (
+        *llama.kv_slabs(STATE_SPACE), *llama.state_slabs(STATE_SPACE))}
+    assert {name: (leaf.shape, leaf.dtype.name)
+            for name, leaf in kept.items()} == {
+        "k": ((1, 48, 6144, 8, 128), "bfloat16"),
+        "v": ((1, 48, 6144, 8, 128), "bfloat16"),
+        "s": ((9, 48, 128, 64, 128), "float32"),
+        "conv": ((9, 48, 3, 8448), "bfloat16")}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    a_layer = _tree_bytes((cache["k"], cache["v"]))
+    assert mem.temp_size_in_bytes < a_layer
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < _tree_bytes(params) + _tree_bytes(kept) + a_layer
+    assert need < 13.0 * 2 ** 30
+    text = compiled.as_text()
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+
+
 # llama3-1b with its 2048 columns of attention as 16 heads of 128 (8 of
 # them KV heads: InternLM2-1.8B's attention), the head width of every
 # configuration the benchmark serves.
