@@ -22,7 +22,7 @@ import uuid
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import asyncio
 
@@ -78,11 +78,14 @@ class _StreamState:
     """Owner-side bookkeeping of one streaming task's returns
     (ref: ObjectRefStream, src/ray/core_worker/task_manager.h:67)."""
 
-    received: int = 0                  # contiguous items stored so far
+    received: int = 0                  # contiguous items arrived so far
     total: int | None = None           # set by the end-of-stream marker
     error: Exception | None = None     # mid-stream task failure
     cond: threading.Condition = field(
         default_factory=threading.Condition)
+    # A subscriber's ``sink(index, kind, data)``: set, items are pushed
+    # to it as they arrive and nothing is stored for a puller.
+    sink: Callable | None = None
 
 
 @dataclass
@@ -1789,20 +1792,26 @@ class ClusterRuntime(CoreRuntime):
         """A streaming task produced its next item (worker → owner,
         ordered oneway on one connection)."""
         task_id = payload["task_id"]
-        oid = ObjectID.for_task_return(task_id, payload["index"])
+        index, kind, data = payload["index"], payload["kind"], payload["data"]
+        oid = ObjectID.for_task_return(task_id, index)
         if task_id in self._released_streams:
             # The consumer abandoned this stream; drop the item instead
             # of storing it forever (plasma copies are freed explicitly).
-            if payload["kind"] == "plasma":
+            if kind == "plasma":
                 self._send_oneway(self.gcs_address, "FreeObject",
                                   {"object_id": oid})
             return True
-        self.memory.put(oid, payload["kind"], payload["data"])
         state = self._streams.get(task_id)
-        if state is not None:
-            with state.cond:
-                state.received = max(state.received, payload["index"] + 1)
+        if state is None:
+            self.memory.put(oid, kind, data)
+            return True
+        with state.cond:
+            state.received = max(state.received, index + 1)
+            if state.sink is None:
+                self.memory.put(oid, kind, data)
                 state.cond.notify_all()
+            else:
+                self._push_stream_item(task_id, state, index, kind, data)
         return True
 
     def _register_stream(self, task_id: TaskID) -> None:
@@ -1817,6 +1826,62 @@ class ClusterRuntime(CoreRuntime):
             state.total = total
             state.error = error
             state.cond.notify_all()
+            if state.sink is not None:
+                self._push_stream_item(task_id, state, total, "end", error)
+
+    def _push_stream_item(self, task_id: TaskID, state: _StreamState,
+                          index: int, kind: str, data) -> None:
+        """Hand one item (or the end marker) to the stream's subscriber;
+        under ``state.cond``.  An inline payload goes as it came and is
+        stored nowhere; an item too large to be inline lives in the
+        object plane and the sink gets its ref.  The owner forgets the
+        stream once the marker and every item before it are handed
+        over; a sink that raises has abandoned it."""
+        if kind == "plasma":
+            oid = ObjectID.for_task_return(task_id, index)
+            self.memory.put(oid, kind, data)
+            kind, data = "ref", ObjectRef(oid, owner_address=self.address)
+        try:
+            state.sink(index, kind, data)
+        except Exception:  # noqa: BLE001 — the io thread must live on
+            logger.exception("stream %s: subscriber failed, releasing",
+                             task_id.hex()[:12])
+            self.release_stream(task_id, state.received)
+            return
+        if state.total is not None and state.received >= state.total:
+            self._streams.pop(task_id, None)
+
+    def subscribe_stream(self, task_id: TaskID, consumed: int,
+                         sink: Callable) -> None:
+        """Push instead of pull: from now on ``sink(index, kind, data)``
+        is called, on the io thread, for every item of the stream as it
+        arrives — ``("inline", serialized payload)``, or ``("ref",
+        ObjectRef)`` for an item too large to be inline — and once with
+        ``(total, "end", error or None)``.  The marker travels on
+        another connection than the items and may come before the last
+        of them: the stream is over when ``total`` items were seen.
+        Items from ``consumed`` on that arrived before the subscription
+        are handed over here, in order, on the caller's thread, and
+        leave the store.  The sink must not block; a subscriber that
+        stops listening calls ``release_stream``."""
+        state = self._streams.get(task_id)
+        if state is None:               # consumed to its end, or released
+            sink(consumed, "end", None)
+            return
+        with state.cond:
+            state.sink = sink
+            for index in range(consumed, state.received):
+                oid = ObjectID.for_task_return(task_id, index)
+                kind, data = self.memory.get_entry(oid)
+                if kind == "plasma":
+                    kind, data = "ref", ObjectRef(
+                        oid, owner_address=self.address)
+                else:
+                    self.memory.delete(oid)
+                sink(index, kind, data)
+            if state.total is not None:
+                self._push_stream_item(task_id, state, state.total, "end",
+                                       state.error)
 
     def stream_next(self, task_id: TaskID, index: int,
                     timeout: float | None):

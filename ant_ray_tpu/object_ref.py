@@ -141,12 +141,32 @@ class ObjectRefGenerator:
         except StopIteration:
             raise StopAsyncIteration from None
 
+    def subscribe(self, sink) -> None:
+        """Push instead of pull: the owner calls ``sink(index, kind,
+        data)`` for every item not yet taken from this generator, as it
+        arrives and on the runtime's io thread, and iteration is over
+        (``ClusterRuntime.subscribe_stream`` has the contract).  An
+        ``"inline"`` item's value is ``inline_value(data)``; a subscriber
+        that stops listening calls ``release()``."""
+        self._runtime.subscribe_stream(self._task_id, self._index, sink)
+
+    @staticmethod
+    def inline_value(payload):
+        """The value of a pushed ``"inline"`` item."""
+        return serialization.deserialize(
+            serialization.SerializedObject.from_payload(payload))
+
     @property
     def task_id(self):
         return self._task_id
 
+    def release(self) -> None:
+        """Abandon the stream: the owner drops its state, frees what was
+        stored and never taken, and drops items still on their way."""
+        self._runtime.release_stream(self._task_id, self._index)
+
     def __del__(self):
         try:
-            self._runtime.release_stream(self._task_id, self._index)
+            self.release()
         except Exception:  # noqa: BLE001 — interpreter shutdown etc.
             pass

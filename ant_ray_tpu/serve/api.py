@@ -2080,11 +2080,15 @@ def _stream_ingress_span(name: str, service: str, attrs: dict):
     the proxy down to ``llm:engine``.
 
     ``frames``, which the HTTP proxy keeps for a sampled root only:
-    one ``(perf_counter when written, seconds its pull waited for a
-    pool thread)`` a frame written.  They become ``frame_ms``,
-    milliseconds after the span's ``ts`` (the first is
-    ``first_chunk_s``), and ``pull_wait_ms``.  The gRPC proxy keeps
-    none: the call's own thread pulls its stream."""
+    one ``(perf_counter when written, seconds the ready item waited
+    inside the proxy)`` a ``data:`` frame written — the wait runs from
+    the owner's push on the io thread to the handler taking the item
+    off its queue.  They become ``frame_ms``, milliseconds after the
+    span's ``ts`` (the first is ``first_chunk_s``), and
+    ``pull_wait_ms``; the proxy adds ``writes``, the ``resp.write``
+    calls that carried those frames (``chunks`` over ``writes`` is 1
+    while it keeps up).  The gRPC proxy keeps none: the call's own
+    thread pulls its stream."""
     ctx = tracing_plane.mint()
     t_wall = time.time()
     t0 = time.perf_counter()
@@ -2235,18 +2239,6 @@ class HttpProxy:
                                   h._request_meta(timeout_s, trace=ctx))
             return (h, replica, gen)
 
-        def next_chunk(gen):
-            try:
-                ref = next(gen)
-            except StopIteration:
-                return None
-            return art.get(ref)
-
-        def timed_next_chunk(gen):
-            """``next_chunk`` of a sampled stream, behind the time the
-            pull reached its pool thread."""
-            return time.perf_counter(), next_chunk(gen)
-
         async def handler(request: "web.Request"):
             import json as _json  # noqa: PLC0415
 
@@ -2277,25 +2269,13 @@ class HttpProxy:
                     f"http:{request.path}", "http-proxy",
                     {"path": request.path})
 
-                # sampled: (written, its pull's wait for a pool thread)
-                # a data: frame
+                # sampled: (written, its wait in the proxy) a data: frame
                 frames = [] if ctx.sampled else None
+                writes = 0
 
                 def finish(status, first_chunk=None, chunks=0):
                     span_done(status >= 400, first_chunk, chunks, frames,
-                              status=status)
-
-                async def pull():
-                    """The stream's next chunk off the default pool,
-                    and of a sampled stream how long the pull waited
-                    for one of its threads."""
-                    if frames is None:
-                        return await loop_.run_in_executor(
-                            None, next_chunk, gen), None
-                    asked = time.perf_counter()
-                    began, chunk = await loop_.run_in_executor(
-                        None, timed_next_chunk, gen)
-                    return chunk, began - asked
+                              status=status, writes=writes)
 
                 def failed(e):
                     # NB: explicit None check — an unprepared
@@ -2321,41 +2301,97 @@ class HttpProxy:
                         {"error": f"no route for {request.path}"},
                         status=404)
                 sh, replica, gen = started
-                # Pull the FIRST chunk before sending SSE headers: the
-                # replica's admission gate / deadline check fires on
-                # generator start, so a shed must surface as the
-                # documented typed status — not a 200 that dies
-                # mid-stream with no Retry-After.
-                try:
-                    chunk, waited = await pull()
-                except Exception as e:  # noqa: BLE001 — classified below
-                    _record_result(sh._routing, replica, e)
-                    return failed(e)
-                resp = web.StreamResponse(
-                    headers={"Content-Type": "text/event-stream",
-                             "Cache-Control": "no-cache"})
-                await resp.prepare(request)
+                # The owner PUSHES the stream's items: its io thread
+                # hands each, as it arrives, to this request's queue —
+                # no pool thread waits on the stream (not through a
+                # prompt's queue + prefill either) and no item passes
+                # through the object store.
+                pushed: asyncio.Queue = asyncio.Queue()
+
+                def sink(index, kind, data):
+                    loop_.call_soon_threadsafe(
+                        pushed.put_nowait,
+                        (index, kind, data, time.perf_counter()))
+
+                async def sse_response():
+                    opened = web.StreamResponse(
+                        headers={"Content-Type": "text/event-stream",
+                                 "Cache-Control": "no-cache"})
+                    await opened.prepare(request)
+                    return opened
+
+                gen.subscribe(sink)
+                resp = None
                 first_chunk_s, chunks = None, 0
-                while chunk is not None:
-                    await resp.write(
-                        b"data: " + _json.dumps(chunk).encode() + b"\n\n")
-                    chunks += 1
-                    if frames is not None:
-                        frames.append((time.perf_counter(), waited))
-                    elif first_chunk_s is None:
-                        first_chunk_s = time.perf_counter()
-                    try:
-                        chunk, waited = await pull()
-                    except Exception as e:  # noqa: BLE001 — mid-stream
-                        # Headers already went out: feed the breaker
-                        # and end the stream (the client sees the
-                        # missing [DONE]).  resp.write failures (client
-                        # gone) are NOT replica outcomes and propagate.
-                        _record_result(sh._routing, replica, e)
-                        finish(500, first_chunk_s, chunks)
-                        await resp.write_eof()
-                        return resp
+                total = error = None
+                try:
+                    # The end marker travels on another connection than
+                    # the items and may overtake the last of them: the
+                    # stream is over at `chunks == total`, not at the
+                    # marker.
+                    while total is None or chunks < total:
+                        batch = [await pushed.get()]
+                        while not pushed.empty():
+                            batch.append(pushed.get_nowait())
+                        took = time.perf_counter()
+                        out, waited = [], []
+                        for index, kind, data, at in batch:
+                            if kind == "end":
+                                total, error = index, data
+                                continue
+                            if index != chunks + len(out):
+                                raise RuntimeError(
+                                    f"stream item {index} where "
+                                    f"{chunks + len(out)} was due")
+                            chunk = (gen.inline_value(data)
+                                     if kind == "inline" else
+                                     await loop_.run_in_executor(
+                                         None, art.get, data))
+                            out.append(b"data: " + _json.dumps(
+                                chunk).encode() + b"\n\n")
+                            waited.append(took - at)
+                        if not out:
+                            continue
+                        if resp is None:
+                            # The FIRST item is in hand before the SSE
+                            # headers go out: the replica's admission
+                            # gate / deadline check fires on generator
+                            # start, so a shed surfaces below as the
+                            # documented typed status — not a 200 that
+                            # dies mid-stream with no Retry-After.
+                            resp = await sse_response()
+                        # One frame an item; the frames one wake-up
+                        # found, in ONE write.  While the proxy keeps up
+                        # that is one item; behind, runs grow and the
+                        # cost a token falls.
+                        await resp.write(b"".join(out))
+                        writes += 1
+                        wrote = time.perf_counter()
+                        chunks += len(out)
+                        if frames is not None:
+                            frames += [(wrote, w) for w in waited]
+                        elif first_chunk_s is None:
+                            first_chunk_s = wrote
+                finally:
+                    # However the stream ends — the client gone
+                    # (resp.write raises: NOT a replica outcome, it
+                    # propagates), this handler cancelled — the owner
+                    # stops pushing and drops what is still on its way.
+                    # A stream handed over whole is already forgotten.
+                    gen.release()
+                if error is not None:
+                    _record_result(sh._routing, replica, error)
+                    if resp is None:
+                        return failed(error)
+                    # Headers already went out: the breaker is fed and
+                    # the stream ends (the client sees the missing
+                    # [DONE]).
+                    finish(500, first_chunk_s, chunks)
+                    await resp.write_eof()
+                    return resp
                 _record_result(sh._routing, replica)
+                if resp is None:                  # a stream of no items
+                    resp = await sse_response()
                 await resp.write(b"data: [DONE]\n\n")
                 finish(200, first_chunk_s, chunks)
                 await resp.write_eof()

@@ -900,10 +900,11 @@ def test_ingress_span_of_a_stream_carries_a_sampled_roots_frames(
         assert _spans(ctx.trace_id) == []
         return
     finish(False, None, 2, [(at + 0.010, 0.0012), (at + 0.030, 0.25)],
-           status=200)
+           status=200, writes=2)
     (span,) = _spans(ctx.trace_id)
     attrs = span["attrs"]
     assert attrs["chunks"] == len(attrs["frame_ms"]) == 2
+    assert attrs["writes"] <= attrs["chunks"]
     assert attrs["frame_ms"][0] == pytest.approx(
         1000 * attrs["first_chunk_s"], abs=0.006)
     assert attrs["frame_ms"][1] - attrs["frame_ms"][0] == pytest.approx(
@@ -965,6 +966,7 @@ def test_streamed_request_is_one_trace_from_proxy_to_engine(shutdown_only):
         frame_ms = http["attrs"]["frame_ms"]
         waits = http["attrs"]["pull_wait_ms"]
         assert len(frame_ms) == len(waits) == http["attrs"]["chunks"]
+        assert 1 <= http["attrs"]["writes"] <= http["attrs"]["chunks"]
         assert len(frame_ms) == len(engine["attrs"]["emit_ms"]) + 1
         assert frame_ms[0] == pytest.approx(
             1000 * http["attrs"]["first_chunk_s"], abs=0.006)
