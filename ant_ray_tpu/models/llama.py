@@ -638,7 +638,9 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     (``_latent_qkv``).  ``positions``: int32 of ``x``'s leading shape,
     None for arange over the sequence.  ``index``: the layer's number
     where ``layer`` holds the whole stack's expert matrices, ``tile``
-    the grouped kernel's row tile there (``_routed_mlp``).
+    the grouped kernel's row tile there (``_routed_mlp``); only a STEP
+    program passes it, and the products it reshapes to heads and its
+    MLP's then spell their rounding out (``_proj``, ``_swiglu``).
     ``kind``: the layer's, of ``LlamaConfig.kinds``.  A "window"
     layer's ``attend`` masks accordingly; here the kind decides whether
     the heads are rotated (a full layer of a ``full_rope=False`` model
@@ -657,7 +659,7 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     the residual is multiplied by ``residual_multiplier`` where the
     config states one.  Returns ``(x, state, load)``,
     ``load`` as ``_mlp`` gives it."""
-    lead = x.shape[:-1]
+    lead, step = x.shape[:-1], index is not None
     h = _norm(x, layer["ln_attn"], c)
     if kind == "ssm":
         with jax.named_scope("attn_ssm"):
@@ -679,13 +681,15 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
             attn = attn.astype(x.dtype)
     elif c.kv_lora_rank:
         with jax.named_scope("mla"):
-            xq, c_kv, k_rope = _latent_qkv(layer, h, c, cos, sin, positions)
+            xq, c_kv, k_rope = _latent_qkv(layer, h, c, cos, sin, positions,
+                                           step)
             attn, state = attend(xq, c_kv, k_rope, layer["w_kvb"])
     else:
-        xq, xk = _qk_proj(layer, h, c)
+        xq, xk = _qk_proj(layer, h, c, step)
         xq = xq.reshape(*lead, c.n_heads, c.head_dim)
         xk = xk.reshape(*lead, c.n_kv_heads, c.head_dim)
-        xv = (h @ layer["wv"]).reshape(*lead, c.n_kv_heads, c.head_dim)
+        xv = _proj(h, layer["wv"], step).reshape(
+            *lead, c.n_kv_heads, c.head_dim)
         if kind == "window" or c.full_rope:
             xq = apply_rope(xq, cos, sin, positions)
             xk = apply_rope(xk, cos, sin, positions)
@@ -824,20 +828,22 @@ def _attend_linear_rows(u, g, beta, conv_w, c: LlamaConfig):
     return out
 
 
-def _qk_proj(layer: dict, h, c: LlamaConfig):
+def _qk_proj(layer: dict, h, c: LlamaConfig, step: bool = False):
     """The q and k projections of ``h`` (..., dim), still flat
     (..., heads * head_dim): with ``qk_norm`` each is RMS-normalised
     over its WHOLE width — all heads together, as OLMoE publishes it,
     not head by head — before the caller splits heads and applies RoPE.
-    The one place q/k are made, for training, chunks and decode."""
-    xq, xk = h @ layer["wq"], h @ layer["wk"]
+    The one place q/k are made, for training, chunks and decode;
+    ``step`` as ``_proj`` takes it."""
+    xq, xk = _proj(h, layer["wq"], step), _proj(h, layer["wk"], step)
     if c.qk_norm:
         xq = rmsnorm(xq, layer["q_norm"], c.norm_eps)
         xk = rmsnorm(xk, layer["k_norm"], c.norm_eps)
     return xq, xk
 
 
-def _latent_qkv(layer: dict, h, c: LlamaConfig, cos, sin, positions):
+def _latent_qkv(layer: dict, h, c: LlamaConfig, cos, sin, positions,
+                step: bool = False):
     """Latent attention's three products of ``h`` (..., dim), as
     DeepSeek-V2 (arXiv 2405.04434, section 2.1) publishes them: the
     queries ``xq`` (..., heads, nope + rope) through a low-rank
@@ -848,10 +854,12 @@ def _latent_qkv(layer: dict, h, c: LlamaConfig, cos, sin, positions):
     the position; per-head keys and values are ``c_kv @ w_kvb``, made
     (``_attend_latent_rows``) or absorbed (``_attend_slab``)
     where the scores are.  The one place they are made, for training,
-    chunks and decode."""
+    chunks and decode; ``step`` as ``_proj`` takes it, for the one of
+    the three whose result is split into heads."""
     nope, rank = c.qk_nope_head_dim, c.kv_lora_rank
     xq = rmsnorm(h @ layer["w_qa"], layer["q_a_norm"], c.norm_eps)
-    xq = (xq @ layer["w_qb"]).reshape(*h.shape[:-1], c.n_heads, c.head_dim)
+    xq = _proj(xq, layer["w_qb"], step).reshape(
+        *h.shape[:-1], c.n_heads, c.head_dim)
     xq = jnp.concatenate(
         [xq[..., :nope], apply_rope(xq[..., nope:], cos, sin, positions)],
         axis=-1)
@@ -898,21 +906,39 @@ def _rounded(x, dtype):
     return lax.reduce_precision(x, info.nexp, info.nmant).astype(dtype)
 
 
+def _proj(h, w, step: bool):
+    """``h @ w``.  ``step``: in a step program the product's float32
+    sums and their one rounding to the activations' dtype are spelt
+    out — what the bare product in bfloat16 means, no other precision.
+    Two things hang on the spelling there.  The weight is a layer's
+    slice of its stack: before a reshape to heads the TPU compiler
+    gives the bare product a heads-major result, wants the weight
+    transposed for it, and so slices the layer out of the stack and
+    copies it again, a layer a step (Mistral-7B: 48 MiB twice, 2 ms of
+    a 12.6 ms decode step; PERF.md section 6, PR 44); behind the named
+    rounding the slice stays inside the product's fusion, as every
+    other product of the block has it.  And the compiler may keep a
+    bare product's sums unrounded into the operation it fuses it with
+    (``_swiglu``), which one depending on the rows.  Training keeps the
+    bare form."""
+    if not step:
+        return h @ w
+    return _rounded(jnp.dot(h, w, preferred_element_type=jnp.float32),
+                    h.dtype)
+
+
 def _swiglu(h, w_gate, w_up, w_down, step: bool = False):
     """``step``: in a step program the gate's and the up product's
-    rounding to the activations' dtype is spelt out.  The TPU compiler
-    fuses ONE of the two products with the elementwise tail and then
-    keeps that one's float32 sums unrounded (its "excess precision");
-    which one depends on the rows — the gate's for 12 decode rows, the
-    up product's for 76 — so a row's bits depended on the program it
-    went through (InternLM2: decode logits 2.6e-2 off, PERF.md section
-    6, PR 39).  Rounded by name both are what the equations say, in
-    every program.  Training keeps the bare form."""
-    if not step:
-        return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
-    gate, up = (_rounded(jnp.dot(h, w, preferred_element_type=jnp.float32),
-                         h.dtype) for w in (w_gate, w_up))
-    return (jax.nn.silu(gate) * up) @ w_down
+    rounding to the activations' dtype is spelt out (``_proj``).  The
+    TPU compiler fuses ONE of the two products with the elementwise
+    tail and then keeps that one's float32 sums unrounded (its "excess
+    precision"); which one depends on the rows — the gate's for 12
+    decode rows, the up product's for 76 — so a row's bits depended on
+    the program it went through (InternLM2: decode logits 2.6e-2 off,
+    PERF.md section 6, PR 39).  Rounded by name both are what the
+    equations say, in every program.  Training keeps the bare form."""
+    return (jax.nn.silu(_proj(h, w_gate, step)) * _proj(h, w_up, step)
+            ) @ w_down
 
 
 def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):

@@ -80,10 +80,11 @@ def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
     def block(x, scanned):
         layer, ck_all, cv_all, i = scanned
         h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-        xq, xk = llama._qk_proj(layer, h, c)
+        xq, xk = llama._qk_proj(layer, h, c, True)
         xq = xq.reshape(chunk, c.n_heads, c.head_dim)
         xk = xk.reshape(chunk, c.n_kv_heads, c.head_dim)
-        xv = (h @ layer["wv"]).reshape(chunk, c.n_kv_heads, c.head_dim)
+        xv = llama._proj(h, layer["wv"], True).reshape(
+            chunk, c.n_kv_heads, c.head_dim)
         xq = _rope_one(xq, pc, ps)
         xk = _rope_one(xk, pc, ps)
         ck = lax.dynamic_index_in_dim(ck_all, slot, axis=0,
@@ -142,10 +143,11 @@ def scanned_decode_step(params, last_tokens, cache, config, active=None):
     def block(x, scanned):
         layer, ck, cv, i = scanned
         h = rmsnorm(x, layer["ln_attn"], c.norm_eps)
-        xq, xk = llama._qk_proj(layer, h, c)
+        xq, xk = llama._qk_proj(layer, h, c, True)
         xq = xq.reshape(slots, c.n_heads, c.head_dim)
         xk = xk.reshape(slots, c.n_kv_heads, c.head_dim)
-        xv = (h @ layer["wv"]).reshape(slots, c.n_kv_heads, c.head_dim)
+        xv = llama._proj(h, layer["wv"], True).reshape(
+            slots, c.n_kv_heads, c.head_dim)
         pc = cos[pos][:, None, :]
         ps = sin[pos][:, None, :]
         xq = _rope_one(xq, pc, ps)

@@ -310,6 +310,64 @@ def test_every_program_runs_the_one_block(tiny_params, monkeypatch, program):
     assert calls
 
 
+def test_only_the_step_programs_spell_their_roundings_out(tiny_params):
+    """``_proj``'s named rounding is the step programs' (they pass
+    ``index`` to the block): training's ``forward`` and ``loss_fn``
+    trace to the bare products, as before it existed."""
+    bf16 = dataclasses.replace(CFG, dtype=jnp.bfloat16)
+    params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), tiny_params)
+
+    def loss(p):
+        return llama.loss_fn(p, {"tokens": _tokens(seq=17)}, bf16)
+
+    def decode(p):
+        return llama.decode_step(p, jnp.zeros((4,), jnp.int32),
+                                 llama.init_kv_cache(bf16, 4, 64), bf16,
+                                 active=jnp.ones((4,), bool))
+
+    def chunk(p):
+        return llama.prefill_chunk_into_cache(
+            p, jnp.zeros((16,), jnp.int32), llama.init_kv_cache(bf16, 4, 64),
+            1, 0, 5, bf16)
+
+    spelt = {name: str(jax.make_jaxpr(f)(params)).count("reduce_precision")
+             for name, f in (("loss", loss), ("grad", jax.grad(loss)),
+                             ("decode", decode), ("chunk", chunk))}
+    # a layer's scan body is traced once: q, k, v, the gate and the up
+    assert spelt == {"loss": 0, "grad": 0, "decode": 5, "chunk": 5}
+
+
+@pytest.mark.parametrize("rows", [12, 64, 512])
+@pytest.mark.parametrize("name", ["tiny", "olmoe-tiny", "axk1-tiny"])
+def test_the_step_form_projection_is_the_bare_product(name, rows):
+    """The products a step program splits into heads, spelt out
+    (``_proj``: float32 sums, one rounding by name), for a decode
+    step's rows and a 64- and a 512-row chunk's: bit for bit the bare
+    ``h @ w`` in bfloat16, each compiled alone — a spelling, not another
+    precision.  At 512 rows the CPU's bare product takes another
+    kernel, whose float32 sums run in another order: an element or two
+    in 32,768 then land on the other side of a rounding, one ulp away,
+    and as often it is the bare one that misses the exact product's
+    rounding.  (Compiled TOGETHER with what follows it the bare form is
+    the one that moves: the CPU's compiler too keeps its sums unrounded
+    into the QK-norm it fuses them with.)"""
+    c = dataclasses.replace(llama.CONFIGS[name], dtype=jnp.bfloat16)
+    layer = jax.tree.map(lambda x: x[0], llama.init_params(
+        c, jax.random.PRNGKey(0))["layers"])
+    proj = jax.jit(llama._proj, static_argnums=2)
+    for leaf in ("w_qb",) if c.kv_lora_rank else ("wq", "wk", "wv"):
+        w = layer[leaf]
+        h = jax.random.normal(jax.random.PRNGKey(rows), (rows, w.shape[0]),
+                              c.dtype)
+        bare, spelt = proj(h, w, False), proj(h, w, True)
+        assert spelt.dtype == bare.dtype == jnp.bfloat16
+        apart = _bits(spelt) != _bits(bare)
+        assert apart.sum() <= (bare.size // 8192 if rows > 64 else 0), leaf
+        ulp = np.abs(np.asarray(bare, np.float32)) * 2.0 ** -7
+        assert (np.abs(np.asarray(spelt, np.float32) - np.asarray(
+            bare, np.float32)) <= ulp).all(), leaf
+
+
 # ------------------------------------------ the block walk over the cache
 # ``_attend_slab`` walks a slot's slab in blocks of ``ATTEND_BLOCK``
 # positions and stops behind the longest live one.  The tests' slabs are

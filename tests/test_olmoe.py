@@ -274,8 +274,10 @@ def test_a_dense_engine_has_no_routing_counters():
     assert not any(key.startswith("moe_") for key in engine.stats)
 
 
-def per_head_qk_proj(layer, h, c):
-    """The wrong QK-norm: RMS over each head, not the whole width."""
+def per_head_qk_proj(layer, h, c, step=False):
+    """The wrong QK-norm: RMS over each head, not the whole width
+    (``step``: the step programs' spelling of the products, not this
+    stand-in's concern)."""
     def norm(x, weight, heads):
         split = x.reshape(*x.shape[:-1], heads, c.head_dim)
         return rmsnorm(split, weight.reshape(heads, c.head_dim),

@@ -27,6 +27,7 @@ from ant_ray_tpu.ops.pallas.flash_attention import (
     flash_attention_backward,
     flash_attention_fwd_lse,
 )
+from benchmarks import step_weight_copies
 
 CFG = llama.CONFIGS["llama3-1b"]
 # (batch, seq, heads, kv_heads, head_dim): Llama-3.2-1B attention at the
@@ -112,34 +113,8 @@ def _compile_step(device, program, config, slots, max_seq, chunk=64):
     PR 39) as the engine jits it (the cache donated, prompts in chunks
     of ``chunk`` tokens) for one described chip -> (compiled, the
     shapes of the parameters, of the cache)."""
-    params = jax.eval_shape(
-        lambda: llama.init_params(config, jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(
-        lambda: llama.init_kv_cache(config, slots, max_seq, chunk))
-    params, cache = _on(device, (params, cache))
-    last, tokens, scalar, active = _on(device, (
-        jax.ShapeDtypeStruct((slots,), jnp.int32),
-        jax.ShapeDtypeStruct((chunk,), jnp.int32),
-        jax.ShapeDtypeStruct((), jnp.int32),
-        jax.ShapeDtypeStruct((slots,), jnp.bool_)))
-    if program == "decode":
-        lowered = jax.jit(
-            lambda p, c, last, act: llama.decode_step(
-                p, last, c, config, active=act),
-            donate_argnums=(1,)).lower(params, cache, last, active)
-    elif program == "mixed_step":
-        lowered = jax.jit(
-            lambda p, c, last, act, t, slot, start, n: llama.mixed_step(
-                p, last, t, c, config, act, slot, start, n),
-            donate_argnums=(1,)).lower(params, cache, last, active, tokens,
-                                       scalar, scalar, scalar)
-    else:
-        lowered = jax.jit(
-            lambda p, c, t, slot, start, n: llama.prefill_chunk_into_cache(
-                p, t, c, slot, start, n, config),
-            donate_argnums=(1,)).lower(params, cache, tokens, scalar,
-                                       scalar, scalar)
-    return lowered.compile(), params, cache
+    return step_weight_copies.compile_step(device, program, config, slots,
+                                           max_seq, chunk)
 
 
 def _fits_beside_one_cache(compiled, params, cache):
@@ -279,6 +254,49 @@ def test_routed_step_reads_the_expert_stack_in_place(
     assert all(any(stack in line for stack in stacks) for line in calls)
     for gathered in one_layers_experts:
         assert gathered not in text
+
+
+# Mistral-7B's block at its published widths (three layers of it, so
+# that the layers are a loop): 32 query / 8 KV heads of 128, MLP 14,336.
+DENSE = dataclasses.replace(
+    CFG, vocab_size=32768, dim=4096, n_layers=3, n_heads=32, n_kv_heads=8,
+    mlp_dim=14336, max_seq=32768, rope_theta=1000000.0, norm_eps=1e-5,
+    tie_embeddings=False)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("config,slots,max_seq,chunk,split", [
+    pytest.param(DENSE, 16, 512, 64, {(4096, 4096), (1024, 4096)},
+                 id="dense"),
+    pytest.param(ROUTED, 16, 512, 64, {(2048, 2048)}, id="qk-norm"),
+    pytest.param(LATENT, 48, 512, 64, {(1536, 12288)}, id="latent"),
+    pytest.param(MIXED, 16, 8192, 512, {(4096, 16384), (1024, 4096)},
+                 id="one-period"),
+    pytest.param(MIXED_TWICE, 16, 8192, 512, {(4096, 16384), (1024, 4096)},
+                 id="two-periods")])
+def test_step_reads_the_head_projections_in_place(
+        v5e, monkeypatch, program, config, slots, max_seq, chunk, split):
+    """The products whose result is split into heads — ``wq``, ``wk``,
+    ``wv``, the latent's ``w_qb`` — read their layer of the stack where
+    it lies, in every step program: no operation of the program's own
+    writes a buffer of such a weight's size and dimensions, in any
+    layout, with or without the stack's leading 1.  Bare, the compiler
+    wanted each transposed for a heads-major result and so sliced it
+    out and copied it again, a layer a step (``llama._proj``; PERF.md
+    section 6, PR 44).  A prefetch into the other memory space, in the
+    layout the weight has, is no copy of that kind."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, _ = _compile_step(v5e.devices[0], program, config,
+                                        slots, max_seq, chunk)
+    weights = step_weight_copies.layer_weights(params)
+    heads = {key: names for key, names in weights.items()
+             if {"wq", "wk", "wv", "w_qb"} & set(names)}
+    # the cases name the dimensions they hold (sorted), so that a renamed
+    # leaf cannot pass for a program without copies
+    assert set(heads) == {("bf16", dims) for dims in split}
+    assert [row for row in step_weight_copies.materialised(
+        compiled.as_text(), heads)
+        if row["op"] not in step_weight_copies.ASYNC] == []
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
