@@ -4,7 +4,7 @@ Capability mirror of the reference's ``ray.llm`` (ref: python/ray/llm/
 _internal/serve/engines/vllm/, deployments/, batch/stages/
 vllm_engine_stage.py), re-designed TPU-first: instead of wrapping an
 external CUDA engine, the engine IS the framework's own JAX model with
-dense per-slot KV slabs, bucketed prefill, and a continuous-batching
+dense per-slot KV slabs, chunked prefill, and a continuous-batching
 scheduler whose compiled step functions have static shapes.
 """
 

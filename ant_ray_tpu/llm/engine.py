@@ -5,15 +5,12 @@ llm/_internal/serve/engines/vllm/vllm_engine.py, batch/stages/
 vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
 
 * **Static shapes everywhere.**  The decode step is one jitted function
-  over a fixed number of slots.  Prompt ingestion has two modes: the
-  legacy bucketed prefill (lengths padded to powers of two — O(log
-  max_seq) compiled variants) and **chunked prefill**
-  (``prefill_chunk_tokens``): prompts are ingested in fixed-size chunks
-  through ONE compiled `prefill_chunk` variant (slot/offset/length all
-  traced), interleaved with decode steps at a configurable
-  ``decode_steps_per_chunk`` ratio — a long prompt no longer
-  monopolizes a step, so short-request TTFT stops queueing behind it
-  and resident sessions keep decoding smoothly during ingestion.
+  over a fixed number of slots.  Every prompt is ingested in chunks of
+  ``prefill_chunk_tokens`` through ONE compiled `prefill_chunk` variant
+  (slot/offset/length all traced), a chunk an iteration beside the
+  decode step — a long prompt does not monopolize a step, so
+  short-request TTFT does not queue behind it and resident sessions
+  keep decoding smoothly during ingestion.
 * **Dense per-slot KV slabs** (models/llama.py `init_kv_cache`) instead
   of paged KV: XLA cannot tile dynamic gather-heavy paging the way a
   CUDA kernel can, while dense slabs keep decode attention a plain
@@ -25,8 +22,7 @@ vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
   call).  Slot reuse gives the same
   admit-new-work-each-step behavior as paged attention's block reuse.
 * **Continuous batching**: each `step()` admits queued prompts, runs at
-  most one prefill unit (a full bucketed prompt, or one chunk), and
-  decodes every active slot in one batched call.
+  most one chunk, and decodes every active slot in one batched call.
 * **Three step programs.**  When a chunk is due AND rows decode, the
   iteration dispatches ONE program for both (``_mixed_step``,
   models/llama.py `mixed_step`): the chunk's rows ride the decode step
@@ -98,7 +94,7 @@ class _Seq:
     # the engine's key table and advances inside the jitted sampler.
     rng_key: Any = None
     session: Any = None           # _Session | None
-    prefill_done: int = 0         # prompt tokens ingested (chunked mode)
+    prefill_done: int = 0         # prompt tokens ingested
     kv_len: int = 0               # slab tokens written for this slot
     last_tok: int | None = None   # newest token read (resume after restore)
     # Decode steps still to dispatch before max_tokens or max_seq ends
@@ -110,9 +106,9 @@ class _Seq:
     submitted: tuple = ()         # (wall, perf_counter, step) at submit
     first_chunk: tuple | None = None   # first prefill dispatch
     first_token: tuple | None = None   # first token handed to on_event
-    chunks: int = 0               # prefill dispatches (chunks, or 1)
+    chunks: int = 0               # chunks dispatched
     # Under a sampled trace_ctx only: (perf_counter, the engine's
-    # prefill dispatches so far) a token handed over, the first token's
+    # chunks so far) a token handed over, the first token's
     # too -> the span's emit_ms and chunk_gaps.
     emits: list | None = None
 
@@ -131,13 +127,6 @@ class _Session:
     current: _Seq | None = None   # seq owning the slot right now
     paused: _Seq | None = None    # mid-generation seq parked by eviction
     pending: list = field(default_factory=list)  # seqs awaiting the slab
-
-
-def _bucket(n: int, cap: int) -> int:
-    b = 16
-    while b < n:
-        b *= 2
-    return min(b, cap)
 
 
 def sampler_work(asked) -> int:
@@ -161,6 +150,9 @@ PHASES = ("drain", "admit", "chunk", "decode", "sample", "fetch", "emit",
 # its rows standing still: four times the longest step any benchmark
 # cell runs (62.6 ms).
 STALL_S = 0.25
+# The chunk a prompt is ingested in where the caller names no width:
+# Serve's, and that of the five serving cells whose chunk rides.
+PREFILL_CHUNK_TOKENS = 64
 # A chunk rides a decode step (one program for both) while the step's
 # rows, slots + chunk, stay at or under this.  Two reasons, the second
 # the one that binds.  Under the chip's ridge (v5e: 197 T operations a
@@ -387,11 +379,9 @@ class LLMEngine:
                  max_seq: int | None = None, tokenizer=None,
                  seed: int = 0, tensor_parallel_size: int = 1,
                  mesh=None, max_waiting: int | None = None,
-                 prefill_chunk_tokens: int | None = None,
-                 decode_steps_per_chunk: int = 1,
+                 prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
                  kv_idle_evict_s: float | None = None,
-                 kv_offload_store=None,
-                 kv_evict_on_pressure: bool = True):
+                 kv_offload_store=None):
         """``tensor_parallel_size > 1`` makes the ENGINE build a tp mesh
         over this process's local devices and shard params + KV slabs
         itself (ref: vllm_models.py:222 tensor_parallel_size — serving
@@ -399,20 +389,21 @@ class LLMEngine:
         overrides it with a prebuilt mesh (e.g. tp×sp for long-prompt
         prefill via ring attention — forward() switches on sp>1).
 
-        ``prefill_chunk_tokens``: enable chunked prefill with this fixed
-        chunk width (None = legacy bucketed prefill).
-        ``decode_steps_per_chunk``: decode steps run between successive
-        prefill chunks while both kinds of work are pending (the
-        TTFT-vs-decode-smoothness budget knob).
+        ``prefill_chunk_tokens``: the fixed width of the chunks every
+        prompt is ingested in, a positive int.
         ``kv_idle_evict_s``: evict a session's slab after this many
-        seconds idle (None disables the LRU sweep; pressure eviction is
-        governed separately by ``kv_evict_on_pressure``).
+        seconds idle (None disables the LRU sweep; admission pressure
+        evicts an idle session either way).
         ``kv_offload_store``: a kv_offload.py store (LocalKvStore /
         ObjectPlaneKvStore); defaults to a LocalKvStore built lazily on
         first eviction.
         """
         from ant_ray_tpu._private.jax_utils import import_jax
 
+        if type(prefill_chunk_tokens) is not int or prefill_chunk_tokens < 1:
+            raise ValueError(
+                "prefill_chunk_tokens must be a positive int (the width "
+                f"of a prefill chunk), got {prefill_chunk_tokens!r}")
         self._jax = jax = import_jax()
         import jax.numpy as jnp  # noqa: PLC0415
 
@@ -443,11 +434,6 @@ class LLMEngine:
         self.max_seq = min(max_seq or self.config.max_seq,
                            self.config.max_seq)
         self.slots = slots
-        if self.config.n_recurrent and prefill_chunk_tokens is None:
-            raise ValueError(
-                "bucketed prefill keeps no recurrent state: a model with "
-                "linear-attention or state-space layers is ingested in "
-                "chunks (prefill_chunk_tokens=)")
         self.tokenizer = tokenizer or get_tokenizer(None)
         if params is None:
             # Random weights as ONE program: each leaf is drawn, scaled
@@ -467,11 +453,11 @@ class LLMEngine:
                 tp=tensor_parallel_size)
         self.params = params
         self.cache = llama.init_kv_cache(self.config, slots, self.max_seq,
-                                         prefill_chunk_tokens or 0)
+                                         prefill_chunk_tokens)
         # A window layer's ring rows (0: the model has none), as the
         # cache was made.
         self._ring = llama.ring_positions(self.config, self.max_seq,
-                                          prefill_chunk_tokens or 0)
+                                          prefill_chunk_tokens)
         # Per-slot sampling keys, resident on the device: a key enters
         # its row when its sequence joins the decode batch, the jitted
         # sampler splits every active row each step, and the row leaves
@@ -517,18 +503,13 @@ class LLMEngine:
 
         # ---- chunked prefill + session state
         self._chunk_tokens = prefill_chunk_tokens
-        self._chunk_rides = (prefill_chunk_tokens is not None and
-                             slots + prefill_chunk_tokens <= RIDE_ROWS)
-        self._decode_per_chunk = max(1, int(decode_steps_per_chunk))
-        self._decode_since_chunk = self._decode_per_chunk  # 1st chunk runs now
-        self._prefilling: list[_Seq] = []         # chunked-mode ingest queue
+        self._chunk_rides = slots + prefill_chunk_tokens <= RIDE_ROWS
+        self._prefilling: list[_Seq] = []         # the ingest queue
         self._sessions: dict[str, _Session] = {}
         self._kv_idle_evict_s = kv_idle_evict_s
-        self._kv_evict_on_pressure = kv_evict_on_pressure
         self._kv_store = kv_offload_store
         self._restoring: dict[str, dict] = {}     # sid -> ticket
         self._chunk_rate: float | None = None     # tokens/s EWMA
-        self._prefills = 0    # prefill programs dispatched: chunks, prompts
         self._last_chunk_t: float | None = None
         # Flat on purpose: readers on other threads take dict(stats),
         # a shallow copy under which a nested dict would alias.
@@ -549,10 +530,6 @@ class LLMEngine:
 
         cfg = self.config
         eng_mesh = self.mesh
-
-        def _prefill(params, cache, tokens, slot, length):
-            return llama.prefill_into_cache(params, tokens, cache, slot,
-                                            length, cfg, mesh=eng_mesh)
 
         def _prefill_chunk(params, cache, tokens, slot, start, length):
             return llama.prefill_chunk_into_cache(
@@ -602,11 +579,9 @@ class LLMEngine:
             return lax.dynamic_index_in_dim(keys, slot, axis=0,
                                             keepdims=False)
 
-        # one compile per prompt bucket (slot/length traced); ONE chunk
-        # variant (slot/start/length traced); one decode; one of a chunk
-        # and a decode step together; one extract / install / row write
-        # / key read each (slot traced).
-        self._prefill_jit = jax.jit(_prefill, donate_argnums=(1,))
+        # ONE chunk variant (slot/start/length traced); one decode; one
+        # of a chunk and a decode step together; one extract / install /
+        # row write / key read each (slot traced).
         self._prefill_chunk_jit = jax.jit(_prefill_chunk,
                                           donate_argnums=(1,))
         self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
@@ -676,15 +651,15 @@ class LLMEngine:
         control at the engine boundary, so overload sheds instead of
         growing an unbounded prompt queue toward OOM.  Before shedding,
         an idle resident session is evicted to the offload store if one
-        exists (``kv_evict_on_pressure``) — pressure admits new work by
-        spilling cold state instead of refusing.  The retry hint derives
+        exists — pressure admits new work by spilling cold state instead
+        of refusing.  The retry hint derives
         from the measured chunk-drain rate.  Offline batch paths
         (``generate``) pass ``admit=False``: a caller handing the
         engine a fixed batch wants queueing.
 
         ``session_id`` attaches the request to a persistent session: its
-        KV slab survives the request (multi-turn reuse; continuations
-        require chunked mode) and may be offloaded/restored.
+        KV slab survives the request (multi-turn reuse: the next turn's
+        chunks append at its offset) and may be offloaded/restored.
         ``on_event`` streams per-token dicts to the caller (EngineLoop's
         sink); ``trace_ctx`` attributes the `llm:engine` and
         `llm:restore` spans; ``submitted`` is EngineLoop.submit's
@@ -733,15 +708,6 @@ class LLMEngine:
             if sess is None or sess.state == "failed":
                 sess = _Session(session_id)
                 self._sessions[session_id] = sess
-            elif self._chunk_tokens is None:
-                # Any reuse, not just kv_len > 0: a continuation queued
-                # while turn 1 is still in flight (kv_len still 0 here)
-                # would otherwise reach _admit with a slab offset the
-                # bucketed kernel cannot append at.
-                raise ValueError(
-                    "session continuation requires chunked prefill "
-                    "(prefill_chunk_tokens=) — bucketed prefill cannot "
-                    "append at a slab offset")
             seq.session = sess
         seed = sampling.seed
         key = (self._jax.random.PRNGKey(seed) if seed is not None
@@ -759,10 +725,9 @@ class LLMEngine:
 
     def step(self) -> list[RequestOutput]:
         """One engine iteration: land finished restores, admit prompts,
-        run one prefill unit (bucketed prompt or one chunk), dispatch
-        decode step N+1 for all active slots, read step N's tokens and
-        emit them, sweep idle sessions.  Returns outputs finished since
-        the last call.
+        run one chunk of one prompt, dispatch decode step N+1 for all
+        active slots, read step N's tokens and emit them, sweep idle
+        sessions.  Returns outputs finished since the last call.
 
         One of three step programs does the device's part: the mixed
         step where a chunk is due and rows decode (the chunk rides
@@ -792,14 +757,12 @@ class LLMEngine:
         rec.enter("admit")
         self._poll_restores()
         self._admit()
-        chunk = None
-        if self._chunk_tokens is not None:
-            rec.enter("chunk")
-            chunk = self._next_chunk()
-            if chunk is not None and not (self._active and self._chunk_rides):
-                # nothing decodes beside it, or it is too wide to ride
-                self._chunk_alone(*chunk)
-                chunk = None
+        rec.enter("chunk")
+        chunk = self._next_chunk()
+        if chunk is not None and not (self._active and self._chunk_rides):
+            # nothing decodes beside it, or it is too wide to ride
+            self._chunk_alone(*chunk)
+            chunk = None
         self._decode(chunk)
         rec.enter("housekeeping")
         self._sweep_idle()
@@ -876,7 +839,7 @@ class LLMEngine:
         outstanding = sum(max(0, len(s.prompt) - s.prefill_done)
                           for s in self._prefilling)
         outstanding += sum(len(s.prompt) for s in self._waiting)
-        outstanding += self._chunk_tokens or 0   # the admitted request
+        outstanding += self._chunk_tokens        # the admitted request
         return min(30.0, max(0.05, outstanding / rate + 0.02))
 
     def evict_session(self, session_id: str, *, force: bool = False
@@ -934,16 +897,13 @@ class LLMEngine:
 
     def _admit(self):
         """Route waiting requests: park session continuations behind
-        restores, assign free (or pressure-evicted) slots, and in
-        legacy mode run at most one full bucketed prefill per step —
-        the budget covers BOTH the resident-idle-session branch and the
-        fresh-slot branch."""
+        restores, and hand every other one a slot — its session's, a
+        free one or one that pressure evicts — and the ingest queue."""
         # Sessions parked with work but offloaded: ensure a restore is
         # in flight (covers forced mid-generation eviction).
         for sess in self._sessions.values():
             if sess.state == "offloaded" and (sess.paused or sess.pending):
                 self._start_restore(sess)
-        admitted_prefill = False
         i = 0
         while i < len(self._waiting):
             seq = self._waiting[i]
@@ -961,17 +921,12 @@ class LLMEngine:
                 sess.pending.append(seq)
                 continue
             if sess is not None and sess.slot >= 0:
-                if self._chunk_tokens is None and admitted_prefill:
-                    break                     # legacy: ≤1 prefill/step
                 self._waiting.pop(i)          # resident idle: append
                 self._begin_ingest(seq, sess.slot, sess.kv_len)
-                admitted_prefill = True
                 continue
             if not self._free_slots and not self._evict_for_pressure():
                 i += 1
                 continue
-            if self._chunk_tokens is None and admitted_prefill:
-                break                         # legacy: ≤1 prefill/step
             slot = self._free_slots.pop()
             # not pop(i): the eviction above lands the step in flight,
             # and a turn that ends there puts its session's next one
@@ -981,20 +936,11 @@ class LLMEngine:
                 sess.slot = slot
                 sess.state = "resident"
             self._begin_ingest(seq, slot, sess.kv_len if sess else 0)
-            admitted_prefill = True
 
     def _begin_ingest(self, seq: _Seq, slot: int, start: int):
-        jnp = self._jnp
+        """``seq`` has ``slot``: its prompt joins the ingest queue, its
+        chunks to be written from position ``start`` on."""
         sess = seq.session
-        if self._chunk_tokens is None and start != 0:
-            # add_request rejects bucketed session continuations, so
-            # this is a backstop: fail the one seq typed (the session
-            # keeps its resident slot, idle) — raising mid-step would
-            # leave the seq in no queue and wedge its caller's wait().
-            self._fail_seq(seq, ValueError(
-                "bucketed prefill cannot continue a session at offset "
-                f"{start}; configure prefill_chunk_tokens"))
-            return
         if sess is not None:
             sess.current = seq
             sess.last_used = time.monotonic()
@@ -1003,29 +949,13 @@ class LLMEngine:
                 sess.carry = []
         seq.slot = slot
         seq.kv_len = start
-        if self._chunk_tokens is not None:
-            self._prefilling.append(seq)
-            return
-        rec = self._rec
-        rec.enter("chunk")            # the bucketed prefill, whole
-        bucket = _bucket(len(seq.prompt), self.max_seq)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(seq.prompt)] = seq.prompt
-        last_logits, self.cache = self._prefill_jit(
-            self.params, self.cache, jnp.asarray(padded), slot,
-            len(seq.prompt))
-        self._note_dispatch(seq)
-        seq.kv_len = len(seq.prompt)
-        self._first_token(seq, last_logits)
-        rec.enter("admit")            # back to the caller's phase
+        self._prefilling.append(seq)
 
     def _next_chunk(self):
         """The next chunk of ONE pending prompt: ``(seq, its tokens
         padded to the chunk width, how many are real)``; the prompt
         stays in the queue until ``_chunk_end`` — or None: nothing
-        waits, or fewer than ``decode_steps_per_chunk`` decode steps
-        have run since the last chunk (decode for resident sessions
-        stays smooth while a long prompt trickles in).
+        waits.
 
         Selection is shortest-remaining-prompt-first (FIFO tiebreak):
         a short interactive prompt's single chunk jumps ahead of a
@@ -1035,9 +965,6 @@ class LLMEngine:
         a sustained flood of short prompts will stall them; that is
         the intended bias for an interactive serving tier."""
         if not self._prefilling:
-            return None
-        if self._active and \
-                self._decode_since_chunk < self._decode_per_chunk:
             return None
         seq = min(self._prefilling,
                   key=lambda s: len(s.prompt) - s.prefill_done)
@@ -1071,14 +998,18 @@ class LLMEngine:
 
     def _chunk_dispatched(self, seq: _Seq, n: int):
         """A program that ingests ``n`` tokens of ``seq`` — the chunk
-        program, or the mixed one — was dispatched: the host's books."""
-        self._note_dispatch(seq)
+        program, or the mixed one — was dispatched: the host's books.
+        The iteration counts as a step, and a request's first chunk
+        ends its queue stage."""
+        self._rec.dispatched = True
+        seq.chunks += 1
+        if seq.first_chunk is None:
+            seq.first_chunk = (time.perf_counter(), self.stats["steps"])
         self._note_recurrent(self._chunk_tokens, n, seq.kv_len == 0)
         seq.prefill_done += n
         seq.kv_len += n
         self._note_walk(seq.kv_len)
         self._note_chunk(n)
-        self._decode_since_chunk = 0
 
     def _chunk_end(self, seq: _Seq, logits):
         """Behind a chunk's dispatch: a prompt's end leaves the queue
@@ -1191,7 +1122,6 @@ class LLMEngine:
         work = sampler_work(self._sampling_rows[slot] for slot, _ in rows)
         stats["sample_plain_steps"] += work == 1
         stats["sample_sorted_steps"] += work == 2
-        self._decode_since_chunk += 1
         rec.enter("sample")
         sampled = self._sample_all(logits)
         for slot, seq in rows:
@@ -1280,16 +1210,6 @@ class LLMEngine:
             stats["recurrent_chunk_tokens"] += n * live
             stats["recurrent_resets"] += fresh
 
-    def _note_dispatch(self, seq: _Seq):
-        """A prefill program for ``seq`` was dispatched: the iteration
-        counts as a step, and the first one ends the request's queue
-        stage."""
-        self._rec.dispatched = True
-        self._prefills += 1
-        seq.chunks += 1
-        if seq.first_chunk is None:
-            seq.first_chunk = (time.perf_counter(), self.stats["steps"])
-
     def _note_chunk(self, n: int):
         self.stats["chunks"] += 1
         self.stats["chunk_tokens"] += n
@@ -1314,8 +1234,6 @@ class LLMEngine:
         """Free one slot by offloading the least-recently-used IDLE
         resident session.  Admission pressure spills cold state instead
         of shedding new work."""
-        if not self._kv_evict_on_pressure:
-            return False
         idle = [s for s in self._sessions.values()
                 if s.state == "resident" and s.slot >= 0
                 and s.current is None and s.paused is None]
@@ -1555,7 +1473,7 @@ class LLMEngine:
             reason = "length"
         if reason != "stop":
             if seq.emits is not None:
-                seq.emits.append((now, self._prefills))
+                seq.emits.append((now, self.stats["chunks"]))
             if seq.on_event is not None:
                 seq.on_event({"type": "token", "token_id": tok})
         if reason is not None:

@@ -23,7 +23,7 @@ from __future__ import annotations
 import os
 import time
 
-from ant_ray_tpu.llm.engine import EngineLoop, LLMEngine
+from ant_ray_tpu.llm.engine import PREFILL_CHUNK_TOKENS, EngineLoop, LLMEngine
 from ant_ray_tpu.llm.sampling import SamplingParams
 
 
@@ -34,8 +34,7 @@ class LLMServer:
                  max_seq: int | None = None, tokenizer_name: str | None =
                  None, seed: int = 0, tensor_parallel_size: int = 1,
                  max_waiting: int | None = None,
-                 prefill_chunk_tokens: int | None = 64,
-                 decode_steps_per_chunk: int = 1,
+                 prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
                  kv_idle_evict_s: float | None = None,
                  kv_offload="auto"):
         from ant_ray_tpu._private.jax_utils import require_tpu  # noqa: PLC0415
@@ -52,7 +51,6 @@ class LLMServer:
             tensor_parallel_size=tensor_parallel_size,
             max_waiting=max_waiting,
             prefill_chunk_tokens=prefill_chunk_tokens,
-            decode_steps_per_chunk=decode_steps_per_chunk,
             kv_idle_evict_s=kv_idle_evict_s,
             kv_offload_store=store)
         self._loop = EngineLoop(self.engine, max_waiting=max_waiting)
@@ -322,8 +320,7 @@ def build_llm_deployment(model="tiny", *, name: str = "llm",
                          request_timeout_s: float | None = None,
                          max_waiting: int | None = None,
                          autoscaling_config=None,
-                         prefill_chunk_tokens: int | None = 64,
-                         decode_steps_per_chunk: int = 1,
+                         prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
                          kv_idle_evict_s: float | None = None,
                          kv_offload="auto"):
     """Application for ``serve.run`` exposing the engine under the
@@ -337,8 +334,8 @@ def build_llm_deployment(model="tiny", *, name: str = "llm",
     is busy — all sheds surface as 429/RESOURCE_EXHAUSTED with the
     retry hint derived from the measured chunk-drain rate.
 
-    Serving enables chunked prefill by default
-    (``prefill_chunk_tokens=64``); ``kv_idle_evict_s`` turns on
+    Prompts are ingested in chunks of ``prefill_chunk_tokens`` (64
+    unless given); ``kv_idle_evict_s`` turns on
     idle-session offload through ``kv_offload`` ("auto" picks the
     object plane inside a cluster).  ``autoscaling_config`` may target
     the engine's published load signals (see
@@ -373,6 +370,5 @@ def build_llm_deployment(model="tiny", *, name: str = "llm",
                     tensor_parallel_size=tensor_parallel_size,
                     max_waiting=max_waiting,
                     prefill_chunk_tokens=prefill_chunk_tokens,
-                    decode_steps_per_chunk=decode_steps_per_chunk,
                     kv_idle_evict_s=kv_idle_evict_s,
                     kv_offload=kv_offload)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ant_ray_tpu.ops import delta_rule, ssd
-from ant_ray_tpu.ops.attention import attention, kernel_fits
+from ant_ray_tpu.ops.attention import attention
 from ant_ray_tpu.ops.layernorm import layernorm
 from ant_ray_tpu.ops.pallas import grouped_matmul
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
@@ -1146,23 +1146,12 @@ def _checkpointed(block, remat: str):
 
 
 def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
-            attn_impl: str = "auto", positions=None,
-            return_kv: bool = False, logits_at=None,
-            remat: str = "full"):
+            attn_impl: str = "auto", positions=None, remat: str = "full"):
     """tokens: (batch, seq) int32 → logits (batch, seq, vocab) fp32.
 
     When ``mesh`` is provided, activations get sharding constraints
     (batch over dp/fsdp, seq over sp, heads over tp) and sequence-sharded
     meshes use ring attention.
-
-    ``return_kv=True`` additionally returns what the layers keep of
-    every position (``kv_slabs``: the per-layer K and V, (layers, b, s,
-    kv_heads, hd) each — or a latent model's latents and rotary keys),
-    which the block's attention hands back as its state, for the
-    bucketed prefill (``prefill_into_cache``) to put into a slot;
-    ``logits_at`` (traced scalar position) computes logits for that one
-    position only — (b, vocab) — skipping the full-sequence lm-head
-    matmul.
 
     ``remat`` trades HBM for recompute FLOPs in the backward pass:
     "full" (checkpoint every block — the multi-chip/8B default), "dots"
@@ -1171,12 +1160,6 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     bench).
     """
     c = config
-    if return_kv and c.recurrent:
-        raise ValueError(
-            "forward(return_kv=True) returns what layers keep of every "
-            "position; a linear layer keeps a state of the sequence, "
-            "and so does an ssm layer: ingest it in chunks "
-            "(prefill_chunk_into_cache)")
     scale = c.attention_multiplier or None       # None: head_dim^-1/2
     cos, sin = _rope_tables(c)
     use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
@@ -1224,8 +1207,7 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
                     ("batch", "seq", "heads", "head_dim"), rules),
                 kv_spec=logical_to_spec(
                     ("batch", "seq", "kv_heads", "head_dim"), rules))
-        kv = (xk.astype(c.dtype), xv.astype(c.dtype)) if return_kv else None
-        return out, kv
+        return out, None
 
     state_rows = {"linear": _attend_linear_rows, "ssm": _attend_ssm_rows}
 
@@ -1234,22 +1216,17 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
 
         def period(x, layers):
             # the period's unlike layers, each with its own way to attend
-            kvs = []
             for j, kind in enumerate(cfg.kinds):
-                x, kv, _ = apply_block(
+                x, _, _ = apply_block(
                     _place(layers, whole, j, cfg), x, cfg, cos, sin,
                     positions,
                     functools.partial(attend_state, state_rows[kind])
                     if kind in RECURRENT else functools.partial(
                         attend, cfg.window if kind == "window" else 0),
                     constrain_act, kind=kind)
-                kvs.append(kv)
-            if len(kvs) == 1:
-                return x, kvs[0]
-            return x, jax.tree.map(lambda *parts: jnp.stack(parts), *kvs)
+            return x, None
 
-        x, kv = lax.scan(_checkpointed(period, remat), x, scanned)
-        return x, _unperiod(kv, cfg)
+        return lax.scan(_checkpointed(period, remat), x, scanned)[0]
 
     x = _embed(params, tokens, c)
     # Staged reshard: first acknowledge the gather's TABLE-natural
@@ -1267,20 +1244,10 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         x = lax.with_sharding_constraint(
             x, NamedSharding(mesh, PartitionSpec("dp", "sp", "fsdp")))
     x = constrain_act(x, ("batch", "seq", "embed"))
-    kvs = []
     for stack, cfg in _stacks(params, c):
-        x, kv = scan_stack(x, stack, cfg)
-        kvs.append(kv)
-    kv = kvs[0] if len(kvs) == 1 else jax.tree.map(
-        lambda *parts: jnp.concatenate(parts), *kvs)
+        x = scan_stack(x, stack, cfg)
     x = _norm(x, params["norm_f"], c)
-    if logits_at is not None:
-        logits = _head(params, jnp.take(x, logits_at, axis=1), c)
-    else:
-        logits = constrain_act(_head(params, x, c), ("batch", "seq", None))
-    if return_kv:
-        return logits, kv[0], kv[1]
-    return logits
+    return constrain_act(_head(params, x, c), ("batch", "seq", None))
 
 
 def loss_fn(params: dict, batch: dict, config: LlamaConfig, *, mesh=None,
@@ -1424,9 +1391,9 @@ def state_slabs(config: LlamaConfig) -> dict:
 
 def ring_positions(config: LlamaConfig, max_seq: int, chunk: int = 0) -> int:
     """Rows of a window layer's ring in a ``max_seq``-position cache
-    whose prompts arrive in chunks of ``chunk`` tokens (0: whole, or
-    never): position ``p`` lies at row ``p mod ring``.  The step
-    programs write a call's rows and only THEN attend
+    whose prompts arrive in chunks of ``chunk`` tokens (0: never, a
+    token at a time): position ``p`` lies at row ``p mod ring``.  The
+    step programs write a call's rows and only THEN attend
     (``_scan_layers``), so the ring holds the window AND the chunk: in
     one of exactly ``window`` rows a chunk's later tokens would
     overwrite keys its first queries still see.  0 without window
@@ -1548,53 +1515,6 @@ def _count_routing(cache: dict, loads, routed, decode: bool,
     seen = jnp.concatenate([seen, apart if decode else apart * 0,
                             jnp.stack([visited])])
     return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
-
-
-def prefill_into_cache(params: dict, tokens, cache: dict, slot,
-                       length, config: LlamaConfig, *, mesh=None):
-    """Run prefill on one padded prompt (1, s) and write what its
-    layers keep of it into ``slot``; returns (last-token logits
-    (vocab,), new cache).
-
-    ``slot`` and ``length`` may be traced (one compile per prompt
-    bucket, none per slot); logits are computed for the last real token
-    only — the padded tail writes garbage rows that decode masks (and
-    later overwrites)."""
-    last_pos = jnp.maximum(length - 1, 0)
-    # Prompt buckets start at 16 tokens: below the flash kernel's tile
-    # the blockwise path is named, as the dispatcher demands on a TPU.
-    qkv_shape = (1, tokens.shape[1], config.n_heads, config.head_dim)
-    logits, *kept = forward(
-        params, tokens, config, mesh=mesh, return_kv=True,
-        logits_at=last_pos,
-        attn_impl="auto" if kernel_fits(qkv_shape, qkv_shape)
-        else "blockwise")
-    cache = dict(cache)
-    slot = jnp.asarray(slot, jnp.int32)
-
-    def put(name, rows):
-        cache[name] = lax.dynamic_update_slice(
-            cache[name], rows, (0, slot) + (0,) * (rows.ndim - 2))
-
-    if config.window:
-        # full layers keep the prompt as it lies; a window layer's ring
-        # row r gets the newest position p <= length - 1 with p = r
-        # (mod ring) — rows that hold none yet are masked by the walk
-        kinds = config.period * (config.n_layers // len(config.period))
-        full, windowed = (jnp.array(
-            [i for i, w in enumerate(kinds) if w == kind], jnp.int32)
-            for kind in (False, True))
-        ring = cache["k_ring"].shape[2]
-        held = jnp.clip(_ring_holds(last_pos, jnp.arange(ring), ring),
-                        0, tokens.shape[1] - 1)
-        for name, rows in zip(("k", "v"), kept):
-            put(name, rows[full])
-            put(name + "_ring", rows[windowed][:, :, held])
-    else:
-        for name, rows in zip(kv_slabs(config), kept):
-            put(name, rows)
-    cache["length"] = cache["length"].at[slot].set(length)
-    return logits[0], cache
 
 
 # Positions a step program attends over at a time.  A module constant,
@@ -2059,8 +1979,7 @@ def prefill_chunk_into_cache(params: dict, tokens, cache: dict, slot,
     the engine's fixed chunk width.  ``slot``, ``start`` (absolute
     offset of the chunk in the slab) and ``chunk_len`` are all traced
     scalars, so a single compiled variant covers every chunk of every
-    prompt — the chunked-prefill replacement for the O(log max_seq)
-    bucketed `prefill_into_cache` variants.
+    prompt, wherever in the slab it lies.
 
     Chunk queries attend against the slot's slab as far as the chunk's
     own end, ``start + chunk_len`` rounded up to a block

@@ -177,16 +177,13 @@ _GUARDED_METRICS = {
     "cpu_profiler_overhead_fraction": "lower",
     "rpc_pushtask_send_bytes_per_call": "lower",
     # LLM serving plane (PR 18): short-prompt TTFT under long-prompt
-    # interference with chunked prefill on (absolute guard), the
-    # chunked-vs-unchunked p99 improvement ratio (acceptance >= 5x —
-    # dropping toward 1.0 means chunking stopped isolating TTFT),
-    # decode throughput under that same mixed load, and the number of
-    # live sessions a 2-slot engine held via KV offload (> slots, or
-    # eviction/restore stopped expanding capacity).
+    # interference (absolute guard), decode throughput under that same
+    # mixed load, and the number of live sessions a 2-slot engine held
+    # via KV offload (> slots, or eviction/restore stopped expanding
+    # capacity).
     "llm_tokens_per_s": "higher",
     "llm_ttft_short_p50_us": "lower",
     "llm_ttft_short_p99_us": "lower",
-    "llm_ttft_chunked_improvement_x": "higher",
     "llm_resident_sessions": "higher",
     # Scale observatory (PR 19): control-plane cost at 100 stub nodes
     # (benchmarks/scale_harness.py — real wire protocol, no workers).

@@ -9,12 +9,11 @@ TTFT samples and whole-run token throughput.
 
 Used by benchmarks/microbench.py for the guarded
 ``llm_ttft_short_p50_us`` / ``llm_ttft_short_p99_us`` /
-``llm_tokens_per_s`` / ``llm_resident_sessions`` numbers (both the
-chunked and unchunked arm run the SAME generator), and by the `slow`
-soak test in tests/test_llm_sessions.py.
+``llm_tokens_per_s`` / ``llm_resident_sessions`` numbers, and by the
+`slow` soak test in tests/test_llm_sessions.py.
 
 Prompts are synthetic token-id lists (tiny-config vocab), deterministic
-per client index — two arms see identical offered work.
+per client index — two runs see identical offered work.
 """
 
 from __future__ import annotations

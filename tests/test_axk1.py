@@ -150,18 +150,6 @@ def test_the_tolerance_sees_what_it_must(params, wrong, least):
     assert off.max() > least > TOL
 
 
-def test_bucketed_prefill_writes_the_same_latent_rows(params):
-    tokens = tokens_of(5, 32)
-    _, chunked = through_the_cache(CFG, params, tokens, 32)
-    logits, whole = llama.prefill_into_cache(
-        params, jnp.asarray(tokens)[None], llama.init_kv_cache(
-            CFG, SLOTS, MAX_SEQ), 1, 32, CFG)
-    for name in llama.kv_slabs(CFG):
-        np.testing.assert_allclose(whole[name][:, 1, :32],
-                                   chunked[name][:, 1, :32], atol=2e-5)
-    assert rel_l2(logits, reference_logits(params, tokens)[-1]) < TOL
-
-
 # --------------------------------- (b) absorbed = per-head on the same cache
 
 @pytest.mark.parametrize("a_slab_a_row", [False, True])

@@ -249,26 +249,6 @@ def test_each_of_the_configs_switches_is_read(params, change):
     assert rel_l2(got, reference_logits(params, tokens)).max() > 1e-3
 
 
-def test_bucketed_prefill_writes_the_rings_where_the_chunks_would(params):
-    """``prefill_into_cache`` (the engine without chunked prefill,
-    ``generate()``): the full layers' rows as they lie, of the window
-    layers the newest 16 positions at ``p mod ring`` — the next decode
-    step reads the reference's logits."""
-    tokens = tokens_of(5, 41)
-    cache = llama.init_kv_cache(CFG, SLOTS, MAX_SEQ)
-    assert cache["k_ring"].shape[2] == WINDOW
-    padded = np.zeros((1, 64), np.int32)
-    padded[0, :40] = tokens[:40]
-    logits, cache = llama.prefill_into_cache(
-        params, jnp.asarray(padded), cache, 1, 40, CFG)
-    want = reference_logits(params, tokens)
-    assert rel_l2(logits, want[39]) < TOL
-    last = jnp.zeros((SLOTS,), jnp.int32).at[1].set(int(tokens[40]))
-    logits, _ = decode_step(params, last, cache,
-                            jnp.asarray([False, True, False]))
-    assert rel_l2(logits[1], want[40]) < TOL
-
-
 # ------------------------------------- (b) a row's sums are its own: bits
 
 def _step_logits(params, lengths, active, seed=1):
@@ -415,14 +395,10 @@ def _turn(eng, sid, prompt, n):
     return outs[0].token_ids
 
 
-@pytest.mark.parametrize("chunked", [True, False],
-                         ids=["chunked", "bucketed"])
-def test_engine_greedy_tokens_are_the_references(params, chunked):
-    """Through ``LLMEngine`` — chunked prefill, and the bucketed one of
-    offline ``generate()`` — prompts inside and beyond the window, six
+def test_engine_greedy_tokens_are_the_references(params):
+    """Through ``LLMEngine``, prompts inside and beyond the window, six
     greedy tokens each: the reference's argmax, token by token."""
-    eng = _engine(params, slots=3,
-                  prefill_chunk_tokens=CHUNK if chunked else None)
+    eng = _engine(params, slots=3)
     prompts = [tokens_of(20 + i, n).tolist()
                for i, n in enumerate((5, 19, 45))]
     outs = eng.generate(prompts, SamplingParams(max_tokens=6))

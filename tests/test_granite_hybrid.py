@@ -543,19 +543,12 @@ def test_what_a_recurrent_state_is_refused_by_name(params):
     with pytest.raises(ValueError, match="sessions are not kept over a "
                                          "recurrent state.*state-space"):
         eng.add_request([1, 2, 3], session_id="turns")
-    with pytest.raises(ValueError, match="bucketed prefill keeps no "
-                                         "recurrent state.*state-space"):
-        LLMEngine(CFG, params, slots=2, max_seq=64)
     with pytest.raises(ValueError, match="a recurrent state .*state-space.* "
                                          "is not sharded"):
         _engine(params, tensor_parallel_size=2)
     with pytest.raises(ValueError, match="no ssm layers"):
         llama.loss_fn_pp(params, {"tokens": jnp.zeros((2, 9), jnp.int32)},
                          CFG, mesh=type("M", (), {"shape": {"pp": 2}})())
-    with pytest.raises(ValueError, match="so does an ssm layer"):
-        llama.prefill_into_cache(
-            params, jnp.zeros((1, 16), jnp.int32),
-            llama.init_kv_cache(CFG, 2, 64), 0, 5, CFG)
     with pytest.raises(ValueError, match="ssm layers state their heads"):
         dataclasses.replace(llama.CONFIGS["tiny"],
                             layer_kinds=("full", "ssm"))

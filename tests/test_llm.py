@@ -24,9 +24,9 @@ def params():
     return llama.init_params(CFG, jax.random.PRNGKey(7))
 
 
-def _reference_greedy(params, prompt, n):
+def _reference_greedy(params, prompt, n, cfg=CFG):
     """No-KV-cache greedy decode via the training forward pass."""
-    toks = llama.greedy_generate(params, CFG, np.asarray(prompt, np.int32),
+    toks = llama.greedy_generate(params, cfg, np.asarray(prompt, np.int32),
                                  max_new_tokens=n)
     return [int(t) for t in np.asarray(toks[0])[len(prompt):]]
 
@@ -133,7 +133,7 @@ def _run_all(eng, requests):
     return [outs[r].token_ids for r in rids]
 
 
-@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("chunk", [64, 8])
 @pytest.mark.parametrize("seed", sorted(PINNED))
 def test_seeded_stream_is_the_pinned_one(params, seed, chunk):
     got = _pinned_engine(params, chunk).generate([[10, 20, 30]],
@@ -141,7 +141,7 @@ def test_seeded_stream_is_the_pinned_one(params, seed, chunk):
     assert got[0].token_ids == PINNED[seed]
 
 
-@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("chunk", [64, 8])
 def test_mixed_batch_streams_are_the_pinned_ones(params, chunk):
     got = _run_all(_pinned_engine(params, chunk),
                    [(p, _sampling(seed, n)) for p, seed, n, _ in MIXED])
@@ -363,7 +363,7 @@ _STAGGERED_RUNS = {}
 
 
 def _run_staggered(params, chunk):
-    """The staggered batch, once a prefill mode: the outputs by request,
+    """The staggered batch, once a chunk width: the outputs by request,
     the engine, and what the spy on ``_land`` saw — for every row the
     engine dropped, who held the row's slot when it was."""
     if chunk in _STAGGERED_RUNS:
@@ -394,7 +394,7 @@ def _run_staggered(params, chunk):
     return _STAGGERED_RUNS[chunk]
 
 
-@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("chunk", [64, 8])
 @pytest.mark.parametrize("rid", [r[0] for r in STAGGERED])
 def test_pipelined_batch_answers_as_the_unpipelined_engine_did(
         params, rid, chunk):
@@ -408,7 +408,7 @@ def test_pipelined_batch_answers_as_the_unpipelined_engine_did(
     assert outs[rid].finish_reason == want[7]
 
 
-@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("chunk", [64, 8])
 def test_a_stop_costs_one_dropped_row_and_it_reaches_nobody(params, chunk):
     """A stop token is read one step late: the step in flight computed
     a row for the sequence that ended.  The row is counted as decoded
@@ -421,14 +421,13 @@ def test_a_stop_costs_one_dropped_row_and_it_reaches_nobody(params, chunk):
     assert eng.stats["decode_slots"] == 30 + len(dropped)
     assert sum(len(o.token_ids) + (o.finish_reason == "stop") - 1
                for o in outs.values()) == 30
-    if chunk is not None:
-        # a waiting prompt took A's slot in the iteration after the stop
-        # was read: its prefill and the stale row's read crossed
-        readmitted = [seq.request_id for _, seq in dropped if seq]
-        assert readmitted, dropped
-        for rid in readmitted:
-            (want,) = [r for r in STAGGERED if r[0] == rid]
-            assert outs[rid].token_ids == want[6]
+    # a waiting prompt took A's slot in the iteration after the stop
+    # was read: its prefill and the stale row's read crossed
+    readmitted = [seq.request_id for _, seq in dropped if seq]
+    assert readmitted, dropped
+    for rid in readmitted:
+        (want,) = [r for r in STAGGERED if r[0] == rid]
+        assert outs[rid].token_ids == want[6]
     assert eng._flight is None and not eng._active
 
 
@@ -460,7 +459,7 @@ def test_a_chunk_rides_the_decode_step_it_shares(params):
     the decode rows — where it dispatched the chunk program and then the
     decode program.  The counters as documented: a mixed step is a
     decode step (``decode_steps``, ``decode_slots``,
-    ``decode_ahead_steps``) and a chunk (``chunks``, ``_prefills``), and
+    ``decode_ahead_steps``) and a chunk (``chunks``, ``chunk_tokens``), and
     ``chunks_fused`` counts it; reads are one a decode step and one a
     prompt's end, as before; the streams are those of each request
     alone."""
@@ -490,7 +489,6 @@ def test_a_chunk_rides_the_decode_step_it_shares(params):
     eng.add_request([7, 8, 9], _sampling(None, 4), request_id="c",
                     admit=False)
     before = dict(eng.stats)
-    prefills = eng._prefills
     while eng.has_unfinished():
         step()
     # shortest first: c's one chunk, then b's three, each on a step
@@ -498,7 +496,6 @@ def test_a_chunk_rides_the_decode_step_it_shares(params):
     assert all(programs in (["decode"], []) for programs in per_step[6:])
     stats = eng.stats
     assert stats["chunks_fused"] == 4 == stats["chunks"] - before["chunks"]
-    assert eng._prefills - prefills == 4
     assert stats["chunk_tokens"] == 3 + 19 + 3
     assert stats["decode_steps"] == sum(
         programs[-1:] in (["decode"], ["mixed"]) for programs in per_step)
@@ -509,26 +506,6 @@ def test_a_chunk_rides_the_decode_step_it_shares(params):
     assert stats["steps"] == sum(bool(programs) for programs in per_step)
     assert [outs[rid].token_ids for rid in "abc"] == want
     assert "empty" not in sum(per_step[1:], [])
-
-
-def test_decode_steps_per_chunk_counts_a_mixed_step_as_a_step(params):
-    """``decode_steps_per_chunk=2``: a chunk rides every second step —
-    the mixed step is the first decode step after its own chunk."""
-    eng = LLMEngine(CFG, params, slots=2, max_seq=96,
-                    prefill_chunk_tokens=8, decode_steps_per_chunk=2)
-    seen = _watch_programs(eng)
-    eng.add_request([10, 20, 30], SamplingParams(max_tokens=12),
-                    admit=False)
-    eng.step()
-    eng.add_request(LONG, SamplingParams(max_tokens=2), admit=False)
-    order = []
-    while eng.has_unfinished():
-        del seen[:]
-        eng.step()
-        order.extend(seen)
-    assert order[:6] == ["decode", "mixed", "decode", "mixed", "decode",
-                         "mixed"]
-    assert eng.stats["chunks_fused"] == 3
 
 
 def test_the_empty_mixed_step_is_the_compilation_of_the_real_ones(params):
@@ -587,12 +564,57 @@ def test_a_chunk_too_wide_to_ride_runs_alone(params, monkeypatch, rows,
     assert [outs[rid].token_ids for rid in "ab"] == want
 
 
-def test_prompt_longer_than_bucket(params):
+def test_prompt_longer_than_a_chunk(params):
     engine = LLMEngine(CFG, params, slots=1, max_seq=128)
-    prompt = list(np.random.RandomState(0).randint(1, 200, 50))
+    prompt = list(np.random.RandomState(0).randint(1, 200, 100))
     out = engine.generate([prompt],
                           SamplingParams(max_tokens=4))[0]
     assert 1 <= len(out.token_ids) <= 4
+    assert engine.stats["chunks"] == 2
+
+
+PRESETS = ("tiny", "moe-tiny", "olmoe-tiny", "axk1-tiny", "cmdaplus-tiny",
+           "solar2-tiny", "granite-h-tiny")
+
+
+@pytest.mark.parametrize("name", PRESETS)
+def test_a_default_engine_ingests_in_chunks(name):
+    """``LLMEngine(name)`` with no width given: every architecture is
+    ingested through the ONE chunk program, 64 tokens at a time (a
+    recurrent state, a ring and a latent included), and a prompt longer
+    than a chunk and one shorter get the cacheless reference's greedy
+    tokens."""
+    cfg = llama.CONFIGS[name]
+    eng = LLMEngine(name, slots=2, max_seq=128)
+    assert eng._chunk_tokens == engine_mod.PREFILL_CHUNK_TOKENS == 64
+    rng = np.random.default_rng(45)
+    prompts = [rng.integers(1, 250, n).tolist() for n in (90, 20)]
+    outs = eng.generate(prompts, SamplingParams(max_tokens=6))
+    for prompt, out in zip(prompts, outs):
+        assert out.token_ids == _truncate_at_eos(
+            _reference_greedy(eng.params, prompt, 6, cfg))
+    assert eng.stats["chunks"] == 3              # 64 + 26, then 20
+    assert eng.stats["chunk_tokens"] == 90 + 20
+    assert eng._prefill_chunk_jit._cache_size() == 1
+
+
+@pytest.mark.parametrize("width", [None, 0, -8])
+def test_a_chunk_width_that_is_no_positive_int_is_refused(params, width):
+    with pytest.raises(ValueError, match="prefill_chunk_tokens"):
+        LLMEngine(CFG, params, slots=1, max_seq=32,
+                  prefill_chunk_tokens=width)
+
+
+def test_a_chunk_wider_than_a_slot_is_masked_not_refused(params):
+    """A slot of 32 positions under the default 64-token chunk: the
+    chunk program's masks drop the rows behind the prompt, whatever the
+    slab's length — no second rule for a small engine."""
+    eng = LLMEngine(CFG, params, slots=1, max_seq=32)
+    prompt = list(range(3, 23))
+    out = eng.generate([prompt], SamplingParams(max_tokens=6))[0]
+    assert out.token_ids == _truncate_at_eos(
+        _reference_greedy(params, prompt, 6))
+    assert eng.stats["chunks"] == 1
 
 
 @pytest.mark.slow

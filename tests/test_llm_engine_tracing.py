@@ -671,14 +671,10 @@ def test_emit_ms_is_one_entry_a_token_handed_over(params, ended_by):
     assert attrs["chunk_gaps"] == []          # no other request
 
 
-@pytest.mark.parametrize("chunk_tokens,dispatches", [(8, 2), (None, 1)],
-                         ids=["chunked", "bucketed"])
-def test_chunk_gaps_name_the_gaps_that_saw_a_prefill_dispatched(
-        params, chunk_tokens, dispatches):
-    """Two requests, the second submitted in mid-decode: its prefill
-    programs (two chunks, or the whole prompt as one) are dispatched
-    across exactly as many of the first one's gaps."""
-    eng = _engine(params, prefill_chunk_tokens=chunk_tokens)
+def test_chunk_gaps_name_the_gaps_that_saw_a_prefill_dispatched(params):
+    """Two requests, the second submitted in mid-decode: its two chunks
+    are dispatched across exactly as many of the first one's gaps."""
+    eng = _engine(params, prefill_chunk_tokens=8)
     first, second = (tracing_plane.mint(sampled=True) for _ in range(2))
     chunks_at = []        # stats["chunks"] at each of the first's tokens
 
@@ -699,13 +695,12 @@ def test_chunk_gaps_name_the_gaps_that_saw_a_prefill_dispatched(
     assert len(span["attrs"]["emit_ms"]) == len(chunks_at) == 12
     # the iteration that admits the second dispatches its first
     # prefill program and then lands the first one's next token
-    assert gaps == list(range(had, had + dispatches))
-    if chunk_tokens:
-        assert gaps == [i for i in range(1, 12)
-                        if chunks_at[i] != chunks_at[i - 1]]
-        # both chunks rode the first one's decode steps (PR 39): a gap
-        # is named where a prefill was dispatched, alone or not
-        assert eng.stats["chunks_fused"] == dispatches
+    assert gaps == list(range(had, had + 2))
+    assert gaps == [i for i in range(1, 12)
+                    if chunks_at[i] != chunks_at[i - 1]]
+    # both chunks rode the first one's decode steps (PR 39): a gap is
+    # named where a prefill was dispatched, alone or not
+    assert eng.stats["chunks_fused"] == 2
     # nothing was prefilled after the second one's own first token
     (span,) = _spans(second.trace_id, "llm:engine")
     assert span["attrs"]["chunk_gaps"] == []

@@ -50,14 +50,15 @@ def _run_session_turn(eng, sid, prompt, n, **kw):
 
 def test_chunked_prefill_single_compile_entry_and_parity(params):
     """Acceptance: chunked prefill compiles EXACTLY ONE chunk variant
-    (slot/offset/length traced — not O(log max_seq) buckets), and its
-    greedy token stream matches the legacy bucketed engine."""
-    legacy = LLMEngine(CFG, params, slots=2, max_seq=96)
+    (slot/offset/length traced — none per prompt length), and its
+    greedy token stream is the cacheless reference's."""
     chunked = _engine(params)
     for prompt in ([5, 9, 17, 3, 88], list(range(2, 24))):
-        want = legacy.generate([prompt], SamplingParams(max_tokens=8))[0]
+        want = np.asarray(llama.greedy_generate(
+            params, CFG, np.asarray(prompt, np.int32), 8))[0, len(prompt):]
         got = chunked.generate([prompt], SamplingParams(max_tokens=8))[0]
-        assert got.token_ids == want.token_ids
+        assert 255 not in want          # no stop token cuts the stream
+        assert got.token_ids == want.tolist()
     assert chunked._prefill_chunk_jit._cache_size() == 1
     assert chunked.stats["chunks"] >= 1 + 3   # ceil(5/8) + ceil(22/8)
 
@@ -390,23 +391,30 @@ def test_pressure_eviction_admits_instead_of_shedding(params):
     assert err.value.retry_after_s > 0
 
 
-def test_bucketed_mode_rejects_in_flight_session_continuation(params):
-    """A second request for a session whose first turn is still in
-    flight is rejected at add_request in bucketed mode (kv_len is still
-    0 then, so the guard must key on session existence): previously it
-    parked in sess.pending and later wedged the engine mid-step."""
-    eng = LLMEngine(CFG, params, slots=2, max_seq=96)   # bucketed
-    eng.add_request([5, 9, 17], SamplingParams(max_tokens=8),
-                    admit=False, session_id="s")
-    with pytest.raises(ValueError, match="chunked prefill"):
-        eng.add_request([3, 4], SamplingParams(max_tokens=4),
-                        admit=False, session_id="s")
-    outs = []
-    deadline = time.monotonic() + 120
-    while eng.has_unfinished():
-        outs.extend(eng.step())
-        assert time.monotonic() < deadline, "engine wedged"
-    assert len(outs) == 1 and outs[0].finish_reason != "error"
+def test_a_default_engine_continues_a_session(params):
+    """An engine given no chunk width keeps a session: turn 2, queued
+    while turn 1 is still in flight, waits for it and appends at turn
+    1's offset — its tokens are the cacheless reference's over the whole
+    conversation."""
+    eng = LLMEngine(CFG, params, slots=2, max_seq=96)
+    starts, chunk = [], eng._prefill_chunk_jit
+    eng._prefill_chunk_jit = lambda *args: (starts.append(args[4]),
+                                            chunk(*args))[1]
+    first, then = [5, 9, 17], [3, 4]
+    rids = [eng.add_request(list(prompt), SamplingParams(max_tokens=n),
+                            admit=False, session_id="s")
+            for prompt, n in ((first, 8), (then, 4))]
+    outs = {}
+    _drain(eng, outs)
+    one, two = (outs[rid] for rid in rids)
+    assert one.finish_reason == two.finish_reason == "length"
+    said = first + one.token_ids
+    want = np.asarray(llama.greedy_generate(
+        params, CFG, np.asarray(said + then, np.int32), 4))[0]
+    assert two.token_ids == want[len(said) + len(then):].tolist()
+    # turn 1's last token was never written: turn 2 carries it in
+    assert starts == [0, len(said) - 1]
+    assert eng._sessions["s"].kv_len == len(said) + len(then) + 4 - 1
 
 
 def test_local_store_spill_capacity_and_distinct_files(tmp_path):

@@ -508,20 +508,12 @@ def test_what_a_recurrent_state_is_refused_by_name(params):
     with pytest.raises(ValueError, match="sessions are not kept over a "
                                          "recurrent state"):
         eng.add_request([1, 2, 3], session_id="turns")
-    with pytest.raises(ValueError, match="bucketed prefill keeps no "
-                                         "recurrent state"):
-        LLMEngine(CFG, params, slots=2, max_seq=64)
     with pytest.raises(ValueError, match="a recurrent state .* is not "
                                          "sharded"):
         _engine(params, tensor_parallel_size=2)
     with pytest.raises(ValueError, match="no linear layers"):
         llama.loss_fn_pp(params, {"tokens": jnp.zeros((2, 9), jnp.int32)},
                          CFG, mesh=type("M", (), {"shape": {"pp": 2}})())
-    with pytest.raises(ValueError, match="a linear layer keeps a state of "
-                                         "the sequence"):
-        llama.prefill_into_cache(
-            params, jnp.zeros((1, 16), jnp.int32),
-            llama.init_kv_cache(CFG, 2, 64), 0, 5, CFG)
 
 
 def test_the_state_moves_with_the_slabs(params):
