@@ -626,7 +626,9 @@ class LLMEngine:
                 f"{self.config.n_kv_heads}")
         shardings = self._llama.param_shardings(self.config, mesh)
         self.params = jax.device_put(self.params, shardings)
-        kv = NamedSharding(mesh, P(None, None, None, "tp", None))
+        # the axis behind the positions: the KV heads, or all of them
+        # side by side (``LlamaConfig.flat_kv_heads``), heads-major
+        kv = NamedSharding(mesh, P(None, None, None, "tp"))
         rep = NamedSharding(mesh, P())
         slabs = self._llama.kv_slabs(self.config)
         self.cache = {

@@ -119,13 +119,21 @@ class LlamaConfig:
     layer_kinds: tuple = ()
     window_pattern: dataclasses.InitVar[tuple] = ()
     full_rope: bool = True
-    # A linear layer: ``linear_heads`` heads whose keys and values are
-    # both ``linear_head_dim`` wide (the state a head is that squared,
-    # float32), a depth-wise causal convolution of ``linear_conv`` taps
-    # over q, k and v, and low-rank projections of width
-    # ``linear_rank`` for the decay and the output gate.
+    # A linear layer: ``linear_heads`` heads whose queries and keys are
+    # ``linear_head_dim`` wide and whose values ``linear_value_dim`` (0:
+    # as wide as the keys; the state a head is d_k x d_v, float32), a
+    # depth-wise causal convolution of ``linear_conv`` taps over q, k
+    # and v.  What the config states of the decay decides the FORM:
+    # with ``linear_rank`` the decay is a key CHANNEL's own and comes,
+    # like the output's sigmoid gate, through a low-rank pair of that
+    # width (Solar Open 2's ``kda``); with ``linear_rank`` 0 there are
+    # no such pairs — ONE decay a head, a plain ``dim -> heads``
+    # projection as the write strength's is, and the output gate a full
+    # ``dim -> heads * d_v`` projection under SiLU (Gated DeltaNet as
+    # Olmo Hybrid publishes it, ``gdn``).
     linear_heads: int = 0
     linear_head_dim: int = 0
+    linear_value_dim: int = 0
     linear_conv: int = 4
     linear_rank: int = 0
     # A state-space layer: ``ssm_heads`` heads of width
@@ -162,6 +170,12 @@ class LlamaConfig:
     parallel_block: bool = False
     # The shared experts' outputs are averaged, not summed.
     shared_experts_average: bool = False
+    # Olmo 2's reordered norm: a sequential block whose two norms sit
+    # on each sub-layer's OUTPUT, x + norm(mix(x)) and then x +
+    # norm(ffn(x)); nothing norms a sub-layer's input.  The leaves keep
+    # their names (``ln_attn``: the mix's norm, ``ln_mlp``: the
+    # feed-forward's).
+    norm_after: bool = False
 
     def __post_init__(self, window_pattern):
         if window_pattern:
@@ -198,11 +212,14 @@ class LlamaConfig:
                 self.window or self.parallel_block
                 or "full" not in self.layer_kinds or not (
                     self.linear_heads and self.linear_head_dim
-                    and self.linear_rank and self.linear_conv > 1)):
+                    and self.linear_conv > 1)
+                or (self.linear_rank and self.linear_value_dim not in (
+                    0, self.linear_head_dim))):
             raise ValueError(
-                "linear layers state their heads, a head's width, the "
-                "convolution's taps and the low-rank width, and stand "
-                "in a sequential block beside full layers only")
+                "linear layers state their heads, a head's width and "
+                "the convolution's taps, stand in a sequential block "
+                "beside full layers only, and with a decay a channel "
+                "(linear_rank) keep a square state")
         if "ssm" in self.layer_kinds and (
                 self.window or self.parallel_block or self.kv_lora_rank
                 or "full" not in self.layer_kinds or self.ssm_groups != 1
@@ -216,6 +233,10 @@ class LlamaConfig:
                 "only")
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
+        if self.norm_after and self.parallel_block:
+            raise ValueError("norm_after reorders a sequential block's "
+                             "two norms: a parallel block has one, on "
+                             "its input")
         if self.parallel_block and self.residual_multiplier != 1.0:
             raise ValueError("residual_multiplier scales what a "
                              "sequential block adds: a parallel block's "
@@ -227,6 +248,22 @@ class LlamaConfig:
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_width or self.dim // self.n_heads
+
+    @property
+    def flat_kv_heads(self) -> bool:
+        """Whether a position of the slabs holds its KV heads side by
+        side on ONE axis (``kv_slabs``).  A slab (..., positions, heads,
+        head_dim) lies with the heads on the sublanes, in tiles of 8:
+        up to 8 heads are one tile and 16 are two, and the walk takes a
+        block out of the slab where it lies; 30 heads (Olmo Hybrid's
+        multi-head layers) are three tiles and three quarters, and the
+        TPU compiler then re-lays the WHOLE carried slab, keys heads-
+        major and values positions-minor, before every layer's walk —
+        6 GiB of temporaries and copies at 8 x 12,288 positions (compiled
+        for the described v5e, PERF.md section 6, PR 46).  Side by side,
+        every head is a lane tile of its own and positions fill the
+        sublanes: nothing is padded and nothing re-laid."""
+        return self.n_kv_heads > 8 and self.n_kv_heads % 8 != 0
 
     @property
     def kinds(self) -> tuple:
@@ -244,6 +281,12 @@ class LlamaConfig:
     def n_linear(self) -> int:
         """Linear layers of the ``n_layers``."""
         return self.n_layers // len(self.kinds) * self.kinds.count("linear")
+
+    @property
+    def linear_widths(self) -> tuple:
+        """(d_k, d_v) of a linear layer's head: its state's shape."""
+        return (self.linear_head_dim,
+                self.linear_value_dim or self.linear_head_dim)
 
     @property
     def recurrent(self) -> str:
@@ -406,6 +449,19 @@ CONFIGS: dict[str, LlamaConfig] = {
         ssm_heads=4, ssm_head_dim=16, ssm_state=16,
         embedding_multiplier=12.0, residual_multiplier=0.22,
         attention_multiplier=1 / 16, logits_scaling=16.0),
+    # Olmo Hybrid's block at test size: two periods of three gated
+    # delta-rule layers with ONE decay a head (4 heads, a state of 8 x
+    # 16: keys and values of unlike widths, conv of 4 taps, no low-rank
+    # pairs, the output gate a full projection under SiLU) and a
+    # multi-head softmax layer without positional embedding (4 heads of
+    # 16, RMSNorm over the whole q and k), the norms on each
+    # sub-layer's output, dense
+    "olmo-hybrid-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=8, n_heads=4, n_kv_heads=4,
+        mlp_dim=96, max_seq=512, norm_eps=1e-6, dtype=jnp.float32,
+        qk_norm=True, full_rope=False, norm_after=True,
+        layer_kinds=("linear", "linear", "linear", "full"),
+        linear_heads=4, linear_head_dim=8, linear_value_dim=16),
 }
 
 
@@ -434,24 +490,36 @@ def _layer_leaves(c: LlamaConfig, stack: str = "layers") -> dict:
         }
     elif stack == LINEAR:
         heads, rank = c.linear_heads, c.linear_rank
-        width = heads * c.linear_head_dim
+        d_k, d_v = c.linear_widths
+        width, v_width = heads * d_k, heads * d_v
         attn = {
             "wq": ((c.dim, width), (e, p)),
             "wk": ((c.dim, width), (e, p)),
-            "wv": ((c.dim, width), (e, p)),
+            "wv": ((c.dim, v_width), (e, p)),
             # q, k and v side by side, as the cache keeps their tails
-            "conv_w": ((c.linear_conv, 3 * width), (None, "conv")),
-            # the decay: a low-rank pair, a rate a head, a bias a channel
-            "w_fa": ((c.dim, rank), (e, None)),
-            "w_fb": ((rank, width), (None, p)),
-            "a_log": ((heads,), ("decay_rate",)),
-            "dt_bias": ((width,), ("decay_bias",)),
+            "conv_w": ((c.linear_conv, 2 * width + v_width),
+                       (None, "conv")),
+            **({
+                # the decay: a low-rank pair, a rate a head, a bias a
+                # channel
+                "w_fa": ((c.dim, rank), (e, None)),
+                "w_fb": ((rank, width), (None, p)),
+                "a_log": ((heads,), ("decay_rate",)),
+                "dt_bias": ((width,), ("decay_bias",)),
+            } if rank else {
+                # ONE decay a head: a projection, a rate, a bias
+                "w_a": ((c.dim, heads), (e, None)),
+                "a_log": ((heads,), ("decay_rate",)),
+                "dt_bias": ((heads,), ("decay_bias",)),
+            }),
             "w_beta": ((c.dim, heads), (e, None)),
-            # the output gate's pair, and the norm a head before it
-            "w_ga": ((c.dim, rank), (e, None)),
-            "w_gb": ((rank, width), (None, p)),
-            "o_norm": ((c.linear_head_dim,), ("norm",)),
-            "wo": ((width, c.dim), (p, e)),
+            # the output gate — a low-rank pair or one full projection —
+            # and the norm a head before it
+            **({"w_ga": ((c.dim, rank), (e, None)),
+                "w_gb": ((rank, width), (None, p))} if rank else
+               {"w_g": ((c.dim, v_width), (e, p))}),
+            "o_norm": ((d_v,), ("norm",)),
+            "wo": ((v_width, c.dim), (p, e)),
         }
     elif c.kv_lora_rank:
         attn = {
@@ -657,10 +725,12 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     ``parallel_block`` attention and feed-forward read the same normed
     input and join in one residual sum; otherwise what either adds to
     the residual is multiplied by ``residual_multiplier`` where the
-    config states one.  Returns ``(x, state, load)``,
-    ``load`` as ``_mlp`` gives it."""
+    config states one.  With ``norm_after`` the block's two norms sit
+    on the sub-layers' OUTPUTS — x + norm(mix(x)), x + norm(ffn(x)) —
+    and a sub-layer reads the residual as it is.  Returns ``(x, state,
+    load)``, ``load`` as ``_mlp`` gives it."""
     lead, step = x.shape[:-1], index is not None
-    h = _norm(x, layer["ln_attn"], c)
+    h = x if c.norm_after else _norm(x, layer["ln_attn"], c)
     if kind == "ssm":
         with jax.named_scope("attn_ssm"):
             z, u, dt = _ssm_inputs(layer, h, c)
@@ -673,9 +743,13 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         with jax.named_scope("attn_linear"):
             attn, state = attend(*_linear_inputs(layer, h, c),
                                  layer["conv_w"])
-            gate = jax.nn.sigmoid(jnp.dot(
-                h @ layer["w_ga"], layer["w_gb"],
-                preferred_element_type=jnp.float32))
+            if c.linear_rank:
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h @ layer["w_ga"], layer["w_gb"],
+                    preferred_element_type=jnp.float32))
+            else:
+                gate = jax.nn.silu(jnp.dot(
+                    h, layer["w_g"], preferred_element_type=jnp.float32))
             attn = rmsnorm(attn, layer["o_norm"].astype(jnp.float32),
                            c.norm_eps).reshape(*lead, -1) * gate
             attn = attn.astype(x.dtype)
@@ -703,6 +777,8 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
             h, layer["w_attn_gate"],
             preferred_element_type=jnp.float32))).astype(x.dtype)
     attn = (attn @ layer["wo"]).astype(x.dtype)
+    if c.norm_after:
+        attn = _norm(attn, layer["ln_attn"], c)
     if c.parallel_block:
         out, load = _mlp(layer, h, c, index, tile)
         x = x + attn + out.astype(x.dtype)
@@ -710,9 +786,12 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     x = _residual(x, attn, c)
     x = constrain_act(x, ("batch", "seq", "embed"))
 
-    h = _norm(x, layer["ln_mlp"], c)
+    h = x if c.norm_after else _norm(x, layer["ln_mlp"], c)
     out, load = _mlp(layer, h, c, index, tile)
-    x = _residual(x, out.astype(x.dtype), c)
+    out = out.astype(x.dtype)
+    if c.norm_after:
+        out = _norm(out, layer["ln_mlp"], c)
+    x = _residual(x, out, c)
     x = constrain_act(x, ("batch", "seq", "embed"))
     return x, state, load
 
@@ -742,11 +821,13 @@ def _unconstrained(x, _dims):
 
 def _linear_inputs(layer: dict, h, c: LlamaConfig):
     """What a linear layer makes of ``h`` (..., dim) before anything
-    runs along the sequence: ``u`` (..., 3 * heads * d_k), the q, k and
-    v projections side by side, not yet convolved; ``g`` (..., heads,
-    d_k) float32, every key channel's log-decay ``-exp(a_log) *
-    softplus(w_fb (w_fa h) + dt_bias)``, a rate a head and a bias a
-    channel around a low-rank projection; ``beta`` (..., heads)
+    runs along the sequence: ``u`` (..., heads * (2 * d_k + d_v)), the
+    q, k and v projections side by side, not yet convolved; ``g``
+    float32, the log-decays ``-exp(a_log) * softplus(. + dt_bias)`` —
+    with ``linear_rank`` (..., heads, d_k), every key channel's own, a
+    rate a head and a bias a channel around a low-rank projection
+    ``w_fb (w_fa h)``; without it (..., heads), ONE a head, a rate and
+    a bias a head around ``w_a h``; ``beta`` (..., heads)
     float32, the write strength ``2 * sigmoid(w_beta h)`` — up to 2, so
     that a write may turn a direction of the state over.  The one place
     they are made, for training, chunks and decode."""
@@ -754,10 +835,12 @@ def _linear_inputs(layer: dict, h, c: LlamaConfig):
     heads = c.linear_heads
     u = jnp.concatenate([h @ layer[w] for w in ("wq", "wk", "wv")], axis=-1)
     step = jax.nn.softplus(
-        jnp.dot(h @ layer["w_fa"], layer["w_fb"], **f32)
+        (jnp.dot(h @ layer["w_fa"], layer["w_fb"], **f32) if c.linear_rank
+         else jnp.dot(h, layer["w_a"], **f32))
         + layer["dt_bias"].astype(jnp.float32))
-    g = -jnp.exp(layer["a_log"].astype(jnp.float32))[:, None] * step.reshape(
-        *h.shape[:-1], heads, -1)
+    rate = jnp.exp(layer["a_log"].astype(jnp.float32))
+    g = (-rate[:, None] * step.reshape(*h.shape[:-1], heads, -1)
+         if c.linear_rank else -rate * step)
     beta = 2.0 * jax.nn.sigmoid(jnp.dot(h, layer["w_beta"], **f32))
     return u, g, beta
 
@@ -805,11 +888,18 @@ def _attend_ssm_rows(u, dt, weights: dict, c: LlamaConfig):
 
 
 def _linear_qkv(y, c: LlamaConfig):
-    """The convolution's output ``y`` (..., 3 * heads * d_k) float32 ->
-    q, k, v (..., heads, d_k) float32: SiLU, then a head at a time q
-    and k to unit length, q times d_k^-1/2 besides."""
-    y = jax.nn.silu(y).reshape(*y.shape[:-1], 3, c.linear_heads, -1)
-    q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
+    """The convolution's output ``y`` (..., heads * (2 * d_k + d_v))
+    float32 -> q, k (..., heads, d_k) and v (..., heads, d_v) float32:
+    SiLU, then a head at a time q and k to unit length, q times
+    d_k^-1/2 besides."""
+    heads, width = c.linear_heads, c.linear_heads * c.linear_head_dim
+    y = jax.nn.silu(y)
+    if len(set(c.linear_widths)) == 1:           # three like parts
+        y = y.reshape(*y.shape[:-1], 3, heads, -1)
+        q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
+    else:
+        q, k, v = (part.reshape(*y.shape[:-1], heads, -1) for part in (
+            y[..., :width], y[..., width:2 * width], y[..., 2 * width:]))
 
     def unit(x):
         return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
@@ -817,13 +907,23 @@ def _linear_qkv(y, c: LlamaConfig):
     return unit(q) * c.linear_head_dim ** -0.5, unit(k), v
 
 
+def _delta_forms(c: LlamaConfig) -> tuple:
+    """(the block form, the step form) of ``ops/delta_rule.py`` that a
+    linear layer of this config runs: the channel forms where it states
+    a low-rank decay (``linear_rank``), else those of one decay a
+    head."""
+    if c.linear_rank:
+        return delta_rule.chunk_delta_rule, delta_rule.delta_rule_step
+    return delta_rule.chunk_gdn, delta_rule.gdn_step
+
+
 def _attend_linear_rows(u, g, beta, conv_w, c: LlamaConfig):
     """A linear layer over ONE whole sequence from an empty state, no
-    cache: u (seq, 3 * heads * d_k), g (seq, heads, d_k), beta (seq,
-    heads) -> (seq, heads, d_v) float32.  The chunk form."""
+    cache: u (seq, heads * (2 * d_k + d_v)), g (seq, heads[, d_k]), beta
+    (seq, heads) -> (seq, heads, d_v) float32.  The chunk form."""
     y, _ = delta_rule.causal_conv(
         u, jnp.zeros((c.linear_conv - 1, u.shape[-1]), u.dtype), conv_w)
-    out, _ = delta_rule.chunk_delta_rule(
+    out, _ = _delta_forms(c)[0](
         *_linear_qkv(y, c), g, beta, jnp.zeros(state_slabs(c)["s"][0]))
     return out
 
@@ -1357,11 +1457,16 @@ def kv_slabs(config: LlamaConfig) -> dict:
     ``kv_lora_rank + qk_rope_head_dim`` values and no heads axis.  A
     model with window layers has a second group, ``k_ring`` / ``v_ring``:
     its full layers keep every position in ``k`` / ``v``, its window
-    layers the newest ``ring_positions`` in a ring."""
+    layers the newest ``ring_positions`` in a ring.  Where the KV heads
+    do not fill whole sublane tiles (``LlamaConfig.flat_kv_heads``) a
+    position holds them side by side, one axis of ``n_kv_heads *
+    head_dim`` values, and a block is split into heads where it is
+    attended over (``_attend_slab``)."""
     c = config
     if c.kv_lora_rank:
         return {"c_kv": (c.kv_lora_rank,), "k_rope": (c.qk_rope_head_dim,)}
-    position = (c.n_kv_heads, c.head_dim)
+    position = ((c.n_kv_heads * c.head_dim,) if c.flat_kv_heads
+                else (c.n_kv_heads, c.head_dim))
     return {"k": position, "v": position,
             **({"k_ring": position, "v_ring": position} if c.window else {})}
 
@@ -1371,7 +1476,8 @@ def state_slabs(config: LlamaConfig) -> dict:
     its length: the cache's state leaves by name, each (its shape a
     slot, its dtype), under the same two names for either kind.  ``s``:
     the state, float32 (it is summed into over the whole sequence) — a
-    linear layer's (d_k, d_v) a head, a state-space layer's (P, N) a
+    linear layer's (d_k, d_v) a head (``linear_widths``: not always
+    square), a state-space layer's (P, N) a
     head; ``conv``: the last ``taps - 1`` inputs of the layer's
     convolution — over q, k and v, or over the heads' inputs and the
     two directions — as the block made them.  Empty without such
@@ -1384,9 +1490,10 @@ def state_slabs(config: LlamaConfig) -> dict:
                 "conv": ((c.ssm_conv - 1, ssm_widths(c)[1]), c.dtype)}
     if not c.n_linear:
         return {}
-    hd = c.linear_head_dim
-    return {"s": ((c.linear_heads, hd, hd), jnp.float32),
-            "conv": ((c.linear_conv - 1, 3 * c.linear_heads * hd), c.dtype)}
+    d_k, d_v = c.linear_widths
+    return {"s": ((c.linear_heads, d_k, d_v), jnp.float32),
+            "conv": ((c.linear_conv - 1, c.linear_heads * (2 * d_k + d_v)),
+                     c.dtype)}
 
 
 def ring_positions(config: LlamaConfig, max_seq: int, chunk: int = 0) -> int:
@@ -1647,7 +1754,10 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
         at = (i, 0 if every else slot, start) + (0,) * (slabs.ndim - 3)
         shape = (1, slabs.shape[1] if every else 1, size) + slabs.shape[3:]
         taken = lax.dynamic_slice(slabs, at, shape)
-        return taken[0] if every else taken[0, 0]
+        taken = taken[0] if every else taken[0, 0]
+        if w_kvb is None and c.flat_kv_heads:    # held side by side
+            taken = taken.reshape(*taken.shape[:-1], c.n_kv_heads, -1)
+        return taken
 
     def walk(b, state):
         high, denom, out = state
@@ -1679,6 +1789,12 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
         return out.reshape(xq.shape).astype(xq.dtype)
     out = jnp.einsum("rhc,chd->rhd", out.astype(xq.dtype), wv, **f32)
     return out.astype(xq.dtype)
+
+
+def _as_held(x, slab):
+    """A call's new rows ``x`` (rows, *position) as ``slab`` holds a
+    position (``kv_slabs``)."""
+    return x.astype(slab.dtype).reshape(x.shape[0], *slab.shape[3:])
 
 
 def _slab_positions(cache: dict, c: LlamaConfig) -> int:
@@ -1798,8 +1914,8 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
     def write(ks, vs, i, xk, xv):
         """The chunk's real rows into (layer i, slot)."""
         n = ks.shape[2]
-        return (ks.at[i, slot, write_pos[n]].set(xk.astype(ks.dtype)),
-                vs.at[i, slot, write_pos[n]].set(xv.astype(vs.dtype)))
+        return (ks.at[i, slot, write_pos[n]].set(_as_held(xk, ks)),
+                vs.at[i, slot, write_pos[n]].set(_as_held(xv, vs)))
 
     def attend(ks, vs, i, window, xq, w_kvb=None):
         """Over that slot's slab — a window layer's ring — causally by
@@ -1828,8 +1944,9 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
         else:
             g, beta, conv_w = inputs
             y, ext = delta_rule.causal_conv(u, tail, conv_w)
-            out, s1 = delta_rule.chunk_delta_rule(
-                *_linear_qkv(y, c), jnp.where(real[:, None, None], g, 0.0),
+            out, s1 = _delta_forms(c)[0](
+                *_linear_qkv(y, c),
+                jnp.where(jnp.expand_dims(real, range(1, g.ndim)), g, 0.0),
                 jnp.where(real[:, None], beta, 0.0), s0)
         tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
         return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
@@ -1857,8 +1974,8 @@ def _decode_rows(cache: dict, c: LlamaConfig, active):
     def write(ks, vs, i, xk, xv):
         """One row a slot into layer i."""
         n = ks.shape[2]
-        return (ks.at[i, slots, write_pos[n]].set(xk.astype(ks.dtype)),
-                vs.at[i, slots, write_pos[n]].set(xv.astype(vs.dtype)))
+        return (ks.at[i, slots, write_pos[n]].set(_as_held(xk, ks)),
+                vs.at[i, slots, write_pos[n]].set(_as_held(xv, vs)))
 
     def attend(ks, vs, i, window, xq, w_kvb=None):
         """Over the layer's slabs (a window layer's rings), each slot up
@@ -1879,7 +1996,7 @@ def _decode_rows(cache: dict, c: LlamaConfig, active):
         else:
             g, beta, conv_w = inputs
             y, tail = delta_rule.causal_conv_step(u, conv[i], conv_w)
-            out, new = delta_rule.delta_rule_step(
+            out, new = _delta_forms(c)[1](
                 *_linear_qkv(y, c), g, beta, s[i], active)
         tail = jnp.where(active[:, None, None], tail, conv[i])
         return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),

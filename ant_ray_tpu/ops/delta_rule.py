@@ -1,7 +1,7 @@
-"""The gated delta rule with a decay a CHANNEL its own (linear
-attention whose state is a matrix a head), in its three forms, and the
-short causal convolution in front of it.  Plain jnp: no kernel here
-computes it yet.
+"""The gated delta rule (linear attention whose state is a matrix a
+head), in its three forms, with a decay a CHANNEL its own (``kda``) or
+ONE decay a head (``gdn``: a scalar), and the short causal convolution
+in front of it.  Plain jnp: no kernel here computes it yet.
 
 A head keeps a state ``S`` (d_k, d_v), float32, and reads one token as
 
@@ -34,6 +34,31 @@ S_{t-1} + k_t u_t^T``: a decay, then a rank-one write.
 
 A token with ``beta = 0`` and ``g = 0`` changes nothing: that is how a
 caller pads.
+
+With ONE log-decay a head (``g`` (..., heads): Gated DeltaNet as Olmo
+Hybrid publishes it) the same equations hold with ``Diag(alpha_t)`` a
+multiple of the identity, and the state may be RECTANGULAR, d_k x d_v
+with d_k != d_v:
+
+* ``chunk_gdn`` — the block form's pair products become MATRIX
+  products over d_k, ``A = (K K^T) * exp(G_t - G_i)`` and ``(Q K^T) *
+  exp(G_t - G_i)`` with ``G`` (block,) a head: block * block
+  exponentials a head where the channel form takes block * block * d_k
+  (its ``k_t[c] k_i[c] exp(G_t[c] - G_i[c])`` cannot leave the
+  exponential out of the sum over ``c``).  Broadcasting a scalar decay
+  to d_k channels through ``chunk_delta_rule`` gives the same values
+  at d_k times the exponentials.
+* ``gdn_step`` — ``delta_rule_step`` with one ``exp`` a head.
+
+How such a state lies: ``s`` (..., heads, d_k, d_v) float32 row-major,
+so d_v runs along the 128 lanes and d_k along the sublanes.  Olmo
+Hybrid's 96 x 192: 96 = 12 whole sublane tiles of 8, 192 = one lane
+tile and a half — the device pads a state's rows from 192 to 256 lanes
+(a third more bytes than values: 98,304 B a head against 73,728); the
+other order, d_k on the lanes, pads 96 to 128, the same third, so the
+order is the one ``S^T k`` and ``S^T q`` read without a transpose: the
+step's sums run over sublanes, the block form's products contract the
+96 (three quarters of an MXU pass).
 """
 
 from __future__ import annotations
@@ -122,24 +147,34 @@ def _unit_lower_inverse(lower, leaf: int = 16):
     return inverse[..., 0, :, :]
 
 
+def _by_blocks(form, q, k, v, g, beta, s0, block: int):
+    """The scaffolding of a block form: the sequence filled up to whole
+    blocks with tokens that change nothing, each input as (n, heads,
+    block, ...), ``form(lower, strict)`` — given the block's lower and
+    strictly lower triangular masks — the scan's body ``(s, a block's
+    inputs) -> (s, o)``, the state handed block to block in the carry;
+    -> (o (tokens, heads, d_v), the last state)."""
+    given, heads = q.shape[:2]
+    q, k, v, g, beta = (
+        jnp.pad(x, ((0, -given % block),) + ((0, 0),) * (x.ndim - 1))
+        for x in (q, k, v, g, beta))
+    tokens = q.shape[0]
+    n = tokens // block
+
+    def blocks(x):                        # -> (n, heads, block, ...)
+        x = x.reshape(n, block, *x.shape[1:])
+        return jnp.moveaxis(x, 2, 1)
+
+    one = form(jnp.tril(jnp.ones((block, block), bool)),
+               jnp.tril(jnp.ones((block, block), bool), -1))
+    s, o = lax.scan(one, s0, tuple(map(blocks, (q, k, v, g, beta))))
+    return jnp.moveaxis(o, 1, 2).reshape(tokens, heads, -1)[:given], s
+
+
 def chunk_delta_rule(q, k, v, g, beta, s0, block: int = BLOCK):
     """``delta_rule_scan``'s values in blocks of ``block`` tokens, the
     last one filled up with tokens that change nothing."""
-    with jax.named_scope("kda_chunk"):
-        given, heads = q.shape[:2]
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, -given % block),) + ((0, 0),) * (x.ndim - 1))
-            for x in (q, k, v, g, beta))
-        tokens = q.shape[0]
-        n = tokens // block
-
-        def blocks(x):                        # -> (n, heads, block, ...)
-            x = x.reshape(n, block, *x.shape[1:])
-            return jnp.moveaxis(x, 2, 1)
-
-        lower = jnp.tril(jnp.ones((block, block), bool))
-        strict = jnp.tril(jnp.ones((block, block), bool), -1)
-
+    def form(lower, strict):
         def one(s, x):
             q, k, v, g, beta = x              # (heads, block, ...)
             gc = jnp.cumsum(g, axis=1)
@@ -160,22 +195,74 @@ def chunk_delta_rule(q, k, v, g, beta, s0, block: int = BLOCK):
                 "hck,hcv->hkv", k * out_of, u)
             return s, o
 
-        s, o = lax.scan(one, s0, tuple(map(blocks, (q, k, v, g, beta))))
-        return jnp.moveaxis(o, 1, 2).reshape(tokens, heads, -1)[:given], s
+        return one
+
+    with jax.named_scope("kda_chunk"):
+        return _by_blocks(form, q, k, v, g, beta, s0, block)
+
+
+def _step(decayed, q, k, v, beta, s, active):
+    """One token a row from the states already ``decayed`` (alpha * S):
+    two passes over them, the first reads ``(alpha * S)^T k`` and
+    ``(alpha * S)^T q`` together, the second writes ``S``; ``o =
+    S_new^T q = (alpha * S)^T q + u (k . q)``."""
+    sk = jnp.sum(decayed * k[..., None], axis=-2)
+    sq = jnp.sum(decayed * q[..., None], axis=-2)
+    u = beta[..., None] * (v - sk)
+    o = sq + u * jnp.sum(k * q, axis=-1, keepdims=True)
+    new = decayed + k[..., None] * u[..., None, :]
+    return o, jnp.where(active[:, None, None, None], new, s)
 
 
 def delta_rule_step(q, k, v, g, beta, s, active):
     """One token a row: q, k, g (rows, heads, d_k), v (rows, heads,
     d_v), beta (rows, heads), s (rows, heads, d_k, d_v), active (rows,)
     bool -> (o (rows, heads, d_v), the new states).  Elementwise
-    products and sums in float32, two passes over the states: the first
-    reads ``(alpha * S)^T k`` and ``(alpha * S)^T q`` together, the
-    second writes ``S``; ``o = S_new^T q = (alpha * S)^T q + u (k . q)``."""
+    products and sums in float32 (``_step``)."""
     with jax.named_scope("kda_step"):
-        decayed = jnp.exp(g)[..., None] * s
-        sk = jnp.sum(decayed * k[..., None], axis=-2)
-        sq = jnp.sum(decayed * q[..., None], axis=-2)
-        u = beta[..., None] * (v - sk)
-        o = sq + u * jnp.sum(k * q, axis=-1, keepdims=True)
-        new = decayed + k[..., None] * u[..., None, :]
-        return o, jnp.where(active[:, None, None, None], new, s)
+        return _step(jnp.exp(g)[..., None] * s, q, k, v, beta, s, active)
+
+
+def chunk_gdn(q, k, v, g, beta, s0, block: int = BLOCK):
+    """``chunk_delta_rule`` for ONE log-decay a head: q, k (tokens,
+    heads, d_k), v (tokens, heads, d_v), g and beta (tokens, heads), s0
+    (heads, d_k, d_v), all float32 -> (o (tokens, heads, d_v), the last
+    state): what ``delta_rule_scan`` gives with ``g`` repeated over the
+    d_k channels.  The pair products are matrix products (the module's
+    docstring); those the triangular system is made of and solved with
+    run at the highest matmul precision, as the channel form's
+    elementwise sums are exact float32."""
+    def form(lower, strict):
+        def one(s, x):
+            q, k, v, g, beta = x              # (heads, block[, ...])
+            gc = jnp.cumsum(g, axis=1)
+            # exp(G_t - G_i) for i <= t, 0 above the diagonal
+            decay = jnp.exp(jnp.where(
+                lower, gc[:, :, None] - gc[:, None], -jnp.inf))
+            kk = jnp.einsum("htk,hik->hti", k, k,
+                            precision=_HIGHEST) * decay
+            qk = jnp.einsum("htk,hik->hti", q, k,
+                            precision=_HIGHEST) * decay
+            solve = _unit_lower_inverse(
+                beta[..., None] * jnp.where(strict, kk, 0.0))
+            into = jnp.exp(gc)[..., None]     # from the block's start
+            u = jnp.matmul(solve, beta[..., None] * (
+                v - into * jnp.matmul(k, s)),
+                precision=_HIGHEST)
+            o = into * jnp.matmul(q, s) + jnp.matmul(qk, u)
+            out_of = jnp.exp(gc[:, -1:] - gc)[..., None]  # to its end
+            s = into[:, -1:] * s + jnp.einsum(
+                "hck,hcv->hkv", k * out_of, u)
+            return s, o
+
+        return one
+
+    with jax.named_scope("gdn_chunk"):
+        return _by_blocks(form, q, k, v, g, beta, s0, block)
+
+
+def gdn_step(q, k, v, g, beta, s, active):
+    """``delta_rule_step`` for ONE log-decay a head: g (rows, heads)."""
+    with jax.named_scope("gdn_step"):
+        return _step(jnp.exp(g)[..., None, None] * s, q, k, v, beta, s,
+                     active)

@@ -32,9 +32,11 @@ def _rel_l2(got, want, jnp):
             / jnp.sqrt(jnp.sum(want ** 2, -1))]
 
 
-def _reference(spec, params, tokens, first, jax, jnp, cast=None):
+def _reference(spec, params, tokens, first, jax, jnp, cast=None, **other):
     """The plain reference's logits from position ``first`` on; ``cast``
-    rounds every matrix it reads first."""
+    rounds every matrix it reads first; ``other``: static keywords of
+    the reference's ``block`` that a control sets (another model on the
+    same weights)."""
     import importlib
 
     from chipbench.spec import resolve
@@ -51,9 +53,9 @@ def _reference(spec, params, tokens, first, jax, jnp, cast=None):
 
         embed, head = cast(embed), cast(head)
     block = jax.jit(ref.block, static_argnames=(
-        "n_heads", "n_kv_heads", "rope_theta", "norm_eps"))
+        "n_heads", "n_kv_heads", "rope_theta", "norm_eps", *other))
     return ref.forward(embed, (layer, n), norm_f, head, jnp.asarray(tokens),
-                       block_fn=block, **ref.dims_of(spec))[first:]
+                       block_fn=block, **ref.dims_of(spec), **other)[first:]
 
 
 def main(argv=None) -> int:

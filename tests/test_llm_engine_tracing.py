@@ -420,7 +420,8 @@ RECURRENT_COUNTERS = ("recurrent_decode_rows", "recurrent_slot_rows",
                       "recurrent_resets")
 
 
-@pytest.mark.parametrize("name", ["solar2-tiny", "granite-h-tiny"])
+@pytest.mark.parametrize("name", ["solar2-tiny", "granite-h-tiny",
+                                  "olmo-hybrid-tiny"])
 def test_recurrent_state_traffic_is_counted_by_the_devices_rule(
         monkeypatch, name):
     """The five ``recurrent_*`` counters (PR 38) of a model with
@@ -568,6 +569,46 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     for scope in (*scopes, *block_forms):
         assert (f'{scope}/' in mixed) == (
             scope.split("/")[0] in scopes), scope
+
+
+def test_a_dense_hybrid_names_the_scalar_forms_scopes():
+    """Olmo Hybrid's linear layers (PR 46: ONE decay a head) run the
+    scalar forms of ``ops/delta_rule.py`` under scopes of their own,
+    ``gdn_step`` and ``gdn_chunk`` inside ``attn_linear`` — never the
+    channel forms' ``kda_*`` — beside ``attn_full``; a dense model has
+    no routing counters and no ``moe`` scope, and its recurrent
+    counters count its linear layers."""
+    cfg = llama.CONFIGS["olmo-hybrid-tiny"]
+    eng = LLMEngine(cfg, slots=2, max_seq=64, prefill_chunk_tokens=8,
+                    tokenizer=_NoEos())
+    assert "routing" not in eng.cache
+    eng.generate([[5, 9, 17]], SamplingParams(max_tokens=3))
+    stats = eng.stats
+    assert not any(name in stats for name in llama.ROUTING_COUNTERS)
+    assert stats["recurrent_chunk_rows"] == 6 * 8 * stats["chunks"]
+    assert stats["recurrent_chunk_tokens"] == 6 * 3
+    assert stats["recurrent_slot_rows"] == 6 * 2 * stats["decode_steps"]
+    decode = eng._decode_jit.lower(
+        eng.params, eng.cache, eng._last,
+        eng._jnp.ones((2,), bool)).as_text(debug_info=True)
+    chunk = eng._prefill_chunk_jit.lower(
+        eng.params, eng.cache, eng._jnp.zeros((8,), "int32"), 0, 0,
+        3).as_text(debug_info=True)
+    mixed = eng._mixed_step_jit.lower(
+        eng.params, eng.cache, eng._last, eng._jnp.ones((2,), bool),
+        eng._jnp.zeros((8,), "int32"), 0, 0, 3).as_text(debug_info=True)
+    for text, inside, outside in (
+            (decode, ("attn_linear/gdn_step/", "attn_full/"),
+             ("gdn_chunk/",)),
+            (chunk, ("attn_linear/gdn_chunk/", "attn_full/"),
+             ("gdn_step/",)),
+            (mixed, ("attn_linear/gdn_step/", "attn_linear/gdn_chunk/",
+                     "attn_full/"), ())):
+        for scope in inside:
+            assert scope in text, scope
+        for scope in (*outside, "kda_step/", "kda_chunk/", "moe/",
+                      "attn_ssm/", "attn_window/"):
+            assert scope not in text, scope
 
 
 def test_phases_tile_the_loop_and_stats_keep_their_keys(params):

@@ -430,6 +430,56 @@ def test_state_space_state_fits_the_chip_at_the_cells_size(
     assert "grouped_matmul" in text and "ragged-dot" not in text
 
 
+# Olmo Hybrid's blocks at their published widths, the benchmark's cut:
+# four periods of three gated delta-rule layers with ONE decay a head
+# (30 heads, a state of 96 x 192, a convolution of 4 taps over 11,520
+# channels, no low-rank pairs) and a multi-head softmax layer without
+# positional embedding (30 query = 30 KV heads of 128, RMSNorm over the
+# whole q and k), the norms on each sub-layer's output, a dense SwiGLU
+# of 11,008, the whole vocabulary, the head not tied.
+SCALAR_GATED = llama.LlamaConfig(
+    vocab_size=100352, dim=3840, n_layers=16, n_heads=30, n_kv_heads=30,
+    mlp_dim=11008, max_seq=65536, norm_eps=1e-6, qk_norm=True,
+    full_rope=False, norm_after=True,
+    layer_kinds=("linear", "linear", "linear", "full"),
+    linear_heads=30, linear_head_dim=96, linear_value_dim=192)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_thirty_heads_slabs_stay_where_they_lie_at_the_cells_size(
+        v5e, monkeypatch, program):
+    """`olmo-hybrid-7b.longdoc` as it is served: 8 slots x 12,288, FOUR
+    layers' slabs of 30 KV heads (5.625 GiB) beside twelve layers'
+    rectangular states (0.2 GiB), prompts in chunks of 512 — 520 rows
+    with the slots, over ``engine.RIDE_ROWS``: the chunk and the decode
+    step stay two programs.  With the heads on an axis of their own the
+    compiler re-laid BOTH whole slabs before every softmax layer's walk
+    (30 heads are no whole sublane tiles: 6 GiB of temporaries, 19.8
+    GiB in all, refused); side by side in a position
+    (``LlamaConfig.flat_kv_heads``) nothing of a slab's size is
+    written: the temporaries stay under a twentieth of the slabs."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, cache = _compile_step(
+        v5e.devices[0], program, SCALAR_GATED, 8, 12288, chunk=512)
+    kept = {name: cache[name] for name in (
+        *llama.kv_slabs(SCALAR_GATED), *llama.state_slabs(SCALAR_GATED))}
+    assert {name: (leaf.shape, leaf.dtype.name)
+            for name, leaf in kept.items()} == {
+        "k": ((4, 8, 12288, 3840), "bfloat16"),
+        "v": ((4, 8, 12288, 3840), "bfloat16"),
+        "s": ((12, 8, 30, 96, 192), "float32"),
+        "conv": ((12, 8, 3, 11520), "bfloat16")}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    slabs = _tree_bytes((cache["k"], cache["v"]))
+    assert mem.temp_size_in_bytes < slabs / 16
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < 14.0 * 2 ** 30
+    text = compiled.as_text()
+    assert not re.search(r"bf16\[4,8,12288,3840\]\S* copy\(", text)
+
+
 # llama3-1b with its 2048 columns of attention as 16 heads of 128 (8 of
 # them KV heads: InternLM2-1.8B's attention), the head width of every
 # configuration the benchmark serves.
