@@ -199,7 +199,16 @@ class _PhaseRecorder:
     step before them was still unread.  ``decode_span_positions`` adds
     up, per decode step, the positions of a slab its attention walks
     (whole blocks up to the longest active row, from the host's own
-    ``kv_len``: no read), ``decode_slab_positions`` the slab's.  Of a
+    ``kv_len``: no read), ``decode_slab_positions`` the slab's.
+    ``decode_walk_positions`` adds up, per decode step, that span times
+    the slots — what a walk bound by the longest row reads of a layer's
+    slabs — and ``decode_read_positions`` what the dispatched program
+    reads of them: where the step's rows attend through
+    ``ops/pallas/decode_attention.py`` (``llama._decode_kernel``: full
+    slabs, no latent attention, one TPU device) the sum of each ACTIVE
+    row's own whole blocks, an idle slot nothing; where they take the
+    XLA walk (latent slabs, a mesh, any other backend) the same as
+    ``decode_walk_positions``.  Of a
     model with window layers (``LlamaConfig.window``; every other
     leaves these three at zero) ``full_span_positions`` and
     ``window_span_positions`` add up, per decode step AND per chunk,
@@ -251,7 +260,8 @@ class _PhaseRecorder:
         # the dict change size.
         for key in ("steps", "decode_steps", "decode_slots",
                     "decode_ahead_steps", "decode_span_positions",
-                    "decode_slab_positions", "window_span_positions",
+                    "decode_slab_positions", "decode_walk_positions",
+                    "decode_read_positions", "window_span_positions",
                     "full_span_positions", "decode_rows_past_window",
                     "recurrent_decode_rows", "recurrent_slot_rows",
                     "recurrent_chunk_tokens", "recurrent_chunk_rows",
@@ -373,6 +383,17 @@ class LLMEngine:
     CHECKPOINT DIRECTORY (HF Llama layout — real weights, loaded via
     models/checkpoint.py), or a LlamaConfig; ``params`` overrides both
     (random init remains the default for named configs: tests/bench).
+
+    Which rows attend how (``llama._attend_slab``): a decode step's rows
+    — in ``_decode`` and as the decode rows of ``_mixed_step`` — read,
+    over the full slabs of a model without latent attention on one TPU
+    device, each ACTIVE row's own blocks as far as that row's length
+    through ``ops/pallas/decode_attention.py``; a chunk's rows, a window
+    layer's rings, latent slabs, a mesh and every other backend walk in
+    XLA, every slot as far as the longest live row.
+    ``stats["decode_walk_positions"]`` counts what the second rule
+    reads of a decode step's slabs, ``stats["decode_read_positions"]``
+    what the dispatched program reads (``_PhaseRecorder``).
     """
 
     def __init__(self, model="tiny", params=None, *, slots: int = 8,
@@ -458,6 +479,10 @@ class LLMEngine:
         # cache was made.
         self._ring = llama.ring_positions(self.config, self.max_seq,
                                           prefill_chunk_tokens)
+        # Whether a decode step's rows read their own blocks alone (the
+        # kernel) or every slot's as far as the longest (the walk): what
+        # ``decode_read_positions`` counts.
+        self._decode_kernel = llama._decode_kernel(self.config, self.mesh)
         # Per-slot sampling keys, resident on the device: a key enters
         # its row when its sequence joins the decode batch, the jitted
         # sampler splits every active row each step, and the row leaves
@@ -1116,9 +1141,13 @@ class LLMEngine:
         # than the host's kv_len, which moves when its token lands.
         unread = {id(seq) for _, seq in flight[1]} if flight else ()
         contexts = [1 + seq.kv_len + (id(seq) in unread) for _, seq in rows]
-        stats["decode_span_positions"] += self._llama.span_positions(
-            max(contexts), self.max_seq)
+        span = self._llama.span_positions(max(contexts), self.max_seq)
+        stats["decode_span_positions"] += span
         stats["decode_slab_positions"] += self.max_seq
+        walk = self.slots * span
+        stats["decode_walk_positions"] += walk
+        stats["decode_read_positions"] += self._llama.read_positions(
+            contexts, self.max_seq) if self._decode_kernel else walk
         self._note_walk(max(contexts), contexts)
         self._note_recurrent(self.slots, len(rows))
         work = sampler_work(self._sampling_rows[slot] for slot, _ in rows)

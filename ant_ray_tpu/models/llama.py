@@ -26,7 +26,7 @@ from jax import lax
 from ant_ray_tpu.ops import delta_rule, ssd
 from ant_ray_tpu.ops.attention import attention
 from ant_ray_tpu.ops.layernorm import layernorm
-from ant_ray_tpu.ops.pallas import grouped_matmul
+from ant_ray_tpu.ops.pallas import decode_attention, grouped_matmul
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
 from ant_ray_tpu.ops.rope import (
     YarnScaling,
@@ -1647,6 +1647,26 @@ def span_positions(longest: int, max_seq: int) -> int:
     return min(max(-(-longest // size), 1) * size, max_seq)
 
 
+def _decode_kernel(c: LlamaConfig, mesh) -> bool:
+    """Whether a decode step's rows attend over a layer's FULL slabs
+    through ``ops/pallas/decode_attention.py`` (each ACTIVE row's own
+    blocks, read where they lie) and not through ``_attend_slab``'s XLA
+    walk, which a window layer's rings always take.  The walk also
+    keeps latent slabs, slabs sharded over a ``mesh`` (a Mosaic kernel
+    is not partitioned automatically), any backend but the TPU, and a
+    head that is no whole lane tiles (the kernel's blocks are cut by
+    the 128 lanes)."""
+    return (not c.kv_lora_rank and mesh is None
+            and jax.default_backend() == "tpu" and c.head_dim % 128 == 0)
+
+
+def read_positions(contexts, max_seq: int) -> int:
+    """The positions the kernel reads for a decode step whose active
+    rows hold ``contexts`` positions each, on the host: every row's own
+    whole blocks (``decode_attention.blocks_read``)."""
+    return sum(span_positions(n, max_seq) for n in contexts)
+
+
 def _ring_holds(top, rows, ring: int):
     """The position each of a ring's ``rows`` holds once position
     ``top`` is written: the newest p <= top with p = row (mod ring);
@@ -1665,12 +1685,28 @@ def _rows_of(pos, live, length: int, max_seq: int):
 
 
 def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
-                 w_kvb=None, window: int = 0, top=None):
+                 w_kvb=None, window: int = 0, top=None, visits=None):
     """Attention of rows ``xq`` (rows, heads, hd), row ``r`` over cached
     positions 0..``pos[r]`` of layer ``i`` of the CARRIED slabs ``ks``,
     ``vs`` (layers, slots, max_seq, *position): of ONE slot's slab that
     the rows share (``slot`` a scalar: a chunk's), or row ``r`` of slot
     ``r``'s (``slot`` None: a decode step's).
+
+    Which rows take which path: a decode step's rows that bring their
+    step's ``visits`` (``decode_attention.work_list``, which
+    ``_decode_rows`` builds once a step over the full slabs of a model
+    without latent attention on one TPU device: ``_decode_kernel``) go
+    through ``ops/pallas/decode_attention.py`` — the same blocks, sums
+    and roundings as stated below, each ACTIVE row's own blocks alone
+    fetched from the carried slabs where they lie, nothing for an idle
+    slot, whose output is zeros.  A chunk's rows (512 of them share one
+    slot's blocks: matrix-shaped already), a window layer's rings,
+    latent slabs, slabs under a mesh and every other backend take the
+    XLA walk that follows.  The engine counts both a decode step
+    (``LLMEngine.stats``): ``decode_walk_positions``, what the walk
+    reads of a layer's slabs — every slot as far as the longest active
+    row — and ``decode_read_positions``, what the path taken reads
+    (``read_positions`` for the kernel).
 
     The slab is walked in blocks of ``ATTEND_BLOCK`` positions, each
     sliced out of the carried array where it lies, the first ``blocks``
@@ -1721,6 +1757,11 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
     rows, max_seq = xq.shape[0], ks.shape[2]
     size = min(ATTEND_BLOCK, max_seq)
     every = slot is None                         # row r reads slot r
+    if every and visits is not None and not window:
+        return decode_attention.decode_attention(
+            xq, ks, vs, i, pos, visits, block=ATTEND_BLOCK,
+            scale=c.attention_multiplier or c.head_dim ** -0.5,
+            interpret=jax.default_backend() != "tpu")
     t = "rt" if every else "t"                   # a block's leading axes
     f32 = {"preferred_element_type": jnp.float32}
     if w_kvb is None:
@@ -1954,10 +1995,11 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
     return chunk, pos, write, attend, state
 
 
-def _decode_rows(cache: dict, c: LlamaConfig, active):
+def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
     """What a decode step's rows — one a slot, those ``active`` live —
     do with a layer, for ``_row_groups``: ``(rows, their positions,
-    write, attend, state)``."""
+    write, attend, state)``.  ``mesh``: the one the slabs are sharded
+    over, if any (``_decode_kernel``)."""
     max_seq = _slab_positions(cache, c)
     pos = cache["length"]                       # (slots,) write position
     # The walk ends behind the longest ACTIVE row: an idle slot that
@@ -1970,6 +2012,11 @@ def _decode_rows(cache: dict, c: LlamaConfig, active):
     lengths = {cache[name].shape[2] for name in kv_slabs(c)}
     write_pos = {n: _rows_of(pos, active, n, max_seq) for n in lengths}
     blocks = {n: _span_blocks(longest, n) for n in lengths}
+    # each active row's own blocks, for the full slabs' kernel: one list
+    # a step, the layers' alike
+    visits = decode_attention.work_list(
+        pos, active, ATTEND_BLOCK, max_seq) if _decode_kernel(c, mesh) \
+        else None
 
     def write(ks, vs, i, xk, xv):
         """One row a slot into layer i."""
@@ -1981,7 +2028,7 @@ def _decode_rows(cache: dict, c: LlamaConfig, active):
         """Over the layer's slabs (a window layer's rings), each slot up
         to its own position."""
         return _attend_slab(xq, ks, vs, i, None, pos, blocks[ks.shape[2]],
-                            c, w_kvb, window, pos)
+                            c, w_kvb, window, pos, visits)
 
     def state(s, conv, i, u, *inputs):
         """A recurrent layer: one token a slot from layer i's states; a
@@ -2148,7 +2195,8 @@ def decode_step(params: dict, last_tokens, cache: dict,
     c = config
     x = _embed(params, last_tokens, c)                 # (slots, dim)
     x, written = _scan_layers(
-        params, x, cache, c, *_row_groups(_decode_rows(cache, c, active)),
+        params, x, cache, c, *_row_groups(
+            _decode_rows(cache, c, active, mesh)),
         decode=True, mesh=mesh)
     return _logits(params, x, c), {**written, "length": _stepped(
         cache["length"], active, _slab_positions(cache, c))}
@@ -2188,7 +2236,7 @@ def mixed_step(params: dict, last_tokens, tokens, cache: dict,
     x = _embed(params, jnp.concatenate([last_tokens, tokens]), c)
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(
-            _decode_rows(cache, c, active),
+            _decode_rows(cache, c, active, mesh),
             _chunk_rows(cache, c, tokens.shape[0], slot, start, chunk_len)),
         decode=False, mesh=mesh)
     # Behind the layers each part goes on as in its own program — the

@@ -2,23 +2,30 @@
 logits for that row, are bit-equal whatever bound the OTHER rows'
 lengths set for the walk over the cache (``llama._attend_slab``) — what
 ``tests/test_llama.py`` holds on the CPU at test size, here at Mistral's,
-OLMoE's and A.X-K1's widths and slabs (two layers each, random weights
-and cache); and the mixed step's two walks (PR 39: ``_row_groups`` of a
+OLMoE's, A.X-K1's and Olmo Hybrid's widths and slabs (two layers each —
+one period of Olmo Hybrid's four — random weights and cache); the same
+of the rows' path through ``ops/pallas/decode_attention.py`` (PR 47:
+what a decode step's rows take on the chip over every slab but the
+latent — a row alone, among rows of other lengths and beside idle
+slots; the step's logits and the mixed step's decode rows go through it
+too); and the mixed step's two walks (PR 39: ``_row_groups`` of a
 decode step's rows and a chunk's) each give their rows what the same
 walk gives them alone, to the bit, whatever the other part's lengths.
 One JSON line a shape; through the chip tool, from the root:
 
-    python -m benchmarks.attend_invariance
+    python -m benchmarks.attend_invariance [shape ...]
 """
 
 import functools
 import json
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ant_ray_tpu.models import llama
+from ant_ray_tpu.ops.pallas import decode_attention
 from ant_ray_tpu.ops.rope import YarnScaling
 
 MISTRAL = llama.LlamaConfig(
@@ -37,6 +44,16 @@ AXK1 = llama.LlamaConfig(
     kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
     v_head_dim=128, rope_scaling=YarnScaling(
         32.0, 4096, mscale=1.0, mscale_all_dim=1.0))
+OLMO_HYBRID = llama.LlamaConfig(
+    vocab_size=100352, dim=3840, n_layers=4, n_heads=30, n_kv_heads=30,
+    mlp_dim=11008, max_seq=65536, norm_eps=1e-6, qk_norm=True,
+    full_rope=False, norm_after=True,
+    layer_kinds=("linear", "linear", "linear", "full"),
+    linear_heads=30, linear_head_dim=96, linear_value_dim=192)
+# shape: (configuration, slots, max_seq)
+SHAPES = {"mistral-7b": (MISTRAL, 16, 3072), "olmoe-1b-7b": (OLMOE, 16, 3072),
+          "ax-k1": (AXK1, 48, 4096),
+          "olmo-hybrid-7b": (OLMO_HYBRID, 8, 12288)}
 
 
 def bits(x):
@@ -53,6 +70,7 @@ def check(name, c, slots, max_seq):
             k, cache[slab].shape, jnp.float32).astype(c.dtype)
     size = min(llama.ATTEND_BLOCK, max_seq)
     total, own = -(-max_seq // size), 437 // size + 1   # row 0 holds 438
+    layer = cache[names[0]].shape[0] - 1      # the last that has slabs
 
     # the attention alone: one compiled program, the bound an argument
     w_kvb = params["layers"]["w_kvb"][0] if c.kv_lora_rank else None
@@ -60,11 +78,30 @@ def check(name, c, slots, max_seq):
         slots, c.n_heads, c.head_dim), jnp.float32).astype(c.dtype)
     pos = jnp.full((slots,), 300, jnp.int32).at[0].set(437)
     attend = jax.jit(lambda xq, ks, vs, w_kvb, blocks: llama._attend_slab(
-        xq, ks, vs, 1, None, pos, blocks, c, w_kvb))
+        xq, ks, vs, layer, None, pos, blocks, c, w_kvb))
     outs = [attend(xq, cache[names[0]], cache[names[1]], w_kvb,
                    jnp.int32(blocks)) for blocks in (own, own + 1, total)]
     same_attention = all((bits(out[0]) == bits(outs[0][0])).all()
                          for out in outs)
+
+    # the decode rows' own path (the kernel, where ``_decode_kernel``
+    # gives it them): row 0 among short rows, among long ones, beside
+    # idle slots, and alone
+    same_kernel = None
+    if llama._decode_kernel(c, None):
+        rows_path = jax.jit(
+            lambda xq, ks, vs, pos, active: llama._attend_slab(
+                xq, ks, vs, layer, None, pos, None, c,
+                visits=decode_attention.work_list(
+                    pos, active, llama.ATTEND_BLOCK, max_seq)))
+        far = pos.at[1:].set(max_seq - 2)
+        everyone = jnp.ones((slots,), bool)
+        outs = [rows_path(xq, cache[names[0]], cache[names[1]], p, a)
+                for p, a in ((pos, everyone), (far, everyone),
+                             (far, everyone.at[-1].set(False)),
+                             (pos, everyone.at[1:].set(False)))]
+        same_kernel = bool(all((bits(out[0]) == bits(outs[0][0])).all()
+                               for out in outs))
 
     # the whole step: the other rows short, one of them near its slab's
     # end, and that one inactive
@@ -102,7 +139,8 @@ def check(name, c, slots, max_seq):
                   "chunk": (slots, slots + chunk)}[parts]
         _, write_attend, _ = llama._row_groups(*(
             groups if parts == "both" else groups[parts == "chunk":][:1]))
-        return write_attend(held[names[0]], held[names[1]], 1, 0, xq2[lo:hi],
+        return write_attend(held[names[0]], held[names[1]], layer, 0,
+                            xq2[lo:hi],
                             fresh[0][lo:hi], fresh[1][lo:hi], w_kvb)[0]
 
     walks = functools.partial(jax.jit(walks, static_argnums=4), tuple(
@@ -124,6 +162,8 @@ def check(name, c, slots, max_seq):
         "shape": name, "slots": slots, "max_seq": max_seq, "blocks": total,
         "attention_row0_bit_equal_at_its_own_bound_one_more_and_all":
             bool(same_attention),
+        "kernel_row0_bit_equal_among_short_long_idle_rows_and_alone":
+            same_kernel,
         "decode_step_row0_bit_equal_short_vs_long_active":
             bool((bits(a[0]) == bits(b[0])).all()),
         "decode_step_row0_bit_equal_short_vs_long_inactive":
@@ -138,6 +178,5 @@ def check(name, c, slots, max_seq):
 
 if __name__ == "__main__":
     print(jax.devices())
-    check("mistral-7b", MISTRAL, 16, 3072)
-    check("olmoe-1b-7b", OLMOE, 16, 3072)
-    check("ax-k1", AXK1, 48, 4096)
+    for shape in sys.argv[1:] or SHAPES:
+        check(shape, *SHAPES[shape])

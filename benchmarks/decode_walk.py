@@ -1,0 +1,114 @@
+"""On the chip: a decode step's attention over the slabs alone, the XLA
+walk of ``llama._attend_slab`` beside ``ops/pallas/decode_attention.py``,
+at the shapes and contexts the benchmark's serving cells decode at: one
+program a path that attends in ONE layer of the cell's slabs (a traced
+layer index, as the step programs have), run on each layer in turn,
+device ms a layer,
+the GB/s over the LIVE rows' own whole blocks of keys and values (what
+the kernel reads; the walk reads every slot as far as the longest), and
+the largest difference between the two on an active row.  Times are the
+device's (``XLA Modules`` events of a profiler trace, median of the
+executions); without a TPU the kernel runs in the interpreter at a cut
+size and nothing is timed.  One JSON line a shape; through the chip
+tool, from the root:
+
+    python -m benchmarks.decode_walk [shape ...]
+"""
+
+import json
+import statistics
+import sys
+import tempfile
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ant_ray_tpu.models import llama
+from ant_ray_tpu.ops.pallas import decode_attention
+from benchmarks.sampler_paths import device_ms
+
+# shape: (softmax layers with full slabs, slots, max_seq, heads, KV
+#         heads, the active rows' contexts; the other slots idle)
+SHAPES = {
+    "olmo-hybrid-7b.longdoc": (4, 8, 12288, 30, 30,
+                               (11000, 9500, 7700, 6000, 4100, 1500)),
+    "command-a-plus.docqa": (1, 16, 32768, 128, 8, (26000, 9000, 2500)),
+    "solar-open2.digest": (1, 24, 32768, 64, 8,
+                           tuple(range(8000, 31000, 1000)) + (25000,)),
+    "granite-4.0-h-small.sessions": (1, 48, 6144, 32, 8,
+                                     tuple(range(700, 5400, 100))),
+    "mistral-7b.decode": (16, 16, 3072, 32, 8, tuple(range(300, 940, 40))),
+    "olmoe-1b-7b.rollout": (8, 16, 3072, 16, 16, tuple(range(300, 940, 40))),
+    "internlm2-1.8b.chat": (24, 12, 2048, 16, 8, tuple(range(150, 450, 25))),
+}
+RUNS = 8
+
+
+def measure(name):
+    layers, slots, max_seq, heads, kv_heads, contexts = SHAPES[name]
+    on_chip = jax.default_backend() == "tpu"
+    if not on_chip:                              # a rehearsal's size
+        layers, max_seq = min(layers, 2), 1024
+        contexts = tuple(min(n, max_seq - 1) // 4 + 1 for n in contexts)
+    c = llama.LlamaConfig(vocab_size=256, dim=heads * 128, n_layers=layers,
+                          n_heads=heads, n_kv_heads=kv_heads, head_width=128,
+                          mlp_dim=256, max_seq=max_seq)
+    cache = llama.init_kv_cache(c, slots, max_seq)
+    keys = jax.random.split(jax.random.PRNGKey(47), 3)
+    ks, vs = (jax.jit(lambda k, like: jax.random.normal(
+        k, like.shape, jnp.float32).astype(like.dtype))(k, cache[n])
+        for k, n in zip(keys, ("k", "v")))
+    xq = jax.random.normal(keys[2], (slots, heads, 128),
+                           jnp.float32).astype(c.dtype)
+    pos = jnp.asarray(contexts + (77,) * (slots - len(contexts)), jnp.int32)
+    active = jnp.arange(slots) < len(contexts)
+    blocks = llama._span_blocks(max(contexts) + 1, max_seq)
+
+    def walk(xq, ks, vs, i, pos, active):
+        return llama._attend_slab(xq, ks, vs, i, None, pos, blocks, c)
+
+    # the step's work list, built once a step: not a layer's cost
+    visits = decode_attention.work_list(pos, active, llama.ATTEND_BLOCK,
+                                        max_seq)
+
+    def kernel(xq, ks, vs, i, pos, active):
+        return decode_attention.decode_attention(
+            xq, ks, vs, i, pos, visits, block=llama.ATTEND_BLOCK,
+            scale=128 ** -0.5, interpret=not on_chip)
+
+    paths = {"walk": jax.jit(walk), "kernel": jax.jit(kernel)}
+    layer = [jnp.int32(i) for i in range(layers)]
+    out = {path: np.asarray(run(xq, ks, vs, layer[-1], pos, active).astype(
+        jnp.float32)) for path, run in paths.items()}
+    live = np.asarray(active)
+    line = {"shape": name, "layers": layers, "slots": slots,
+            "max_seq": max_seq, "heads": [heads, kv_heads],
+            "flat_kv_heads": c.flat_kv_heads, "active": len(contexts),
+            "max_abs_diff_active_rows": float(np.abs(
+                out["walk"][live] - out["kernel"][live]).max()),
+            "idle_rows_zero": bool((out["kernel"][~live] == 0).all())}
+    read = llama.read_positions([n + 1 for n in contexts], max_seq)
+    walked = slots * llama.span_positions(max(contexts) + 1, max_seq)
+    line["read_pct_of_walk"] = 100.0 * read / walked
+    if on_chip:
+        for path, run in paths.items():
+            with tempfile.TemporaryDirectory() as directory:
+                jax.profiler.start_trace(directory)
+                for run_no in range(RUNS):
+                    run(xq, ks, vs, layer[run_no % layers], pos,
+                        active).block_until_ready()
+                jax.profiler.stop_trace()
+                # the path's own program: no other is in the trace
+                ms = statistics.median(t for program, t in device_ms(
+                    directory) if path in program)
+            line[f"{path}_ms_a_layer"] = ms
+            line[f"{path}_live_gb_s"] = (
+                read * 2 * kv_heads * 128 * 2 / ms / 1e6)
+    print(json.dumps(line), flush=True)
+
+
+if __name__ == "__main__":
+    print(jax.devices())
+    for shape in sys.argv[1:] or SHAPES:
+        measure(shape)

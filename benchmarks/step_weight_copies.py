@@ -72,6 +72,31 @@ def layer_weights(params) -> dict:
     return names
 
 
+def slab_buffers(config, rows: int, max_seq: int) -> dict:
+    """``_key`` -> what of a full layer's keys or values such a buffer
+    would be, for ``materialised``: the layer's slab over ``rows`` slots
+    or a block of ``ATTEND_BLOCK`` positions of each of them — taken
+    out of the carried slabs, or re-laid heads-major — with the heads
+    on an axis or side by side.  A decode step whose rows read the
+    slabs where they lie (``ops/pallas/decode_attention.py``) writes
+    none with ``rows`` its slots; a chunk's walk writes its one slot's
+    blocks (``rows`` 1)."""
+    import jax.numpy as jnp
+
+    from ant_ray_tpu.models import llama
+
+    c = config
+    dtype = _XLA[str(jnp.dtype(c.dtype))][0]
+    names = {}
+    for what, positions in (("slab", max_seq),
+                            ("block", min(llama.ATTEND_BLOCK, max_seq))):
+        for position in ((c.n_kv_heads, c.head_dim),
+                         (c.n_kv_heads * c.head_dim,)):
+            names.setdefault(_key(dtype, (rows, positions) + position),
+                             []).append(what)
+    return names
+
+
 def materialised(text: str, weights: dict) -> list:
     """The operations of the compiled program ``text`` that WRITE a
     buffer told as one of ``weights`` (``layer_weights``) -> dicts of

@@ -1,0 +1,214 @@
+"""``ops/pallas/decode_attention.py`` in the Pallas interpreter against
+``llama._attend_slab``'s XLA walk (the CPU has no Mosaic: what the
+chip's compiler makes of the kernel is ``tests/test_tpu_compile.py``'s,
+what it computes there ``benchmarks/attend_invariance.py``'s), the
+invariances the mixed step and the benchmark's probes rest on, to the
+bit, and the step programs through the kernel."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ant_ray_tpu.models import llama
+from ant_ray_tpu.ops.pallas import decode_attention as da
+
+BLOCK = 16                    # positions a block here (256 on the chip)
+
+
+def _config(heads, kv_heads, head_dim, max_seq):
+    return llama.LlamaConfig(
+        vocab_size=64, dim=heads * head_dim, n_layers=2, n_heads=heads,
+        n_kv_heads=kv_heads, head_width=head_dim, mlp_dim=64,
+        max_seq=max_seq)
+
+
+def _slabs(c, slots, max_seq, seed=0):
+    """Random queries and two layers of random slabs as ``c`` holds
+    them."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (2, slots, max_seq) + llama.kv_slabs(c)["k"]
+    ks, vs = (jax.random.normal(k, shape, jnp.float32).astype(c.dtype)
+              for k in keys[:2])
+    xq = jax.random.normal(keys[2], (slots, c.n_heads, c.head_dim),
+                           jnp.float32).astype(c.dtype)
+    return xq, ks, vs
+
+
+def _kernel(c, xq, ks, vs, pos, active):
+    pos = jnp.asarray(pos, jnp.int32)
+    return da.decode_attention(
+        xq, ks, vs, 1, pos, da.work_list(pos, jnp.asarray(active), BLOCK,
+                                         ks.shape[2]),
+        block=BLOCK, scale=c.head_dim ** -0.5, interpret=True)
+
+
+def _bits(x):
+    return np.asarray(x.astype(jnp.float32)).view(np.uint32)
+
+
+# (heads, KV heads, head_dim, max_seq, each row's position): the shapes
+# the cells run, at test size
+SHAPES = {
+    "30x128-flat": (30, 30, 128, 40, (39, 0, 17, 31)),
+    "16of16x128-heads-axis": (16, 16, 128, 48, (47, 5, 16, 15)),
+    "32of8x128": (32, 8, 128, 48, (20, 47, 3, 32)),
+    "16of8x128": (16, 8, 128, 64, (63, 1, 40, 16)),
+    "64-wide-head": (8, 4, 64, 48, (30, 47, 2, 15)),
+    "grouped-flat": (20, 10, 32, 48, (30, 47, 2, 15)),
+    "slab-shorter-than-a-block": (4, 2, 32, 12, (11, 0, 5, 7)),
+    "last-block-starts-early": (4, 2, 32, 40, (39, 33, 31, 32)),
+}
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_kernel_agrees_with_the_walk(shape, monkeypatch):
+    """Every active row's output within bf16 rounding of the XLA
+    walk's, in both held layouts; an idle row's is zeros."""
+    heads, kv_heads, head_dim, max_seq, pos = SHAPES[shape]
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+    c = _config(heads, kv_heads, head_dim, max_seq)
+    assert c.flat_kv_heads == ("flat" in shape)
+    xq, ks, vs = _slabs(c, len(pos), max_seq)
+    active = np.array([True, True, False, True])
+    pos = jnp.asarray(pos, jnp.int32)
+    want = llama._attend_slab(
+        xq, ks, vs, 1, None, pos,
+        llama._span_blocks(jnp.max(pos) + 1, max_seq), c)
+    got = _kernel(c, xq, ks, vs, pos, active)
+    assert got.dtype == xq.dtype and got.shape == xq.shape
+    np.testing.assert_allclose(
+        np.asarray(got.astype(jnp.float32))[active],
+        np.asarray(want.astype(jnp.float32))[active], rtol=2e-2, atol=2e-2)
+    assert (np.asarray(got.astype(jnp.float32))[~active] == 0).all()
+
+
+def _others(ks, vs, pos, active, case):
+    """Row 0 stays as it is; what ``case`` changes of the others."""
+    pos, active = np.array(pos), np.array(active)
+    if case == "other-rows-longer":
+        pos[1:] = [47, 46, 33]
+    elif case == "other-rows-shorter":
+        pos[1:] = [0, 1, 2]
+    elif case == "other-slots-idle":
+        active[1:] = False
+    elif case == "an-idle-slots-slab-nan":
+        active[2] = False
+        ks, vs = (x.at[:, 2].set(jnp.nan) for x in (ks, vs))
+    elif case == "only-the-last-slot-beside-it":
+        active[1:3] = False
+    return ks, vs, pos, active
+
+
+@pytest.mark.parametrize("layout", ["flat", "heads-axis"])
+@pytest.mark.parametrize("case", [
+    "other-rows-longer", "other-rows-shorter", "other-slots-idle",
+    "an-idle-slots-slab-nan", "only-the-last-slot-beside-it"])
+def test_a_rows_output_is_its_own_to_the_bit(case, layout):
+    """Row 0's output does not depend, to the bit, on the other rows'
+    lengths, on which other slots are active (one live or all), or on
+    what an idle slot's slab holds — NaN included: nothing of it is
+    read."""
+    c = _config(*{"flat": (10, 10, 32), "heads-axis": (8, 4, 32)}[layout],
+                48)
+    xq, ks, vs = _slabs(c, 4, 48, seed=3)
+    pos, active = (37, 20, 9, 40), (True,) * 4
+    alone = _kernel(c, xq, ks, vs, pos, active)
+    beside = _kernel(c, xq, *_others(ks, vs, pos, active, case))
+    assert np.isfinite(np.asarray(beside.astype(jnp.float32))).all()
+    np.testing.assert_array_equal(_bits(alone[0]), _bits(beside[0]))
+
+
+def test_work_list_visits_each_active_rows_own_blocks_in_order(monkeypatch):
+    """An active row's blocks 0, 1, 2, … row after row, none for an idle
+    slot, and the host's ``read_positions`` counts the same."""
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+    pos = jnp.asarray([37, 20, 9, 47], jnp.int32)
+    active = jnp.asarray([True, False, True, True])
+    rows, blocks, per_row, visits = da.work_list(pos, active, BLOCK, 48)
+    n = int(visits)
+    assert per_row.tolist() == [3, 0, 1, 3] and n == 7
+    assert rows[:n].tolist() == [0, 0, 0, 2, 3, 3, 3]
+    assert blocks[:n].tolist() == [0, 1, 2, 0, 0, 1, 2]
+    assert llama.read_positions([38, 10, 48], 48) == BLOCK * n
+    none = da.work_list(pos, jnp.zeros((4,), bool), BLOCK, 48)
+    assert int(none[3]) == 0 and int(none[1].min()) >= 0
+
+
+@pytest.fixture
+def through_the_kernel(monkeypatch):
+    """The step programs take the kernel as they do on one TPU device
+    (on the CPU ``_decode_kernel`` keeps the walk), interpreted."""
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+
+    def switch(on):
+        monkeypatch.setattr(
+            llama, "_decode_kernel", lambda c, mesh:
+            on and not c.kv_lora_rank and mesh is None)
+    return switch
+
+
+@pytest.mark.parametrize("name", ["tiny", "cmdaplus-tiny"])
+def test_step_programs_through_the_kernel(name, through_the_kernel):
+    """A chunk and decode steps with the kernel behind ``_attend_slab``
+    give the logits of the same programs through the walk inside the
+    parity tolerance and the same arg-max; and a row comes out of
+    ``mixed_step`` with the BITS ``decode_step`` gives it — both call
+    ``_decode_rows.attend``, so both get the kernel.  (Command A+'s
+    tiny preset: its window layers' rings keep the walk beside the full
+    layers' kernel.)"""
+    cfg = dataclasses.replace(llama.CONFIGS[name], max_seq=64)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = {0: 23, 2: 40}                     # slot -> prompt tokens
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (48,), 0,
+                                cfg.vocab_size)
+    active = jnp.asarray([True, False, True, False])
+    last = jnp.asarray([3, 1, 4, 1], jnp.int32)
+    ride = jax.random.randint(jax.random.PRNGKey(2), (16,), 0,
+                              cfg.vocab_size)
+
+    def run(on):
+        through_the_kernel(on)
+        chunk = jax.jit(
+            lambda p, c, t, slot, at, n: llama.prefill_chunk_into_cache(
+                p, t, c, slot, at, n, cfg), donate_argnums=(1,))
+        decode = jax.jit(lambda p, c, last, act: llama.decode_step(
+            p, last, c, cfg, act))
+        mixed = jax.jit(lambda p, c, last, act, t: llama.mixed_step(
+            p, last, t, c, cfg, act, 1, 0, 11))
+        cache = llama.init_kv_cache(cfg, 4, 64, chunk=16)
+        for slot, n in prompts.items():
+            for at in range(0, n, 16):
+                _, cache = chunk(params, cache, tokens[at:at + 16], slot,
+                                 at, min(16, n - at))
+        out = []
+        for _ in range(3):
+            beside = mixed(params, cache, last, active, ride)[0]
+            logits, cache = decode(params, cache, last, active)
+            np.testing.assert_array_equal(_bits(logits[active]),
+                                          _bits(beside[active]))
+            out.append(np.asarray(logits)[np.asarray(active)])
+        return np.stack(out)
+
+    got, want = run(True), run(False)
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 0.02
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+
+
+def test_decode_kernel_keeps_the_walk_where_the_arguments_say():
+    """``_decode_kernel``: off the TPU, under a mesh, for latent slabs
+    and heads that are no whole lane tiles, the XLA walk (a window
+    layer's rings always: ``test_step_programs_through_the_kernel``)."""
+    wide = _config(4, 2, 128, 64)
+    assert not llama._decode_kernel(wide, None)              # the CPU
+    on_tpu = pytest.MonkeyPatch()
+    try:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        assert llama._decode_kernel(wide, None)
+        assert not llama._decode_kernel(wide, object())      # a mesh
+        assert not llama._decode_kernel(_config(4, 2, 64, 64), None)
+        assert not llama._decode_kernel(llama.CONFIGS["axk1-tiny"], None)
+    finally:
+        on_tpu.undo()

@@ -306,6 +306,83 @@ def test_decode_span_counters_follow_the_longest_active_row(
     assert stats["decode_span_positions"] < stats["decode_slab_positions"]
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["walk", "kernel"])
+def test_decode_read_counters_count_what_the_dispatched_program_reads(
+        params, monkeypatch, kernel):
+    """``decode_walk_positions`` / ``decode_read_positions`` (PR 47), per
+    decode step and from the host's own ``kv_len``: what a walk bound by
+    the longest active row reads of a layer's slabs — slots x the span —
+    and what the dispatched program reads: the same where the rows walk
+    in XLA (the CPU; ``llama._decode_kernel``), the sum of each ACTIVE
+    row's own whole blocks (16 here) where they go through
+    ``ops/pallas/decode_attention.py`` — the device's own rule,
+    ``decode_attention.blocks_read`` over the step's own inputs — with a
+    resident session's idle slab adding nothing."""
+    from ant_ray_tpu.ops.pallas import decode_attention
+
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
+    if kernel:
+        monkeypatch.setattr(llama, "_decode_kernel", lambda c, mesh: True)
+    eng = _engine(params, slots=3)
+    assert eng._decode_kernel == kernel
+    assert eng.stats["decode_walk_positions"] == \
+        eng.stats["decode_read_positions"] == 0
+    device = {"walk": 0, "read": 0}
+
+    def watch(program):
+        def watched(params, cache, last, active, *chunk):
+            if not chunk or int(chunk[-1]):
+                active_, length = np.asarray(active), np.asarray(
+                    cache["length"])
+                blocks = np.asarray(decode_attention.blocks_read(
+                    length, active_, 16, eng.max_seq))
+                device["walk"] += 3 * 16 * int(blocks.max())
+                device["read"] += 16 * int(blocks.sum())
+            return program(params, cache, last, active, *chunk)
+        return watched
+
+    monkeypatch.setattr(eng, "_decode_jit", watch(eng._decode_jit))
+    monkeypatch.setattr(eng, "_mixed_step_jit", watch(eng._mixed_step_jit))
+    eng.add_request(list(range(3, 71)), SamplingParams(max_tokens=3),
+                    admit=False, session_id="resident")
+    while eng.has_unfinished():
+        eng.step()
+    eng.add_request([5, 9, 17, 3, 88], SamplingParams(max_tokens=41),
+                    admit=False)
+    eng.add_request([44, 55, 66], SamplingParams(max_tokens=10), admit=False)
+    while eng.has_unfinished():
+        eng.step()
+    stats = eng.stats
+    assert stats["decode_walk_positions"] == device["walk"] \
+        == 3 * stats["decode_span_positions"] > 0
+    assert stats["decode_read_positions"] == (
+        device["read"] if kernel else device["walk"])
+    assert (stats["decode_read_positions"]
+            < stats["decode_walk_positions"]) == kernel
+
+
+def test_decode_read_pct_reads_the_counters_and_nothing_without_them(params):
+    """``chipbench/layer_metrics/decode_read_pct.py`` over a window of
+    the engine's own stats: 100 where the rows walk in XLA; a program
+    without the counters (the parent of PR 47) gives None, which leaves
+    the metric out of the line."""
+    from chipbench.layer_metrics import decode_read_pct
+
+    eng = _engine(params)
+    before = dict(eng.stats)
+    eng.generate(list(PROMPTS), SamplingParams(max_tokens=6))
+    obs = {"traced": {"engine": dict(eng.stats), "engine_before": before}}
+    assert eng.stats["decode_walk_positions"] == \
+        4 * 96 * eng.stats["decode_steps"] > 0
+    assert decode_read_pct.read(obs) == 100.0
+    obs["traced"]["engine"]["decode_read_positions"] //= 4
+    assert decode_read_pct.read(obs) == 25.0
+    for side in obs["traced"].values():
+        del side["decode_read_positions"]
+    assert decode_read_pct.read(obs) is None
+    assert decode_read_pct.read({"traced": None}) is None
+
+
 def test_decode_span_pct_reads_the_counters_and_nothing_without_them(params):
     """``chipbench/layer_metrics/decode_span_pct.py`` over a window of
     the engine's own stats: slabs of 96 are ONE serving block, so every

@@ -445,9 +445,11 @@ SCALAR_GATED = llama.LlamaConfig(
     linear_heads=30, linear_head_dim=96, linear_value_dim=192)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("program,kernel", [
+    ("decode", True), ("decode", False), ("prefill_chunk", True)],
+    ids=["decode-kernel", "decode-walk", "prefill_chunk"])
 def test_thirty_heads_slabs_stay_where_they_lie_at_the_cells_size(
-        v5e, monkeypatch, program):
+        v5e, monkeypatch, program, kernel):
     """`olmo-hybrid-7b.longdoc` as it is served: 8 slots x 12,288, FOUR
     layers' slabs of 30 KV heads (5.625 GiB) beside twelve layers'
     rectangular states (0.2 GiB), prompts in chunks of 512 — 520 rows
@@ -457,8 +459,12 @@ def test_thirty_heads_slabs_stay_where_they_lie_at_the_cells_size(
     (30 heads are no whole sublane tiles: 6 GiB of temporaries, 19.8
     GiB in all, refused); side by side in a position
     (``LlamaConfig.flat_kv_heads``) nothing of a slab's size is
-    written: the temporaries stay under a twentieth of the slabs."""
+    written: the temporaries stay under a twentieth of the slabs —
+    with the decode rows through ``ops/pallas/decode_attention.py`` (the
+    chip's path since PR 47) as through the XLA walk."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if not kernel:
+        monkeypatch.setattr(llama, "_decode_kernel", lambda *a: False)
     compiled, params, cache = _compile_step(
         v5e.devices[0], program, SCALAR_GATED, 8, 12288, chunk=512)
     kept = {name: cache[name] for name in (
@@ -486,14 +492,17 @@ def test_thirty_heads_slabs_stay_where_they_lie_at_the_cells_size(
 DENSE_128 = dataclasses.replace(CFG, n_heads=16)
 
 
-@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("program,on_tpu", [
+    *((program, False) for program in PROGRAMS),
+    ("decode", True), ("mixed_step", True)], ids=[
+        *PROGRAMS, "decode-as-on-the-chip", "mixed_step-as-on-the-chip"])
 @pytest.mark.parametrize("config,slots,max_seq", [
     pytest.param(DENSE_128, 8, 2048, id="dense"),
     pytest.param(ROUTED, 16, 1536, id="routed"),
     pytest.param(LATENT, 48, 4096, id="latent"),
     pytest.param(MIXED_TWICE, 8, 16384, id="window-and-full")])
-def test_step_updates_the_cache_in_place(v5e, program, config, slots,
-                                         max_seq):
+def test_step_updates_the_cache_in_place(v5e, monkeypatch, program, on_tpu,
+                                         config, slots, max_seq):
     """The step programs write their rows into the donated cache and
     move nothing slab-sized: every leaf of the cache is aliased to its
     output, the temporaries stay under ONE layer's slabs (K + V, or the
@@ -505,8 +514,13 @@ def test_step_updates_the_cache_in_place(v5e, program, config, slots,
     full-span read) or one slot's (``bf16[1, 1, max_seq, …]``, a
     chunk's): the attention reads the carried cache a block of
     ``ATTEND_BLOCK`` positions at a time (slabs of several blocks
-    here)."""
+    here).  ``on_tpu``: the programs as the chip runs them — the decode
+    rows through ``ops/pallas/decode_attention.py``, which is handed the
+    carried slabs themselves, and the routed experts through the grouped
+    kernel; without it, as every other backend does: the XLA walk."""
     assert max_seq > llama.ATTEND_BLOCK
+    if on_tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled, _, cache = _compile_step(v5e.devices[0], program, config,
                                        slots, max_seq)
     mem = compiled.memory_analysis()
@@ -530,6 +544,50 @@ def test_step_updates_the_cache_in_place(v5e, program, config, slots,
             sliced = [line.strip()[:160] for line in lines if re.match(
                 produces + re.escape(full_span + "]"), line)]
             assert not sliced, sliced
+
+
+# Mistral-7B as `mistral-7b.decode` serves it: sixteen layers.
+DENSE_CELL = dataclasses.replace(DENSE, n_layers=16)
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed_step"])
+@pytest.mark.parametrize("config,slots,max_seq,chunk,gib", [
+    pytest.param(SCALAR_GATED, 8, 12288, 512,
+                 {"decode": 13.55, "mixed_step": 14.0}, id="thirty-heads"),
+    pytest.param(DENSE_CELL, 16, 3072, 64,
+                 {"decode": 10.1, "mixed_step": 10.1},
+                 id="grouped-heads-axis")])
+def test_decode_rows_read_the_slabs_where_they_lie(
+        v5e, monkeypatch, program, config, slots, max_seq, chunk, gib):
+    """`olmo-hybrid-7b.longdoc`'s and `mistral-7b.decode`'s decode rows
+    on the chip (PR 47): a softmax layer's attention is ONE call of
+    ``ops/pallas/decode_attention.py``, handed the carried slabs whole
+    in the shape they are held in (heads side by side) or as the same
+    bytes with a position's heads as rows (a heads axis: a bitcast) and
+    the layer by scalar prefetch; no operation of the program's own
+    writes a slab- or block-shaped buffer of the slots' keys or values
+    — the walk took every block out and re-laid it heads-major — and
+    the program fits the chip in what it took before (longdoc's decode:
+    13.54 GiB at PR 46; its mixed step, 520 rows, which the engine never
+    runs — ``engine.RIDE_ROWS`` — 13.85)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, _, cache = _compile_step(v5e.devices[0], program, config,
+                                       slots, max_seq, chunk)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "decode_attention" in line]
+    assert len(calls) == 1                 # in the layers' scan body
+    columns = slots * max_seq * (
+        1 if config.flat_kv_heads else config.n_kv_heads)
+    full = config.layer_counts()[1]
+    assert f"bf16[{full},{slots},{columns // slots}," in calls[0]
+    assert step_weight_copies.materialised(
+        text, step_weight_copies.slab_buffers(config, slots, max_seq)) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < gib[program] * 2 ** 30
 
 
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
