@@ -40,6 +40,16 @@ vllm_engine_stage.py) designed for TPU/XLA rather than around CUDA:
   A finish by count (``max_tokens``, ``max_seq``) is known at dispatch
   and leaves the next step's mask; a stop token is read one step late,
   and the row that step computed for the ended sequence is dropped.
+  A prompt's end is one more read, of its first token, and where it
+  stands in the iteration is the same in all three programs' cases
+  wherever a step is unread: the token is sampled on the device and
+  the row joins the decode batch from there, and the read comes
+  BEHIND the landing of step N — after the mixed step that carried
+  the prompt's last chunk, or after the chunk program AND step N+1,
+  which already holds the new row.  Step N's tokens, ready before the
+  program that ended the prompt, never wait it out.  Only with no
+  step unread (nothing decodes) is the first token read at once,
+  before the decode step of the same iteration is dispatched.
 * **Session KV offload** (``session_id=`` + kv_offload.py stores): a
   finished request's slab stays RESIDENT in its slot for multi-turn
   reuse; idle sessions are evicted — LRU past ``kv_idle_evict_s`` or on
@@ -108,8 +118,9 @@ class _Seq:
     first_token: tuple | None = None   # first token handed to on_event
     chunks: int = 0               # chunks dispatched
     # Under a sampled trace_ctx only: (perf_counter, the engine's
-    # chunks so far) a token handed over, the first token's
-    # too -> the span's emit_ms and chunk_gaps.
+    # chunks so far, its prompt ends so far) a token handed over, the
+    # first token's too -> the span's emit_ms, chunk_gaps and
+    # prompt_end_gaps.
     emits: list | None = None
 
 
@@ -187,7 +198,11 @@ class _PhaseRecorder:
     ``block_s`` and to the open phase's ``block_<name>_s``, so host time
     proper is ``sum(phase_*_s) - block_s - phase_idle_wait_s``.  A
     decode step reads once, its tokens in ``fetch``; a prompt's end
-    reads its first token in ``chunk``; ``sample`` only dispatches.
+    reads its first token in ``chunk`` — a second stretch of that
+    phase behind ``fetch`` and ``emit`` where a step was unread, so
+    ``block_chunk_s`` holds what was left of the program that ended
+    the prompt after step N's tokens had gone out; ``sample`` only
+    dispatches.
 
     The decode step is pipelined one deep, so an iteration's phases run
     ``decode`` and ``sample`` (dispatch step N+1), then ``fetch`` (read
@@ -196,7 +211,11 @@ class _PhaseRecorder:
     the iteration: ``block_fetch_s`` is the remainder of the device
     step that the host did not cover, not the step.
     ``decode_ahead_steps`` counts the decode steps dispatched while the
-    step before them was still unread.  ``decode_span_positions`` adds
+    step before them was still unread.  ``prompt_ends`` counts the
+    prompts whose LAST chunk was dispatched, alone or riding, raised
+    at that dispatch (``chunks`` counts every chunk there): what a
+    sampled request's hand-overs are stamped with, beside ``chunks``.
+    ``decode_span_positions`` adds
     up, per decode step, the positions of a slab its attention walks
     (whole blocks up to the longest active row, from the host's own
     ``kv_len``: no read), ``decode_slab_positions`` the slab's.
@@ -539,7 +558,8 @@ class LLMEngine:
         # Flat on purpose: readers on other threads take dict(stats),
         # a shallow copy under which a nested dict would alias.
         self.stats = {"tokens_generated": 0, "chunks": 0,
-                      "chunks_fused": 0, "chunk_tokens": 0, "offloads": 0,
+                      "chunks_fused": 0, "chunk_tokens": 0,
+                      "prompt_ends": 0, "offloads": 0,
                       "offload_bytes": 0, "restores": 0,
                       "restore_wait_s": 0.0, "restore_failures": 0,
                       "pressure_evictions": 0, "idle_evictions": 0}
@@ -759,11 +779,15 @@ class LLMEngine:
         One of three step programs does the device's part: the mixed
         step where a chunk is due and rows decode (the chunk rides
         decode step N+1; a prompt that ends there reads its first token
-        behind step N's landing), the chunk program alone where nothing
-        decodes (a prompt that ends there joins the decode step of the
+        behind step N's landing, and its row joins step N+2), the chunk
+        program alone where nothing decodes (a prompt that ends there
+        reads its first token at once and joins the decode step of the
         same iteration), the decode program alone where no chunk is
         due.  A chunk too wide to ride (``RIDE_ROWS``) runs alone and
-        the decode step behind it, two programs an iteration.
+        the decode step behind it, two programs an iteration; a prompt
+        that ends there beside an unread step joins step N+1 from its
+        first token on the device, and the token is read behind step
+        N's landing, as in the mixed step's case.
 
         The decode step runs one ahead of the host: a token comes back
         from the ``step()`` after the one that dispatched it, and while
@@ -785,12 +809,16 @@ class LLMEngine:
         self._poll_restores()
         self._admit()
         rec.enter("chunk")
-        chunk = self._next_chunk()
+        chunk, first = self._next_chunk(), None
         if chunk is not None and not (self._active and self._chunk_rides):
             # nothing decodes beside it, or it is too wide to ride
-            self._chunk_alone(*chunk)
+            first = self._chunk_alone(*chunk)
             chunk = None
-        self._decode(chunk)
+            if first is not None and self._flight is None:
+                # no step is unread: nothing to hand over before it
+                self._first_token(*first)
+                first = None
+        self._decode(chunk, first)
         rec.enter("housekeeping")
         self._sweep_idle()
 
@@ -1011,7 +1039,9 @@ class LLMEngine:
         arrives while rows decode.  Its arguments are of the kinds a
         real mixed step passes (the length read to the host: a Python
         number like ``kv_len``, not the device's scalar), or the first
-        real one would miss the program's cache and compile again."""
+        real one would miss the program's cache and compile again.
+        Returns what ``_chunk_end`` does: the prompt's first token,
+        unread, where the chunk ended it."""
         if self._chunk_rides and not self._mixed_ran:
             _, _, self.cache = self._mixed_step_jit(
                 self.params, self.cache, self._last,
@@ -1021,7 +1051,7 @@ class LLMEngine:
         logits, self.cache = self._prefill_chunk_jit(
             self.params, self.cache, tokens, seq.slot, seq.kv_len, n)
         self._chunk_dispatched(seq, n)
-        self._chunk_end(seq, logits)
+        return self._chunk_end(seq, logits)
 
     def _chunk_dispatched(self, seq: _Seq, n: int):
         """A program that ingests ``n`` tokens of ``seq`` — the chunk
@@ -1035,40 +1065,54 @@ class LLMEngine:
         self._note_recurrent(self._chunk_tokens, n, seq.kv_len == 0)
         seq.prefill_done += n
         seq.kv_len += n
+        self.stats["prompt_ends"] += seq.prefill_done == len(seq.prompt)
         self._note_walk(seq.kv_len)
         self._note_chunk(n)
 
     def _chunk_end(self, seq: _Seq, logits):
-        """Behind a chunk's dispatch: a prompt's end leaves the queue
-        and reads its first token, any other goes to the queue's end."""
+        """Behind a chunk's dispatch: a prompt's end leaves the queue,
+        any other goes to the queue's end.  The prompt that ended has
+        its first token sampled on the device and joins the decode
+        batch from there, no read: returns ``(seq, token)`` for
+        ``_first_token``, which the iteration calls where the read
+        keeps nobody waiting — else None."""
         self._prefilling.remove(seq)
-        if seq.prefill_done == len(seq.prompt):
-            self._first_token(seq, logits)
-        else:
+        if seq.prefill_done < len(seq.prompt):
             self._prefilling.append(seq)
-
-    def _first_token(self, seq: _Seq, logits):
-        """A prompt's end: sample, read and emit its first token.  The
-        read stays where it was, in ``chunk``, and waits out the
-        program that made ``logits`` — the prefill program behind the
-        decode step in flight, or the mixed step, whose own tokens are
-        read an iteration later like any step's; the token enters the
-        slot's row from the device."""
+            return None
         self._rec.enter("chunk")
         token = self._sample_one(seq, logits)
-        tok = int(self._rec.to_host(token)[0])
+        self._join_decode(seq, token)
+        return seq, token
+
+    def _first_token(self, seq: _Seq, token):
+        """Read and emit a prompt's first token (``_chunk_end``'s).
+        The read is the prompt end's one, in ``chunk`` (the phase the
+        caller is in), and waits out the program that made the token:
+        the prefill program or the mixed step.  Where a decode step was
+        unread when that program was dispatched, the step's tokens were
+        handed over first (``_decode``).  A stop token ends the
+        sequence here; a decode step dispatched since with its row is
+        read like any step whose sequence a stop ended (``_land``)."""
+        seq.last_tok = tok = int(self._rec.to_host(token)[0])
         self._rec.enter("emit")
         self._after_token(seq, tok, time.perf_counter())
-        if seq.slot >= 0:
-            seq.last_tok = tok
-            self._join_decode(seq, token)
 
     def _join_decode(self, seq: _Seq, token=None):
         """``seq`` (slot set) decodes from the next step on: its newest
         token (``token``, (1,) on the device, else ``last_tok``), its
         key and its sampling parameters move into the slot's rows —
-        token and key through one small program, no read."""
+        token and key through one small program, no read.  ``token``
+        is a prompt's first and not read yet, so not in ``generated``:
+        it counts among the tokens made, and a sequence that it ends by
+        count (``max_tokens``, ``max_seq``) — known here, on the host —
+        does not join."""
         slot, s = seq.slot, seq.sampling
+        made = len(seq.generated) + (token is not None)
+        seq.steps_left = min(s.max_tokens - made,
+                             self.max_seq - 1 - seq.kv_len)
+        if seq.steps_left <= 0:
+            return
         # as float32 holds them: what sampler_work tests on the host
         # is what the sampler tests on the device
         row = (float(np.float32(s.temperature)), s.top_k,
@@ -1081,18 +1125,20 @@ class LLMEngine:
         self._keys, self._last = self._put_row_jit(
             self._keys, self._last, seq.rng_key, token, slot)
         seq.rng_key = None
-        seq.steps_left = min(s.max_tokens - len(seq.generated),
-                             self.max_seq - 1 - seq.kv_len)
         self._active[slot] = seq
         self._active_dev = None
 
-    def _decode(self, chunk: tuple | None = None):
+    def _decode(self, chunk: tuple | None = None,
+                first: tuple | None = None):
         """Dispatch step N+1, then read and emit step N: the host's
         turn-around and the transfer run under the device's step.  A
         dispatch that fails still lands the step before it.  ``chunk``
         (``_next_chunk``'s, rows are active) rides step N+1, one
-        program; its prompt's end is read behind step N's landing, so
-        that step N's emit work runs under the mixed step too."""
+        program.  A prompt's end is read behind step N's landing —
+        ``first`` (``_chunk_end``'s), where the chunk program before
+        step N+1 ended it, or the riding chunk's own — so that step N's
+        tokens, ready before the program that ended the prompt, do not
+        wait it out, and their emit work runs under it."""
         flight, self._flight = self._flight, None
         logits = None
         try:
@@ -1101,8 +1147,12 @@ class LLMEngine:
         finally:
             if flight is not None:
                 self._land(flight)
-        if chunk is not None:
-            self._chunk_end(chunk[0], logits)
+            if logits is not None:
+                first = self._chunk_end(chunk[0], logits)
+            elif first is not None:
+                self._rec.enter("chunk")
+            if first is not None:
+                self._first_token(*first)
 
     def _dispatch_decode(self, flight: tuple | None,
                          chunk: tuple | None = None) -> tuple:
@@ -1444,8 +1494,11 @@ class LLMEngine:
         handed to its caller (a stop token is not), milliseconds after
         the first one's, which is the end of ``prefill``; and
         ``chunk_gaps``, the indexes ``i`` of those whose gap from token
-        ``i - 1`` saw a prefill program of ANY request dispatched.  An
-        unsampled context records nothing unless ``error``."""
+        ``i - 1`` saw a prefill program of ANY request dispatched, and
+        ``prompt_end_gaps``, those of them whose program was a prompt's
+        LAST (``stats["prompt_ends"]`` rose: the gap in which a first
+        token is sampled, and the row joins).  An unsampled context
+        records nothing unless ``error``."""
         ctx = seq.trace_ctx
         if ctx is None:
             return
@@ -1457,10 +1510,10 @@ class LLMEngine:
         if seq.emits is not None:
             emits = seq.emits
             attrs["emit_ms"] = [round(1000.0 * (t - t_token), 2)
-                                for t, _ in emits]
-            attrs["chunk_gaps"] = [
-                i for i in range(1, len(emits))
-                if emits[i][1] != emits[i - 1][1]]
+                                for t, *_ in emits]
+            for name, seen in (("chunk_gaps", 1), ("prompt_end_gaps", 2)):
+                attrs[name] = [i for i in range(1, len(emits))
+                               if emits[i][seen] != emits[i - 1][seen]]
         try:
             from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
 
@@ -1504,7 +1557,8 @@ class LLMEngine:
             reason = "length"
         if reason != "stop":
             if seq.emits is not None:
-                seq.emits.append((now, self.stats["chunks"]))
+                seq.emits.append((now, self.stats["chunks"],
+                                  self.stats["prompt_ends"]))
             if seq.on_event is not None:
                 seq.on_event({"type": "token", "token_id": tok})
         if reason is not None:

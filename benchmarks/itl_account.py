@@ -6,7 +6,13 @@ benchmark run: reads what ``python3 -m chipbench.run --workload <cell>
   (``engine_itl_p95_ms``), the stream's lag and the pool's wait, the
   share of gaps that saw a prefill dispatched (the five readers of
   ``chipbench/layer_metrics``, as the benchmark applies them);
-* how long a gap is where ``chunk_gaps`` names it and where not;
+* how long a gap is where ``chunk_gaps`` names it and where not, and
+  where ANOTHER request's first token was handed over inside it — a
+  prompt's end as any program's spans show it, the parent's of the PR
+  that added ``prompt_end_gaps`` too (that attribute names the gap in
+  which the prompt's last chunk was DISPATCHED: the same gap where the
+  first token is read before the step in flight is landed, the one
+  before where it is read behind it);
 * the stream's lag of a request's FIRST token beside that of its later
   ones (p50, p95, p99), and the pool's wait likewise;
 * the requests joined by ``trace_id`` beside the client's count of
@@ -22,6 +28,7 @@ No chip and no jax needed: it reads the file.
     python -m benchmarks.itl_account chiprun_out/decode.json [...]
 """
 
+import bisect
 import collections
 import importlib
 import json
@@ -29,10 +36,12 @@ import sys
 
 from chipbench.layer_metrics import engine_itl_p95_ms as itl
 from chipbench.layer_metrics.loop_host_ms_per_step import deltas
+from chipbench.layer_metrics.serve_ingress_p50_ms import first_token_wall
 from chipbench.layer_metrics.serve_stream_lag_p95_ms import frames
 from chipbench.loadgen import percentile
 
 METRICS = ("engine_itl_p95_ms", "itl_chunk_gaps_pct",
+           "itl_prompt_end_gaps_pct", "prompt_end_gap_p50_ms",
            "serve_stream_lag_p95_ms", "serve_pull_wait_p95_ms",
            "engine_stall_s", "loop_host_ms_per_step")
 DUMP_KEEPS = 2000
@@ -42,13 +51,34 @@ def _ms(values, q):
     return round(1000.0 * percentile(values, q), 3) if values else None
 
 
+def first_token_gaps(obs) -> list:
+    """Seconds of the window's gaps inside which ANOTHER request (a
+    probe too) was handed its first token."""
+    firsts = sorted(
+        first_token_wall({"llm:engine": span})
+        for span in obs.get("spans") or ()
+        if span.get("name") == "llm:engine" and "stages" in span)
+    found = []
+    for stream in itl.streams(obs):
+        first = first_token_wall(stream)
+        walls = [first + 0.001 * ms
+                 for ms in stream["llm:engine"]["attrs"]["emit_ms"]]
+        found += [b - a for a, b in zip(walls, walls[1:])
+                  if itl.in_window(obs, b)
+                  and bisect.bisect_right(firsts, b)
+                  > bisect.bisect_right(firsts, a)]
+    return found
+
+
 def gap_populations(obs) -> dict:
     """Milliseconds (count, p50, p95) of the window's gaps, those
-    ``chunk_gaps`` names apart from the others."""
+    ``chunk_gaps`` names apart from the others, and those that hold
+    another request's first token."""
     found = itl.gaps(obs)
     return {key: [len(v), _ms(v, 50), _ms(v, 95)] for key, v in
             (("in_chunk_gaps", [g for g, named in found if named]),
-             ("others", [g for g, named in found if not named]))}
+             ("others", [g for g, named in found if not named]),
+             ("across_a_first_token", first_token_gaps(obs)))}
 
 
 def lag_populations(obs) -> dict:

@@ -564,6 +564,118 @@ def test_a_chunk_too_wide_to_ride_runs_alone(params, monkeypatch, rows,
     assert [outs[rid].token_ids for rid in "ab"] == want
 
 
+class _NoEos:
+    """Token ids through, no end-of-sequence: a stop is the asked one."""
+
+    def encode(self, text):
+        return [ord(c) % CFG.vocab_size for c in text]
+
+    def decode(self, ids):
+        return " ".join(str(i) for i in ids)
+
+
+def _wide_engine(name):
+    """Three slots under a 256-token chunk: 259 rows pass ``RIDE_ROWS``,
+    so a chunk runs alone and the decode step behind it."""
+    eng = LLMEngine(llama.CONFIGS[name], slots=3, max_seq=32,
+                    prefill_chunk_tokens=256, tokenizer=_NoEos())
+    assert not eng._chunk_rides
+    return eng
+
+
+def _run_wide(eng, requests, overlap):
+    """``requests`` (rid, prompt, sampling, session) through ``eng``:
+    one at a time, or — ``overlap`` — the first alone until it decodes
+    and the rest at once beside it.  Outputs by request."""
+    outs = {}
+
+    def run(until=lambda: not eng.has_unfinished()):
+        while not until():
+            for out in eng.step():
+                outs[out.request_id] = out
+
+    for i, (rid, prompt, sampling, session) in enumerate(requests):
+        eng.add_request(list(prompt), sampling, request_id=rid,
+                        admit=False, session_id=session)
+        if not overlap:
+            run()
+        elif i == 0:
+            run(lambda: eng._flight is not None)
+    run()
+    return outs
+
+
+@pytest.mark.parametrize("name", ["tiny", "granite-h-tiny"])
+def test_wide_chunk_prompt_ends_beside_decoding_rows_answer_as_alone(name):
+    """A prompt that ends in a lone chunk beside decoding rows (PR 48)
+    joins step N+1 from its first token ON THE DEVICE, before that
+    token is read: greedy and seeded streams, a prompt whose FIRST
+    token is a stop token (the row step N+1 computed for it is dropped,
+    and the prompt that takes the freed slot starts from an empty
+    state), ``max_tokens == 1`` (known on the host: never joins) and —
+    dense only, a recurrent state keeps no session — a session's turn
+    that ends at ``max_seq - 1`` (likewise) give the tokens and the
+    finish reasons they give one at a time."""
+    dense = not llama.CONFIGS[name].n_recurrent
+    seeded = SamplingParams(max_tokens=8, temperature=1.0, seed=5)
+    alone = _run_wide(_wide_engine(name), [
+        ("c", [44, 55, 66, 77], SamplingParams(max_tokens=8), None),
+        ("d", [9, 8, 7], dataclasses.replace(seeded, seed=11), None)],
+        overlap=False)
+    stops = {rid: (alone[rid].token_ids[0],) for rid in "cd"}
+    requests = [
+        ("a", [10, 20, 30], SamplingParams(max_tokens=24), None),
+        ("b", [7, 8, 9, 10, 11], seeded, None),
+        ("c", [44, 55, 66, 77],
+         SamplingParams(max_tokens=8, stop_token_ids=stops["c"]), None),
+        ("d", [9, 8, 7], dataclasses.replace(
+            seeded, seed=11, stop_token_ids=stops["d"]), None),
+        ("e", [5, 9, 17, 3, 88, 41], SamplingParams(max_tokens=1), None),
+        ("f", [12, 13], dataclasses.replace(seeded, max_tokens=1), None),
+        ("g", list(range(60, 69)), SamplingParams(max_tokens=5), None)]
+    if dense:
+        # 10 + 3 - 1 positions and a carried token, then 18 more: 31
+        requests += [
+            ("s1", list(range(100, 110)), SamplingParams(max_tokens=3), "s"),
+            ("s2", list(range(120, 138)), SamplingParams(max_tokens=4), "s")]
+    want = _run_wide(_wide_engine(name), requests, overlap=False)
+    eng = _wide_engine(name)
+    joined, join = [], eng._join_decode
+
+    def spy_join(seq, token=None):
+        join(seq, token)
+        if seq.slot in eng._active and eng._flight is not None:
+            joined.append(seq.request_id)   # beside an unread step
+
+    eng._join_decode = spy_join
+    got = _run_wide(eng, requests, overlap=True)
+    assert set(got) == set(want) == {rid for rid, *_ in requests}
+    for rid in want:
+        assert got[rid].token_ids == want[rid].token_ids, rid
+        assert got[rid].finish_reason == want[rid].finish_reason, rid
+    assert [len(got[rid].token_ids) for rid in "abcdefg"] == [
+        24, 8, 0, 0, 1, 1, 5]
+    assert [got[rid].finish_reason for rid in "cdef"] == [
+        "stop", "stop", "length", "length"]
+    if dense:
+        assert got["s2"].finish_reason == "length"
+        assert len(got["s2"].token_ids) == 1
+    # every other prompt ended beside a's unread step and joined there;
+    # e, f (and s2) were known to end with their first token
+    assert set(joined) == set("bcdg") | ({"s1"} if dense else set())
+    stats = eng.stats
+    assert stats["chunks_fused"] == 0
+    assert stats["prompt_ends"] == len(requests)
+    assert stats["d2h_syncs"] == stats["decode_steps"] + len(requests)
+    # c's and d's rows of the step dispatched before their stop was read
+    assert stats["decode_slots"] - stats["tokens_generated"] == 2
+    assert stats["tokens_generated"] == sum(
+        len(out.token_ids) - 1 for out in got.values()
+        if out.finish_reason != "stop")
+    assert eng._flight is None and not eng._active
+    assert len(set(eng._free_slots)) == 3 - dense    # the session's stays
+
+
 def test_prompt_longer_than_a_chunk(params):
     engine = LLMEngine(CFG, params, slots=1, max_seq=128)
     prompt = list(np.random.RandomState(0).randint(1, 200, 100))

@@ -22,7 +22,7 @@ import jax
 
 import ant_ray_tpu as art
 from ant_ray_tpu.llm import LLMEngine, SamplingParams
-from ant_ray_tpu.llm.engine import PHASES, STALL_S, EngineLoop
+from ant_ray_tpu.llm.engine import PHASES, RIDE_ROWS, STALL_S, EngineLoop
 from ant_ray_tpu.llm.kv_offload import LocalKvStore
 from ant_ray_tpu.models import llama
 from ant_ray_tpu.observability import tracing_plane
@@ -214,6 +214,77 @@ def test_a_step_is_dispatched_before_the_step_before_it_is_read(
     # every prompt here is one chunk: all but the batch's first rode
     assert rode == stats["chunks_fused"]
     assert (rode > 0) == (len(lengths) > 1)
+
+
+@pytest.mark.parametrize("name", ["tiny", "granite-h-tiny"])
+def test_a_lone_chunks_prompt_end_is_read_behind_the_step_in_flight(name):
+    """The order test's twin for a chunk too wide to ride (slots + chunk
+    = 260 > ``RIDE_ROWS``; PR 48), dense and recurrent: in the iteration
+    where a lone chunk ends a prompt beside a decoding row, the prompt's
+    first token is sampled on the device and its row joins step N+1
+    from there; step N+1 is dispatched, step N is landed, and only then
+    is the first token read — its one read, in ``chunk``."""
+    eng = LLMEngine(llama.CONFIGS[name], slots=4, max_seq=96,
+                    prefill_chunk_tokens=256, tokenizer=_NoEos())
+    assert eng.slots + eng._chunk_tokens > RIDE_ROWS
+    assert not eng._chunk_rides
+    events, phases = [], []
+    dispatch, land = eng._dispatch_decode, eng._land
+    to_host, enter = eng._rec.to_host, eng._rec.enter
+
+    def spy_dispatch(ahead, chunk=None):
+        assert chunk is None                  # nothing rides
+        flight, logits = dispatch(ahead, chunk)
+        events.append(("dispatch", len(flight[1]), ahead is not None))
+        return flight, logits
+
+    def spy_land(flight):
+        events.append(("land", len(flight[1])))
+        return land(flight)
+
+    def spy_read(value):
+        events.append(("read", eng._rec._phase))
+        return to_host(value)
+
+    eng._dispatch_decode, eng._land = spy_dispatch, spy_land
+    eng._rec.to_host = spy_read
+    eng._rec.enter = lambda phase: phases.append(phase) or enter(phase)
+    outs = {}
+
+    def step():
+        del events[:], phases[:]
+        for out in eng.step():
+            outs[out.request_id] = out
+
+    eng.add_request([3, 9, 17], SamplingParams(max_tokens=12),
+                    request_id="a", admit=False)
+    step()
+    # nothing was unread: the first token is read at once, as before,
+    # and the row joins the decode step of the same iteration
+    assert events == [("read", "chunk"), ("dispatch", 1, False)]
+    step()
+    assert eng._flight is not None and len(eng._active) == 1
+    eng.add_request([7, 8, 9, 10, 11], SamplingParams(max_tokens=6),
+                    request_id="b", admit=False)
+    ends = eng.stats["prompt_ends"]
+    step()
+    # b's one chunk ran alone beside a's row and ended b's prompt: step
+    # N+1 holds BOTH rows, step N (a's alone) lands before b's first
+    # token is read
+    assert events == [("dispatch", 2, True), ("land", 1), ("read", "fetch"),
+                      ("read", "chunk")]
+    assert eng.stats["prompt_ends"] == ends + 1 == 2
+    assert phases[phases.index("decode"):] == [
+        "decode", "sample", "fetch", "emit", "chunk", "emit",
+        "housekeeping"]
+    assert eng.stats["chunks_fused"] == 0
+    while eng.has_unfinished():
+        step()
+    assert [len(outs[rid].token_ids) for rid in "ab"] == [12, 6]
+    stats = eng.stats
+    assert stats["d2h_syncs"] == stats["decode_steps"] + 2
+    assert stats["decode_ahead_steps"] == stats["decode_steps"] - 1
+    assert stats["decode_slots"] == stats["tokens_generated"] == 11 + 5
 
 
 def test_decode_ahead_pct_reads_the_counter_and_nothing_without_it(params):
@@ -819,9 +890,15 @@ def test_chunk_gaps_name_the_gaps_that_saw_a_prefill_dispatched(params):
     # both chunks rode the first one's decode steps (PR 39): a gap is
     # named where a prefill was dispatched, alone or not
     assert eng.stats["chunks_fused"] == 2
+    # the second of them was the prompt's last (PR 48): the one gap
+    # across which a prompt ended — the first request's own end, before
+    # its first token, lies in no gap
+    assert span["attrs"]["prompt_end_gaps"] == [had + 1]
+    assert eng.stats["prompt_ends"] == 2
     # nothing was prefilled after the second one's own first token
     (span,) = _spans(second.trace_id, "llm:engine")
     assert span["attrs"]["chunk_gaps"] == []
+    assert span["attrs"]["prompt_end_gaps"] == []
     assert len(span["attrs"]["emit_ms"]) == 3
 
 
