@@ -572,6 +572,17 @@ class LLMEngine:
             self.stats.update(dict.fromkeys(llama.ROUTING_COUNTERS, 0))
             self._routing_seen = np.zeros(
                 (len(llama.ROUTING_COUNTERS),), np.uint32)
+        # A looped model: ``loop_passes``, the passes of every step
+        # program dispatched (a reader knows a step's depth without the
+        # config); with an exit gate its counters
+        # (llama.EXIT_COUNTERS) come with the tokens as the routing
+        # counters do — ``exit_pass_sum`` in passes, a float.
+        if self.config.loops > 1:
+            self.stats["loop_passes"] = 0
+        if self.config.exit_gate:
+            self.stats.update(exit_rows=0, exit_pass_sum=0.0)
+            self._exits_seen = np.zeros(
+                (len(llama.EXIT_COUNTERS),), np.uint32)
 
         cfg = self.config
         eng_mesh = self.mesh
@@ -1048,8 +1059,10 @@ class LLMEngine:
                 self._jnp.asarray(np.zeros((self.slots,), bool)), tokens,
                 seq.slot, int(self.cache["length"][seq.slot]), 0)
             self._mixed_ran = True
+            self._note_passes()
         logits, self.cache = self._prefill_chunk_jit(
             self.params, self.cache, tokens, seq.slot, seq.kv_len, n)
+        self._note_passes()
         self._chunk_dispatched(seq, n)
         return self._chunk_end(seq, logits)
 
@@ -1184,6 +1197,7 @@ class LLMEngine:
             self._chunk_dispatched(seq, n)
             stats["chunks_fused"] += 1
         rec.dispatched = True
+        self._note_passes()
         stats["decode_steps"] += 1
         stats["decode_slots"] += len(rows)
         stats["decode_ahead_steps"] += flight is not None
@@ -1230,6 +1244,8 @@ class LLMEngine:
         now = time.perf_counter()     # a step's tokens leave together
         if self.config.num_experts:
             self._note_routing(toks[self.slots:])
+        if self.config.exit_gate:
+            self._note_exits(toks[self.slots:])
         for slot, seq in rows:
             if seq.slot != slot:
                 continue
@@ -1258,6 +1274,24 @@ class LLMEngine:
             self.stats[name] += more
         self._routing_seen = now
 
+    def _note_passes(self):
+        """A step program was dispatched: of a looped model, the passes
+        it runs its rows through."""
+        if self.config.loops > 1:
+            self.stats["loop_passes"] += self.config.loops
+
+    def _note_exits(self, counters):
+        """``counters``: the device's running exit counters as they
+        came with a step's tokens (``_note_routing``'s neighbour: a
+        looped model is not routed, they are all that rides) — what was
+        added since the last reading goes to ``stats``, the expected
+        exit passes in passes."""
+        now = counters.view(np.uint32)
+        rows, passes = (now - self._exits_seen).tolist()
+        self.stats["exit_rows"] += rows
+        self.stats["exit_pass_sum"] += passes * self._llama.EXIT_PASS_UNIT
+        self._exits_seen = now
+
     def _note_walk(self, longest: int, decoding=()):
         """A step program was dispatched whose longest live row holds
         ``longest`` positions (``decoding``: a decode step's rows'
@@ -1266,7 +1300,7 @@ class LLMEngine:
         if not self._ring:
             return
         span, stats = self._llama.span_positions, self.stats
-        n_window, n_full = self.config.layer_counts()
+        n_window, n_full = self.config.slab_layers()
         stats["full_span_positions"] += n_full * span(longest, self.max_seq)
         stats["window_span_positions"] += n_window * span(longest,
                                                           self._ring)
@@ -1628,7 +1662,9 @@ class LLMEngine:
                                   jnp.asarray(top_ps, jnp.float32))
         sampled, self._keys, self._last = self._sample_jit(
             logits, self._keys, self._active_dev, *self._sampling_dev,
-            self.cache.get("routing"), self._last)
+            # what rides with the tokens: a routed model's counters, or
+            # a looped model's (which is not routed)
+            self.cache.get("routing", self.cache.get("exits")), self._last)
         return sampled
 
     def _kept(self, scaled, top_ks, top_ps):
@@ -1681,8 +1717,8 @@ class LLMEngine:
         Every row of ``keys`` (uint32 (n, 2)) is split as the eager
         ``jax.random.split`` would: the second half samples, the first
         half is the row's next key where ``active``, and an inactive
-        row keeps its key.  ``routing`` (a routed model's counters,
-        uint32) is appended to the tokens bit for bit, so that the
+        row keeps its key.  ``routing`` (a routed model's counters, or a
+        looped model's exit counters, uint32) is appended to the tokens bit for bit, so that the
         step's one read brings both.  ``last`` (the per-slot token
         table) takes the active rows' tokens: the next decode step is
         fed from it on the device.  Returns ``(tokens, keys, last)``."""

@@ -176,6 +176,27 @@ class LlamaConfig:
     # their names (``ln_attn``: the mix's norm, ``ln_mlp``: the
     # feed-forward's).
     norm_after: bool = False
+    # SANDWICH norms: a sequential block with a norm on each sub-layer's
+    # input AND on its output, four a layer, each with its own weight:
+    # x + norm(mix(norm(x))), then x + norm(ffn(norm(x))).  The output
+    # norms' leaves are ``ln_attn_out`` / ``ln_mlp_out``.
+    sandwich_norm: bool = False
+    # A LOOPED stack (Ouro, arXiv 2510.25741; the published
+    # ``total_ut_steps``): a token goes through the ``n_layers`` layers
+    # ``loops`` times, every pass with the SAME weights, and the final
+    # norm closes every pass — its output is what the next pass starts
+    # from and what the head reads behind the last.  Attention in pass
+    # ``u``, layer ``l`` sees the keys and values that pass ``u``,
+    # layer ``l`` made of the earlier positions: the cache holds a slab
+    # layer for every (pass, layer) pair, at ``u * n_layers + l``
+    # (``slab_layers``).  1: every model before it.
+    loops: int = 1
+    # The looped model's exit gate: one ``dim -> 1`` linear with bias
+    # under a sigmoid, read on each pass's normed state
+    # (``exit_gate`` in the tree).  The step programs count the exit
+    # distribution it gives (``EXIT_COUNTERS``) and choose nothing by
+    # it: every row runs every pass.
+    exit_gate: bool = False
 
     def __post_init__(self, window_pattern):
         if window_pattern:
@@ -241,6 +262,29 @@ class LlamaConfig:
             raise ValueError("residual_multiplier scales what a "
                              "sequential block adds: a parallel block's "
                              "one sum is not scaled")
+        if self.sandwich_norm and (self.parallel_block or self.norm_after):
+            raise ValueError(
+                "sandwich_norm puts a norm on a sequential block's "
+                "sub-layer inputs AND outputs: with parallel_block there "
+                "is one norm, with norm_after the outputs' alone")
+        if self.loops < 1:
+            raise ValueError(f"loops {self.loops!r}: a token passes "
+                             "through the layers at least once")
+        not_looped = {
+            "window layers": bool(self.window),
+            "a recurrent kind (linear or ssm layers)": bool(self.recurrent),
+            "latent attention": bool(self.kv_lora_rank),
+            "leading dense layers": bool(self.n_dense_layers),
+            "routed experts": bool(self.num_experts),
+        }
+        if self.loops > 1 and any(not_looped.values()):
+            raise ValueError(
+                "loops run ONE stack of like dense full-attention layers "
+                "several times; not computed with " + ", ".join(
+                    what for what, found in not_looped.items() if found))
+        if self.exit_gate and self.loops == 1:
+            raise ValueError("exit_gate reads the state between the "
+                             "passes of a looped model: loops is 1")
 
     @property
     def head_dim(self) -> int:
@@ -305,6 +349,13 @@ class LlamaConfig:
         """(window layers, full layers) of the ``n_layers``."""
         n_window = self.n_layers // len(self.kinds) * sum(self.period)
         return n_window, self.n_layers - n_window - self.n_recurrent
+
+    def slab_layers(self) -> tuple:
+        """(ring layers, slab layers) of the CACHE, the one place that
+        says so: a window layer's ring and a full layer's slab for
+        every (pass, layer) pair — ``layer_counts`` times ``loops``."""
+        n_window, n_full = self.layer_counts()
+        return n_window * self.loops, n_full * self.loops
 
     def place(self, j: int) -> tuple:
         """Where the layer at place ``j`` of the period lies: (its
@@ -462,6 +513,14 @@ CONFIGS: dict[str, LlamaConfig] = {
         qk_norm=True, full_rope=False, norm_after=True,
         layer_kinds=("linear", "linear", "linear", "full"),
         linear_heads=4, linear_head_dim=8, linear_value_dim=16),
+    # Ouro's stack at test size: three layers that a token passes
+    # through three times (nine slab layers in the cache), 4 heads of
+    # 16, plain multi-head, rotated, four norms a layer (sandwich), the
+    # final norm between the passes, the exit gate, dense
+    "ouro-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        mlp_dim=96, max_seq=512, rope_theta=1000000.0, norm_eps=1e-6,
+        dtype=jnp.float32, sandwich_norm=True, loops=3, exit_gate=True),
 }
 
 
@@ -568,6 +627,8 @@ def _layer_leaves(c: LlamaConfig, stack: str = "layers") -> dict:
         **attn,
         **({} if c.parallel_block else {"ln_mlp": ((c.dim,), ("norm",))}),
         **mlp,
+        **({"ln_attn_out": ((c.dim,), ("norm",)),
+            "ln_mlp_out": ((c.dim,), ("norm",))} if c.sandwich_norm else {}),
         **({"q_norm": ((c.n_heads * hd,), ("norm",)),
             "k_norm": ((c.n_kv_heads * hd,), ("norm",))}
            if c.qk_norm and stack == "layers" else {}),
@@ -598,6 +659,9 @@ def _param_tree(config: LlamaConfig, pick) -> dict:
         **{name: {leaf: pick(n, *both) for leaf, both in leaves.items()}
            for name, (n, leaves) in stacks.items()},
         "norm_f": pick(None, (c.dim,), ("norm",)),
+        **({"exit_gate": {"w": pick(None, (c.dim, 1), ("embed_param", None)),
+                          "b": pick(None, (1,), ("bias",))}}
+           if c.exit_gate else {}),
         **({} if c.tie_embeddings else {"lm_head": pick(
             None, (c.dim, c.vocab_size), ("embed_param", "vocab"))}),
     }
@@ -727,8 +791,10 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     the residual is multiplied by ``residual_multiplier`` where the
     config states one.  With ``norm_after`` the block's two norms sit
     on the sub-layers' OUTPUTS — x + norm(mix(x)), x + norm(ffn(x)) —
-    and a sub-layer reads the residual as it is.  Returns ``(x, state,
-    load)``, ``load`` as ``_mlp`` gives it."""
+    and a sub-layer reads the residual as it is; with ``sandwich_norm``
+    on its input and its output both — x + norm(mix(norm(x))) — the
+    outputs' under leaves of their own.  Returns ``(x, state, load)``,
+    ``load`` as ``_mlp`` gives it."""
     lead, step = x.shape[:-1], index is not None
     h = x if c.norm_after else _norm(x, layer["ln_attn"], c)
     if kind == "ssm":
@@ -779,6 +845,8 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     attn = (attn @ layer["wo"]).astype(x.dtype)
     if c.norm_after:
         attn = _norm(attn, layer["ln_attn"], c)
+    if c.sandwich_norm:
+        attn = _norm(attn, layer["ln_attn_out"], c)
     if c.parallel_block:
         out, load = _mlp(layer, h, c, index, tile)
         x = x + attn + out.astype(x.dtype)
@@ -791,6 +859,8 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     out = out.astype(x.dtype)
     if c.norm_after:
         out = _norm(out, layer["ln_mlp"], c)
+    if c.sandwich_norm:
+        out = _norm(out, layer["ln_mlp_out"], c)
     x = _residual(x, out, c)
     x = constrain_act(x, ("batch", "seq", "embed"))
     return x, state, load
@@ -1344,9 +1414,22 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         x = lax.with_sharding_constraint(
             x, NamedSharding(mesh, PartitionSpec("dp", "sp", "fsdp")))
     x = constrain_act(x, ("batch", "seq", "embed"))
-    for stack, cfg in _stacks(params, c):
-        x = scan_stack(x, stack, cfg)
-    x = _norm(x, params["norm_f"], c)
+
+    def layers(x):
+        # the layers once, then the final norm
+        for stack, cfg in _stacks(params, c):
+            x = scan_stack(x, stack, cfg)
+        return _norm(x, params["norm_f"], c)
+
+    def a_pass(x, _):
+        with jax.named_scope("loop_pass"):
+            return constrain_act(layers(x), ("batch", "seq", "embed")), None
+
+    # a looped model's passes are a loop in the program, the same
+    # weights every pass, the norm closing each; the head reads the
+    # last pass's normed state
+    x = layers(x) if c.loops == 1 else lax.scan(
+        a_pass, x, None, length=c.loops)[0]
     return constrain_act(_head(params, x, c), ("batch", "seq", None))
 
 
@@ -1387,6 +1470,9 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
                          "grouped-query layers: no leading dense layers, "
                          "no latent attention, no window layers, no "
                          "linear layers, no ssm layers")
+    if c.loops > 1:
+        raise ValueError("the pipeline schedule runs its stages' layers "
+                         "once: loops (a looped stack) are not computed")
     cos, sin = _rope_tables(c)
 
     def attend(xq, xk, xv):
@@ -1432,7 +1518,11 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
     idle = max(c.num_experts - active, 0)
     matmul = 6 * (c.num_params() - (c.n_layers - c.n_dense_layers)
                   * idle * 3 * c.dim * c.mlp_dim)
-    attn = 6 * c.n_layers * c.n_heads * seq_len * (
+    if c.loops > 1:
+        # every pass multiplies with the layers' matrices again
+        matmul += 6 * (c.loops - 1) * sum(
+            math.prod(shape) for shape in param_shapes(c)["layers"].values())
+    attn = 6 * c.loops * c.n_layers * c.n_heads * seq_len * (
         c.head_dim + (c.v_head_dim or c.head_dim))
     return matmul + attn
 
@@ -1520,9 +1610,13 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     both under ONE ``length``; ``chunk`` is the width its prompts are
     ingested in.  A model's recurrent layers keep no positions but
     ``state_slabs`` (recurrent layers, slots, ...), under the same
-    ``length``; its full layers alone have slabs.  A routed model's
+    ``length``; its full layers alone have slabs.  A looped model
+    has a slab layer for every (pass, layer) pair, ``loops`` times its
+    layers (``LlamaConfig.slab_layers``), pass ``u``'s behind pass
+    ``u - 1``'s.  A routed model's
     cache also carries ``routing``, the
-    step programs' running counters (``ROUTING_COUNTERS``).
+    step programs' running counters (``ROUTING_COUNTERS``), a model
+    with an exit gate ``exits`` (``EXIT_COUNTERS``).
 
     Whoever jits a step program owns these buffers and DONATES them
     (``llm/engine.py``: ``donate_argnums=(1,)``): every leaf of the
@@ -1531,7 +1625,7 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     the donation a call allocates and fills a second whole cache."""
     c = config
     ms = max_seq or c.max_seq
-    n_window, n_full = c.layer_counts()
+    n_window, n_full = c.slab_layers()
     ring = ring_positions(c, ms, chunk)
     cache = {name: jnp.zeros(
         ((n_window, slots, ring) if name.endswith("_ring")
@@ -1543,7 +1637,46 @@ def init_kv_cache(config: LlamaConfig, slots: int,
     cache["length"] = jnp.zeros((slots,), jnp.int32)
     if c.num_experts:
         cache["routing"] = jnp.zeros((len(ROUTING_COUNTERS),), jnp.uint32)
+    if c.exit_gate:
+        cache["exits"] = jnp.zeros((len(EXIT_COUNTERS),), jnp.uint32)
     return cache
+
+
+# What the step programs count of a looped model's exit gate, summed
+# over executions in ``cache["exits"]`` (uint32, wraps; a reader takes
+# differences), of a decode step's ACTIVE rows alone: the rows, and the
+# sum of their expected exit pass ``sum_u (u + 1) p_u`` (1 .. loops)
+# under the exit distribution the gates give, in units of
+# ``EXIT_PASS_UNIT`` of a pass, rounded once a step.
+EXIT_COUNTERS = ("exit_rows", "exit_pass_sum")
+EXIT_PASS_UNIT = 1 / 1024
+
+
+def exit_distribution(gates):
+    """The gates ``lambda_u`` (loops, rows) of a looped model's passes
+    -> the probability (loops, rows) that a row leaves behind pass
+    ``u``: ``p_u = lambda_u * prod_{j<u} (1 - lambda_j)``, the last
+    pass's the remainder."""
+    stay = jnp.cumprod(1.0 - gates, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([(gates * before)[:-1], before[-1:]])
+
+
+def _count_exits(cache: dict, gates, counted) -> dict:
+    """``gates``: (loops, rows) float32 of one execution, None without
+    an exit gate; ``counted``: (rows,) bool, its decode rows that are
+    active, None where it has none -> the cache entry to carry."""
+    if gates is None:
+        return {}
+    if counted is None:
+        return {"exits": cache["exits"]}
+    passes = jnp.arange(1, gates.shape[0] + 1, dtype=jnp.float32)
+    expected = jnp.sum(passes[:, None] * exit_distribution(gates), axis=0)
+    seen = jnp.stack([
+        jnp.sum(counted).astype(jnp.float32),
+        jnp.round(jnp.sum(jnp.where(counted, expected, 0.0))
+                  / EXIT_PASS_UNIT)])
+    return {"exits": cache["exits"] + seen.astype(jnp.uint32)}
 
 
 # What the step programs count of a routed
@@ -1843,9 +1976,15 @@ def _slab_positions(cache: dict, c: LlamaConfig) -> int:
     return cache[next(iter(kv_slabs(c)))].shape[2]
 
 
+def _pass_first(c: LlamaConfig, u):
+    """The first slab layer of a looped model's pass ``u`` (traced):
+    the (pass, layer) index's pass part."""
+    return u * c.n_layers
+
+
 def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                  write_attend, write_state=None, *, decode: bool,
-                 mesh=None):
+                 mesh=None, counted=None):
     """A step program's layers over rows ``x`` (rows, dim): a
     ``lax.scan`` over each stack's PERIODS of the layer pattern
     (``_stacks``, ``LlamaConfig.period``), whose body is the period's
@@ -1855,6 +1994,17 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     entries: its slabs, its counters — a ``decode`` step's counted
     apart as well, ``ROUTING_COUNTERS``).  ``mesh``: the one the
     parameters are sharded over, if any (``_grouped_tile``).
+
+    A looped model (``LlamaConfig.loops``) runs that scan ``loops``
+    times, as a ``lax.scan`` over the passes AROUND it — one loop in
+    the program, the same weights every pass, rows and cache its carry
+    too: pass ``u``, layer ``l`` writes and reads slab layer ``u *
+    n_layers + l``.  The final norm closes every pass, so the rows
+    returned are NORMED (``_logits`` does not norm them again), and the
+    exit gate reads each pass's normed rows: ``counted`` ((rows,) bool:
+    a decode step's active rows, None where the program has none) says
+    whose exit distribution goes into ``cache["exits"]``
+    (``EXIT_COUNTERS``).
 
     ``write_attend(ks, vs, i, window, xq, xk, xv[, w_kvb]) -> (out, (ks,
     vs))`` is the block's attention over the carried slabs of the
@@ -1923,14 +2073,37 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                                 (layers, hoisted["layers"][2]))
         return carry, _unperiod(loads, cfg)
 
-    carry, first, loads = (x, {n: cache[n] for n in names + states}), 0, None
-    for stacks, cfg in _stacks(params, c):
-        carry, loads = scan_stack(carry, stacks, cfg, first)
-        first += cfg.n_layers
+    def once(carry, first):
+        """Every stack, once; ``first``: the pass's first slab layer."""
+        loads = None
+        for stacks, cfg in _stacks(params, c):
+            carry, loads = scan_stack(carry, stacks, cfg, first)
+            first += cfg.n_layers
+        return carry, loads
+
+    def a_pass(carry, u):
+        with jax.named_scope("loop_pass"):
+            (x, slabs), _ = once(carry, _pass_first(c, u))
+            x = _norm(x, params["norm_f"], c)
+        if not c.exit_gate:
+            return (x, slabs), None
+        with jax.named_scope("exit_gate"):
+            gate = params["exit_gate"]
+            return (x, slabs), jax.nn.sigmoid(jnp.dot(
+                x, gate["w"], preferred_element_type=jnp.float32)[:, 0]
+                + gate["b"].astype(jnp.float32))
+
+    carry, gates = (x, {n: cache[n] for n in names + states}), None
+    if c.loops == 1:
+        carry, loads = once(carry, 0)
+    else:
+        carry, gates = lax.scan(a_pass, carry, jnp.arange(c.loops))
+        loads = None
     routed = None if loads is None else (
         loads.shape[0] * x.shape[0] * c.experts_per_token)
     return carry[0], {**carry[1],
-                      **_count_routing(cache, loads, routed, decode, tile)}
+                      **_count_routing(cache, loads, routed, decode, tile),
+                      **_count_exits(cache, gates, counted)}
 
 
 def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
@@ -2121,16 +2294,22 @@ def _head(params: dict, x, c: LlamaConfig):
     return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling
 
 
+def _closed(params: dict, x, c: LlamaConfig):
+    """Rows behind the layers (``_scan_layers``), normed for the head:
+    a looped model's every pass ended in the norm, the last too."""
+    return x if c.loops > 1 else _norm(x, params["norm_f"], c)
+
+
 def _logits(params: dict, x, c: LlamaConfig):
     """Rows behind the layers -> their logits."""
-    return _head(params, _norm(x, params["norm_f"], c), c)
+    return _head(params, _closed(params, x, c), c)
 
 
 def _chunk_logits(params: dict, x, chunk_len, c: LlamaConfig):
     """A chunk's rows behind the layers -> the logits (vocab,) at its
     last REAL token: the rows normed, that one's multiplied with the
     head as one vector."""
-    return _head(params, jnp.take(_norm(x, params["norm_f"], c),
+    return _head(params, jnp.take(_closed(params, x, c),
                                   jnp.maximum(chunk_len - 1, 0), axis=0), c)
 
 
@@ -2197,7 +2376,7 @@ def decode_step(params: dict, last_tokens, cache: dict,
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(
             _decode_rows(cache, c, active, mesh)),
-        decode=True, mesh=mesh)
+        decode=True, mesh=mesh, counted=active)
     return _logits(params, x, c), {**written, "length": _stepped(
         cache["length"], active, _slab_positions(cache, c))}
 
@@ -2238,8 +2417,11 @@ def mixed_step(params: dict, last_tokens, tokens, cache: dict,
         params, x, cache, c, *_row_groups(
             _decode_rows(cache, c, active, mesh),
             _chunk_rows(cache, c, tokens.shape[0], slot, start, chunk_len)),
-        decode=False, mesh=mesh)
-    # Behind the layers each part goes on as in its own program — the
+        decode=False, mesh=mesh, counted=jnp.concatenate(
+            [active, jnp.zeros(tokens.shape, bool)]) if c.exit_gate
+        else None)
+    # Behind the layers each part goes on as in its own program (a
+    # looped model's rows were normed, row by row, as each pass ended) — the
     # decode rows normed and multiplied with the head together, the
     # chunk's rows normed and its last real token's multiplied as one
     # vector — and not the rows as one operand: on the chip the one-row

@@ -328,6 +328,56 @@ def test_mixed_cache_fits_the_chip_at_the_cells_size(v5e, program):
         assert not re.search(rf"= bf16\[1,{read},4608,8,128\]", text)
 
 
+# Ouro-2.6B whole, as `ouro-2.6b.rollout` serves it: 48 layers that a
+# token passes through four times, sandwich norms, the exit gate, the
+# whole vocabulary; 192 slab layers in the cache.
+LOOPED = llama.LlamaConfig(
+    vocab_size=49152, dim=2048, n_layers=48, n_heads=16, n_kv_heads=16,
+    head_width=128, mlp_dim=5632, max_seq=65536, rope_theta=1000000.0,
+    norm_eps=1e-6, sandwich_norm=True, loops=4, exit_gate=True)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_looped_cache_fits_the_chip_at_the_cells_size(v5e, monkeypatch,
+                                                      program):
+    """`ouro-2.6b.rollout` as it is served on the chip: 8 slots x 768,
+    a slab layer for every (pass, layer) pair — 192 of them, 9.0 GiB —
+    under 4.97 GiB of weights, prompts in chunks of 64 (72 rows with
+    the slots: the chunk rides).  The passes are a scan AROUND the scan
+    over the layers, and the cache is the carry of both: the sizing
+    rule holds — the weights, ONE set of slabs, every leaf of the
+    donated cache aliased to its output, and under one slab layer (48
+    MiB) of temporaries; a nested loop that copied its carry would need
+    9 GiB more and not fit.  The decode rows' kernel is handed the 192
+    layers' slabs whole, the layer ``u * 48 + l`` by scalar prefetch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, cache = _compile_step(
+        v5e.devices[0], program, LOOPED, 8, 768)
+    assert LOOPED.slab_layers() == (0, 192)
+    assert cache["k"].shape == cache["v"].shape == (192, 8, 768, 16, 128)
+    assert _tree_bytes(params) == 2 * 2_667_974_657
+    slabs = _tree_bytes((cache["k"], cache["v"]))
+    assert slabs == 9 * 2 ** 30
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    a_layer = slabs // 192
+    assert mem.temp_size_in_bytes < a_layer
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < _tree_bytes(params) + slabs + a_layer
+    assert need < 14.1 * 2 ** 30
+    text = compiled.as_text()
+    assert "loop_pass" in text
+    # the gate is computed where its rows are counted: a decode step's
+    assert ("exit_gate" in text) == (program != "prefill_chunk")
+    assert not re.search(r"bf16\[192,8,768,16,128\]\S* copy\(", text)
+    if program != "prefill_chunk":
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and "decode_attention" in line]
+        assert len(calls) == 1             # in the two scans' one body
+        assert "bf16[192,8,12288,128]" in calls[0]
+
+
 # Solar Open 2's blocks at their published widths, the benchmark's cut:
 # one period of a gated softmax layer without positional embedding (64
 # query / 8 KV heads of 128) and three gated delta-rule layers (64 heads
@@ -492,6 +542,10 @@ def test_thirty_heads_slabs_stay_where_they_lie_at_the_cells_size(
 DENSE_128 = dataclasses.replace(CFG, n_heads=16)
 
 
+# Ouro's stack cut to four layers, twice through: eight slab layers.
+LOOPED_TWICE = dataclasses.replace(LOOPED, n_layers=4, loops=2)
+
+
 @pytest.mark.parametrize("program,on_tpu", [
     *((program, False) for program in PROGRAMS),
     ("decode", True), ("mixed_step", True)], ids=[
@@ -500,7 +554,8 @@ DENSE_128 = dataclasses.replace(CFG, n_heads=16)
     pytest.param(DENSE_128, 8, 2048, id="dense"),
     pytest.param(ROUTED, 16, 1536, id="routed"),
     pytest.param(LATENT, 48, 4096, id="latent"),
-    pytest.param(MIXED_TWICE, 8, 16384, id="window-and-full")])
+    pytest.param(MIXED_TWICE, 8, 16384, id="window-and-full"),
+    pytest.param(LOOPED_TWICE, 8, 768, id="looped")])
 def test_step_updates_the_cache_in_place(v5e, monkeypatch, program, on_tpu,
                                          config, slots, max_seq):
     """The step programs write their rows into the donated cache and
@@ -528,8 +583,8 @@ def test_step_updates_the_cache_in_place(v5e, monkeypatch, program, on_tpu,
     slabs = [cache[name] for name in llama.kv_slabs(config)]
     # (MIXED_TWICE's slabs are cut small here; its temporaries are the
     # weights' copies: the rule is held at the cell's size above)
-    assert mem.temp_size_in_bytes < _tree_bytes(slabs) // config.n_layers \
-        or config.window
+    assert mem.temp_size_in_bytes < _tree_bytes(slabs) // sum(
+        config.slab_layers()) or config.window
     produces = r"\s*(ROOT )?%?[\w.\-]+ = "
     lines = compiled.as_text().splitlines()
     for slab in slabs:
