@@ -29,6 +29,28 @@ S_{t-1} + k_t u_t^T``: a decay, then a rank-one write.
   1: ``exp(-G_i)`` alone overflows float32 within one block when a
   channel forgets fast (g = -1.6 a token is exp(102) after 64).  The
   state passes from block to block in the scan's carry.
+
+  The pair sums ``A`` (and the same with ``q_t`` for the outputs) keep
+  the exponential inside the sum over ``c`` only where they must
+  (``_pair_sums``).  The block is cut into sub-blocks of ``SUB`` = 16
+  tokens; with ``R_a = G_{SUB a}``, the cumulative log-decay at
+  sub-block ``a``'s first token, a pair that lies on two sides of it —
+  ``t`` in ``a``, ``i`` in an earlier sub-block — has
+
+      exp(G_t - G_i) = exp(G_t - R_a) * exp(R_a - G_i)
+
+  and BOTH factors are differences of the kind above, so at most 1
+  (``G`` only falls: ``t >= SUB a`` gives ``G_t <= R_a``, ``i < SUB a``
+  gives ``R_a <= G_i``); a factor that underflows stands for a product
+  under 1e-38.  Its sum over ``c`` is then a MATRIX product of the
+  sub-block's rows ``k_t * exp(G_t - R_a)`` with the earlier tokens'
+  rows ``k_i * exp(R_a - G_i)``, at the highest precision (``A`` enters
+  the triangular system).  Only a pair inside ONE sub-block has no
+  reference point between its tokens and keeps the pairwise form:
+  block * SUB * d_k exponentials a head and as many terms in each sum,
+  a quarter of the block taken pairwise whole, and 3 * block * d_k for
+  the factors (block = 4 SUB: the later sub-blocks' own rows, and the
+  tokens before the last sub-block once a reference point).
 * ``delta_rule_step`` — one token a row, elementwise in float32; a row
   that is not ``active`` keeps its state bit for bit.
 
@@ -43,11 +65,13 @@ with d_k != d_v:
 * ``chunk_gdn`` — the block form's pair products become MATRIX
   products over d_k, ``A = (K K^T) * exp(G_t - G_i)`` and ``(Q K^T) *
   exp(G_t - G_i)`` with ``G`` (block,) a head: block * block
-  exponentials a head where the channel form takes block * block * d_k
+  exponentials a head where the channel form takes block * SUB * d_k
   (its ``k_t[c] k_i[c] exp(G_t[c] - G_i[c])`` cannot leave the
-  exponential out of the sum over ``c``).  Broadcasting a scalar decay
-  to d_k channels through ``chunk_delta_rule`` gives the same values
-  at d_k times the exponentials.
+  exponential out of the sum over ``c`` for a pair inside one
+  sub-block; across a sub-block's first token it can, above).
+  Broadcasting a scalar decay to d_k channels through
+  ``chunk_delta_rule`` gives the same values at SUB * d_k times the
+  exponentials.
 * ``gdn_step`` — ``delta_rule_step`` with one ``exp`` a head.
 
 How such a state lies: ``s`` (..., heads, d_k, d_v) float32 row-major,
@@ -68,6 +92,7 @@ import jax.numpy as jnp
 from jax import lax
 
 BLOCK = 64
+SUB = 16              # a block's sub-blocks, as _unit_lower_inverse's leaf
 _HIGHEST = lax.Precision.HIGHEST
 
 
@@ -171,6 +196,52 @@ def _by_blocks(form, q, k, v, g, beta, s0, block: int):
     return jnp.moveaxis(o, 1, 2).reshape(tokens, heads, -1)[:given], s
 
 
+def _pairwise(q, k, gc, lower):
+    """The pair sums with the exponential inside the sum: q, k, gc
+    (..., n, d_k), ``lower`` (n, n) -> (kk, qk) (..., n, n), 0 above the
+    diagonal."""
+    # exp(G_t - G_i) for i <= t, 0 above the diagonal
+    decay = jnp.exp(jnp.where(
+        lower[..., None], gc[..., :, None, :] - gc[..., None, :, :],
+        -jnp.inf))
+    kk = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
+    qk = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
+    return kk, qk
+
+
+def _pair_sums(q, k, gc, lower):
+    """A block's ``kk[t, i] = sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c])``
+    and ``qk`` (the same with ``q_t``) for ``i <= t``: q, k, gc (heads,
+    block, d_k) -> two (heads, block, block), 0 above the diagonal.
+    Pairwise inside a sub-block of ``SUB``; between sub-blocks ONE
+    matrix product (the module's docstring): the rows of every later
+    sub-block ``a``, ``k`` and ``q`` together, scaled to its first
+    token, with the tokens before the last sub-block scaled from it, 0
+    where a token does not stand before ``a``."""
+    heads, block, d_k = k.shape
+    if block <= SUB:
+        return _pairwise(q, k, gc, lower)
+    n, m = block // SUB, block - SUB
+    qs, ks, gs = (x.reshape(heads, n, SUB, d_k) for x in (q, k, gc))
+    kk, qk = _pairwise(qs, ks, gs, lower[:SUB, :SUB])   # (heads, n, SUB, SUB)
+    ref = gs[:, 1:, :1]                   # R_a, a = 1 .. n - 1
+    fall = jnp.exp(gs[:, 1:] - ref)       # exp(G_t - R_a) <= 1
+    rows = jnp.concatenate([ks[:, 1:] * fall, qs[:, 1:] * fall], axis=2)
+    # exp(R_a - G_i) <= 1 for a token i before sub-block a, 0 from a on
+    before = jnp.arange(m) < SUB * jnp.arange(1, n)[:, None]
+    earlier = k[:, None, :m] * jnp.exp(jnp.where(
+        before[..., None], ref - gc[:, None, :m], -jnp.inf))
+    off = jnp.einsum("hatc,haic->hati", rows, earlier, precision=_HIGHEST)
+    off = jnp.pad(off, ((0, 0), (1, 0), (0, 0), (0, SUB)))
+    eye = jnp.eye(n, dtype=k.dtype)[:, None, :, None]
+
+    def whole(off, diagonal):             # (heads, n, SUB, block) + blocks
+        placed = diagonal[:, :, :, None, :] * eye
+        return (off + placed.reshape(off.shape)).reshape(heads, block, block)
+
+    return whole(off[:, :, :SUB], kk), whole(off[:, :, SUB:], qk)
+
+
 def chunk_delta_rule(q, k, v, g, beta, s0, block: int = BLOCK):
     """``delta_rule_scan``'s values in blocks of ``block`` tokens, the
     last one filled up with tokens that change nothing."""
@@ -178,11 +249,7 @@ def chunk_delta_rule(q, k, v, g, beta, s0, block: int = BLOCK):
         def one(s, x):
             q, k, v, g, beta = x              # (heads, block, ...)
             gc = jnp.cumsum(g, axis=1)
-            # exp(G_t - G_i) for i <= t, 0 above the diagonal
-            decay = jnp.exp(jnp.where(
-                lower[..., None], gc[:, :, None] - gc[:, None], -jnp.inf))
-            kk = jnp.sum(k[:, :, None] * k[:, None] * decay, axis=-1)
-            qk = jnp.sum(q[:, :, None] * k[:, None] * decay, axis=-1)
+            kk, qk = _pair_sums(q, k, gc, lower)
             solve = _unit_lower_inverse(
                 beta[..., None] * jnp.where(strict, kk, 0.0))
             into = jnp.exp(gc)                # from the block's start

@@ -32,6 +32,7 @@ import pytest
 from ant_ray_tpu.llm import LLMEngine, SamplingParams
 from ant_ray_tpu.models import llama
 from ant_ray_tpu.ops import delta_rule
+from benchmarks import delta_rule_chunk
 from chipbench.models import solar_open2
 from chipbench.reference import solar_open2_decoder as ref
 
@@ -240,6 +241,61 @@ def test_blocks_of_64_are_the_recurrence_token_by_token(tokens):
     assert np.isfinite(np.asarray(got_o)).all()
     np.testing.assert_allclose(got_o, want_o, rtol=1e-4, atol=2e-6)
     np.testing.assert_allclose(got_s, want_s, rtol=1e-4, atol=2e-6)
+
+
+def _fast_between(g, first, last, fastest=3.0):
+    """Channel 0 forgets at the fastest rate, exp(-3) a token, in tokens
+    [first, last) and next to nothing elsewhere."""
+    g = g.at[:, :, 0].set(-1e-3)
+    return g.at[first:last, :, 0].set(-fastest)
+
+
+@pytest.mark.parametrize("tokens, shape, fast, block", [
+    (128, dict(heads=2, d_k=128, d_v=128), None, delta_rule.BLOCK),
+    (64, {}, (18, 31), delta_rule.BLOCK),
+    (64, {}, (26, 39), delta_rule.BLOCK),
+    (17, {}, None, delta_rule.BLOCK),
+    (40, {}, None, delta_rule.SUB),
+], ids=["the-published-head", "fast-inside-a-sub-block",
+        "fast-across-a-sub-blocks-first-token", "seventeen-tokens",
+        "blocks-of-16-are-pairwise-whole"])
+def test_sub_blocks_of_16_are_the_recurrence_token_by_token(tokens, shape,
+                                                            fast, block):
+    """The pair sums between sub-blocks are matrix products against the
+    later sub-block's first token (``delta_rule.SUB``): the published
+    128 x 128 head; a channel that forgets fastest only INSIDE sub-block
+    1 (tokens 16-31: the pairwise part alone sees it fall) and only
+    ACROSS sub-block 2's first token, 32 (both factors fall); a block
+    whose real tokens end one token into the second sub-block; a
+    caller's block of one sub-block, pairwise whole.  (A factor that
+    underflows: the three-blocks case above, whose channel 0 falls by
+    exp(-48) a sub-block, so from a token of sub-block 0 to sub-block
+    3's first token by exp(-144), 0 in float32.)"""
+    q, k, v, g, beta, s0 = _delta_inputs(9, tokens, **shape)
+    if fast:
+        g = _fast_between(g, *fast)
+    want_o, want_s = delta_rule.delta_rule_scan(q, k, v, g, beta, s0)
+    got_o, got_s = jax.jit(functools.partial(
+        delta_rule.chunk_delta_rule, block=block))(q, k, v, g, beta, s0)
+    assert np.isfinite(np.asarray(got_o)).all()
+    np.testing.assert_allclose(got_o, want_o, rtol=1e-4, atol=2e-6)
+    np.testing.assert_allclose(got_s, want_s, rtol=1e-4, atol=2e-6)
+
+
+def test_a_blocks_exponentials_are_a_quarter_of_the_pairwise_forms():
+    """What holds the quarter: in the jaxpr of one block of one
+    published head no ``exp`` reads BLOCK x BLOCK x d_k elements, and
+    the pair sums' exponentials — every ``exp`` but ``into`` and
+    ``out_of``, BLOCK x d_k each, which scale the products with the
+    state — are under 0.3 of that (the four diagonal sub-blocks a
+    quarter, the factors to and from a sub-block's first token the
+    rest)."""
+    block, d_k = delta_rule.BLOCK, 128
+    sizes = delta_rule_chunk.exp_operands(
+        delta_rule.chunk_delta_rule, *_delta_inputs(1, block, heads=1,
+                                                    d_k=d_k, d_v=d_k))
+    assert max(sizes) == block * delta_rule.SUB * d_k < block * block * d_k
+    assert sum(sizes) - 2 * block * d_k <= 0.3 * block * block * d_k
 
 
 def test_keys_that_lie_close_together_do_not_lose_the_inverse():
