@@ -305,6 +305,9 @@ class ClusterRuntime(CoreRuntime):
         self._actor_meta_cache: dict[ActorID, dict] = {}
         self._pg_bundle_cache: dict = {}  # pg_id -> [node addresses]
         self._renv_cache: dict = {}       # runtime_env -> wire form
+        # actor_id -> (wire ctx, parent span, submitted wall, attrs) of
+        # an `actor:create` span not yet recorded (`create_actor`).
+        self._actor_create_spans: dict = {}
         self._arena_client = ArenaClient()
         # Live zero-copy pins (weak: pins die when their values are
         # GC'd); the renewal loop heartbeats their daemon leases.
@@ -2124,6 +2127,9 @@ class ClusterRuntime(CoreRuntime):
     def create_actor(self, actor_class, args, kwargs, options: ActorOptions):
         from ant_ray_tpu.actor import ActorHandle  # noqa: PLC0415
 
+        # artlint: disable=banned-apis — `actor:create`'s `ts`: a
+        # cross-process wall-clock wire field
+        called = time.time()
         declared = set(options.concurrency_groups or ())
         undeclared = {g for g in actor_class.method_concurrency_groups()
                       .values() if g not in declared}
@@ -2184,8 +2190,22 @@ class ClusterRuntime(CoreRuntime):
             scheduling_strategy=strategy_wire(
                 options.scheduling_strategy),
         )
+        # Inside a start-up trace (`serve:run`, `train:fit`, a
+        # constructor of an actor they created) the creation is a span
+        # of it, recorded where the creator learns the actor is alive
+        # (`_actor_sender`), and the context rides the spec to the
+        # daemon and the worker — sampled or not: the spans are forced.
+        creator = tracing_plane.current()
+        if creator is not None:
+            spec.trace_ctx = creator.child().to_wire()
+            self._actor_create_spans[actor_id] = (
+                spec.trace_ctx, creator.span_id, called,
+                {"actor_id": actor_id.hex(),
+                 "class": actor_class._class_name,
+                 "resources": dict(spec.resources)})
         reply = self._gcs.call("CreateActor", spec, retries=3)
         if "error" in reply:
+            self._actor_create_spans.pop(actor_id, None)
             if options.get_if_exists and options.name:
                 return self.get_actor(options.name, options.namespace)
             raise ValueError(reply["error"])
@@ -2352,6 +2372,30 @@ class ClusterRuntime(CoreRuntime):
         except (RpcConnectionError, OSError):
             pass
 
+    def _record_actor_create(self, actor_id, info: dict | None) -> None:
+        """The `actor:create` span of an actor created inside a
+        start-up trace: ``.remote()`` called → alive, split where a
+        daemon took it (`schedule`: the class exported and the
+        arguments serialised here, the GCS's placement, a runtime env's
+        build, the spawn's `Popen`; `start`: the worker's boot and the
+        constructor).  Both instants are the GCS's wall clock;
+        an actor that never came alive ends now, as an error."""
+        created = self._actor_create_spans.pop(actor_id, None)
+        if created is None:
+            return
+        wire, parent_id, submitted, attrs = created
+        info = info or {}
+        alive = info.get("state") == ACTOR_ALIVE
+        # artlint: disable=banned-apis — span ends on the wall clock
+        end = max((alive and info.get("alive_at")) or time.time(),
+                  submitted)
+        leased = min(max(info.get("leased_at") or end, submitted), end)
+        tracing_plane.record_span(
+            wire, "actor:create", ts=submitted, dur_s=end - submitted,
+            stages={"schedule": leased - submitted, "start": end - leased},
+            attrs=attrs, forced=True, error=not alive, span_id=wire[1],
+            parent_id=parent_id)
+
     async def _actor_sender(self, state: _ActorSubmitState):
         """Drains the per-actor queue in order; pipelined deferred sends
         coalesce each burst into one transport write, flushed whenever
@@ -2372,6 +2416,7 @@ class ClusterRuntime(CoreRuntime):
                     info = await self._gcs.call_async("WaitActorAlive", {
                         "actor_id": state.actor_id, "timeout": 120.0,
                     }, timeout=-1)
+                    self._record_actor_create(state.actor_id, info)
                     if info is None or info["state"] != ACTOR_ALIVE:
                         reason = (info or {}).get("death_reason",
                                                   "actor not found")

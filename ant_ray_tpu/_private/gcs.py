@@ -45,6 +45,10 @@ class ActorRecord:
     node_id: NodeID | None = None
     restarts_used: int = 0
     death_reason: str = ""
+    # Wall clock of the newest placement (a daemon took the actor) and
+    # of its ALIVE report: the creator's `actor:create` span's stages.
+    leased_at: float = 0.0
+    alive_at: float = 0.0
     state_event: asyncio.Event = field(default_factory=asyncio.Event)
 
 
@@ -1570,6 +1574,9 @@ class GcsServer:
                 try:
                     await client.call_async("StartActorWorker", spec,
                                             timeout=30)
+                    # artlint: disable=banned-apis — a span's stage
+                    # boundary, read by the creator on its wall clock
+                    record.leased_at = time.time()
                     return  # worker will report ALIVE via ActorStateUpdate
                 except Exception as e:  # noqa: BLE001 — reschedule
                     logger.warning("actor %s placement on %s failed: %s",
@@ -1761,6 +1768,9 @@ class GcsServer:
             record.node_id = payload["node_id"]
         if record.state == ACTOR_DEAD:
             record.death_reason = payload.get("reason", "")
+        elif record.state == ACTOR_ALIVE:
+            # artlint: disable=banned-apis — as `leased_at`
+            record.alive_at = time.time()
         record.state_event.set()
         record.state_event = asyncio.Event()
         self._save_actor(record)
@@ -1816,6 +1826,8 @@ class GcsServer:
             "class_name": record.spec.class_name,
             "death_reason": record.death_reason,
             "name": record.spec.name,
+            "leased_at": record.leased_at,
+            "alive_at": record.alive_at,
         }
 
     async def _wait_actor_alive(self, payload):

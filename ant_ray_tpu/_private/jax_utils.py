@@ -16,8 +16,9 @@ process opens a TPU, owners included, and whatever reports a device
 reports ``cpu`` (:func:`chip_platform`).
 
 Every module of the package imports jax through :func:`import_jax`,
-the one place that applies the launcher's platform and places the
-persistent compile cache.
+the one place that applies the launcher's platform, places the
+persistent compile cache and registers the process's one compilation
+listener (``observability/compile_watch.py``).
 """
 
 from __future__ import annotations
@@ -86,6 +87,10 @@ def import_jax():
             # is set in code; children inherit the variable.
             jax.config.update("jax_compilation_cache_dir",
                               default_cache_dir())
+        # Every compilation of this process a `jit:compile` span.
+        from ant_ray_tpu.observability import compile_watch  # noqa: PLC0415
+
+        compile_watch.install(jax)
         _configured = True
     return jax
 

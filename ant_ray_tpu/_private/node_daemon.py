@@ -108,6 +108,9 @@ class WorkerHandle:
     job_id: object | None = None         # last job served (log scoping)
     blocked: bool = False
     env_key: str = ""                    # runtime-env pool identity
+    # Wall clock just before ``Popen``: where `worker:spawn` and the
+    # worker's own `worker:boot` span start.
+    spawned_at: float = 0.0
     registered: asyncio.Event = field(default_factory=asyncio.Event)
 
 
@@ -902,13 +905,17 @@ class NodeManager:
         # _ensure_runtime_env before the spawn reaches here).
         python = renv.venv_python(runtime_env, self._session_dir) \
             or sys.executable
+        # artlint: disable=banned-apis — a span's `ts`: a cross-process
+        # wall-clock wire field
+        spawned_at = time.time()
         proc = subprocess.Popen(
             [python, "-m", "ant_ray_tpu._private.worker_main"],
             env=env, cwd=cwd, stdout=log_file, stderr=subprocess.STDOUT,
             start_new_session=True)
         log_file.close()
         handle = WorkerHandle(worker_id, proc, actor_spec=actor_spec,
-                              env_key=renv.env_key(runtime_env))
+                              env_key=renv.env_key(runtime_env),
+                              spawned_at=spawned_at)
         if self._cgroups is not None:
             self._cgroups.add_worker_process(proc.pid)
         self._workers[worker_id] = handle
@@ -920,8 +927,10 @@ class NodeManager:
         if handle is None:
             return {"error": "unknown worker"}
         handle.address = payload["address"]
+        reply = {"ok": True, "spawned_at": handle.spawned_at}
         was_actor = handle.actor_spec is not None
         if was_actor:
+            reply["trace"] = self._record_spawn(handle)
             client = self._clients.get(handle.address)
             _spawn(
                 client.call_async("InstantiateActor", handle.actor_spec,
@@ -931,7 +940,30 @@ class NodeManager:
             handle.state = IDLE
             self._lease_event.set()
         handle.registered.set()
-        return {"ok": True}
+        return reply
+
+    def _record_spawn(self, handle: WorkerHandle) -> tuple | None:
+        """`worker:spawn`, ``Popen`` → this registration, of a worker
+        spawned for an actor that was created inside a start-up trace
+        (``ActorSpec.trace_ctx``: the creator's `actor:create`).
+        Returns the context the worker's own `worker:boot` hangs
+        under."""
+        wire = handle.actor_spec.trace_ctx
+        if not wire:
+            return None
+        from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
+
+        # artlint: disable=banned-apis — the span ends on the wall clock
+        dur = time.time() - handle.spawned_at
+        sid = tracing_plane.record_span(
+            wire, "worker:spawn", ts=handle.spawned_at, dur_s=dur,
+            attrs={"pid": handle.proc.pid,
+                   "JAX_PLATFORMS": self._chips.platform
+                   if self._chips.held_by(handle.worker_id) else "cpu",
+                   "tpu_chips": list(self._chips.held_by(handle.worker_id)),
+                   "worker_id": handle.worker_id.hex()[:8]},
+            forced=True, service="node-daemon")
+        return (wire[0], sid, wire[2])
 
     async def _monitor_workers_loop(self):
         gcs = self._clients.get(self._gcs_address)

@@ -112,6 +112,11 @@ class ActorSpec:
     label_selector: dict | None = None
     # Wire-form scheduling strategy (see TaskSpec.scheduling_strategy).
     scheduling_strategy: "dict | str | None" = None
+    # Wire TraceContext of the creator's `actor:create` span — sampled
+    # or not, unlike a task's: the daemon's `worker:spawn`, the worker's
+    # `worker:boot` / `actor:init` and what the constructor records are
+    # forced spans of the creator's start-up trace.
+    trace_ctx: "tuple | None" = None
 
 
 @dataclass

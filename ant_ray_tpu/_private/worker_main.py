@@ -12,6 +12,7 @@ loop).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import inspect
 import logging
 import os
@@ -460,7 +461,35 @@ def _report_actor_state(runtime: ClusterRuntime, spec: ActorSpec | None,
         logger.exception("failed to report actor state")
 
 
+def _record_boot(reply: dict, main_wall: float, main_t: float,
+                 connected_t: float) -> "tracing_plane.TraceContext | None":
+    """`worker:boot` of a worker spawned for an actor that was created
+    inside a start-up trace: the daemon's ``Popen`` (its wall clock, in
+    the registration's reply — not a guess) → this worker registered
+    and ready for its first task.  Stages: ``imports`` (the interpreter
+    and this module's imports, to ``main``'s first line), ``connect``
+    (the core runtime: GCS, daemon, store, its own server),
+    ``register``.  A runtime env is built by the DAEMON before the
+    spawn and lies in `actor:create`'s ``schedule``, not here.  Returns
+    the context the actor's `actor:init` hangs under."""
+    wire = reply.get("trace") if isinstance(reply, dict) else None
+    if not wire:
+        return None
+    spawned = reply["spawned_at"]
+    stages = {"imports": max(0.0, main_wall - spawned),
+              "connect": connected_t - main_t,
+              "register": time.perf_counter() - connected_t}
+    sid = tracing_plane.record_span(
+        wire, "worker:boot", ts=spawned, dur_s=sum(stages.values()),
+        stages=stages, attrs={"pid": os.getpid()}, forced=True,
+        service="worker")
+    return tracing_plane.TraceContext(wire[0], sid, bool(wire[2]))
+
+
 def main():  # pragma: no cover — exercised via subprocess in tests
+    # artlint: disable=banned-apis — `worker:boot`'s stage boundary,
+    # against the daemon's wall clock at ``Popen``
+    main_wall, main_t = time.time(), time.perf_counter()
     logging.basicConfig(
         level=global_config().log_level,
         format="[worker %(levelname)s %(asctime)s] %(message)s")
@@ -499,6 +528,11 @@ def main():  # pragma: no cover — exercised via subprocess in tests
 
     executor = TaskExecutor(runtime)
     io = IoThread.get()
+    # The start-up context (`worker:boot`), once this worker is
+    # registered: the daemon may send InstantiateActor before the
+    # registration's reply is read here.
+    boot: dict = {}
+    booted = threading.Event()
 
     def handle_push_task(spec: TaskSpec):
         # Sync fast-route handler: returns the reply future directly, so
@@ -518,18 +552,33 @@ def main():  # pragma: no cover — exercised via subprocess in tests
 
         def _do_instantiate():
             try:
-                cls = runtime.fetch_code(spec.class_id)
-                ser = serialization.SerializedObject.from_payload(
-                    spec.args_payload)
-                obj = serialization.deserialize(ser)
-                if isinstance(obj, PromotedArgs):
-                    args, kwargs = runtime.get([obj.ref], timeout=None)[0]
-                else:
-                    args, kwargs = obj
-                args = [executor._maybe_fetch(a) for a in args]
-                kwargs = {k: executor._maybe_fetch(v)
-                          for k, v in kwargs.items()}
-                executor.actor_instance = cls(*args, **kwargs)
+                booted.wait(10.0)
+                # Inside a start-up trace `actor:init` is a span of it
+                # and the context current in the constructor: ``load``
+                # (the class and its arguments — unpickling imports
+                # their modules), ``construct``.
+                ctx = boot.get("ctx")
+                with (tracing_plane.staged_span(
+                        "actor:init", ctx, {"class": spec.class_name})
+                      if ctx is not None
+                      else contextlib.nullcontext()) as sp:
+                    cls = runtime.fetch_code(spec.class_id)
+                    ser = serialization.SerializedObject.from_payload(
+                        spec.args_payload)
+                    obj = serialization.deserialize(ser)
+                    if isinstance(obj, PromotedArgs):
+                        args, kwargs = runtime.get([obj.ref],
+                                                   timeout=None)[0]
+                    else:
+                        args, kwargs = obj
+                    args = [executor._maybe_fetch(a) for a in args]
+                    kwargs = {k: executor._maybe_fetch(v)
+                              for k, v in kwargs.items()}
+                    if sp is not None:
+                        sp.lap("load")
+                    executor.actor_instance = cls(*args, **kwargs)
+                    if sp is not None:
+                        sp.lap("construct")
                 _report_actor_state(runtime, spec, ACTOR_ALIVE,
                                     address=runtime.address)
                 io.loop.call_soon_threadsafe(fut.set_result, True)
@@ -559,11 +608,16 @@ def main():  # pragma: no cover — exercised via subprocess in tests
     })
     runtime.server.fast_route("PushTask", handle_push_task)
 
-    runtime._node.call("RegisterWorker", {
+    connected_t = time.perf_counter()
+    reply = runtime._node.call("RegisterWorker", {
         "worker_id": worker_id,
         "address": runtime.address,
         "pid": os.getpid(),
     }, retries=5)
+    try:
+        boot["ctx"] = _record_boot(reply, main_wall, main_t, connected_t)
+    finally:
+        booted.set()
     logger.info("worker %s serving at %s", worker_id.hex()[:8],
                 runtime.address)
 

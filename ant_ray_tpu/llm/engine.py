@@ -324,6 +324,12 @@ class _PhaseRecorder:
             self._record_stall(now)
         if self.dispatched:
             self._stats["steps"] += 1
+            if self._stats["steps"] == 1:
+                # From the first completed step on, a compilation is
+                # one a request waits for: `jit:compile` says so.
+                from ant_ray_tpu.observability import compile_watch  # noqa: PLC0415
+
+                compile_watch.mark_ready()
 
     def _record_stall(self, now: float) -> None:
         began, blocked = self._began
@@ -341,14 +347,14 @@ class _PhaseRecorder:
         try:
             from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
 
-            # forced, as a shed is kept: no sampled request needed, and
-            # a trace id of its own
+            # forced (kept whatever the sampling coin says, and no
+            # error): no sampled request needed, a trace id of its own
             # artlint: disable=banned-apis — span `ts` is a cross-
             # process wall-clock wire field, the iteration's start
             ts = time.time() - dur
             tracing_plane.record_span(
                 tracing_plane.mint(sampled=False), "llm:stall",
-                ts=ts, dur_s=dur, attrs=attrs, error=True)
+                ts=ts, dur_s=dur, attrs=attrs, forced=True)
         except Exception:  # noqa: BLE001 — tracing is best-effort
             pass
 
@@ -440,6 +446,7 @@ class LLMEngine:
         """
         from ant_ray_tpu._private.jax_utils import import_jax
 
+        t_init = time.perf_counter()
         if type(prefill_chunk_tokens) is not int or prefill_chunk_tokens < 1:
             raise ValueError(
                 "prefill_chunk_tokens must be a positive int (the width "
@@ -492,6 +499,10 @@ class LLMEngine:
                 devices=jax.local_devices()[:tensor_parallel_size],
                 tp=tensor_parallel_size)
         self.params = params
+        # `llm:init`'s `weights` stage is the device's seconds, not the
+        # dispatch's: wait here, once, and once more behind the cache.
+        jax.block_until_ready(params)
+        t_cache = time.perf_counter()
         self.cache = llama.init_kv_cache(self.config, slots, self.max_seq,
                                          prefill_chunk_tokens)
         # A window layer's ring rows (0: the model has none), as the
@@ -652,6 +663,12 @@ class LLMEngine:
         self._take_key_jit = jax.jit(_take_key)
         self._sample_jit = jax.jit(self._sample_batch)
         self._one_active = jnp.ones((1,), bool)   # _sample_one's mask
+        jax.block_until_ready((self.cache, self._keys, self._last))
+        # Seconds of this constructor, for the `llm:init` span of
+        # whoever builds the engine (``LLMServer``): the weights to the
+        # device, then slabs, states and counters on it.
+        self.init_s = {"weights": t_cache - t_init,
+                       "cache": time.perf_counter() - t_cache}
 
     def _shard_state(self):
         """Distribute params and KV slabs over the engine's mesh: params

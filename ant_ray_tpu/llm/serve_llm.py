@@ -27,6 +27,13 @@ from ant_ray_tpu.llm.engine import PREFILL_CHUNK_TOKENS, EngineLoop, LLMEngine
 from ant_ray_tpu.llm.sampling import SamplingParams
 
 
+def _tree_bytes(tree) -> int:
+    import jax  # noqa: PLC0415 — the engine imported it
+
+    return sum(getattr(leaf, "nbytes", 0)
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
 class LLMServer:
     """Replica class: one engine + one background engine loop."""
 
@@ -39,21 +46,41 @@ class LLMServer:
                  kv_offload="auto"):
         from ant_ray_tpu._private.jax_utils import require_tpu  # noqa: PLC0415
         from ant_ray_tpu.llm.tokenizer import get_tokenizer  # noqa: PLC0415
+        from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
 
-        # No fallback on the chip path: unless this process is pinned
-        # to the CPU backend (it leased no chip, or the whole tree is
-        # pinned from outside), the engine runs on a TPU or not at all.
-        self._device = require_tpu("LLM replica")
-        store = self._resolve_store(kv_offload)
-        self.engine = LLMEngine(
-            model, slots=slots, max_seq=max_seq,
-            tokenizer=get_tokenizer(tokenizer_name), seed=seed,
-            tensor_parallel_size=tensor_parallel_size,
-            max_waiting=max_waiting,
-            prefill_chunk_tokens=prefill_chunk_tokens,
-            kv_idle_evict_s=kv_idle_evict_s,
-            kv_offload_store=store)
-        self._loop = EngineLoop(self.engine, max_waiting=max_waiting)
+        # `llm:init`: this constructor as one forced span of the
+        # start-up trace that created the replica (a child of the
+        # worker's `actor:init`; alone, a root), its stages the laps
+        # below; what it compiles hangs under it.
+        with tracing_plane.staged_span("llm:init") as sp:
+            # No fallback on the chip path: unless this process is
+            # pinned to the CPU backend (it leased no chip, or the whole
+            # tree is pinned from outside), the engine runs on a TPU or
+            # not at all.
+            self._device = require_tpu("LLM replica")
+            sp.lap("device_open")       # jax import, backend, devices()
+            store = self._resolve_store(kv_offload)
+            tokenizer = get_tokenizer(tokenizer_name)
+            t_engine = sp.lap("tokenizer")
+            self.engine = LLMEngine(
+                model, slots=slots, max_seq=max_seq,
+                tokenizer=tokenizer, seed=seed,
+                tensor_parallel_size=tensor_parallel_size,
+                max_waiting=max_waiting,
+                prefill_chunk_tokens=prefill_chunk_tokens,
+                kv_idle_evict_s=kv_idle_evict_s,
+                kv_offload_store=store)
+            # the engine waited for both on the device
+            sp.lap("weights", t_engine + self.engine.init_s["weights"])
+            sp.lap("cache")
+            self._loop = EngineLoop(self.engine, max_waiting=max_waiting)
+            sp.attrs.update(
+                platform=self._device.platform,
+                device_kind=self._device.device_kind,
+                param_bytes=_tree_bytes(self.engine.params),
+                cache_bytes=_tree_bytes(self.engine.cache),
+                slots=self.engine.slots, max_seq=self.engine.max_seq)
+            sp.lap("loop")
 
     @staticmethod
     def _resolve_store(kv_offload):

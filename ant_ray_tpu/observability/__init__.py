@@ -36,7 +36,15 @@ substrate:
   propagated through request metadata, per-process flight recorders
   (force-sampled error rings), the GCS span ring behind
   ``GET /api/trace/{id}``, and ``art_rpc_latency_s`` histograms with
-  trace-id exemplars.
+  trace-id exemplars.  ``serve.run`` and ``JaxTrainer.fit`` leave their
+  own START-UP on it as one trace of forced spans
+  (``tracing_plane.staged_span``; `serve:run` / `train:fit` →
+  `actor:create` → `worker:spawn` → `worker:boot` → `actor:init` →
+  `llm:init` / `train:worker_init`).
+* ``compile_watch.py`` — the process's one ``jax.monitoring`` listener
+  (installed by ``jax_utils.import_jax()``): every compilation a forced
+  `jit:compile` span with its program's name and the persistent cache's
+  verdict, marked ``after_ready`` once the engine has completed a step.
 """
 
 from ant_ray_tpu.observability import tracing_plane

@@ -15,8 +15,9 @@ answer built native to our wire protocol:
 * spans land in a per-process **flight recorder**: two bounded
   GIL-atomic rings (``collections.deque`` appends — no lock on the hot
   path), one for head-sampled spans and a separate one for force-sampled
-  error/shed spans so a wrapping ring can never evict the evidence of a
-  failure;
+  spans — errors and sheds, and what ``forced=True`` keeps without
+  calling it a failure (start-up, compilations, stalls) — so a wrapping
+  ring can never evict the evidence of a failure;
 * sampled spans batch-publish best-effort to the GCS ``SpanEventsAdd``
   ring (the step-events idiom: oneway, dropped outside a cluster), where
   ``GET /api/trace/{trace_id}``, the Perfetto timeline and the OTLP
@@ -288,21 +289,25 @@ def _runtime():
 
 def record_span(ctx, name: str, *, ts: float, dur_s: float,
                 stages: dict | None = None, attrs: dict | None = None,
-                error: bool = False, span_id: str | None = None,
+                error: bool = False, forced: bool = False,
+                span_id: str | None = None,
                 parent_id: str | None = None,
                 service: str = "") -> str | None:
     """Record one completed span under ``ctx`` (a TraceContext or wire
-    tuple).  Unsampled contexts record nothing UNLESS ``error`` — error
-    and shed spans are force-sampled into the recorder's protected ring
-    (and still published, so a 429's trace id is findable).  Returns the
-    span id (for callers chaining children explicitly)."""
+    tuple).  Unsampled contexts record nothing UNLESS ``error`` or
+    ``forced`` — error and shed spans, and the spans nobody may lose to
+    the sampling coin (start-up, compilations, stalls: ``forced=True``,
+    which says nothing about failure), are force-sampled into the
+    recorder's protected ring (and still published, so a 429's trace id
+    is findable).  Returns the span id (for callers chaining children
+    explicitly)."""
     if isinstance(ctx, tuple):
         ctx = TraceContext.from_wire(ctx)
     if ctx is None:
         return None
-    forced = error and not ctx.sampled
-    if not ctx.sampled and not error:
+    if not (ctx.sampled or error or forced):
         return None
+    protected = forced or not ctx.sampled
     sid = span_id or f"{random.getrandbits(64):016x}"
     span = {
         "trace_id": ctx.trace_id,
@@ -320,12 +325,81 @@ def record_span(ctx, name: str, *, ts: float, dur_s: float,
         span["attrs"] = attrs
     if error:
         span["error"] = True
-    if forced:
+    if protected:
         span["forced"] = True
     if service:
         span["service"] = service
-    recorder().record(span, forced=forced)
+    recorder().record(span, forced=protected)
     return sid
+
+
+def descend(parent: "TraceContext | None" = None) -> tuple:
+    """``(context, parent span id)`` of a new span under ``parent``
+    (default: the thread's current context); with neither, the root of
+    a freshly minted trace and ``""``."""
+    parent = parent if parent is not None else _current.get()
+    if parent is None:
+        return mint(), ""
+    return parent.child(), parent.span_id
+
+
+def stages_line(dur_s: float, stages: dict, trace_id: str) -> str:
+    """``21.3 s: controller 0.4 | deploy 0.3 | ... (trace <id>)``."""
+    return (f"{dur_s:.1f} s: " + " | ".join(
+        f"{k} {v:.1f}" for k, v in stages.items())
+        + f" (trace {trace_id})")
+
+
+class staged_span:
+    """``with tracing_plane.staged_span("llm:init") as sp:`` — ONE
+    forced span of a start-up path whose ``stages`` are laps of one
+    clock: ``sp.lap("weights")`` closes the stretch since the last lap
+    (or the entry) under that name, so the stages add up to ``dur_s``
+    when the last lap is the block's last statement.  The span is a
+    child of ``ctx`` (default: the thread's current context; with none,
+    a root of a trace of its own) and is the CURRENT context inside the
+    block, so what the block causes — an actor it creates, a program it
+    compiles — hangs under it.  An exception marks it an error."""
+
+    __slots__ = ("name", "ctx", "attrs", "stages", "parent_id", "ts",
+                 "dur_s", "_t0", "_t", "_token")
+
+    def __init__(self, name: str, ctx: "TraceContext | None" = None,
+                 attrs: dict | None = None):
+        self.ctx, self.parent_id = descend(ctx)
+        self.name = name
+        self.attrs = dict(attrs or {})
+        self.stages: dict = {}
+        self.dur_s = 0.0
+
+    def __enter__(self):
+        # artlint: disable=banned-apis — span `ts` is a cross-process
+        # wall-clock wire field
+        self.ts = time.time()
+        self._t0 = self._t = time.perf_counter()
+        self._token = _current.set(self.ctx)
+        return self
+
+    def lap(self, stage: str, now: float | None = None) -> float:
+        """Close the stretch since the last lap as ``stage``, at the
+        ``perf_counter`` reading ``now`` (default: this instant), which
+        is returned."""
+        now = time.perf_counter() if now is None else now
+        self.stages[stage] = self.stages.get(stage, 0.0) + now - self._t
+        self._t = now
+        return now
+
+    def __exit__(self, exc_type, exc, tb):
+        _current.reset(self._token)
+        self.dur_s = time.perf_counter() - self._t0
+        record_span(self.ctx, self.name, ts=self.ts, dur_s=self.dur_s,
+                    stages=self.stages, attrs=self.attrs, forced=True,
+                    error=exc_type is not None,
+                    span_id=self.ctx.span_id, parent_id=self.parent_id)
+        return False
+
+    def summary(self) -> str:
+        return stages_line(self.dur_s, self.stages, self.ctx.trace_id)
 
 
 class _Noop:

@@ -18,6 +18,7 @@ import collections
 import contextvars
 import functools
 import itertools
+import logging
 import math
 import random
 import threading
@@ -32,6 +33,8 @@ from ant_ray_tpu.exceptions import (
 )
 from ant_ray_tpu.observability import tracing_plane
 from ant_ray_tpu.observability.tracing_plane import TraceContext
+
+logger = logging.getLogger(__name__)
 
 CONTROLLER_NAME = "_serve_controller"
 
@@ -1463,6 +1466,9 @@ class ServeController:
         # deployment's version advances (ref: serve/_private/
         # long_poll.py LongPollHost snapshot ids).
         self._version_cv = threading.Condition(self._lock)
+        # The calling thread's `serve:deploy` span (``.span``), whose
+        # stages ``_make_replicas`` laps.
+        self._deploying = threading.local()
         self._stopping = False
         self._scaler = threading.Thread(
             target=self._scale_loop, daemon=True, name="serve-scaler")
@@ -1543,12 +1549,17 @@ class ServeController:
             replica_cls.remote(deployment.cls_or_fn, args, kwargs, limits)
             for _ in range(n)
         ]
+        span = getattr(self._deploying, "span", None)
+        if span is not None:
+            span.lap("create")
         try:
             # Readiness gate.  ``timeout`` lets retry-loop callers (the
             # drain watcher) bound an unplaceable replica instead of
             # wedging their thread forever.
             art.get([r.health.remote() for r in replicas],
                     timeout=timeout)
+            if span is not None:
+                span.lap("replicas_ready")
         except BaseException:
             # Never leak half-placed replicas: handles aren't reaped on
             # GC, and a retrying caller would compound the leak — worse,
@@ -1562,10 +1573,30 @@ class ServeController:
             raise
         return replicas
 
-    def deploy(self, deployment: Deployment, args, kwargs) -> dict:
-        if self._deployments.get(deployment.name) is not None:
-            return self._rolling_redeploy(deployment, args, kwargs)
-        return self._fresh_deploy(deployment, args, kwargs)
+    def deploy(self, deployment: Deployment, args, kwargs,
+               trace=None) -> dict:
+        """`serve:deploy`: this call as a span of the start-up trace of
+        the `serve:run` that asks (``trace``, its wire context; without
+        one, a trace of its own) — the replicas it creates hang under
+        it.  Stages: ``create`` (to the replicas' creation submitted),
+        ``replicas_ready`` (their readiness gates), ``publish``.  The
+        reply's ``replicas_ready_s`` is that stage, for the asker's
+        own."""
+        with tracing_plane.staged_span(
+                "serve:deploy", TraceContext.from_wire(trace),
+                {"deployment": deployment.name}) as sp:
+            self._deploying.span = sp
+            try:
+                if self._deployments.get(deployment.name) is not None:
+                    reply = self._rolling_redeploy(deployment, args,
+                                                   kwargs)
+                else:
+                    reply = self._fresh_deploy(deployment, args, kwargs)
+            finally:
+                self._deploying.span = None
+            sp.lap("publish")
+        return {**reply,
+                "replicas_ready_s": sp.stages.get("replicas_ready", 0.0)}
 
     def _fresh_deploy(self, deployment: Deployment, args, kwargs) -> dict:
         n = deployment.num_replicas
@@ -2012,25 +2043,37 @@ class ServeController:
             if e["route_prefix"]
         }
 
-    def start_grpc_proxy(self, port: int) -> int:
+    def _start_proxy(self, kind: str, proxy, make, port: int, trace):
+        """`serve:proxy`: an ingress made (the first time) and started,
+        as a span of the asking `serve:run`'s start-up trace (``trace``,
+        as ``deploy``'s) — the proxy's actor hangs under it."""
         art = _art()
-        if getattr(self, "_grpc_proxy", None) is None:
-            proxy_cls = art.remote(GrpcProxy).options(
-                max_concurrency=32, num_cpus=0)
-            controller = art.get_actor(CONTROLLER_NAME,
-                                       namespace="_serve")
-            self._grpc_proxy = proxy_cls.remote(controller)
-        return art.get(self._grpc_proxy.start.remote(port))
+        with tracing_plane.staged_span(
+                "serve:proxy", TraceContext.from_wire(trace),
+                {"kind": kind}) as sp:
+            if proxy is None:
+                proxy = make(art.get_actor(CONTROLLER_NAME,
+                                           namespace="_serve"))
+            bound = art.get(proxy.start.remote(port))
+            sp.attrs["port"] = bound
+            sp.lap("start")
+        return proxy, bound
 
-    def start_http_proxy(self, port: int) -> int:
+    def start_grpc_proxy(self, port: int, trace=None) -> int:
         art = _art()
-        if self._proxy is None:
-            proxy_cls = art.remote(HttpProxy).options(
-                max_concurrency=32, num_cpus=0)
-            controller = art.get_actor(CONTROLLER_NAME,
-                                       namespace="_serve")
-            self._proxy = proxy_cls.remote(controller)
-        return art.get(self._proxy.start.remote(port))
+        self._grpc_proxy, bound = self._start_proxy(
+            "grpc", getattr(self, "_grpc_proxy", None),
+            art.remote(GrpcProxy).options(max_concurrency=32,
+                                          num_cpus=0).remote, port, trace)
+        return bound
+
+    def start_http_proxy(self, port: int, trace=None) -> int:
+        art = _art()
+        self._proxy, bound = self._start_proxy(
+            "http", self._proxy,
+            art.remote(HttpProxy).options(max_concurrency=32,
+                                          num_cpus=0).remote, port, trace)
+        return bound
 
     def shutdown_all(self):
         art = _art()
@@ -2633,23 +2676,55 @@ def run(app: Application, *, port: int | None = None,
     ``grpc_port`` additionally starts the gRPC ingress (0 = ephemeral;
     bound port in ``run.last_grpc_port``)."""
     art = _art()
-    if not art.is_initialized():
-        art.init()
-    controller = _get_or_create_controller()
-    art.get(controller.deploy.remote(app.deployment, app.args, app.kwargs))
-    if port is not None or app.deployment.route_prefix:
-        actual = art.get(controller.start_http_proxy.remote(
-            8000 if port is None else port))
-        run.last_http_port = actual  # discoverable for tests/clients
-    if grpc_port is not None:
-        run.last_grpc_port = art.get(
-            controller.start_grpc_proxy.remote(grpc_port))
-    info = art.get(
-        controller.get_handle_info.remote(app.deployment.name))
-    # The controller reference lets the handle refresh its replica set
-    # (autoscaling) and queue snapshot (po2 routing) on a TTL.
-    return DeploymentHandle(app.deployment.name, info["replicas"],
-                            controller=controller, _info=info)
+    # `serve:run`: the root of this deployment's start-up trace, from
+    # the call to the handle.  What it causes in other processes — an
+    # `actor:create` for the controller, each replica and the proxy,
+    # the daemon's `worker:spawn`, the worker's `worker:boot` and
+    # `actor:init`, a replica's `llm:init`, every `jit:compile` inside
+    # them — is a forced span of the same trace (GET /api/trace/<id>).
+    with tracing_plane.staged_span("serve:run", attrs={
+            "app": app.deployment.name,
+            "deployments": [app.deployment.name]}) as sp:
+        if not art.is_initialized():
+            art.init()
+        controller = _get_or_create_controller()
+        # the controller answers: it is alive
+        art.get(controller.routes.remote())
+        t_deploy = sp.lap("controller")
+        # `serve:submit`: the deploy call's arguments pickled here, in
+        # the driver, and the call sent — the driver's own share of
+        # `deploy` (the first class of a checkout's module a process
+        # pickles costs it seconds: `serialization.
+        # _is_installed_distribution` reads every installed
+        # distribution's metadata).
+        with tracing_plane.staged_span("serve:submit") as submit:
+            ref = controller.deploy.remote(
+                app.deployment, app.args, app.kwargs, sp.ctx.to_wire())
+            submit.lap("serialize")
+        reply = art.get(ref)
+        # What the controller waited for its replicas' readiness, by
+        # its own clock: the rest of the call is ``deploy``.
+        ready_s = reply.get("replicas_ready_s", 0.0)
+        sp.lap("deploy", max(t_deploy, time.perf_counter() - ready_s))
+        sp.lap("replicas_ready")
+        if port is not None or app.deployment.route_prefix:
+            actual = art.get(controller.start_http_proxy.remote(
+                8000 if port is None else port, sp.ctx.to_wire()))
+            run.last_http_port = actual  # discoverable for tests/clients
+        if grpc_port is not None:
+            run.last_grpc_port = art.get(
+                controller.start_grpc_proxy.remote(
+                    grpc_port, sp.ctx.to_wire()))
+        info = art.get(
+            controller.get_handle_info.remote(app.deployment.name))
+        sp.attrs["replicas"] = len(info["replicas"])
+        # The controller reference lets the handle refresh its replica
+        # set (autoscaling) and queue snapshot (po2 routing) on a TTL.
+        handle = DeploymentHandle(app.deployment.name, info["replicas"],
+                                  controller=controller, _info=info)
+        sp.lap("proxy")
+    logger.info("serve.run ready in %s", sp.summary())
+    return handle
 
 
 run.last_http_port = None

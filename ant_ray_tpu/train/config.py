@@ -183,6 +183,10 @@ class Result:
     checkpoint: "object | None"
     error: Exception | None
     path: str
+    # The fit's start-up as the controller saw it, stage → seconds from
+    # ``fit()``'s call to the first ``report`` (the `train:fit` span's
+    # stages; empty from a controller that was handed no trace).
+    startup: dict = dataclasses.field(default_factory=dict)
 
     @property
     def best_checkpoint(self):

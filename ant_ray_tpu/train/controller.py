@@ -17,6 +17,7 @@ import os
 import threading
 import time
 
+from ant_ray_tpu.observability import tracing_plane
 from ant_ray_tpu.train.checkpoint import (
     Checkpoint,
     CheckpointManager,
@@ -55,6 +56,11 @@ class TrainWorker:
         self._experiment_name = experiment_name
         self._use_tpu = use_tpu
         self._num_slices = num_slices
+        # The start-up trace this rank's actor was created in (the
+        # constructor runs under its `actor:init`): `train:worker_init`
+        # is recorded under it.  None outside one.
+        self._trace = tracing_plane.current()
+        self._dist = None     # (wall, perf_counter, seconds) of the rendezvous
 
     def propose_coordinator(self) -> str:
         """Rank 0 advertises host:port for the jax.distributed
@@ -75,6 +81,15 @@ class TrainWorker:
         train/v2/jax/config.py:30,73).  A gang that cannot rendezvous
         fails here: ranks that went on single-process would each train
         alone and report success."""
+        # artlint: disable=banned-apis — `train:worker_init`'s `ts`: a
+        # cross-process wall-clock wire field
+        wall, t0 = time.time(), time.perf_counter()
+        try:
+            return self._rendezvous(coordinator)
+        finally:
+            self._dist = (wall, t0, time.perf_counter() - t0)
+
+    def _rendezvous(self, coordinator: str | None) -> bool:
         if not self._use_tpu or self._world_size == 1 or coordinator is None:
             return False
         from ant_ray_tpu._private.jax_utils import import_jax  # noqa: PLC0415
@@ -88,6 +103,29 @@ class TrainWorker:
                 f"jax.distributed joined {jax.process_count()} processes, "
                 f"the gang has {self._world_size}")
         return True
+
+    def _record_init(self, run_t: float, device) -> None:
+        """`train:worker_init`: this rank from its rendezvous task's
+        start to the user's loop entered.  Stages:
+        ``distributed_init`` (``setup_distributed``: jax import and
+        ``jax.distributed.initialize`` on a multi-process TPU gang, else
+        nothing), ``run_dispatch`` (the controller between the two
+        tasks: checkpoints flushed, dataset shards made, ``run`` sent),
+        ``device_open`` (``require_tpu``: jax import, backend, first
+        ``jax.devices()``; a rank without chips opens none)."""
+        if self._trace is None or self._dist is None:
+            return
+        wall, t0, dist_s = self._dist
+        now = time.perf_counter()
+        stages = {"distributed_init": dist_s,
+                  "run_dispatch": max(0.0, run_t - t0 - dist_s),
+                  "device_open": now - run_t}
+        tracing_plane.record_span(
+            self._trace, "train:worker_init", ts=wall,
+            dur_s=sum(stages.values()), stages=stages, forced=True,
+            attrs={"rank": self._rank,
+                   "platform": getattr(device, "platform", None),
+                   "device_kind": getattr(device, "device_kind", None)})
 
     def run(self, loop_fn, loop_config, controller, latest_checkpoint,
             attempt: int = 0, dataset_shards: dict | None = None):
@@ -116,13 +154,16 @@ class TrainWorker:
             dataset_shards=dataset_shards or {},
         )
         _set_context(ctx)
+        run_t = time.perf_counter()
         try:
+            device = None
             if self._use_tpu:
                 # No fallback on the chip path: this rank leased chips,
                 # so it runs on them or not at all.
                 from ant_ray_tpu._private.jax_utils import require_tpu  # noqa: PLC0415
 
-                require_tpu(f"Train worker rank {self._rank}")
+                device = require_tpu(f"Train worker rank {self._rank}")
+            self._record_init(run_t, device)
             if loop_config is None:
                 return loop_fn()
             return loop_fn(loop_config)
@@ -143,7 +184,14 @@ class TrainController:
     def __init__(self, loop_fn, loop_config, scaling: ScalingConfig,
                  run_config: RunConfig, resume: bool = False,
                  run_token: str | None = None, datasets: dict | None = None,
-                 data_config=None):
+                 data_config=None, trace: tuple | None = None):
+        # ``trace``: (wire context of the `train:fit` that asks, the
+        # wall clock of its call).  The gang is created under that
+        # context, and ``_startup`` keeps the wall clock at which each
+        # stage of the start-up ENDED, first launch only, until the
+        # first report closes it (``Result.startup``).
+        self._trace, called = trace or (None, None)
+        self._startup = None if called is None else {"": called}
         self._loop_fn = loop_fn
         self._loop_config = loop_config
         self._scaling = scaling
@@ -177,7 +225,28 @@ class TrainController:
 
     # ---- called by workers (concurrently with run())
 
+    def _startup_mark(self, stage: str) -> None:
+        """``stage`` of the start-up ended now — the first time only,
+        and only until the first report."""
+        marks = self._startup
+        if marks is not None and "first_report" not in marks:
+            # artlint: disable=banned-apis — the stages begin at the
+            # driver's wall clock (``fit()``'s call)
+            marks.setdefault(stage, time.time())
+
+    def _startup_stages(self) -> dict:
+        marks = dict(self._startup or {})
+        if not marks:
+            return {}
+        # a fit that never reported: its start-up ends with it
+        # artlint: disable=banned-apis — as ``_startup_mark``
+        marks.setdefault("first_report", time.time())
+        walls = list(marks.values())
+        return {stage: max(0.0, end - begin) for stage, begin, end
+                in zip(list(marks)[1:], walls, walls[1:])}
+
     def report_from_worker(self, rank: int, metrics: dict, checkpoint):
+        self._startup_mark("first_report")
         step_record = metrics.pop("_step_record", None)
         with self._lock:
             if step_record is not None:
@@ -392,10 +461,18 @@ class TrainController:
     # ---- control loop
 
     def run(self, self_handle):
+        # Every actor the run creates is a span of the fit's start-up
+        # trace (`actor:create` under `train:fit`).
+        with tracing_plane.use(
+                tracing_plane.TraceContext.from_wire(self._trace)):
+            return self._run(self_handle)
+
+    def _run(self, self_handle):
         import ant_ray_tpu as art  # noqa: PLC0415
 
         from ant_ray_tpu.train.scaling_policy import policy_for  # noqa: PLC0415
 
+        self._startup_mark("controller")
         policy = policy_for(self._scaling)
         failure_config: FailureConfig = self._run_config.failure_config
         attempts = failure_config.max_failures + 1
@@ -473,6 +550,7 @@ class TrainController:
         pg, slice_pg = self._reserve_gang(scaling, world)
         self._worker_pg = pg          # set BEFORE anything can fail, so
         self._worker_slice = slice_pg  # the finally always releases it
+        self._startup_mark("placement_group")
         workers = []
         drain_watch_stop = threading.Event()
         try:
@@ -503,6 +581,12 @@ class TrainController:
                          getattr(scaling, "num_slices", 1))
                 for rank in range(world)
             ]
+            if self._startup is not None and \
+                    "workers" not in self._startup:
+                # The start-up's `workers` stage ends with every rank's
+                # actor alive; the rendezvous is `backend`'s.
+                art.get([w.ping.remote() for w in workers])
+                self._startup_mark("workers")
             # Rendezvous: rank 0's host coordinates (multi-host slices).
             coordinator = None
             if scaling.use_tpu and world > 1:
@@ -521,6 +605,7 @@ class TrainController:
                              self_handle, latest, attempt, shards[rank])
                 for rank, w in enumerate(workers)
             ]
+            self._startup_mark("backend")
             # Preemption watcher: a drain notice on any node hosting a
             # gang worker flips _drain_stop, which the report acks
             # relay to every rank (see session.report).
@@ -769,4 +854,5 @@ class TrainController:
             checkpoint=self._ckpt_manager.latest,
             error=error,
             path=self._storage_path,
+            startup=self._startup_stages(),
         )

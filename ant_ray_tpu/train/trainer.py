@@ -5,6 +5,7 @@
 from __future__ import annotations
 
 import logging
+import time
 from typing import Any, Callable
 
 from ant_ray_tpu.train.config import Result, RunConfig, ScalingConfig
@@ -113,11 +114,26 @@ class JaxTrainer:
         import uuid as _uuid  # noqa: PLC0415
 
         run_token = _uuid.uuid4().hex
+        # `train:fit`: the root of this fit's start-up trace, from the
+        # call to the first ``report`` the controller received.  The
+        # controller and, through it, every rank's actor are created
+        # under it (`actor:create` → `worker:spawn` → `worker:boot` →
+        # `actor:init`; a rank's `train:worker_init`; the `jit:compile`
+        # spans of a rank hang under the trace of its process).  The
+        # stages are the controller's, handed back in the Result.
+        from ant_ray_tpu.observability import tracing_plane  # noqa: PLC0415
+
+        fit_ctx, parent_id = tracing_plane.descend()
+        # artlint: disable=banned-apis — span `ts` is a cross-process
+        # wall-clock wire field, and the controller's stages start here
+        called = time.time()
         for attempt in range(retries + 1):
-            controller = controller_cls.remote(
-                self._loop, self._loop_config, self._scaling,
-                self._run_config, attempt > 0, run_token,
-                self._datasets, self._dataset_config)
+            with tracing_plane.use(fit_ctx):
+                controller = controller_cls.remote(
+                    self._loop, self._loop_config, self._scaling,
+                    self._run_config, attempt > 0, run_token,
+                    self._datasets, self._dataset_config,
+                    (fit_ctx.to_wire(), called))
             try:
                 result: Result = art.get(
                     controller.run.remote(controller), timeout=None)
@@ -144,6 +160,19 @@ class JaxTrainer:
                     art.kill(controller)
                 except Exception:  # noqa: BLE001
                     pass
+        if result.startup:
+            dur = sum(result.startup.values())
+            tracing_plane.record_span(
+                fit_ctx, "train:fit", ts=called, dur_s=dur,
+                stages=result.startup, forced=True,
+                attrs={"workers": self._scaling.num_workers,
+                       "mesh": self._scaling.topology,
+                       "chips_per_worker":
+                           self._scaling.worker_resources().get("TPU", 0)},
+                span_id=fit_ctx.span_id, parent_id=parent_id)
+            logger.info("fit reached its first report in %s",
+                        tracing_plane.stages_line(dur, result.startup,
+                                                  fit_ctx.trace_id))
         if result.error is not None:
             raise result.error
         return result

@@ -974,7 +974,8 @@ def test_a_step_that_keeps_rows_standing_still_leaves_one_forced_span(
         return
     (stall,) = [s for s in _stalls() if s not in before]
     attrs = stall["attrs"]
-    assert stall["forced"] is True and stall["error"] is True
+    # kept whatever the sampling coin says, and no failure (PR 52)
+    assert stall["forced"] is True and "error" not in stall
     assert attrs["phase"] == where and attrs["step"] == slept[0]
     assert sleep_s <= attrs["phase_s"] <= stall["dur_s"] < sleep_s + 0.2
     assert attrs["rows"] == 1          # the one row the read kept waiting
