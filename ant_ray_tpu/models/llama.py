@@ -26,7 +26,8 @@ from jax import lax
 from ant_ray_tpu.ops import delta_rule, ssd
 from ant_ray_tpu.ops.attention import attention
 from ant_ray_tpu.ops.layernorm import layernorm
-from ant_ray_tpu.ops.pallas import decode_attention, grouped_matmul
+from ant_ray_tpu.ops.pallas import (decode_attention, gather_sum,
+                                    grouped_matmul)
 from ant_ray_tpu.ops.rmsnorm import rmsnorm
 from ant_ray_tpu.ops.rope import (
     YarnScaling,
@@ -1147,10 +1148,17 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
     rows lie together, and a GROUPED PRODUCT multiplies each run of rows
     with its expert's matrix: its operations are those of k experts a
     token, and it reads an expert's weights only if it has a row.  The
-    rows then go back to token order and are summed under their float32
-    gates.  Shapes are static (tokens * k rows whatever the routing), so
-    one formulation serves the training step, a prefill chunk and a
-    decode step.
+    way back to token order is ONE pass over the down product's float32
+    rows: each token gathers the k rows that are its own, a held one
+    under its float32 gate and one of no group as zero, and adds them
+    in the picks' order — the (tokens * k, dim) array is read once and
+    never written again in token order.  ``_back_to_tokens`` is that
+    equation in plain XLA; where the grouped product is the Pallas
+    kernel, so is the way back (``ops/pallas/gather_sum.py``: the same
+    select, gates, k adds and rounding in one call, where XLA leaves 2 k
+    small operations a layer).  Shapes are static (tokens * k rows
+    whatever the routing), so one formulation serves the training step,
+    a prefill chunk and a decode step.
 
     Which grouped product runs where (bf16 operands, float32 sums and
     float32 out in both; the same mathematics):
@@ -1175,7 +1183,9 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
     to an absent expert sorts behind the last group, belongs to no
     group — the grouped product's sizes add up to the assignments held,
     so it neither reads a weight nor multiplies for it — and counts
-    zero in the sum.  Nothing stands in for the absent experts.
+    zero in the sum, by a select on its row's place (the product's
+    output holds there whatever the buffer held).  Nothing stands in
+    for the absent experts.
 
     ``layer``'s expert matrices are one layer's (experts, in, out), as
     a scan over the stacked layers slices them — or, with ``index``,
@@ -1228,14 +1238,37 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
 
         gated = jax.nn.silu(grouped(rows, layer["w_gate"])) * grouped(
             rows, layer["w_up"])
-        out = grouped(gated.astype(h.dtype), layer["w_down"])
-        if share:
-            # a row of no group is whatever the product left there
-            out = jnp.where(
-                (jnp.arange(out.shape[0]) < jnp.sum(load))[:, None], out, 0.0)
-        out = out[jnp.argsort(order)].reshape(-1, k, dim)  # token order
-        out = jnp.sum(out * gates[..., None], axis=1)
-    return out.astype(h.dtype).reshape(*lead, dim), load
+        down = grouped(gated.astype(h.dtype), layer["w_down"])
+        # token t's pick j is sorted row back[t, j], held if below held
+        back, held = jnp.argsort(order).reshape(-1, k), jnp.sum(load)
+        if tile:
+            out = gather_sum.gather_sum(
+                down, back, gates, held, dtype=h.dtype,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            out = _back_to_tokens(down, back, gates, held, h.dtype)
+    return out.reshape(*lead, dim), load
+
+
+def _back_to_tokens(down, back, gates, held, dtype):
+    """The grouped down product's rows ``down`` (tokens * k, dim)
+    float32, sorted by expert, back in token order under their gates:
+    ``sum_j select(back[t, j] < held, down[back[t, j]], 0) * gates[t,
+    j]`` -> (tokens, dim) ``dtype``.  A row from ``held`` on belongs to
+    no group and is whatever the product left there, a NaN too: a SELECT
+    drops it, before the gate (a product with 0 would keep the NaN, and
+    so would the gate's gradient).  The k terms are added in float32 in
+    the picks' order, spelt as k adds — a sum over an axis is added in
+    an order the compiler takes from the operand's shape, and a row's
+    bits must not depend on its company (PERF.md section 6, PR 39, PR
+    53)."""
+    out = None
+    for j in range(back.shape[1]):
+        rows = back[:, j]
+        term = jnp.where((rows < held)[:, None], down[rows],
+                         0.0) * gates[:, j, None]
+        out = term if out is None else out + term
+    return out.astype(dtype)
 
 
 def _rope_tables(c: LlamaConfig, positions: int | None = None):

@@ -47,6 +47,7 @@ _COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$")
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\w+\[[\d,]*\]\{[^}]*\}) ([\w-]+)\(")
 _ARRAY = re.compile(r"(\w+)\[([\d,]*)\]\{([^}]*)\}")
+_SCOPE = re.compile(r'op_name="([^"]*)"')
 _BOUND = re.compile(r"= s32\[\][^ ]* constant\((\d+)\)")
 
 
@@ -97,13 +98,18 @@ def slab_buffers(config, rows: int, max_seq: int) -> dict:
     return names
 
 
-def materialised(text: str, weights: dict) -> list:
+def materialised(text: str, weights) -> list:
     """The operations of the compiled program ``text`` that WRITE a
-    buffer told as one of ``weights`` (``layer_weights``) -> dicts of
-    ``runs`` (the enclosing loops' trip counts multiplied), ``name``,
-    ``op``, ``shape``, ``layout``, ``weights`` and ``mib``.  What sits
-    inside a fusion's computation writes nothing: a product's fusion
-    that slices the stack reads it in place."""
+    buffer told as one of ``weights`` (``layer_weights``; or a function
+    of a buffer's element type and dimensions that names it, or not) ->
+    dicts of ``runs`` (the enclosing loops' trip counts multiplied),
+    ``name``, ``op``, ``scope`` (the named scopes it was traced under,
+    empty for an operation of the compiler's own), ``shape``, ``layout``,
+    ``weights`` and ``mib``.
+    What sits inside a fusion's computation writes nothing: a product's
+    fusion that slices the stack reads it in place."""
+    told = weights if callable(weights) else (
+        lambda dtype, dims: weights.get(_key(dtype, dims)))
     inside, found, callers, loops, bounds = None, [], {}, {}, {}
     widths = dict(_XLA.values())
     fused = set(re.findall(r"kind=\w+, calls=%([^\s,)]+)", text))
@@ -130,10 +136,12 @@ def materialised(text: str, weights: dict) -> list:
         for dtype, dims, layout in _ARRAY.findall(result)[
                 :1 if op in ASYNC else None]:
             dims = tuple(int(d) for d in dims.split(",") if d)
-            held = weights.get(_key(dtype, dims))
+            held = told(dtype, dims)
             if held:
+                scope = _SCOPE.search(line)
                 found.append({
                     "inside": inside, "name": name, "op": op,
+                    "scope": scope.group(1) if scope else "",
                     "shape": f"{dtype}[{','.join(map(str, dims))}]",
                     "layout": "{" + layout + "}",
                     "weights": sorted(set(held)),

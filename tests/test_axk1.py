@@ -16,6 +16,7 @@ un-rotated, gates not rescaled by 2.5 (0.3).
 """
 
 import dataclasses
+import functools
 import json
 import os
 import time
@@ -252,8 +253,10 @@ def routed_mlp_as_it_was(layer, h, c, index=None):
     gated = jax.nn.silu(grouped(rows, layer["w_gate"])) * grouped(
         rows, layer["w_up"])
     out = grouped(gated.astype(h.dtype), layer["w_down"])
-    out = out[jnp.argsort(order)].reshape(-1, k, dim)
-    out = jnp.sum(out * gates[..., None], axis=1)
+    out = out[jnp.argsort(order)].reshape(-1, k, dim) * gates[..., None]
+    # the picks' terms in their order (PR 53: a sum over the axis is
+    # added in an order the compiler takes from the shape)
+    out = functools.reduce(jnp.add, [out[:, j] for j in range(k)])
     return out.astype(h.dtype).reshape(*lead, dim), load
 
 

@@ -190,6 +190,41 @@ def test_routed_mlp_through_the_kernel_is_what_ragged_dot_gives(name, rows):
     assert np.isfinite(np.asarray(got[0])).all()
 
 
+@pytest.mark.parametrize("name", ["axk1-tiny", "cmdaplus-tiny",
+                                  "granite-h-tiny"])
+def test_rows_of_no_group_may_hold_anything(name, monkeypatch):
+    """A share's rows behind the last group are never visited: the
+    kernel's output holds there whatever the buffer held.  With every
+    such row of all three products set to NaN the routed feed-forward is
+    what XLA's product gives — the way back to token order drops them
+    by a select, not by a product with 0."""
+    kernel = gm.grouped_matmul
+
+    def poisoned(lhs, rhs, sizes, layer=0, **how):
+        out = kernel(lhs, rhs, sizes, layer, **how)
+        return jnp.where((jnp.arange(out.shape[0]) < jnp.sum(sizes))[:, None],
+                         out, jnp.nan)
+
+    cfg = llama.CONFIGS[name].stacks()["layers"]
+    stack = llama.init_params(llama.CONFIGS[name],
+                              jax.random.PRNGKey(3))["layers"]
+    layer = {**{k: v[1] * 8 for k, v in stack.items()},
+             **{k: stack[k] * 8 for k in ("w_gate", "w_up", "w_down")}}
+    h = jax.random.normal(jax.random.PRNGKey(4), (48, cfg.dim))
+
+    def run(tile):
+        return jax.jit(lambda i: llama._routed_mlp(layer, h, cfg, i, tile))(
+            jnp.int32(1))
+
+    want = run(0)
+    monkeypatch.setattr(gm, "grouped_matmul", poisoned)
+    got = run(16)
+    assert int(jnp.sum(got[1])) < 48 * cfg.experts_per_token   # some absent
+    assert np.isfinite(np.asarray(got[0])).all()
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-6)
+
+
 @pytest.mark.parametrize("name", ["olmoe-tiny", "axk1-tiny",
                                   "cmdaplus-tiny"])
 def test_step_programs_through_the_kernel(name, monkeypatch):

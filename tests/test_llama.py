@@ -2,6 +2,7 @@
 a short training run that actually learns."""
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -178,7 +179,9 @@ def _per_token_loop(layer, h, cfg):
         gates = probs[chosen]
         if cfg.norm_topk_prob:
             gates = gates / gates.sum()
-        for e, g in zip(chosen, gates):
+        for e, g in zip(chosen - cfg.first_expert, gates):
+            if not 0 <= e < cfg.num_experts:
+                continue        # a share: held elsewhere, counts nothing
             a = x @ w["w_gate"][e]
             out[t] += g * ((a / (1 + np.exp(-a)) * (x @ w["w_up"][e]))
                            @ w["w_down"][e])
@@ -237,6 +240,162 @@ def test_routed_mlp_equals_the_per_token_loop(name):
         llama._routed_mlp(l, h, cfg)[0] ** 2))(layer)
     hit = np.asarray(jnp.abs(grads["w_down"]).sum(axis=(1, 2)) > 0)
     np.testing.assert_array_equal(hit, want_load > 0)
+
+
+def _wide_case(k, share):
+    """(config, one layer's weights, 40 tokens) of a router as wide as
+    the serving cells': ``k`` of 16 experts a token, all held — or,
+    ``share``, experts 4-9 of them."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        llama.CONFIGS["olmoe-tiny"], experts_per_token=k,
+        **(dict(num_experts=6, router_width=16, first_expert=4) if share
+           else dict(num_experts=16)))
+    params = llama.init_params(cfg, jax.random.PRNGKey(5))
+    layer = {name: leaf[0] * 8 for name, leaf in params["layers"].items()}
+    return cfg, layer, jax.random.normal(jax.random.PRNGKey(6),
+                                         (40, cfg.dim))
+
+
+@pytest.mark.parametrize("share", [False, True], ids=["all-held", "a-share"])
+@pytest.mark.parametrize("k", [8, 10])
+def test_way_back_sums_a_tokens_own_rows(k, share):
+    """Eight and ten picks a token (the serving cells'), every expert
+    held and a share whose other picks sort behind the last group:
+    ``_routed_mlp`` against the loop over each token's held experts."""
+    cfg, layer, h = _wide_case(k, share)
+    got, load = jax.jit(lambda l, x: llama._routed_mlp(l, x, cfg))(layer, h)
+    want, want_load = _per_token_loop(layer, h, cfg)
+    np.testing.assert_array_equal(np.asarray(load), want_load)
+    assert (int(load.sum()) < 40 * k) == share
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+
+
+def _way_back_case(k, tokens, dim=128):
+    """(rows sorted by "expert", back, gates, held) of ``tokens`` tokens
+    of which the FIRST is the same in every case — its k rows, its
+    gates, which of its picks are held — wherever the sort put them;
+    the rows from ``held`` on are NaN."""
+    rng = np.random.default_rng(53)
+    mine, gate = rng.standard_normal((k, dim)), rng.random(k)
+    keep = np.arange(k) % 3 != 1                # the first token's held picks
+    rng = np.random.default_rng(tokens)
+    held = int(keep.sum()) + (tokens - 1) * k // 2
+    first = np.empty(k, np.int64)
+    first[keep] = rng.permutation(held)[:keep.sum()]
+    first[~keep] = held + rng.permutation(tokens * k - held)[:k - keep.sum()]
+    others = rng.permutation(np.setdiff1d(np.arange(tokens * k), first))
+    back = np.concatenate([first, others]).reshape(tokens, k)
+    down = rng.standard_normal((tokens * k, dim))
+    down[first] = mine
+    down[held:] = np.nan
+    gates = rng.random((tokens, k))
+    gates[0] = gate
+    return (jnp.asarray(down, jnp.float32), jnp.asarray(back, jnp.int32),
+            jnp.asarray(gates, jnp.float32), jnp.int32(held), keep)
+
+
+def _way_back_kernel(down, back, gates, held, dtype, columns=None):
+    from ant_ray_tpu.ops.pallas import gather_sum
+
+    return gather_sum.gather_sum(down, back, gates, held, dtype=dtype,
+                                 columns=columns, interpret=True)
+
+
+# the way back's two forms: the plain one (training, any backend, a
+# mesh) and the step programs' kernel on one TPU device, interpreted
+# here — whole and in panels of 128 columns
+WAYS_BACK = {
+    "plain": jax.jit(llama._back_to_tokens, static_argnums=4),
+    "kernel": _way_back_kernel,
+    "kernel-in-panels": functools.partial(_way_back_kernel, columns=128),
+}
+
+
+@pytest.mark.parametrize("form", WAYS_BACK)
+@pytest.mark.parametrize("k", [8, 10])
+def test_way_back_drops_a_row_of_no_group_by_a_select(k, form):
+    """The rows behind the last group are whatever the grouped product
+    left there — NaN here.  They count zero by a SELECT: the sum is
+    that of the held rows alone, and neither a NaN nor its gradient
+    (the gate's is the row itself) gets through."""
+    down, back, gates, held, _ = _way_back_case(k, 48, dim=256)
+    clean = jnp.where(jnp.isnan(down), 0.0, down)
+
+    def total(down, gates):
+        return jnp.sum(llama._back_to_tokens(down, back, gates, held,
+                                             jnp.float32) ** 2)
+
+    got = WAYS_BACK[form](down, back, gates, held, jnp.float32)
+    want = sum(np.where((np.asarray(back)[:, j] < int(held))[:, None],
+                        np.asarray(clean, np.float64)[np.asarray(back)[:, j]],
+                        0.0) * np.asarray(gates, np.float64)[:, j, None]
+               for j in range(k))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+    if form != "plain":
+        return                  # the step programs' kernel: no gradient
+    poisoned = jax.grad(total, argnums=(0, 1))(down, gates)
+    sound = jax.grad(total, argnums=(0, 1))(clean, gates)
+    for a, b in zip(poisoned, sound):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("form", WAYS_BACK)
+@pytest.mark.parametrize("k", [8, 10])
+def test_way_back_gives_a_token_the_same_bits_in_any_company(k, form):
+    """One token's row alone, among 47 and among 511 others (a decode
+    step's rows, a chunk's): the same k rows under the same gates,
+    wherever the sort put them, are the same bits — the k terms are
+    added in the picks' order, not in one the compiler takes from the
+    operand's shape."""
+    rows = []
+    for tokens in (1, 48, 512):
+        down, back, gates, held, keep = _way_back_case(k, tokens, dim=256)
+        np.testing.assert_array_equal(np.asarray(back[0]) < int(held), keep)
+        rows.append(np.asarray(WAYS_BACK[form](
+            down, back, gates, held, jnp.float32))[0])
+    assert np.abs(rows[0]).sum() > 0
+    np.testing.assert_array_equal(rows[1], rows[2])
+    if form == "plain":
+        np.testing.assert_array_equal(rows[0], rows[1])
+    else:
+        # interpreted on the CPU a lone tile is no loop, and XLA's CPU
+        # compiler contracts its multiply-adds as it likes: the chip's
+        # proof is benchmarks/mixed_step_bits and routed_way_back
+        np.testing.assert_allclose(rows[0], rows[1], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["moe-tiny", "olmoe-tiny", "axk1-tiny",
+                                  "granite-h-tiny"])
+def test_loss_gradients_through_the_way_back(name, monkeypatch):
+    """``loss_fn`` differentiates through the gathers and selects as
+    written: every gradient finite and that of the form it replaced."""
+    cfg = llama.CONFIGS[name]
+    params = llama.init_params(cfg, jax.random.PRNGKey(1))
+    batch = {"tokens": _tokens(1, 33)}
+
+    def grads():
+        return jax.value_and_grad(llama.loss_fn)(params, batch, cfg)
+
+    from benchmarks import routed_way_back
+
+    loss, got = grads()
+    monkeypatch.setattr(llama, "_back_to_tokens",
+                        routed_way_back.four_passes)
+    was_loss, was = grads()
+    np.testing.assert_allclose(float(loss), float(was_loss), rtol=1e-5)
+    flat, was_flat = jax.tree.leaves(got), jax.tree.leaves(was)
+    assert all(np.isfinite(np.asarray(g)).all() for g in flat)
+    routers = [stack["router"] for stack in jax.tree.leaves(
+        got, is_leaf=lambda x: isinstance(x, dict) and "router" in x)
+        if isinstance(stack, dict)]
+    assert routers and all(float(jnp.abs(r).sum()) > 0 for r in routers)
+    for a, b in zip(flat, was_flat):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4 * float(
+                                       jnp.abs(b).max()))
 
 
 def test_pp_loss_matches_dense_loss(tiny_params):

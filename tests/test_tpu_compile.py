@@ -13,6 +13,7 @@ read back).
 
 import dataclasses
 import functools
+import math
 import os
 import re
 
@@ -22,12 +23,13 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from ant_ray_tpu.models import llama
+from ant_ray_tpu.ops.pallas import gather_sum
 from ant_ray_tpu.ops.rope import YarnScaling
 from ant_ray_tpu.ops.pallas.flash_attention import (
     flash_attention_backward,
     flash_attention_fwd_lse,
 )
-from benchmarks import step_weight_copies
+from benchmarks import routed_way_back, step_weight_copies
 
 CFG = llama.CONFIGS["llama3-1b"]
 # (batch, seq, heads, kv_heads, head_dim): Llama-3.2-1B attention at the
@@ -247,7 +249,8 @@ def test_routed_step_reads_the_expert_stack_in_place(
     text = _compile_step(v5e.devices[0], program, config, slots, max_seq,
                          chunk)[0].as_text()
     calls = [line for line in text.splitlines()
-             if "tpu_custom_call" in line and "grouped_matmul" in line]
+             if "tpu_custom_call" in line
+             and line.lstrip().startswith("%grouped_matmul")]
     assert len(calls) >= 3 and "ragged-dot" not in text
     for stack in stacks:                          # the stack, whole
         assert any(stack in line for line in calls)
@@ -478,6 +481,75 @@ def test_state_space_state_fits_the_chip_at_the_cells_size(
     assert need < 13.0 * 2 ** 30
     text = compiled.as_text()
     assert "grouped_matmul" in text and "ragged-dot" not in text
+
+
+# Granite 4.0-H Small's routed block three times (a share of a router
+# of 72, TEN a token: no whole sublane tiles), around its mixes.
+TEN_PICKS = dataclasses.replace(STATE_SPACE, n_layers=3,
+                                layer_kinds=("ssm", "full", "ssm"))
+
+
+@pytest.mark.parametrize("program,config,slots,max_seq,chunk", [
+    pytest.param("prefill_chunk", TEN_PICKS, 48, 6144, 512,
+                 id="ten-picks-512-token-chunk"),
+    pytest.param("decode", TEN_PICKS, 48, 6144, 512, id="ten-picks-48-rows"),
+    pytest.param("prefill_chunk", MIXED, 16, 8192, 512,
+                 id="eight-picks-512-token-chunk"),
+    pytest.param("decode", LATENT, 48, 512, 64, id="eight-picks-48-rows")])
+def test_routed_rows_go_back_to_token_order_in_one_pass(
+        v5e, monkeypatch, program, config, slots, max_seq, chunk):
+    """Behind the grouped down product a routed layer brings the
+    float32 (tokens x k, width) rows back to token order by reading
+    each token's k rows and writing one (``ops/pallas/gather_sum.py``,
+    the step programs' form of ``llama._back_to_tokens``): no
+    operation of the program's own writes a float32 buffer of tokens x
+    k x width elements again, in any dimensions or layout — not a
+    select over the rows, not a gather of all of them, not a
+    ``reshape`` to (tokens, k, width), which with k = 10 is no bitcast
+    but a re-laying copy (PERF.md section 6, PR 53: four such passes a
+    layer, 80 MiB each in a 512-token chunk of ten picks).  The grouped
+    product's own (its kernel's output, its wrapper's cut of the rows it
+    padded to whole tiles: scope ``jit(grouped_matmul)``) and the
+    compiler's asynchronous staging of that output into the other
+    memory space (``slice-start`` / ``copy-start`` and the custom call
+    that joins them, ``ConcatBitcast``) are not held against the
+    program; nor is what another layer writes at that size by chance
+    (a block of attention scores)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile_step(v5e.devices[0], program, config, slots, max_seq,
+                         chunk)[0].as_text()
+    assert "grouped_matmul" in text and "gather_sum" in text
+    rows = chunk if program == "prefill_chunk" else slots
+    elements = rows * config.experts_per_token * config.dim
+    written = step_weight_copies.materialised(
+        text, lambda dtype, dims: ["rows"] if dtype == "f32" and math.prod(
+            dims) == elements else None)
+    assert [(row["op"], row["name"], row["shape"]) for row in written
+            if row["op"] not in step_weight_copies.ASYNC
+            and "jit(grouped_matmul)" not in row["scope"]
+            and ("/moe/" in row["scope"] or row["op"] != "custom-call"
+                 and not row["scope"])] == []
+
+
+@pytest.mark.parametrize("shape", routed_way_back.SHAPES)
+def test_gather_sum_compiles_for_v5e(v5e, shape):
+    """The way back's kernel alone at the routed cells' shapes — a
+    decode step's rows, the widest chunk or mixed step: one Mosaic
+    call, the rows in whole or in column panels within the chip's
+    VMEM, and no float32 (tokens x k, width) buffer beside its input."""
+    tokens, k, _, _, dim = routed_way_back.SHAPES[shape]
+    rows = jax.ShapeDtypeStruct((tokens * k, dim), jnp.float32)
+    back = jax.ShapeDtypeStruct((tokens, k), jnp.int32)
+    gates = jax.ShapeDtypeStruct((tokens, k), jnp.float32)
+    held = jax.ShapeDtypeStruct((), jnp.int32)
+    text = jax.jit(functools.partial(
+        gather_sum.gather_sum, dtype=jnp.bfloat16)).lower(
+            *_on(v5e.devices[0], (rows, back, gates, held))
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert [row["name"] for row in step_weight_copies.materialised(
+        text, lambda dtype, dims: ["rows"] if dtype == "f32" and math.prod(
+            dims) >= tokens * k * dim else None)] == []
 
 
 # Olmo Hybrid's blocks at their published widths, the benchmark's cut:
