@@ -214,8 +214,9 @@ class LlamaConfig:
     # ``confidence_threshold`` — or, where fewer than ``block_length //
     # denoising_steps`` did, that many of the largest probability — and
     # a block with no mask left takes one more pass, which stores its
-    # keys and values (``decode_step`` with ``store``; the rule itself
-    # is the engine's sampler's, ``LLMEngine._sample_block``).
+    # keys and values (``decode_step`` with ``store``) — alone, or
+    # riding the next block's first step (``closing``); the rule itself
+    # is the engine's sampler's, ``LLMEngine._sample_block``.
     block_length: int = 0
     mask_token: int = 0
     denoising_steps: int = 0
@@ -2387,15 +2388,15 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
     return pos.shape[0], pos, write, attend, state
 
 
-def _block_rows(cache: dict, c: LlamaConfig, active, mesh=None):
-    """What a BLOCK step's rows — ``block_length`` a slot, slot ``r``'s
-    block in flight at positions ``length[r]`` .. ``length[r] +
-    block_length - 1``, slot-major, those of ``active`` slots live — do
-    with a layer, for ``_row_groups``: ``(rows, their positions, write,
-    attend, state)``.  Written then attended, as every group: a place's
-    keys and values lie BEHIND the slot's length until a store pass
-    moves it (``decode_step``), and the next step of the block writes
-    them again.  Every place of a block sees the stored positions and
+def _block_rows(cache: dict, c: LlamaConfig, live, first, mesh=None):
+    """What ``block_length`` rows a slot — slot ``r``'s block at
+    positions ``first[r]`` .. ``first[r] + block_length - 1``,
+    slot-major, those of ``live`` slots live — do with a layer, for
+    ``_row_groups``: ``(rows, their positions, write, attend, state)``.
+    Written then attended, as every group: a place's keys and values lie
+    BEHIND the slot's length until a step moves it over them
+    (``_step_lengths``), and the next step of a block in flight writes
+    them again.  Every place of a block sees the positions before it and
     all of its block, so a slot's places ask ONE question of the slab
     and go through it together: folded among the query heads of their
     KV head — (slots, kv_heads, places x heads a KV head) — they are a
@@ -2404,17 +2405,16 @@ def _block_rows(cache: dict, c: LlamaConfig, active, mesh=None):
     (``_decode_kernel``), and a slot's blocks are read once for all its
     places."""
     size, max_seq = c.block_length, _slab_positions(cache, c)
-    length = cache["length"]
-    n_slots = length.shape[0]
+    n_slots = first.shape[0]
     slots = jnp.repeat(jnp.arange(n_slots), size)
-    pos = (length[:, None] + jnp.arange(size, dtype=jnp.int32)).reshape(-1)
+    pos = (first[:, None] + jnp.arange(size, dtype=jnp.int32)).reshape(-1)
     # a place behind the slab's end is dropped by the scatter, an idle
     # slot's pushed there (``_rows_of``)
-    write_pos = _rows_of(pos, jnp.repeat(active, size), max_seq, max_seq)
-    seen = length + size - 1                     # (slots,) a block's last
-    blocks = _span_blocks(jnp.max(jnp.where(active, seen, 0)) + 1, max_seq)
+    write_pos = _rows_of(pos, jnp.repeat(live, size), max_seq, max_seq)
+    seen = first + size - 1                      # (slots,) a block's last
+    blocks = _span_blocks(jnp.max(jnp.where(live, seen, 0)) + 1, max_seq)
     visits = decode_attention.work_list(
-        seen, active, ATTEND_BLOCK, max_seq) if _decode_kernel(c, mesh) \
+        seen, live, ATTEND_BLOCK, max_seq) if _decode_kernel(c, mesh) \
         else None
     group = c.n_heads // c.n_kv_heads
 
@@ -2450,7 +2450,9 @@ def _row_groups(*groups):
     of a model that generates by diffusion over blocks ``block_length``
     rows a slot (``_block_rows``: a BLOCK step, whose rows bring no
     token each but a block's places, 0 to all of which the step's
-    transfer rule fills) — and a chunk's rows may ride behind it."""
+    transfer rule fills; behind them, where the caller has blocks
+    CLOSING, as many rows a slot again: ``_step_rows``) — and a chunk's
+    rows may ride behind it."""
     edges = [0]
     for rows, *_ in groups:
         edges.append(edges[-1] + rows)
@@ -2574,27 +2576,60 @@ def _stepped(length, active, max_seq: int, by: int = 1):
                      length)
 
 
-def _step_rows(cache: dict, c: LlamaConfig, active, mesh):
-    """A decode step's row group: one row a slot — or, of a model that
-    generates by diffusion over blocks, ``block_length`` rows a slot
-    (``_block_rows``)."""
-    return (_block_rows if c.block_length else _decode_rows)(
-        cache, c, active, mesh)
+def _step_rows(cache: dict, c: LlamaConfig, active, mesh, closing=None):
+    """A decode step's row groups, for ``_row_groups``: one row a slot —
+    or, of a model that generates by diffusion over blocks,
+    ``block_length`` rows a slot (``_block_rows``), the block in flight
+    at the slot's length.  With ``closing`` ((slots,) bool: the slots
+    whose last block's FINAL tokens ride this step, ``decode_step``)
+    there are two such groups: the blocks in flight, one block further
+    on where the slot has a block closing, and behind them the closing
+    blocks at the lengths themselves.  The order of ``_row_groups``
+    gives the mask: both are written, then the closing rows see the
+    stored positions and their own block (nothing of the new one, which
+    lies behind their last place), and the new block's rows the stored
+    positions, the closing block as this step wrote it and all of their
+    own — position ``t`` sees ``s`` iff ``s // block_length <= t //
+    block_length``."""
+    if not c.block_length:
+        return [_decode_rows(cache, c, active, mesh)]
+    length = cache["length"]
+    if closing is None:
+        return [_block_rows(cache, c, active, length, mesh)]
+    return [_block_rows(cache, c, active,
+                        length + c.block_length * closing, mesh),
+            _block_rows(cache, c, active & closing, length, mesh)]
 
 
-def _step_lengths(cache: dict, c: LlamaConfig, active, store):
+def _step_lengths(cache: dict, c: LlamaConfig, active, store, closing=None):
     """The lengths behind a decode step: every ``active`` slot one
-    further — or, of a block step, the slots that ``store`` a whole
-    block further."""
+    further — or, of a block step, the slots that ``store`` the block in
+    flight or have a block ``closing`` a whole block further: ONE block
+    a step (a slot with a block closing and no mask left in flight has
+    nothing in flight: the slab's end)."""
     max_seq = _slab_positions(cache, c)
     if not c.block_length:
         return _stepped(cache["length"], active, max_seq)
+    if closing is not None:
+        store = store | closing
     return _stepped(cache["length"], active & store, max_seq,
                     c.block_length)
 
 
+def _step_tokens(last_tokens, closing, *more):
+    """The tokens of a step's rows in the order of its groups
+    (``_step_rows``): the decode rows', a block step's closing blocks'
+    behind them, then ``more`` (a riding chunk's)."""
+    parts = [last_tokens.reshape(-1)]
+    if closing is not None:
+        parts.append(closing[0].reshape(-1))
+    parts += more
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
 def decode_step(params: dict, last_tokens, cache: dict,
-                config: LlamaConfig, active, *, mesh=None, store=None):
+                config: LlamaConfig, active, *, mesh=None, store=None,
+                closing=None):
     """One token for every slot, attending against the cache.
 
     last_tokens: (slots,) int32 — the most recent token per slot.
@@ -2619,20 +2654,33 @@ def decode_step(params: dict, last_tokens, cache: dict,
     — the store pass of a block with no mask left, decided by the
     caller from the slot's own masks.  A denoise step leaves ``length``
     as it is and its rows are overwritten by the block's next step.
-    """
+
+    ``closing`` (``(tokens (slots, block_length), live (slots,) bool)``)
+    lets a block's store pass RIDE the next block's first step: where
+    ``live`` (and ``active``) the slot's last block, its final
+    ``tokens``, goes through the layers at ``length`` .. ``length +
+    block_length - 1`` in this step too, the block in flight lies one
+    block further on and sees it (``_step_rows``), and the length moves
+    over the closing block.  The closing rows' keys and values are what
+    a store pass of their own writes; they have no logits: the rows
+    normed and multiplied with the head are those of the blocks in
+    flight alone."""
     c = config
-    x = _embed(params, last_tokens.reshape(-1), c)     # (rows, dim)
+    x = _embed(params, _step_tokens(last_tokens, closing), c)  # (rows, dim)
+    live = None if closing is None else closing[1]
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(
-            _step_rows(cache, c, active, mesh)),
+            *_step_rows(cache, c, active, mesh, live)),
         decode=True, mesh=mesh, counted=active)
+    if closing is not None:
+        x = x[:last_tokens.size]
     return _logits(params, x, c), {
-        **written, "length": _step_lengths(cache, c, active, store)}
+        **written, "length": _step_lengths(cache, c, active, store, live)}
 
 
 def mixed_step(params: dict, last_tokens, tokens, cache: dict,
                config: LlamaConfig, active, slot, start, chunk_len, *,
-               mesh=None, store=None):
+               mesh=None, store=None, closing=None):
     """A decode step AND one prompt's chunk as one program: the
     ``slots`` decode rows (``decode_step``'s ``last_tokens`` and
     ``active``) followed by the chunk's rows
@@ -2655,18 +2703,21 @@ def mixed_step(params: dict, last_tokens, tokens, cache: dict,
     all, not among the decode steps (``ROUTING_COUNTERS``).  With no
     row active and ``chunk_len`` 0 at the slot's own length it leaves
     slabs, states and lengths as they are.  ``last_tokens`` (slots,
-    block_length) and ``store``: a block step's, as ``decode_step``
-    takes them.
+    block_length), ``store`` and ``closing``: a block step's, as
+    ``decode_step`` takes them; the closing blocks' rows lie between
+    the blocks in flight and the chunk.
     """
     c = config
     slots = last_tokens.size
+    rows = slots if closing is None else slots + closing[0].size
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
-    x = _embed(params, jnp.concatenate([last_tokens.reshape(-1), tokens]), c)
+    x = _embed(params, _step_tokens(last_tokens, closing, tokens), c)
+    live = None if closing is None else closing[1]
     x, written = _scan_layers(
         params, x, cache, c, *_row_groups(
-            _step_rows(cache, c, active, mesh),
+            *_step_rows(cache, c, active, mesh, live),
             _chunk_rows(cache, c, tokens.shape[0], slot, start, chunk_len)),
         decode=False, mesh=mesh, counted=jnp.concatenate(
             [active, jnp.zeros(tokens.shape, bool)]) if c.exit_gate
@@ -2679,9 +2730,9 @@ def mixed_step(params: dict, last_tokens, tokens, cache: dict,
     # product differs from a row of a many-row product in every logit
     # (1.6e-3, PERF.md section 6, PR 39), and a token must not depend
     # on whether its prompt ended in company.
-    length = _step_lengths(cache, c, active, store)
+    length = _step_lengths(cache, c, active, store, live)
     return (_logits(params, x[:slots], c),
-            _chunk_logits(params, x[slots:], chunk_len, c),
+            _chunk_logits(params, x[rows:], chunk_len, c),
             {**written, "length": length.at[slot].set(start + chunk_len)})
 
 

@@ -13,10 +13,16 @@ then more of their kind) alone on the idle engine, then among 47 sampled
 requests that come and go (so chunks ride the block steps), then alone
 again in the slot it had among them — and, where a pair differs, says
 WHERE: the prompt's stored keys by layer and, of the first layer that
-differs, by position; per block step of the probe's row the program
-that ran it (``decode`` / ``mixed``, as the engine names its calls) and
-how many bits of its four rows' logits differ.  Exit code 1 where a
-reply among the company is not the reply alone.
+differs, by position; per block step of the probe's row whether a chunk
+rode it (``decode`` / ``mixed``: one program either way) and how many
+bits of its four rows' logits differ.  Exit code 1 where a reply among
+the company is not the reply alone.
+
+Since PR 55 a block's store pass rides its successor's first step: what
+a step was FED is the block in flight, its masks, and the block closing
+where the slot has one — all three are recorded and compared, so that a
+closing block's tokens or its timing differing between alone and in
+company would show as ``fed_equal`` false at that step.
 
 As measured (PR 54, PERF.md section 6): with a chunk program, a block
 program and a mixed step of their own (64, 192 and 256 rows) one
@@ -24,7 +30,9 @@ row-layer in about a thousand came out of the mixed step with another
 bfloat16 in ONE element of the residual behind the attention, 7 of 16
 probes differed somewhere and the driver's seed 1551952836 gave probe 2
 another reply; through ONE program (``LLMEngine._block_programs``) 16 of
-16 are bit-equal at every step, layer and position.
+16 are bit-equal at every step, layer and position.  PR 55's 448-row
+program (the closing blocks' rows behind the blocks in flight): PERF.md
+section 6, PR 55.
 """
 
 from __future__ import annotations
@@ -47,9 +55,8 @@ class Watch:
     def __init__(self, eng):
         self.eng, self.seq, self.steps, self.prompt_kv = eng, None, [], None
         self.chunks = []
-        self._decode, self._mixed = eng._decode_jit, eng._mixed_step_jit
-        eng._decode_jit = self._wrap(self._decode, "decode")
-        eng._mixed_step_jit = self._wrap(self._mixed, "mixed")
+        self._mixed = eng._mixed_step_jit
+        eng._mixed_step_jit = self._wrap(self._mixed)
         alone = eng._prefill_chunk_jit
 
         def chunk_alone(params, cache, tokens, slot, start, n):
@@ -73,10 +80,14 @@ class Watch:
                 "tokens": int(n), "rows_beside": len(held),
                 "longest_beside": max(held, default=0)})
 
-    def _wrap(self, program, kind):
-        def run(params, cache, blocks, masked, active, *chunk):
+    def _wrap(self, program):
+        def run(params, cache, blocks, masked, closed, closing, active,
+                *chunk):
             seq, eng = self.seq, self.eng
-            if chunk:
+            # every step is the one program; no chunk is a chunk of no
+            # token in the slot behind the last
+            kind = "mixed" if int(chunk[1]) < eng.slots else "decode"
+            if kind == "mixed":
                 self._chunk(kind, *chunk[1:])
             mine = (seq is not None and seq.slot is not None
                     and eng._active.get(seq.slot) is seq)
@@ -86,8 +97,10 @@ class Watch:
                     n = len(seq.prompt) - len(seq.prompt) % size
                     self.prompt_kv = (cache["k"][:, slot, :n],
                                       cache["v"][:, slot, :n])
-                fed = (blocks[slot], masked[slot], cache["length"][slot])
-            out = program(params, cache, blocks, masked, active, *chunk)
+                fed = (blocks[slot], masked[slot], cache["length"][slot],
+                       closing[slot], closed[slot])
+            out = program(params, cache, blocks, masked, closed, closing,
+                          active, *chunk)
             if mine:
                 self.steps.append((kind, fed,
                                    out[0][slot * size:(slot + 1) * size]))
@@ -197,11 +210,15 @@ def main(argv=None) -> int:
         steps = []
         for j, ((kind, fed, lg), (_, a_fed, a_lg)) in enumerate(
                 zip(one["steps"], two["steps"])):
-            same_fed = all(np.array_equal(x, y)
-                           for x, y in zip(fed[:2], a_fed[:2]))
+            # the block in flight, its masks, whether a block is
+            # closing and, where one is, its tokens
+            same_fed = all(np.array_equal(x, y) for x, y in zip(
+                fed[:2] + fed[3:4 + bool(fed[3])],
+                a_fed[:2] + a_fed[3:4 + bool(a_fed[3])]))
             steps.append({
                 "step": j, "program": kind, "fed_equal": bool(same_fed),
                 "length": int(fed[2]), "masks": int(fed[1].sum()),
+                "closing": bool(fed[3]),
                 "logit_bits": int((_bits(lg) != _bits(a_lg)).sum()),
                 "rel": float(np.linalg.norm(lg - a_lg)
                              / np.linalg.norm(a_lg))})

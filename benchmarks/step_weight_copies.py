@@ -168,7 +168,9 @@ def compile_step(device, program, config, slots, max_seq, chunk):
     ``chunk`` tokens) for one described chip -> (compiled, the shapes of
     the parameters, of the cache).  Of a model that generates by
     diffusion over blocks the decode step is the block step: a slot is
-    fed its whole block, and every active slot stores."""
+    fed its whole block, and every active slot stores; its mixed step
+    is the engine's ONE program, which carries every slot's closing
+    block behind the blocks in flight (``decode_step``'s ``closing``)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -202,7 +204,8 @@ def compile_step(device, program, config, slots, max_seq, chunk):
         "mixed_step": (
             lambda p, c, last, act, t, slot, start, n: llama.mixed_step(
                 p, last, t, c, config, act, slot, start, n,
-                store=act if size else None),
+                store=act if size else None,
+                closing=(last, act) if size else None),
             (last, active, tokens, scalar, scalar, scalar)),
     }[program]
     return jax.jit(fn, donate_argnums=(1,)).lower(
@@ -242,8 +245,8 @@ def main(argv):
         print(f"{cell.name}: {slots} slots x {max_seq}, chunks of {chunk}",
               flush=True)
         for program in PROGRAMS:
-            if program == "mixed_step" and slots * max(
-                    config.block_length, 1) + chunk > engine.RIDE_ROWS:
+            if program == "mixed_step" and not config.block_length \
+                    and slots + chunk > engine.RIDE_ROWS:
                 print(f"  {program}: never run ({slots} + {chunk} rows pass "
                       f"engine.RIDE_ROWS)")
                 continue

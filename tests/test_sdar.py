@@ -10,9 +10,12 @@ logits, per position.  Both sides compute in float32, so the distance is
 rounding and the order of summation; ``TOL`` = 1e-4 lies two orders
 under what it must catch — every control below (weights in float8, a
 causal mask inside the block, the store pass dropped, q and k normed
-over the whole width, the prompt under the plain causal mask) reads over
-1e-2.  Generation is compared token for token, greedy: the engine's
-loop against the reference's, a full forward pass a step.
+over the whole width, the prompt under the plain causal mask, a closing
+block that sees its successor, a successor that does not see it) reads
+over 1e-2.  Generation is compared token for token, greedy: the engine's
+loop against the reference's, a full forward pass a step.  A block's
+store pass RIDES the next block's first step; that the fused step stores
+what a store pass of its own stores is held bit for bit.
 
 The step programs' attention block is cut to 16 positions.
 """
@@ -116,6 +119,15 @@ def mixed_block_step(params, fed, tokens, cache, active, store, slot,
                      start, n, cfg=CFG):
     return llama.mixed_step(params, fed, tokens, cache, cfg, active, slot,
                             start, n, store=store)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def fused_step(params, fed, closed, closing, tokens, cache, active, store,
+               slot, start, n, cfg=CFG):
+    """The engine's ONE step program: blocks in flight, blocks closing,
+    a chunk's rows."""
+    return llama.mixed_step(params, fed, tokens, cache, cfg, active, slot,
+                            start, n, store=store, closing=(closed, closing))
 
 
 def ingest(params, cache, tokens, slot, cfg=CFG):
@@ -244,6 +256,95 @@ def test_the_mixed_step_is_both_programs(params):
     assert cache["length"].tolist() == [8, 4, 12 + B]
 
 
+def _fused(params, cache, slot, fed, closed=None, store=False):
+    """One step of the ONE program for ``slot`` alone, no chunk: its
+    block in flight ``fed`` (None: nothing active), its closing block
+    ``closed`` (None: none) -> (the block in flight's logits, cache)."""
+    blocks, active = one_slot(slot, fed)
+    closing, live = one_slot(slot, closed)
+    logits, _, cache = fused_step(
+        params, blocks, closing, live, jnp.zeros((CHUNK,), jnp.int32),
+        cache, active, active & store, SLOTS, 1, 0)
+    return logits.reshape(SLOTS, B, -1)[slot], cache
+
+
+@pytest.fixture(scope="module")
+def riding(params):
+    """The state "block b closing + block b+1's first step" behind 8
+    stored tokens, as the fused step ran it, and its cache."""
+    tokens = tokens_of(7, 8 + 2 * B)
+    cache = ingest(params, llama.init_kv_cache(CFG, SLOTS, MAX_SEQ, CHUNK),
+                   tokens[:8], 1)
+    fed = np.where([False, True, False, False], tokens[8 + B:], MASK)
+    logits, after = _fused(params, cache, 1, fed, tokens[8:8 + B])
+    return tokens, cache, fed, logits, after
+
+
+def test_a_closing_block_rides_the_next_blocks_first_step(riding, params):
+    """The closing block's rows and the next block's, one step: the new
+    block sees the stored positions, the closing block as this step
+    wrote it and itself — the block-causal mask, unchanged — and the
+    length moves over the closing block alone."""
+    tokens, _, fed, logits, after = riding
+    want = reference_logits(params, np.concatenate([tokens[:8 + B], fed]))
+    assert rel_l2(logits, want[8 + B:]).max() < TOL
+    assert after["length"].tolist() == [0, 8 + B, 0]
+
+
+def test_the_fused_step_stores_what_a_store_pass_stores(riding, params):
+    """Through the ONE program, bit for bit: a store pass of its own
+    (block rows with no mask left, nothing closing) and then the next
+    block's first step give the logits and leave the keys and values
+    that the one fused step gives and leaves."""
+    tokens, cache, fed, logits, after = riding
+    _, apart = _fused(params, cache, 1, tokens[8:8 + B], store=True)
+    assert apart["length"].tolist() == [0, 8 + B, 0]
+    step, apart = _fused(params, apart, 1, fed)
+    assert np.array_equal(np.asarray(step), np.asarray(logits))
+    assert apart["length"].tolist() == after["length"].tolist()
+    for name in llama.kv_slabs(CFG):
+        # the stored positions and the block in flight behind them
+        assert np.array_equal(np.asarray(apart[name][:, 1, :8 + 2 * B]),
+                              np.asarray(after[name][:, 1, :8 + 2 * B]))
+
+
+def test_control_closing_rows_that_see_the_new_block_fail(riding, params):
+    tokens, _, fed, logits, _ = riding
+    n = 8 + 2 * B
+    mask = ref.block_mask(n, B).at[8:8 + B, 8 + B:].set(True)
+    want = reference_logits(params, np.concatenate([tokens[:8 + B], fed]),
+                            mask)
+    assert rel_l2(logits, want[8 + B:]).min() > 1e-2
+
+
+def test_control_a_new_block_that_does_not_see_the_closing_one_fails(
+        riding, params):
+    tokens, _, fed, logits, _ = riding
+    n = 8 + 2 * B
+    mask = ref.block_mask(n, B).at[8 + B:, 8:8 + B].set(False)
+    want = reference_logits(params, np.concatenate([tokens[:8 + B], fed]),
+                            mask)
+    assert rel_l2(logits, want[8 + B:]).min() > 1e-2
+
+
+def test_nothing_closing_and_no_mask_left_is_still_a_store_pass(params):
+    """What the benchmark's probe relies on (``eng._decode_jit``): block
+    rows with no mask left, nothing closing, move the length by a block;
+    a slot with a block closing and NOTHING in flight (no mask left: the
+    slab's end) moves it once, not twice."""
+    tokens = tokens_of(8, 8 + 2 * B)
+    cache = ingest(params, llama.init_kv_cache(CFG, SLOTS, MAX_SEQ, CHUNK),
+                   tokens[:8], 2)
+    _, stored = _fused(params, cache, 2, tokens[8:8 + B], store=True)
+    assert stored["length"].tolist() == [0, 0, 8 + B]
+    _, once = _fused(params, cache, 2, tokens[8 + B:], tokens[8:8 + B],
+                     store=True)
+    assert once["length"].tolist() == [0, 0, 8 + B]
+    for name in llama.kv_slabs(CFG):
+        assert np.array_equal(np.asarray(stored[name][:, 2, :8 + B]),
+                              np.asarray(once[name][:, 2, :8 + B]))
+
+
 # --------------------------------------------- (b) the controls all FAIL
 
 def _worst(params, steps, **how):
@@ -349,24 +450,38 @@ def test_the_transfer_rule_place_by_place(params, confident, masked,
     places[1] = mask_row
     active = np.array([False, True, False])
     keys = jax.random.split(jax.random.PRNGKey(9), SLOTS)
-    read, new_keys, new_blocks, left, counts = eng._sample_jit(
-        jnp.asarray(logits.reshape(SLOTS * B, -1)), keys,
-        jnp.asarray(active), jnp.full((SLOTS,), temperature, jnp.float32),
-        jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), jnp.float32),
-        None, jnp.asarray(blocks), jnp.asarray(places),
-        jnp.zeros((3,), jnp.uint32), jnp.zeros((SLOTS,), jnp.int32))
+    read, new_keys, new_blocks, left, closed, closing, counts = \
+        eng._sample_jit(
+            jnp.asarray(logits.reshape(SLOTS * B, -1)), keys,
+            jnp.asarray(active),
+            jnp.full((SLOTS,), temperature, jnp.float32),
+            jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), jnp.float32),
+            None, jnp.asarray(blocks), jnp.asarray(places),
+            jnp.full((SLOTS, B), 5, jnp.int32), jnp.zeros((SLOTS,), bool),
+            jnp.zeros((3,), jnp.uint32), jnp.zeros((SLOTS,), jnp.int32))
     place_keys = jax.random.split(jax.random.split(keys[1])[1], B)
     want_block, want_left, taken, sure = ref.transfer(
         logits[1], blocks[1], mask_row, place_keys,
         temperature=temperature, threshold=CFG.confidence_threshold,
         n_transfer=B // CFG.denoising_steps)
-    assert np.asarray(new_blocks)[1].tolist() == want_block
-    assert np.asarray(left)[1].tolist() == want_left
+    filled = not any(want_left)
+    if filled:
+        # its last mask went: the block is closing, its tokens final,
+        # and the next one starts behind it as masks at once
+        assert np.asarray(closed)[1].tolist() == want_block
+        assert np.asarray(new_blocks)[1].tolist() == [MASK] * B
+        assert np.asarray(left)[1].tolist() == [True] * B
+    else:
+        assert np.asarray(closed)[1].tolist() == [5] * B
+        assert np.asarray(new_blocks)[1].tolist() == want_block
+        assert np.asarray(left)[1].tolist() == want_left
+    assert np.asarray(closing).tolist() == [False, filled, False]
     assert sure == (len(set(confident) & set(masked)) >= 1)
     assert np.asarray(counts).tolist() == [
         0, sum(taken), sum(taken) if sure else 0]
     read = np.asarray(read)
-    assert read[:SLOTS].tolist() == [0, int(not any(want_left)), 0]
+    assert read[:SLOTS].tolist() == [
+        0, engine_mod.BLOCK_FILLED if filled else 0, 0]
     assert read[SLOTS:SLOTS * (1 + B)].reshape(SLOTS, B)[1].tolist() \
         == want_block
     # an idle slot's block, masks and key stay as they are
@@ -382,36 +497,83 @@ def test_the_transfer_rule_breaks_ties_by_place(params):
     logits = np.tile(one.astype(np.float32), (SLOTS * B, 1))
     places = np.zeros((SLOTS, B), bool)
     places[0, 1:] = True
-    _, _, _, left, _ = eng._sample_jit(
+    _, _, _, left, *_ = eng._sample_jit(
         jnp.asarray(logits), jax.random.split(jax.random.PRNGKey(1), SLOTS),
         jnp.asarray([True, False, False]), jnp.zeros((SLOTS,), jnp.float32),
         jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), jnp.float32),
         None, jnp.zeros((SLOTS, B), jnp.int32), jnp.asarray(places),
+        jnp.zeros((SLOTS, B), jnp.int32), jnp.zeros((SLOTS,), bool),
         jnp.zeros((3,), jnp.uint32), jnp.zeros((SLOTS,), jnp.int32))
     assert np.asarray(left)[0].tolist() == [False, False, True, True]
 
 
+def _transfer(eng, places, closing, length, active=(True, True, True)):
+    """The sampler on flat logits (greedy: the first masked place takes
+    token 0): every slot's block in flight all 9s, its closing block all
+    5s -> (status, blocks, masks left, closed, closing, counts)."""
+    read, _, blocks, left, closed, closing, counts = eng._sample_jit(
+        jnp.zeros((SLOTS * B, CFG.vocab_size), jnp.float32),
+        jax.random.split(jax.random.PRNGKey(1), SLOTS),
+        jnp.asarray(active), jnp.zeros((SLOTS,), jnp.float32),
+        jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), jnp.float32),
+        None, jnp.full((SLOTS, B), 9, jnp.int32), jnp.asarray(places),
+        jnp.full((SLOTS, B), 5, jnp.int32), jnp.asarray(closing),
+        jnp.zeros((3,), jnp.uint32), jnp.asarray(length, jnp.int32))
+    return (np.asarray(read)[:SLOTS].tolist(), np.asarray(blocks),
+            np.asarray(left).tolist(), np.asarray(closed),
+            np.asarray(closing).tolist(), np.asarray(counts).tolist())
+
+
 def test_a_store_pass_starts_the_next_block_as_masks(params):
-    """No mask left: STORED, and the next block's masks reach as far as
-    the slab (a last block of two places under max_seq 62)."""
+    """No mask left and nothing closing: a store pass of its own,
+    STORED, and the next block's masks reach as far as the slab (a last
+    block of two places under max_seq 62).  A block whose last mask
+    goes is FILLED: it is closing, and its successor's masks lie a
+    block further on."""
     eng = _engine(params, max_seq=62)
     places = np.zeros((SLOTS, B), bool)
     places[2, 0] = True
-    read, _, blocks, left, counts = eng._sample_jit(
-        jnp.zeros((SLOTS * B, CFG.vocab_size), jnp.float32),
-        jax.random.split(jax.random.PRNGKey(1), SLOTS),
-        jnp.asarray([True, True, True]), jnp.zeros((SLOTS,), jnp.float32),
-        jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), jnp.float32),
-        None, jnp.full((SLOTS, B), 9, jnp.int32), jnp.asarray(places),
-        jnp.zeros((3,), jnp.uint32), jnp.asarray([20, 60, 8], jnp.int32))
-    assert np.asarray(read)[:SLOTS].tolist() == [
-        engine_mod.BLOCK_STORED, engine_mod.BLOCK_STORED,
-        engine_mod.BLOCK_FILLED]
-    assert np.asarray(left).tolist() == [[True] * 4,
-                                         [True, True, False, False],
-                                         [False] * 4]
-    assert np.asarray(blocks)[:2].tolist() == [[MASK] * B] * 2
-    assert np.asarray(counts).tolist() == [2, 1, 0]
+    status, blocks, left, closed, closing, counts = _transfer(
+        eng, places, [False] * 3, [20, 60, 52])
+    assert status == [engine_mod.BLOCK_STORED, engine_mod.BLOCK_STORED,
+                      engine_mod.BLOCK_FILLED]
+    assert left == [[True] * 4, [True, True, False, False],
+                    [True, True, True, True]]
+    assert blocks.tolist() == [[MASK] * B] * 3
+    assert closing == [False, False, True]
+    assert closed.tolist() == [[5] * B, [5] * B, [0, 9, 9, 9]]
+    assert counts == [2, 1, 0]
+    # ... and behind a filled block at the slab's end nothing starts
+    _, _, left, _, closing, _ = _transfer(eng, places, [False] * 3,
+                                          [20, 60, 58])
+    assert left[2] == [False] * 4 and closing[2]
+
+
+def test_a_closing_block_is_stored_by_the_step_that_carried_it(params):
+    """The status bits a slot: a block closing beside a denoise step of
+    its successor is STORED and FUSED; beside the step that fills the
+    successor FILLED too; with NOTHING in flight behind it (no mask
+    left: the slab's end) it is a store pass and nothing else — STORED,
+    counted in ``block_store_rows``, and not a second store."""
+    eng = _engine(params, max_seq=62)
+    stored, fused, filled = (engine_mod.BLOCK_STORED, engine_mod.BLOCK_FUSED,
+                             engine_mod.BLOCK_FILLED)
+    places = np.zeros((SLOTS, B), bool)
+    places[0, :2] = True
+    places[1, 3] = True
+    status, blocks, left, closed, closing, counts = _transfer(
+        eng, places, [True] * 3, [24, 24, 62])
+    assert status == [stored | fused, stored | fused | filled, stored]
+    assert closing == [False, True, False]
+    assert closed.tolist() == [[5] * B, [9, 9, 9, 0], [5] * B]
+    assert left == [[False, True, False, False], [True] * B, [False] * B]
+    assert blocks[0].tolist() == [0, 9, 9, 9]
+    assert counts == [1, 2, 0]
+    # an idle slot keeps what it has closing
+    status, _, _, _, closing, _ = _transfer(
+        eng, places, [True] * 3, [24, 24, 62], active=(False, True, False))
+    assert status == [0, stored | fused | filled, 0]
+    assert closing == [True, True, True]
 
 
 # ------------------------------------------ (d) generation, the engine's
@@ -446,6 +608,57 @@ def test_generation_is_the_references(params, prompt_tokens):
     assert stats["block_places_confident"] == 0
     assert stats["blocks_stored"] == (prompt_tokens % B + 10) // B - (
         (prompt_tokens % B + 10) % B == 0)
+
+
+@pytest.mark.parametrize("blocks,prompt_tokens", [(3, 8), (2, 11), (4, 4)])
+def test_whole_blocks_take_denoising_steps_row_steps_each(params, blocks,
+                                                          prompt_tokens):
+    """A request of N whole blocks (after the prompt's tail): N x
+    ``denoising_steps`` row-steps and the one dispatched behind its end
+    — not N x (``denoising_steps`` + 1): every block but the last is
+    stored by its successor's first step, none by a pass of its own."""
+    prompt = tokens_of(60 + blocks, prompt_tokens).tolist()
+    tail = prompt_tokens % B
+    eng = _engine(params)
+    out = eng.generate([prompt], SamplingParams(
+        max_tokens=blocks * B - tail))[0]
+    assert len(out.token_ids) == blocks * B - tail
+    stats = eng.stats
+    assert stats["block_places_confident"] == 0       # a place a step
+    assert stats["block_rows"] == blocks * CFG.denoising_steps - tail + 1
+    assert stats["block_store_rows"] == 0
+    assert stats["blocks_stored"] == stats["blocks_fused"] == blocks - 1
+
+
+def test_generation_stores_the_keys_a_store_pass_stores(params):
+    """The engine's loop, its store passes riding, against the unfused
+    schedule by hand through the same ONE program (``eng._decode_jit``:
+    a store pass of its own a block): the tokens are the reference's
+    and the stored keys and values equal bit for bit."""
+    prompt = tokens_of(61, 10).tolist()
+    eng = _engine(params)
+    out = eng.generate([prompt], SamplingParams(max_tokens=14))[0]
+    want, _ = reference_generate(params, prompt, 14, stop=_stops(eng))
+    assert out.token_ids == want
+    sequence = np.asarray(prompt + out.token_ids, np.int32)
+    stored = 8 + 3 * B           # 2 + 14 = 4 blocks; the last is not stored
+    assert eng.stats["blocks_fused"] == 3
+    hand = _engine(params)
+    slot = hand._free_slots[-1]
+    buf = np.zeros((CHUNK,), np.int32)
+    buf[:8] = sequence[:8]
+    _, hand.cache = hand._prefill_chunk_jit(
+        hand.params, hand.cache, jnp.asarray(buf), slot, 0, 8)
+    for at in range(8, stored, B):
+        blocks, active = one_slot(slot, sequence[at:at + B])
+        _, hand.cache = hand._decode_jit(
+            hand.params, hand.cache, blocks, jnp.zeros((SLOTS, B), bool),
+            active)
+    assert int(hand.cache["length"][slot]) == stored
+    assert hand._mixed_step_jit._cache_size() == 1
+    for name in llama.kv_slabs(CFG):
+        assert np.array_equal(np.asarray(eng.cache[name][:, slot, :stored]),
+                              np.asarray(hand.cache[name][:, slot, :stored]))
 
 
 @pytest.mark.parametrize("max_tokens", [1, 2, 3, 4, 5, 6, 7, 8])
@@ -626,7 +839,7 @@ def test_no_block_length_is_the_model_without_the_field():
     assert "jit(_decode)" in decode and "jit(_mixed_step)" in mixed
     blocks = _engine(seeded_params())
     text = blocks._mixed_step_jit.lower(
-        blocks.params, blocks.cache, blocks._last, blocks._masked,
+        blocks.params, blocks.cache, *blocks._fed(),
         blocks._jnp.ones((SLOTS,), bool),
         blocks._jnp.zeros((CHUNK,), "int32"), SLOTS, 0,
         0).as_text(debug_info=True)
@@ -635,7 +848,7 @@ def test_no_block_length_is_the_model_without_the_field():
         jnp.zeros((SLOTS * B, CFG.vocab_size)), blocks._keys,
         jnp.ones((SLOTS,), bool), jnp.zeros((SLOTS,)),
         jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,)),
-        blocks.cache["routing"], blocks._last, blocks._masked,
+        blocks.cache["routing"], *blocks._fed(),
         blocks._block_counts, blocks.cache["length"]).as_text(
             debug_info=True)
     assert "block_transfer/" in sampler

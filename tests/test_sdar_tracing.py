@@ -1,6 +1,6 @@
 """What a model that generates by diffusion over blocks leaves in the
 tracing the repository has: the block counters of ``LLMEngine.stats``
-add up, the ``llm:engine`` span carries ``blocks`` and
+add up, the ``llm:engine`` span carries ``blocks``, ``blocks_fused`` and
 ``block_row_steps``, the phases keep their names, the chip's probe
 (``chipbench/replica_block.py``) reads the reference at test size — and
 every reader the benchmark gained returns None, and raises nothing, on
@@ -55,23 +55,27 @@ def test_the_block_counters_add_up(params):
                         SamplingParams(max_tokens=10))
     stats = eng.stats
     assert [len(o.token_ids) for o in outs] == [10] * 3
-    assert {"block_steps", "block_rows", "blocks_stored",
+    assert {"block_steps", "block_rows", "blocks_stored", "blocks_fused",
             *BLOCK_COUNTERS} <= set(stats)
     # greedy on seeded random weights: no draw passes the threshold, so
     # a denoise row-step fills ONE place; every row-step is a denoise
-    # step or a store pass
+    # step — a block's store rides its successor's first — or a store
+    # pass and nothing else, of which this run has none
     assert stats["block_places_confident"] == 0
     assert stats["block_rows"] == stats["block_places_filled"] \
         + stats["block_store_rows"]
-    # a place filled is a token handed over, or one cut behind
-    # max_tokens in the last block; the prompt's tail fills nothing
+    assert stats["block_store_rows"] == 0
+    # a place filled is a token handed over, one cut behind max_tokens
+    # in the last block, or the first of the block that the step
+    # dispatched behind a request's end began; the prompt's tail fills
+    # nothing
     assert stats["tokens_generated"] == 30
-    assert 30 <= stats["block_places_filled"] <= 30 + 3 * (B - 1)
+    assert 30 <= stats["block_places_filled"] <= 30 + 3 * (B - 1) + 3
     # tails of 3, 3 and 0 known places: 10 tokens end in the 4th, 4th
-    # and 3rd block, so 3 + 3 + 2 blocks were stored for a live request;
-    # the store pass dispatched behind a last block is a row for nobody
-    assert stats["blocks_stored"] == 8
-    assert stats["blocks_stored"] <= stats["block_store_rows"] <= 8 + 3
+    # and 3rd block, so 3 + 3 + 2 blocks were stored for a live request,
+    # each by its successor's first step; the last block's closing rows
+    # ride a step dispatched for nobody
+    assert stats["blocks_stored"] == stats["blocks_fused"] == 8
     # one read a step, a step's rows its active slots
     assert stats["d2h_syncs"] == stats["decode_steps"] \
         == stats["block_steps"]
@@ -110,9 +114,10 @@ def test_the_request_span_counts_blocks_and_row_steps(params):
         tail = len(prompt) % B
         assert attrs["output_tokens"] == len(out.token_ids) == 10
         assert attrs["blocks"] == (tail + 10 - 1) // B
-        # a step a place and a store pass a stored block
+        # a step a place; a stored block rode its successor's first
         filled = (attrs["blocks"] + 1) * B - tail
-        assert attrs["block_row_steps"] == filled + attrs["blocks"]
+        assert attrs["block_row_steps"] == filled
+        assert attrs["blocks_fused"] == attrs["blocks"]
         assert attrs["chunks"] == max(1, len(prompt) // 8)
         # a block's tokens are handed over together: equal stamps
         emit = attrs["emit_ms"]
@@ -123,7 +128,8 @@ def test_the_request_span_counts_blocks_and_row_steps(params):
         assert emit[first] > emit[0]
         total_blocks += attrs["blocks"]
         total_rows += attrs["block_row_steps"]
-    assert eng.stats["blocks_stored"] == total_blocks
+    assert eng.stats["blocks_stored"] == total_blocks \
+        == eng.stats["blocks_fused"]
     # the rows of the steps dispatched behind each request's end
     assert total_rows <= eng.stats["block_rows"] <= total_rows + 3
 
@@ -131,7 +137,7 @@ def test_the_request_span_counts_blocks_and_row_steps(params):
 def test_a_model_without_blocks_has_no_block_counters():
     eng = LLMEngine("tiny", slots=2, max_seq=32, prefill_chunk_tokens=8)
     assert not {"block_steps", "block_rows", "blocks_stored",
-                *BLOCK_COUNTERS} & set(eng.stats)
+                "blocks_fused", *BLOCK_COUNTERS} & set(eng.stats)
     ctx = tracing_plane.mint(sampled=True)
     eng.add_request([5, 6, 7], SamplingParams(max_tokens=2), admit=False,
                     trace_ctx=ctx)
@@ -196,21 +202,16 @@ def test_the_new_readers_read_a_block_engines_counters(params):
     stats = eng.stats
     assert read("block_row_steps_per_token") == pytest.approx(
         stats["block_rows"] / 30)
-    assert 1.25 <= read("block_row_steps_per_token") < 1.7
-    assert read("block_store_pct") == pytest.approx(
-        100 * stats["block_store_rows"] / stats["block_rows"])
+    # 4 steps a block of 4, the blocks cut by max_tokens and the step
+    # behind each request's end above it: no fifth pass a block
+    assert 1.0 <= read("block_row_steps_per_token") < 1.4
+    assert read("block_store_pct") == 0.0
     assert read("block_confident_pct") == 0.0
     assert read("block_step_ms") is None            # no device trace
 
 
-def test_the_chips_probe_reads_the_reference_at_test_size():
-    """``BlockProbeLLMServer.probe_logits`` on the CPU at a tiny size
-    (bfloat16, as the factory builds it): 2 + 4 + 1 denoise steps of 4
-    rows, each against the reference's full forward pass at ONE length —
-    and a reference of the other q/k norm reads far outside."""
-    from chipbench import replica_block
-
-    spec = {"vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
+def _tiny_spec():
+    return {"vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
             "num_attention_heads": 4, "num_key_value_heads": 2,
             "head_dim": 16, "moe_intermediate_size": 32,
             "max_position_embeddings": 512, "rope_theta": 1000000,
@@ -230,6 +231,16 @@ def test_the_chips_probe_reads_the_reference_at_test_size():
                 "params": "chipbench.models.sdar_moe:reference_layers"},
             "serve": {"probe": {"tail_tokens": 2, "blocks": 3,
                                 "short_prompt_tokens": 8}}}
+
+
+def test_the_chips_probe_reads_the_reference_at_test_size():
+    """``BlockProbeLLMServer.probe_logits`` on the CPU at a tiny size
+    (bfloat16, as the factory builds it): 2 + 4 + 1 denoise steps of 4
+    rows, each against the reference's full forward pass at ONE length —
+    and a reference of the other q/k norm reads far outside."""
+    from chipbench import replica_block
+
+    spec = _tiny_spec()
     server = replica_block.BlockProbeLLMServer(
         spec, slots=3, max_seq=64, seed=4, prefill_chunk_tokens=8)
     try:
@@ -261,6 +272,34 @@ def test_the_chips_probe_reads_the_reference_at_test_size():
     assert len(out["rel_l2_by_position"]) == 56
     assert max(out["rel_l2"]) < 0.02 and right < 0.02 < 0.05 < wrong
     assert eng.stats["tokens_generated"] == 0       # no request was served
+
+
+def test_the_parity_script_reads_the_fused_states_at_test_size():
+    """``benchmarks/sdar_block_parity.py``'s fused states — block b
+    closing + block b+1's first step, one step of the engine's one
+    program — at the probe's tiny size: the rows of the block in flight
+    read the reference (0.005), and both wrong masks read far outside
+    (a closing block that sees its successor 0.06, a block blind to the
+    closing one 0.24)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import sdar_block_parity
+    from chipbench import replica_block
+
+    spec = _tiny_spec()
+    server = replica_block.BlockProbeLLMServer(
+        spec, slots=3, max_seq=64, seed=4, prefill_chunk_tokens=8)
+    try:
+        out = server._loop._call_on_loop(
+            lambda e: sdar_block_parity.fused_reading(
+                spec, e, 7, 8, jnp, np, True), timeout=300.0)
+    finally:
+        server.shutdown()
+    assert len(out["rows"]) == 2 * B and out["rel_l2"] < 0.02
+    for name, rows in out["controls"].items():
+        # as the probe reads: the median row (behind 8 stored tokens)
+        assert len(rows) == 2 and np.median(rows) > 0.03, name
 
 
 def test_a_block_model_streams_over_http(shutdown_only):

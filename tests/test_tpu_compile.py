@@ -732,16 +732,21 @@ BLOCKS = llama.LlamaConfig(
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_block_steps_fit_the_chip_at_the_cells_size(v5e, monkeypatch,
                                                     program):
-    """`sdar-30b-a3b.reason` as it is served: 48 slots x 4,096, 192
-    block rows a step and a chunk's 64 (the engine runs the mixed step
-    alone, for every row: ``LLMEngine._block_programs``; the other two
-    are the model file's own programs), 9.28 GiB of weights
-    beside 2.63 GiB of slabs.  The block rows' attention is ONE call of
+    """`sdar-30b-a3b.reason` as it is served: 48 slots x 4,096, 9.28
+    GiB of weights beside 2.63 GiB of slabs.  The engine runs the mixed
+    step alone, for every row (``LLMEngine._block_programs``; the other
+    two are the model file's own programs): since PR 55 the 192 rows of
+    the blocks in flight, the 192 of the blocks CLOSING behind them —
+    a block's store pass rides its successor's first step — and a
+    chunk's 64, 448 rows.  A row group's attention is ONE call of
     ``ops/pallas/decode_attention.py`` in the layers' scan body, handed
     the carried slabs whole with a slot's four places folded among its
-    query heads (128 a slot); the experts go through the grouped kernel
-    over the whole stack; no program moves a slab, and each fits the
-    chip's 15.75 GiB with the sampler's 0.11 GiB of logits to spare."""
+    query heads (128 a slot): two calls in the mixed step, the closing
+    blocks' a walk of their own over the slots that have one; the
+    experts go through the grouped kernel over the whole stack; no
+    program moves a slab, and each fits the chip's 15.75 GiB with the
+    sampler's 0.11 GiB of logits to spare — the 448-row program in
+    what the 256-row one took."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled, params, cache = _compile_step(v5e.devices[0], program, BLOCKS,
                                             48, 4096, 64)
@@ -753,7 +758,10 @@ def test_block_steps_fit_the_chip_at_the_cells_size(v5e, monkeypatch,
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "decode_attention" in line]
-    assert len(calls) == (0 if program == "prefill_chunk" else 1)
+    assert len(calls) == {"decode": 1, "prefill_chunk": 0,
+                          "mixed_step": 2}[program]
+    rows = {"decode": 192, "prefill_chunk": 64, "mixed_step": 448}[program]
+    assert f"bf16[{rows},2048]" in text
     for call in calls:
         assert "bf16[7,48,16384,128]" in call       # the slabs, whole
         assert "bf16[48,128,128]" in call           # 4 places x 32 heads
