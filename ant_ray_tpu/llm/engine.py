@@ -698,6 +698,11 @@ class LLMEngine:
         # counters do — ``exit_pass_sum`` in passes, a float.
         if self.config.loops > 1:
             self.stats["loop_passes"] = 0
+        # Several residual streams a token (``LlamaConfig.hc_mult``): the
+        # REAL rows whose streams the chunks and the decode steps
+        # dispatched read and rewrote, from the host's own books.
+        if self.config.hc_mult > 1:
+            self.stats.update(hc_chunk_rows=0, hc_decode_rows=0)
         if self.config.exit_gate:
             self.stats.update(exit_rows=0, exit_pass_sum=0.0)
             self._exits_seen = np.zeros(
@@ -909,6 +914,8 @@ class LLMEngine:
                 "over blocks) is not sharded: its places go through the "
                 "slabs folded among the query heads, which no mesh was "
                 "measured with (tensor_parallel_size=1, no mesh)")
+        if self.config.hc_mult > 1:
+            raise ValueError(self._llama.HC_NO_MESH)
         if self.config.n_kv_heads % tp or self.config.n_heads % tp:
             raise ValueError(
                 f"tensor_parallel_size={tp} must divide n_heads="
@@ -1331,6 +1338,7 @@ class LLMEngine:
         if seq.first_chunk is None:
             seq.first_chunk = (time.perf_counter(), self.stats["steps"])
         self._note_recurrent(self._chunk_tokens, n, seq.kv_len == 0)
+        self._note_streams("hc_chunk_rows", n)
         seq.prefill_done += n
         seq.kv_len += n
         self.stats["prompt_ends"] += seq.prefill_done == self._ingest(seq)
@@ -1523,6 +1531,7 @@ class LLMEngine:
         self._note_read(contexts)
         self._note_walk(max(contexts), contexts)
         self._note_recurrent(self.slots, len(rows))
+        self._note_streams("hc_decode_rows", len(rows))
         work = sampler_work(self._sampling_rows[slot] for slot, _ in rows)
         stats["sample_plain_steps"] += work == 1
         stats["sample_sorted_steps"] += work == 2
@@ -1702,6 +1711,14 @@ class LLMEngine:
             stats["recurrent_chunk_rows"] += n * rows
             stats["recurrent_chunk_tokens"] += n * live
             stats["recurrent_resets"] += fresh
+
+    def _note_streams(self, counter: str, rows: int):
+        """A step program with ``rows`` real rows was dispatched: of a
+        model with several residual streams a token, ``counter``
+        (``hc_chunk_rows``: a chunk's tokens, ``hc_decode_rows``: a
+        decode step's live rows) counts them."""
+        if self.config.hc_mult > 1:
+            self.stats[counter] += rows
 
     def _note_chunk(self, n: int):
         self.stats["chunks"] += 1

@@ -221,6 +221,28 @@ class LlamaConfig:
     mask_token: int = 0
     denoising_steps: int = 0
     confidence_threshold: float = 0.0
+    # SEVERAL RESIDUAL STREAMS a token (manifold-constrained
+    # hyper-connections, mHC, arXiv 2512.24880, over hyper-connections,
+    # arXiv 2409.19606; 1: one residual vector, every model before it).
+    # A token's residual state is ``hc_mult`` streams of ``dim`` values,
+    # all equal to its embedding at first (``_embed``) and summed before
+    # the final norm (``_closed``).  Every sub-layer has three maps of
+    # its own, made of the normed state itself (``hc_maps``): it READS a
+    # mix of the streams (``H_pre``), its output goes back to each
+    # stream under a weight (``H_post``), and the streams are mixed
+    # among themselves by a matrix that ``hc_sinkhorn_iters`` passes of
+    # Sinkhorn's normalisation (columns, then rows, each sum +
+    # ``hc_eps``) make doubly stochastic, from logits clamped to
+    # ``hc_res_clamp`` (``_hc_read``, ``_residual``).
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
+    # DeepSeek-V3's ``noaux_tc`` (arXiv 2412.19437, section 2.1.2): a
+    # bias an expert (``router_bias`` in the tree) is added to the
+    # scores where the k experts are PICKED; the gates are the picked
+    # experts' scores as they were.
+    router_bias: bool = False
 
     def __post_init__(self, window_pattern):
         if window_pattern:
@@ -312,6 +334,17 @@ class LlamaConfig:
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"unknown qk_norm {self.qk_norm!r}: True "
                              "(over the whole projection) or \"head\"")
+        if self.hc_mult < 1 or (self.hc_mult > 1 and (
+                self.parallel_block or self.loops > 1
+                or self.residual_multiplier != 1.0)):
+            raise ValueError(
+                f"hc_mult {self.hc_mult!r}: several residual streams are "
+                "written around the two sub-layers of a sequential block "
+                "that runs once; not computed with parallel_block, loops "
+                "or a residual_multiplier")
+        if self.router_bias and not self.num_experts:
+            raise ValueError("router_bias corrects a router's scores: the "
+                             "model has no routed experts")
         if self.block_length:
             self._check_blocks()
         elif (self.mask_token or self.denoising_steps
@@ -462,7 +495,7 @@ class LlamaConfig:
             return {"layers": rest}
         dense = dataclasses.replace(
             rest, n_layers=self.n_dense_layers, num_experts=0,
-            router_width=0, n_shared_experts=0,
+            router_width=0, router_bias=False, n_shared_experts=0,
             mlp_dim=self.dense_mlp_dim)
         return {"dense_layers": dense, "layers": rest}
 
@@ -524,6 +557,22 @@ CONFIGS: dict[str, LlamaConfig] = {
         rope_scaling=YarnScaling(
             factor=4.0, original_max_position_embeddings=32,
             mscale=1.0, mscale_all_dim=1.0)),
+    # Xing4.0's block at test size: A.X-K1's latent attention and YaRN,
+    # two leading dense layers, then two whose sigmoid router picks 2 of
+    # 8 experts (all held) by score + correction bias, beside a shared
+    # one — and FOUR residual streams a token, a read, a write and a
+    # Sinkhorn-projected mix around every sub-layer
+    "xing4-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=4, n_heads=4, n_kv_heads=4,
+        mlp_dim=32, max_seq=512, rope_theta=10000.0, norm_eps=1e-6,
+        dtype=jnp.float32, num_experts=8, experts_per_token=2,
+        router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=2.0, n_shared_experts=1, n_dense_layers=2,
+        dense_mlp_dim=96, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_scaling=YarnScaling(
+            factor=4.0, original_max_position_embeddings=32,
+            mscale=1.0, mscale_all_dim=1.0), hc_mult=4),
     # Command A+'s block at test size (Cohere2-MoE): two periods of three
     # window layers (16 positions, rotated) and a full one (no positional
     # embedding), 8 heads of 16 on a hidden size of 64, ONE LayerNorm a
@@ -682,6 +731,8 @@ def _layer_leaves(c: LlamaConfig, stack: str = "layers") -> dict:
         n = c.num_experts
         mlp = {
             "router": ((c.dim, c.router_width or n), (None, "experts")),
+            **({"router_bias": ((c.router_width or n,), ("router_bias",))}
+               if c.router_bias else {}),
             "w_gate": ((n, c.dim, c.mlp_dim), ("experts", e, m)),
             "w_up": ((n, c.dim, c.mlp_dim), ("experts", e, m)),
             "w_down": ((n, c.mlp_dim, c.dim), ("experts", m, e)),
@@ -711,7 +762,22 @@ def _layer_leaves(c: LlamaConfig, stack: str = "layers") -> dict:
             "k_norm": ((hd if c.qk_norm == "head" else c.n_kv_heads * hd,),
                        ("norm",))}
            if c.qk_norm and stack == "layers" else {}),
+        **({f"hc_{sub}_{name}": both for sub in ("attn", "mlp")
+            for name, both in _hc_leaves(c).items()}
+           if c.hc_mult > 1 else {}),
     }
+
+
+def _hc_leaves(c: LlamaConfig) -> dict:
+    """ONE sub-layer's leaves of the residual streams' maps
+    (``hc_maps``): ``phi`` from the normed state, all streams side by
+    side, to the maps' ``n`` + ``n`` + ``n * n`` logits, their bias
+    ``b`` and the three scalars ``alpha`` (read, write, mix) that scale
+    what depends on the input."""
+    n = c.hc_mult
+    return {"phi": ((n * c.dim, 2 * n + n * n), ("embed_param", "hc_maps")),
+            "b": ((2 * n + n * n,), ("hc_bias",)),
+            "alpha": ((3,), ("norm",))}
 
 
 def ssm_widths(c: LlamaConfig) -> tuple:
@@ -761,7 +827,8 @@ def param_logical_dims(config: LlamaConfig) -> dict:
 # recurrent layer's small leaves, named for how they are drawn, are
 # replicated
 LLAMA_RULES_EXTRA = {"heads_flat": "tp", "conv": None, "taps": None,
-                     "bias": None, "decay_rate": None, "decay_bias": None}
+                     "bias": None, "decay_rate": None, "decay_bias": None,
+                     "hc_maps": None, "hc_bias": None, "router_bias": None}
 
 
 def llama_rules() -> dict:
@@ -770,6 +837,22 @@ def llama_rules() -> dict:
     rules = dict(DEFAULT_LLAMA_RULES)
     rules.update(LLAMA_RULES_EXTRA)
     return rules
+
+
+# How ``init_params`` draws the leaves that only several residual
+# streams (``hc_mult``) and a bias-corrected router have: logical name
+# -> the standard deviation for a shape.  ``phi`` at (streams * dim)^-1/2
+# makes the input's part of every map's logit of order 1 under ``alpha``
+# 1 (the normed state has unit mean square), the maps' bias is a unit
+# normal, and the router's bias a tenth — of the size of the gaps between
+# a token's sigmoid scores, so that it changes picks without deciding
+# them alone.  A published checkpoint starts near ``alpha`` 0.01 and a
+# mix close to the identity; random weights drawn so would make the maps
+# constants, and no comparison with a reference would see a fault in
+# them.
+HC_DRAWS = {"hc_maps": lambda shape: shape[-2] ** -0.5,
+            "hc_bias": lambda shape: 1.0,
+            "router_bias": lambda shape: 0.1}
 
 
 def init_params(config: LlamaConfig, key) -> dict:
@@ -804,6 +887,11 @@ def init_params(config: LlamaConfig, key) -> dict:
             # (a linear layer sets q and k to unit length instead)
             drawn = jax.random.uniform(
                 k, shape, jnp.float32, -1.0, 1.0) * shape[-2] ** -0.5
+        elif logical[-1] in HC_DRAWS:
+            # the residual streams' maps and the router's correction
+            # bias, at sizes at which they MATTER (``HC_DRAWS``)
+            drawn = jax.random.normal(k, shape, jnp.float32) * HC_DRAWS[
+                logical[-1]](shape)
         else:
             # every matrix, a linear layer's taps among them
             drawn = jax.random.normal(k, shape, jnp.float32) * 0.02
@@ -872,9 +960,15 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     on the sub-layers' OUTPUTS — x + norm(mix(x)), x + norm(ffn(x)) —
     and a sub-layer reads the residual as it is; with ``sandwich_norm``
     on its input and its output both — x + norm(mix(norm(x))) — the
-    outputs' under leaves of their own.  Returns ``(x, state, load)``,
-    ``load`` as ``_mlp`` gives it."""
-    lead, step = x.shape[:-1], index is not None
+    outputs' under leaves of their own.  Of a model with several
+    residual streams (``hc_mult``) ``x`` is (..., hc_mult, dim): either
+    sub-layer reads a mix of the streams and its output goes back to all
+    of them (``_hc_read``, ``_residual``); everything between is as
+    written above.  Returns ``(x, state, load)``, ``load`` as ``_mlp``
+    gives it."""
+    step = index is not None
+    x, streams = _hc_read(layer, x, c, "hc_attn")
+    lead = x.shape[:-1]
     h = x if c.norm_after else _norm(x, layer["ln_attn"], c)
     if kind == "ssm":
         with jax.named_scope("attn_ssm"):
@@ -930,9 +1024,10 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         out, load = _mlp(layer, h, c, index, tile)
         x = x + attn + out.astype(x.dtype)
         return constrain_act(x, ("batch", "seq", "embed")), state, load
-    x = _residual(x, attn, c)
+    x = _residual(x, attn, c, streams)
     x = constrain_act(x, ("batch", "seq", "embed"))
 
+    x, streams = _hc_read(layer, x, c, "hc_mlp")
     h = x if c.norm_after else _norm(x, layer["ln_mlp"], c)
     out, load = _mlp(layer, h, c, index, tile)
     out = out.astype(x.dtype)
@@ -940,19 +1035,91 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
         out = _norm(out, layer["ln_mlp"], c)
     if c.sandwich_norm:
         out = _norm(out, layer["ln_mlp_out"], c)
-    x = _residual(x, out, c)
+    x = _residual(x, out, c, streams)
     x = constrain_act(x, ("batch", "seq", "embed"))
     return x, state, load
 
 
-def _residual(x, out, c: LlamaConfig):
+def _residual(x, out, c: LlamaConfig, streams=None):
     """``x`` + what a mix or a feed-forward adds to it, under the
     config's ``residual_multiplier`` (the product in float32, rounded
-    once)."""
+    once).  With ``streams`` (``_hc_read``'s: the residual streams the
+    sub-layer read ``x`` from, and its write and mix maps) what comes
+    back is the streams: stream i is ``sum_j H_res[i, j] X_j + H_post[i]
+    out``, in float32, the n + 1 terms added in their order, rounded
+    once."""
+    if streams is not None:
+        with jax.named_scope("hc"):
+            held, h_post, h_res = streams
+            wide = held.astype(jnp.float32)
+            mixed = h_res[..., :, 0, None] * wide[..., 0, None, :]
+            for j in range(1, c.hc_mult):
+                mixed = mixed + h_res[..., :, j, None] * wide[..., j, None, :]
+            mixed = mixed + h_post[..., :, None] * out.astype(
+                jnp.float32)[..., None, :]
+            return mixed.astype(held.dtype)
     if c.residual_multiplier == 1.0:
         return x + out
     return x + (out.astype(jnp.float32)
                 * c.residual_multiplier).astype(x.dtype)
+
+
+def hc_maps(phi, b, alpha, x, c: LlamaConfig):
+    """A sub-layer's three maps of the residual streams ``x`` (..., n,
+    dim), n = ``hc_mult``, all float32: ``H_pre`` (..., n), how it reads
+    them; ``H_post`` (..., n), how its output goes back to each; ``H_res``
+    (..., n, n), how they are mixed among themselves.  With ``x_hat`` the
+    streams side by side over their root mean square (no learned scale:
+    it folds into ``phi``) and ``[p | q | r] = x_hat phi``:
+
+        H_pre  = sigmoid(alpha_0 p + b_p)
+        H_post = 2 sigmoid(alpha_1 q + b_q)
+        H_res  = Sinkhorn(exp(clamp(alpha_2 r + b_r)))
+
+    Sinkhorn: ``hc_sinkhorn_iters`` times, every column over its sum +
+    ``hc_eps``, then every row over its sum + ``hc_eps`` — a positive
+    matrix's way to a doubly stochastic one (mHC's manifold: the mix
+    neither grows nor shrinks what the streams carry together).  The
+    product with ``phi`` is made of the streams as they are and scaled
+    by the root mean square behind it (the same sums), at the HIGHEST
+    precision: the TPU's default would round its float32 operands to
+    bfloat16."""
+    n = c.hc_mult
+    wide = x.astype(jnp.float32).reshape(*x.shape[:-2], n * x.shape[-1])
+    inv_rms = lax.rsqrt(jnp.mean(wide * wide, axis=-1, keepdims=True)
+                        + c.norm_eps)
+    b, alpha = b.astype(jnp.float32), alpha.astype(jnp.float32)
+    pqr = jnp.dot(wide, phi.astype(jnp.float32),
+                  precision=lax.Precision.HIGHEST) * inv_rms
+    h_pre = jax.nn.sigmoid(alpha[0] * pqr[..., :n] + b[:n])
+    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * pqr[..., n:2 * n] + b[n:2 * n])
+    m = jnp.exp(jnp.clip(alpha[2] * pqr[..., 2 * n:] + b[2 * n:],
+                         *c.hc_res_clamp)).reshape(*pqr.shape[:-1], n, n)
+    for _ in range(c.hc_sinkhorn_iters):
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + c.hc_eps)
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + c.hc_eps)
+    return h_pre, h_post, m
+
+
+def _hc_read(layer: dict, x, c: LlamaConfig, sub: str):
+    """What a sub-layer reads of the residual state ``x``, and what its
+    output needs on the way back (``_residual``'s ``streams``): ``x`` as
+    it is and None where a token has ONE residual vector; of several
+    streams (..., n, dim) their mix under ``H_pre`` (..., dim), in
+    float32, the n terms added in their order, rounded once — beside
+    the streams themselves and the sub-layer's other two maps.  ``sub``:
+    the sub-layer's leaves' prefix, "hc_attn" or "hc_mlp"."""
+    if c.hc_mult == 1:
+        return x, None
+    with jax.named_scope("hc"):
+        h_pre, h_post, h_res = hc_maps(
+            layer[sub + "_phi"], layer[sub + "_b"], layer[sub + "_alpha"],
+            x, c)
+        wide = x.astype(jnp.float32)
+        read = h_pre[..., 0, None] * wide[..., 0, :]
+        for j in range(1, c.hc_mult):
+            read = read + h_pre[..., j, None] * wide[..., j, :]
+    return read.astype(x.dtype), (x, h_post, h_res)
 
 
 def _norm(x, weight, c: LlamaConfig):
@@ -1312,7 +1479,13 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
                          preferred_element_type=jnp.float32)
         scores = (jax.nn.sigmoid(logits) if c.router_scoring == "sigmoid"
                   else jax.nn.softmax(logits, axis=-1))
-        gates, experts = lax.top_k(scores, k)              # (tokens, k)
+        if c.router_bias:
+            # the bias picks and does not weigh
+            _, experts = lax.top_k(
+                scores + layer["router_bias"].astype(jnp.float32), k)
+            gates = jnp.take_along_axis(scores, experts, axis=-1)
+        else:
+            gates, experts = lax.top_k(scores, k)          # (tokens, k)
         if c.norm_topk_prob:
             gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
         if c.routed_scaling_factor != 1.0:
@@ -1469,6 +1642,8 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     bench).
     """
     c = config
+    if c.hc_mult > 1 and mesh is not None:
+        raise ValueError(HC_NO_MESH)
     scale = c.attention_multiplier or None       # None: head_dim^-1/2
     cos, sin = _rope_tables(c)
     use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
@@ -1564,7 +1739,7 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
         # the layers once, then the final norm
         for stack, cfg in _stacks(params, c):
             x = scan_stack(x, stack, cfg)
-        return _norm(x, params["norm_f"], c)
+        return _norm(_joined(x, c), params["norm_f"], c)
 
     def a_pass(x, _):
         with jax.named_scope("loop_pass"):
@@ -1576,6 +1751,13 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
     x = layers(x) if c.loops == 1 else lax.scan(
         a_pass, x, None, length=c.loops)[0]
     return constrain_act(_head(params, x, c), ("batch", "seq", None))
+
+
+HC_NO_MESH = (
+    "several residual streams (hc_mult) are not laid out over a mesh: tp "
+    "splits dim, and the maps' norm over all streams would need a "
+    "reduction over it that is not written (no mesh, "
+    "tensor_parallel_size=1)")
 
 
 def _refuse_block_training(c: LlamaConfig):
@@ -1629,6 +1811,10 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
     if c.loops > 1:
         raise ValueError("the pipeline schedule runs its stages' layers "
                          "once: loops (a looped stack) are not computed")
+    if c.hc_mult > 1:
+        raise ValueError("the pipeline schedule hands ONE residual vector "
+                         "a token from stage to stage: several residual "
+                         "streams (hc_mult) are not computed")
     cos, sin = _rope_tables(c)
 
     def attend(xq, xk, xv):
@@ -1680,6 +1866,11 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
             math.prod(shape) for shape in param_shapes(c)["layers"].values())
     attn = 6 * c.loops * c.n_layers * c.n_heads * seq_len * (
         c.head_dim + (c.v_head_dim or c.head_dim))
+    if c.hc_mult > 1:
+        # a sub-layer's read, write and mix of the streams (their maps'
+        # product is among the parameters)
+        n = c.hc_mult
+        matmul += 6 * c.n_layers * 2 * (n * n + 2 * n) * c.dim
     return matmul + attn
 
 
@@ -2500,7 +2691,19 @@ def _embed(params: dict, tokens, c: LlamaConfig):
     x = params["embed"][tokens].astype(c.dtype)
     if c.embedding_multiplier != 1.0:
         x = x * c.embedding_multiplier
+    if c.hc_mult > 1:
+        # every residual stream starts as the token's embedding
+        x = jnp.broadcast_to(x[..., None, :],
+                             (*x.shape[:-1], c.hc_mult, c.dim))
     return x
+
+
+def _joined(x, c: LlamaConfig):
+    """The residual state behind the last layer as ONE vector a token:
+    several streams (``hc_mult``) summed, in float32, rounded once."""
+    if c.hc_mult == 1:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
 
 
 def _head(params: dict, x, c: LlamaConfig):
@@ -2514,7 +2717,7 @@ def _head(params: dict, x, c: LlamaConfig):
 def _closed(params: dict, x, c: LlamaConfig):
     """Rows behind the layers (``_scan_layers``), normed for the head:
     a looped model's every pass ended in the norm, the last too."""
-    return x if c.loops > 1 else _norm(x, params["norm_f"], c)
+    return x if c.loops > 1 else _norm(_joined(x, c), params["norm_f"], c)
 
 
 def _logits(params: dict, x, c: LlamaConfig):

@@ -780,6 +780,53 @@ def test_block_steps_fit_the_chip_at_the_cells_size(v5e, monkeypatch,
     assert need < 13.0 * 2 ** 30
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_streams_cell_fits_the_chip_at_the_cells_size(v5e, monkeypatch,
+                                                      program):
+    """`xing4.0-29b-a4b.docqa` as it is served, from its own file: 16
+    slots x 16,384, prompts in chunks of 512 (too wide to ride: the
+    engine runs these two programs), 9.17 GiB of weights — all 64
+    experts, the whole vocabulary — beside 1.97 GiB of latent slabs.
+    The sizing rule: a step program needs the weights and ONE set of
+    slabs, every leaf of the donated cache aliased to its output; the
+    chunk's four residual streams (512, 4, 3584) and their float32
+    mixes are temporaries of tens of MiB, not a second copy of
+    anything; the experts go through the grouped kernel over the whole
+    stack; all of it inside the chip's 15.75 GiB with the reference's
+    float32 blocks of set-up to spare."""
+    from chipbench.models import xing4
+    from chipbench.spec import Cell
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = Cell("xing4.0-29b-a4b.docqa")
+    config = xing4.build(cell.config)
+    slots, max_seq = cell.traffic["slots"], cell.traffic["max_seq"]
+    chunk = cell.config["serve"]["kwargs"]["prefill_chunk_tokens"]
+    assert (slots, max_seq, chunk) == (16, 16384, 512)
+    compiled, params, cache = _compile_step(v5e.devices[0], program, config,
+                                            slots, max_seq, chunk)
+    assert 9.16 < _tree_bytes(params) / 2 ** 30 < 9.17
+    slabs = {name: cache[name] for name in llama.kv_slabs(config)}
+    assert {name: leaf.shape for name, leaf in slabs.items()} == {
+        "c_kv": (7, 16, 16384, 512), "k_rope": (7, 16, 16384, 64)}
+    assert 1.96 < _tree_bytes(slabs) / 2 ** 30 < 1.97
+    text = compiled.as_text()
+    rows = {"decode": 16, "prefill_chunk": 512}[program]
+    assert f"bf16[{rows},4,3584]" in text            # the streams
+    grouped = [line for line in text.splitlines()
+               if "tpu_custom_call" in line
+               and line.lstrip().startswith("%grouped_matmul")]
+    assert len(grouped) >= 3 and "ragged-dot" not in text
+    assert all("bf16[5,64,3584,1024]" in line
+               or "bf16[5,64,1024,3584]" in line for line in grouped)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    assert mem.temp_size_in_bytes < 256 << 20
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < 11.5 * 2 ** 30
+
+
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
     """loss + grad under the fsdp=4 rule table on the described 2x2
     mesh (Llama-3.2-1B widths, 2 layers): the flash kernel runs per
