@@ -284,9 +284,9 @@ class _PhaseRecorder:
     slabs — and ``decode_read_positions`` what the dispatched program
     reads of them: where the step's rows attend through
     ``ops/pallas/decode_attention.py`` (``llama._decode_kernel``: full
-    slabs, no latent attention, one TPU device) the sum of each ACTIVE
+    slabs, latent ones too, one TPU device) the sum of each ACTIVE
     row's own whole blocks, an idle slot nothing; where they take the
-    XLA walk (latent slabs, a mesh, any other backend) the same as
+    XLA walk (a mesh, any other backend) the same as
     ``decode_walk_positions``.  Of a
     model with window layers (``LlamaConfig.window``; every other
     leaves these three at zero) ``full_span_positions`` and
@@ -471,11 +471,11 @@ class LLMEngine:
 
     Which rows attend how (``llama._attend_slab``): a decode step's rows
     — in ``_decode`` and as the decode rows of ``_mixed_step`` — read,
-    over the full slabs of a model without latent attention on one TPU
-    device, each ACTIVE row's own blocks as far as that row's length
-    through ``ops/pallas/decode_attention.py``; a chunk's rows, a window
-    layer's rings, latent slabs, a mesh and every other backend walk in
-    XLA, every slot as far as the longest live row.
+    over the full slabs — keys and values, or latents and rotary keys —
+    on one TPU device, each ACTIVE row's own blocks as far as that row's
+    length through ``ops/pallas/decode_attention.py``; a chunk's rows, a
+    window layer's rings, a mesh and every other backend walk in XLA,
+    every slot as far as the longest live row.
     ``stats["decode_walk_positions"]`` counts what the second rule
     reads of a decode step's slabs, ``stats["decode_read_positions"]``
     what the dispatched program reads (``_PhaseRecorder``).
@@ -607,7 +607,8 @@ class LLMEngine:
         # Whether a decode step's rows read their own blocks alone (the
         # kernel) or every slot's as far as the longest (the walk): what
         # ``decode_read_positions`` counts.
-        self._decode_kernel = llama._decode_kernel(self.config, self.mesh)
+        self._decode_kernel = llama._decode_kernel(self.config, self.mesh,
+                                                   self.max_seq)
         # Per-slot sampling keys, resident on the device: a key enters
         # its row when its sequence joins the decode batch, the jitted
         # sampler splits every active row each step, and the row leaves

@@ -2127,17 +2127,22 @@ def span_positions(longest: int, max_seq: int) -> int:
     return min(max(-(-longest // size), 1) * size, max_seq)
 
 
-def _decode_kernel(c: LlamaConfig, mesh) -> bool:
-    """Whether a decode step's rows attend over a layer's FULL slabs
-    through ``ops/pallas/decode_attention.py`` (each ACTIVE row's own
-    blocks, read where they lie) and not through ``_attend_slab``'s XLA
-    walk, which a window layer's rings always take.  The walk also
-    keeps latent slabs, slabs sharded over a ``mesh`` (a Mosaic kernel
-    is not partitioned automatically), any backend but the TPU, and a
-    head that is no whole lane tiles (the kernel's blocks are cut by
-    the 128 lanes)."""
-    return (not c.kv_lora_rank and mesh is None
-            and jax.default_backend() == "tpu" and c.head_dim % 128 == 0)
+def _decode_kernel(c: LlamaConfig, mesh, max_seq: int) -> bool:
+    """Whether a decode step's rows attend over a layer's FULL slabs of
+    ``max_seq`` positions through ``ops/pallas/decode_attention.py``
+    (each ACTIVE row's own blocks, read where they lie) and not through
+    ``_attend_slab``'s XLA walk, which a window layer's rings always
+    take.  The walk also keeps slabs sharded over a ``mesh`` (a Mosaic
+    kernel is not partitioned automatically), any backend but the TPU,
+    and a head that is no whole lane tiles (the kernel's blocks are cut
+    by the 128 lanes) — of latent slabs the latent, and the slab's own
+    length too: the chip holds the rotary keys with the positions ALONG
+    the lanes, where the kernel takes its blocks of them."""
+    if mesh is not None or jax.default_backend() != "tpu":
+        return False
+    if c.kv_lora_rank:
+        return c.kv_lora_rank % 128 == 0 and max_seq % 128 == 0
+    return c.head_dim % 128 == 0
 
 
 def read_positions(contexts, max_seq: int) -> int:
@@ -2174,15 +2179,16 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
 
     Which rows take which path: a decode step's rows that bring their
     step's ``visits`` (``decode_attention.work_list``, which
-    ``_decode_rows`` builds once a step over the full slabs of a model
-    without latent attention on one TPU device: ``_decode_kernel``) go
-    through ``ops/pallas/decode_attention.py`` — the same blocks, sums
-    and roundings as stated below, each ACTIVE row's own blocks alone
+    ``_decode_rows`` builds once a step over the full slabs on one TPU
+    device: ``_decode_kernel``) go through
+    ``ops/pallas/decode_attention.py`` — the same blocks, sums and
+    roundings as stated below, each ACTIVE row's own blocks alone
     fetched from the carried slabs where they lie, nothing for an idle
-    slot, whose output is zeros.  A chunk's rows (512 of them share one
-    slot's blocks: matrix-shaped already), a window layer's rings,
-    latent slabs, slabs under a mesh and every other backend take the
-    XLA walk that follows.  The engine counts both a decode step
+    slot, whose output is zeros; latent slabs too (PR 57), between
+    ``w_kvb``'s two by-head products, which stay here.  A chunk's rows
+    (512 of them share one slot's blocks: matrix-shaped already), a
+    window layer's rings, slabs under a mesh and every other backend
+    take the XLA walk that follows.  The engine counts both a decode step
     (``LLMEngine.stats``): ``decode_walk_positions``, what the walk
     reads of a layer's slabs — every slot as far as the longest active
     row — and ``decode_read_positions``, what the path taken reads
@@ -2237,14 +2243,18 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
     rows, max_seq = xq.shape[0], ks.shape[2]
     size = min(ATTEND_BLOCK, max_seq)
     every = slot is None                         # row r reads slot r
-    if every and visits is not None and not window:
+    through = every and visits is not None and not window
+
+    def kernel(q, scale):
         return decode_attention.decode_attention(
-            xq, ks, vs, i, pos, visits, block=ATTEND_BLOCK,
-            scale=c.attention_multiplier or c.head_dim ** -0.5,
+            q, ks, vs, i, pos, visits, block=ATTEND_BLOCK, scale=scale,
             interpret=jax.default_backend() != "tpu")
+
     t = "rt" if every else "t"                   # a block's leading axes
     f32 = {"preferred_element_type": jnp.float32}
     if w_kvb is None:
+        if through:
+            return kernel(xq, c.attention_multiplier or c.head_dim ** -0.5)
         # the query heads of a KV head, from the queries themselves: a
         # block step's rows bring a slot's places folded among them
         q = xq.reshape(rows, c.n_kv_heads, xq.shape[1] // c.n_kv_heads,
@@ -2264,6 +2274,13 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
         q_lat = jnp.einsum("rhd,chd->rhc", xq[..., :nope], wk,
                            **f32).astype(xq.dtype)
         q_rope, scale = xq[..., nope:], c.attn_scale
+
+        def by_head(out):                        # the latents' sums -> v
+            return jnp.einsum("rhc,chd->rhd", out.astype(xq.dtype), wv,
+                              **f32).astype(xq.dtype)
+
+        if through:
+            return by_head(kernel((q_lat, q_rope), scale))
         heads, width = (c.n_heads,), c.kv_lora_rank
 
         def scores(bk, bv):                      # bk: c_kv, bv: k_rope
@@ -2310,8 +2327,7 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
     out = out / denom[..., None]
     if w_kvb is None:
         return out.reshape(xq.shape).astype(xq.dtype)
-    out = jnp.einsum("rhc,chd->rhd", out.astype(xq.dtype), wv, **f32)
-    return out.astype(xq.dtype)
+    return by_head(out)
 
 
 def _as_held(x, slab):
@@ -2542,8 +2558,8 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
     # each active row's own blocks, for the full slabs' kernel: one list
     # a step, the layers' alike
     visits = decode_attention.work_list(
-        pos, active, ATTEND_BLOCK, max_seq) if _decode_kernel(c, mesh) \
-        else None
+        pos, active, ATTEND_BLOCK, max_seq) if _decode_kernel(
+            c, mesh, max_seq) else None
 
     def write(ks, vs, i, xk, xv):
         """One row a slot into layer i."""
@@ -2605,8 +2621,8 @@ def _block_rows(cache: dict, c: LlamaConfig, live, first, mesh=None):
     seen = first + size - 1                      # (slots,) a block's last
     blocks = _span_blocks(jnp.max(jnp.where(live, seen, 0)) + 1, max_seq)
     visits = decode_attention.work_list(
-        seen, live, ATTEND_BLOCK, max_seq) if _decode_kernel(c, mesh) \
-        else None
+        seen, live, ATTEND_BLOCK, max_seq) if _decode_kernel(
+            c, mesh, max_seq) else None
     group = c.n_heads // c.n_kv_heads
 
     def write(ks, vs, i, xk, xv):

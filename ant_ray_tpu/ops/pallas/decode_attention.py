@@ -15,8 +15,8 @@ The pipeline fetches visit v + 1's blocks of keys and values while visit
 v is multiplied, across the rows' borders too; a slot without a visit
 costs no grid step and no byte.
 
-Both layouts ``llama.kv_slabs`` holds are ONE kernel over a block as a
-matrix ``(columns, width)``:
+The three layouts ``llama.kv_slabs`` holds are ONE kernel over a block
+as a matrix ``(columns, width)``, told apart by the slabs' shapes:
 
 * heads side by side in a position (``flat_kv_heads``: (…, max_seq,
   kv_heads * head_dim)): a column is a position, its width every head's
@@ -29,9 +29,21 @@ matrix ``(columns, width)``:
   (…, max_seq * kv_heads, head_dim) — the same bytes, a position's heads
   are consecutive sublanes — a column is (position, KV head), and a
   query head sees the columns of its own KV head alone: the others are
-  masked like the positions behind the row's own.
+  masked like the positions behind the row's own;
+* latent slabs (no heads axis, unequal widths: ``ks`` the latents (…,
+  max_seq, rank), ``vs`` the one rotary key a position (…, max_seq,
+  rope)), attended in the ABSORBED form: the queries come as the pair
+  (q times ``w_kvb``'s keys' part (rows, heads, rank), the rotated part
+  (rows, heads, rope)), a column is a position, every head shares it;
+  the scores are the sum of two products — with the block of latents
+  and with the block of rotary keys — and the values ARE the latents:
+  the output is (rows, heads, rank), ``w_kvb``'s values' part the
+  caller's.  The chip holds 64 values a position ACROSS the lanes with
+  the positions along them, so the rotary keys are taken as (…, rope,
+  max_seq) — there the same bytes, a bitcast — and their block (rope,
+  columns) multiplies as it lies.
 
-Either way the products are the MXU's with bfloat16 inputs and float32
+In each the products are the MXU's with bfloat16 inputs and float32
 sums (what they cost is the block's tiles loaded as the stationary
 operand, 256 B a cycle a unit: the chip's four keep up with its HBM),
 the scores, the running maximum, the denominator and the output are
@@ -56,9 +68,15 @@ slots at 300-900 0.127 -> 0.083; 16 / 16 the same 0.218 -> 0.147; 16 /
 8, 12 slots at 150-450 0.048 -> 0.043.  No shape ran slower through the
 kernel, so none keeps the walk by its sizes; ``llama._decode_kernel``
 leaves out what was not measured (heads that are no whole lane tiles)
-and what the kernel does not read (rings, latent slabs, a mesh).  The
+and what the kernel does not read (rings, a mesh).  The
 same products on the vector unit (float32 multiply, a lane reduce a
 head) read the same 0.83 ms at the first shape: the time is the HBM's.
+Latent slabs (PR 57; a layer's attention between ``w_kvb``'s two
+by-head products, which both paths run): 64 heads, 48 slots all live at
+512-2,560 0.537 -> 0.319; 32 heads, 4 of 16 slots live at 1.5-11k
+0.803 -> 0.096 (289 and 272 GB/s over the live blocks: a visit of 295
+KB is ~0.9 us, the MXU's — the block is the stationary operand of
+products with 32-64 rows — and the grid step's, not the HBM's 0.36).
 """
 
 from __future__ import annotations
@@ -111,10 +129,10 @@ def _spread(q, kv_heads: int):
 
 
 def _kernel(layer_ref, row_ids_ref, block_ids_ref, per_row_ref, pos_ref,
-            q_ref, k_ref, v_ref, out_ref, high_ref, denom_ref, acc_ref, *,
-            size: int, max_seq: int, per: int, group: int, head_dim: int,
-            scale: float):
+            *refs, size: int, max_seq: int, per: int, group: int,
+            head_dim: int, scale: float, latent: bool):
     del layer_ref                                   # the index maps' alone
+    *q_refs, k_ref, v_ref, out_ref, high_ref, denom_ref, acc_ref = refs
     visit = pl.program_id(0)
     row, b = row_ids_ref[visit], block_ids_ref[visit]
 
@@ -125,8 +143,13 @@ def _kernel(layer_ref, row_ids_ref, block_ids_ref, per_row_ref, pos_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     k, v = k_ref[0, 0], v_ref[0, 0]                 # (columns, width)
-    s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    s = jax.lax.dot_general(q_refs[0][...], k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if latent:      # k: the latents, the values too; v: the rotary keys,
+        s += jnp.dot(q_refs[1][...], v,             # (rope, columns)
+                     preferred_element_type=jnp.float32)
+        v = k
+    s = s * scale
     heads, columns = s.shape
     first = b * size
     start = jnp.minimum(first, max_seq - size)
@@ -150,7 +173,7 @@ def _kernel(layer_ref, row_ids_ref, block_ids_ref, per_row_ref, pos_ref,
     @pl.when(b == per_row_ref[row] - 1)
     def _store():
         out = acc_ref[...] / denom_ref[...]
-        if per > 1:
+        if per > 1 or latent:
             out_ref[...] = out.astype(out_ref.dtype)
         else:           # of all heads' lanes, head h's own KV head's
             for h in range(heads):
@@ -169,19 +192,36 @@ def decode_attention(q, ks, vs, layer, pos, visits, *, block: int,
     ``work_list(pos, active, block, max_seq)`` -> (rows, heads,
     head_dim) in ``q``'s dtype: row ``r``'s softmax attention over
     positions 0..``pos[r]`` of slot ``r``, the scores times ``scale``;
-    zeros for a row that is not active (none of the visits)."""
-    rows, heads, head_dim = q.shape
+    zeros for a row that is not active (none of the visits).
+
+    Latent slabs — ``ks`` the latents (layers, rows, max_seq, rank),
+    ``vs`` the rotary keys (layers, rows, max_seq, rope): no heads axis
+    and unequal widths — take ``q`` as the pair (``q_lat`` (rows, heads,
+    rank), ``q_rope`` (rows, heads, rope)) and give (rows, heads, rank):
+    the absorbed form's sum of latents, ``w_kvb``'s two parts the
+    caller's."""
     max_seq = ks.shape[2]
     size = min(block, max_seq)
-    if ks.ndim == 5:                # a heads axis: (position, head) columns
+    latent = ks.ndim == 4 and ks.shape[3] != vs.shape[3]
+    if latent:                      # one "KV head" every head shares
+        per, kv_heads, qs = 1, 1, tuple(q)
+        head_dim = ks.shape[3]
+        # as the chip holds them: so narrow a position lies ACROSS the
+        # lanes, the positions along them — a bitcast there, and the
+        # block's product with the queries needs no transpose
+        vs = jnp.swapaxes(vs, 2, 3)
+    elif ks.ndim == 5:              # a heads axis: (position, head) columns
         per = kv_heads = ks.shape[3]
+        head_dim, qs = q.shape[2], (q,)
         ks, vs = (x.reshape(*x.shape[:2], max_seq * per, head_dim)
                   for x in (ks, vs))
     else:                           # side by side: a position a column
+        head_dim = q.shape[2]
         per, kv_heads = 1, ks.shape[3] // head_dim
-        q = _spread(q, kv_heads)
-    width = ks.shape[3]
-    columns = size * per
+        qs = (_spread(q, kv_heads),)
+    rows, heads = qs[0].shape[:2]
+    width = ks.shape[3]             # of the scores' first product, and of
+    columns = size * per            # the values: the output's sums
     row_ids, block_ids, per_row, visits = visits
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
@@ -194,20 +234,34 @@ def decode_attention(q, ks, vs, layer, pos, visits, *, block: int,
     def row(v, layer, row_ids, block_ids, per_row, pos):
         return row_ids[v], 0, 0
 
+    def across(v, *lists):
+        layer, row, start, _ = slab(v, *lists)
+        if size % 128 == 0 and max_seq % 128 == 0:  # whole lane tiles
+            start = pl.multiple_of(start, 128)
+        return layer, row, 0, start
+
     # offsets in elements, so every dimension's are (the lowering's rule)
-    taken = pl.BlockSpec((pl.Element(1), pl.Element(1),
-                          pl.Element(columns), pl.Element(width)), slab)
-    held = 2 * 2 * columns * width * ks.dtype.itemsize
-    working = 4 * heads * (2 * width + 4 * columns) + 4 * heads * q.shape[2]
+    one = pl.Element(1)
+    taken = [pl.BlockSpec((one, one, pl.Element(columns), pl.Element(width)),
+                          slab)] * 2
+    other = width                   # the second slab's values a column
+    if latent:
+        other = vs.shape[2]
+        taken[1] = pl.BlockSpec((one, one, pl.Element(other),
+                                 pl.Element(columns)), across)
+    held = 2 * columns * (width + other) * ks.dtype.itemsize
+    working = 4 * heads * (2 * width + 4 * columns) + 4 * heads * sum(
+        x.shape[2] for x in qs)
     out = pl.pallas_call(
         functools.partial(_kernel, size=size, max_seq=max_seq, per=per,
                           group=heads // kv_heads, head_dim=head_dim,
-                          scale=scale),
-        out_shape=jax.ShapeDtypeStruct((rows, heads, head_dim), q.dtype),
+                          scale=scale, latent=latent),
+        out_shape=jax.ShapeDtypeStruct((rows, heads, head_dim),
+                                       qs[0].dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            in_specs=[pl.BlockSpec((None, heads, q.shape[2]), row),
-                      taken, taken],
+            in_specs=[*(pl.BlockSpec((None, heads, x.shape[2]), row)
+                        for x in qs), *taken],
             out_specs=pl.BlockSpec((None, heads, head_dim), row),
             # no row active: one visit, whose output nobody keeps
             grid=(jnp.maximum(visits, 1),),
@@ -218,10 +272,12 @@ def decode_attention(q, ks, vs, layer, pos, visits, *, block: int,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=held + working + (8 << 20)),
         cost_estimate=pl.CostEstimate(
-            flops=4 * rows * heads * max_seq * per * width,
+            flops=2 * rows * heads * max_seq * per * (
+                2 * width + (other if latent else 0)),
             transcendentals=rows * heads * max_seq * per,
-            bytes_accessed=2 * rows * max_seq * per * width
+            bytes_accessed=rows * max_seq * per * (width + other)
             * ks.dtype.itemsize),
         name="decode_attention", interpret=interpret,
-    )(layer, row_ids, block_ids, per_row, pos.astype(jnp.int32), q, ks, vs)
+    )(layer, row_ids, block_ids, per_row, pos.astype(jnp.int32), *qs, ks,
+      vs)
     return jnp.where(per_row[:, None, None] > 0, out, 0)

@@ -5,10 +5,10 @@ lengths set for the walk over the cache (``llama._attend_slab``) — what
 OLMoE's, A.X-K1's and Olmo Hybrid's widths and slabs (two layers each —
 one period of Olmo Hybrid's four — random weights and cache); the same
 of the rows' path through ``ops/pallas/decode_attention.py`` (PR 47:
-what a decode step's rows take on the chip over every slab but the
-latent — a row alone, among rows of other lengths and beside idle
-slots; the step's logits and the mixed step's decode rows go through it
-too); and the mixed step's two walks (PR 39: ``_row_groups`` of a
+what a decode step's rows take on the chip over every full slab, the
+latent ones since PR 57 — a row alone, among rows of other lengths and
+beside idle slots; the step's logits and the mixed step's decode rows go
+through it too); and the mixed step's two walks (PR 39: ``_row_groups`` of a
 decode step's rows and a chunk's) each give their rows what the same
 walk gives them alone, to the bit, whatever the other part's lengths.
 One JSON line a shape; through the chip tool, from the root:
@@ -88,15 +88,15 @@ def check(name, c, slots, max_seq):
     # gives it them): row 0 among short rows, among long ones, beside
     # idle slots, and alone
     same_kernel = None
-    if llama._decode_kernel(c, None):
+    if llama._decode_kernel(c, None, max_seq):
         rows_path = jax.jit(
-            lambda xq, ks, vs, pos, active: llama._attend_slab(
-                xq, ks, vs, layer, None, pos, None, c,
+            lambda xq, ks, vs, w_kvb, pos, active: llama._attend_slab(
+                xq, ks, vs, layer, None, pos, None, c, w_kvb,
                 visits=decode_attention.work_list(
                     pos, active, llama.ATTEND_BLOCK, max_seq)))
         far = pos.at[1:].set(max_seq - 2)
         everyone = jnp.ones((slots,), bool)
-        outs = [rows_path(xq, cache[names[0]], cache[names[1]], p, a)
+        outs = [rows_path(xq, cache[names[0]], cache[names[1]], w_kvb, p, a)
                 for p, a in ((pos, everyone), (far, everyone),
                              (far, everyone.at[-1].set(False)),
                              (pos, everyone.at[1:].set(False)))]

@@ -4,7 +4,9 @@ at the shapes and contexts the benchmark's serving cells decode at: one
 program a path that attends in ONE layer of the cell's slabs (a traced
 layer index, as the step programs have), run on each layer in turn,
 device ms a layer,
-the GB/s over the LIVE rows' own whole blocks of keys and values (what
+the GB/s over the LIVE rows' own whole blocks of keys and values — of
+latent slabs the latents and rotary keys, the layer then with
+``w_kvb``'s two by-head products around either path — (what
 the kernel reads; the walk reads every slot as far as the longest), and
 the largest difference between the two on an active row.  Times are the
 device's (``XLA Modules`` events of a profiler trace, median of the
@@ -29,7 +31,9 @@ from ant_ray_tpu.ops.pallas import decode_attention
 from benchmarks.sampler_paths import device_ms
 
 # shape: (softmax layers with full slabs, slots, max_seq, heads, KV
-#         heads, the active rows' contexts; the other slots idle)
+#         heads — 0: latent slabs, ranks 1536 / 512, 128 + 64 / 128 a
+#         head, both by-head products of the absorbed form beside either
+#         path —, the active rows' contexts; the other slots idle)
 SHAPES = {
     "olmo-hybrid-7b.longdoc": (4, 8, 12288, 30, 30,
                                (11000, 9500, 7700, 6000, 4100, 1500)),
@@ -41,6 +45,10 @@ SHAPES = {
     "mistral-7b.decode": (16, 16, 3072, 32, 8, tuple(range(300, 940, 40))),
     "olmoe-1b-7b.rollout": (8, 16, 3072, 16, 16, tuple(range(300, 940, 40))),
     "internlm2-1.8b.chat": (24, 12, 2048, 16, 8, tuple(range(150, 450, 25))),
+    "ax-k1.reason": (7, 48, 4096, 64, 0, tuple(
+        512 + 2048 * (7 * n % 48) // 47 for n in range(48))),
+    "xing4.0-29b-a4b.docqa": (7, 16, 16384, 32, 0,
+                              (1500, 4000, 6000, 11000)),
 }
 RUNS = 8
 
@@ -51,36 +59,42 @@ def measure(name):
     if not on_chip:                              # a rehearsal's size
         layers, max_seq = min(layers, 2), 1024
         contexts = tuple(min(n, max_seq - 1) // 4 + 1 for n in contexts)
+    latent = dict(q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128) if not kv_heads else {}
     c = llama.LlamaConfig(vocab_size=256, dim=heads * 128, n_layers=layers,
-                          n_heads=heads, n_kv_heads=kv_heads, head_width=128,
-                          mlp_dim=256, max_seq=max_seq)
+                          n_heads=heads, n_kv_heads=kv_heads or heads,
+                          head_width=128, mlp_dim=256, max_seq=max_seq,
+                          **latent)
     cache = llama.init_kv_cache(c, slots, max_seq)
-    keys = jax.random.split(jax.random.PRNGKey(47), 3)
+    names = tuple(llama.kv_slabs(c))
+    keys = jax.random.split(jax.random.PRNGKey(47), 4)
     ks, vs = (jax.jit(lambda k, like: jax.random.normal(
         k, like.shape, jnp.float32).astype(like.dtype))(k, cache[n])
-        for k, n in zip(keys, ("k", "v")))
-    xq = jax.random.normal(keys[2], (slots, heads, 128),
+        for k, n in zip(keys, names))
+    xq = jax.random.normal(keys[2], (slots, heads, c.head_dim),
                            jnp.float32).astype(c.dtype)
+    w_kvb = (jax.random.normal(keys[3], (512, heads * 256), jnp.float32)
+             / 16).astype(c.dtype) if latent else None
     pos = jnp.asarray(contexts + (77,) * (slots - len(contexts)), jnp.int32)
     active = jnp.arange(slots) < len(contexts)
     blocks = llama._span_blocks(max(contexts) + 1, max_seq)
 
-    def walk(xq, ks, vs, i, pos, active):
-        return llama._attend_slab(xq, ks, vs, i, None, pos, blocks, c)
+    def walk(xq, ks, vs, w_kvb, i, pos, active):
+        return llama._attend_slab(xq, ks, vs, i, None, pos, blocks, c, w_kvb)
 
-    # the step's work list, built once a step: not a layer's cost
-    visits = decode_attention.work_list(pos, active, llama.ATTEND_BLOCK,
-                                        max_seq)
-
-    def kernel(xq, ks, vs, i, pos, active):
-        return decode_attention.decode_attention(
-            xq, ks, vs, i, pos, visits, block=llama.ATTEND_BLOCK,
-            scale=128 ** -0.5, interpret=not on_chip)
+    def kernel(xq, ks, vs, w_kvb, i, pos, active):
+        # the step's work list is built once a step: not a layer's cost,
+        # but small beside one
+        return llama._attend_slab(
+            xq, ks, vs, i, None, pos, blocks, c, w_kvb,
+            visits=decode_attention.work_list(
+                pos, active, llama.ATTEND_BLOCK, max_seq))
 
     paths = {"walk": jax.jit(walk), "kernel": jax.jit(kernel)}
     layer = [jnp.int32(i) for i in range(layers)]
-    out = {path: np.asarray(run(xq, ks, vs, layer[-1], pos, active).astype(
-        jnp.float32)) for path, run in paths.items()}
+    out = {path: np.asarray(run(xq, ks, vs, w_kvb, layer[-1], pos,
+                                active).astype(jnp.float32))
+           for path, run in paths.items()}
     live = np.asarray(active)
     line = {"shape": name, "layers": layers, "slots": slots,
             "max_seq": max_seq, "heads": [heads, kv_heads],
@@ -96,15 +110,16 @@ def measure(name):
             with tempfile.TemporaryDirectory() as directory:
                 jax.profiler.start_trace(directory)
                 for run_no in range(RUNS):
-                    run(xq, ks, vs, layer[run_no % layers], pos,
+                    run(xq, ks, vs, w_kvb, layer[run_no % layers], pos,
                         active).block_until_ready()
                 jax.profiler.stop_trace()
                 # the path's own program: no other is in the trace
                 ms = statistics.median(t for program, t in device_ms(
                     directory) if path in program)
             line[f"{path}_ms_a_layer"] = ms
-            line[f"{path}_live_gb_s"] = (
-                read * 2 * kv_heads * 128 * 2 / ms / 1e6)
+            line[f"{path}_live_gb_s"] = read * sum(
+                x.shape[-1] * x.shape[-2] if x.ndim == 5 else x.shape[-1]
+                for x in (ks, vs)) * 2 / ms / 1e6
     print(json.dumps(line), flush=True)
 
 

@@ -78,7 +78,8 @@ def slab_buffers(config, rows: int, max_seq: int) -> dict:
     would be, for ``materialised``: the layer's slab over ``rows`` slots
     or a block of ``ATTEND_BLOCK`` positions of each of them — taken
     out of the carried slabs, or re-laid heads-major — with the heads
-    on an axis or side by side.  A decode step whose rows read the
+    on an axis or side by side (latent slabs: the latents and the rotary
+    keys, no heads).  A decode step whose rows read the
     slabs where they lie (``ops/pallas/decode_attention.py``) writes
     none with ``rows`` its slots; a chunk's walk writes its one slot's
     blocks (``rows`` 1)."""
@@ -91,8 +92,8 @@ def slab_buffers(config, rows: int, max_seq: int) -> dict:
     names = {}
     for what, positions in (("slab", max_seq),
                             ("block", min(llama.ATTEND_BLOCK, max_seq))):
-        for position in ((c.n_kv_heads, c.head_dim),
-                         (c.n_kv_heads * c.head_dim,)):
+        for position in llama.kv_slabs(c).values() if c.kv_lora_rank else (
+                (c.n_kv_heads, c.head_dim), (c.n_kv_heads * c.head_dim,)):
             names.setdefault(_key(dtype, (rows, positions) + position),
                              []).append(what)
     return names

@@ -6,6 +6,7 @@ invariances the mixed step and the benchmark's probes rest on, to the
 bit, and the step programs through the kernel."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,40 @@ def _kernel(c, xq, ks, vs, pos, active):
         xq, ks, vs, 1, pos, da.work_list(pos, jnp.asarray(active), BLOCK,
                                          ks.shape[2]),
         block=BLOCK, scale=c.head_dim ** -0.5, interpret=True)
+
+
+def _latent_config(heads, max_seq, rank=32, rope=8):
+    """Latent attention at test size: ``heads`` of 16 + ``rope`` / 16
+    over latents of ``rank``."""
+    return llama.LlamaConfig(
+        vocab_size=64, dim=64, n_layers=2, n_heads=heads, n_kv_heads=heads,
+        mlp_dim=64, max_seq=max_seq, q_lora_rank=24, kv_lora_rank=rank,
+        qk_nope_head_dim=16, qk_rope_head_dim=rope, v_head_dim=16)
+
+
+def _latent_slabs(c, slots, max_seq, seed=0):
+    """Random queries, two layers of random latents and rotary keys, and
+    a ``w_kvb``."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    c_kv, k_rope = (
+        jax.random.normal(k, (2, slots, max_seq) + position,
+                          jnp.float32).astype(c.dtype)
+        for k, position in zip(keys, llama.kv_slabs(c).values()))
+    xq = jax.random.normal(keys[2], (slots, c.n_heads, c.head_dim),
+                           jnp.float32).astype(c.dtype)
+    w_kvb = (jax.random.normal(keys[3], (c.kv_lora_rank, c.n_heads * 32),
+                               jnp.float32) / 4).astype(c.dtype)
+    return xq, c_kv, k_rope, w_kvb
+
+
+def _latent_rows(c, xq, c_kv, k_rope, w_kvb, pos, active):
+    """``_attend_slab``'s decode rows with the step's visits: through
+    the kernel, interpreted (the CPU)."""
+    pos = jnp.asarray(pos, jnp.int32)
+    return llama._attend_slab(
+        xq, c_kv, k_rope, 1, None, pos, None, c, w_kvb,
+        visits=da.work_list(pos, jnp.asarray(active), BLOCK,
+                            c_kv.shape[2]))
 
 
 def _bits(x):
@@ -85,6 +120,39 @@ def test_kernel_agrees_with_the_walk(shape, monkeypatch):
     assert (np.asarray(got.astype(jnp.float32))[~active] == 0).all()
 
 
+# (heads, max_seq, each row's position): the latent cells' head counts
+LATENT_SHAPES = {
+    "64-heads": (64, 48, (47, 5, 16, 15)),
+    "32-heads": (32, 64, (63, 1, 40, 16)),
+    "slab-shorter-than-a-block": (4, 12, (11, 0, 5, 7)),
+    "last-block-starts-early": (4, 40, (39, 33, 31, 32)),
+}
+
+
+@pytest.mark.parametrize("shape", LATENT_SHAPES)
+def test_latent_kernel_agrees_with_the_walk(shape, monkeypatch):
+    """The third layout — latents and rotary keys, no heads axis, the
+    absorbed form — against ``_attend_slab``'s walk over the same slabs,
+    both behind the same two by-head products: every active row within
+    bf16 rounding, an idle row's latent sum zeros (so its output)."""
+    heads, max_seq, pos = LATENT_SHAPES[shape]
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+    c = _latent_config(heads, max_seq)
+    xq, c_kv, k_rope, w_kvb = _latent_slabs(c, len(pos), max_seq)
+    active = np.array([True, True, False, True])
+    pos = jnp.asarray(pos, jnp.int32)
+    want = llama._attend_slab(
+        xq, c_kv, k_rope, 1, None, pos,
+        llama._span_blocks(jnp.max(pos) + 1, max_seq), c, w_kvb)
+    got = _latent_rows(c, xq, c_kv, k_rope, w_kvb, pos, active)
+    assert got.dtype == xq.dtype and got.shape == (
+        len(pos), heads, c.v_head_dim)
+    np.testing.assert_allclose(
+        np.asarray(got.astype(jnp.float32))[active],
+        np.asarray(want.astype(jnp.float32))[active], rtol=2e-2, atol=2e-2)
+    assert (np.asarray(got.astype(jnp.float32))[~active] == 0).all()
+
+
 def _others(ks, vs, pos, active, case):
     """Row 0 stays as it is; what ``case`` changes of the others."""
     pos, active = np.array(pos), np.array(active)
@@ -102,7 +170,7 @@ def _others(ks, vs, pos, active, case):
     return ks, vs, pos, active
 
 
-@pytest.mark.parametrize("layout", ["flat", "heads-axis"])
+@pytest.mark.parametrize("layout", ["flat", "heads-axis", "latent"])
 @pytest.mark.parametrize("case", [
     "other-rows-longer", "other-rows-shorter", "other-slots-idle",
     "an-idle-slots-slab-nan", "only-the-last-slot-beside-it"])
@@ -111,12 +179,19 @@ def test_a_rows_output_is_its_own_to_the_bit(case, layout):
     lengths, on which other slots are active (one live or all), or on
     what an idle slot's slab holds — NaN included: nothing of it is
     read."""
-    c = _config(*{"flat": (10, 10, 32), "heads-axis": (8, 4, 32)}[layout],
-                48)
-    xq, ks, vs = _slabs(c, 4, 48, seed=3)
     pos, active = (37, 20, 9, 40), (True,) * 4
-    alone = _kernel(c, xq, ks, vs, pos, active)
-    beside = _kernel(c, xq, *_others(ks, vs, pos, active, case))
+    if layout == "latent":
+        c = _latent_config(8, 48)
+        xq, ks, vs, w_kvb = _latent_slabs(c, 4, 48, seed=3)
+        rows = functools.partial(_latent_rows, c, xq, w_kvb=w_kvb)
+    else:
+        c = _config(*{"flat": (10, 10, 32),
+                      "heads-axis": (8, 4, 32)}[layout], 48)
+        xq, ks, vs = _slabs(c, 4, 48, seed=3)
+        rows = functools.partial(_kernel, c, xq)
+    alone = rows(ks, vs, pos=pos, active=active)
+    ks, vs, pos, active = _others(ks, vs, pos, active, case)
+    beside = rows(ks, vs, pos=pos, active=active)
     assert np.isfinite(np.asarray(beside.astype(jnp.float32))).all()
     np.testing.assert_array_equal(_bits(alone[0]), _bits(beside[0]))
 
@@ -145,12 +220,13 @@ def through_the_kernel(monkeypatch):
 
     def switch(on):
         monkeypatch.setattr(
-            llama, "_decode_kernel", lambda c, mesh:
-            on and not c.kv_lora_rank and mesh is None)
+            llama, "_decode_kernel",
+            lambda c, mesh, max_seq: on and mesh is None)
     return switch
 
 
-@pytest.mark.parametrize("name", ["tiny", "cmdaplus-tiny"])
+@pytest.mark.parametrize("name", ["tiny", "cmdaplus-tiny", "axk1-tiny",
+                                  "xing4-tiny"])
 def test_step_programs_through_the_kernel(name, through_the_kernel):
     """A chunk and decode steps with the kernel behind ``_attend_slab``
     give the logits of the same programs through the walk inside the
@@ -158,7 +234,8 @@ def test_step_programs_through_the_kernel(name, through_the_kernel):
     ``mixed_step`` with the BITS ``decode_step`` gives it — both call
     ``_decode_rows.attend``, so both get the kernel.  (Command A+'s
     tiny preset: its window layers' rings keep the walk beside the full
-    layers' kernel.)"""
+    layers' kernel; A.X-K1's and Xing4.0's: latent slabs, the kernel's
+    third layout between ``w_kvb``'s two by-head products.)"""
     cfg = dataclasses.replace(llama.CONFIGS[name], max_seq=64)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     prompts = {0: 23, 2: 40}                     # slot -> prompt tokens
@@ -198,17 +275,25 @@ def test_step_programs_through_the_kernel(name, through_the_kernel):
 
 
 def test_decode_kernel_keeps_the_walk_where_the_arguments_say():
-    """``_decode_kernel``: off the TPU, under a mesh, for latent slabs
-    and heads that are no whole lane tiles, the XLA walk (a window
-    layer's rings always: ``test_step_programs_through_the_kernel``)."""
+    """``_decode_kernel``: off the TPU, under a mesh, for heads — or
+    latents, or latent slabs' lengths — that are no whole lane tiles,
+    the XLA walk (a window layer's rings always:
+    ``test_step_programs_through_the_kernel``); latent slabs of whole
+    lane tiles take the kernel since PR 57."""
     wide = _config(4, 2, 128, 64)
-    assert not llama._decode_kernel(wide, None)              # the CPU
+    assert not llama._decode_kernel(wide, None, 64)          # the CPU
     on_tpu = pytest.MonkeyPatch()
     try:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
-        assert llama._decode_kernel(wide, None)
-        assert not llama._decode_kernel(wide, object())      # a mesh
-        assert not llama._decode_kernel(_config(4, 2, 64, 64), None)
-        assert not llama._decode_kernel(llama.CONFIGS["axk1-tiny"], None)
+        assert llama._decode_kernel(wide, None, 64)
+        assert not llama._decode_kernel(wide, object(), 64)  # a mesh
+        assert not llama._decode_kernel(_config(4, 2, 64, 64), None, 64)
+        assert not llama._decode_kernel(llama.CONFIGS["axk1-tiny"], None,
+                                        512)
+        latent = _latent_config(4, 4096, rank=512, rope=64)
+        assert llama._decode_kernel(latent, None, 4096)
+        assert not llama._decode_kernel(latent, object(), 4096)
+        # the rotary keys' positions lie along the lanes: whole tiles
+        assert not llama._decode_kernel(latent, None, 1000)
     finally:
         on_tpu.undo()

@@ -393,7 +393,7 @@ def test_decode_read_counters_count_what_the_dispatched_program_reads(
 
     monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
     if kernel:
-        monkeypatch.setattr(llama, "_decode_kernel", lambda c, mesh: True)
+        monkeypatch.setattr(llama, "_decode_kernel", lambda *a: True)
     eng = _engine(params, slots=3)
     assert eng._decode_kernel == kernel
     assert eng.stats["decode_walk_positions"] == \

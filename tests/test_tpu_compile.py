@@ -780,6 +780,18 @@ def test_block_steps_fit_the_chip_at_the_cells_size(v5e, monkeypatch,
     assert need < 13.0 * 2 ** 30
 
 
+def _streams_cell():
+    """`xing4.0-29b-a4b.docqa`'s configuration, slots, max_seq and chunk,
+    from its own file."""
+    from chipbench.models import xing4
+    from chipbench.spec import Cell
+
+    cell = Cell("xing4.0-29b-a4b.docqa")
+    return (xing4.build(cell.config), cell.traffic["slots"],
+            cell.traffic["max_seq"],
+            cell.config["serve"]["kwargs"]["prefill_chunk_tokens"])
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
 def test_streams_cell_fits_the_chip_at_the_cells_size(v5e, monkeypatch,
                                                       program):
@@ -794,14 +806,8 @@ def test_streams_cell_fits_the_chip_at_the_cells_size(v5e, monkeypatch,
     anything; the experts go through the grouped kernel over the whole
     stack; all of it inside the chip's 15.75 GiB with the reference's
     float32 blocks of set-up to spare."""
-    from chipbench.models import xing4
-    from chipbench.spec import Cell
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cell = Cell("xing4.0-29b-a4b.docqa")
-    config = xing4.build(cell.config)
-    slots, max_seq = cell.traffic["slots"], cell.traffic["max_seq"]
-    chunk = cell.config["serve"]["kwargs"]["prefill_chunk_tokens"]
+    config, slots, max_seq, chunk = _streams_cell()
     assert (slots, max_seq, chunk) == (16, 16384, 512)
     compiled, params, cache = _compile_step(v5e.devices[0], program, config,
                                             slots, max_seq, chunk)
@@ -825,6 +831,52 @@ def test_streams_cell_fits_the_chip_at_the_cells_size(v5e, monkeypatch,
     need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert need < 11.5 * 2 ** 30
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed_step"])
+@pytest.mark.parametrize("cell", ["ax-k1.reason", "xing4.0-29b-a4b.docqa"])
+def test_latent_decode_rows_read_the_slabs_where_they_lie(
+        v5e, monkeypatch, program, cell):
+    """`ax-k1.reason`'s (48 x 4,096, 64 heads, 64-token chunks: the
+    mixed step is what the engine runs) and `xing4.0-29b-a4b.docqa`'s
+    (16 x 16,384, 32 heads) decode rows on the chip (PR 57): a latent
+    layer's attention is ONE call of ``ops/pallas/decode_attention.py``
+    in its stack's scan body, handed the queries times ``w_kvb``'s keys'
+    part, the rotary queries, the latents whole as they are held and the
+    rotary keys whole as the CHIP holds them — 64 values a position are
+    laid across the lanes, the positions along them, so (layers, slots,
+    rope, max_seq) is the same bytes: a bitcast, not a copy; no
+    operation of the program's own writes a slab- or block-shaped
+    buffer of the slots' latents or rotary keys (the walk took every
+    block of all slots out), a chunk's rows keep their walk over ONE
+    slot's blocks, and the program fits the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config, slots, max_seq, chunk = (
+        (LATENT, 48, 4096, 64) if cell == "ax-k1.reason"
+        else _streams_cell())
+    compiled, _, cache = _compile_step(v5e.devices[0], program, config,
+                                       slots, max_seq, chunk)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "decode_attention" in line]
+    # in the scan body of each stack: the dense layers', the routed ones'
+    assert len(calls) == len(config.stacks()) == 2
+    heads = config.n_heads
+    for call in calls:
+        for handed in (
+                f"bf16[{slots},{heads},512]", f"bf16[{slots},{heads},64]",
+                f"bf16[7,{slots},{max_seq},512]",
+                f"bf16[7,{slots},64,{max_seq}]"):
+            assert handed in call[call.index("operand_layout"):], handed
+    assert not re.search(
+        rf"bf16\[7,{slots},(64,{max_seq}|{max_seq},64)\]\S* copy\(", text)
+    assert step_weight_copies.materialised(
+        text, step_weight_copies.slab_buffers(config, slots, max_seq)) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < 13.0 * 2 ** 30
 
 
 def test_sharded_loss_keeps_the_kernel_under_fsdp4(v5e, monkeypatch):
