@@ -273,7 +273,14 @@ class _PhaseRecorder:
     rings (each a layer's walk times the layers of its kind; the same
     rule, no read), and ``decode_rows_past_window`` the active rows of
     a decode step whose context exceeds the window; the two
-    ``decode_*_positions`` count the full layers' walk.  Of a model
+    ``decode_*_positions`` count the full layers' walk, and
+    ``window_walk_positions`` / ``window_read_positions`` are their like
+    of the window layers' rings, per decode step: window layers x slots
+    x the ring's whole blocks up to the longest active row — what the
+    XLA walk reads of the rings — and what the dispatched program
+    reads of them: window layers x the sum of each ACTIVE row's own
+    whole ring blocks where the rows go through the kernel (all the
+    ring's once it has wrapped), the walk's figure where they walk.  Of a model
     with recurrent layers — linear or state-space ones
     (``LlamaConfig.n_recurrent``; every other leaves these five at
     zero), whose state is a slot's and not a position's:
@@ -320,6 +327,7 @@ class _PhaseRecorder:
                     "decode_slab_positions", "decode_walk_positions",
                     "decode_read_positions", "window_span_positions",
                     "full_span_positions", "decode_rows_past_window",
+                    "window_walk_positions", "window_read_positions",
                     "recurrent_decode_rows", "recurrent_slot_rows",
                     "recurrent_chunk_tokens", "recurrent_chunk_rows",
                     "recurrent_resets", "sample_plain_steps", "sample_sorted_steps",
@@ -451,12 +459,15 @@ class LLMEngine:
     — in ``_decode`` and as the decode rows of ``_mixed_step`` — read,
     over the full slabs — keys and values, or latents and rotary keys —
     on one TPU device, each ACTIVE row's own blocks as far as that row's
-    length through ``ops/pallas/decode_attention.py``; a chunk's rows, a
-    window layer's rings, a mesh and every other backend walk in XLA,
-    every slot as far as the longest live row.
+    length through ``ops/pallas/decode_attention.py`` — and over a
+    window layer's rings the same, under the ring's mask; a chunk's
+    rows, a mesh and every other backend walk in XLA, every slot as far
+    as the longest live row.
     ``stats["decode_walk_positions"]`` counts what the second rule
     reads of a decode step's slabs, ``stats["decode_read_positions"]``
-    what the dispatched program reads (``_PhaseRecorder``).
+    what the dispatched program reads, ``window_walk_positions`` /
+    ``window_read_positions`` the same of the rings
+    (``_PhaseRecorder``).
 
     A step is not always one token a row.  Of a model that generates
     by diffusion over blocks (``LlamaConfig.block_length``, the module
@@ -579,7 +590,7 @@ class LLMEngine:
                                           prefill_chunk_tokens)
         # Whether a decode step's rows read their own blocks alone (the
         # kernel) or every slot's as far as the longest (the walk): what
-        # ``decode_read_positions`` counts.
+        # ``decode_read_positions`` and ``window_read_positions`` count.
         self._decode_kernel = llama._decode_kernel(self.config, self.mesh,
                                                    self.max_seq)
         # Per-slot sampling keys, resident on the device: a key enters
@@ -1524,16 +1535,25 @@ class LLMEngine:
         """A step program was dispatched whose longest live row holds
         ``longest`` positions (``decoding``: a decode step's rows'
         contexts): of a model with window layers, what its attention
-        walks, by the device's own rule."""
+        walks, by the device's own rule — and of a decode step, what a
+        walk of its rows reads of the rings and what the path taken
+        reads (``_note_read``'s like)."""
         if not self._ring:
             return
         span, stats = self._llama.span_positions, self.stats
         n_window, n_full = self.config.slab_layers()
+        ring_span = n_window * span(longest, self._ring)
         stats["full_span_positions"] += n_full * span(longest, self.max_seq)
-        stats["window_span_positions"] += n_window * span(longest,
-                                                          self._ring)
+        stats["window_span_positions"] += ring_span
+        if not decoding:
+            return
         stats["decode_rows_past_window"] += sum(
             n > self.config.window for n in decoding)
+        walk = self.slots * ring_span
+        stats["window_walk_positions"] += walk
+        stats["window_read_positions"] += (
+            n_window * self._llama.read_positions(decoding, self._ring)
+            if self._decode_kernel else walk)
 
     def _note_recurrent(self, rows: int, live: int, fresh=None):
         """A step program of ``rows`` rows was dispatched, ``live`` of

@@ -2128,16 +2128,18 @@ def span_positions(longest: int, max_seq: int) -> int:
 
 
 def _decode_kernel(c: LlamaConfig, mesh, max_seq: int) -> bool:
-    """Whether a decode step's rows attend over a layer's FULL slabs of
-    ``max_seq`` positions through ``ops/pallas/decode_attention.py``
-    (each ACTIVE row's own blocks, read where they lie) and not through
-    ``_attend_slab``'s XLA walk, which a window layer's rings always
-    take.  The walk also keeps slabs sharded over a ``mesh`` (a Mosaic
-    kernel is not partitioned automatically), any backend but the TPU,
-    and a head that is no whole lane tiles (the kernel's blocks are cut
-    by the 128 lanes) — of latent slabs the latent, and the slab's own
-    length too: the chip holds the rotary keys with the positions ALONG
-    the lanes, where the kernel takes its blocks of them."""
+    """Whether a decode step's rows attend over a layer's slabs of
+    ``max_seq`` positions — and over a window layer's rings, whose rows
+    have the slabs' shape a position (PR 59: the same kernel with the
+    ring's mask) — through ``ops/pallas/decode_attention.py`` (each
+    ACTIVE row's own blocks, read where they lie) and not through
+    ``_attend_slab``'s XLA walk.  The walk keeps slabs sharded over a
+    ``mesh`` (a Mosaic kernel is not partitioned automatically), any
+    backend but the TPU, and a head that is no whole lane tiles (the
+    kernel's blocks are cut by the 128 lanes) — of latent slabs the
+    latent, and the slab's own length too: the chip holds the rotary
+    keys with the positions ALONG the lanes, where the kernel takes its
+    blocks of them."""
     if mesh is not None or jax.default_backend() != "tpu":
         return False
     if c.kv_lora_rank:
@@ -2179,20 +2181,22 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
 
     Which rows take which path: a decode step's rows that bring their
     step's ``visits`` (``decode_attention.work_list``, which
-    ``_decode_rows`` builds once a step over the full slabs on one TPU
-    device: ``_decode_kernel``) go through
-    ``ops/pallas/decode_attention.py`` — the same blocks, sums and
-    roundings as stated below, each ACTIVE row's own blocks alone
+    ``_decode_rows`` builds once a step on one TPU device, over the
+    slabs and over a window layer's rings: ``_decode_kernel``) go
+    through ``ops/pallas/decode_attention.py`` — the same blocks, sums
+    and roundings as stated below, each ACTIVE row's own blocks alone
     fetched from the carried slabs where they lie, nothing for an idle
     slot, whose output is zeros; latent slabs too (PR 57), between
-    ``w_kvb``'s two by-head products, which stay here.  A chunk's rows
-    (512 of them share one slot's blocks: matrix-shaped already), a
-    window layer's rings, slabs under a mesh and every other backend
-    take the XLA walk that follows.  The engine counts both a decode step
+    ``w_kvb``'s two by-head products, which stay here; a window layer's
+    rings too (PR 59), under the ring's mask as stated below.  A chunk's
+    rows (512 of them share one slot's blocks: matrix-shaped already),
+    slabs under a mesh and every other backend take the XLA walk that
+    follows.  The engine counts both a decode step
     (``LLMEngine.stats``): ``decode_walk_positions``, what the walk
     reads of a layer's slabs — every slot as far as the longest active
     row — and ``decode_read_positions``, what the path taken reads
-    (``read_positions`` for the kernel).
+    (``read_positions`` for the kernel); ``window_walk_positions`` and
+    ``window_read_positions`` the same of the window layers' rings.
 
     The slab is walked in blocks of ``ATTEND_BLOCK`` positions, each
     sliced out of the carried array where it lies, the first ``blocks``
@@ -2243,12 +2247,12 @@ def _attend_slab(xq, ks, vs, i, slot, pos, blocks, c: LlamaConfig,
     rows, max_seq = xq.shape[0], ks.shape[2]
     size = min(ATTEND_BLOCK, max_seq)
     every = slot is None                         # row r reads slot r
-    through = every and visits is not None and not window
+    through = every and visits is not None
 
     def kernel(q, scale):
         return decode_attention.decode_attention(
             q, ks, vs, i, pos, visits, block=ATTEND_BLOCK, scale=scale,
-            interpret=jax.default_backend() != "tpu")
+            window=window, interpret=jax.default_backend() != "tpu")
 
     t = "rt" if every else "t"                   # a block's leading axes
     f32 = {"preferred_element_type": jnp.float32}
@@ -2555,11 +2559,10 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
     lengths = {cache[name].shape[2] for name in kv_slabs(c)}
     write_pos = {n: _rows_of(pos, active, n, max_seq) for n in lengths}
     blocks = {n: _span_blocks(longest, n) for n in lengths}
-    # each active row's own blocks, for the full slabs' kernel: one list
-    # a step, the layers' alike
-    visits = decode_attention.work_list(
-        pos, active, ATTEND_BLOCK, max_seq) if _decode_kernel(
-            c, mesh, max_seq) else None
+    # each active row's own blocks, for the kernel: one list a step for
+    # the slabs and one for a window layer's rings, the layers' alike
+    visits = {n: decode_attention.work_list(pos, active, ATTEND_BLOCK, n)
+              for n in lengths} if _decode_kernel(c, mesh, max_seq) else None
 
     def write(ks, vs, i, xk, xv):
         """One row a slot into layer i."""
@@ -2569,9 +2572,10 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
 
     def attend(ks, vs, i, window, xq, w_kvb=None):
         """Over the layer's slabs (a window layer's rings), each slot up
-        to its own position."""
-        return _attend_slab(xq, ks, vs, i, None, pos, blocks[ks.shape[2]],
-                            c, w_kvb, window, pos, visits)
+        to its own position, with the visits of the slab's length."""
+        n = ks.shape[2]
+        return _attend_slab(xq, ks, vs, i, None, pos, blocks[n], c, w_kvb,
+                            window, pos, visits[n] if visits else None)
 
     def state(s, conv, i, u, *inputs):
         """A recurrent layer: one token a slot from layer i's states; a

@@ -58,6 +58,20 @@ A slab no longer than a block is one block; the last block of one that
 is no multiple of the block starts early (``pl.Element``: the offset is
 in positions) and masks what the block before it covered.
 
+With a ``window`` (static; 0: none of this, and not an operation traced
+differently) the slabs handed in are a window layer's RINGS
+(``llama.ring_positions`` rows a slot, position ``p`` at row ``p mod
+ring``) in either of the first two layouts, and a column's mask is the
+walk's own: ring row ``at`` holds ``pos[r] - (pos[r] - at) mod ring``
+(``llama._ring_holds``), seen iff that is no position before 0 and less
+than ``window`` behind the row's own.  A row reads the ring's blocks 0,
+1, 2, … in the order they lie — ``pos[r] // block + 1`` of them until
+the ring has wrapped, all of them after (``blocks_read`` of the ring's
+length): a block that holds only positions behind the window is masked
+whole and adds exact zeros under a rescale of exactly 1 (the running
+maximum starts at float32's lowest finite value, not at -inf), the
+walk's sums in the walk's order.
+
 Measured on a v5e (``benchmarks/decode_walk.py``, PERF.md section 6,
 PR 47; a layer of the cell's slabs at the cell's contexts, the XLA walk
 -> this kernel, device ms): 30 heads side by side, 6 of 8 slots live at
@@ -68,7 +82,7 @@ slots at 300-900 0.127 -> 0.083; 16 / 16 the same 0.218 -> 0.147; 16 /
 8, 12 slots at 150-450 0.048 -> 0.043.  No shape ran slower through the
 kernel, so none keeps the walk by its sizes; ``llama._decode_kernel``
 leaves out what was not measured (heads that are no whole lane tiles)
-and what the kernel does not read (rings, a mesh).  The
+and what the kernel does not read (slabs under a mesh).  The
 same products on the vector unit (float32 multiply, a lane reduce a
 head) read the same 0.83 ms at the first shape: the time is the HBM's.
 Latent slabs (PR 57; a layer's attention between ``w_kvb``'s two
@@ -77,6 +91,10 @@ by-head products, which both paths run): 64 heads, 48 slots all live at
 0.803 -> 0.096 (289 and 272 GB/s over the live blocks: a visit of 295
 KB is ~0.9 us, the MXU's — the block is the stationary operand of
 products with 32-64 rows — and the grid step's, not the HBM's 0.36).
+A window layer's rings (PR 59; ``command-a-plus.docqa``'s: 16 slots x
+4,608 rows, 128 / 8 heads, a window of 4,096, 3 slots live past it):
+0.620 -> 0.104 (543 GB/s over the live rows' 18 blocks each; in the
+cell's step three such layers, ~0.04 ms a call at 1.9 rows live).
 """
 
 from __future__ import annotations
@@ -130,7 +148,7 @@ def _spread(q, kv_heads: int):
 
 def _kernel(layer_ref, row_ids_ref, block_ids_ref, per_row_ref, pos_ref,
             *refs, size: int, max_seq: int, per: int, group: int,
-            head_dim: int, scale: float, latent: bool):
+            head_dim: int, scale: float, latent: bool, window: int):
     del layer_ref                                   # the index maps' alone
     *q_refs, k_ref, v_ref, out_ref, high_ref, denom_ref, acc_ref = refs
     visit = pl.program_id(0)
@@ -155,7 +173,16 @@ def _kernel(layer_ref, row_ids_ref, block_ids_ref, per_row_ref, pos_ref,
     start = jnp.minimum(first, max_seq - size)
     column = jax.lax.broadcasted_iota(jnp.int32, (heads, columns), 1)
     at = start + column // per
-    valid = (at >= first) & (at <= pos_ref[row])
+    if window:
+        # a ring of ``max_seq`` rows: row ``at`` holds the newest position
+        # ``gap`` behind the row's own with that remainder
+        # (``llama._ring_holds``) — none yet where that lies before 0
+        here = pos_ref[row]
+        gap = jax.lax.rem(here, max_seq) - at
+        gap = jnp.where(gap < 0, gap + max_seq, gap)
+        valid = (at >= first) & (gap <= here) & (gap < window)
+    else:
+        valid = (at >= first) & (at <= pos_ref[row])
     if per > 1:                     # a column is one KV head's: its own
         head = jax.lax.broadcasted_iota(jnp.int32, (heads, columns), 0)
         valid &= column % per == head // group
@@ -182,9 +209,11 @@ def _kernel(layer_ref, row_ids_ref, block_ids_ref, per_row_ref, pos_ref,
                     out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "scale", "window",
+                                             "interpret"))
 def decode_attention(q, ks, vs, layer, pos, visits, *, block: int,
-                     scale: float, interpret: bool = False):
+                     scale: float, window: int = 0,
+                     interpret: bool = False):
     """``q`` (rows, heads, head_dim), ``ks`` / ``vs`` (layers, rows,
     max_seq, kv_heads, head_dim) or (layers, rows, max_seq, kv_heads *
     head_dim) of which layer ``layer`` (traced) is meant, ``pos`` (rows,)
@@ -193,6 +222,11 @@ def decode_attention(q, ks, vs, layer, pos, visits, *, block: int,
     head_dim) in ``q``'s dtype: row ``r``'s softmax attention over
     positions 0..``pos[r]`` of slot ``r``, the scores times ``scale``;
     zeros for a row that is not active (none of the visits).
+
+    With ``window`` ``ks`` / ``vs`` are a window layer's rings (layers,
+    rows, ring, …), ``visits`` the work list of the RING's length, and
+    row ``r`` attends over the positions its slot's ring holds that lie
+    less than ``window`` behind ``pos[r]``, itself among them.
 
     Latent slabs — ``ks`` the latents (layers, rows, max_seq, rank),
     ``vs`` the rotary keys (layers, rows, max_seq, rope): no heads axis
@@ -255,7 +289,7 @@ def decode_attention(q, ks, vs, layer, pos, visits, *, block: int,
     out = pl.pallas_call(
         functools.partial(_kernel, size=size, max_seq=max_seq, per=per,
                           group=heads // kv_heads, head_dim=head_dim,
-                          scale=scale, latent=latent),
+                          scale=scale, latent=latent, window=window),
         out_shape=jax.ShapeDtypeStruct((rows, heads, head_dim),
                                        qs[0].dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -8,7 +8,11 @@ of the rows' path through ``ops/pallas/decode_attention.py`` (PR 47:
 what a decode step's rows take on the chip over every full slab, the
 latent ones since PR 57 — a row alone, among rows of other lengths and
 beside idle slots; the step's logits and the mixed step's decode rows go
-through it too); and the mixed step's two walks (PR 39: ``_row_groups`` of a
+through it too; PR 59: Command A+'s widths, one period of three
+window layers to a full one at the cell's 16 x 32,768 — the RINGS
+through the kernel under the ring's mask, row 0 before its ring has
+wrapped and after, among rows that have and have not, beside idle slots
+and alone, and how far the kernel's row lies from the walk's); and the mixed step's two walks (PR 39: ``_row_groups`` of a
 decode step's rows and a chunk's) each give their rows what the same
 walk gives them alone, to the bit, whatever the other part's lengths.
 One JSON line a shape; through the chip tool, from the root:
@@ -50,22 +54,64 @@ OLMO_HYBRID = llama.LlamaConfig(
     full_rope=False, norm_after=True,
     layer_kinds=("linear", "linear", "linear", "full"),
     linear_heads=30, linear_head_dim=96, linear_value_dim=192)
-# shape: (configuration, slots, max_seq)
+COMMAND_A_PLUS = llama.LlamaConfig(
+    vocab_size=32768, dim=4096, n_layers=4, n_heads=128, n_kv_heads=8,
+    head_width=128, mlp_dim=4096, max_seq=200000, rope_theta=50000.0,
+    norm_eps=1e-5, tie_embeddings=True, num_experts=4,
+    experts_per_token=8, router_scoring="sigmoid", router_width=128,
+    n_shared_experts=4, shared_experts_average=True, window=4096,
+    window_pattern=(True, True, True, False), full_rope=False,
+    norm="layer", parallel_block=True)
+# shape: (configuration, slots, max_seq[, the chunk its rings hold])
 SHAPES = {"mistral-7b": (MISTRAL, 16, 3072), "olmoe-1b-7b": (OLMOE, 16, 3072),
           "ax-k1": (AXK1, 48, 4096),
-          "olmo-hybrid-7b": (OLMO_HYBRID, 8, 12288)}
+          "olmo-hybrid-7b": (OLMO_HYBRID, 8, 12288),
+          "command-a-plus": (COMMAND_A_PLUS, 16, 32768, 512)}
 
 
 def bits(x):
     return np.asarray(x.astype(jnp.float32)).view(np.uint32)
 
 
-def check(name, c, slots, max_seq):
+def rows_in_company(c, ks, vs, xq, w_kvb, reach, window=0, owns=(437,)):
+    """The decode rows' own path (the kernel, where ``_decode_kernel``
+    gives it them) over the last layer of ``ks`` / ``vs`` — slabs, or
+    with ``window`` a window layer's rings under the ring's mask: row 0
+    at each of the positions ``owns`` among short rows, among rows at
+    ``reach``, beside an idle slot and alone -> (row 0 bit-equal in all,
+    the largest distance of its output from the XLA walk's)."""
+    slots, layer, rows = xq.shape[0], ks.shape[0] - 1, ks.shape[2]
+    rows_path = jax.jit(
+        lambda xq, ks, vs, w_kvb, pos, active: llama._attend_slab(
+            xq, ks, vs, layer, None, pos, None, c, w_kvb, window, pos,
+            visits=decode_attention.work_list(
+                pos, active, llama.ATTEND_BLOCK, rows)))
+    walk = jax.jit(lambda xq, ks, vs, w_kvb, pos: llama._attend_slab(
+        xq, ks, vs, layer, None, pos, llama._span_blocks(
+            jnp.max(pos) + 1, rows), c, w_kvb, window, pos))
+    everyone = jnp.ones((slots,), bool)
+    same, apart = True, 0.0
+    for own in owns:
+        pos = jnp.full((slots,), 300, jnp.int32).at[0].set(own)
+        far = pos.at[1:].set(reach)
+        outs = [rows_path(xq, ks, vs, w_kvb, p, a)
+                for p, a in ((pos, everyone), (far, everyone),
+                             (far, everyone.at[-1].set(False)),
+                             (pos, everyone.at[1:].set(False)))]
+        same &= all((bits(out[0]) == bits(outs[0][0])).all() for out in outs)
+        apart = max(apart, float(jnp.max(jnp.abs(
+            outs[0][0].astype(jnp.float32)
+            - walk(xq, ks, vs, w_kvb, pos)[0].astype(jnp.float32)))))
+    return bool(same), apart
+
+
+def check(name, c, slots, max_seq, chunk=0):
     key = jax.random.PRNGKey(11)
     params = jax.jit(llama.init_params, static_argnums=0)(c, key)
-    cache = llama.init_kv_cache(c, slots, max_seq)
+    cache = llama.init_kv_cache(c, slots, max_seq, chunk)
     names = tuple(llama.kv_slabs(c))
-    for slab, k in zip(names, jax.random.split(key, 2)):
+    for slab, k in zip(names, [*jax.random.split(key, 2),
+                               *jax.random.split(jax.random.PRNGKey(13), 2)]):
         cache[slab] = jax.random.normal(
             k, cache[slab].shape, jnp.float32).astype(c.dtype)
     size = min(llama.ATTEND_BLOCK, max_seq)
@@ -84,24 +130,16 @@ def check(name, c, slots, max_seq):
     same_attention = all((bits(out[0]) == bits(outs[0][0])).all()
                          for out in outs)
 
-    # the decode rows' own path (the kernel, where ``_decode_kernel``
-    # gives it them): row 0 among short rows, among long ones, beside
-    # idle slots, and alone
-    same_kernel = None
+    # the decode rows' own path: the slabs, and a window layer's rings
+    # with row 0 before its ring has wrapped and after
+    same_kernel = same_rings = (None, None)
     if llama._decode_kernel(c, None, max_seq):
-        rows_path = jax.jit(
-            lambda xq, ks, vs, w_kvb, pos, active: llama._attend_slab(
-                xq, ks, vs, layer, None, pos, None, c, w_kvb,
-                visits=decode_attention.work_list(
-                    pos, active, llama.ATTEND_BLOCK, max_seq)))
-        far = pos.at[1:].set(max_seq - 2)
-        everyone = jnp.ones((slots,), bool)
-        outs = [rows_path(xq, cache[names[0]], cache[names[1]], w_kvb, p, a)
-                for p, a in ((pos, everyone), (far, everyone),
-                             (far, everyone.at[-1].set(False)),
-                             (pos, everyone.at[1:].set(False)))]
-        same_kernel = bool(all((bits(out[0]) == bits(outs[0][0])).all()
-                               for out in outs))
+        same_kernel = rows_in_company(c, cache[names[0]], cache[names[1]],
+                                      xq, w_kvb, max_seq - 2)
+        if c.window:
+            same_rings = rows_in_company(
+                c, cache["k_ring"], cache["v_ring"], xq, None, max_seq - 2,
+                c.window, owns=(437, 9001))
 
     # the whole step: the other rows short, one of them near its slab's
     # end, and that one inactive
@@ -163,7 +201,10 @@ def check(name, c, slots, max_seq):
         "attention_row0_bit_equal_at_its_own_bound_one_more_and_all":
             bool(same_attention),
         "kernel_row0_bit_equal_among_short_long_idle_rows_and_alone":
-            same_kernel,
+            same_kernel[0],
+        "ring_kernel_row0_bit_equal_wrapped_or_not_among_short_long_idle"
+        "_rows_and_alone": same_rings[0],
+        "ring_kernel_row0_max_abs_diff_from_the_walk": same_rings[1],
         "decode_step_row0_bit_equal_short_vs_long_active":
             bool((bits(a[0]) == bits(b[0])).all()),
         "decode_step_row0_bit_equal_short_vs_long_inactive":

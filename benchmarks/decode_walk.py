@@ -6,7 +6,10 @@ layer index, as the step programs have), run on each layer in turn,
 device ms a layer,
 the GB/s over the LIVE rows' own whole blocks of keys and values — of
 latent slabs the latents and rotary keys, the layer then with
-``w_kvb``'s two by-head products around either path — (what
+``w_kvb``'s two by-head products around either path; of a window
+layer's RINGS (PR 59: a shape with a window, its ``max_seq`` the ring's
+rows) the ring blocks a row has written, all of them once it has wrapped
+— (what
 the kernel reads; the walk reads every slot as far as the longest), and
 the largest difference between the two on an active row.  Times are the
 device's (``XLA Modules`` events of a profiler trace, median of the
@@ -33,7 +36,9 @@ from benchmarks.sampler_paths import device_ms
 # shape: (softmax layers with full slabs, slots, max_seq, heads, KV
 #         heads — 0: latent slabs, ranks 1536 / 512, 128 + 64 / 128 a
 #         head, both by-head products of the absorbed form beside either
-#         path —, the active rows' contexts; the other slots idle)
+#         path —, the active rows' contexts; the other slots idle[, the
+#         window: the slabs are then that many window layers' RINGS of
+#         ``max_seq`` rows — the window and a chunk of 512])
 SHAPES = {
     "olmo-hybrid-7b.longdoc": (4, 8, 12288, 30, 30,
                                (11000, 9500, 7700, 6000, 4100, 1500)),
@@ -49,28 +54,35 @@ SHAPES = {
         512 + 2048 * (7 * n % 48) // 47 for n in range(48))),
     "xing4.0-29b-a4b.docqa": (7, 16, 16384, 32, 0,
                               (1500, 4000, 6000, 11000)),
+    "command-a-plus.docqa.rings": (3, 16, 4608, 128, 8,
+                                   (26000, 9000, 6000), 4096),
 }
 RUNS = 8
 
 
 def measure(name):
-    layers, slots, max_seq, heads, kv_heads, contexts = SHAPES[name]
+    layers, slots, max_seq, heads, kv_heads, contexts, *window = SHAPES[name]
+    window = window[0] if window else 0
     on_chip = jax.default_backend() == "tpu"
     if not on_chip:                              # a rehearsal's size
         layers, max_seq = min(layers, 2), 1024
-        contexts = tuple(min(n, max_seq - 1) // 4 + 1 for n in contexts)
+        contexts = tuple((n if window else min(n, max_seq - 1)) // 4 + 1
+                         for n in contexts)      # a ring's have wrapped
+        window = min(window, max_seq - 256)
     latent = dict(q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128) if not kv_heads else {}
-    c = llama.LlamaConfig(vocab_size=256, dim=heads * 128, n_layers=layers,
-                          n_heads=heads, n_kv_heads=kv_heads or heads,
-                          head_width=128, mlp_dim=256, max_seq=max_seq,
-                          **latent)
-    cache = llama.init_kv_cache(c, slots, max_seq)
-    names = tuple(llama.kv_slabs(c))
+    # (of a model with a window: ``layers`` window layers to a full one)
+    rings = dict(window=window,
+                 window_pattern=(True,) * layers + (False,)) if window else {}
+    c = llama.LlamaConfig(vocab_size=256, dim=heads * 128,
+                          n_layers=layers + bool(window), n_heads=heads,
+                          n_kv_heads=kv_heads or heads, head_width=128,
+                          mlp_dim=256, max_seq=max_seq, **latent, **rings)
     keys = jax.random.split(jax.random.PRNGKey(47), 4)
-    ks, vs = (jax.jit(lambda k, like: jax.random.normal(
-        k, like.shape, jnp.float32).astype(like.dtype))(k, cache[n])
-        for k, n in zip(keys, names))
+    ks, vs = (jax.jit(lambda k, position=position: jax.random.normal(
+        k, (layers, slots, max_seq) + position, jnp.float32).astype(
+            c.dtype))(k)
+        for k, position in zip(keys[:2], llama.kv_slabs(c).values()))
     xq = jax.random.normal(keys[2], (slots, heads, c.head_dim),
                            jnp.float32).astype(c.dtype)
     w_kvb = (jax.random.normal(keys[3], (512, heads * 256), jnp.float32)
@@ -80,13 +92,14 @@ def measure(name):
     blocks = llama._span_blocks(max(contexts) + 1, max_seq)
 
     def walk(xq, ks, vs, w_kvb, i, pos, active):
-        return llama._attend_slab(xq, ks, vs, i, None, pos, blocks, c, w_kvb)
+        return llama._attend_slab(xq, ks, vs, i, None, pos, blocks, c, w_kvb,
+                                  window, pos)
 
     def kernel(xq, ks, vs, w_kvb, i, pos, active):
         # the step's work list is built once a step: not a layer's cost,
         # but small beside one
         return llama._attend_slab(
-            xq, ks, vs, i, None, pos, blocks, c, w_kvb,
+            xq, ks, vs, i, None, pos, blocks, c, w_kvb, window, pos,
             visits=decode_attention.work_list(
                 pos, active, llama.ATTEND_BLOCK, max_seq))
 
@@ -97,7 +110,8 @@ def measure(name):
            for path, run in paths.items()}
     live = np.asarray(active)
     line = {"shape": name, "layers": layers, "slots": slots,
-            "max_seq": max_seq, "heads": [heads, kv_heads],
+            "max_seq": max_seq, "window": window,
+            "heads": [heads, kv_heads],
             "flat_kv_heads": c.flat_kv_heads, "active": len(contexts),
             "max_abs_diff_active_rows": float(np.abs(
                 out["walk"][live] - out["kernel"][live]).max()),

@@ -154,6 +154,89 @@ def test_latent_kernel_agrees_with_the_walk(shape, monkeypatch):
     assert (np.asarray(got.astype(jnp.float32))[~active] == 0).all()
 
 
+def _ring_config(heads, kv_heads, head_dim, window):
+    """Window layers and full ones by turns: a ring's position is held
+    as a slab's, so ``_slabs`` of the ring's rows are its rings."""
+    return dataclasses.replace(
+        _config(heads, kv_heads, head_dim, 4096), window=window,
+        window_pattern=(True, False))
+
+
+def _ring_rows(c, xq, ks, vs, pos, active):
+    """``_attend_slab``'s decode rows over rings with the step's visits
+    of the RING's length: through the kernel, interpreted (the CPU)."""
+    pos = jnp.asarray(pos, jnp.int32)
+    return llama._attend_slab(
+        xq, ks, vs, 1, None, pos, None, c, None, c.window, pos,
+        da.work_list(pos, jnp.asarray(active), BLOCK, ks.shape[2]))
+
+
+# (heads, KV heads, head_dim, ring rows, window, each row's position —
+#  the third slot idle): a ring holds the window and a chunk
+RINGS = {
+    "not-yet-wrapped": (8, 4, 32, 48, 32, (30, 5, 16, 47)),
+    "wrapped-once": (8, 4, 32, 48, 32, (50, 70, 60, 95)),
+    "wrapped-many-times": (8, 4, 32, 48, 32, (500, 1007, 16, 4090)),
+    "a-row-exactly-at-the-window": (8, 4, 32, 48, 32, (31, 32, 40, 33)),
+    "heads-side-by-side": (10, 10, 32, 48, 32, (30, 70, 16, 1000)),
+    "128-lane-heads": (16, 2, 128, 64, 48, (200, 63, 64, 47)),
+    "ring-no-longer-than-a-block": (4, 2, 32, 12, 8, (11, 0, 5, 70)),
+    "ring-no-multiple-of-the-block": (4, 2, 32, 40, 24, (39, 33, 31, 100)),
+}
+
+
+@pytest.mark.parametrize("shape", RINGS)
+def test_ring_kernel_is_the_walk_to_the_bit(shape, monkeypatch):
+    """A window layer's rings through the kernel (PR 59): every active
+    row's output is ``_attend_slab``'s XLA walk's over the same rings
+    TO THE BIT — the same blocks in the order they lie, the same mask
+    (``_ring_holds``), the same sums and roundings — before the ring has
+    wrapped, after one turn and after many, for a row whose context is
+    exactly the window and one a position past it (128-lane heads:
+    within a rounding here, the CPU's doing); an idle slot's is
+    zeros."""
+    heads, kv_heads, head_dim, ring, window, pos = RINGS[shape]
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+    c = _ring_config(heads, kv_heads, head_dim, window)
+    assert c.flat_kv_heads == ("side-by-side" in shape)
+    xq, ks, vs = _slabs(c, len(pos), ring)
+    active = np.array([True, True, False, True])
+    pos = jnp.asarray(pos, jnp.int32)
+    want = llama._attend_slab(
+        xq, ks, vs, 1, None, pos, llama._span_blocks(jnp.max(pos) + 1, ring),
+        c, None, window, pos)
+    got = _ring_rows(c, xq, ks, vs, pos, active)
+    assert got.dtype == xq.dtype and got.shape == xq.shape
+    if head_dim < 128:
+        np.testing.assert_array_equal(_bits(got)[active],
+                                      _bits(want)[active])
+    else:       # XLA's CPU products group a 128-wide sum by the matrix's
+        np.testing.assert_allclose(          # shape: a rounding apart
+            np.asarray(got.astype(jnp.float32))[active],
+            np.asarray(want.astype(jnp.float32))[active], rtol=1e-2,
+            atol=1e-2)
+    assert (np.asarray(got.astype(jnp.float32))[~active] == 0).all()
+    # and what a row sees is its newest ``window`` positions, itself
+    # among them: against the plain softmax over them
+    held = np.asarray(llama._ring_holds(pos[:, None], jnp.arange(ring),
+                                        ring))
+    seen = (held >= 0) & (np.asarray(pos)[:, None] - held < window)
+    assert seen.sum(1).tolist() == [
+        min(int(p) + 1, window) for p in pos]
+    k, v = (np.asarray(x[1].astype(jnp.float32)).reshape(
+        len(pos), ring, kv_heads, head_dim) for x in (ks, vs))
+    q = np.asarray(xq.astype(jnp.float32)).reshape(
+        len(pos), kv_heads, heads // kv_heads, head_dim)
+    scores = np.einsum("rkgd,rtkd->rkgt", q, k) * head_dim ** -0.5
+    scores = np.where(seen[:, None, None], scores, -np.inf)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    plain = np.einsum("rkgt,rtkd->rkgd", probs / probs.sum(-1, keepdims=True),
+                      v).reshape(xq.shape)
+    np.testing.assert_allclose(
+        np.asarray(got.astype(jnp.float32))[active], plain[active],
+        rtol=2e-2, atol=2e-2)
+
+
 def _others(ks, vs, pos, active, case):
     """Row 0 stays as it is; what ``case`` changes of the others."""
     pos, active = np.array(pos), np.array(active)
@@ -171,7 +254,7 @@ def _others(ks, vs, pos, active, case):
     return ks, vs, pos, active
 
 
-@pytest.mark.parametrize("layout", ["flat", "heads-axis", "latent"])
+@pytest.mark.parametrize("layout", ["flat", "heads-axis", "latent", "ring"])
 @pytest.mark.parametrize("case", [
     "other-rows-longer", "other-rows-shorter", "other-slots-idle",
     "an-idle-slots-slab-nan", "only-the-last-slot-beside-it"])
@@ -179,9 +262,14 @@ def test_a_rows_output_is_its_own_to_the_bit(case, layout):
     """Row 0's output does not depend, to the bit, on the other rows'
     lengths, on which other slots are active (one live or all), or on
     what an idle slot's slab holds — NaN included: nothing of it is
-    read."""
+    read.  (``ring``: a window layer's rings of 48 rows under a window
+    of 32 — row 0 has not wrapped, its company wraps or not.)"""
     pos, active = (37, 20, 9, 40), (True,) * 4
-    if layout == "latent":
+    if layout == "ring":
+        c = _ring_config(8, 4, 32, 32)
+        xq, ks, vs = _slabs(c, 4, 48, seed=3)
+        rows = functools.partial(_ring_rows, c, xq)
+    elif layout == "latent":
         c = _latent_config(8, 48)
         xq, ks, vs, w_kvb = _latent_slabs(c, 4, 48, seed=3)
         rows = functools.partial(_latent_rows, c, xq, w_kvb=w_kvb)
@@ -195,6 +283,54 @@ def test_a_rows_output_is_its_own_to_the_bit(case, layout):
     beside = rows(ks, vs, pos=pos, active=active)
     assert np.isfinite(np.asarray(beside.astype(jnp.float32))).all()
     np.testing.assert_array_equal(_bits(alone[0]), _bits(beside[0]))
+
+
+@pytest.mark.parametrize("case", [
+    "other-rows-wrapped-further", "other-rows-not-wrapped",
+    "other-slots-idle", "an-idle-slots-slab-nan"])
+def test_a_wrapped_ring_rows_output_is_its_own_to_the_bit(case):
+    """Row 0 of a ring that has wrapped many times (position 1,000 in 48
+    rows): the same bits whether the other rows' rings have wrapped
+    further, not at all, or their slots are idle — an idle slot's ring
+    full of NaN included."""
+    c = _ring_config(8, 4, 32, 32)
+    xq, ks, vs = _slabs(c, 4, 48, seed=5)
+    pos, active = np.array([1000, 1020, 999, 1040]), np.array([True] * 4)
+    alone = _ring_rows(c, xq, ks, vs, pos, active)
+    if case == "other-rows-wrapped-further":
+        pos[1:] = [4000, 2047, 1001]
+    elif case == "other-rows-not-wrapped":
+        pos[1:] = [0, 31, 47]
+    elif case == "other-slots-idle":
+        active[1:] = False
+    else:
+        active[2] = False
+        ks, vs = (x.at[:, 2].set(jnp.nan) for x in (ks, vs))
+    beside = _ring_rows(c, xq, ks, vs, pos, active)
+    assert np.isfinite(np.asarray(beside.astype(jnp.float32))).all()
+    np.testing.assert_array_equal(_bits(alone[0]), _bits(beside[0]))
+
+
+def test_ring_work_list_reads_a_ring_as_far_as_it_is_written(monkeypatch):
+    """The ring's work list is ``work_list`` of the RING's length: an
+    active row's blocks 0 … ``pos // block`` until its ring has wrapped,
+    all the ring's blocks in the order they lie after — none skipped for
+    holding only positions behind the window — and none for an idle
+    slot; the host's ``read_positions`` of the ring counts the same."""
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", BLOCK)
+    pos = jnp.asarray([20, 500, 47, 48, 15], jnp.int32)
+    active = jnp.asarray([True, False, True, True, True])
+    rows, blocks, per_row, visits = da.work_list(pos, active, BLOCK, 48)
+    n = int(visits)
+    assert per_row.tolist() == [2, 0, 3, 3, 1] and n == 9
+    assert rows[:n].tolist() == [0, 0, 2, 2, 2, 3, 3, 3, 4]
+    assert blocks[:n].tolist() == [0, 1, 0, 1, 2, 0, 1, 2, 0]
+    assert llama.read_positions([21, 48, 49, 16], 48) == BLOCK * n
+    # a ring that is no multiple of the block: its last block starts
+    # early, and is the one more
+    assert da.blocks_read(jnp.asarray([31, 32, 39, 4000]),
+                          jnp.ones((4,), bool), BLOCK, 40).tolist() == [
+        2, 3, 3, 3]
 
 
 def test_work_list_visits_each_active_rows_own_blocks_in_order(monkeypatch):
@@ -234,9 +370,10 @@ def test_step_programs_through_the_kernel(name, through_the_kernel):
     parity tolerance and the same arg-max; and a row comes out of
     ``mixed_step`` with the BITS ``decode_step`` gives it — both call
     ``_decode_rows.attend``, so both get the kernel.  (Command A+'s
-    tiny preset: its window layers' rings keep the walk beside the full
-    layers' kernel; A.X-K1's and Xing4.0's: latent slabs, the kernel's
-    third layout between ``w_kvb``'s two by-head products.)"""
+    tiny preset: its window layers' rings of 32 rows — slot 2's has
+    wrapped — go through the kernel under the ring's mask beside the
+    full layers' slabs, PR 59; A.X-K1's and Xing4.0's: latent slabs, the
+    kernel's third layout between ``w_kvb``'s two by-head products.)"""
     cfg = dataclasses.replace(llama.CONFIGS[name], max_seq=64)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     prompts = {0: 23, 2: 40}                     # slot -> prompt tokens
@@ -246,6 +383,13 @@ def test_step_programs_through_the_kernel(name, through_the_kernel):
     last = jnp.asarray([3, 1, 4, 1], jnp.int32)
     ride = jax.random.randint(jax.random.PRNGKey(2), (16,), 0,
                               cfg.vocab_size)
+
+    windows = set()
+    attention = da.decode_attention
+
+    def watched(*args, window=0, **kwargs):
+        windows.add((args[1].shape[2], window))
+        return attention(*args, window=window, **kwargs)
 
     def run(on):
         through_the_kernel(on)
@@ -268,7 +412,13 @@ def test_step_programs_through_the_kernel(name, through_the_kernel):
             out.append(np.asarray(logits)[np.asarray(active)])
         return np.stack(out)
 
-    got, want = run(True), run(False)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(da, "decode_attention", watched)
+        got = run(True)
+    # the slabs' call, and a window layer's rings' with the window
+    assert windows == {(64, 0)} | ({(32, cfg.window)} if cfg.window
+                                   else set())
+    want = run(False)
     assert np.linalg.norm(got - want) / np.linalg.norm(want) < 0.02
     np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
 
@@ -276,9 +426,11 @@ def test_step_programs_through_the_kernel(name, through_the_kernel):
 def test_decode_kernel_keeps_the_walk_where_the_arguments_say():
     """``_decode_kernel``: off the TPU, under a mesh, for heads — or
     latents, or latent slabs' lengths — that are no whole lane tiles,
-    the XLA walk (a window layer's rings always:
-    ``test_step_programs_through_the_kernel``); latent slabs of whole
-    lane tiles take the kernel since PR 57."""
+    the XLA walk; latent slabs of whole lane tiles take the kernel
+    since PR 57, a window layer's rings with the slabs since PR 59 (the
+    rule is the slabs': a ring's position has the slab's shape, and no
+    option, variable or name chooses —
+    ``test_step_programs_through_the_kernel``)."""
     wide = _config(4, 2, 128, 64)
     assert not llama._decode_kernel(wide, None, 64)          # the CPU
     on_tpu = pytest.MonkeyPatch()
@@ -286,6 +438,11 @@ def test_decode_kernel_keeps_the_walk_where_the_arguments_say():
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
         assert llama._decode_kernel(wide, None, 64)
         assert not llama._decode_kernel(wide, object(), 64)  # a mesh
+        rings = _ring_config(4, 2, 128, 16)
+        assert llama._decode_kernel(rings, None, 64)
+        assert not llama._decode_kernel(rings, object(), 64)
+        assert not llama._decode_kernel(_ring_config(4, 2, 64, 16), None,
+                                        64)
         assert not llama._decode_kernel(_config(4, 2, 64, 64), None, 64)
         assert not llama._decode_kernel(llama.CONFIGS["axk1-tiny"], None,
                                         512)

@@ -553,13 +553,87 @@ def test_window_and_full_walks_are_counted_by_the_devices_rule(monkeypatch):
     assert window_walk_pct.read(obs) is None          # the parent's program
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["walk", "kernel"])
+def test_window_read_counters_count_what_the_dispatched_program_reads(
+        monkeypatch, kernel):
+    """``window_walk_positions`` / ``window_read_positions`` (PR 59), per
+    decode step of a model with window layers and from the host's own
+    ``kv_len``: what a walk bound by the longest active row reads of the
+    window layers' rings — window layers x slots x the ring's whole
+    blocks (16 here) up to that row, the ring's 32 rows at most — and
+    what the dispatched program reads: the same where the rows walk in
+    XLA (the CPU), window layers x the sum of each ACTIVE row's own
+    whole ring blocks where they go through
+    ``ops/pallas/decode_attention.py`` — the device's own rule,
+    ``decode_attention.blocks_read`` over the step's own inputs and the
+    RING's length — an idle slot adding nothing.  The reader
+    (``chipbench/layer_metrics/window_read_pct.py``) gives their ratio,
+    and None for a program without the counters: the parent's."""
+    from ant_ray_tpu.ops.pallas import decode_attention
+    from chipbench.layer_metrics import window_read_pct
+
+    monkeypatch.setattr(llama, "ATTEND_BLOCK", 16)
+    if kernel:
+        monkeypatch.setattr(llama, "_decode_kernel", lambda *a: True)
+    cfg = llama.CONFIGS["cmdaplus-tiny"]
+    eng = LLMEngine(cfg, slots=3, max_seq=96, prefill_chunk_tokens=16,
+                    tokenizer=_NoEos())
+    assert eng._ring == 32 and eng._decode_kernel == kernel
+    before = dict(eng.stats)
+    assert before["window_walk_positions"] == \
+        before["window_read_positions"] == 0
+    device = {"walk": 0, "read": 0, "steps": 0}
+
+    def watch(program):
+        def watched(params, cache, last, active, *chunk):
+            if not chunk or int(chunk[-1]):
+                blocks = np.asarray(decode_attention.blocks_read(
+                    np.asarray(cache["length"]), np.asarray(active), 16, 32))
+                device["walk"] += 6 * 3 * 16 * int(blocks.max())
+                device["read"] += 6 * 16 * int(blocks.sum())
+                device["steps"] += 1
+            return program(params, cache, last, active, *chunk)
+        return watched
+
+    monkeypatch.setattr(eng, "_decode_jit", watch(eng._decode_jit))
+    monkeypatch.setattr(eng, "_mixed_step_jit", watch(eng._mixed_step_jit))
+    eng.generate([list(range(3, 43)), [5, 9, 17], list(range(7, 20))],
+                 SamplingParams(max_tokens=12))
+    eng.generate([[44, 55, 66]], SamplingParams(max_tokens=20))
+    stats = eng.stats
+    assert stats["decode_steps"] == device["steps"] > 0
+    assert stats["window_walk_positions"] == device["walk"] > 0
+    assert stats["window_read_positions"] == (
+        device["read"] if kernel else device["walk"])
+    # rows of other lengths and idle slots: the kernel reads less
+    assert (stats["window_read_positions"]
+            < stats["window_walk_positions"]) == kernel
+    obs = {"traced": {"engine": dict(stats), "engine_before": before}}
+    assert window_read_pct.read(obs) == pytest.approx(
+        100.0 * (device["read"] if kernel else device["walk"])
+        / device["walk"])
+    assert (window_read_pct.read(obs) < 100.0) == kernel
+    for side in obs["traced"].values():
+        del side["window_read_positions"]
+    assert window_read_pct.read(obs) is None          # the parent's program
+    assert window_read_pct.read({"traced": None}) is None
+    assert window_read_pct.read({}) is None
+
+
 def test_a_model_without_window_layers_leaves_the_new_counters_at_zero(
         params):
+    from chipbench.layer_metrics import window_read_pct
+
     eng = _engine(params)
+    before = dict(eng.stats)
     eng.generate(list(PROMPTS), SamplingParams(max_tokens=6))
     assert eng._ring == 0 and eng.stats["decode_span_positions"] > 0
     assert eng.stats["full_span_positions"] == eng.stats[
         "window_span_positions"] == eng.stats["decode_rows_past_window"] == 0
+    assert eng.stats["window_walk_positions"] == eng.stats[
+        "window_read_positions"] == 0
+    assert window_read_pct.read({"traced": {
+        "engine": dict(eng.stats), "engine_before": before}}) is None
     assert [eng.stats[name] for name in RECURRENT_COUNTERS] == [0] * 5
 
 

@@ -328,6 +328,41 @@ def test_mixed_cache_fits_the_chip_at_the_cells_size(v5e, program):
         assert not re.search(rf"= bf16\[1,{read},4608,8,128\]", text)
 
 
+def test_window_decode_rows_read_the_rings_where_they_lie(v5e, monkeypatch):
+    """`command-a-plus.docqa`'s decode rows on the chip (PR 59): every
+    one of the four layers' attention is a call of
+    ``ops/pallas/decode_attention.py`` — the three window layers' handed
+    the carried RINGS whole (the same bytes with a position's heads as
+    rows: a bitcast) and the work list of the ring's 18 blocks a slot,
+    the full layer's the slabs and theirs — no operation of the
+    program's own writes a ring- or block-shaped buffer of the slots'
+    keys or values (the walk took a block of all 16 slots out of the
+    rings an iteration), no loop is left in the step (the layers are
+    unrolled, and the walk was the only other), and the program fits
+    the chip as before."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, cache = _compile_step(
+        v5e.devices[0], "decode", MIXED, 16, 32768, chunk=512)
+    text = compiled.as_text()
+    calls = [line[line.index("operand_layout"):]
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and "decode_attention" in line]
+    rings = [call for call in calls if "bf16[3,16,36864,128]" in call]
+    slabs = [call for call in calls if "bf16[1,16,262144,128]" in call]
+    assert (len(calls), len(rings), len(slabs)) == (4, 3, 1)
+    # (row, block) visits: 16 slots x 18 blocks of a ring, 128 of a slab
+    assert all("s32[288]" in call for call in rings)
+    assert "s32[2048]" in slabs[0]
+    assert step_weight_copies.materialised(
+        text, step_weight_copies.slab_buffers(MIXED, 16, 4608)) == []
+    assert not re.search(r"\bwhile\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(cache)
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < 13.0 * 2 ** 30
+
+
 # Ouro-2.6B whole, as `ouro-2.6b.rollout` serves it: 48 layers that a
 # token passes through four times, sandwich norms, the exit gate, the
 # whole vocabulary; 192 slab layers in the cache.
