@@ -922,7 +922,7 @@ def param_shardings(config: LlamaConfig, mesh) -> dict:
 
 def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
                 attend, constrain_act, index=None, kind: str = "full",
-                tile: int = 0):
+                tile: int = 0, live=None):
     """One transformer block on ``x`` (..., dim), the only place its
     equations are written: training hands it (batch, seq, dim), a
     prefill chunk and a decode step their rows, (chunk, dim) and
@@ -937,7 +937,8 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     (``_latent_qkv``).  ``positions``: int32 of ``x``'s leading shape,
     None for arange over the sequence.  ``index``: the layer's number
     where ``layer`` holds the whole stack's expert matrices, ``tile``
-    the grouped kernel's row tile there (``_routed_mlp``); only a STEP
+    the grouped kernel's row tile there and ``live`` which of the rows
+    anybody reads (``_routed_mlp``); only a STEP
     program passes it, and the products it reshapes to heads and its
     MLP's then spell their rounding out (``_proj``, ``_swiglu``).
     ``kind``: the layer's, of ``LlamaConfig.kinds``.  A "window"
@@ -1021,7 +1022,7 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     if c.sandwich_norm:
         attn = _norm(attn, layer["ln_attn_out"], c)
     if c.parallel_block:
-        out, load = _mlp(layer, h, c, index, tile)
+        out, load = _mlp(layer, h, c, index, tile, live)
         x = x + attn + out.astype(x.dtype)
         return constrain_act(x, ("batch", "seq", "embed")), state, load
     x = _residual(x, attn, c, streams)
@@ -1029,7 +1030,7 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
 
     x, streams = _hc_read(layer, x, c, "hc_mlp")
     h = x if c.norm_after else _norm(x, layer["ln_mlp"], c)
-    out, load = _mlp(layer, h, c, index, tile)
+    out, load = _mlp(layer, h, c, index, tile, live)
     out = out.astype(x.dtype)
     if c.norm_after:
         out = _norm(out, layer["ln_mlp"], c)
@@ -1385,18 +1386,20 @@ def _swiglu(h, w_gate, w_up, w_down, step: bool = False):
             ) @ w_down
 
 
-def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
+def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0, live=None):
     """The block's feed-forward on ``h`` (..., dim): dense SwiGLU, or
     the routed experts, with the shared expert beside them where the
     model has one.  Returns ``(out, load)``; ``load`` is the
     (num_experts,) int32 count of rows each expert held was given, None
     for a dense layer.  The one MLP of training, chunks and decode; a
     step program's (it alone passes ``index``) spells its roundings out
-    (``_swiglu``)."""
+    (``_swiglu``) and says which rows are ``live`` — to the routed
+    experts alone, whose bytes follow the rows (``_routed_mlp``): a
+    dense product reads its weights once whatever the rows."""
     if not c.num_experts:
         return _swiglu(h, layer["w_gate"], layer["w_up"],
                        layer["w_down"], index is not None), None
-    out, load = _routed_mlp(layer, h, c, index, tile)
+    out, load = _routed_mlp(layer, h, c, index, tile, live)
     if c.n_shared_experts:
         with jax.named_scope("moe_shared"):
             # one SwiGLU as wide as all the shared experts together is
@@ -1409,7 +1412,8 @@ def _mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
     return out, load
 
 
-def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
+def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0,
+                live=None):
     """Top-k mixture of experts; every token is computed by those of its
     k experts that are held here, and none is dropped.
 
@@ -1450,15 +1454,34 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
       of a share stops multiplying tiles of masked rows; a row's sum is
       its own whatever rows share its tile.
 
-    Where the router is wider than the experts held (``router_width``
-    against ``num_experts`` from ``first_expert`` on), the routing is
-    over all of them and the products over the held ones: an assignment
-    to an absent expert sorts behind the last group, belongs to no
-    group — the grouped product's sizes add up to the assignments held,
-    so it neither reads a weight nor multiplies for it — and counts
-    zero in the sum, by a select on its row's place (the product's
-    output holds there whatever the buffer held).  Nothing stands in
-    for the absent experts.
+    Three kinds of assignment belong to NO GROUP: they are labelled
+    ``num_experts``, sort behind the last group and are dropped by the
+    count, so the grouped product's sizes add up to the assignments
+    that are somebody's — it neither reads a weight nor multiplies for
+    the others — and they count zero in the sum, by a select on their
+    row's place (the product's output holds there whatever the buffer
+    held).
+
+    * An ABSENT expert's.  Where the router is wider than the experts
+      held (``router_width`` against ``num_experts`` from
+      ``first_expert`` on), the routing is over all of them and the
+      products over the held ones.  Nothing stands in for the absent
+      experts.
+    * An IDLE SLOT's.  A decode step computes one row a slot, and the
+      row of a slot that is not active is whatever its stale token
+      makes of it: routed like any other it would fall on experts no
+      live row asked for, and their weights would be read for a
+      product nobody reads.
+    * A chunk's PADDING rows' (behind the prompt's last token) and a
+      block step's DEAD CLOSING rows' (a slot with no block closing).
+
+    ``live`` ((rows,) bool, None: every row) says which rows anybody
+    reads; the step programs pass it (``_row_groups``), training does
+    not.  All k picks of a row that is not live are of no group: the
+    row comes back as zeros, and every live row's sum is bit for bit
+    what it is with no mask — a row's products are its own whatever
+    shares its tile, the stable sort keeps the live rows' order within
+    an expert, and a token's picks are added in the picks' order.
 
     ``layer``'s expert matrices are one layer's (experts, in, out), as
     a scan over the stacked layers slices them — or, with ``index``,
@@ -1497,6 +1520,10 @@ def _routed_mlp(layer: dict, h, c: LlamaConfig, index=None, tile=0):
             experts = experts - c.first_expert
             experts = jnp.where((experts >= 0) & (experts < n_exp),
                                 experts, n_exp)
+        if live is not None:
+            # a row nobody reads: its k picks are of no group either
+            experts = jnp.where(jnp.repeat(live.reshape(-1), k), experts,
+                                n_exp)
         order = jnp.argsort(experts)                       # stable
         load = jnp.zeros((n_exp,), jnp.int32).at[experts].add(1)
         rows = x[order // k]                               # sorted by expert
@@ -2029,15 +2056,19 @@ def _count_exits(cache: dict, gates, counted) -> dict:
 # What the step programs count of a routed
 # model's routing, summed over layers and executions in
 # ``cache["routing"]`` (uint32, wraps; a reader takes differences).  They
-# count the rows the program computed, padded and idle ones included:
-# that is what decides which expert weights a step reads.  The first four
-# are over the experts HELD (all of them, unless the model is a share).
+# count the LIVE rows' pairs — the rows somebody reads: an active slot's,
+# a chunk's real tokens', a closing block's (``_row_groups``) — for those
+# alone reach an expert and decide which expert weights a step reads; the
+# pairs of the other rows a program computes are ``moe_dead_pairs``.  The
+# first four are over the experts HELD (all of them, unless the model is
+# a share).
 ROUTING_COUNTERS = (
-    "moe_assignments",    # (row, expert) pairs computed
+    "moe_assignments",    # (live row, expert) pairs computed
     "moe_experts_hit",    # experts given at least one row
     "moe_expert_slots",   # experts there were: num_experts a layer
     "moe_load_max",       # rows of each layer's busiest expert, summed
-    "moe_rows_routed",    # (row, expert) pairs routed: rows * k, held or not
+    "moe_rows_routed",    # (live row, expert) pairs routed: live rows * k,
+                          # on an expert held or not
     # The same of the decode steps alone: a chunk's rows are ONE
     # sequence's and route alike, so what a decode step reads cannot be
     # told from counters that a window's share of chunks moves.  A
@@ -2048,6 +2079,11 @@ ROUTING_COUNTERS = (
     # length times its tile, a layer): what it multiplied, where
     # ``moe_assignments`` is what it was asked; 0 under XLA's kernel
     "moe_tile_rows",
+    # (row, expert) pairs of the rows that were NOT live — idle slots,
+    # padding, dead closing rows: rows * k of them a layer, which reached
+    # no expert.  Over these plus ``moe_rows_routed``: the share of a
+    # program's rows the routed experts were spared
+    "moe_dead_pairs",
 )
 
 
@@ -2085,10 +2121,11 @@ def _hoist_experts(layers: dict, c: LlamaConfig, stack: str = "layers"):
 
 
 def _count_routing(cache: dict, loads, routed, decode: bool,
-                   tile: int = 0) -> dict:
+                   tile: int = 0, dead=0) -> dict:
     """``loads``: (layers, num_experts) rows per expert held of one
     execution, None for a dense model; ``routed``: the (row, expert)
-    pairs its routers made, held or not; ``decode``: the execution is a
+    pairs its routers made for live rows, held or not, and ``dead``
+    those of its other rows; ``decode``: the execution is a
     decode step; ``tile``: the grouped kernel's row tile, 0 under XLA's
     kernel -> the cache entries to carry."""
     if loads is None:
@@ -2100,7 +2137,7 @@ def _count_routing(cache: dict, loads, routed, decode: bool,
     visited = tile * jnp.sum(jax.vmap(grouped_matmul.visits, (0, None))(
         loads, tile)) if tile else 0
     seen = jnp.concatenate([seen, apart if decode else apart * 0,
-                            jnp.stack([visited])])
+                            jnp.stack([visited, dead])])
     return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
 
 
@@ -2352,7 +2389,7 @@ def _pass_first(c: LlamaConfig, u):
 
 
 def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
-                 write_attend, write_state=None, *, decode: bool,
+                 write_attend, write_state=None, live=None, *, decode: bool,
                  mesh=None, counted=None):
     """A step program's layers over rows ``x`` (rows, dim): a
     ``lax.scan`` over each stack's PERIODS of the layer pattern
@@ -2362,7 +2399,11 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     carry is the rows and the whole cache.  Returns (x, the cache's new
     entries: its slabs, its counters — a ``decode`` step's counted
     apart as well, ``ROUTING_COUNTERS``).  ``mesh``: the one the
-    parameters are sharded over, if any (``_grouped_tile``).
+    parameters are sharded over, if any (``_grouped_tile``).  ``live``
+    ((rows,) bool, as ``_row_groups`` joins it; None: every row) is
+    made once a program and goes to every layer's routed experts, which
+    a row that is not live does not reach (``_routed_mlp``); a dense
+    layer's ``_mlp`` drops it.
 
     A looped model (``LlamaConfig.loops``) runs that scan ``loops``
     times, as a ``lax.scan`` over the passes AROUND it — one loop in
@@ -2373,7 +2414,8 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     exit gate reads each pass's normed rows: ``counted`` ((rows,) bool:
     a decode step's active rows, None where the program has none) says
     whose exit distribution goes into ``cache["exits"]``
-    (``EXIT_COUNTERS``).
+    (``EXIT_COUNTERS``) — fewer than ``live``: a riding chunk's
+    tokens are live and are no decode rows.
 
     ``write_attend(ks, vs, i, window, xq, xk, xv[, w_kvb]) -> (out, (ks,
     vs))`` is the block's attention over the carried slabs of the
@@ -2432,7 +2474,7 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                 x, kept, load = apply_block(
                     {**_place(layers, whole, j, cfg), **experts[stack]}, x,
                     cfg, cos, sin, positions, attend, _unconstrained,
-                    p * a_period + rank, kind, tile)
+                    p * a_period + rank, kind, tile, live)
                 slabs = {**slabs, **dict(zip(held, kept))}
                 loads.append(load)
             return (x, slabs), (loads[0] if len(loads) == 1
@@ -2468,10 +2510,16 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     else:
         carry, gates = lax.scan(a_pass, carry, jnp.arange(c.loops))
         loads = None
-    routed = None if loads is None else (
-        loads.shape[0] * x.shape[0] * c.experts_per_token)
+    routed = dead = None
+    if loads is not None:
+        # (row, expert) pairs of every layer's router: the live rows'
+        # and the others', which reached no expert
+        pairs = loads.shape[0] * c.experts_per_token
+        rows = x.shape[0] if live is None else jnp.sum(live)
+        routed, dead = pairs * rows, pairs * (x.shape[0] - rows)
     return carry[0], {**carry[1],
-                      **_count_routing(cache, loads, routed, decode, tile),
+                      **_count_routing(cache, loads, routed, decode, tile,
+                                       dead),
                       **_count_exits(cache, gates, counted)}
 
 
@@ -2480,7 +2528,8 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
     """What the ``chunk`` rows of ONE prompt in ``slot`` — ``chunk_len``
     real tokens from absolute position ``start`` on, the rest padding —
     do with a layer, for ``_row_groups``: ``(rows, their positions,
-    write, attend, state)``.  Under ``block_length`` a row sees its
+    which are live, write, attend, state)``; live are the real tokens.
+    Under ``block_length`` a row sees its
     whole block, as far as the chunk's own end: the engine's chunks
     hold whole blocks from a block's first place on."""
     max_seq = _slab_positions(cache, c)
@@ -2539,14 +2588,14 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
         tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
         return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
 
-    return chunk, pos, write, attend, state
+    return chunk, pos, offs < chunk_len, write, attend, state
 
 
 def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
     """What a decode step's rows — one a slot, those ``active`` live —
     do with a layer, for ``_row_groups``: ``(rows, their positions,
-    write, attend, state)``.  ``mesh``: the one the slabs are sharded
-    over, if any (``_decode_kernel``)."""
+    which are live, write, attend, state)``.  ``mesh``: the one the
+    slabs are sharded over, if any (``_decode_kernel``)."""
     max_seq = _slab_positions(cache, c)
     pos = cache["length"]                       # (slots,) write position
     # The walk ends behind the longest ACTIVE row: an idle slot that
@@ -2596,14 +2645,15 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
         return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),
                      lax.dynamic_update_index_in_dim(conv, tail, i, 0))
 
-    return pos.shape[0], pos, write, attend, state
+    return pos.shape[0], pos, active, write, attend, state
 
 
 def _block_rows(cache: dict, c: LlamaConfig, live, first, mesh=None):
     """What ``block_length`` rows a slot — slot ``r``'s block at
     positions ``first[r]`` .. ``first[r] + block_length - 1``,
     slot-major, those of ``live`` slots live — do with a layer, for
-    ``_row_groups``: ``(rows, their positions, write, attend, state)``.
+    ``_row_groups``: ``(rows, their positions, which are live, write,
+    attend, state)``.
     Written then attended, as every group: a place's keys and values lie
     BEHIND the slot's length until a step moves it over them
     (``_step_lengths``), and the next step of a block in flight writes
@@ -2621,7 +2671,8 @@ def _block_rows(cache: dict, c: LlamaConfig, live, first, mesh=None):
     pos = (first[:, None] + jnp.arange(size, dtype=jnp.int32)).reshape(-1)
     # a place behind the slab's end is dropped by the scatter, an idle
     # slot's pushed there (``_rows_of``)
-    write_pos = _rows_of(pos, jnp.repeat(live, size), max_seq, max_seq)
+    live_rows = jnp.repeat(live, size)
+    write_pos = _rows_of(pos, live_rows, max_seq, max_seq)
     seen = first + size - 1                      # (slots,) a block's last
     blocks = _span_blocks(jnp.max(jnp.where(live, seen, 0)) + 1, max_seq)
     visits = decode_attention.work_list(
@@ -2644,12 +2695,12 @@ def _block_rows(cache: dict, c: LlamaConfig, live, first, mesh=None):
                                c.head_dim).transpose(0, 2, 1, 3, 4).reshape(
                 xq.shape)
 
-    return n_slots * size, pos, write, attend, None
+    return n_slots * size, pos, live_rows, write, attend, None
 
 
 def _row_groups(*groups):
-    """``_scan_layers``' ``(positions, write_attend, write_state)`` for
-    a step program whose rows are ``groups`` laid end to end
+    """``_scan_layers``' ``(positions, write_attend, write_state,
+    live)`` for a step program whose rows are ``groups`` laid end to end
     (``_decode_rows``, ``_chunk_rows``): a layer's rows are split where
     the groups meet, EVERY group writes its rows into the carried slabs
     first, then each attends over them as it does alone, one group
@@ -2663,7 +2714,15 @@ def _row_groups(*groups):
     token each but a block's places, 0 to all of which the step's
     transfer rule fills; behind them, where the caller has blocks
     CLOSING, as many rows a slot again: ``_step_rows``) — and a chunk's
-    rows may ride behind it."""
+    rows may ride behind it.
+
+    ``live`` ((rows,) bool) joins what each group knows of its rows:
+    an active slot's, a chunk's real tokens, a block of a live slot —
+    the closing rows' only where the slot has a block closing.  A row
+    that is not live writes nothing (each group pushes its write out of
+    bounds) and nobody reads what it becomes; it is computed all the
+    same, the shapes being static, except where its cost follows the
+    rows: the routed experts (``_routed_mlp``)."""
     edges = [0]
     for rows, *_ in groups:
         edges.append(edges[-1] + rows)
@@ -2677,10 +2736,10 @@ def _row_groups(*groups):
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
     def write_attend(ks, vs, i, window, xq, xk, xv, w_kvb=None):
-        for (_, _, write, _, _), k, v in zip(groups, split(xk), split(xv)):
+        for (*_, write, _, _), k, v in zip(groups, split(xk), split(xv)):
             ks, vs = write(ks, vs, i, k, v)
         outs = []
-        for (_, _, _, attend, _), q in zip(groups, split(xq)):
+        for (*_, attend, _), q in zip(groups, split(xq)):
             if outs:
                 # One walk hands the slabs to the next: as two readers
                 # of one array the TPU compiler gave both walks a copy
@@ -2702,7 +2761,7 @@ def _row_groups(*groups):
         return join(outs), (s, conv)
 
     return (join([pos for _, pos, *_ in groups]), write_attend,
-            write_state)
+            write_state, join([live for _, _, live, *_ in groups]))
 
 
 def _embed(params: dict, tokens, c: LlamaConfig):

@@ -175,7 +175,7 @@ def check(name, c, slots, max_seq, chunk=0):
                   llama._chunk_rows(held, c, chunk, ride, start, n))
         lo, hi = {"both": (0, slots + chunk), "decode": (0, slots),
                   "chunk": (slots, slots + chunk)}[parts]
-        _, write_attend, _ = llama._row_groups(*(
+        _, write_attend, _, _ = llama._row_groups(*(
             groups if parts == "both" else groups[parts == "chunk":][:1]))
         return write_attend(held[names[0]], held[names[1]], layer, 0,
                             xq2[lo:hi],

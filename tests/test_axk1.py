@@ -422,22 +422,27 @@ def test_engine_greedy_tokens_are_the_references_and_routing_is_counted(
     assert runs == stats["decode_steps"] + chunks - stats["chunks_fused"] + 1
     assert 0 < stats["moe_assignments"] < stats["moe_rows_routed"]
     assert stats["moe_experts_hit"] <= stats["moe_expert_slots"]
-    # a chunk routes 8 rows, a decode step 3, each to 2 of 8 experts
+    # a chunk computes 8 rows, a decode step 3, each routed to 2 of 8
+    # experts in 2 layers: the prompts' tokens and the decoding rows are
+    # live, the others' pairs (the empty run's all) reach no expert
     assert stats["moe_rows_routed"] == 2 * 2 * (
+        sum(map(len, prompts)) + stats["decode_slots"])
+    assert stats["moe_rows_routed"] + stats["moe_dead_pairs"] == 2 * 2 * (
         8 * chunks + 3 * stats["decode_steps"] + (3 + 8))
 
 
 def test_decode_steps_are_counted_apart_from_chunks(params):
     """``ROUTING_COUNTERS``: the first five over every execution, the
     ``moe_decode_*`` four over the decode steps alone — 2 chunks of 16
-    rows, then 5 steps of 3 rows, 2 routed layers of 4 held experts,
-    2 picks a row over the router's 8."""
+    rows, then 5 steps of 3 rows of which ONE is live, 2 routed layers
+    of 4 held experts, 2 picks a row over the router's 8."""
     _, cache = through_the_cache(CFG, params, tokens_of(31, 37), 32)
     seen = dict(zip(llama.ROUTING_COUNTERS, np.asarray(cache["routing"])))
     assert seen["moe_expert_slots"] == 2 * 4 * (2 + 5)
     assert seen["moe_decode_expert_slots"] == 2 * 4 * 5
-    assert seen["moe_rows_routed"] == 2 * 2 * (2 * 16 + 5 * 3)
-    assert seen["moe_decode_rows_routed"] == 2 * 2 * 5 * 3
+    assert seen["moe_rows_routed"] == 2 * 2 * (2 * 16 + 5 * 1)
+    assert seen["moe_decode_rows_routed"] == 2 * 2 * 5 * 1
+    assert seen["moe_dead_pairs"] == 2 * 2 * 5 * 2
     assert 0 < seen["moe_decode_assignments"] < seen["moe_assignments"]
     assert 0 < seen["moe_decode_experts_hit"] < seen["moe_experts_hit"]
     assert seen["moe_decode_experts_hit"] <= seen["moe_decode_expert_slots"]

@@ -672,6 +672,7 @@ def test_the_linear_kinds_cache_is_what_it_was():
         "v": ((2, 3, 64, 2, 16), "float32"),
         "s": ((6, 3, 4, 16, 16), "float32"),
         "conv": ((6, 3, 3, 192), "float32"),
-        "length": ((3,), "int32"), "routing": ((10,), "uint32")}
+        "length": ((3,), "int32"),
+        "routing": ((len(llama.ROUTING_COUNTERS),), "uint32")}
     assert llama.CONFIGS["tiny"].recurrent == ""
     assert llama.state_slabs(llama.CONFIGS["cmdaplus-tiny"]) == {}

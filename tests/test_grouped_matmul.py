@@ -165,6 +165,16 @@ def test_a_tiling_that_does_not_tile_is_refused():
 
 # ------------------------------------------------ through ``_routed_mlp``
 
+def _routed_layer(name):
+    """``(the routed stack's config, its layer 1 with the whole stack's
+    expert matrices, weights x 8 so that the routing is uneven)``."""
+    cfg = llama.CONFIGS[name].stacks()["layers"]
+    stack = llama.init_params(llama.CONFIGS[name],
+                              jax.random.PRNGKey(3))["layers"]
+    return cfg, {**{k: v[1] * 8 for k, v in stack.items()},
+                 **{k: stack[k] * 8 for k in ("w_gate", "w_up", "w_down")}}
+
+
 @pytest.mark.parametrize("name", ["moe-tiny", "olmoe-tiny", "axk1-tiny",
                                   "cmdaplus-tiny"])
 @pytest.mark.parametrize("rows", [5, 48])
@@ -173,11 +183,7 @@ def test_routed_mlp_through_the_kernel_is_what_ragged_dot_gives(name, rows):
     held, and a share whose absent experts' rows sort behind — with the
     kernel (tile 16) against XLA's product, the stack whole and the
     layer traced; and the same ``load``."""
-    cfg = llama.CONFIGS[name].stacks()["layers"]
-    stack = llama.init_params(llama.CONFIGS[name],
-                              jax.random.PRNGKey(3))["layers"]
-    layer = {**{k: v[1] * 8 for k, v in stack.items()},
-             **{k: stack[k] * 8 for k in ("w_gate", "w_up", "w_down")}}
+    cfg, layer = _routed_layer(name)
     h = jax.random.normal(jax.random.PRNGKey(4), (rows, cfg.dim))
 
     def run(tile):
@@ -206,11 +212,7 @@ def test_rows_of_no_group_may_hold_anything(name, monkeypatch):
         return jnp.where((jnp.arange(out.shape[0]) < jnp.sum(sizes))[:, None],
                          out, jnp.nan)
 
-    cfg = llama.CONFIGS[name].stacks()["layers"]
-    stack = llama.init_params(llama.CONFIGS[name],
-                              jax.random.PRNGKey(3))["layers"]
-    layer = {**{k: v[1] * 8 for k, v in stack.items()},
-             **{k: stack[k] * 8 for k in ("w_gate", "w_up", "w_down")}}
+    cfg, layer = _routed_layer(name)
     h = jax.random.normal(jax.random.PRNGKey(4), (48, cfg.dim))
 
     def run(tile):
@@ -260,7 +262,8 @@ def test_step_programs_through_the_kernel(name, monkeypatch):
     got, got_routing = run(16)
     want, want_routing = run(0)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-    np.testing.assert_array_equal(got_routing[:-1], want_routing[:-1])
+    tiled = np.arange(len(got_routing)) == counted("moe_tile_rows")
+    np.testing.assert_array_equal(got_routing[~tiled], want_routing[~tiled])
     assert want_routing[counted("moe_tile_rows")] == 0
     tile_rows = int(got_routing[counted("moe_tile_rows")])
     assert tile_rows % 16 == 0
@@ -276,15 +279,17 @@ def test_tile_rows_are_the_work_lists_visits():
                         jnp.int32)
     cache = {"routing": jnp.zeros((len(llama.ROUTING_COUNTERS),),
                                   jnp.uint32)}
+    tile_rows = llama.ROUTING_COUNTERS.index("moe_tile_rows")
     seen = np.asarray(llama._count_routing(cache, loads, 99, True, 16)[
         "routing"])
     # layer 0: rows 0-2 | 3-22 | 23 -> tiles 0 | 0, 1 | 1 = 4 visits;
     # layer 1: none -> 1; layer 2: 0 | 1 | 2 = 3
-    assert seen[llama.ROUTING_COUNTERS.index("moe_tile_rows")] == 16 * 8
+    assert seen[tile_rows] == 16 * 8
     assert seen[0] == 57
     none = np.asarray(llama._count_routing(cache, loads, 99, True, 0)[
         "routing"])
-    assert none[-1] == 0 and (none[:-1] == seen[:-1]).all()
+    assert none[tile_rows] == 0
+    assert (np.delete(none, tile_rows) == np.delete(seen, tile_rows)).all()
 
 
 def test_grouped_tile_keeps_ragged_dot_off_the_tpu_and_under_a_mesh(
@@ -300,3 +305,206 @@ def test_grouped_tile_keeps_ragged_dot_off_the_tpu_and_under_a_mesh(
     assert llama._grouped_tile(wide, 16, None) == 32        # 8 * 1: the least
     assert llama._grouped_tile(wide, 128, None) == 64       # 8 * 128 * 8 / 128
     assert llama._grouped_tile(wide, 512, None) == 128      # the most
+
+
+# ------------------------------------------------- rows that nobody reads
+
+# a share's (absent experts' picks are of no group already) and models
+# that hold every expert; through the interpreter's kernel and XLA's
+MASKED = ["cmdaplus-tiny", "axk1-tiny", "olmoe-tiny", "xing4-tiny"]
+BOTH_PRODUCTS = pytest.mark.parametrize("tile", [16, 0],
+                                        ids=["kernel", "ragged_dot"])
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+@BOTH_PRODUCTS
+@pytest.mark.parametrize("name", MASKED)
+def test_a_row_nobody_reads_reaches_no_expert(name, tile):
+    """``_routed_mlp`` with ``live``: a live row's sum is bit for bit
+    the same call's without the mask, a dead row comes back as zeros,
+    and ``load`` counts the live rows' held picks alone — what the same
+    block gives the live rows by themselves."""
+    cfg, layer = _routed_layer(name)
+    h = jax.random.normal(jax.random.PRNGKey(4), (48, cfg.dim))
+    live = jax.random.bernoulli(jax.random.PRNGKey(5), 0.3, (48,))
+    assert 4 < int(live.sum()) < 24
+    run = jax.jit(lambda h, live: llama._routed_mlp(
+        layer, h, cfg, jnp.int32(1), tile, live))
+    got, load = run(h, live)
+    want, every = run(h, None)
+    on = np.asarray(live)
+    np.testing.assert_array_equal(_bits(got)[on], _bits(want)[on])
+    assert (np.asarray(got)[~on] == 0).all()
+    _, alone = run(h[on], None)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(alone))
+    assert int(load.sum()) < int(every.sum())
+    assert (np.asarray(load) <= np.asarray(every)).all()
+    # every row live: the mask changes nothing
+    full, same = run(h, jnp.ones((48,), bool))
+    np.testing.assert_array_equal(_bits(full), _bits(want))
+    np.testing.assert_array_equal(np.asarray(same), np.asarray(every))
+
+
+@BOTH_PRODUCTS
+@pytest.mark.parametrize("name", ["cmdaplus-tiny", "olmoe-tiny"])
+def test_no_row_live_is_one_empty_visit(name, tile):
+    """All rows dead (the mixed program's empty run in a server's
+    warm-up): no group has a row, the kernel makes its ONE visit of an
+    empty group, and zeros come back — no NaN from rows never stored."""
+    cfg, layer = _routed_layer(name)
+    h = jax.random.normal(jax.random.PRNGKey(4), (48, cfg.dim))
+    out, load = jax.jit(lambda: llama._routed_mlp(
+        layer, h, cfg, jnp.int32(1), tile, jnp.zeros((48,), bool)))()
+    assert int(load.sum()) == 0 and int(gm.visits(load, 16)) == 1
+    assert (np.asarray(out) == 0).all()
+
+
+def _without_the_mask(monkeypatch):
+    """The parent's formulation: the routed block never learns which
+    rows are live."""
+    routed = llama._routed_mlp
+    monkeypatch.setattr(
+        llama, "_routed_mlp",
+        lambda layer, h, c, index=None, tile=0, live=None: routed(
+            layer, h, c, index, tile))
+
+
+def _force_tile(monkeypatch, tile):
+    monkeypatch.setattr(llama, "_grouped_tile",
+                        lambda c, rows, mesh: tile if c.num_experts else 0)
+
+
+def _counted(before, after):
+    return dict(zip(llama.ROUTING_COUNTERS, (
+        np.asarray(after["routing"]).astype(np.int64)
+        - np.asarray(before["routing"]).astype(np.int64)).tolist()))
+
+
+def _same_but_the_counters(got, want):
+    assert got.keys() == want.keys()
+    for name in got:
+        if name != "routing":
+            np.testing.assert_array_equal(np.asarray(got[name]),
+                                          np.asarray(want[name]), name)
+
+
+@BOTH_PRODUCTS
+@pytest.mark.parametrize("name", MASKED)
+def test_a_decode_steps_idle_slots_reach_no_expert(name, tile, monkeypatch):
+    """``_decode`` with 2 of 8 slots active behind a prompt each: the
+    active rows' logits and everything the step writes are the bits of
+    the program whose routed block takes no mask; the six idle slots'
+    pairs are counted dead and hit no expert."""
+    cfg = llama.CONFIGS[name]
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    size = {"slots": 8, "max_seq": 64, "chunk": 16}
+    active = jnp.asarray([s in (1, 5) for s in range(8)])
+    last = jax.random.randint(jax.random.PRNGKey(2), (8,), 0, 256)
+
+    def run():
+        _force_tile(monkeypatch, tile)
+        steps = step_programs(cfg, **size)      # traced under the patches
+        cache = llama.init_kv_cache(cfg, 8, 64, chunk=16)
+        for slot, n in ((1, 16), (5, 9)):
+            tokens = jax.random.randint(jax.random.PRNGKey(slot), (16,), 0,
+                                        256)
+            _, cache = steps.prefill_chunk(params, cache, tokens, slot, 0, n)
+        before = {"routing": np.asarray(cache["routing"])}
+        logits, cache = steps.decode(params, cache, last, active)
+        return np.asarray(logits), cache, _counted(before, cache)
+
+    got, cache, counted = run()
+    _without_the_mask(monkeypatch)
+    want, parent_cache, _ = run()
+    on = np.asarray(active)
+    np.testing.assert_array_equal(_bits(got)[on], _bits(want)[on])
+    _same_but_the_counters(cache, parent_cache)
+    layers = cfg.stacks()["layers"].n_layers
+    pairs = cfg.experts_per_token * layers
+    assert counted["moe_decode_rows_routed"] == 2 * pairs
+    assert counted["moe_rows_routed"] == 2 * pairs
+    assert counted["moe_dead_pairs"] == 6 * pairs
+    assert 0 < counted["moe_decode_experts_hit"] <= 2 * pairs
+    assert counted["moe_decode_assignments"] <= 2 * pairs
+    assert counted["moe_decode_assignments"] == counted["moe_assignments"]
+
+
+@BOTH_PRODUCTS
+@pytest.mark.parametrize("name", MASKED)
+def test_a_chunks_padding_reaches_no_expert(name, tile, monkeypatch):
+    """``_prefill_chunk`` with 8 real tokens of 16: the logits at the
+    last real token and the cache are the unmasked program's bits, and
+    the padding's pairs are counted dead."""
+    cfg = llama.CONFIGS[name]
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (16,), 0, 256)
+
+    def run():
+        _force_tile(monkeypatch, tile)
+        steps = step_programs(cfg, slots=4, max_seq=64, chunk=16)
+        cache = llama.init_kv_cache(cfg, 4, 64, chunk=16)
+        before = {"routing": np.asarray(cache["routing"])}
+        logits, cache = steps.prefill_chunk(params, cache, tokens, 2, 0, 8)
+        return np.asarray(logits), cache, _counted(before, cache)
+
+    got, cache, counted = run()
+    _without_the_mask(monkeypatch)
+    want, parent_cache, _ = run()
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    _same_but_the_counters(cache, parent_cache)
+    layers = cfg.stacks()["layers"].n_layers
+    pairs = cfg.experts_per_token * layers
+    assert counted["moe_rows_routed"] == 8 * pairs
+    assert counted["moe_dead_pairs"] == 8 * pairs
+    assert 0 < counted["moe_experts_hit"] <= 8 * pairs
+    assert counted["moe_assignments"] <= 8 * pairs
+    assert counted["moe_decode_rows_routed"] == 0
+
+
+@BOTH_PRODUCTS
+def test_a_block_steps_dead_closing_rows_reach_no_expert(tile, monkeypatch):
+    """``_block_decode`` with four slots in flight, ONE of them with a
+    block closing, and no chunk: the logits and the cache are the
+    unmasked program's bits; live are the 4 blocks in flight and 1
+    closing, dead the other 3 closing blocks and the chunk's 8 rows."""
+    cfg = llama.CONFIGS["sdar-tiny"]
+    size, block = {"slots": 4, "max_seq": 64, "chunk": 8}, cfg.block_length
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    key = jax.random.split(jax.random.PRNGKey(7), 3)
+    blocks = jax.random.randint(key[0], (4, block), 0, 255)
+    closed = jax.random.randint(key[1], (4, block), 0, 255)
+    masked = jax.random.bernoulli(key[2], 0.5, (4, block))
+    closing = jnp.asarray([False, True, False, False])
+    active = jnp.ones((4,), bool)
+
+    def run():
+        _force_tile(monkeypatch, tile)
+        steps = step_programs(cfg, **size)
+        cache = llama.init_kv_cache(cfg, 4, 64, chunk=8)
+        nothing = (jnp.zeros((4, block), jnp.int32), jnp.zeros((4,), bool))
+        for slot in range(4):
+            tokens = jax.random.randint(jax.random.PRNGKey(slot), (8,), 0,
+                                        255)
+            _, cache = steps.prefill_chunk(
+                params, cache, blocks, masked, *nothing, tokens, slot, 0, 8)
+        before = {"routing": np.asarray(cache["routing"])}
+        logits, _, cache = steps.mixed_step(
+            params, cache, blocks, masked, closed, closing, active,
+            *steps.no_chunk)
+        return np.asarray(logits), cache, _counted(before, cache)
+
+    got, cache, counted = run()
+    _without_the_mask(monkeypatch)
+    want, parent_cache, _ = run()
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    _same_but_the_counters(cache, parent_cache)
+    pairs = cfg.experts_per_token * cfg.n_layers
+    assert counted["moe_rows_routed"] == (4 + 1) * block * pairs
+    assert counted["moe_dead_pairs"] == (3 * block + 8) * pairs
+    assert 0 < counted["moe_experts_hit"] <= cfg.n_layers * cfg.num_experts
+    assert counted["moe_assignments"] == counted["moe_rows_routed"]
+    assert counted["moe_decode_rows_routed"] == 0

@@ -50,11 +50,15 @@ def _rope_one(x, cos, sin):
         axis=-1).astype(x.dtype)
 
 
-def _counted(cache, loads, decode):
+def _counted(cache, loads, decode, c, live):
     """The program's own counters, of a model that holds every expert
-    (what it routes is what it computes)."""
-    routed = None if loads is None else jnp.sum(loads)
-    return llama._count_routing(cache, loads, routed, decode)
+    (what it routes for its ``live`` rows is what it computes; the other
+    rows' pairs reach no expert and are counted dead)."""
+    if loads is None:
+        return {}
+    return llama._count_routing(
+        cache, loads, jnp.sum(loads), decode,
+        dead=c.n_layers * c.experts_per_token * jnp.sum(~live))
 
 
 def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
@@ -106,7 +110,7 @@ def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
         out = out.reshape(chunk, c.n_heads * c.head_dim).astype(x.dtype)
         x = x + (out @ layer["wo"]).astype(x.dtype)
         h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-        out, load = llama._mlp({**layer, **experts}, h, c, i)
+        out, load = llama._mlp({**layer, **experts}, h, c, i, live=real)
         x = x + out.astype(x.dtype)
         ck_all = lax.dynamic_update_slice(ck_all, ck[None],
                                           (slot, 0, 0, 0))
@@ -121,7 +125,7 @@ def scanned_prefill_chunk(params, tokens, cache, slot, start, chunk_len,
     x_last = jnp.take(x, jnp.maximum(chunk_len - 1, 0), axis=0)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x_last @ head.astype(c.dtype)).astype(jnp.float32)
-    cache = {**_counted(cache, loads, decode=False), "k": new_k, "v": new_v,
+    cache = {**_counted(cache, loads, False, c, real), "k": new_k, "v": new_v,
              "length": cache["length"].at[slot].set(start + chunk_len)}
     return logits, cache
 
@@ -131,10 +135,9 @@ def scanned_decode_step(params, last_tokens, cache, config, active=None):
     slots = last_tokens.shape[0]
     max_seq = cache["k"].shape[2]
     pos = cache["length"]
-    if active is not None:
-        write_pos = jnp.where(active, pos, jnp.int32(max_seq))
-    else:
-        write_pos = pos
+    if active is None:
+        active = jnp.ones((slots,), bool)
+    write_pos = jnp.where(active, pos, jnp.int32(max_seq))
     cos, sin = llama.rope_frequencies(c.head_dim, c.max_seq, c.rope_theta,
                                       jnp.float32)
     group = c.n_heads // c.n_kv_heads
@@ -167,7 +170,7 @@ def scanned_decode_step(params, last_tokens, cache, config, active=None):
         out = out.reshape(slots, c.n_heads * c.head_dim).astype(x.dtype)
         x = x + (out @ layer["wo"]).astype(x.dtype)
         h = rmsnorm(x, layer["ln_mlp"], c.norm_eps)
-        out, load = llama._mlp({**layer, **experts}, h, c, i)
+        out, load = llama._mlp({**layer, **experts}, h, c, i, live=active)
         x = x + out.astype(x.dtype)
         return x, (ck, cv, load)
 
@@ -178,9 +181,8 @@ def scanned_decode_step(params, last_tokens, cache, config, active=None):
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
     new_len = jnp.minimum(cache["length"] + 1, jnp.int32(max_seq))
-    if active is not None:
-        new_len = jnp.where(active, new_len, cache["length"])
-    cache = {**_counted(cache, loads, decode=True), "k": new_k, "v": new_v,
+    new_len = jnp.where(active, new_len, cache["length"])
+    cache = {**_counted(cache, loads, True, c, active), "k": new_k, "v": new_v,
              "length": new_len}
     return logits, cache
 
@@ -290,8 +292,10 @@ def test_decode_step_equals_the_scanned_form(model, active):
     want_len = np.where(on, np.minimum(np.asarray(pos) + 1, MAX_SEQ), pos)
     np.testing.assert_array_equal(np.asarray(new["length"]), want_len)
     if c.num_experts:
-        assert int(new["routing"][0]) == \
-            SLOTS * c.experts_per_token * c.n_layers
+        # the active rows' pairs reach an expert, the others' none
+        pairs = c.experts_per_token * c.n_layers
+        assert int(new["routing"][0]) == int(on.sum()) * pairs
+        assert int(new["routing"][-1]) == int((~on).sum()) * pairs
 
 
 @pytest.mark.parametrize("start,chunk_len", [

@@ -732,7 +732,8 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     """What a routed model's step programs record, always on: the
     routing counters (``llama.ROUTING_COUNTERS``, PR 27; ``moe_rows_routed``
     and the decode steps' own PR 32, ``moe_tile_rows`` PR 37: 0 where XLA's
-    kernel multiplies, as here) in ``LLMEngine.stats`` from the start, riding the step's one
+    kernel multiplies, as here, ``moe_dead_pairs`` PR 62: the pairs of the
+    rows nobody reads, which reach no expert) in ``LLMEngine.stats`` from the start, riding the step's one
     read, and the named scopes a reducer can file operations under —
     ``moe`` around the routed experts, ``moe_shared`` around the shared
     one, ``mla`` around latent attention, ``attn_window`` and
@@ -747,7 +748,7 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
         "moe_assignments", "moe_experts_hit", "moe_expert_slots",
         "moe_load_max", "moe_rows_routed", "moe_decode_assignments",
         "moe_decode_experts_hit", "moe_decode_expert_slots",
-        "moe_decode_rows_routed", "moe_tile_rows")
+        "moe_decode_rows_routed", "moe_tile_rows", "moe_dead_pairs")
     eng = LLMEngine(cfg, slots=2, max_seq=64, prefill_chunk_tokens=8,
                     tokenizer=_NoEos())
     assert set(llama.ROUTING_COUNTERS) <= set(eng.stats)
@@ -763,13 +764,17 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     assert stats["chunks"] == 1 and stats["chunks_fused"] == 0
     assert stats["moe_expert_slots"] == cfg.num_experts * routed_layers * (
         stats["decode_steps"] + stats["chunks"] + 1)
-    assert routed == cfg.experts_per_token * routed_layers * (
+    # routed are the LIVE rows' pairs: the prompt's 3 tokens and the one
+    # decoding row; the idle slot's, the padding's and the empty run's
+    # are dead
+    pairs = cfg.experts_per_token * routed_layers
+    assert routed == pairs * (3 + stats["decode_slots"])
+    assert routed + stats["moe_dead_pairs"] == pairs * (
         2 * stats["decode_steps"] + 8 * stats["chunks"] + (2 + 8))
     # the decode steps' own, apart from the chunks'
     assert stats["moe_decode_expert_slots"] == (
         cfg.num_experts * routed_layers * stats["decode_steps"])
-    assert stats["moe_decode_rows_routed"] == (
-        cfg.experts_per_token * routed_layers * 2 * stats["decode_steps"])
+    assert stats["moe_decode_rows_routed"] == pairs * stats["decode_slots"]
     assert 0 < stats["moe_decode_assignments"] < held
     assert 0 < stats["moe_decode_experts_hit"] < stats["moe_experts_hit"]
     lowered = eng._decode_jit.lower(

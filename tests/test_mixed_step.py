@@ -124,9 +124,13 @@ def test_mixed_step_is_the_chunk_and_the_decode_step(name, real):
         apart = dict(zip(llama.ROUTING_COUNTERS,
                          np.asarray(two["routing"]).tolist()))
         routed_layers = c.n_layers - c.n_dense_layers
+        # the two decoding rows' and the real tokens' pairs are routed,
+        # the idle slots' and the padding's reach no expert
+        pairs = routed_layers * c.experts_per_token
         assert counted["moe_rows_routed"] == apart["moe_rows_routed"] == (
-            before["moe_rows_routed"]
-            + routed_layers * c.experts_per_token * (SLOTS + CHUNK))
+            before["moe_rows_routed"] + pairs * (2 + real))
+        assert counted["moe_dead_pairs"] == apart["moe_dead_pairs"] == (
+            before["moe_dead_pairs"] + pairs * (SLOTS - 2 + CHUNK - real))
         assert counted["moe_expert_slots"] - before["moe_expert_slots"] \
             == routed_layers * c.num_experts
         assert apart["moe_expert_slots"] - before["moe_expert_slots"] \

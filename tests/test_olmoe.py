@@ -168,13 +168,17 @@ def test_prefill_in_chunks_then_decode_equals_the_full_forward(
     if not cfg.num_experts:
         assert "routing" not in cache
         return
-    # The step programs counted what they computed: 4 chunks of 16 rows
-    # and 10 steps of 4 slots, k experts a row, in each layer.
+    # The step programs counted what reached an expert: the prompt's 50
+    # tokens (4 chunks of 16 rows) and 10 steps' ONE active slot of 4, k
+    # experts a row, in each layer — and, apart, the pairs of the rows
+    # nobody reads: the last chunk's padding and the idle slots.
     executions, rows = 4 + 10, 4 * 16 + 10 * 4
     counted = dict(zip(llama.ROUTING_COUNTERS,
                        np.asarray(cache["routing"]).tolist()))
-    assert counted["moe_assignments"] == \
-        rows * CFG.experts_per_token * CFG.n_layers
+    assert counted["moe_assignments"] == counted["moe_rows_routed"] == \
+        (prompt + 10) * CFG.experts_per_token * CFG.n_layers
+    assert counted["moe_dead_pairs"] == \
+        (rows - prompt - 10) * CFG.experts_per_token * CFG.n_layers
     assert counted["moe_expert_slots"] == \
         executions * CFG.num_experts * CFG.n_layers
     assert 0 < counted["moe_experts_hit"] <= counted["moe_expert_slots"]
@@ -215,13 +219,17 @@ def test_engine_batch_of_two_lengths_equals_the_reference(params):
     assert stats["d2h_syncs"] == stats["decode_steps"] + len(prompts)
     # the programs run: a chunk that rode a decode step is one with it
     # (PR 39), and the first lone chunk ran the mixed program once
-    # before it, empty — 4 + 8 rows that route like any
+    # before it, empty — 4 + 8 rows, none of them live
     assert stats["chunks_fused"] == 3       # the longer prompt's
     assert stats["moe_expert_slots"] == CFG.num_experts * CFG.n_layers * (
         stats["decode_steps"] + stats["chunks"] - stats["chunks_fused"] + 1)
-    assert stats["moe_assignments"] == \
-        CFG.experts_per_token * CFG.n_layers * (
-            4 * stats["decode_steps"] + 8 * stats["chunks"] + (4 + 8))
+    # the prompts' tokens and the decoding rows reach their experts; the
+    # other rows the programs computed — idle slots, padding — reach none
+    pairs = CFG.experts_per_token * CFG.n_layers
+    assert stats["moe_assignments"] == stats["moe_rows_routed"] == pairs * (
+        sum(map(len, prompts)) + stats["decode_slots"])
+    assert stats["moe_assignments"] + stats["moe_dead_pairs"] == pairs * (
+        4 * stats["decode_steps"] + 8 * stats["chunks"] + (4 + 8))
     assert 0 < stats["moe_experts_hit"] <= stats["moe_expert_slots"]
     assert stats["moe_load_max"] > 0
 

@@ -215,7 +215,7 @@ MIXED = llama.LlamaConfig(
 MIXED_TWICE = dataclasses.replace(MIXED, n_layers=8, num_experts=4)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize(
     "config,slots,max_seq,chunk,stacks,one_layers_experts", [
         pytest.param(ROUTED, 16, 512, 64, (
@@ -239,7 +239,9 @@ def test_routed_step_reads_the_expert_stack_in_place(
     of expert matrices (layers x experts HELD) with the layer by scalar
     prefetch — no per-layer slice of a layer's experts is ever
     materialised in front of it, which would copy every expert's
-    weights on every step."""
+    weights on every step.  All three step programs, each with the mask
+    of its live rows in front of the sort (PR 62): the group sizes are
+    then the live rows' alone, and nothing else about the call moves."""
     # ``_grouped_tile`` asks the process's own backend, which is the CPU
     # here; the program is compiled for the described chip.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -262,6 +264,36 @@ DENSE = dataclasses.replace(
     CFG, vocab_size=32768, dim=4096, n_layers=3, n_heads=32, n_kv_heads=8,
     mlp_dim=14336, max_seq=32768, rope_theta=1000000.0, norm_eps=1e-5,
     tie_embeddings=False)
+
+
+def _lowered_for_the_tpu(program, config, slots=16, max_seq=512, chunk=64):
+    size = {"slots": slots, "max_seq": max_seq, "chunk": chunk}
+    return programs.step_programs(config, **size).jitted(program).trace(
+        *programs.step_arguments(config, **size)[program]).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_a_dense_program_never_learns_which_rows_are_live(monkeypatch,
+                                                          program):
+    """The mask of a program's live rows (``llama._row_groups``, PR 62)
+    is the routed experts' alone: a dense model's step programs lower to
+    the same text whether ``_scan_layers`` is handed it or not — not an
+    operation more for Mistral's or InternLM2's cells — where a routed
+    model's change with it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with_mask = {name: _lowered_for_the_tpu(program, config)
+                 for name, config in (("dense", DENSE), ("routed", ROUTED))}
+    scan = llama._scan_layers
+
+    def no_mask(params, x, cache, c, positions, write_attend, write_state,
+                live, **how):
+        return scan(params, x, cache, c, positions, write_attend,
+                    write_state, None, **how)
+
+    monkeypatch.setattr(llama, "_scan_layers", no_mask)
+    assert _lowered_for_the_tpu(program, DENSE) == with_mask["dense"]
+    assert _lowered_for_the_tpu(program, ROUTED) != with_mask["routed"]
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
