@@ -2084,6 +2084,12 @@ ROUTING_COUNTERS = (
     # no expert.  Over these plus ``moe_rows_routed``: the share of a
     # program's rows the routed experts were spared
     "moe_dead_pairs",
+    # the expert matrices the grouped kernel FETCHED, over a layer's
+    # three products (``grouped_matmul.fetches``): a product's visits
+    # where its k is in tiles, its experts hit where an expert is one
+    # block.  Over 3 * ``moe_experts_hit``: 1.0 where every expert hit
+    # is read once a product; 0 under XLA's kernel
+    "moe_expert_reads",
 )
 
 
@@ -2121,23 +2127,36 @@ def _hoist_experts(layers: dict, c: LlamaConfig, stack: str = "layers"):
 
 
 def _count_routing(cache: dict, loads, routed, decode: bool,
-                   tile: int = 0, dead=0) -> dict:
+                   tile: int = 0, dead=0, expert=None) -> dict:
     """``loads``: (layers, num_experts) rows per expert held of one
     execution, None for a dense model; ``routed``: the (row, expert)
     pairs its routers made for live rows, held or not, and ``dead``
     those of its other rows; ``decode``: the execution is a
     decode step; ``tile``: the grouped kernel's row tile, 0 under XLA's
-    kernel -> the cache entries to carry."""
+    kernel, and with a tile ``expert``, what it multiplies by: an
+    expert's (dim, width, bytes an element) -> the cache entries to
+    carry."""
     if loads is None:
         return {}
     seen = jnp.stack([jnp.sum(loads), jnp.sum(loads > 0), loads.size,
                       jnp.sum(jnp.max(loads, axis=-1)), routed])
     apart = seen[jnp.array([0, 1, 2, 4])]
-    # the kernel's own rule, layer by layer
-    visited = tile * jnp.sum(jax.vmap(grouped_matmul.visits, (0, None))(
-        loads, tile)) if tile else 0
+    visited = reads = 0
+    if tile:
+        def over_layers(count, *rule):
+            """the kernel's own rule, layer by layer"""
+            return jnp.sum(jax.vmap(lambda sizes: count(sizes, tile, *rule))(
+                loads))
+
+        def fetched(k, n):
+            return over_layers(grouped_matmul.fetches,
+                               k // grouped_matmul.panel(k, n, itemsize)[0])
+
+        dim, wide, itemsize = expert
+        visited = tile * over_layers(grouped_matmul.visits)
+        reads = 2 * fetched(dim, wide) + fetched(wide, dim)  # gate, up; down
     seen = jnp.concatenate([seen, apart if decode else apart * 0,
-                            jnp.stack([visited, dead])])
+                            jnp.stack([visited, dead, reads])])
     return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
 
 
@@ -2518,8 +2537,9 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
         rows = x.shape[0] if live is None else jnp.sum(live)
         routed, dead = pairs * rows, pairs * (x.shape[0] - rows)
     return carry[0], {**carry[1],
-                      **_count_routing(cache, loads, routed, decode, tile,
-                                       dead),
+                      **_count_routing(
+                          cache, loads, routed, decode, tile, dead,
+                          (c.dim, c.mlp_dim, jnp.dtype(c.dtype).itemsize)),
                       **_count_exits(cache, gates, counted)}
 
 

@@ -19,14 +19,19 @@ Grid ``(n tiles, visits, k tiles)``, k innermost: a visit's float32
 accumulator sums its k tiles in their order, and a row's sum is its own
 — the same bits whatever rows share its tile or its batch.  Consecutive
 visits of one group (a group that straddles row tiles) name the same
-weight block, which is then not fetched again where k is one tile.
+weight block, which is then not fetched again where the expert is ONE
+block; where k is in tiles, the step from a visit's last k tile to the
+next visit's first changes the block, and the whole expert is fetched
+again though it is the same expert.
 
 ``tiling`` is the rule for (tm, tk, tn), a function of shapes alone,
-read once from ``benchmarks/grouped_product``'s sweep on a v5e
-(``PERF.md`` section 6, PR 37): a visit costs its expert's bytes —
-680-740 GB/s over the experts hit from 32 to 128 rows a tile — so what
-the tile decides is how often a run of rows crosses a tile boundary and
-its expert is read a second time.
+read from ``benchmarks/grouped_product``'s sweeps on a v5e (``PERF.md``
+section 6, PR 37 and PR 63): a visit costs its expert's bytes — 680-740
+GB/s over the experts hit from 32 to 128 rows a tile — unless the block
+is the one already there.  So the row tile decides how often a run of
+rows crosses a tile boundary, and the panel whether a crossing costs
+the expert a second time: an expert within ``WHOLE_BYTES`` is one block
+and is read once a call, however many row tiles its rows cross.
 """
 
 from __future__ import annotations
@@ -38,9 +43,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# The bytes of one weight panel (tk x tn) a visit step fetches: two of
-# them are in flight (the pipeline's double buffer).
+# The bytes of one weight panel (tk x tn) a visit step fetches where an
+# expert is cut into panels: two of them are in flight (the pipeline's
+# double buffer).
 PANEL_BYTES = 4 << 20
+# An expert's matrix of at most this many bytes is ONE block (two in
+# flight): consecutive visits of its group then fetch it once.  Read from
+# ``benchmarks/grouped_product``'s sweep of twelve shapes on a v5e
+# (``PERF.md`` section 6, PR 63): whole, experts of 6.3, 7.3 and 10.5 MB
+# take 10-26 % less a call at a 512-token chunk and as much or 1-4 %
+# less at a decode step; experts of 29 and 34 MB are slower whole at
+# their decode steps, and two of them are 60-75 MB of VMEM.
+WHOLE_BYTES = 12 << 20
 # bf16 rows come in tiles of 16 sublanes: what a row tile is a multiple of
 MIN_ROWS = 16
 # the rule's row tiles
@@ -62,8 +76,8 @@ def row_tile(rows_an_expert: float) -> int:
     return tm
 
 
-def panel(k: int, n: int, itemsize: int = 2,
-          budget: int = PANEL_BYTES) -> tuple[int, int]:
+def cut(k: int, n: int, itemsize: int = 2,
+        budget: int = PANEL_BYTES) -> tuple[int, int]:
     """(tk, tn): the widest panel of whole rows of an expert's (k, n)
     matrix within ``budget`` bytes — ``tn`` = n, so a panel is one
     contiguous stretch of the stack, and ``tk`` k halved while it is
@@ -76,6 +90,19 @@ def panel(k: int, n: int, itemsize: int = 2,
     while tk * tn * itemsize > budget and tk % 256 == 0:
         tk //= 2
     return tk, tn
+
+
+def panel(k: int, n: int, itemsize: int = 2) -> tuple[int, int]:
+    """(tk, tn), the block of an expert's (k, n) matrix a visit step
+    fetches: the WHOLE matrix where it is within ``WHOLE_BYTES`` — a
+    visit costs its expert's bytes unless the block is the one already
+    there, and with k in tiles it never is: every row tile a group's
+    rows cross would read the expert again — else ``cut`` to
+    ``PANEL_BYTES`` (an expert of tens of MB: two whole ones in flight
+    are most of the core's VMEM, and its steps' rows are one tile)."""
+    if k * n * itemsize <= WHOLE_BYTES:
+        return k, n
+    return cut(k, n, itemsize)
 
 
 def tiling(rows_an_expert: float, k: int, n: int,
@@ -101,6 +128,18 @@ def visits(sizes, tm: int):
     with no row in any group still ONE, of an empty group (it stores
     nothing) — a grid of no step at all is not asked of the chip."""
     return jnp.maximum(jnp.sum(visits_by_group(sizes, tm)), 1)
+
+
+def fetches(sizes, tm: int, k_tiles: int):
+    """The expert matrices one product over ``sizes`` fetches: with k in
+    ``k_tiles`` tiles its VISITS — a visit's first k tile is never the
+    block the last left there, so a group's every row tile reads its
+    expert again — and with the expert ONE block the groups HIT
+    (consecutive visits of one group name the block already there); of
+    no row in any group still one, the empty visit's."""
+    if k_tiles > 1:
+        return visits(sizes, tm)
+    return jnp.maximum(jnp.sum(sizes > 0), 1)
 
 
 def work_list(sizes, m: int, tm: int):

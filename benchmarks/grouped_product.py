@@ -1,6 +1,6 @@
 """On the chip: the routed experts' grouped product alone, XLA's
 ``ragged-dot`` kernel beside ``ops/pallas/grouped_matmul.py`` by tile, at
-the six shapes the benchmark's routed cells run (three configurations x
+the twelve shapes the benchmark's routed cells run (six configurations x
 decode step / prefill chunk): device ms a call, the GB/s at which the
 experts HIT are read, and whether the two agree to bf16 rounding on the
 rows that belong to a group.  Both read the whole stack of ``LAYERS``
@@ -38,10 +38,16 @@ SHAPES = {
     "ax-k1.chunk": (64, 8, 12, 192, (7168, 2048)),
     "command-a-plus.decode": (16, 8, 16, 128, (4096, 4096)),
     "command-a-plus.chunk": (512, 8, 16, 128, (4096, 4096)),
+    "granite-4.0-h-small.decode": (48, 10, 36, 72, (4096, 768)),
+    "granite-4.0-h-small.chunk": (512, 10, 36, 72, (4096, 768)),
+    "xing4.0-29b-a4b.decode": (16, 4, 64, 64, (3584, 1024)),
+    "xing4.0-29b-a4b.chunk": (512, 4, 64, 64, (3584, 1024)),
+    "solar-open2.decode": (24, 8, 40, 320, (4096, 1280)),
+    "solar-open2.chunk": (512, 8, 40, 320, (4096, 1280)),
 }
 LAYERS, LAYER, RUNS = 2, 1, 12
 ROW_TILES = (16, 32, 64, 128, 256)
-PANELS_MB = (1, 2, 4, 8)
+PANELS_MB = (1, 2, 4, 8, 12, 16)
 
 
 def routing(key, tokens, k, held, width):
@@ -73,7 +79,7 @@ def candidates(m, rows_an_expert, k, n):
         if tm <= max(m, gm.MIN_ROWS):
             found[f"tm{tm}"] = (tm,) + rule[1:]
     for mb in PANELS_MB:
-        found[f"panel{mb}MB"] = (rule[0],) + gm.panel(k, n, 2, mb << 20)
+        found[f"panel{mb}MB"] = (rule[0],) + gm.cut(k, n, 2, mb << 20)
     # whole columns instead of whole rows: tk = k, tn cut
     tn = n
     while k * tn * 2 > gm.PANEL_BYTES and tn % 256 == 0:

@@ -129,8 +129,8 @@ def test_a_rows_sum_is_its_own_whatever_shares_its_batch(tiling):
     np.testing.assert_array_equal(alone, rows_of_expert_2(100, 88, 200))
 
 
-# the rule, at the six shapes of the benchmark's routed cells: (tokens
-# of the step, k a token, router width, an expert's (in, out)) -> tiling
+# the rule, at the shapes of the benchmark's routed cells: (tokens of the
+# step, k a token, router width, an expert's (in, out)) -> tiling
 @pytest.mark.parametrize("tokens,k,width,expert,want", [
     (16, 8, 64, (2048, 1024), (32, 2048, 1024)),
     (64, 8, 64, (1024, 2048), (64, 1024, 2048)),
@@ -138,19 +138,79 @@ def test_a_rows_sum_is_its_own_whatever_shares_its_batch(tiling):
     (64, 8, 192, (2048, 7168), (32, 256, 7168)),
     (16, 8, 128, (4096, 4096), (32, 512, 4096)),
     (512, 8, 128, (4096, 4096), (128, 512, 4096)),
+    # PR 63: an expert within ``WHOLE_BYTES`` is one block — granite's
+    # decode step and chunk, xing's, solar-open2's, gate / up and down
+    (48, 10, 72, (4096, 768), (64, 4096, 768)),
+    (48, 10, 72, (768, 4096), (64, 768, 4096)),
+    (512, 10, 72, (4096, 768), (128, 4096, 768)),
+    (512, 10, 72, (768, 4096), (128, 768, 4096)),
+    (16, 4, 64, (3584, 1024), (32, 3584, 1024)),
+    (16, 4, 64, (1024, 3584), (32, 1024, 3584)),
+    (512, 4, 64, (3584, 1024), (128, 3584, 1024)),
+    (512, 4, 64, (1024, 3584), (128, 1024, 3584)),
+    (24, 8, 320, (4096, 1280), (32, 4096, 1280)),
+    (24, 8, 320, (1280, 4096), (32, 1280, 4096)),
+    (512, 8, 320, (4096, 1280), (128, 4096, 1280)),
+    (512, 8, 320, (1280, 4096), (128, 1280, 4096)),
 ], ids=str)
 def test_tiling_follows_the_rows_an_expert_gets(tokens, k, width, expert,
                                                 want):
     """``tm`` from eight times rows * k over the router's width, from 32
-    to 128, a panel of whole rows within ``PANEL_BYTES``: a function of
-    shapes alone (each the best or within 1 % of it in
-    ``benchmarks/grouped_product``'s sweep on the chip, the last within
-    8 %: ``PERF.md`` section 6, PR 37)."""
+    to 128; the whole expert where it is within ``WHOLE_BYTES``, else a
+    panel of whole rows within ``PANEL_BYTES``: a function of shapes
+    alone (``benchmarks/grouped_product``'s sweeps on the chip:
+    ``PERF.md`` section 6, PR 37 and PR 63)."""
     got = gm.tiling(tokens * k / width, *expert)
     assert got == want
     tm, tk, tn = got
-    assert tk * tn * 2 <= gm.PANEL_BYTES and expert[0] % tk == 0
+    if expert[0] * expert[1] * 2 <= gm.WHOLE_BYTES:
+        assert (tk, tn) == expert
+    else:
+        assert tk * tn * 2 <= gm.PANEL_BYTES and expert[0] % tk == 0
     assert tm % gm.MIN_ROWS == 0 and tk % 128 == 0 and tn % 128 == 0
+
+
+def test_a_group_over_three_tiles_of_a_whole_expert_keeps_its_rows_bits():
+    """Under a whole-expert tiling (tk = k, tn = n) a group that
+    straddles three row tiles is three visits of ONE block: each of its
+    rows has the bits the same row has with the group alone in one tile
+    of (tm, k, n) — a row's sum is one dot over the whole k, whatever
+    tile it lies in and whatever was fetched before it."""
+    tm = 16
+    lhs, rhs = _operands(64, 3)
+    kernel = _kernel((tm, K, N))
+    # rows 0-9 expert 0 | 10-41 expert 1: tiles 0, 1, 2 | 42-63 expert 2
+    sizes = jnp.asarray([10, 32, 22], jnp.int32)
+    assert gm.visits_by_group(sizes, tm).tolist() == [1, 3, 2]
+    among = np.asarray(kernel(lhs, rhs, sizes, jnp.int32(2)))[10:42]
+    for first in (10, 26):          # the same rows, alone, a tile at a time
+        alone = np.asarray(kernel(
+            lhs[first:first + tm], rhs, jnp.asarray([0, tm, 0], jnp.int32),
+            jnp.int32(2)))
+        np.testing.assert_array_equal(
+            _bits(among[first - 10:first - 10 + tm]), _bits(alone))
+
+
+def test_fetches_are_visits_where_k_is_tiled_and_groups_hit_where_not():
+    """``fetches``: a product's visits with k in tiles, the groups hit
+    with the expert one block, one for no row at all — and at sessions'
+    chunk shape (512 tokens, a top-10 of 72 with 36 held, tiles of 128)
+    the visits are 1.4 to 1.65 times the groups hit: what reading an
+    expert once a call saves there."""
+    sizes = jnp.asarray([3, 0, 20, 1, 40], jnp.int32)
+    # rows 0-2 | 3-22 | 23 | 24-63 in tiles of 16: 1 + 2 + 1 + 3 visits
+    assert int(gm.fetches(sizes, 16, 2)) == int(gm.visits(sizes, 16)) == 7
+    assert int(gm.fetches(sizes, 16, 1)) == 4
+    none = jnp.zeros((5,), jnp.int32)
+    assert int(gm.fetches(none, 16, 2)) == int(gm.fetches(none, 16, 1)) == 1
+    _, picks = lax.top_k(jax.random.normal(jax.random.PRNGKey(63),
+                                           (512, 72)), 10)
+    held = jnp.zeros((36,), jnp.int32).at[picks.reshape(-1)].add(
+        1, mode="drop")
+    tm = gm.row_tile(512 * 10 / 72)
+    visits, hit = int(gm.fetches(held, tm, 2)), int(gm.fetches(held, tm, 1))
+    assert tm == 128 and hit == 36
+    assert 1.4 <= visits / hit <= 1.65
 
 
 def test_a_tiling_that_does_not_tile_is_refused():
@@ -234,7 +294,8 @@ def test_step_programs_through_the_kernel(name, monkeypatch):
     """A chunk and three decode steps with the kernel's tile forced (on
     the CPU ``_grouped_tile`` keeps XLA's product) give the logits of
     the same programs without it, and ``moe_tile_rows`` counts tile x
-    visits by the work list's own rule where it stays 0 without."""
+    visits by the work list's own rule where it stays 0 without, as
+    ``moe_expert_reads`` does."""
     cfg = llama.CONFIGS[name]
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (16,), 0, 256)
@@ -262,9 +323,15 @@ def test_step_programs_through_the_kernel(name, monkeypatch):
     got, got_routing = run(16)
     want, want_routing = run(0)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-    tiled = np.arange(len(got_routing)) == counted("moe_tile_rows")
+    tiled = np.isin(np.arange(len(got_routing)), [
+        counted("moe_tile_rows"), counted("moe_expert_reads")])
     np.testing.assert_array_equal(got_routing[~tiled], want_routing[~tiled])
-    assert want_routing[counted("moe_tile_rows")] == 0
+    assert (want_routing[tiled] == 0).all()
+    # a tiny expert is one block: every expert hit is read once a product
+    # (and of a layer-step with no held pick, the empty visit's)
+    reads = int(got_routing[counted("moe_expert_reads")])
+    hit = int(got_routing[counted("moe_experts_hit")])
+    assert 3 * hit <= reads <= 3 * (hit + 4 * cfg.n_layers)
     tile_rows = int(got_routing[counted("moe_tile_rows")])
     assert tile_rows % 16 == 0
     routed_layers = cfg.stacks()["layers"].n_layers
@@ -280,8 +347,8 @@ def test_tile_rows_are_the_work_lists_visits():
     cache = {"routing": jnp.zeros((len(llama.ROUTING_COUNTERS),),
                                   jnp.uint32)}
     tile_rows = llama.ROUTING_COUNTERS.index("moe_tile_rows")
-    seen = np.asarray(llama._count_routing(cache, loads, 99, True, 16)[
-        "routing"])
+    seen = np.asarray(llama._count_routing(
+        cache, loads, 99, True, 16, 0, (256, 384, 2))["routing"])
     # layer 0: rows 0-2 | 3-22 | 23 -> tiles 0 | 0, 1 | 1 = 4 visits;
     # layer 1: none -> 1; layer 2: 0 | 1 | 2 = 3
     assert seen[tile_rows] == 16 * 8
@@ -289,7 +356,30 @@ def test_tile_rows_are_the_work_lists_visits():
     none = np.asarray(llama._count_routing(cache, loads, 99, True, 0)[
         "routing"])
     assert none[tile_rows] == 0
-    assert (np.delete(none, tile_rows) == np.delete(seen, tile_rows)).all()
+    kernels = [tile_rows, llama.ROUTING_COUNTERS.index("moe_expert_reads")]
+    assert (np.delete(none, kernels) == np.delete(seen, kernels)).all()
+
+
+@pytest.mark.parametrize("expert,want", [
+    # an expert of 256 x 384 bf16 is one block in all three products:
+    # the groups hit, 3 | 1 (the empty visit's) | 3, three times
+    ((256, 384, 2), 3 * 7),
+    # A.X-K1's: k in 8 tiles, gate / up and down: the visits, 4 | 1 | 3
+    ((7168, 2048, 2), 3 * 8),
+], ids=["one-block", "k-in-tiles"])
+def test_expert_reads_are_what_the_kernels_rule_fetches(expert, want):
+    """``_count_routing``'s ``moe_expert_reads``: ``gm.fetches`` of each
+    layer's loads for gate, up and down, the k tiles ``gm.panel``'s at
+    the expert's shape; 0 under XLA's kernel."""
+    loads = jnp.asarray([[3, 0, 20, 1], [0, 0, 0, 0], [16, 16, 0, 1]],
+                        jnp.int32)
+    cache = {"routing": jnp.zeros((len(llama.ROUTING_COUNTERS),),
+                                  jnp.uint32)}
+    reads = llama.ROUTING_COUNTERS.index("moe_expert_reads")
+    assert np.asarray(llama._count_routing(
+        cache, loads, 99, True, 16, 0, expert)["routing"])[reads] == want
+    assert np.asarray(llama._count_routing(
+        cache, loads, 99, True, 0, 0, expert)["routing"])[reads] == 0
 
 
 def test_grouped_tile_keeps_ragged_dot_off_the_tpu_and_under_a_mesh(

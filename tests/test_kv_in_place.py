@@ -295,7 +295,8 @@ def test_decode_step_equals_the_scanned_form(model, active):
         # the active rows' pairs reach an expert, the others' none
         pairs = c.experts_per_token * c.n_layers
         assert int(new["routing"][0]) == int(on.sum()) * pairs
-        assert int(new["routing"][-1]) == int((~on).sum()) * pairs
+        dead = llama.ROUTING_COUNTERS.index("moe_dead_pairs")
+        assert int(new["routing"][dead]) == int((~on).sum()) * pairs
 
 
 @pytest.mark.parametrize("start,chunk_len", [

@@ -24,13 +24,13 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from ant_ray_tpu.llm import programs
 from ant_ray_tpu.models import llama
-from ant_ray_tpu.ops.pallas import gather_sum
+from ant_ray_tpu.ops.pallas import gather_sum, grouped_matmul
 from ant_ray_tpu.ops.rope import YarnScaling
 from ant_ray_tpu.ops.pallas.flash_attention import (
     flash_attention_backward,
     flash_attention_fwd_lse,
 )
-from benchmarks import routed_way_back, step_weight_copies
+from benchmarks import grouped_product, routed_way_back, step_weight_copies
 
 CFG = llama.CONFIGS["llama3-1b"]
 # (batch, seq, heads, kv_heads, head_dim): Llama-3.2-1B attention at the
@@ -614,6 +614,38 @@ def test_gather_sum_compiles_for_v5e(v5e, shape):
     assert [row["name"] for row in step_weight_copies.materialised(
         text, lambda dtype, dims: ["rows"] if dtype == "f32" and math.prod(
             dims) >= tokens * k * dim else None)] == []
+
+
+# the shapes whose expert is ONE block (PR 63: granite's, xing's and
+# solar-open2's beside OLMoE's), a decode step's rows and a chunk's,
+# gate / up and down
+WHOLE_EXPERTS = [(shape, product)
+                 for shape, (*_, (dim, wide)) in grouped_product.SHAPES.items()
+                 if grouped_matmul.panel(dim, wide) == (dim, wide)
+                 for product in ("gate_or_up", "down")]
+
+
+@pytest.mark.parametrize("shape,product", WHOLE_EXPERTS)
+def test_a_whole_expert_is_one_block_the_chips_vmem_takes(v5e, shape,
+                                                          product):
+    """``grouped_matmul`` at the rule's tiling for the
+    configurations whose expert is within ``WHOLE_BYTES``: the block is
+    the whole (k, n) matrix, two of them in flight — up to 2 x 10.5 MB
+    beside the row and out tiles — and the chip's compiler takes it as
+    ONE Mosaic call (the interpreter knows no VMEM)."""
+    tokens, picks, held, width, (dim, wide) = grouped_product.SHAPES[shape]
+    k, n = (dim, wide) if product == "gate_or_up" else (wide, dim)
+    tiling = grouped_matmul.tiling(tokens * picks / width, k, n)
+    assert tiling[1:] == (k, n)
+    rows = jax.ShapeDtypeStruct((tokens * picks, k), jnp.bfloat16)
+    stack = jax.ShapeDtypeStruct((2, held, k, n), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    text = jax.jit(functools.partial(
+        grouped_matmul.grouped_matmul, tiling=tiling)).lower(
+            *_on(v5e.devices[0], (rows, stack, sizes, layer))
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
 
 
 # Olmo Hybrid's blocks at their published widths, the benchmark's cut:
