@@ -281,9 +281,10 @@ class _PhaseRecorder:
     reads of them: window layers x the sum of each ACTIVE row's own
     whole ring blocks where the rows go through the kernel (all the
     ring's once it has wrapped), the walk's figure where they walk.  Of a model
-    with recurrent layers — linear or state-space ones
-    (``LlamaConfig.n_recurrent``; every other leaves these five at
-    zero), whose state is a slot's and not a position's:
+    with recurrent layers — linear, state-space or gated
+    short-convolution ones, the last with a convolution tail for all
+    its state (``LlamaConfig.n_recurrent``; every other leaves these
+    five at zero), whose state is a slot's and not a position's:
     ``recurrent_decode_rows`` adds up, per decode step, the active rows
     times the recurrent layers — the states the step advanced — and
     ``recurrent_slot_rows`` the slots times the recurrent layers, the
@@ -754,8 +755,9 @@ class LLMEngine:
         if self.config.n_recurrent:
             raise ValueError(
                 "a recurrent state (linear-attention or state-space "
-                "layers) is not sharded: the engine runs such a model on "
-                "one device only (tensor_parallel_size=1, no mesh)")
+                "layers, or a short convolution's tail) is not sharded: "
+                "the engine runs such a model on one device only "
+                "(tensor_parallel_size=1, no mesh)")
         if self.config.kv_lora_rank:
             raise ValueError(
                 "a latent (MLA) cache has no heads axis to shard: the "
@@ -853,8 +855,9 @@ class LLMEngine:
             # overwrite the row; a state cannot take a token back).
             raise ValueError(
                 "sessions are not kept over a recurrent state: a model "
-                "with linear-attention or state-space layers serves each "
-                "request from an empty state (no session_id)")
+                "with linear-attention, state-space or short-convolution "
+                "layers serves each request from an empty state (no "
+                "session_id)")
         if session_id is not None and self._block:
             # A turn ends where a stop token or max_tokens falls, inside
             # a block: the next turn would begin with part of a block

@@ -115,7 +115,18 @@ class LlamaConfig:
     # (``LINEAR``).  "ssm": Mamba-2's selective state-space recurrence
     # (``_ssm_inputs``, ``ops/ssd.py``), the other kind that keeps a
     # state of the sequence, of another shape; its leaves are the stack
-    # ``SSM``.  A model has one RECURRENT kind or none.
+    # ``SSM``.  "conv": the gated short convolution (LFM2's:
+    # ``apply_block``), which keeps of a sequence the last
+    # ``conv_L_cache - 1`` inputs of its convolution and NO state
+    # matrix; its leaves are the stack ``CONV``.  A model has one
+    # RECURRENT kind of the three or none.
+    # ONE meaning with or without ``n_dense_layers``: layer ``i`` is of
+    # kind ``layer_kinds[i % len(layer_kinds)]`` (``pattern``).  Leading
+    # dense layers cut that pattern into two runs — all "conv" the
+    # dense one, "full" and "conv" the routed one — and each run is
+    # scanned over its OWN shortest period (``stacks``); a published
+    # ``layer_types`` of ``n_layers`` entries is a period that repeats
+    # once.
     # ``window_pattern`` is the same period written as
     # booleans (window or full), taken at construction only.  With
     # ``full_rope`` False the full layers rotate nothing (no positional
@@ -154,6 +165,11 @@ class LlamaConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
+    # A gated short-convolution layer (the published key): ``dim -> 3
+    # dim`` gives B, C and u; a depth-wise causal convolution of
+    # ``conv_L_cache`` taps a channel, no bias and no activation, runs
+    # over B * u; C gates what comes out, before ``dim -> dim``.
+    conv_L_cache: int = 0
     # Granite's four scalars, each absent at its default: the embedding
     # is multiplied by ``embedding_multiplier``, what a mix and a
     # feed-forward add to the residual by ``residual_multiplier``, the
@@ -264,17 +280,25 @@ class LlamaConfig:
             raise ValueError(f"unknown layer kinds {self.layer_kinds!r}")
         if len(set(self.layer_kinds) & set(RECURRENT)) > 1:
             raise ValueError(
-                "linear (delta-rule) layers and ssm (state-space) layers "
-                "in ONE model are not computed: the cache keeps one kind "
-                "of recurrent state")
+                "linear (delta-rule) layers, ssm (state-space) layers "
+                "and conv (gated short-convolution) layers in ONE model "
+                "are not computed: the cache keeps one kind of recurrent "
+                "state")
         if bool(self.window) != any(self.period):
             raise ValueError("window and window_pattern go together")
         if self.layer_kinds and (
-                self.kv_lora_rank or self.n_dense_layers
-                or self.n_layers % len(self.layer_kinds)):
-            raise ValueError("a window pattern repeats whole over "
-                             "n_layers of grouped-query layers, none of "
-                             "them a leading dense one")
+                self.kv_lora_rank or self.n_layers % len(self.layer_kinds)
+                or (self.n_dense_layers and not (
+                    set(self.pattern[:self.n_dense_layers]) == {"conv"}
+                    and set(self.pattern[self.n_dense_layers:])
+                    == {"full", "conv"}))):
+            raise ValueError(
+                "a window pattern repeats whole over n_layers of "
+                "grouped-query layers, none of them a leading dense one "
+                "- but where the leading dense ones are all conv (gated "
+                "short-convolution) layers and the routed ones full and "
+                "conv layers: the two runs are then scanned each over "
+                "its own period")
         if self.n_linear and (
                 self.window or self.parallel_block
                 or "full" not in self.layer_kinds or not (
@@ -298,6 +322,14 @@ class LlamaConfig:
                 "write and read directions among ALL heads (ssm_groups "
                 "1), and stand in a sequential block beside full layers "
                 "only")
+        if "conv" in self.layer_kinds and (
+                self.window or self.parallel_block or self.kv_lora_rank
+                or self.conv_L_cache < 2):
+            raise ValueError(
+                "conv layers state their convolution's taps "
+                "(conv_L_cache, two at least) and stand in a sequential "
+                "block beside full grouped-query layers only (a run of "
+                "them alone is a routed model's leading dense layers)")
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.norm_after and self.parallel_block:
@@ -318,7 +350,8 @@ class LlamaConfig:
                              "through the layers at least once")
         not_looped = {
             "window layers": bool(self.window),
-            "a recurrent kind (linear or ssm layers)": bool(self.recurrent),
+            "a recurrent kind (linear, ssm or conv layers)":
+                bool(self.recurrent),
             "latent attention": bool(self.kv_lora_rank),
             "leading dense layers": bool(self.n_dense_layers),
             "routed experts": bool(self.num_experts),
@@ -374,7 +407,8 @@ class LlamaConfig:
                 "probability in (0, 1] (1: the schedule's pace alone)")
         not_blocked = {
             "window layers": bool(self.window),
-            "a recurrent kind (linear or ssm layers)": bool(self.recurrent),
+            "a recurrent kind (linear, ssm or conv layers)":
+                bool(self.recurrent),
             "latent attention": bool(self.kv_lora_rank),
             "loops": self.loops > 1,
         }
@@ -405,14 +439,30 @@ class LlamaConfig:
         6 GiB of temporaries and copies at 8 x 12,288 positions (compiled
         for the described v5e, PERF.md section 6, PR 46).  Side by side,
         every head is a lane tile of its own and positions fill the
-        sublanes: nothing is padded and nothing re-laid."""
-        return self.n_kv_heads > 8 and self.n_kv_heads % 8 != 0
+        sublanes: nothing is padded and nothing re-laid.  The same
+        where whole sublane tiles of heads are each NARROWER than the
+        128 lanes and fill whole lane tiles together (8 heads of 64:
+        with a heads axis every head's half tile is padded to a whole
+        one, and the compiler carried a padded copy of both slabs, 4.5
+        GiB of temporaries beside 2.25 GiB of slabs at 96 x 4,096
+        positions: compiled for the described v5e, PERF.md section 6,
+        PR 65; fewer heads than a sublane tile were not compiled, and
+        keep their axis)."""
+        return (self.n_kv_heads > 8 and self.n_kv_heads % 8 != 0) or (
+            not self.kv_lora_rank and self.n_kv_heads % 8 == 0
+            and self.head_dim % 128 != 0
+            and self.n_kv_heads * self.head_dim % 128 == 0)
 
     @property
     def kinds(self) -> tuple:
         """The layer pattern's period: the kind of the layer at each
         place.  ("full",) where all layers are alike."""
         return self.layer_kinds or ("full",)
+
+    @property
+    def pattern(self) -> tuple:
+        """The kind of every one of the ``n_layers``, in order."""
+        return self.kinds * (self.n_layers // len(self.kinds))
 
     @property
     def period(self) -> tuple:
@@ -433,9 +483,9 @@ class LlamaConfig:
 
     @property
     def recurrent(self) -> str:
-        """The model's RECURRENT kind — "linear", "ssm" — or "": the
-        kind of layer that keeps a state a slot (``state_slabs``) and
-        nothing of a position."""
+        """The model's RECURRENT kind — "linear", "ssm", "conv" — or
+        "": the kind of layer that keeps a state a slot
+        (``state_slabs``) and nothing of a position."""
         return next((kind for kind in RECURRENT if kind in self.kinds), "")
 
     @property
@@ -484,19 +534,26 @@ class LlamaConfig:
         return scale
 
     def stacks(self) -> dict:
-        """The model's layers in order, as stacks of LIKE layers: the
-        name of each stack in the parameter tree -> the config that
-        reads it (``n_layers`` its own).  A routed model's leading dense
-        layers are ``dense_layers``; everything else is ``layers``."""
+        """The model's layers in order, as RUNS of layers with one
+        feed-forward: the run's name -> the config that reads it
+        (``n_layers`` its own, ``layer_kinds`` its own period).  A
+        routed model's leading dense layers are ``dense_layers``;
+        everything else is ``layers``.  A run's softmax layers lie in
+        the parameter tree under the run's name, its recurrent layers
+        in a stack beside it (``run_stacks``)."""
+        if not self.n_dense_layers:
+            return {"layers": self}
+        lead, routed = ({"layer_kinds": _shortest_period(run)}
+                        if self.layer_kinds else {} for run in (
+                            self.pattern[:self.n_dense_layers],
+                            self.pattern[self.n_dense_layers:]))
         rest = dataclasses.replace(
             self, n_layers=self.n_layers - self.n_dense_layers,
-            n_dense_layers=0)
-        if not self.n_dense_layers:
-            return {"layers": rest}
+            n_dense_layers=0, **routed)
         dense = dataclasses.replace(
             rest, n_layers=self.n_dense_layers, num_experts=0,
             router_width=0, router_bias=False, n_shared_experts=0,
-            mlp_dim=self.dense_mlp_dim)
+            mlp_dim=self.dense_mlp_dim, **lead)
         return {"dense_layers": dense, "layers": rest}
 
     def num_params(self) -> int:
@@ -512,7 +569,29 @@ class LlamaConfig:
 # kinds that keep a state a slot -> their stack's name.
 LINEAR = "linear_layers"
 SSM = "ssm_layers"
-RECURRENT = {"linear": LINEAR, "ssm": SSM}
+CONV = "conv_layers"
+RECURRENT = {"linear": LINEAR, "ssm": SSM, "conv": CONV}
+
+
+def _shortest_period(kinds: tuple) -> tuple:
+    """The shortest tuple that ``kinds`` is whole repetitions of."""
+    return next(kinds[:n] for n in range(1, len(kinds) + 1)
+                if kinds == kinds[:n] * (len(kinds) // n))
+
+
+def run_stacks(name: str, run: LlamaConfig) -> dict:
+    """Where the layers of a run (an entry of ``LlamaConfig.stacks``)
+    lie in the parameter tree: the stack's name WITHIN the run, as
+    ``LlamaConfig.place`` gives it -> its name in the tree.  The
+    softmax layers' stack has the run's name; a recurrent kind's stands
+    beside it under the kind's name — ``dense_layers``' under
+    ``dense_`` and that name, for the dense feed-forward's leaves are
+    not the routed one's."""
+    stacks = {"layers": name} if run.n_layers > run.n_recurrent else {}
+    if run.recurrent:
+        beside = RECURRENT[run.recurrent]
+        stacks[beside] = name[:-len("layers")] + beside
+    return stacks
 
 CONFIGS: dict[str, LlamaConfig] = {
     # ref parity: the Llama-3-8B benchmark model (BASELINE.md north star)
@@ -628,6 +707,21 @@ CONFIGS: dict[str, LlamaConfig] = {
         qk_norm=True, full_rope=False, norm_after=True,
         layer_kinds=("linear", "linear", "linear", "full"),
         linear_heads=4, linear_head_dim=8, linear_value_dim=16),
+    # LFM2-8B-A1B's stack at test size: two leading DENSE layers that
+    # are gated short-convolution layers (3 taps, a tail of 2 x 64 a
+    # slot and no state matrix), then two periods of a softmax layer (4
+    # query / 2 KV heads of 16, an RMSNorm a head on q and k, rotated)
+    # and three more conv layers; a sigmoid router that picks 2 of 8
+    # experts by score + bias and divides their scores by their sum, no
+    # shared expert; the embedding tied
+    "lfm2-tiny": LlamaConfig(
+        vocab_size=256, dim=64, n_layers=10, n_heads=4, n_kv_heads=2,
+        mlp_dim=32, max_seq=512, rope_theta=1000000.0, norm_eps=1e-5,
+        dtype=jnp.float32, tie_embeddings=True, num_experts=8,
+        experts_per_token=2, router_scoring="sigmoid", router_bias=True,
+        n_dense_layers=2, dense_mlp_dim=96, qk_norm="head",
+        layer_kinds=("conv", "conv") + ("full", "conv", "conv", "conv") * 2,
+        conv_L_cache=3),
     # Ouro's stack at test size: three layers that a token passes
     # through three times (nine slab layers in the cache), 4 heads of
     # 16, plain multi-head, rotated, four norms a layer (sandwich), the
@@ -656,10 +750,18 @@ def _layer_leaves(c: LlamaConfig, stack: str = "layers") -> dict:
     """One stack of like layers: leaf name -> (its shape after the
     leading layers axis, its logical dims after it); ``stack``: the
     model's linear layers (``LINEAR``), its state-space layers
-    (``SSM``), else its softmax layers.  A leaf's last logical dim also
-    says how ``init_params`` draws it."""
+    (``SSM``), its gated short-convolution layers (``CONV``), else its
+    softmax layers.  A leaf's last logical dim also says how
+    ``init_params`` draws it."""
     hd, e, p, m = c.head_dim, "embed_param", "heads_flat", "mlp"
-    if stack == SSM:
+    if stack == CONV:
+        attn = {
+            # B, C and u side by side, in that order
+            "in_proj": ((c.dim, 3 * c.dim), (e, p)),
+            "conv_w": ((c.conv_L_cache, c.dim), (None, "taps")),
+            "wo": ((c.dim, c.dim), (p, e)),
+        }
+    elif stack == SSM:
         heads, inner, channels = c.ssm_heads, *ssm_widths(c)
         attn = {
             # the gate z, the convolution's channels, a step a head
@@ -791,14 +893,18 @@ def _param_tree(config: LlamaConfig, pick) -> dict:
     """The parameter tree with ``pick(layers, shape, dims)`` at every
     leaf (``layers`` None outside the stacks).  A routed model's leading
     dense layers are a stack of their own, ``dense_layers``, beside
-    ``layers``, which then holds the routed ones only."""
+    ``layers``, which then holds the routed ones only; a run's
+    recurrent layers are a stack beside its softmax layers'
+    (``run_stacks``), and a run that has none of a sort has no stack of
+    it."""
     c = config
-    stacks = {name: (stack.n_layers - stack.n_recurrent,
-                     _layer_leaves(stack))
-              for name, stack in c.stacks().items()}
-    if c.recurrent:
-        name = RECURRENT[c.recurrent]
-        stacks[name] = (c.n_recurrent, _layer_leaves(c, name))
+    stacks = {}
+    for run_name, run in c.stacks().items():
+        for stack, name in run_stacks(run_name, run).items():
+            stacks[name] = (
+                run.n_recurrent if stack in RECURRENT.values()
+                else run.n_layers - run.n_recurrent,
+                _layer_leaves(run, stack))
     return {
         "embed": pick(None, (c.vocab_size, c.dim), ("vocab", "embed_param")),
         **{name: {leaf: pick(n, *both) for leaf, both in leaves.items()}
@@ -953,7 +1059,11 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
     weights)`` what ``_ssm_inputs`` makes of it — the convolution's
     channels BEFORE it and the steps, beside the layer's small leaves —
     and gets back the heads' outputs (..., heads, P) float32, which it
-    gates FIRST and then norms over all channels at once.  With
+    gates FIRST and then norms over all channels at once.  A "conv"
+    layer hands ``attend(bu, conv_w)`` the gated input ``B * u`` of its
+    convolution (..., dim), rounded to the activations' dtype as the
+    cache keeps its tail, and gets back the convolution's sums (...,
+    dim) float32, which it gates with ``C``.  With
     ``parallel_block`` attention and feed-forward read the same normed
     input and join in one residual sum; otherwise what either adds to
     the residual is multiplied by ``residual_multiplier`` where the
@@ -979,6 +1089,13 @@ def apply_block(layer: dict, x, c: LlamaConfig, cos, sin, positions,
             attn = rmsnorm(attn.reshape(*lead, -1) * jax.nn.silu(z),
                            layer["ssm_norm"].astype(jnp.float32),
                            c.norm_eps).astype(x.dtype)
+    elif kind == "conv":
+        with jax.named_scope("short_conv"):
+            b, gate, u = jnp.split(jnp.dot(
+                h, layer["in_proj"], preferred_element_type=jnp.float32),
+                3, axis=-1)
+            attn, state = attend((b * u).astype(h.dtype), layer["conv_w"])
+            attn = (gate * attn).astype(x.dtype)
     elif kind == "linear":
         with jax.named_scope("attn_linear"):
             attn, state = attend(*_linear_inputs(layer, h, c),
@@ -1202,6 +1319,14 @@ def _attend_ssm_rows(u, dt, weights: dict, c: LlamaConfig):
                            jnp.zeros(state_slabs(c)["s"][0]),
                            operand=c.dtype)
     return out
+
+
+def _attend_conv_rows(u, conv_w, c: LlamaConfig):
+    """A gated short-convolution layer over ONE whole sequence from an
+    empty tail, no cache: u (seq, dim), the gated inputs -> (seq, dim)
+    float32."""
+    return delta_rule.causal_conv(
+        u, jnp.zeros((c.conv_L_cache - 1, u.shape[-1]), u.dtype), conv_w)[0]
 
 
 def _linear_qkv(y, c: LlamaConfig):
@@ -1588,13 +1713,12 @@ def _rope_tables(c: LlamaConfig, positions: int | None = None):
 def _stacks(params: dict, c: LlamaConfig) -> list:
     """``[(a run of layers' stacked leaves BY KIND, the config that
     reads them)]``, in the layers' order (``LlamaConfig.stacks``): the
-    softmax layers' stack under "layers", and beside it the recurrent
-    layers' (``LINEAR`` or ``SSM``) where the run has any
-    (``LlamaConfig.place`` says which a place of the period reads)."""
-    beside = [RECURRENT[c.recurrent]] if c.recurrent else []
-    return [({"layers": params[name],
-              **{stack: params[stack] for stack in beside}}, cfg)
-            for name, cfg in c.stacks().items()]
+    softmax layers' stack under "layers" where the run has any, and
+    beside it the recurrent layers' (``LINEAR``, ``SSM`` or ``CONV``)
+    where it has any (``LlamaConfig.place`` says which a place of the
+    period reads, ``run_stacks`` where each lies in ``params``)."""
+    return [({stack: params[lies] for stack, lies in run_stacks(
+        name, cfg).items()}, cfg) for name, cfg in c.stacks().items()]
 
 
 def _by_period(stacks: dict, c: LlamaConfig):
@@ -1607,7 +1731,7 @@ def _by_period(stacks: dict, c: LlamaConfig):
     it lies (``_place``) — a period's slice, sliced again by place, is a
     copy of every weight of the period on every call."""
     if len(c.kinds) == 1:
-        return stacks["layers"], None
+        return stacks[c.place(0)[0]], None
     return jnp.arange(c.n_layers // len(c.kinds)), stacks
 
 
@@ -1726,7 +1850,8 @@ def forward(params: dict, tokens, config: LlamaConfig, *, mesh=None,
                     ("batch", "seq", "kv_heads", "head_dim"), rules))
         return out, None
 
-    state_rows = {"linear": _attend_linear_rows, "ssm": _attend_ssm_rows}
+    state_rows = {"linear": _attend_linear_rows, "ssm": _attend_ssm_rows,
+                  "conv": _attend_conv_rows}
 
     def scan_stack(x, stack, cfg):
         scanned, whole = _by_period(stack, cfg)
@@ -1834,7 +1959,7 @@ def loss_fn_pp(params: dict, batch: dict, config: LlamaConfig, *, mesh,
         raise ValueError("the pipeline schedule runs one stack of like "
                          "grouped-query layers: no leading dense layers, "
                          "no latent attention, no window layers, no "
-                         "linear layers, no ssm layers")
+                         "linear layers, no ssm layers, no conv layers")
     if c.loops > 1:
         raise ValueError("the pipeline schedule runs its stages' layers "
                          "once: loops (a looped stack) are not computed")
@@ -1891,8 +2016,11 @@ def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
         # every pass multiplies with the layers' matrices again
         matmul += 6 * (c.loops - 1) * sum(
             math.prod(shape) for shape in param_shapes(c)["layers"].values())
-    attn = 6 * c.loops * c.n_layers * c.n_heads * seq_len * (
-        c.head_dim + (c.v_head_dim or c.head_dim))
+    # the softmax layers' scores and sums (a recurrent layer has none:
+    # a conv layer's taps are among the parameters, a multiply-add a
+    # token each)
+    attn = 6 * c.loops * (c.n_layers - c.n_recurrent) * c.n_heads * seq_len \
+        * (c.head_dim + (c.v_head_dim or c.head_dim))
     if c.hc_mult > 1:
         # a sub-layer's read, write and mix of the streams (their maps'
         # product is among the parameters)
@@ -1938,16 +2066,20 @@ def kv_slabs(config: LlamaConfig) -> dict:
 def state_slabs(config: LlamaConfig) -> dict:
     """What a RECURRENT layer keeps of a sequence — a slot's, whatever
     its length: the cache's state leaves by name, each (its shape a
-    slot, its dtype), under the same two names for either kind.  ``s``:
+    slot, its dtype), under the same names for every kind.  ``s``:
     the state, float32 (it is summed into over the whole sequence) — a
     linear layer's (d_k, d_v) a head (``linear_widths``: not always
     square), a state-space layer's (P, N) a
-    head; ``conv``: the last ``taps - 1`` inputs of the layer's
-    convolution — over q, k and v, or over the heads' inputs and the
-    two directions — as the block made them.  Empty without such
+    head; a gated short-convolution layer has NONE, and no ``s``;
+    ``conv``: the last ``taps - 1`` inputs of the layer's
+    convolution — over q, k and v, over the heads' inputs and the
+    two directions, or over the gated input ``B * u`` — as the block
+    made them, in the weights' dtype.  Empty without such
     layers.  Not among ``kv_slabs``, whose third axis is positions:
     these have none."""
     c = config
+    if c.recurrent == "conv":
+        return {"conv": ((c.conv_L_cache - 1, c.dim), c.dtype)}
     if c.recurrent == "ssm":
         return {"s": ((c.ssm_heads, c.ssm_head_dim, c.ssm_state),
                       jnp.float32),
@@ -2451,11 +2583,14 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     old slab with the new rows beside it compiles to more temporaries
     and reorders the float32 sums.
 
-    ``write_state(s, conv, i, *inputs) -> (out, (s, conv))`` is a
+    ``write_state(held, i, *inputs) -> (out, held)`` is a
     RECURRENT layer's, ``inputs`` what ``apply_block`` hands the kind's
     ``attend``: it runs the call's rows from the carried states of
-    recurrent layer ``i`` (``state_slabs``) and leaves the new ones
-    where they lay; a model without such layers never calls it."""
+    recurrent layer ``i`` (``held``: the cache's ``state_slabs`` by
+    name — ``s`` and ``conv``, or ``conv`` alone) and leaves the new
+    ones where they lay; a model without such layers never calls it.
+    Slab layers and state layers count through the runs, a routed
+    model's leading dense layers first."""
     # as far as a row's position goes: a full slot's is max_seq itself,
     # a chunk's last padded row's max_seq + chunk - 2
     cos, sin = _rope_tables(c, _slab_positions(cache, c) + x.shape[0])
@@ -2464,7 +2599,8 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
     tile = _grouped_tile(c, x.shape[0], mesh)
 
     def scan_stack(carry, stacks, cfg, first):
-        """``first``: the run's first layer's place in the cache."""
+        """``first``: the places in the cache of the run's first slab
+        layer and of its first state layer."""
         hoisted = {name: _hoist_experts(stack, cfg, name)
                    for name, stack in stacks.items()}
         experts = {name: parts[1] for name, parts in hoisted.items()}
@@ -2480,11 +2616,13 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                 stack, a_period, rank = cfg.place(j)
                 # the layer's place among the slabs of its kind
                 i = (p * kinds.count(kind) + kinds[:j].count(kind)
-                     + (first if kind == "full" else 0))
+                     + (first[0] if kind == "full" else
+                        first[1] if kind in RECURRENT else 0))
                 if kind in RECURRENT:
                     held = states
                     attend = functools.partial(
-                        write_state, *(slabs[name] for name in held), i)
+                        write_state, {name: slabs[name] for name in held},
+                        i)
                 else:
                     held = names[2:] if kind == "window" else names[:2]
                     attend = functools.partial(
@@ -2494,21 +2632,23 @@ def _scan_layers(params: dict, x, cache: dict, c: LlamaConfig, positions,
                     {**_place(layers, whole, j, cfg), **experts[stack]}, x,
                     cfg, cos, sin, positions, attend, _unconstrained,
                     p * a_period + rank, kind, tile, live)
-                slabs = {**slabs, **dict(zip(held, kept))}
+                slabs = {**slabs, **(kept if kind in RECURRENT
+                                     else dict(zip(held, kept)))}
                 loads.append(load)
             return (x, slabs), (loads[0] if len(loads) == 1
                                 or loads[0] is None else jnp.stack(loads))
 
-        carry, loads = lax.scan(period, carry,
-                                (layers, hoisted["layers"][2]))
+        carry, loads = lax.scan(
+            period, carry, (layers, next(iter(hoisted.values()))[2]))
         return carry, _unperiod(loads, cfg)
 
     def once(carry, first):
         """Every stack, once; ``first``: the pass's first slab layer."""
-        loads = None
+        loads, first = None, (first, 0)
         for stacks, cfg in _stacks(params, c):
             carry, loads = scan_stack(carry, stacks, cfg, first)
-            first += cfg.n_layers
+            first = (first[0] + cfg.layer_counts()[1],
+                     first[1] + cfg.n_recurrent)
         return carry, loads
 
     def a_pass(carry, u):
@@ -2580,18 +2720,24 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
         return _attend_slab(xq, ks, vs, i, slot, seen, blocks[ks.shape[2]],
                             c, w_kvb, window, top)
 
-    def state(s, conv, i, u, *inputs):
+    def state(held, i, u, *inputs):
         """A recurrent layer: the chunk from (layer i, slot)'s state —
         from an EMPTY one where the prompt begins there (``start`` 0:
         what the slot's last occupant left is never read) — and back
         goes the state behind the chunk's last REAL token: padding
         neither decays nor writes, and the convolution's tail is the
-        last real inputs."""
+        last real inputs — of a chunk with fewer real tokens than the
+        tail is long, the tail it began from moved up by them.  A conv
+        layer keeps the tail alone."""
         real = offs < chunk_len
-        s0 = jnp.where(start == 0, 0.0, s[i, slot])
+        s, conv = held.get("s"), held["conv"]
+        if s is not None:
+            s0 = jnp.where(start == 0, 0.0, s[i, slot])
         tail = jnp.where(start == 0, 0, conv[i, slot]).astype(conv.dtype)
         u = u.astype(conv.dtype)
-        if c.recurrent == "ssm":
+        if c.recurrent == "conv":
+            out, ext = delta_rule.causal_conv(u, tail, *inputs)
+        elif c.recurrent == "ssm":
             dt, weights = inputs
             y, ext = delta_rule.causal_conv(
                 u, tail, weights["conv_w"], weights["conv_b"])
@@ -2606,7 +2752,8 @@ def _chunk_rows(cache: dict, c: LlamaConfig, chunk: int, slot, start,
                 jnp.where(jnp.expand_dims(real, range(1, g.ndim)), g, 0.0),
                 jnp.where(real[:, None], beta, 0.0), s0)
         tail = lax.dynamic_slice_in_dim(ext, chunk_len, tail.shape[0])
-        return out, (s.at[i, slot].set(s1), conv.at[i, slot].set(tail))
+        return out, {**({} if s is None else {"s": s.at[i, slot].set(s1)}),
+                     "conv": conv.at[i, slot].set(tail)}
 
     return chunk, pos, offs < chunk_len, write, attend, state
 
@@ -2646,11 +2793,15 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
         return _attend_slab(xq, ks, vs, i, None, pos, blocks[n], c, w_kvb,
                             window, pos, visits[n] if visits else None)
 
-    def state(s, conv, i, u, *inputs):
+    def state(held, i, u, *inputs):
         """A recurrent layer: one token a slot from layer i's states; a
         slot that is not ``active`` — free, or between two chunks of
-        its own prompt — keeps its state and its tail bit for bit."""
-        if c.recurrent == "ssm":
+        its own prompt — keeps its state and its tail bit for bit.  A
+        conv layer keeps the tail alone."""
+        s, conv = held.get("s"), held["conv"]
+        if c.recurrent == "conv":
+            out, tail = delta_rule.causal_conv_step(u, conv[i], *inputs)
+        elif c.recurrent == "ssm":
             dt, weights = inputs
             y, tail = delta_rule.causal_conv_step(
                 u, conv[i], weights["conv_w"], weights["conv_b"])
@@ -2662,8 +2813,10 @@ def _decode_rows(cache: dict, c: LlamaConfig, active, mesh=None):
             out, new = _delta_forms(c)[1](
                 *_linear_qkv(y, c), g, beta, s[i], active)
         tail = jnp.where(active[:, None, None], tail, conv[i])
-        return out, (lax.dynamic_update_index_in_dim(s, new, i, 0),
-                     lax.dynamic_update_index_in_dim(conv, tail, i, 0))
+        return out, {
+            **({} if s is None else {
+                "s": lax.dynamic_update_index_in_dim(s, new, i, 0)}),
+            "conv": lax.dynamic_update_index_in_dim(conv, tail, i, 0)}
 
     return pos.shape[0], pos, active, write, attend, state
 
@@ -2772,13 +2925,13 @@ def _row_groups(*groups):
             outs.append(attend(ks, vs, i, window, q, w_kvb))
         return join(outs), (ks, vs)
 
-    def write_state(s, conv, i, *inputs):
+    def write_state(held, i, *inputs):
         # the last input is the layer's own leaves, the others are rows
         outs = []
         for (*_, state), *mine in zip(groups, *map(split, inputs[:-1])):
-            out, (s, conv) = state(s, conv, i, *mine, inputs[-1])
+            out, held = state(held, i, *mine, inputs[-1])
             outs.append(out)
-        return join(outs), (s, conv)
+        return join(outs), held
 
     return (join([pos for _, pos, *_ in groups]), write_attend,
             write_state, join([live for _, _, live, *_ in groups]))
