@@ -2222,6 +2222,13 @@ ROUTING_COUNTERS = (
     # block.  Over 3 * ``moe_experts_hit``: 1.0 where every expert hit
     # is read once a product; 0 under XLA's kernel
     "moe_expert_reads",
+    # of those, the fetches the kernel started under an EARLIER group's
+    # crossing visit (``grouped_matmul.fetches_ahead``: an expert that is
+    # one block is fetched by group, the next one from the current one's
+    # first visit on).  Over ``moe_expert_reads``: the share of fetches
+    # that no longer wait for their group's turn — ~0 in a decode step,
+    # whose groups are one visit each; 0 where k is in tiles
+    "moe_fetches_ahead",
 )
 
 
@@ -2273,22 +2280,26 @@ def _count_routing(cache: dict, loads, routed, decode: bool,
     seen = jnp.stack([jnp.sum(loads), jnp.sum(loads > 0), loads.size,
                       jnp.sum(jnp.max(loads, axis=-1)), routed])
     apart = seen[jnp.array([0, 1, 2, 4])]
-    visited = reads = 0
+    visited = reads = ahead = 0
     if tile:
         def over_layers(count, *rule):
             """the kernel's own rule, layer by layer"""
             return jnp.sum(jax.vmap(lambda sizes: count(sizes, tile, *rule))(
                 loads))
 
-        def fetched(k, n):
-            return over_layers(grouped_matmul.fetches,
-                               k // grouped_matmul.panel(k, n, itemsize)[0])
+        def three_products(count):
+            """gate and up (dim, wide), down (wide, dim)"""
+            def of(k, n):
+                return over_layers(
+                    count, k // grouped_matmul.panel(k, n, itemsize)[0])
+            return 2 * of(dim, wide) + of(wide, dim)
 
         dim, wide, itemsize = expert
         visited = tile * over_layers(grouped_matmul.visits)
-        reads = 2 * fetched(dim, wide) + fetched(wide, dim)  # gate, up; down
+        reads = three_products(grouped_matmul.fetches)
+        ahead = three_products(grouped_matmul.fetches_ahead)
     seen = jnp.concatenate([seen, apart if decode else apart * 0,
-                            jnp.stack([visited, dead, reads])])
+                            jnp.stack([visited, dead, reads, ahead])])
     return {"routing": cache["routing"] + seen.astype(jnp.uint32)}
 
 

@@ -17,12 +17,19 @@ never visited and hold whatever was there: the caller masks them.
 
 Grid ``(n tiles, visits, k tiles)``, k innermost: a visit's float32
 accumulator sums its k tiles in their order, and a row's sum is its own
-— the same bits whatever rows share its tile or its batch.  Consecutive
-visits of one group (a group that straddles row tiles) name the same
-weight block, which is then not fetched again where the expert is ONE
-block; where k is in tiles, the step from a visit's last k tile to the
-next visit's first changes the block, and the whole expert is fetched
-again though it is the same expert.
+— the same bits whatever rows share its tile or its batch.  WHO fetches
+an expert follows from its size.  Cut into panels (``_kernel``), a panel
+is a ``BlockSpec`` block and Pallas' pipeline fetches the next grid
+step's during this one: the step from a visit's last k tile to the next
+visit's first changes the block, so a group's every row tile reads its
+expert again.  ONE block (``_kernel_by_group``), the stack stays in HBM
+and the kernel copies experts itself, double-buffered by GROUP over the
+work list: the next hit group's matrix is in flight from the current
+group's FIRST visit on, and a group that straddles row tiles is fetched
+once.  A pipeline keyed by the grid step had no copy in flight during
+such a group's later visits, and the next expert then arrived under ONE
+visit's arithmetic (``PERF.md`` section 6, PR 63 and PR 66); the visits,
+their sums and the store mask are the same in both.
 
 ``tiling`` is the rule for (tm, tk, tn), a function of shapes alone,
 read from ``benchmarks/grouped_product``'s sweeps on a v5e (``PERF.md``
@@ -142,6 +149,22 @@ def fetches(sizes, tm: int, k_tiles: int):
     return jnp.maximum(jnp.sum(sizes > 0), 1)
 
 
+def fetches_ahead(sizes, tm: int, k_tiles: int):
+    """Of the expert matrices one product over ``sizes`` fetches, those
+    whose copy starts under an EARLIER group's crossing visit: where the
+    expert is one block the kernel starts a hit group's copy at its
+    predecessor's FIRST visit, so every hit group with two or more
+    visits and a hit group behind it moves that one's fetch forward,
+    under visits the step-keyed pipeline left without a copy in flight;
+    none with k in tiles (that pipeline is the path there)."""
+    if k_tiles > 1:
+        return jnp.int32(0)
+    per_group = visits_by_group(sizes, tm)
+    hit = (per_group > 0).astype(jnp.int32)
+    behind = jnp.cumsum(hit[::-1])[::-1] - hit       # hit groups after one
+    return jnp.sum((per_group >= 2) & (behind > 0)).astype(jnp.int32)
+
+
 def work_list(sizes, m: int, tm: int):
     """The visits of ``sizes`` (groups,) over ``m`` rows in tiles of
     ``tm``: ``(offsets (groups + 1,), group of visit i, row tile of
@@ -164,16 +187,19 @@ def work_list(sizes, m: int, tm: int):
     return offsets, group_ids, tile_ids, visits(sizes, tm)
 
 
-def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, layer_ref, lhs_ref,
-            rhs_ref, out_ref, acc_ref, *, tm: int, k_tiles: int):
-    del layer_ref                                   # the index maps' alone
+def _visit(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, rhs_ref, held,
+           out_ref, acc_ref, *, tm: int, k_tiles: int):
+    """One grid step: the row tile times ``rhs_ref[held]``, the block of
+    its visit's expert this step holds, into the visit's accumulator;
+    behind the last k tile the store of the rows that are the visit's
+    group's."""
     visit, k_i = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k_i == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[held],
                             preferred_element_type=jnp.float32)
 
     @pl.when(k_i == k_tiles - 1)
@@ -187,6 +213,74 @@ def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, layer_ref, lhs_ref,
         out_ref[...] = jnp.where(mine, acc_ref[...], out_ref[...])
 
 
+def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, layer_ref, lhs_ref,
+            rhs_ref, out_ref, acc_ref, *, tm: int, k_tiles: int):
+    """An expert in panels: the pipeline fetches a step's panel."""
+    del layer_ref                                   # the index maps' alone
+    _visit(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, rhs_ref, ...,
+           out_ref, acc_ref, tm=tm, k_tiles=k_tiles)
+
+
+def fetch_plan(offsets, group_ids, visit, visits, tm: int):
+    """What the by-group kernel does at ``visit`` of ``visits``, from the
+    work list it has anyway: ``(whether the visit is its group's first,
+    the next group hit — -1 behind the last)``, the second meaningful at
+    a first visit alone: a group's visits are the row tiles its rows
+    touch, and behind them comes the next hit group's first.
+    ``offsets`` and ``group_ids``: anything a scalar indexes — the
+    kernel's SMEM, a test's arrays."""
+    group = group_ids[visit]
+    first = (visit == 0) | (group != group_ids[jnp.maximum(visit - 1, 0)])
+    # the row tiles its rows touch (``visits_by_group``, of scalars)
+    its = (offsets[group + 1] - 1) // tm - offsets[group] // tm + 1
+    behind = visit + jnp.maximum(its, 1)        # (the empty visit: one)
+    ahead = jnp.where(behind < visits,
+                      group_ids[jnp.minimum(behind, visits - 1)], -1)
+    return first, ahead
+
+
+def _kernel_by_group(offsets_ref, group_ids_ref, tile_ids_ref, layer_ref,
+                     lhs_ref, stack_ref, out_ref, acc_ref, experts_ref,
+                     arrived, turn_ref, *, tm: int):
+    """An expert ONE block: the stack stays in HBM and the kernel copies
+    experts into ``experts_ref`` (2, k, n) itself, by group
+    (``fetch_plan``).  At a group's first visit it starts the NEXT hit
+    group's copy into the other buffer — free: its last holder's visits
+    all ended before this one began, the visits' axis being sequential —
+    and waits for its own, which its predecessor's first visit started
+    (the call's very first visit starts its own).  So a copy is in
+    flight under every visit but the last group's, and each one started
+    is waited for.  ``turn_ref``: the buffer of the group being visited,
+    turned at every group's first visit."""
+    visit = pl.program_id(1)
+    group = group_ids_ref[visit]
+    first, ahead = fetch_plan(offsets_ref, group_ids_ref, visit,
+                              pl.num_programs(1), tm)
+
+    def copy(group, buffer):
+        return pltpu.make_async_copy(stack_ref.at[layer_ref[0], group],
+                                     experts_ref.at[buffer],
+                                     arrived.at[buffer])
+
+    @pl.when(first)
+    def _fetch():
+        buffer = jnp.where(visit == 0, 0, 1 - turn_ref[0])
+        turn_ref[0] = buffer
+
+        @pl.when(visit == 0)
+        def _mine():
+            copy(group, buffer).start()
+
+        @pl.when(ahead >= 0)
+        def _ahead():
+            copy(ahead, 1 - buffer).start()
+
+        copy(group, buffer).wait()
+
+    _visit(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, experts_ref,
+           turn_ref[0], out_ref, acc_ref, tm=tm, k_tiles=1)
+
+
 @functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
 def grouped_matmul(lhs, rhs, sizes, layer=0, *,
                    tiling: tuple[int, int, int], interpret: bool = False):
@@ -196,7 +290,9 @@ def grouped_matmul(lhs, rhs, sizes, layer=0, *,
     group g is ``lhs[r] @ rhs[layer, g]``.  Rows behind the groups are
     not computed: they hold whatever was there.  ``tiling`` = (tm, tk,
     tn), tm a multiple of 16, tk and tn multiples of 128 that divide k
-    and n (or k and n themselves)."""
+    and n (or k and n themselves); with (tk, tn) the whole expert the
+    kernel fetches experts by group (``_kernel_by_group``), else the
+    pipeline a panel a step."""
     m, k = lhs.shape
     n = rhs.shape[-1]
     tm, tk, tn = tiling
@@ -211,25 +307,38 @@ def grouped_matmul(lhs, rhs, sizes, layer=0, *,
     offsets, group_ids, tile_ids, visits = work_list(sizes, padded, tm)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     k_tiles = k // tk
+    # two experts' blocks in VMEM either way: the pipeline's double
+    # buffer, or the kernel's own two
     blocks = 2 * (tm * tk * lhs.dtype.itemsize + tk * tn * rhs.dtype.itemsize
                   + tm * tn * 4) + tm * tn * 4
+    scratch = [pltpu.VMEM((tm, tn), jnp.float32)]
+    if (tk, tn) == (k, n):
+        kernel = functools.partial(_kernel_by_group, tm=tm)
+        rhs_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch += [pltpu.VMEM((2, k, n), rhs.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SMEM((1,), jnp.int32)]
+    else:
+        kernel = functools.partial(_kernel, tm=tm, k_tiles=k_tiles)
+        rhs_spec = pl.BlockSpec(
+            (None, None, tk, tn),
+            lambda n_i, v, k_i, offsets, groups, tiles, layer: (
+                layer[0], groups[v], k_i, n_i))
     out = pl.pallas_call(
-        functools.partial(_kernel, tm=tm, k_tiles=k_tiles),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((padded, n), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda n_i, v, k_i, offsets, groups,
                              tiles, layer: (tiles[v], k_i)),
-                pl.BlockSpec((None, None, tk, tn),
-                             lambda n_i, v, k_i, offsets, groups, tiles,
-                             layer: (layer[0], groups[v], k_i, n_i)),
+                rhs_spec,
             ],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda n_i, v, k_i, offsets, groups, tiles,
                 layer: (tiles[v], n_i)),
             grid=(n // tn, visits, k_tiles),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+            scratch_shapes=scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=blocks + (8 << 20)),

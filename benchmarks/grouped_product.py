@@ -1,9 +1,13 @@
 """On the chip: the routed experts' grouped product alone, XLA's
 ``ragged-dot`` kernel beside ``ops/pallas/grouped_matmul.py`` by tile, at
-the twelve shapes the benchmark's routed cells run (six configurations x
-decode step / prefill chunk): device ms a call, the GB/s at which the
-experts HIT are read, and whether the two agree to bf16 rounding on the
-rows that belong to a group.  Both read the whole stack of ``LAYERS``
+the fourteen shapes the benchmark's routed cells run (seven
+configurations x decode step / prefill chunk): device ms a call, the
+GB/s at which the experts HIT are read, the rule's visits and crossings
+(visits beyond a group's first) a call, whether the two agree to bf16
+rounding on the rows that belong to a group, and a digest of each
+tiling's output bits (``bits``: equal between two trees where the
+kernel's sums are the same — run this file against another tree with
+``cd <its copy> && PYTHONPATH=. python <this file>``).  Both read the whole stack of ``LAYERS``
 layers' experts with a traced layer index, as the step programs do; the
 routing is a top-k of random scores over the router's width, of which
 the first ``held`` experts are here (the benchmark's weights are random
@@ -16,6 +20,7 @@ from the root:
 """
 
 import functools
+import hashlib
 import json
 import statistics
 import sys
@@ -44,6 +49,8 @@ SHAPES = {
     "xing4.0-29b-a4b.chunk": (512, 4, 64, 64, (3584, 1024)),
     "solar-open2.decode": (24, 8, 40, 320, (4096, 1280)),
     "solar-open2.chunk": (512, 8, 40, 320, (4096, 1280)),
+    "lfm2-8b-a1b.decode": (96, 4, 32, 32, (2048, 1792)),
+    "lfm2-8b-a1b.chunk": (512, 4, 32, 32, (2048, 1792)),
 }
 LAYERS, LAYER, RUNS = 2, 1, 12
 ROW_TILES = (16, 32, 64, 128, 256)
@@ -110,15 +117,23 @@ def measure(shape, product, k, n, x, sizes, key):
             interpret=jax.default_backend() != "tpu"))
     real = int(sizes.sum())
     want = np.asarray(programs["xla"](x, stack, sizes, LAYER))[:real]
+    hit, rule_visits = int((sizes > 0).sum()), int(gm.visits(
+        sizes, tilings["rule"][0]))
     line = {"shape": shape, "product": product, "rows": m, "k": k, "n": n,
-            "experts_hit": int((sizes > 0).sum()), "experts_held": held,
-            "rows_held": real, "device": jax.devices()[0].device_kind,
-            "tilings": {}, "worst_rel_diff": {}}
+            "experts_hit": hit, "experts_held": held,
+            "rows_held": real, "visits": rule_visits,
+            "crossings": rule_visits - hit,
+            "device": jax.devices()[0].device_kind,
+            "tilings": {}, "worst_rel_diff": {}, "bits": {}}
+    if hasattr(gm, "fetches_ahead"):
+        line["fetches_ahead"] = int(gm.fetches_ahead(
+            sizes, tilings["rule"][0], k // tilings["rule"][1]))
     ok = True
     for label, program in programs.items():
         got = np.asarray(program(x, stack, sizes, LAYER))[:real]
         diff = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
         line["worst_rel_diff"][label] = diff
+        line["bits"][label] = hashlib.sha256(got.tobytes()).hexdigest()[:12]
         ok = ok and diff < 2.0 ** -8
     with tempfile.TemporaryDirectory() as directory:
         jax.profiler.start_trace(directory)
@@ -130,7 +145,7 @@ def measure(shape, product, k, n, x, sizes, key):
         ran = [ms for _, ms in device_ms(directory)]
     if ran:
         assert len(ran) == RUNS * len(programs), len(ran)
-        hit_bytes = line["experts_hit"] * k * n * 2
+        hit_bytes = hit * k * n * 2
         for i, label in enumerate(programs):
             ms = statistics.median(ran[i * RUNS:][:RUNS])
             line["tilings"][label] = {

@@ -101,6 +101,89 @@ def test_work_list_visits_every_tile_a_group_touches_once(name):
     assert np.all(np.diff(np.asarray(tile_ids)[:int(visits)]) >= 0)
 
 
+# hand-made sizes in tiles of 16: name -> (rows, sizes, per visit: its
+# group and, at the group's first visit, the group it fetches ahead —
+# -1 behind the last — else None; fetches moved forward)
+PLANS = {
+    "empty-groups-first-last-and-between": (
+        64, [0, 20, 0, 0, 9, 0],        # rows 0-19: tiles 0, 1 | 20-28: 1
+        [(1, 4), (1, None), (4, -1)], 1),
+    "one-group-over-three-tiles": (
+        64, [10, 32, 22],               # 0-9 | 10-41: 0, 1, 2 | 42-63: 2, 3
+        [(0, 1), (1, 2), (1, None), (1, None), (2, -1), (2, None)], 1),
+    "every-group-crosses": (
+        96, [20, 0, 30, 40],            # 0-19: 0, 1 | 20-49: 1, 2, 3 | 50-89
+        [(0, 2), (0, None), (2, 3), (2, None), (2, None), (3, -1),
+         (3, None), (3, None)], 2),
+    "one-group-only": (
+        48, [0, 0, 40, 0], [(2, -1), (2, None), (2, None)], 0),
+    "no-crossing": (
+        64, [16, 16, 16, 16], [(0, 1), (1, 2), (2, 3), (3, -1)], 0),
+    # the ONE visit of an empty group (whichever the list names): it
+    # fetches its own, nothing ahead
+    "no-row-at-all": (32, [0, 0, 0, 0], [(None, -1)], 0),
+}
+
+
+@pytest.mark.parametrize("name", PLANS)
+def test_fetch_plan_is_by_group(name):
+    """What the by-group kernel does at a visit, from the work list's
+    own ``offsets`` and ``group_ids``: a group's first visit fetches,
+    and ahead of its turn the next group HIT — the empty ones skipped,
+    -1 behind the last; ``fetches_ahead`` counts the hit groups with a
+    crossing visit and a hit group behind them — none where k is in
+    tiles."""
+    rows, sizes, want, ahead = PLANS[name]
+    sizes = jnp.asarray(sizes, jnp.int32)
+    offsets, group_ids, _, visits = gm.work_list(sizes, rows, 16)
+    offsets, group_ids = np.asarray(offsets), np.asarray(group_ids)
+    assert int(visits) == len(want)
+    started = None              # the copy in flight, by its group
+    for visit, (group, ahead_of) in enumerate(want):
+        first, got_ahead = gm.fetch_plan(offsets, group_ids, visit,
+                                         len(want), 16)
+        assert bool(first) == (ahead_of is not None), visit
+        if group is None:
+            assert sizes[group_ids[visit]] == 0
+        else:
+            assert group_ids[visit] == group
+        if first:
+            # every copy started is waited for, by the group it is of
+            assert started in (None, group_ids[visit])
+            assert int(got_ahead) == ahead_of, visit
+            started = None if ahead_of < 0 else ahead_of
+    assert started is None
+    assert int(gm.fetches_ahead(sizes, 16, 1)) == ahead
+    assert int(gm.fetches_ahead(sizes, 16, 2)) == 0
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+@pytest.mark.parametrize("name", PLANS)
+def test_fetched_by_group_every_row_is_its_experts_own_product(name, layer):
+    """The by-group kernel (the whole expert one block) at the plans'
+    sizes, a layer that is not the stack's first: every row of a group
+    has bit for bit ``lhs[r] @ rhs[layer, g]`` — ONE float32 dot over
+    the whole k, so no copy landed in the wrong buffer, came late or
+    from another layer."""
+    rows, sizes, _, _ = PLANS[name]
+    lhs, rhs = _operands(rows, len(sizes))
+    got = np.asarray(_kernel((16, K, N))(
+        lhs, rhs, jnp.asarray(sizes, jnp.int32), jnp.int32(layer)))
+    dot = jax.jit(lambda a, w: jnp.dot(a, w,
+                                       preferred_element_type=jnp.float32))
+    first = 0
+    for group, size in enumerate(sizes):
+        if size:
+            # the same (16, K) x (K, N) product the kernel's visit makes
+            want = np.concatenate([np.asarray(dot(
+                jnp.pad(lhs[at:at + 16], ((0, 16 - len(lhs[at:at + 16])),
+                                          (0, 0))), rhs[layer, group]))
+                for at in range(first, first + size, 16)])[:size]
+            np.testing.assert_array_equal(_bits(got[first:first + size]),
+                                          _bits(want), str(group))
+        first += size
+
+
 @pytest.mark.parametrize("tiling", [(16, 256, 384), (32, 128, 384),
                                     (64, 128, 128)], ids=str)
 def test_a_rows_sum_is_its_own_whatever_shares_its_batch(tiling):
@@ -324,7 +407,8 @@ def test_step_programs_through_the_kernel(name, monkeypatch):
     want, want_routing = run(0)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     tiled = np.isin(np.arange(len(got_routing)), [
-        counted("moe_tile_rows"), counted("moe_expert_reads")])
+        counted("moe_tile_rows"), counted("moe_expert_reads"),
+        counted("moe_fetches_ahead")])
     np.testing.assert_array_equal(got_routing[~tiled], want_routing[~tiled])
     assert (want_routing[tiled] == 0).all()
     # a tiny expert is one block: every expert hit is read once a product
@@ -356,30 +440,40 @@ def test_tile_rows_are_the_work_lists_visits():
     none = np.asarray(llama._count_routing(cache, loads, 99, True, 0)[
         "routing"])
     assert none[tile_rows] == 0
-    kernels = [tile_rows, llama.ROUTING_COUNTERS.index("moe_expert_reads")]
+    kernels = [tile_rows, llama.ROUTING_COUNTERS.index("moe_expert_reads"),
+               llama.ROUTING_COUNTERS.index("moe_fetches_ahead")]
     assert (np.delete(none, kernels) == np.delete(seen, kernels)).all()
 
 
-@pytest.mark.parametrize("expert,want", [
+@pytest.mark.parametrize("expert,want,want_ahead", [
     # an expert of 256 x 384 bf16 is one block in all three products:
-    # the groups hit, 3 | 1 (the empty visit's) | 3, three times
-    ((256, 384, 2), 3 * 7),
+    # the groups hit, 3 | 1 (the empty visit's) | 3, three times; ahead
+    # of its turn comes expert 3 of layer 0 (expert 2 crosses a tile),
+    # of layer 2 none (16 | 16 | 1: a visit each)
+    ((256, 384, 2), 3 * 7, 3 * 1),
     # A.X-K1's: k in 8 tiles, gate / up and down: the visits, 4 | 1 | 3
-    ((7168, 2048, 2), 3 * 8),
+    ((7168, 2048, 2), 3 * 8, 0),
 ], ids=["one-block", "k-in-tiles"])
-def test_expert_reads_are_what_the_kernels_rule_fetches(expert, want):
+def test_expert_reads_are_what_the_kernels_rule_fetches(expert, want,
+                                                        want_ahead):
     """``_count_routing``'s ``moe_expert_reads``: ``gm.fetches`` of each
     layer's loads for gate, up and down, the k tiles ``gm.panel``'s at
-    the expert's shape; 0 under XLA's kernel."""
+    the expert's shape, and ``moe_fetches_ahead`` ``gm.fetches_ahead``
+    of the same; 0 under XLA's kernel."""
     loads = jnp.asarray([[3, 0, 20, 1], [0, 0, 0, 0], [16, 16, 0, 1]],
                         jnp.int32)
     cache = {"routing": jnp.zeros((len(llama.ROUTING_COUNTERS),),
                                   jnp.uint32)}
     reads = llama.ROUTING_COUNTERS.index("moe_expert_reads")
-    assert np.asarray(llama._count_routing(
-        cache, loads, 99, True, 16, 0, expert)["routing"])[reads] == want
-    assert np.asarray(llama._count_routing(
-        cache, loads, 99, True, 0, 0, expert)["routing"])[reads] == 0
+    ahead = llama.ROUTING_COUNTERS.index("moe_fetches_ahead")
+    seen = np.asarray(llama._count_routing(
+        cache, loads, 99, True, 16, 0, expert)["routing"])
+    assert (seen[reads], seen[ahead]) == (want, want_ahead)
+    assert [int(gm.fetches_ahead(sizes, 16, 1)) for sizes in loads] == [
+        1, 0, 0]
+    none = np.asarray(llama._count_routing(
+        cache, loads, 99, True, 0, 0, expert)["routing"])
+    assert none[reads] == none[ahead] == 0
 
 
 def test_grouped_tile_keeps_ragged_dot_off_the_tpu_and_under_a_mesh(

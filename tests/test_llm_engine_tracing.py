@@ -734,7 +734,9 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
     and the decode steps' own PR 32, ``moe_tile_rows`` PR 37: 0 where XLA's
     kernel multiplies, as here, ``moe_dead_pairs`` PR 62: the pairs of the
     rows nobody reads, which reach no expert, ``moe_expert_reads`` PR 63:
-    the expert matrices the grouped kernel fetched, 0 here too) in ``LLMEngine.stats`` from the start, riding the step's one
+    the expert matrices the grouped kernel fetched, 0 here too, as
+    ``moe_fetches_ahead``, PR 66: those of them the kernel started under
+    an earlier group's crossing visit) in ``LLMEngine.stats`` from the start, riding the step's one
     read, and the named scopes a reducer can file operations under —
     ``moe`` around the routed experts, ``moe_shared`` around the shared
     one, ``mla`` around latent attention, ``attn_window`` and
@@ -750,17 +752,18 @@ def test_routed_models_name_their_counters_and_their_scopes(name, scopes):
         "moe_load_max", "moe_rows_routed", "moe_decode_assignments",
         "moe_decode_experts_hit", "moe_decode_expert_slots",
         "moe_decode_rows_routed", "moe_tile_rows", "moe_dead_pairs",
-        "moe_expert_reads")
+        "moe_expert_reads", "moe_fetches_ahead")
     eng = LLMEngine(cfg, slots=2, max_seq=64, prefill_chunk_tokens=8,
                     tokenizer=_NoEos())
     assert set(llama.ROUTING_COUNTERS) <= set(eng.stats)
-    assert eng.cache["routing"].shape == (12,)
+    assert eng.cache["routing"].shape == (13,)
     eng.generate([[5, 9, 17]], SamplingParams(max_tokens=3))
     stats = eng.stats
     assert stats["d2h_syncs"] == stats["decode_steps"] + 1
     held, routed = stats["moe_assignments"], stats["moe_rows_routed"]
     assert (held == routed) == (not cfg.router_width)
     assert stats["moe_tile_rows"] == stats["moe_expert_reads"] == 0
+    assert stats["moe_fetches_ahead"] == 0           # XLA's kernel: tile 0
     routed_layers = cfg.n_layers - cfg.n_dense_layers
     # a lone prompt: its chunk ran alone, and before it, once, the
     # mixed program empty (PR 39) — an execution of 2 + 8 rows
